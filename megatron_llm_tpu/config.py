@@ -119,8 +119,10 @@ class ModelConfig:
     init_method_std: float = 0.02
     use_scaled_init: bool = True  # scale output-layer init by 1/sqrt(2*layers)
     # attention impl: "flash" (pallas kernel) | "dot" (XLA einsum path).
-    # "dot" is the default until the Pallas kernel covers all shapes; "flash"
-    # falls back to "dot" with a warning when the kernel is unavailable.
+    # "dot" is the default until the Pallas kernel covers all shapes; with
+    # an attention bias or attention dropout "flash" takes the einsum path
+    # (the kernel has neither), and it never stands in for a kernel that
+    # fails to import or compile.
     attention_impl: str = "dot"
     # Pallas flash-attention tile sizes (attention_impl="flash").  1024² is
     # the validated default; the bench sweep (bench.py) tunes per shape.

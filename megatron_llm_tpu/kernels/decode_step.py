@@ -91,6 +91,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import kernels
 from ..ops.kv_quant import fake_quantize_rows
 
 NEG_INF = -1e30
@@ -138,7 +139,8 @@ def _int4_tile(ref, s_ref, cdt, gsz: int):
     high = (p32 << 24) >> 28
     r2, cols = p32.shape
     v = jnp.stack([low, high], axis=1).reshape(2 * r2, cols)
-    v = v.astype(jnp.float32).reshape(-1, gsz, cols) * s_ref[0][:, None, :]
+    scale = s_ref[(0,) * (len(s_ref.shape) - 2)]     # (rows/gsz, cols)
+    v = v.astype(jnp.float32).reshape(-1, gsz, cols) * scale[:, None, :]
     return v.reshape(2 * r2, cols).astype(cdt)
 
 
@@ -622,17 +624,20 @@ def _decode_step_kernel_paged(aq: int, mq: int, gsz: int,
             else:
                 kn_vis = kn_all.astype(kr_ref.dtype).astype(f32)
                 vn_vis = vn_all.astype(vr_ref.dtype).astype(f32)
-            c2 = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (1, block_k), 1)
             sel_rows = jax.lax.broadcasted_iota(jnp.int32, (b, 1, 1), 0)
             if not tree:
+                # column ids at the rank of the tile they mask: Mosaic has
+                # no layout for reshaping a bool vector ((1, bk) ->
+                # (1, bk, 1) i1 is an "unsupported shape cast")
+                c3 = j * block_k + jax.lax.broadcasted_iota(
+                    jnp.int32, (1, block_k, 1), 1)
                 for i in range(W - 1):
                     # one-hot gather of scratch row r·W + i (r is traced,
                     # so no dynamic scratch indexing)
                     sel = (sel_rows == r * W + i).astype(f32)
                     kvi = jnp.sum(kn_vis * sel, axis=0)  # (nkv, d)
                     vvi = jnp.sum(vn_vis * sel, axis=0)
-                    hit = (c2 == fill_r + i)[..., None]  # (1, bk, 1)
+                    hit = c3 == fill_r + i               # (1, bk, 1)
                     k4 = jnp.where(hit, kvi[:, None, :], k4)
                     v4 = jnp.where(hit, vvi[:, None, :], v4)
             else:
@@ -652,6 +657,8 @@ def _decode_step_kernel_paged(aq: int, mq: int, gsz: int,
                 # every row's tile equal to the shared linear splice,
                 # which is what keeps chain-tree verify bitwise-equal to
                 # the W-window path.
+                c4 = j * block_k + jax.lax.broadcasted_iota(
+                    jnp.int32, (1, 1, block_k, 1), 2)
                 k4 = jnp.broadcast_to(k4[None], (b,) + k4.shape)
                 v4 = jnp.broadcast_to(v4[None], (b,) + v4.shape)
                 for dd in range(W - 1):
@@ -668,7 +675,7 @@ def _decode_step_kernel_paged(aq: int, mq: int, gsz: int,
                         row_hit = (sel_rows == r * W + jj).astype(f32)
                         kdd = kdd + row_hit * kv_a[None]
                         vdd = vdd + row_hit * vv_a[None]
-                    hit = (c2 == fill_r + dd)[:, None, :, None]
+                    hit = c4 == fill_r + dd          # (1, 1, bk, 1)
                     k4 = jnp.where(hit, kdd[:, :, None, :], k4)
                     v4 = jnp.where(hit, vdd[:, :, None, :], v4)
             # per-row limits: row (s, j) attends cache positions
@@ -830,7 +837,19 @@ def _stack_eligible(cfg, params, platform: str):
     group size (0 when no class is int4).  Each class must be internally
     uniform, and either both classes are quantized or neither — a
     half-quantized stack (quantize_params never produces one) keeps the
-    composed path instead of silently dequantizing."""
+    composed path instead of silently dequantizing.
+
+    What the TPU compiler enforces on an accepted stack — interpret mode
+    checks none of it, tests/kernels/test_tpu_compile.py does.  Every
+    operand block's last two dims divide by (8, 128) or equal the
+    array's: hence the lane alignment of d, h, ffn and the head products
+    below, the ``[L, 1, out]`` form of the norm and int8 scales, and the
+    ``[L, nm, groups, h]`` form of the w_down int4 group scales
+    (``_chunk_down_scales`` — the number of group rows an MLP chunk
+    streams, ``f_chunk // gsz``, is 11 at the bench geometry and 43 at
+    ffn 11008, neither a multiple of 8, so as a block of the rank-3 array
+    it was refused).  An int4 group must not straddle an MLP chunk
+    (``f_chunk % gsz``): the chunk's scales could not ride with it."""
     from ..config import PositionEmbeddingType
     from ..ops.activations import is_glu
     from ..ops.attention import _mesh_active
@@ -1022,7 +1041,14 @@ def fused_paged_verify_eligible(cfg, params, k_pool, n_slots: int,
     ``tree`` charges the tree splice's per-row (b, nkv, block_k, d) key
     and value tiles (the shared tiles widen to a row axis), which the
     linear window never materializes.  ``mesh`` makes the dispatch
-    shard-aware exactly as in ``fused_paged_decode_eligible``."""
+    shard-aware exactly as in ``fused_paged_decode_eligible``.
+
+    No window width or tree shape is declined for the compiler's sake.
+    The one rule it enforces here is on the kernel, not the caller: the
+    splice masks are built at the rank of the tile they select
+    (``(1, bk, 1)`` linear, ``(1, 1, bk, 1)`` tree) because Mosaic has no
+    vector layout for reshaping a bool vector to a higher rank
+    ("unsupported shape cast")."""
     from ..ops.kv_quant import is_quantized_cache
 
     if n_slots < 1 or window < 1 or table_blocks < 1:
@@ -1055,6 +1081,27 @@ def _mlp_chunks(ffn: int, cap: int = 4) -> int:
         if lanes % nm == 0:
             return nm
     return 1
+
+
+def _chunk_down_scales(scale: jax.Array, nm: int) -> jax.Array:
+    """w_down int4 group scales ``[L, ffn/gsz, h]`` → ``[L, nm, groups per
+    chunk, h]`` (a free split of the group axis).  Each MLP tick streams
+    one chunk's groups; as a block of the rank-3 array that is
+    ``(1, 11, h)`` of ``(L, 22, h)`` at the bench geometry (43 of 86 at
+    ffn 11008), which breaks the TPU block rule — the last two block dims
+    must divide by (8, 128) or equal the array's.  Split this way the
+    block is ``(1, 1, groups, h)`` and its last two dims ARE the
+    array's."""
+    L, groups, h = scale.shape
+    return scale.reshape(L, nm, groups // nm, h)
+
+
+def _down_scale_spec(groups: int, h: int, nk: int, nm: int):
+    """BlockSpec of the ``_chunk_down_scales`` operand: MLP tick
+    ``ki - nk`` streams its own chunk's ``groups`` rows."""
+    def idx(li, ki, *s):
+        return (li, jnp.clip(ki - nk, 0, nm - 1), 0, 0)
+    return pl.BlockSpec((1, 1, groups, h), idx)
 
 
 def _default_block_k(cache_int8: bool) -> int:
@@ -1225,7 +1272,7 @@ def fused_decode_step(
     from ..ops.quant import int4_group_size, weight_bits
 
     if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+        interpret = kernels.default_interpret()
     cq8 = is_quantized_cache(k_cache)
     k_arr = k_cache["q"] if cq8 else k_cache
     v_arr = v_cache["q"] if cq8 else v_cache
@@ -1315,6 +1362,9 @@ def fused_decode_step(
                           attn_p["wo"]))
         + class_scales(mq, (mlp_p["w_gate"], mlp_p["w_up"],
                             mlp_p["w_down"])))
+    if mq == 4:
+        weight_scales = weight_scales[:-1] + (
+            _chunk_down_scales(weight_scales[-1], nm),)
     # int8 cache scales are [L, b, kv, max_len] fp32 → a trailing unit dim
     # keeps the (block_k, 1) block legal (flash_decode _scale_block_spec)
     cache_scales = (k_cache["scale"][..., None],
@@ -1391,7 +1441,7 @@ def fused_decode_step(
                            per_layer((1, h))]
     elif mq == 4:
         mlp_scale_specs = [mlp_col_spec(h // gsz), mlp_col_spec(h // gsz),
-                           mlp_row_spec(f_chunk // gsz)]
+                           _down_scale_spec(f_chunk // gsz, h, nk, nm)]
     else:
         mlp_scale_specs = []
     # packed int4 payloads store two rows per byte along the contraction
@@ -1441,9 +1491,6 @@ def fused_decode_step(
         # w_down LoRA x·A accumulator (see _mlp_chunk / _lora_down)
         scratch.append(pltpu.VMEM((b_pad, lsr), jnp.float32))
 
-    # jax < 0.5 exposes the TPU compiler params under the old name
-    compiler_params_cls = getattr(pltpu, "CompilerParams", None) \
-        or pltpu.TPUCompilerParams
     hidden, k_rows, v_rows = pl.pallas_call(
         functools.partial(_decode_step_kernel, per_row, aq, mq, gsz, cq8,
                           lsr, lt, nk, nm, block_k,
@@ -1456,7 +1503,7 @@ def fused_decode_step(
             scratch_shapes=scratch,
         ),
         out_shape=out_shape,
-        compiler_params=compiler_params_cls(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             # the whole-layer weight blocks are double-buffered by the
             # pipeline (~2x ~26 MB at the bench geometry), far past the
@@ -1587,7 +1634,7 @@ def _fused_paged_call(cfg, stacked, x, k_pool, v_pool, tables, pos,
     from ..ops.quant import int4_group_size, weight_bits
 
     if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+        interpret = kernels.default_interpret()
     cq8 = is_quantized_cache(k_pool)
     k_arr = k_pool["q"] if cq8 else k_pool
     v_arr = v_pool["q"] if cq8 else v_pool
@@ -1666,6 +1713,9 @@ def _fused_paged_call(cfg, stacked, x, k_pool, v_pool, tables, pos,
                           attn_p["wo"]))
         + class_scales(mq, (mlp_p["w_gate"], mlp_p["w_up"],
                             mlp_p["w_down"])))
+    if mq == 4:
+        weight_scales = weight_scales[:-1] + (
+            _chunk_down_scales(weight_scales[-1], nm),)
     # int8 pool scales are [L, nb, kv, block] fp32 → trailing unit dim
     # keeps the (block_k, 1) block legal (flash_decode _scale_block_spec)
     cache_scales = (k_pool["scale"][..., None],
@@ -1741,7 +1791,7 @@ def _fused_paged_call(cfg, stacked, x, k_pool, v_pool, tables, pos,
                            per_layer((1, h))]
     elif mq == 4:
         mlp_scale_specs = [mlp_col_spec(h // gsz), mlp_col_spec(h // gsz),
-                           mlp_row_spec(f_chunk // gsz)]
+                           _down_scale_spec(f_chunk // gsz, h, nk, nm)]
     else:
         mlp_scale_specs = []
     a_rows = h // 2 if aq == 4 else h
@@ -1787,8 +1837,6 @@ def _fused_paged_call(cfg, stacked, x, k_pool, v_pool, tables, pos,
         # w_down LoRA x·A accumulator (see _mlp_chunk / _lora_down)
         scratch.append(pltpu.VMEM((b_pad, lsr), jnp.float32))
 
-    compiler_params_cls = getattr(pltpu, "CompilerParams", None) \
-        or pltpu.TPUCompilerParams
     tree = tree_anc is not None
     prefetch = (lens, tables) if not tree \
         else (lens, tables, jnp.asarray(tree_anc, jnp.int32))
@@ -1805,7 +1853,7 @@ def _fused_paged_call(cfg, stacked, x, k_pool, v_pool, tables, pos,
             scratch_shapes=scratch,
         ),
         out_shape=out_shape,
-        compiler_params=compiler_params_cls(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=110 * 1024 * 1024,
         ),

@@ -34,9 +34,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 exposes the TPU compiler params under the old name
-_CompilerParams = (getattr(pltpu, "CompilerParams", None)
-                   or pltpu.TPUCompilerParams)
+from .. import kernels
 
 NEG_INF = -1e30  # large-negative instead of -inf: keeps exp() NaN-free
 
@@ -53,10 +51,6 @@ class _Config(NamedTuple):
     q_len: int          # un-padded q length
     use_segs: bool
     interpret: bool
-
-
-def _default_interpret() -> bool:
-    return jax.devices()[0].platform != "tpu"
 
 
 def _block_mask(cfg: _Config, qi, ki, s_block):
@@ -198,7 +192,7 @@ def _fwd(cfg: _Config, q, k, v, q_seg, k_seg):
             pltpu.VMEM((cfg.block_q, 128), jnp.float32),
             pltpu.VMEM((cfg.block_q, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
@@ -359,7 +353,7 @@ def _bwd_impl(cfg: _Config, q, k, v, o, lse, do, q_seg, k_seg):
         out_specs=pl.BlockSpec((1, 1, cfg.block_q, d), qmap),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((cfg.block_q, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
@@ -408,7 +402,7 @@ def _bwd_impl(cfg: _Config, q, k, v, o, lse, do, q_seg, k_seg):
             pltpu.VMEM((cfg.block_k, d), jnp.float32),
             pltpu.VMEM((cfg.block_k, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
@@ -502,7 +496,7 @@ def flash_attention(
     if softmax_scale is None:
         softmax_scale = 1.0 / float(np.sqrt(d))
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = kernels.default_interpret()
 
     block_q = min(block_q, max(128, 1 << (sq - 1).bit_length()))
     block_k = min(block_k, max(128, 1 << (sk - 1).bit_length()))

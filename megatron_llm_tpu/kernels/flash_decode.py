@@ -35,9 +35,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 exposes the TPU compiler params under the old name
-_CompilerParams = (getattr(pltpu, "CompilerParams", None)
-                   or pltpu.TPUCompilerParams)
+from .. import kernels
 
 NEG_INF = -1e30
 
@@ -146,7 +144,7 @@ def _decode_call(kernel_fn, q, caches, cache_len, softmax_scale,
     if softmax_scale is None:
         softmax_scale = 1.0 / float(np.sqrt(d))
     if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+        interpret = kernels.default_interpret()
     block_k = min(block_k, max_len)
     while max_len % block_k:
         block_k //= 2
@@ -183,7 +181,7 @@ def _decode_call(kernel_fn, q, caches, cache_len, softmax_scale,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, kv_heads, g_pad, d), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -246,7 +244,7 @@ def _paged_decode_call(kernel_fn, q, caches, tables, cache_len,
     if softmax_scale is None:
         softmax_scale = 1.0 / float(np.sqrt(d))
     if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+        interpret = kernels.default_interpret()
     if not interpret:
         assert block_k % 128 == 0, block_k
     nk = tables.shape[1]
@@ -280,7 +278,7 @@ def _paged_decode_call(kernel_fn, q, caches, tables, cache_len,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, kv_heads, g_pad, d), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -25,13 +25,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 exposes the TPU compiler params under the old name
-_CompilerParams = (getattr(pltpu, "CompilerParams", None)
-                   or pltpu.TPUCompilerParams)
-
-
-def _default_interpret() -> bool:
-    return jax.devices()[0].platform != "tpu"
+from .. import kernels
 
 
 def _block_rows(hidden: int) -> int:
@@ -134,7 +128,7 @@ def _row_call(kernel, n_out, rows_p, hidden, br, dtypes, operands, interpret):
         in_specs=specs,
         out_specs=out_specs if n_out > 1 else out_specs[0],
         out_shape=out_shape if n_out > 1 else out_shape[0],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,
@@ -160,7 +154,7 @@ def rmsnorm_pallas(x, weight, eps: float = 1e-5,
 
 def _rms_fwd(x, weight, eps, interpret):
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = kernels.default_interpret()
     x2, shape = _flatten(x)
     rows, hidden = x2.shape
     br = _block_rows(hidden)
@@ -212,7 +206,7 @@ def layernorm_pallas(x, weight, bias, eps: float = 1e-5,
 
 def _ln_fwd(x, weight, bias, eps, interpret):
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = kernels.default_interpret()
     x2, shape = _flatten(x)
     rows, hidden = x2.shape
     br = _block_rows(hidden)

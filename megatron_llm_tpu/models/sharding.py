@@ -299,13 +299,21 @@ def kv_pool_specs(cfg: ModelConfig, mesh: Mesh) -> tuple:
     return spec, spec
 
 
-def shard_kv_pool(k_pool, v_pool, cfg: ModelConfig, mesh: Mesh):
-    """Place a freshly-allocated block pool onto the serving mesh
-    according to :func:`kv_pool_specs`."""
-    k_spec, v_spec = kv_pool_specs(cfg, mesh)
-    put = lambda a, s: jax.device_put(a, NamedSharding(mesh, s))  # noqa: E731
-    return (jax.tree.map(put, k_pool, k_spec),
-            jax.tree.map(put, v_pool, v_spec))
+def init_sharded_kv_pool(cfg: ModelConfig, n_blocks: int, block_size: int,
+                         mesh: Mesh):
+    """An empty block pool (models/model.py:init_kv_pool) allocated
+    directly under :func:`kv_pool_specs` on the serving mesh.  Created
+    on the default device and moved afterwards, every replica's whole
+    pool would pass through device 0 — which then transiently holds all
+    of them."""
+    from . import model as model_lib
+
+    shardings = jax.tree.map(lambda s: NamedSharding(mesh, s),
+                             kv_pool_specs(cfg, mesh),
+                             is_leaf=lambda x: isinstance(x, P))
+    # tpulint: allow[recompile] one zero-fill per engine start, never hot
+    return jax.jit(lambda: model_lib.init_kv_pool(cfg, n_blocks, block_size),
+                   out_shardings=shardings)()
 
 
 def shard_params(params: Params, specs: Params, mesh: Mesh) -> Params:

@@ -5,8 +5,8 @@ This is the XLA path corresponding to the reference's ``CoreAttention``
 and its FlashAttention-2 fast path (transformer.py:508-523).  The Pallas
 flash kernel lives in ``megatron_llm_tpu.kernels.flash_attention``; this
 module provides the reference einsum implementation (always available, used
-on CPU test meshes and as the fallback mirroring fused_softmax.py:152-172)
-and the dispatcher.
+on CPU test meshes and for the masks the kernel does not cover, as
+fused_softmax.py:152-172 does) and the dispatcher.
 
 Conventions: activations are [batch, seq, heads, head_dim] throughout (the
 reference's [s, b, h] layout is a CUDA-kernel artifact; batch-major is the
@@ -19,13 +19,9 @@ at kv_heads).
 
 from __future__ import annotations
 
-import warnings
-
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-_flash_fallback_warned = False
 
 
 def _backend() -> str:
@@ -54,11 +50,9 @@ def _active_mesh():
     also set when tracing shard_map bodies) first, then this package's own
     ``parallel.mesh.use_mesh`` stack (the training driver / generation
     entry points use the latter)."""
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:  # jax >= 0.5; older jax has no ambient
-        ctx = get_abstract()      # abstract-mesh context to consult
-        if ctx is not None and not ctx.empty:
-            return ctx
+    ctx = jax.sharding.get_abstract_mesh()
+    if ctx is not None and not ctx.empty:
+        return ctx
     from ..parallel import mesh as mesh_lib
 
     return mesh_lib.current_mesh()
@@ -66,6 +60,23 @@ def _active_mesh():
 
 def _mesh_active() -> bool:
     return _active_mesh() is not None
+
+
+def _manual_over_mesh(fn, mesh, in_specs, out_specs):
+    """``shard_map`` ``fn`` manual over EVERY axis of ``mesh`` that is not
+    manual already (inside parallel/pipeline.py's region pp, dp and cp
+    are).  A ``pallas_call`` lowers for the TPU only where all mesh axes
+    are manual — jax refuses a partial-manual region ("Mosaic kernels
+    cannot be automatically partitioned") however the operands are
+    sharded, size-1 axes included — so every kernel reached under a mesh
+    goes through here.  Axes the specs do not name see replicated
+    operands."""
+    free = set(mesh.axis_names) - set(getattr(mesh, "manual_axes", ()))
+    if not free:
+        return fn
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names=free,
+                         check_vma=False)
 
 
 def _kernel_decode(q, k_cache, v_cache, cache_len, softmax_scale):
@@ -93,70 +104,55 @@ def _sharded_flash_decode(q, k_cache, v_cache, cache_len, softmax_scale,
     """Run the Pallas decode kernel under an active mesh, or return None.
 
     GSPMD has no partitioning rule for the ``pallas_call`` over a
-    kv-head-sharded cache, so the kernel is wrapped in a ``shard_map``
-    manual over the head-sharding axes only (batch/dp and the rest stay
-    GSPMD-managed — the partial-manual pattern of
-    parallel/ring_attention.py).  The head axes are tp alone in BOTH
-    layouts now: the serving re-layout shards layers over pp and
-    residency over fsdp (models/sharding.py:serving_param_specs), so a
-    pp axis never carries heads.  Returns None when the head counts
-    don't divide tp (MQA keeps K/V replicated and the einsum path is
-    already correct there) — the caller falls back.
+    kv-head-sharded cache, so the kernel runs inside a fully manual
+    ``shard_map`` (``_manual_over_mesh``) with heads split over tp — the
+    only head axis in both layouts: the serving re-layout shards layers
+    over pp and residency over fsdp (models/sharding.py:
+    serving_param_specs).  Returns None when the head counts don't divide
+    tp (MQA keeps K/V replicated and the einsum path is already correct
+    there) — the caller falls back.
     """
     from jax.sharding import PartitionSpec as P
     from .kv_quant import is_quantized_cache
-    from ..parallel.mesh import TENSOR_AXIS
 
-    if TENSOR_AXIS not in mesh.axis_names:
-        return None
-    if TENSOR_AXIS in getattr(mesh, "manual_axes", ()):
-        # already inside a manual-tp shard_map: shapes are per-shard and
-        # the pallas_call sees local arrays — call straight through.
-        return _kernel_decode(q, k_cache, v_cache, cache_len, softmax_scale)
     kv_q = is_quantized_cache(k_cache)
     n_heads = q.shape[2]
     kv_heads = (k_cache["q"] if kv_q else k_cache).shape[1]
-    # Prefer the serving re-layout's combined (pp, tp) head sharding; a
-    # training-layout mesh whose head counts only divide tp (pp shards
-    # layers there, not heads) keeps its tp-only kernel path.  The
-    # shard_map in_specs respec the operands, so either choice is
-    # correct — this only picks the layout that avoids resharding.
     axes = _head_shard_axes(mesh, n_heads, kv_heads)
     if axes is None:
         return None
-
     # kv-head-sharded cache spec — for the int8 dict form, the per-row
     # scale tensor shards on the same head axis
     cache_spec = ({"q": P(None, axes, None, None),
                    "scale": P(None, axes, None)} if kv_q
                   else P(None, axes, None, None))
-    wrapped = jax.shard_map(
+    wrapped = _manual_over_mesh(
         lambda q_, kc, vc, ln: _kernel_decode(q_, kc, vc, ln, softmax_scale),
-        mesh=mesh,
+        mesh,
         in_specs=(P(None, None, axes, None), cache_spec, cache_spec, P()),
-        out_specs=P(None, None, axes, None),
-        axis_names=set(axes),
-        check_vma=False,
-    )
+        out_specs=P(None, None, axes, None))
     return wrapped(q, k_cache, v_cache, jnp.asarray(cache_len, jnp.int32))
 
 
 def _head_shard_axes(mesh, n_heads: int, kv_heads: int):
-    """Mesh axes to shard decode heads over, or None.
+    """Spec entry for the head dim inside the kernels' ``shard_map``, or
+    None when the kernel path does not apply under this mesh.
 
-    Shared by the dense and paged sharded-kernel wrappers.  tp is the
-    only head axis in both the training layout and the serving
+    tp is the only head axis in both the training layout and the serving
     re-layout (pp shards layers, fsdp shards residency —
-    models/sharding.py); give up when tp doesn't divide both head
-    counts (MQA keeps K/V replicated; the einsum path is already
-    correct)."""
+    models/sharding.py).  Already manual (the caller sits in a manual-tp
+    region and sees per-shard heads): ``()`` — nothing left to split.
+    Free: ``("tp",)`` when it divides both head counts; give up when it
+    doesn't (MQA keeps K/V replicated; the einsum path is already
+    correct) or when the mesh has no tp to shard heads over."""
     from ..parallel.mesh import TENSOR_AXIS
 
-    if (TENSOR_AXIS in mesh.axis_names
-            and TENSOR_AXIS not in getattr(mesh, "manual_axes", ())
-            and mesh.shape[TENSOR_AXIS] > 1
-            and n_heads % mesh.shape[TENSOR_AXIS] == 0
-            and kv_heads % mesh.shape[TENSOR_AXIS] == 0):
+    if TENSOR_AXIS not in mesh.axis_names:
+        return None
+    if TENSOR_AXIS in getattr(mesh, "manual_axes", ()):
+        return ()
+    tp = mesh.shape[TENSOR_AXIS]
+    if tp > 1 and n_heads % tp == 0 and kv_heads % tp == 0:
         return (TENSOR_AXIS,)
     return None
 
@@ -165,23 +161,17 @@ def _sharded_paged_flash_decode(q, k_pool, v_pool, tables, cache_len,
                                 softmax_scale, mesh):
     """Run the PAGED Pallas decode kernel under an active mesh, or None.
 
-    The paged analogue of ``_sharded_flash_decode``: GSPMD cannot
-    partition the ``pallas_call`` over a kv-head-sharded pool, so the
-    kernel is wrapped in a ``shard_map`` manual over the head-sharding
-    axes.  Attention is embarrassingly parallel over kv heads, so each
-    shard walks its own head slice of every pool block; the int32 block
-    tables and fill levels are replicated (``P(None, None)`` /
-    ``P(None)``) — block ids stay global, no table translation — and an
-    int8 pool's ``{"q", "scale"}`` leaves move verbatim with the same
-    head-axis spec the pool was placed with
-    (models/sharding.py:kv_pool_specs).
+    The paged analogue of ``_sharded_flash_decode``: attention is
+    embarrassingly parallel over kv heads, so each shard walks its own
+    head slice of every pool block; the int32 block tables and fill
+    levels are replicated (``P(None, None)`` / ``P()``) — block ids stay
+    global, no table translation — and an int8 pool's ``{"q", "scale"}``
+    leaves move verbatim with the same head-axis spec the pool was placed
+    with (models/sharding.py:kv_pool_specs).
     """
     from jax.sharding import PartitionSpec as P
     from .kv_quant import is_quantized_cache
-    from ..parallel.mesh import TENSOR_AXIS
 
-    if TENSOR_AXIS not in mesh.axis_names:
-        return None
     kv_q = is_quantized_cache(k_pool)
 
     def _call(q_, kp, vp, tbl, ln):
@@ -197,10 +187,6 @@ def _sharded_paged_flash_decode(q, k_pool, v_pool, tables, cache_len,
             q_[:, 0], kp, vp, tbl, ln + 1,
             softmax_scale=softmax_scale)[:, None]
 
-    if TENSOR_AXIS in getattr(mesh, "manual_axes", ()):
-        # already inside a manual-tp shard_map: arrays are per-shard
-        return _call(q, k_pool, v_pool, tables,
-                     jnp.asarray(cache_len, jnp.int32))
     n_heads = q.shape[2]
     kv_heads = (k_pool["q"] if kv_q else k_pool).shape[1]
     axes = _head_shard_axes(mesh, n_heads, kv_heads)
@@ -209,29 +195,45 @@ def _sharded_paged_flash_decode(q, k_pool, v_pool, tables, cache_len,
     pool_spec = ({"q": P(None, axes, None, None), "scale": P(None, axes,
                                                              None)}
                  if kv_q else P(None, axes, None, None))
-    wrapped = jax.shard_map(
-        _call,
-        mesh=mesh,
+    wrapped = _manual_over_mesh(
+        _call, mesh,
         in_specs=(P(None, None, axes, None), pool_spec, pool_spec,
                   P(None, None), P()),
-        out_specs=P(None, None, axes, None),
-        axis_names=set(axes),
-        check_vma=False,
-    )
+        out_specs=P(None, None, axes, None))
     return wrapped(q, k_pool, v_pool, tables,
                    jnp.asarray(cache_len, jnp.int32))
 
 
-def _warn_flash_fallback():
-    global _flash_fallback_warned
-    if not _flash_fallback_warned:
-        _flash_fallback_warned = True
-        warnings.warn(
-            "attention_impl='flash' requested but the Pallas kernel is "
-            "unavailable; falling back to the XLA einsum path "
-            "(O(s^2) score materialization).",
-            stacklevel=3,
-        )
+def _sharded_flash_attention(q, k, v, segment_ids, mesh, **kw):
+    """``flash_attention`` under an active mesh: the training layouts'
+    attention (batch over dp, heads over tp), inside a fully manual
+    ``shard_map`` like the decode kernels.  Attention is independent per
+    (sample, kv head), so each shard runs the kernel on its own slice and
+    no collective is needed; the backward differentiates through the
+    ``shard_map``.  An axis that does not divide its dim is left out of
+    the spec — those shards then compute the same slice redundantly,
+    which is correct.  The sequence dim is never split here: cp > 1
+    takes the ring path before this one."""
+    from jax.sharding import PartitionSpec as P
+    from ..kernels.flash_attention import flash_attention
+    from ..parallel.mesh import DATA_AXIS, TENSOR_AXIS
+
+    manual = getattr(mesh, "manual_axes", ())
+
+    def split(axis, *dims):
+        ok = (axis in mesh.axis_names and axis not in manual
+              and all(d % mesh.shape[axis] == 0 for d in dims))
+        return axis if ok else None
+
+    dp = split(DATA_AXIS, q.shape[0])
+    tp = split(TENSOR_AXIS, q.shape[2], k.shape[2])
+    qkv = P(dp, None, tp, None)
+    seg = None if segment_ids is None else P(dp, None)
+    wrapped = _manual_over_mesh(
+        lambda q_, k_, v_, s_: flash_attention(q_, k_, v_, segment_ids=s_,
+                                               **kw),
+        mesh, in_specs=(qkv, qkv, qkv, seg), out_specs=qkv)
+    return wrapped(q, k, v, segment_ids)
 
 
 def make_causal_mask(seq_q: int, seq_k: int, dtype=jnp.float32) -> jax.Array:
@@ -540,20 +542,16 @@ def attention(
             segment_ids=segment_ids, softmax_scale=softmax_scale,
         )
     if impl == "flash" and bias is None and dropout_rate == 0.0:
-        try:
-            from ..kernels.flash_attention import flash_attention
-        except ImportError:
-            # Kernel module genuinely unavailable → einsum fallback (the
-            # availability-fallback pattern of fused_softmax.py:152-172).
-            # Errors *inside* an available kernel propagate — silent numeric
-            # fallback would mask kernel bugs.
-            _warn_flash_fallback()
-        else:
-            return flash_attention(
-                q, k, v, causal=causal, segment_ids=segment_ids,
-                softmax_scale=softmax_scale,
-                block_q=block_q, block_k=block_k,
-            )
+        # an import error of the kernel module propagates: no einsum
+        # stand-in that would hide the kernel's absence from a TPU run
+        kw = dict(causal=causal, softmax_scale=softmax_scale,
+                  block_q=block_q, block_k=block_k)
+        ctx = _active_mesh()
+        if ctx is not None and ctx.size > 1:
+            return _sharded_flash_attention(q, k, v, segment_ids, ctx, **kw)
+        from ..kernels.flash_attention import flash_attention
+
+        return flash_attention(q, k, v, segment_ids=segment_ids, **kw)
     return dot_product_attention(
         q, k, v, causal=causal, segment_ids=segment_ids,
         softmax_scale=softmax_scale, dropout_rate=dropout_rate,

@@ -52,10 +52,19 @@ def build_mesh(
     """Create the (dp, fsdp, pp, cp, ep, tp, sp) mesh.
 
     Replaces ``mpu.initialize_model_parallel(tp, pp, vpp, split_rank)``
-    (reference: megatron/core/parallel_state.py:51).  Uses
-    ``mesh_utils.create_device_mesh`` when the requested shape covers all
-    devices so the assignment respects the physical ICI topology.  The
-    trailing sp axis is always size 1 (see SEQ_AXIS).
+    (reference: megatron/core/parallel_state.py:51).  The first
+    ``prod(shape)`` of ``devices`` are assigned by
+    ``mesh_utils.create_device_mesh``, which follows the physical ICI
+    topology on a TPU (on a v5e 2x2, tp pairs are ring neighbours) and is
+    a plain reshape elsewhere.  If it cannot place the shape it raises,
+    and so does this: a naive reshape in its stead would silently trade
+    the ICI layout away.  The trailing sp axis is always size 1 (see
+    SEQ_AXIS).
+
+    A mesh over some but not all of the process's devices (a serving
+    replica's submesh, ``--tp 2`` on a four-chip host) turns the
+    persistent compile cache off for the process
+    (utils/compile_cache.py:disable_compile_cache says why).
     """
     if devices is None:
         devices = jax.devices()
@@ -73,16 +82,16 @@ def build_mesh(
         raise ValueError(
             f"mesh shape {shape} needs {n} devices, have {len(devices)}"
         )
-    if n == len(devices):
-        try:
-            from jax.experimental import mesh_utils
+    if 1 < n < jax.device_count():
+        from ..utils.compile_cache import disable_compile_cache
 
-            dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
-        except Exception:
-            dev_array = np.asarray(devices).reshape(shape)
-    else:
-        dev_array = np.asarray(devices[:n]).reshape(shape)
-    return Mesh(dev_array, AXIS_ORDER)
+        disable_compile_cache(
+            f"a mesh over {n} of {jax.device_count()} devices — cached "
+            "executables for a submesh halt the TPU")
+    from jax.experimental import mesh_utils
+
+    return Mesh(mesh_utils.create_device_mesh(shape, devices=devices[:n]),
+                AXIS_ORDER)
 
 
 def single_device_mesh(device: Optional[jax.Device] = None) -> Mesh:

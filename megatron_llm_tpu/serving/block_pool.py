@@ -96,23 +96,41 @@ class BlockPool:
     TRASH = 0
 
     def __init__(self, cfg, n_blocks: int, block_size: int,
-                 on_cow: Optional[Callable[[], None]] = None):
+                 on_cow: Optional[Callable[[], None]] = None, mesh=None):
         if n_blocks < 2:
             raise ValueError("BlockPool needs at least 2 blocks "
                              "(one is the reserved trash block)")
         self.cfg = cfg
         self.n_blocks = int(n_blocks)
         self.block_size = int(block_size)
-        self.mesh = None  # serving submesh, recorded by place()
-        self.k_pool, self.v_pool = model_lib.init_kv_pool(
-            cfg, n_blocks, block_size)
+        # With a serving submesh the pool is born on it: kv heads sharded
+        # over tp and the stacked layer axis over pp, so each pipeline
+        # stage holds only its own layer slice of every block
+        # (models/sharding.py:kv_pool_specs).  The host-side ledger
+        # (block ids, free list, refs) is sharding-agnostic — block ids
+        # stay global integers on every shard and on every stage, which
+        # is what keeps the allocator, prefix cache, COW, and the host
+        # tier topology-blind.
+        self.mesh = mesh
+        if mesh is None:
+            self.k_pool, self.v_pool = model_lib.init_kv_pool(
+                cfg, n_blocks, block_size)
+        else:
+            from ..models import sharding as shard_lib
+
+            self.k_pool, self.v_pool = shard_lib.init_sharded_kv_pool(
+                cfg, n_blocks, block_size, mesh)
         self._ref = np.zeros(n_blocks, dtype=np.int32)
         self._ref[self.TRASH] = 1  # permanently pinned
         self._free: List[int] = list(range(n_blocks - 1, 0, -1))
         self._reserved = 0
         self._on_cow = on_cow
-        # CPU donation aliases freed buffers in place; on accelerators we
-        # keep the plain path for the rare COW copy (simple + safe).
+        # Off the CPU the pool is donated through the COW copy and the
+        # shipment scatter, so neither holds two pools at once; XLA:CPU
+        # does not donate (it would warn on every call), so it takes the
+        # plain twins.  Only a chip run executes the donated ones:
+        # chip_smoke.py drives one COW copy; the shipment scatter has
+        # not run on a chip.
         self._copy = (_copy_block_plain
                       if jax.default_backend() == "cpu"
                       else _copy_block_donated)
@@ -125,23 +143,6 @@ class BlockPool:
         # the blocks cannot be recycled (and the LedgerSanitizer can
         # attribute them) while the transfer is in flight.
         self.shipments: dict = {}
-
-    def place(self, mesh) -> None:
-        """Re-place the pool arrays onto a serving submesh: kv heads
-        sharded over tp and the stacked layer axis over pp, so each
-        pipeline stage holds only its own layer slice of every block
-        (models/sharding.py:kv_pool_specs).
-
-        Called once by the sharded engine before any block is written:
-        the host-side ledger (block ids, free list, refs) is sharding-
-        agnostic — block ids stay global integers on every shard and on
-        every stage, which is what keeps the allocator, prefix cache,
-        COW, and the host tier topology-blind."""
-        from ..models import sharding as shard_lib
-
-        self.mesh = mesh
-        self.k_pool, self.v_pool = shard_lib.shard_kv_pool(
-            self.k_pool, self.v_pool, self.cfg, mesh)
 
     # ------------------------------------------------------------------
     # capacity / reservations

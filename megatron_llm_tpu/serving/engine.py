@@ -1091,9 +1091,9 @@ class ServingEngine:
                     + (cfg_e.prefix_cache_blocks or 0))
                 pool = BlockPool(
                     self.cfg, n_blocks, bk,
-                    on_cow=lambda: self.metrics.inc("cow_copies_total"))
+                    on_cow=lambda: self.metrics.inc("cow_copies_total"),
+                    mesh=self.mesh)
                 if self.mesh is not None:
-                    pool.place(self.mesh)
                     from ..parallel import mesh as mesh_lib
                     pp = mesh_lib.pipeline_parallel_size(self.mesh)
                     # microbatch-interleaved decode: split the slot batch
@@ -1154,12 +1154,13 @@ class ServingEngine:
                     # ledger, no separate alloc/free, and trash (block
                     # 0) masks identically.  Only the head geometry
                     # differs (draft_cfg's kv heads / head dim).
-                    dk, dv = model_lib.init_kv_pool(
-                        self.draft_cfg, n_blocks, bk)
                     if self.mesh is not None:
                         from ..models import sharding as shard_lib
-                        dk, dv = shard_lib.shard_kv_pool(
-                            dk, dv, self.draft_cfg, self.mesh)
+                        dk, dv = shard_lib.init_sharded_kv_pool(
+                            self.draft_cfg, n_blocks, bk, self.mesh)
+                    else:
+                        dk, dv = model_lib.init_kv_pool(
+                            self.draft_cfg, n_blocks, bk)
                     self._draft_kv = (dk, dv)
                     from ..kernels.decode_step import (
                         fused_paged_verify_eligible)

@@ -282,6 +282,9 @@ def main(argv=None) -> int:
                          "--supervise)")
     args = ap.parse_args(argv)
 
+    from ..utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from ..checkpointing import load_params_for_inference
     from ..models import families
     from ..tokenizer.tokenizer import build_tokenizer

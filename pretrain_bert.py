@@ -22,6 +22,7 @@ from megatron_llm_tpu.data.bert_dataset import BertDataset, BertSpecialTokens
 from megatron_llm_tpu.data.indexed_dataset import MMapIndexedDataset
 from megatron_llm_tpu.models import encdec
 from megatron_llm_tpu.training.driver import pretrain_custom
+from megatron_llm_tpu.utils.compile_cache import enable_compile_cache
 
 
 def get_args(argv=None):
@@ -99,6 +100,7 @@ def bert_loss_fn(cfg, params, mb, rng, deterministic):
 
 
 def main(argv=None):
+    enable_compile_cache()
     args = get_args(argv)
     if args.vocab_size is not None:
         vocab = args.vocab_size

@@ -21,6 +21,7 @@ from megatron_llm_tpu.data.ict_dataset import ICTDataset, ICTSpecialTokens
 from megatron_llm_tpu.data.indexed_dataset import MMapIndexedDataset
 from megatron_llm_tpu.models import biencoder
 from megatron_llm_tpu.training.driver import pretrain_custom
+from megatron_llm_tpu.utils.compile_cache import enable_compile_cache
 
 
 def get_args(argv=None):
@@ -68,6 +69,7 @@ def get_args(argv=None):
 
 
 def main(argv=None):
+    enable_compile_cache()
     args = get_args(argv)
     if args.tokenizer_model:
         from megatron_llm_tpu.tokenizer.tokenizer import build_tokenizer

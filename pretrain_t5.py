@@ -20,6 +20,7 @@ from megatron_llm_tpu.data.indexed_dataset import MMapIndexedDataset
 from megatron_llm_tpu.data.t5_dataset import T5Dataset, T5SpecialTokens
 from megatron_llm_tpu.models import encdec
 from megatron_llm_tpu.training.driver import pretrain_custom
+from megatron_llm_tpu.utils.compile_cache import enable_compile_cache
 
 
 def get_args(argv=None):
@@ -105,6 +106,7 @@ def t5_loss_fn(cfg, params, mb, rng, deterministic):
 
 
 def main(argv=None):
+    enable_compile_cache()
     args = get_args(argv)
     sentinel_ids = None
     if args.vocab_size is not None:

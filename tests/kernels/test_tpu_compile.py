@@ -1,0 +1,308 @@
+"""Deviceless compiles for a described TPU v5e: what Mosaic and XLA:TPU
+accept, asked of the compiler itself.
+
+Pallas interpret mode (every other kernel test here) enforces neither the
+(8, 128) block rule, nor vector layouts, nor the partitioning of a
+``pallas_call`` under a mesh — a kernel can pass all of them and stop at
+its first compile on a chip.  The TPU compiler is installed without the
+chip and compiles for a topology that is described, not attached
+(``jax.experimental.topologies``), so each case below lowers one kernel
+with ``interpret=False`` at a real width and compiles it for ``v5e:2x2``.
+Nothing runs: a pass says "compiles", never "correct" or "fast".
+Whole-step compiles (15-25 s each) live in ``chip_smoke.py``'s own
+planning pass, not here.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import pytest  # noqa: E402
+from jax.sharding import (  # noqa: E402
+    NamedSharding,
+    PartitionSpec as P,
+    SingleDeviceSharding,
+)
+
+from megatron_llm_tpu import kernels  # noqa: E402
+from megatron_llm_tpu.config import ParallelConfig, llama2_config  # noqa: E402
+from megatron_llm_tpu.kernels import decode_step as ds  # noqa: E402
+from megatron_llm_tpu.kernels import flash_decode as fd  # noqa: E402
+from megatron_llm_tpu.kernels.flash_attention import flash_attention  # noqa: E402
+from megatron_llm_tpu.kernels.rmsnorm import (  # noqa: E402
+    layernorm_pallas,
+    rmsnorm_pallas,
+)
+from megatron_llm_tpu.models import model as model_lib  # noqa: E402
+from megatron_llm_tpu.models.transformer import rope_tables  # noqa: E402
+from megatron_llm_tpu.ops import attention as attn_ops  # noqa: E402
+from megatron_llm_tpu.ops import lora as lora_ops  # noqa: E402
+from megatron_llm_tpu.ops import quant  # noqa: E402
+from megatron_llm_tpu.parallel import mesh as mesh_lib  # noqa: E402
+
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu / unknown topology
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _as_the_program_compiles():
+    """Two settings of the test bootstrap do not hold for these compiles.
+    A deviceless executable is written to the persistent cache but can
+    never be read back without a chip (each later compile would warn and
+    recompile), so the cache stays off.  And tests/conftest.py pins
+    ``jax_default_matmul_precision=highest`` for tight CPU numerics,
+    which no entry point sets and Mosaic refuses for bf16 operands
+    ("Bad lhs type"): the kernels compile at the default precision."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    cache = jax.config.jax_enable_compilation_cache
+    precision = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_enable_compilation_cache", False)
+    jax.config.update("jax_default_matmul_precision", None)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", cache)
+    jax.config.update("jax_default_matmul_precision", precision)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, args, sharding):
+    """Lower ``fn`` over shape-only ``args`` placed by ``sharding`` (one
+    sharding, or a pytree of them matching ``args``) and compile it for
+    the described chip; the kernel must be IN the executable."""
+    if not isinstance(sharding, (tuple, list)):
+        sharding = jax.tree.map(lambda _: sharding, args)
+    args = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        args, tuple(sharding))
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+def _sds(shape, dtype=BF16):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+# -- attention / norm kernels at published widths --------------------------
+
+@pytest.mark.parametrize("heads,kv,d,segs", [
+    (32, 32, 128, False),   # Llama-2-7B MHA
+    (32, 8, 128, False),    # GQA (Llama-2-70B group shape)
+    (71, 1, 64, False),     # Falcon-7B MQA, 64-wide heads
+    (32, 32, 128, True),    # packed documents
+], ids=["mha_d128", "gqa_32_8", "mqa_71_1_d64", "segment_ids"])
+def test_flash_attention_fwd_bwd(topo, heads, kv, d, segs):
+    s = 1024
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def loss(q, k, v, seg):
+        o = flash_attention(q, k, v, segment_ids=seg if segs else None,
+                            interpret=False)
+        return o.astype(jnp.float32).sum()
+
+    text = _compile(
+        jax.grad(loss, argnums=(0, 1, 2)),
+        (_sds((1, s, heads, d)), _sds((1, s, kv, d)), _sds((1, s, kv, d)),
+         _sds((1, s), jnp.int32)), one)
+    assert text.count("tpu_custom_call") >= 3  # fwd, dq, dkv
+
+
+@pytest.mark.parametrize("variant", ["dense", "dense_int8", "paged",
+                                     "paged_int8"])
+def test_flash_decode(topo, variant):
+    b, h, kv, d, max_len, bk = 8, 32, 32, 128, 2048, 128
+    nb, t = 64, max_len // bk
+    one = SingleDeviceSharding(topo.devices[0])
+    q, lens = _sds((b, h, d)), _sds((b,), jnp.int32)
+    if variant == "dense":
+        fn = lambda q, k, v, n: fd.flash_decode(  # noqa: E731
+            q, k, v, n, interpret=False)
+        args = (q, _sds((b, kv, max_len, d)), _sds((b, kv, max_len, d)),
+                lens)
+    elif variant == "dense_int8":
+        fn = lambda q, k, ks, v, vs, n: fd.flash_decode_int8(  # noqa: E731
+            q, k, ks, v, vs, n, interpret=False)
+        c, sc = (_sds((b, kv, max_len, d), jnp.int8),
+                 _sds((b, kv, max_len), jnp.float32))
+        args = (q, c, sc, c, sc, lens)
+    elif variant == "paged":
+        fn = lambda q, k, v, tb, n: fd.flash_decode_paged(  # noqa: E731
+            q, k, v, tb, n, interpret=False)
+        p = _sds((nb, kv, bk, d))
+        args = (q, p, p, _sds((b, t), jnp.int32), lens)
+    else:
+        fn = lambda q, k, ks, v, vs, tb, n: (  # noqa: E731
+            fd.flash_decode_paged_int8(q, k, ks, v, vs, tb, n,
+                                       interpret=False))
+        p, sc = (_sds((nb, kv, bk, d), jnp.int8),
+                 _sds((nb, kv, bk), jnp.float32))
+        args = (q, p, sc, p, sc, _sds((b, t), jnp.int32), lens)
+    _compile(fn, args, one)
+
+
+@pytest.mark.parametrize("norm,hidden", [("rmsnorm", 4096),
+                                         ("layernorm", 4544)])
+def test_norm_fwd_bwd(topo, norm, hidden):
+    one = SingleDeviceSharding(topo.devices[0])
+    x, w = _sds((2, 1024, hidden)), _sds((hidden,))
+    if norm == "rmsnorm":
+        def loss(x, w):
+            return rmsnorm_pallas(x, w, 1e-5, False).astype(
+                jnp.float32).sum()
+        _compile(jax.grad(loss, argnums=(0, 1)), (x, w), one)
+    else:
+        def loss(x, w, b):
+            return layernorm_pallas(x, w, b, 1e-5, False).astype(
+                jnp.float32).sum()
+        _compile(jax.grad(loss, argnums=(0, 1, 2)), (x, w, w), one)
+
+
+# -- whole-stack fused decode kernels (the geometry that is eligible) ------
+
+def _stack_cfg(kv_quant="none", wide=False):
+    # Llama stacks the fused kernels accept (7B-width layers exceed their
+    # VMEM budget).  wide: bench.py's 374M geometry.  Else a quarter of
+    # its hidden size — Mosaic's compile time grows with the square of it
+    # (int4 at 1024: 40 s) — at the SAME ffn, so w_down still streams 11
+    # int4 scale groups per MLP chunk, the count that broke the block rule
+    hidden, heads = (1024, 8) if wide else (256, 2)
+    return llama2_config(
+        "7b", hidden_size=hidden, num_layers=2, num_attention_heads=heads,
+        num_kv_heads=heads, ffn_hidden_size=2816, seq_length=1024,
+        max_position_embeddings=1024, params_dtype="bfloat16",
+        kv_cache_quant=kv_quant)
+
+
+def _stack_params(cfg, policy):
+    def build():
+        p = model_lib.init_params(jax.random.key(0), cfg)
+        return quant.quantize_params(p, policy) if policy else p
+    return jax.eval_shape(build)
+
+
+def _lora(cfg, rows):
+    arenas = jax.eval_shape(
+        lambda: lora_ops.make_arenas(cfg, 16, 8, lora_ops.LORA_TARGETS))
+    return arenas, _sds((rows, 128), jnp.float32)
+
+
+_PRECISIONS = {
+    "bf16": (None, "none"), "int8": ("int8", "int8"),
+    "int4": ("int4", "none"), "mixed": ("mixed", "none"),
+    "lora": (None, "none"),
+}
+
+
+@pytest.mark.parametrize("precision", list(_PRECISIONS))
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+def test_fused_decode_step(topo, paged, precision):
+    policy, kvq = _PRECISIONS[precision]
+    cfg = _stack_cfg(kvq, wide=precision == "bf16")
+    params = _stack_params(cfg, policy)
+    one = SingleDeviceSharding(topo.devices[0])
+    b, max_len, bk = 8, 1024, 128
+    rope = rope_tables(cfg)
+    x, fills = _sds((b, cfg.hidden_size)), _sds((b,), jnp.int32)
+    lora = _lora(cfg, b) if precision == "lora" else None
+    lsr = 128 if lora else 0
+    if paged:
+        nb, t = 32, max_len // bk
+        k, v = jax.eval_shape(lambda: model_lib.init_kv_pool(cfg, nb, bk))
+        assert ds.fused_paged_decode_eligible(cfg, params, k, b, t, "tpu",
+                                              lora_sr=lsr)
+
+        def fn(layers, x, k, v, tables, fills, lora):
+            return ds.fused_decode_step_paged(
+                cfg, layers, x, k, v, tables, fills, rope, lora=lora,
+                interpret=False)
+        args = (params["layers"], x, k, v, _sds((b, t), jnp.int32), fills,
+                lora)
+    else:
+        k, v = jax.eval_shape(
+            lambda: model_lib.init_kv_cache(cfg, b, max_len))
+        assert ds.fused_decode_eligible(cfg, params, k, 1, "tpu", lsr)
+
+        def fn(layers, x, k, v, fills, lora):
+            return ds.fused_decode_step(
+                cfg, layers, x, k, v, fills, rope, lora=lora,
+                interpret=False)
+        args = (params["layers"], x, k, v, fills, lora)
+    _compile(fn, args, one)
+
+
+@pytest.mark.parametrize("variant", ["linear", "linear_int8", "tree"])
+def test_fused_decode_verify(topo, variant):
+    kvq = "int8" if variant == "linear_int8" else "none"
+    cfg = _stack_cfg(kvq)
+    params = _stack_params(cfg, "int8" if kvq == "int8" else None)
+    one = SingleDeviceSharding(topo.devices[0])
+    s, w, max_len, bk, nb = 4, 4, 1024, 128, 32
+    t = max_len // bk
+    tree = variant == "tree"
+    rope = rope_tables(cfg)
+    k, v = jax.eval_shape(lambda: model_lib.init_kv_pool(cfg, nb, bk))
+    assert ds.fused_paged_verify_eligible(cfg, params, k, s, w, t, "tpu",
+                                          tree=tree)
+
+    def fn(layers, x, k, v, tables, fills, depths, anc):
+        return ds.fused_decode_verify_paged(
+            cfg, layers, x, k, v, tables, fills, rope,
+            depths=depths if tree else None, anc=anc if tree else None,
+            interpret=False)
+
+    _compile(fn, (params["layers"], _sds((s, w, cfg.hidden_size)), k, v,
+                  _sds((s, t), jnp.int32), _sds((s,), jnp.int32),
+                  _sds((s, w), jnp.int32), _sds((s, w, w), jnp.int32)), one)
+
+
+# -- kernels under a mesh: every pallas_call inside a fully manual shard_map
+
+def _tp2_mesh(topo):
+    return mesh_lib.build_mesh(ParallelConfig(tensor_parallel=2),
+                               devices=topo.devices[:2])
+
+
+def test_sharded_paged_decode_attention(topo, monkeypatch):
+    mesh = _tp2_mesh(topo)
+    monkeypatch.setattr(attn_ops, "_backend", lambda: "tpu")
+    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
+    b, h, kv, d, bk, nb, t = 8, 32, 32, 128, 128, 64, 16
+    heads = NamedSharding(mesh, P(None, None, "tp", None))
+    pool = NamedSharding(mesh, P(None, "tp", None, None))
+    rep = NamedSharding(mesh, P())
+
+    def fn(q, k, v, tables, fills):
+        with mesh_lib.use_mesh(mesh):
+            return attn_ops.paged_decode_attention(q, k, v, tables, fills)
+
+    _compile(fn, (_sds((b, 1, h, d)), _sds((nb, kv, bk, d)),
+                  _sds((nb, kv, bk, d)), _sds((b, t), jnp.int32),
+                  _sds((b,), jnp.int32)), (heads, pool, pool, rep, rep))
+
+
+def test_sharded_flash_attention_fwd_bwd(topo, monkeypatch):
+    mesh = _tp2_mesh(topo)
+    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
+    heads = NamedSharding(mesh, P(None, None, "tp", None))
+
+    def loss(q, k, v):
+        with mesh_lib.use_mesh(mesh):
+            o = attn_ops.attention(q, k, v, impl="flash")
+        return o.astype(jnp.float32).sum()
+
+    qkv = _sds((2, 1024, 32, 128))
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), (qkv, qkv, qkv),
+             (heads, heads, heads))
