@@ -1,0 +1,119 @@
+"""``kind: serve_open`` — an open loop at a fixed rate.
+
+Poisson arrivals at the mix's ``rate_rps`` start ``lead_s`` before the
+measured window, so that it opens in steady state; a request counts if
+it was due inside the window, and one that has not finished ``drain_s``
+after the window's end has failed.  The load generator is this thread;
+how late it ran is printed.  ``--sweep`` runs the mix's ``sweep_rates``
+one after another on one set-up and prints the table the knee is read
+from.
+"""
+
+from __future__ import annotations
+
+import time
+
+from benchmarks import serving, stats, traffic
+from benchmarks.common import Ctx, Result, say
+
+
+def drive(sv: serving.Serving, requests, t_start: float, seconds: float,
+          drain_s: float):
+    """Submit ``requests`` when due (relative to ``t_start``), wait for
+    the counted ones → (served, lateness in s, queue depths)."""
+    served, late = [], []
+    depth = {}
+    for r in requests:
+        due = t_start + r.due_s
+        wait = due - time.perf_counter()
+        if wait > 0:
+            time.sleep(wait)
+        if "start" not in depth and r.due_s >= 0:
+            depth["start"] = len(sv.engine.queue)
+        s = serving.Served(due, len(r.prompt), r.max_new_tokens,
+                           0.0 <= r.due_s < seconds)
+        sv.submit(s, r.prompt)
+        late.append(s.submitted - due)
+        served.append(s)
+    time.sleep(max(0.0, t_start + seconds - time.perf_counter()))
+    depth["end"] = len(sv.engine.queue)
+    depth.setdefault("start", 0)
+    sv.wait_idle([s for s in served if s.counted],
+                 t_start + seconds + drain_s)
+    return served, late, depth
+
+
+def sweep(ctx: Ctx, sv: serving.Serving) -> None:
+    mix = sv.mix
+    seconds = float(mix["sweep_seconds"])
+    say("sweep: rate_rps offered completed_in_window completed_rps "
+        "queue_start queue_end ttft_p50_ms ttft_p95_ms itl_p50_ms "
+        "itl_p95_ms unfinished late_max_ms")
+    for rate in mix["sweep_rates"]:
+        reqs = traffic.serve_requests(mix, ctx.seed, seconds,
+                                      sv.model.vocab_size, rate_rps=rate)
+        t_start = time.perf_counter() + float(mix["lead_s"]) + 0.2
+        served, late, depth = drive(sv, reqs, t_start, seconds,
+                                    float(mix["drain_s"]))
+        counted = [s for s in served if s.counted]
+        in_window = sum(1 for s in served if s.done
+                        and t_start <= s.stamps[-1] < t_start + seconds)
+        rep = serving.latency_report(counted)
+        say(f"sweep: {rate} {len(counted)} {in_window} "
+            f"{in_window / seconds:.3f} {depth['start']} {depth['end']} "
+            f"{rep.get('ttft_p50_ms', float('nan')):.1f} "
+            f"{rep.get('ttft_p95_ms', float('nan')):.1f} "
+            f"{rep.get('itl_p50_ms', float('nan')):.1f} "
+            f"{rep.get('itl_p95_ms', float('nan')):.1f} "
+            f"{sum(not s.done for s in counted)} {1e3 * max(late):.1f}")
+        sv.wait_idle(served, time.perf_counter() + 120.0)
+
+
+def run(ctx: Ctx):
+    sv = serving.Serving(ctx)
+    mix = sv.mix
+    requests = traffic.serve_requests(mix, ctx.seed, ctx.seconds,
+                                      sv.model.vocab_size)
+    served = []
+    try:
+        sv.prepare()
+        if ctx.sweep:
+            sweep(ctx, sv)
+            return None
+        lead = float(mix["lead_s"])
+        c0 = ctx.clock.backend_compiles
+        t_start = time.perf_counter() + lead + 0.1
+        sl = serving.TraceSlice(ctx, t_start, ctx.seconds) if ctx.trace \
+            else None
+        served, late, depth = drive(sv, requests, t_start, ctx.seconds,
+                                    float(mix["drain_s"]))
+        compiles = ctx.clock.backend_compiles - c0
+        if sl is not None:
+            sl.join()
+        counted = [s for s in served if s.counted]
+        failed = serving.bad_finishes(counted)
+        evidence = serving.layer_evidence(
+            sv, sl, (t_start, t_start + ctx.seconds))
+    finally:
+        sv.close(served)
+    rep = serving.latency_report(counted)
+    say(f"window: {len(counted)} requests due in {ctx.seconds:.0f} s at "
+        f"{mix['rate_rps']} a second (of {len(served)} sent); ttft p50 "
+        f"{rep['ttft_p50_ms']:.1f} ms p95 {rep['ttft_p95_ms']:.1f} ms over "
+        f"{rep['n_ttft']} requests ({stats.samples_beyond(rep['n_ttft'], 95)}"
+        f" beyond the 95th); gap p50 {rep['itl_p50_ms']:.2f} ms p95 "
+        f"{rep['itl_p95_ms']:.2f} ms over {rep['n_gaps']} gaps")
+    say(f"load generator ran late by at most {1e3 * max(late):.2f} ms "
+        f"(p95 {1e3 * stats.percentile(late, 95):.2f} ms); queue depth "
+        f"{depth['start']} at the window's start, {depth['end']} at its end")
+    notes = sv.correct_notes + [
+        f"compilations inside the window: {compiles}",
+        f"requests that did not end 'length' with every token inside the "
+        f"drain limit: {failed} of {len(counted)}"]
+    return Result(
+        correct=sv.correct and compiles == 0 and failed == 0,
+        attempted=len(counted), failed=failed,
+        end_to_end={"ttft_p95_ms": rep["ttft_p95_ms"],
+                    "itl_p95_ms": rep["itl_p95_ms"],
+                    "setup_s": t_start - ctx.t0},
+        evidence=evidence, notes=notes)
