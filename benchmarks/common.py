@@ -4,6 +4,7 @@ back, and the program's model configuration built from a config file."""
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Any, Dict, List
 
 
@@ -19,7 +20,8 @@ class Ctx:
     mix: dict                  # benchmarks/traffic/<traffic>.json
     seed: int
     seconds: float
-    trace: bool
+    trace: int                 # 0: end-to-end only; 1: a traced run of its
+                               # own; 2: a --trace 0 run, then a traced phase
     rehearsal: bool
     t0: float                  # perf_counter at process start
     device: dict               # {"platform", "kind", "count"}
@@ -40,6 +42,68 @@ class Result:
     end_to_end: Dict[str, float]           # every metric the run took
     evidence: Dict[str, Any]               # what the per-layer readers read
     notes: List[str] = dataclasses.field(default_factory=list)
+
+
+# --- the program's spans and its profile session (--trace 2) ----------------
+
+def recorder_spans(recorder, lo: float, hi: float):
+    """The spans of a ``TraceRecorder`` (``obs/trace.py``) that began in
+    [lo, hi) on the perf_counter clock, as ``(name, start, seconds,
+    args)``.  The recorder exports its epoch, so nothing is injected."""
+    doc = recorder.chrome_trace()
+    epoch = doc["otherData"]["epoch_perf_counter"]
+    out = []
+    for e in doc["traceEvents"]:
+        if e.get("ph") != "X":
+            continue
+        t0 = epoch + e["ts"] / 1e6
+        if lo <= t0 < hi:
+            out.append((e["name"], t0, e["dur"] / 1e6, e.get("args", {})))
+    return out
+
+
+def before_traced_phase() -> str:
+    """Called by a ``--trace 2`` runner right after it has taken the
+    window's numbers: what of the traced phase exists at that moment
+    (nothing may — up to here the run is a ``--trace 0`` run)."""
+    import threading
+
+    from megatron_llm_tpu.obs import profile
+
+    own = [t.name for t in threading.enumerate()
+           if t.name.startswith("bench")]
+    return (f"when the window's numbers were taken: profile sessions so far "
+            f"{0 if profile.last() is None else 1}, threads of the "
+            f"benchmark's own {own}")
+
+
+def stop_profiler():
+    """Stop the program's profile session → the session; says how long
+    collecting and writing the trace took."""
+    from megatron_llm_tpu.obs import profile
+
+    session = profile.stop()
+    say(f"profile session: {session.t_stop - session.t_sync:.2f} s traced, "
+        f"written in {time.perf_counter() - session.t_stop:.1f} s")
+    return session
+
+
+def on_trace_clock(trace, session, spans):
+    """``(name, start, seconds, args)`` spans on the perf_counter clock →
+    ``(offset_ns, [trace_reduce.Event])`` on the clock of ``trace``, the
+    reduced profile of ``session``: joined by the session's
+    ``obs_clock_sync`` annotation.  ``(None, [])`` where the trace does
+    not hold it."""
+    from megatron_llm_tpu.obs import profile
+
+    from benchmarks import trace_reduce
+
+    sync = [e for e in trace.host if e.name == profile.SYNC_NAME]
+    if not sync:
+        return None, []
+    off = profile.to_trace_ns(0.0, session.t_sync, sync[0].start)
+    return off, [trace_reduce.Event(n, t0 * 1e9 + off, (t0 + d) * 1e9 + off)
+                 for n, t0, d, _a in spans]
 
 
 def depth_of(config: dict, kind: str) -> int:

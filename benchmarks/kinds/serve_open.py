@@ -7,13 +7,21 @@ after the window's end has failed.  The load generator is this thread;
 how late it ran is printed.  ``--sweep`` runs the mix's ``sweep_rates``
 one after another on one set-up and prints the table the knee is read
 from.
+
+``--trace 2``: once the counted requests have finished (or hit the drain
+limit) and the latencies are computed, the schedule is offered again
+from its start, shifted to now, up to the end of the slice that
+``--trace 1`` traces — [0.4 S, 0.4 S + 3 s] — and the program's profile
+session runs over that slice.  The same arrivals since the same empty
+start put the engine where the measured window had it: the same slots
+busy, the same requests queued.  Nothing in the replay is counted.
 """
 
 from __future__ import annotations
 
 import time
 
-from benchmarks import serving, stats, traffic
+from benchmarks import common, serving, stats, traffic
 from benchmarks.common import Ctx, Result, say
 
 
@@ -41,6 +49,39 @@ def drive(sv: serving.Serving, requests, t_start: float, seconds: float,
     sv.wait_idle([s for s in served if s.counted],
                  t_start + seconds + drain_s)
     return served, late, depth
+
+
+def traced_replay(ctx: Ctx, sv: serving.Serving, requests, served):
+    """Offer the schedule again up to the traced slice's end and profile
+    the slice → the session.  The prompts keep their lengths and get new
+    tokens: one offered twice would be found in the prefix cache.  The
+    load generator and the session's start and stop share this thread:
+    nothing here is counted, so a submission held up by the profiler's
+    start costs nothing."""
+    from megatron_llm_tpu.obs import profile
+
+    begin = 0.4 * ctx.seconds
+    length = min(serving.TraceSlice.SECONDS, begin)
+    again = [r for r in requests if r.due_s <= begin + length]
+    rng, vocab = traffic.host_seed(ctx.seed, 5), sv.model.vocab_size
+    t0 = time.perf_counter() + float(sv.mix["lead_s"]) + 0.1
+    session = None
+    for r in again + [None]:
+        at = r.due_s if r is not None else begin + length
+        if session is None and at >= begin:
+            time.sleep(max(0.0, t0 + begin - time.perf_counter()))
+            session = profile.start(ctx.trace_dir)
+        time.sleep(max(0.0, t0 + at - time.perf_counter()))
+        if r is not None:
+            s = serving.Served(t0 + at, len(r.prompt), r.max_new_tokens,
+                               False)
+            sv.submit(s, rng.integers(1, vocab - 1,
+                                      size=len(r.prompt)).tolist())
+            served.append(s)
+    say(f"traced replay: the schedule's {len(again)} requests up to "
+        f"{begin + length:.1f} s offered again with new tokens, its last "
+        f"{length:.1f} s under the profile session")
+    return common.stop_profiler()
 
 
 def sweep(ctx: Ctx, sv: serving.Serving) -> None:
@@ -83,8 +124,8 @@ def run(ctx: Ctx):
         lead = float(mix["lead_s"])
         c0 = ctx.clock.backend_compiles
         t_start = time.perf_counter() + lead + 0.1
-        sl = serving.TraceSlice(ctx, t_start, ctx.seconds) if ctx.trace \
-            else None
+        sl = serving.TraceSlice(ctx, t_start, ctx.seconds) \
+            if ctx.trace == 1 else None
         served, late, depth = drive(sv, requests, t_start, ctx.seconds,
                                     float(mix["drain_s"]))
         compiles = ctx.clock.backend_compiles - c0
@@ -94,9 +135,16 @@ def run(ctx: Ctx):
         failed = serving.bad_finishes(counted)
         evidence = serving.layer_evidence(
             sv, sl, (t_start, t_start + ctx.seconds))
+        rep = serving.latency_report(counted)
+        if ctx.trace == 2:
+            untouched = common.before_traced_phase()
+            evidence = serving.window_evidence(
+                sv, serving.recorder_spans(sv.engine, t_start,
+                                           t_start + ctx.seconds))
+            evidence.update(serving.traced_phase_evidence(
+                sv, traced_replay(ctx, sv, requests, served)))
     finally:
         sv.close(served)
-    rep = serving.latency_report(counted)
     say(f"window: {len(counted)} requests due in {ctx.seconds:.0f} s at "
         f"{mix['rate_rps']} a second (of {len(served)} sent); ttft p50 "
         f"{rep['ttft_p50_ms']:.1f} ms p95 {rep['ttft_p95_ms']:.1f} ms over "
@@ -110,6 +158,8 @@ def run(ctx: Ctx):
         f"compilations inside the window: {compiles}",
         f"requests that did not end 'length' with every token inside the "
         f"drain limit: {failed} of {len(counted)}"]
+    if ctx.trace == 2:
+        notes.append(untouched)
     return Result(
         correct=sv.correct and compiles == 0 and failed == 0,
         attempted=len(counted), failed=failed,

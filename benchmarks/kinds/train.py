@@ -7,6 +7,14 @@ stream hook, which runs on the training thread: the window opens when
 the last warm-up step has logged, and once ``--seconds`` have passed the
 next logged step sends this process the SIGTERM that the trainer's
 ``DistSignalHandler`` turns into a clean exit.
+
+``--trace 2``: at the window's close the watch sends no SIGTERM; it asks
+the running job for a trace of its next steps (``obs/profile.py``:
+``request_steps``, what SIGUSR1 does for an operator), and the first
+step logged after that session has closed sends the SIGTERM.  The
+train loop's spans (``obs/trace.py:TRAIN_TRACE``, fed by the loop's
+timers) are on in every mode, so that ``--trace 0`` and ``--trace 2``
+run the same program up to the window's close.
 """
 
 from __future__ import annotations
@@ -19,7 +27,7 @@ import os
 import signal
 import time
 
-from benchmarks import traffic
+from benchmarks import common, trace_reduce, traffic
 from benchmarks.common import Ctx, Result, build_model, say, scaled
 
 # |program loss - reference loss| on the first batch, both from the same
@@ -35,18 +43,24 @@ class _Watch:
     """The event log's stream: called on the training thread at every
     event, keeps the ``log_window`` ones and ends the run."""
 
-    def __init__(self, warmup_steps: int, seconds: float, clock):
+    def __init__(self, warmup_steps: int, seconds: float, clock,
+                 trace_after=None):
         self.warmup, self.seconds, self.clock = warmup_steps, seconds, clock
-        self.events = []
-        self.t_open = None
+        self.trace_after = trace_after     # --trace 2: (steps, directory)
+        self.events, self.stamps = [], []  # stamps: perf_counter
+        self.t_open = self.t_close = None
+        self.asked_to_stop = False
+        self.n_close = None                # events when the window closed
         self.compiles_at_open = None
         self.compiles_at_close = None
+        self.untouched = None
 
     def write(self, line: str) -> None:
         if '"log_window"' not in line:
             return
         now = time.perf_counter()
         self.events.append(json.loads(line))
+        self.stamps.append(now)
         n = len(self.events)
         if n == self.warmup:
             self.t_open = now
@@ -54,7 +68,32 @@ class _Watch:
         elif (self.t_open is not None and self.compiles_at_close is None
               and now - self.t_open >= self.seconds):
             self.compiles_at_close = self.clock.backend_compiles
-            os.kill(os.getpid(), signal.SIGTERM)
+            self.t_close, self.n_close = now, n
+            if self.trace_after is None:
+                os.kill(os.getpid(), signal.SIGTERM)
+            else:
+                self._ask_for_trace()
+        elif self.n_close is not None and not self.asked_to_stop:
+            from megatron_llm_tpu.obs import profile
+
+            # the traced steps are done once the session the request
+            # opened has closed
+            last = profile.last()
+            if profile.active() is None and last is not None \
+                    and last.dir == self.trace_after[1]:
+                self.asked_to_stop = True
+                os.kill(os.getpid(), signal.SIGTERM)
+
+    def _ask_for_trace(self) -> None:
+        """The window's numbers exist; on this, the training thread,
+        between two steps: ask the loop for a trace of its next steps
+        (one more than ``trace_steps``: the window runs from the first
+        step's start to the last one's)."""
+        from megatron_llm_tpu.obs import profile
+
+        steps, trace_dir = self.trace_after
+        self.untouched = common.before_traced_phase()
+        profile.request_steps(steps + 1, trace_dir)
 
     def flush(self) -> None:
         pass
@@ -68,7 +107,9 @@ def run(ctx: Ctx) -> Result:
     from megatron_llm_tpu.data.samplers import BatchIterator
     from megatron_llm_tpu.models import model as model_lib
     from megatron_llm_tpu.models import sharding as shard_lib
+    from megatron_llm_tpu.obs import profile
     from megatron_llm_tpu.obs.logging import EVENT_LOG
+    from megatron_llm_tpu.obs.trace import TRAIN_TRACE
     from megatron_llm_tpu.parallel import mesh as mesh_lib
     from megatron_llm_tpu.training.driver import pretrain
 
@@ -90,7 +131,7 @@ def run(ctx: Ctx) -> Result:
             train_iters=10 ** 6, micro_batch_size=mb, global_batch_size=gb,
             seq_length=seq, seed=traffic.device_seed(ctx.seed),
             log_interval=1,
-            profile_dir=ctx.trace_dir if ctx.trace else None,
+            profile_dir=ctx.trace_dir if ctx.trace == 1 else None,
             profile_step_start=first_traced,
             profile_step_end=first_traced + trace_steps)).validate()
     say(f"train: hidden {model.hidden_size}, {model.num_attention_heads} "
@@ -134,9 +175,13 @@ def run(ctx: Ctx) -> Result:
     say(f"reference loss on the first batch: {ref_loss:.6f} "
         f"({time.perf_counter() - t:.1f} s)")
 
-    watch = _Watch(warmup, ctx.seconds, ctx.clock)
+    watch = _Watch(warmup, ctx.seconds, ctx.clock,
+                   trace_after=((trace_steps, ctx.trace_dir)
+                                if ctx.trace == 2 else None))
     EVENT_LOG.clear()
     EVENT_LOG.configure(stream=watch)
+    TRAIN_TRACE.clear()
+    TRAIN_TRACE.enabled = True     # this run reads the loop's spans
     # a configuration whose optimizer state the program cannot make on one
     # chip asks for it to be made in host memory (its file says why)
     where = (jax.default_device(jax.devices("cpu")[0])
@@ -151,10 +196,12 @@ def run(ctx: Ctx) -> Result:
             raise
     finally:
         EVENT_LOG.configure(stream=None)
+        TRAIN_TRACE.enabled = False
     del params
 
     ev = watch.events
-    window = ev[warmup:]
+    window = ev[warmup:watch.n_close]
+    spans = common.recorder_spans(TRAIN_TRACE, watch.t_open, watch.t_close)
     losses = [e["lm_loss"] for e in ev]
     say("loss per step: " + " ".join(f"{x:.4f}" for x in losses[:12])
         + (" ..." if len(losses) > 12 else ""))
@@ -163,6 +210,11 @@ def run(ctx: Ctx) -> Result:
     rate = tokens / sum(step_s)
     say(f"window: {len(window)} steps, {sum(step_s):.3f} s of step time, "
         f"median step {1e3 * float(np.median(step_s)):.2f} ms")
+    by_name = {}
+    for name, _t0, dur, _a in spans:
+        by_name.setdefault(name, []).append(dur)
+    say("the loop's spans over the window, median ms: " + ", ".join(
+        f"{n} {1e3 * float(np.median(d)):.3f}" for n, d in by_name.items()))
 
     bad_steps = int(ev[-1]["skipped"]) + int(ev[-1]["anomalies"]) + sum(
         not math.isfinite(e["lm_loss"]) for e in window)
@@ -176,11 +228,28 @@ def run(ctx: Ctx) -> Result:
     correct = (loss_gap <= LOSS_TOL and compiles == 0 and bad_steps == 0
                and all(math.isfinite(x) for x in losses))
     sizes = flops.sizes_of(model)
+    traced = {}
+    if ctx.trace == 2:
+        notes.append(watch.untouched)
+        session = profile.last()
+        traced["trace"] = trace_reduce.load(
+            trace_reduce.find_xplane(ctx.trace_dir))
+        _off, traced["host_spans"] = common.on_trace_clock(
+            traced["trace"], session, common.recorder_spans(
+                TRAIN_TRACE, session.t_sync - 60.0, session.t_stop))
+        steps = [e for e, t in zip(ev, watch.stamps)
+                 if session.t_sync <= t <= session.t_stop]
+        if steps:
+            say("traced steps' step_time_s: " + " ".join(
+                f"{1e3 * e['step_time_s']:.2f}" for e in steps)
+                + f" ms (the window's median "
+                f"{1e3 * float(np.median(step_s)):.2f} ms)")
     return Result(
         correct=correct, attempted=len(window), failed=bad_steps,
         end_to_end={"train_tokens_per_s": rate,
                     "setup_s": watch.t_open - ctx.t0},
-        evidence={"log_window": window,
+        evidence={**traced, "log_window": window,
+                  "recorder_spans": [(n, t0, d) for n, t0, d, _a in spans],
                   "tokens_per_step": gb * seq,
                   "train_flops_per_token": flops.train_flops_per_token(
                       sizes, seq),

@@ -1,5 +1,8 @@
 """The traced run's line: the cell's per-layer metrics, each read by the
-reader its file names, the device's busy time, and the breakdown."""
+reader its file names, the device's busy time, and the breakdown.
+``--trace 2``: the device-trace readers find the traced phase's profile
+in the evidence, the program-span and gauge readers the measured
+window's spans and samples; the runner put each there."""
 
 from __future__ import annotations
 

@@ -2,13 +2,17 @@
 """The benchmark's one command.
 
     python3 benchmarks/run.py --workload <name> --seed <n> \\
-        --seconds <s> --trace <0|1>
+        --seconds <s> --trace <0|1|2>
 
 runs one cell of BENCHMARK.json on the machine it is started on, in this
 one process, and prints as its last line of standard output one JSON
 object: ``correct``, ``attempted``, ``failed``, ``metrics``, ``device``
 and, traced, ``breakdown``.  ``--trace 0`` reports the cell's end-to-end
-metrics, ``--trace 1`` its per-layer metrics.  Without a TPU, or with
+metrics, ``--trace 1`` its per-layer metrics from a traced run of its
+own.  ``--trace 2`` is a ``--trace 0`` run up to the moment the measured
+window closes and its numbers are taken; only then does it start the
+program's profile session (``obs/profile.py``) over a few seconds of the
+same traffic, and its one line holds both kinds of metric.  Without a TPU, or with
 fewer chips than the cell asks for, it exits non-zero and prints no
 result; ``--cpu-rehearsal`` runs the same code at tiny widths on the CPU
 backend and can by construction never report ``"platform": "tpu"``.
@@ -42,7 +46,7 @@ def parse(argv):
     ap.add_argument("--seconds", type=float, default=None,
                     help="length of the measured window (default: the "
                          "manifest's run_seconds)")
-    ap.add_argument("--trace", type=int, choices=[0, 1], default=0)
+    ap.add_argument("--trace", type=int, choices=[0, 1, 2], default=0)
     ap.add_argument("--cpu-rehearsal", action="store_true")
     ap.add_argument("--sweep", action="store_true")
     return ap.parse_args(argv)
@@ -92,7 +96,7 @@ def main(argv=None) -> int:
               seed=args.seed,
               seconds=float(args.seconds if args.seconds is not None
                             else man.doc["run_seconds"]),
-              trace=bool(args.trace), rehearsal=args.cpu_rehearsal, t0=_T0,
+              trace=args.trace, rehearsal=args.cpu_rehearsal, t0=_T0,
               device=dev, clock=device_lib.CompileClock(),
               trace_dir=trace_dir, sweep=args.sweep)
     runner = importlib.import_module(f"benchmarks.kinds.{mix['kind']}")
@@ -112,15 +116,15 @@ def main(argv=None) -> int:
     line = {"correct": bool(result.correct),
             "attempted": int(result.attempted),
             "failed": int(result.failed), "metrics": {}, "device": dev}
-    if ctx.trace:
-        from benchmarks import layer_report
-
-        layer_report.fill(ctx, result, line)
-    else:
+    if ctx.trace != 1:
         for m in man.metrics_of(cell["name"], "end_to_end"):
             line["metrics"][m["name"]] = {
                 "value": float(result.end_to_end[m["name"]]),
                 "unit": m["unit"]}
+    if ctx.trace:
+        from benchmarks import layer_report
+
+        layer_report.fill(ctx, result, line)
     shutil.rmtree(trace_dir, ignore_errors=True)
     print(json.dumps(line), flush=True)
     return 0

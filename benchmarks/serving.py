@@ -17,7 +17,7 @@ import threading
 import time
 from typing import Dict, List, Optional
 
-from benchmarks import flops, stats, traffic, trace_reduce
+from benchmarks import common, flops, stats, traffic, trace_reduce
 from benchmarks.common import Ctx, build_model, say, scaled
 
 # max |engine log-prob - reference log-prob| over every position (prompt
@@ -206,8 +206,10 @@ class Serving:
 # -- traced slice ------------------------------------------------------------
 
 class TraceSlice:
-    """A few seconds of profiler trace in the middle of the window, taken
-    from a helper thread so that the load generator is not held up."""
+    """``--trace 1``: a few seconds of profiler trace in the middle of the
+    window, taken from a helper thread so that the load generator is not
+    held up.  (``--trace 2`` traces after the window, through the
+    program's own session: the kinds' traced phases.)"""
 
     SECONDS = 3.0
 
@@ -243,39 +245,33 @@ class TraceSlice:
 def recorder_spans(engine, lo: float, hi: float):
     """The engine's TraceRecorder spans that began in [lo, hi) on the
     perf_counter clock, as ``(name, start, seconds, args)``."""
-    engine.trace.add("bench_epoch", lo, lo)
-    events = engine.trace.chrome_trace()["traceEvents"]
-    epoch = next(lo - e["ts"] / 1e6 for e in reversed(events)
-                 if e["name"] == "bench_epoch")
-    out = []
-    for e in events:
-        if e.get("ph") != "X" or e["name"] == "bench_epoch":
-            continue
-        t0 = epoch + e["ts"] / 1e6
-        if lo <= t0 < hi:
-            out.append((e["name"], t0, e["dur"] / 1e6, e.get("args", {})))
-    return out
+    return common.recorder_spans(engine.trace, lo, hi)
 
 
-def layer_evidence(sv: Serving, sl: Optional[TraceSlice], window) -> dict:
-    """What the per-layer readers read, for a traced run."""
-    if sl is None:
-        return {}
-    spans = recorder_spans(sv.engine, *window)
+def window_evidence(sv: Serving, spans) -> dict:
+    """What the program-span and gauge readers read: ``spans`` (the
+    engine's, of the measured window) and the gauge samples so far."""
     sizes = flops.sizes_of(sv.model)
-    used = sv.gauges["blocks_used"]
+    used = list(sv.gauges["blocks_used"])
     live = (sum(used) / len(used) if used else 0.0) * sv.block_size
-    ev = {"recorder_spans": [(n, t0, d) for n, t0, d, _a in spans],
-          "gauges": sv.gauges, "pool_blocks": sv.pool_blocks,
-          # live tokens taken as mean blocks in use x block size, which
-          # rounds up to whole blocks and counts cached prefixes: under
-          # half a percent of the weights' bytes for this model
-          "decode_step_bytes": flops.decode_step_bytes(sizes, live)}
+    return {"recorder_spans": [(n, t0, d) for n, t0, d, _a in spans],
+            "gauges": {"blocks_used": used}, "pool_blocks": sv.pool_blocks,
+            # live tokens taken as mean blocks in use x block size, which
+            # rounds up to whole blocks and counts cached prefixes: under
+            # half a percent of the weights' bytes for this model
+            "decode_step_bytes": flops.decode_step_bytes(sizes, live)}
+
+
+def trace_evidence(sv: Serving, spans, lo: float, hi: float,
+                   off_of) -> dict:
+    """What the device-trace readers read: the reduced profile, its
+    window [lo, hi) (perf_counter) on the trace's clock, the prompt
+    tokens prefilled in it and the engine's spans that overlap it.
+    ``off_of(trace)`` gives perf_counter → trace clock in ns, or None."""
     trace = trace_reduce.load(trace_reduce.find_xplane(sv.ctx.trace_dir))
-    sync = [e for e in trace.host if e.name == "bench_sync"]
-    if sync and trace.ops:
-        off = sync[0].start - sl.t_sync * 1e9       # perf_counter -> trace
-        lo, hi = sl.t_sync, sl.t_stop
+    ev = {"trace": trace}
+    off = off_of(trace)
+    if off is not None and trace.ops:
         ev["trace_window"] = (lo * 1e9 + off, hi * 1e9 + off)
         ev["traced_prefill_tokens"] = sum(
             a.get("prompt_len", 0) - a.get("cached_tokens", 0)
@@ -283,8 +279,34 @@ def layer_evidence(sv: Serving, sl: Optional[TraceSlice], window) -> dict:
         ev["host_spans"] = [
             trace_reduce.Event(n, t0 * 1e9 + off, (t0 + d) * 1e9 + off)
             for n, t0, d, _a in spans if lo - d <= t0 < hi]
-    ev["trace"] = trace
     return ev
+
+
+def layer_evidence(sv: Serving, sl: Optional[TraceSlice], window) -> dict:
+    """What the per-layer readers read, for a ``--trace 1`` run: the
+    slice lies inside the window, and its own ``bench_sync`` annotation
+    joins the clocks."""
+    if sl is None:
+        return {}
+    spans = recorder_spans(sv.engine, *window)
+
+    def off_of(trace):
+        sync = [e for e in trace.host if e.name == "bench_sync"]
+        return sync[0].start - sl.t_sync * 1e9 if sync else None
+
+    return {**window_evidence(sv, spans),
+            **trace_evidence(sv, spans, sl.t_sync, sl.t_stop, off_of)}
+
+
+def traced_phase_evidence(sv: Serving, session, window=None) -> dict:
+    """``--trace 2``: the device-trace readers' part, from the program's
+    profile session that ran after the window, over ``window``
+    (perf_counter) or else the whole session."""
+    spans = recorder_spans(sv.engine, session.t_sync - 60.0, session.t_stop)
+    lo, hi = window or (session.t_sync, session.t_stop)
+    return trace_evidence(
+        sv, spans, lo, hi,
+        lambda trace: common.on_trace_clock(trace, session, ())[0])
 
 
 def latency_report(served: List[Served]) -> dict:

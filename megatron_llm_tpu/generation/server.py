@@ -35,13 +35,16 @@ from __future__ import annotations
 
 import json
 import math
+import os
 import threading
+import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
 from urllib.parse import parse_qs, urlparse
 
 from ..analysis.sanitizers import make_lock
 from ..config import ModelConfig
+from ..obs import profile as obs_profile
 from ..obs.logging import EVENT_LOG
 from ..obs.registry import REGISTRY
 from ..tokenizer.tokenizer import Tokenizer
@@ -51,6 +54,10 @@ from .api import (
     pld_eligible,
     score_and_post_process,
 )
+
+# the longest profile POST /profile takes: traces grow with time, and the
+# handler's thread holds the process's one session that long
+MAX_PROFILE_SECONDS = 60.0
 
 
 class GenerationService:
@@ -78,6 +85,7 @@ class GenerationService:
                  draft_cfg: ModelConfig | None = None,
                  draft_params=None,
                  trace: bool = True,
+                 profile_dir: str | None = None,
                  tensor_parallel: int = 1,
                  pipeline_parallel: int = 1,
                  replicas: int = 1,
@@ -145,6 +153,9 @@ class GenerationService:
         # per-request span tracing (obs/trace.py, GET /trace); the CLI's
         # --no_trace escape hatch lands here
         self.trace_enabled = trace
+        # where POST /profile writes device profiles (obs/profile.py);
+        # None refuses the route
+        self.profile_dir = profile_dir
         # multi-chip serving (serving/cluster/, docs/serving.md): shard
         # each engine over a pp·tp submesh and/or replicate engines on
         # disjoint device slices behind the health-aware router.  The
@@ -303,6 +314,35 @@ class GenerationService:
             return {"traceEvents": [], "displayTimeUnit": "ms",
                     "otherData": {"dropped_events": 0}}
         return engine.trace.chrome_trace()
+
+    def profile(self, body: dict) -> tuple:
+        """``POST /profile {"seconds": n}``: one device profile of the
+        next ``n`` seconds of whatever this process serves, written under
+        the configured profile directory → ``(status, payload)``.  The
+        handler's own thread waits out the seconds; the engine is not
+        told.  The reply carries the session's clock-sync pair, which
+        joins ``GET /trace`` spans to the profile (docs/observability.md)."""
+        if not self.profile_dir:
+            return 403, "no profile directory is configured"
+        try:
+            seconds = float(body.get("seconds", 3.0))
+        except (TypeError, ValueError, AttributeError):
+            return 400, "seconds must be a number"
+        if not 0.0 < seconds <= MAX_PROFILE_SECONDS:
+            return 400, (f"seconds must be above 0 and at most "
+                         f"{MAX_PROFILE_SECONDS}")
+        out = os.path.join(self.profile_dir,
+                           time.strftime("%Y%m%d-%H%M%S"))
+        try:
+            obs_profile.start(out)
+        except RuntimeError as e:          # a session is already active
+            return 409, str(e)
+        try:
+            time.sleep(seconds)
+        finally:
+            session = obs_profile.stop()
+        return 200, {"dir": out, "seconds": seconds,
+                     "clock_sync": session.clock_sync()}
 
     def kv_snapshot(self) -> dict:
         """Debug view of the paged KV pool (GET /kv,
@@ -622,7 +662,17 @@ class _Handler(BaseHTTPRequestHandler):
         status, payload = self.service.handle(body)
         self._respond(status, payload)
 
-    do_POST = do_PUT  # convenience; the reference accepts PUT only
+    def do_POST(self):
+        if self.path.rstrip("/") != "/profile":
+            self.do_PUT()   # convenience; the reference accepts PUT only
+            return
+        try:
+            n = int(self.headers.get("Content-Length", 0))
+            body = json.loads(self.rfile.read(n) or b"{}")
+        except (ValueError, json.JSONDecodeError):
+            self._respond(400, "invalid JSON body")
+            return
+        self._respond(*self.service.profile(body))
 
     def do_GET(self):
         url = urlparse(self.path)

@@ -1495,6 +1495,7 @@ def fused_decode_step(
         functools.partial(_decode_step_kernel, per_row, aq, mq, gsz, cq8,
                           lsr, lt, nk, nm, block_k,
                           b, nq, nkv, g, d, eps, scale, act),
+        name="decode_step_fused",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(L, nk + nm),
@@ -1845,6 +1846,7 @@ def _fused_paged_call(cfg, stacked, x, k_pool, v_pool, tables, pos,
                           lsr, lt, W,
                           tree, ntb, nm, block_k,
                           b, nq, nkv, g, d, eps, scale, act),
+        name="decode_step_fused",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),
             grid=(L, nk + nm),

@@ -183,6 +183,7 @@ def _fwd(cfg: _Config, q, k, v, q_seg, k_seg):
     ]
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, cfg, nk),
+        name="flash_fwd",
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
@@ -348,6 +349,7 @@ def _bwd_impl(cfg: _Config, q, k, v, o, lse, do, q_seg, k_seg):
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, cfg, nk),
+        name="flash_bwd_dq",
         grid=(b, hq, nq, nk),
         in_specs=base_specs + (seg_specs if cfg.use_segs else []),
         out_specs=pl.BlockSpec((1, 1, cfg.block_q, d), qmap),
@@ -388,6 +390,7 @@ def _bwd_impl(cfg: _Config, q, k, v, o, lse, do, q_seg, k_seg):
         ]
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, cfg, nq),
+        name="flash_bwd_dkv",
         grid=(b, hk, nk, cfg.group * nq),
         in_specs=dkv_specs,
         out_specs=[

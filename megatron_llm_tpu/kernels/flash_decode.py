@@ -165,6 +165,7 @@ def _decode_call(kernel_fn, q, caches, cache_len, softmax_scale,
     grid = (b, kv_heads, nk)
     out = pl.pallas_call(
         functools.partial(kernel_fn, float(softmax_scale), nk, block_k),
+        name="flash_decode",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
@@ -262,6 +263,7 @@ def _paged_decode_call(kernel_fn, q, caches, tables, cache_len,
     out = pl.pallas_call(
         functools.partial(_paged_body(kernel_fn), float(softmax_scale),
                           nk, block_k),
+        name="flash_decode",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=grid,

@@ -124,6 +124,7 @@ def _row_call(kernel, n_out, rows_p, hidden, br, dtypes, operands, interpret):
         out_shape.append(jax.ShapeDtypeStruct(shape, dt))
     return pl.pallas_call(
         kernel,
+        name="rmsnorm",
         grid=(nr,),
         in_specs=specs,
         out_specs=out_specs if n_out > 1 else out_specs[0],

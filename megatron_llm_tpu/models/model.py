@@ -71,6 +71,7 @@ def init_params(key: jax.Array, cfg: ModelConfig, tp: int = 1) -> Params:
     return params
 
 
+@jax.named_scope("embed")
 def embed(cfg: ModelConfig, params: Params, tokens: jax.Array,
           position_ids: Optional[jax.Array] = None,
           tokentype_ids: Optional[jax.Array] = None,
@@ -94,10 +95,13 @@ def embed(cfg: ModelConfig, params: Params, tokens: jax.Array,
     return x
 
 
+@jax.named_scope("lm_head")
 def unembed(cfg: ModelConfig, params: Params, x: jax.Array) -> jax.Array:
-    """Project hidden states to (padded-)vocab logits
-    (reference: parallel_lm_logits, megatron/model/language_model.py:24-53)."""
-    return x @ unembed_weight(cfg, params)
+    """Project hidden states to (padded-)vocab logits, float32
+    (reference: parallel_lm_logits, megatron/model/language_model.py:24-53).
+    The cast is made here, under the scope: XLA fuses it into the matmul,
+    and a fusion is named after its root."""
+    return (x @ unembed_weight(cfg, params)).astype(jnp.float32)
 
 
 def unembed_weight(cfg: ModelConfig, params: Params) -> jax.Array:
@@ -187,7 +191,6 @@ def forward(
         segment_ids=segment_ids, tokentype_ids=tokentype_ids, rng=rng,
         deterministic=deterministic, rope=rope, lora=lora)
     logits = unembed(cfg, params, x)
-    logits = logits.astype(jnp.float32)
     if return_aux:
         return logits, moe_aux
     return logits
@@ -287,7 +290,7 @@ def forward_cached(
         x = jnp.take_along_axis(
             x, logit_rows.astype(jnp.int32)[:, None, None], axis=1)
     logits = unembed(cfg, params, x)
-    return logits.astype(jnp.float32), new_k, new_v
+    return logits, new_k, new_v
 
 
 def forward_cached_paged(
@@ -350,7 +353,7 @@ def forward_cached_paged(
                        params["final_norm"], cfg.norm_eps,
                        impl=cfg.norm_impl)
         logits = unembed(cfg, params, x)
-        return logits.astype(jnp.float32), k_pool, v_pool
+        return logits, k_pool, v_pool
     k_dense = cache_gather_blocks(k_pool, tables)
     v_dense = cache_gather_blocks(v_pool, tables)
     logits, k_dense, v_dense = forward_cached(
@@ -464,7 +467,7 @@ def forward_cached_paged_verify(
         x = norm_apply(cfg.norm_type, hidden, params["final_norm"],
                        cfg.norm_eps, impl=cfg.norm_impl)
         logits = unembed(cfg, params, x)
-        return logits.astype(jnp.float32), k_pool, v_pool
+        return logits, k_pool, v_pool
     k_dense = cache_gather_blocks(k_pool, tables)
     v_dense = cache_gather_blocks(v_pool, tables)
     if tree is None:
@@ -584,6 +587,7 @@ def init_kv_pool(cfg: ModelConfig, n_blocks: int, block_size: int,
     return init_kv_cache(cfg, n_blocks, block_size, dtype)
 
 
+@jax.named_scope("kv_cache")
 def cache_gather_blocks(pool, tables):
     """Gather per-slot block tables into a dense working cache.
 
@@ -609,6 +613,7 @@ def cache_gather_blocks(pool, tables):
     return jax.tree.map(g, pool)
 
 
+@jax.named_scope("kv_cache")
 def cache_scatter_blocks(pool, dense, bids):
     """Publish a batch-1 dense cache's blocks into pool blocks ``bids``.
 
@@ -633,6 +638,7 @@ def cache_scatter_blocks(pool, dense, bids):
     return jax.tree.map(sc, pool, dense)
 
 
+@jax.named_scope("kv_cache")
 def cache_append_rows(pool, rows, bids, offs):
     """Scatter one new K/V row per slot into the pool.
 
@@ -653,6 +659,7 @@ def cache_append_rows(pool, rows, bids, offs):
     return jax.tree.map(ap, pool, rows)
 
 
+@jax.named_scope("kv_cache")
 def cache_move_rows(pool, src_bids, src_offs, dst_bids, dst_offs):
     """Copy pool rows ``(src_bids[i], src_offs[i])`` to
     ``(dst_bids[i], dst_offs[i])`` in one functional gather-then-scatter
@@ -675,6 +682,7 @@ def cache_move_rows(pool, src_bids, src_offs, dst_bids, dst_offs):
     return jax.tree.map(mv, pool)
 
 
+@jax.named_scope("kv_cache")
 def cache_rows_at(dense, fills):
     """Extract each slot's row at its own fill level from a dense cache
     ([L, S, kv, W(, d)] leaves → [L, S, kv, 1(, d)]) — the rows the

@@ -236,6 +236,7 @@ def _lora_add(y: jax.Array, x: jax.Array, lora, target: str) -> jax.Array:
     return (y + lora_delta(x, f["a"], f["b"], mask)).astype(y.dtype)
 
 
+@jax.named_scope("attention")
 def attention_block(cfg: ModelConfig, p: Params, x: jax.Array,
                     side: AttnSideInputs, layer_rng,
                     kv_cache: Optional[tuple] = None, lora=None):
@@ -377,6 +378,7 @@ def mlp_block(cfg: ModelConfig, p: Params, x: jax.Array,
     return out
 
 
+@jax.named_scope("mlp")
 def _mlp_dispatch(cfg: ModelConfig, p: Params, x: jax.Array, lora=None):
     """Dense or routed MLP → ``(out, aux)``.
 

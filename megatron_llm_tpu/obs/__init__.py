@@ -10,8 +10,10 @@ emit, replacing the three disconnected registries that grew organically
   (``GET /metrics?format=prometheus`` on the serving HTTP server).
 - ``trace``: a low-overhead ring buffer of per-request and per-iteration
   spans, exported as Chrome trace-event JSON (``GET /trace``,
-  ``tools/dump_trace.py``) and mirrored into
-  ``jax.profiler.TraceAnnotation`` so device profiles line up.
+  ``tools/dump_trace.py``), joined to device profiles by ``profile``'s
+  clock-sync pair.
+- ``profile``: the process's one ``jax.profiler`` session, started and
+  stopped while it runs (trainer step windows, ``POST /profile``).
 - ``logging``: rank-aware structured JSON event log carrying
   ``request_id`` correlation ids end-to-end.
 - ``slo``: rolling-window TTFT / ITL / availability objectives with
@@ -24,7 +26,8 @@ from .logging import EVENT_LOG, StructuredLog
 from .registry import (REGISTRY, Counter, Gauge, Histogram, MetricFamily,
                        MetricsRegistry, Sample)
 from .slo import SLOConfig, SLOTracker
-from .trace import TraceRecorder, device_annotation
+from . import profile
+from .trace import TRAIN_TRACE, TraceRecorder, device_annotation
 
 __all__ = [
     "Counter",
@@ -38,6 +41,8 @@ __all__ = [
     "SLOConfig",
     "SLOTracker",
     "StructuredLog",
+    "TRAIN_TRACE",
     "TraceRecorder",
     "device_annotation",
+    "profile",
 ]

@@ -12,9 +12,19 @@ Export is Chrome trace-event JSON (``chrome://tracing`` / Perfetto's
 legacy loader): complete events (``ph="X"``) with microsecond timestamps
 relative to the recorder's creation, ``tid`` = request id so each
 request gets its own track, and ``args.request_id`` for correlation
-with the structured event log.  ``device_annotation`` mirrors the same
-phase names into ``jax.profiler.TraceAnnotation`` so spans line up with
-device profiles captured by the existing driver profiler window.
+with the structured event log.
+
+Joining a device profile: ``otherData`` carries ``epoch_perf_counter``
+(``ts`` 0 on the ``perf_counter`` clock) and, once a profile session
+(``obs/profile.py``) has run, its ``clock_sync`` pair, so every span —
+retrospective ``add()`` ones included — maps onto the profile's clock
+with ``profile.to_trace_ns``.  ``span(..., annotate=True)`` and
+``device_annotation`` also write the span into the profile itself as a
+``jax.profiler.TraceAnnotation``; ``add()`` spans are not mirrored.
+
+``TRAIN_TRACE`` is the train loop's recorder (``utils/timers.py`` feeds
+it from the loop's timers).  It is off unless a profile session or a
+reader switches it on.
 
 Overhead discipline: when ``enabled`` is False every record path returns
 before taking the lock or allocating, and the recorder stores compact
@@ -28,8 +38,10 @@ import os
 import time
 from collections import deque
 
-from ..analysis.sanitizers import make_lock
 from typing import Dict, Iterator, List, Optional
+
+from ..analysis.sanitizers import make_lock
+from . import profile
 
 _PROFILER_SENTINEL = object()
 _profiler = _PROFILER_SENTINEL  # lazily resolved jax.profiler module (or None)
@@ -152,5 +164,15 @@ class TraceRecorder:
             if ev_args:
                 ev["args"] = ev_args
             out.append(ev)
+        other = {"dropped_events": dropped,
+                 "epoch_perf_counter": self._epoch}
+        session = profile.last()
+        if session is not None:
+            other["clock_sync"] = session.clock_sync()
         return {"traceEvents": out, "displayTimeUnit": "ms",
-                "otherData": {"dropped_events": dropped}}
+                "otherData": other}
+
+
+# the train loop's spans (driver.pretrain's timers); see the module docstring
+TRAIN_TRACE = TraceRecorder(enabled=False)
+profile.while_profiling(TRAIN_TRACE)

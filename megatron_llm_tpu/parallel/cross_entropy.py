@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 
+@jax.named_scope("cross_entropy")
 def cross_entropy(
     logits: jax.Array,  # [..., vocab] (may be padded)
     targets: jax.Array,  # [...] int
@@ -133,6 +134,7 @@ def vocab_parallel_max_indices(logits: jax.Array) -> jax.Array:
     return jnp.argmax(logits, axis=-1)
 
 
+@jax.named_scope("cross_entropy")
 def masked_mean_loss(per_token_loss: jax.Array, loss_mask: jax.Array):
     """Loss-mask weighted mean (reference: finetune.py:196-213)."""
     loss_mask = loss_mask.astype(per_token_loss.dtype)

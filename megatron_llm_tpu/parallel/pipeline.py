@@ -451,7 +451,7 @@ def pipeline_loss(
             hp = cast(io_p)
             h = norm_apply(model_cfg.norm_type, h, hp["final_norm"],
                            model_cfg.norm_eps, impl=model_cfg.norm_impl)
-            logits = model_lib.unembed(model_cfg, hp, h).astype(jnp.float32)
+            logits = model_lib.unembed(model_cfg, hp, h)
             per_token = cross_entropy(logits, lab,
                                       vocab_size=model_cfg.vocab_size)
             msk = msk.astype(jnp.float32)

@@ -1058,6 +1058,12 @@ class ServingEngine:
         # device/host overlap accounting (metrics.observe_step_breakdown)
         self._last_dispatch_t: Optional[float] = None
         self._last_ready_t: Optional[float] = None
+        # scheduler iteration, carried by the admit / engine_step / prefill
+        # / decode spans so a token's gap can be put down to the iteration
+        # (and the admission) that caused it
+        self._iter = 0
+        self._admit_count = 0        # this iteration's admissions
+        self._admit_tokens = 0       # and their prompt tokens
         # whether forward_cached routes this config's slot batch through
         # the fused decode kernel — resolved once at start() (the
         # predicate is static in cfg/params/cache shape) and used to
@@ -1412,7 +1418,8 @@ class ServingEngine:
                         if self._paused.is_set() and not self._stop.is_set():
                             self._wake.wait(self.config.idle_wait_s)
                     continue
-                self._admit()
+                self._iter += 1
+                self._admit_traced()
                 if self._active:
                     self._step()
                 elif self._inflight is not None:
@@ -1633,6 +1640,18 @@ class ServingEngine:
             self.metrics.set_gauges(queue_depth=len(self.queue))
         return req
 
+    def _admit_traced(self) -> None:
+        """``_admit`` with an ``admit`` span on the scheduler's track when
+        it admitted anything: what a neighbour's token waited for."""
+        self._admit_count = self._admit_tokens = 0
+        t0 = time.perf_counter()
+        self._admit()
+        if self._admit_count:
+            self.trace.add("admit", t0, time.perf_counter(), tid=0,
+                           args={"admitted": self._admit_count,
+                                 "prompt_tokens": self._admit_tokens,
+                                 "iter": self._iter})
+
     def _admit(self) -> None:
         assert self.slots is not None
         if self.host_tier is not None:
@@ -1778,6 +1797,8 @@ class ServingEngine:
             jnp.asarray([req.top_p], jnp.float32))
         first_tok = int(np.asarray(tok)[0])
         t.stop()
+        self._admit_count += 1
+        self._admit_tokens += len(req.prompt)
         self.metrics.inc("admitted")
         self.metrics.inc("prefills")
         EVENT_LOG.emit("engine", "admitted", request_id=req.rid,
@@ -1888,6 +1909,7 @@ class ServingEngine:
                           lease.bids if lease is not None else ())
 
         # first generated token: same per-request sampling rule as decode
+        t_ft = time.perf_counter()
         tok, tok_lp = _first_token_impl(
             self.cfg, last_logits,
             jnp.asarray([req.seed], jnp.uint32),
@@ -1901,7 +1923,10 @@ class ServingEngine:
         self.trace.add("prefill", t_pf, time.perf_counter(),
                        request_id=req.rid, tid=req.id,
                        args={"prompt_len": plen,
-                             "cached_tokens": lease.tokens if lease else 0})
+                             "cached_tokens": lease.tokens if lease else 0,
+                             "iter": self._iter})
+        self._admit_count += 1
+        self._admit_tokens += plen
         self.metrics.inc("admitted")
         self.metrics.inc("prefills")
         EVENT_LOG.emit("engine", "admitted", request_id=req.rid, slot=slot,
@@ -1918,6 +1943,10 @@ class ServingEngine:
             # decode replica re-prefills the draft on install instead
             self._draft_prefill(slot, st)
         self._commit_token(slot, first, float(np.asarray(tok_lp)[0]))
+        # the first token's own cost: sample, host fetch (which waits for
+        # the prefill), slot state, commit
+        self.trace.add("first_token", t_ft, time.perf_counter(),
+                       request_id=req.rid, tid=req.id)
         self._maybe_handoff(slot)
         return True
 
@@ -1974,7 +2003,7 @@ class ServingEngine:
         self.metrics.set_gauges(slots_active=self.slots.active_slots)
         self.trace.add(
             "engine_step", it0, time.perf_counter(), tid=0,
-            args={"batch": len(inflight.slots),
+            args={"iter": self._iter, "batch": len(inflight.slots),
                   "route": "fused" if self._fused_decode else "fallback",
                   "pipelined": self.config.pipeline_decode})
 
@@ -2329,7 +2358,8 @@ class ServingEngine:
             if self.trace.enabled:
                 self.trace.add("decode", t0, t_ready,
                                request_id=st.req.rid, tid=st.req.id,
-                               args={"slot": slot, "spec": True,
+                               args={"slot": slot, "iter": self._iter,
+                                     "spec": True,
                                      "proposed": k_i, "accepted": acc,
                                      "committed": committed_here})
         t.stop()
@@ -2343,7 +2373,7 @@ class ServingEngine:
         self.metrics.set_gauges(slots_active=self.slots.active_slots)
         self.trace.add(
             "engine_step", it0, time.perf_counter(), tid=0,
-            args={"batch": len(drafts),
+            args={"iter": self._iter, "batch": len(drafts),
                   "route": ("spec_fused" if self._fused_verify
                             else "spec_fallback"),
                   "pipelined": False, "proposed": proposed,
@@ -2581,7 +2611,8 @@ class ServingEngine:
             if self.trace.enabled:
                 self.trace.add("decode", t0, t_ready,
                                request_id=st.req.rid, tid=st.req.id,
-                               args={"slot": slot, "spec": True,
+                               args={"slot": slot, "iter": self._iter,
+                                     "spec": True,
                                      "tree": True, "proposed": k_i,
                                      "accepted": acc,
                                      "committed": committed_here})
@@ -2597,7 +2628,7 @@ class ServingEngine:
         self.metrics.set_gauges(slots_active=self.slots.active_slots)
         self.trace.add(
             "engine_step", it0, time.perf_counter(), tid=0,
-            args={"batch": len(plans),
+            args={"iter": self._iter, "batch": len(plans),
                   "route": ("spec_fused" if self._fused_verify
                             else "spec_fallback"),
                   "pipelined": False, "tree": True, "proposed": proposed,
@@ -2747,7 +2778,7 @@ class ServingEngine:
             if self.trace.enabled:
                 self.trace.add("decode", step.t_dispatch, t_ready,
                                request_id=st.req.rid, tid=st.req.id,
-                               args={"slot": slot,
+                               args={"slot": slot, "iter": self._iter,
                                      "token_index": len(st.req.generated)})
             # tpulint: allow[host-sync] tok_lp is host numpy; no device
             # round-trip

@@ -143,6 +143,9 @@ def main(argv=None) -> int:
                     help="periodically print a one-line JSON serving-"
                          "metrics summary (prefix-cache hit rate "
                          "included) to stdout; 0 disables")
+    ap.add_argument("--profile_dir", default=None,
+                    help="directory POST /profile writes device profiles "
+                         "under (obs/profile.py); unset refuses the route")
     ap.add_argument("--no_trace", action="store_true",
                     help="disable per-request span tracing (obs/trace.py, "
                          "GET /trace).  Tracing is on by default and holds "
@@ -413,6 +416,7 @@ def main(argv=None) -> int:
         draft_cfg=draft_cfg,
         draft_params=draft_params,
         trace=not args.no_trace,
+        profile_dir=args.profile_dir,
         tensor_parallel=args.tp if cluster else 1,
         pipeline_parallel=args.pp if cluster else 1,
         replicas=args.replicas,

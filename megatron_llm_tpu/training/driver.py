@@ -37,7 +37,9 @@ from ..data.samplers import BatchIterator
 from ..models import model as model_lib
 from ..models import sharding as shard_lib
 from ..models.transformer import rope_tables
+from ..obs import profile as obs_profile
 from ..obs.logging import EVENT_LOG
+from ..obs.trace import TRAIN_TRACE
 from ..obs.registry import REGISTRY as obs_registry
 from ..parallel import mesh as mesh_lib
 from ..parallel.cross_entropy import cross_entropy, masked_mean_loss
@@ -73,8 +75,8 @@ class DistSignalHandler:
     process exists.
     """
 
-    def __init__(self, sig: int = signal.SIGTERM):
-        self.sig = sig
+    def __init__(self, sig: Optional[int] = signal.SIGTERM):
+        self.sig = sig               # None (no such signal here): inert
         self._received = False
         self._prev = None
 
@@ -82,7 +84,8 @@ class DistSignalHandler:
         def handler(signum, frame):
             self._received = True
 
-        self._prev = signal.signal(self.sig, handler)
+        if self.sig is not None:
+            self._prev = signal.signal(self.sig, handler)
         return self
 
     def __exit__(self, *exc):
@@ -92,6 +95,12 @@ class DistSignalHandler:
 
     def signals_received(self) -> bool:
         return _cluster_any(self._received)
+
+    def take_local(self) -> bool:
+        """This process's own flag, cleared: for a signal that asks one
+        process for something and needs no agreement."""
+        got, self._received = self._received, False
+        return got
 
 
 def _cluster_any(local_flag: bool) -> bool:
@@ -500,6 +509,43 @@ def training_log(cfg: RuntimeConfig, log: _LogState, metrics: dict,
 # The driver (reference pretrain + _train, training.py:55-169,654-770)
 # ---------------------------------------------------------------------------
 
+# steps traced when a running job gets SIGUSR1 (into cfg.train.profile_dir)
+SIGNAL_TRACE_STEPS = 3
+
+
+class _StepTrace:
+    """The loop's side of a step-trace request: opens the profile session
+    when a request falls due, closes it after the request's last step."""
+
+    def __init__(self):
+        self.until = None            # last iteration of the open trace
+
+    def poll(self, next_it: int) -> None:
+        """Top of an iteration, on the normal and the skip path alike."""
+        if self.until is not None:
+            return
+        req = obs_profile.take_step_request(next_it)
+        if req is None:
+            return
+        try:
+            obs_profile.start(req.dir)
+        except RuntimeError as e:    # someone else's session is running
+            print_rank_0(f" profiler: request dropped ({e})")
+            return
+        self.until = next_it + req.steps - 1
+        print_rank_0(f" profiler: tracing iterations {next_it}.."
+                     f"{self.until} -> {req.dir}")
+
+    def done(self, it: int) -> None:
+        if self.until is not None and it >= self.until:
+            self.close("window complete")
+
+    def close(self, reason: str = "closed at loop exit") -> None:
+        if self.until is not None:
+            self.until = None
+            obs_profile.stop()
+            print_rank_0(f" profiler: trace written ({reason})")
+
 
 def _build_train_iterator(cfg: RuntimeConfig, dataset, consumed_samples: int,
                           global_batch_size: int, shuffle: bool,
@@ -594,7 +640,8 @@ def pretrain(
     """
     cfg.validate()
     t_start = time.time()
-    timers = Timers()
+    # the loop's timers are also its spans (obs/trace.py:TRAIN_TRACE)
+    timers = Timers(spans=TRAIN_TRACE)
     writer = NullWriter()
     if jax.process_index() == 0:
         writer = build_writer(cfg.train.tensorboard_dir,
@@ -665,37 +712,15 @@ def pretrain(
     log = _LogState()
     skip_set = set(cfg.train.skip_iters)
     exit_reason = None
-    profiling = False
-
-    def _close_profiler(reason: str = "closed at loop exit"):
-        nonlocal profiling
-        if profiling:
-            # closes on every exit path — incl. exceptions mid-window,
-            # where the partial capture is exactly what's needed
-            jax.profiler.stop_trace()
-            profiling = False
-            print_rank_0(f" profiler: trace written ({reason})")
-
-    def _maybe_start_profiler(next_it: int):
-        """Open the trace when entering the configured window.  Called on
-        BOTH the normal and the skip-iteration paths (a window overlapping
-        --skip_iters must still open/close at the right steps).  The upper
-        bound keeps resumed runs (starting past the window) from writing
-        stray traces."""
-        nonlocal profiling
-        if (cfg.train.profile_dir and not profiling
-                and cfg.train.profile_step_start <= next_it
-                <= cfg.train.profile_step_end):
-            jax.profiler.start_trace(cfg.train.profile_dir)
-            profiling = True
-            print_rank_0(
-                f" profiler: tracing iterations "
-                f"{next_it}..{cfg.train.profile_step_end} "
-                f"-> {cfg.train.profile_dir}")
-
-    def _maybe_stop_profiler(done_it: int):
-        if profiling and done_it >= cfg.train.profile_step_end:
-            _close_profiler("window complete")
+    # Traces are taken on request ("the next n steps into dir",
+    # obs/profile.py), polled at the top of every iteration; the configured
+    # window is one such request made here.  A resumed run that starts past
+    # the window drops it.
+    step_trace = _StepTrace()
+    if cfg.train.profile_dir:
+        obs_profile.request_steps(
+            cfg.train.profile_step_end - cfg.train.profile_step_start + 1,
+            cfg.train.profile_dir, first=cfg.train.profile_step_start)
 
     # Anomaly rollback needs a checkpoint to roll back TO; anchor the run
     # with an initial save when none exists yet.
@@ -709,10 +734,20 @@ def pretrain(
 
     print_rank_0(f" training starts at iteration {iteration} / "
                  f"{cfg.train.train_iters}")
-    with DistSignalHandler() as sig, art.mesh:
+    with DistSignalHandler() as sig, DistSignalHandler(
+            getattr(signal, "SIGUSR1", None)) as usr1, art.mesh:
       try:
         while iteration < cfg.train.train_iters:
-            _maybe_start_profiler(iteration + 1)
+            # SIGUSR1: trace the next few steps of this running job
+            if usr1.take_local():
+                if cfg.train.profile_dir:
+                    obs_profile.request_steps(SIGNAL_TRACE_STEPS,
+                                              cfg.train.profile_dir)
+                else:
+                    print_rank_0(" SIGUSR1 ignored: no profile_dir to "
+                                 "trace into")
+            step_trace.poll(iteration + 1)
+            timers.cause = iteration + 1
             # fault injection: --skip_iters (training.py:397-399,422-426)
             if (iteration + 1) in skip_set:
                 try:
@@ -727,7 +762,7 @@ def pretrain(
                     iteration=state.iteration + jnp.int32(1))
                 print_rank_0(f" skipping iteration {iteration} (fault "
                              "injection)")
-                _maybe_stop_profiler(iteration)
+                step_trace.done(iteration)
                 continue
 
             # batch-size ramp: rebuild the iterator (and step shapes) on rung
@@ -739,35 +774,45 @@ def pretrain(
                 train_iter = make_train_iter(consumed_samples, current_gbs)
                 print_rank_0(f" global batch size ramped to {current_gbs}")
 
-            timers("batch-generator", log_level=1).start()
-            try:
-                batch = next(train_iter)
-            except StopIteration:
-                train_iter = make_train_iter(consumed_samples, current_gbs)
-                batch = next(train_iter)
-            # chaos hook (inert unless a test armed poison_batches): NaN
-            # batches exercise the skip/rollback defenses end-to-end
-            batch = chaos().corrupt_batch(batch, iteration + 1)
-            dev_batch = _put_batch(batch, art.batch_sharding)
-            timers("batch-generator").stop()
+            with jax.profiler.StepTraceAnnotation("train",
+                                                  step_num=iteration + 1):
+                timers("batch-generator", log_level=1).start()
+                try:
+                    batch = next(train_iter)
+                except StopIteration:
+                    train_iter = make_train_iter(consumed_samples,
+                                                 current_gbs)
+                    batch = next(train_iter)
+                # chaos hook (inert unless a test armed poison_batches): NaN
+                # batches exercise the skip/rollback defenses end-to-end
+                batch = chaos().corrupt_batch(batch, iteration + 1)
+                dev_batch = _put_batch(batch, art.batch_sharding)
+                timers("batch-generator").stop()
 
-            timers("train-step", log_level=0).start()
-            state, step_metrics = art.step_fn(state, dev_batch, base_rng)
-            step_metrics = jax.device_get(step_metrics)
-            timers("train-step").stop(wait_for=step_metrics)
+                timers("train-step", log_level=0).start()
+                timers("dispatch", log_level=2).start()
+                state, step_metrics = art.step_fn(state, dev_batch, base_rng)
+                timers("dispatch").stop()
+                timers("metrics_fetch", log_level=2).start()
+                step_metrics = jax.device_get(step_metrics)
+                timers("metrics_fetch").stop()
+                timers("train-step").stop(wait_for=step_metrics)
 
-            # stop right after the window's last step, BEFORE the eval /
-            # save hooks below, so the capture is steady-state train steps
-            # (note: a hook firing on a non-final in-window iteration is
-            # still captured — pick a window clear of eval/save intervals)
-            _maybe_stop_profiler(iteration + 1)
+                iteration += 1
+                consumed_samples += current_gbs
+                calculator.update(consumed_samples, True)
+                log.tokens += current_gbs * cfg.train.seq_length
+                timers("log", log_level=2).start()
+                training_log(cfg, log, step_metrics, iteration,
+                             consumed_samples, writer, timers)
+                timers("log").stop()
 
-            iteration += 1
-            consumed_samples += current_gbs
-            calculator.update(consumed_samples, True)
-            log.tokens += current_gbs * cfg.train.seq_length
-            training_log(cfg, log, step_metrics, iteration, consumed_samples,
-                         writer, timers)
+            # a trace ends right after its last step and that step's log
+            # line, BEFORE the eval / save hooks below, so the capture is
+            # steady-state train steps (a hook firing on an earlier traced
+            # iteration is still captured — ask for steps clear of
+            # eval/save intervals)
+            step_trace.done(iteration)
 
             # --- anomaly rollback (resilience/anomaly.py) ---
             # K consecutive data anomalies: the poisoned window is wider
@@ -828,7 +873,10 @@ def pretrain(
             if exit_reason:
                 break
       finally:
-        _close_profiler()
+        # on every exit path — incl. exceptions mid-trace, where the
+        # partial capture is exactly what's needed
+        step_trace.close()
+        obs_profile.cancel_step_request()   # nobody is left to take one
 
     if exit_reason:
         print_rank_0(f" exiting at iteration {iteration}: {exit_reason}")

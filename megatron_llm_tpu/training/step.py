@@ -126,10 +126,12 @@ def compute_loss(cfg: RuntimeConfig, params, batch: dict, rng=None,
             rng=rng, deterministic=deterministic, rope=rope,
         )
         b, s, h = hidden.shape
-        per_token = fused_linear_cross_entropy(
-            hidden.reshape(b * s, h), unembed_weight(cfg.model, params),
-            batch["labels"].reshape(b * s), cfg.model.vocab_size,
-        ).reshape(b, s)
+        # one fused op is both the head and its loss
+        with jax.named_scope("lm_head"), jax.named_scope("cross_entropy"):
+            per_token = fused_linear_cross_entropy(
+                hidden.reshape(b * s, h), unembed_weight(cfg.model, params),
+                batch["labels"].reshape(b * s), cfg.model.vocab_size,
+            ).reshape(b, s)
     else:
         logits, moe_aux = model_lib.forward(
             cfg.model, params, batch["tokens"],
@@ -201,8 +203,9 @@ def _accumulate_grads(cfg: RuntimeConfig, params, batch, rng, rope,
         mb, idx = mb_and_idx
         mb_rng = jax.random.fold_in(rng, idx) if rng is not None else None
         (_, (loss, stats)), grads = grad_fn(params, mb, mb_rng)
-        grads_acc = jax.tree.map(
-            lambda a, g: a + g.astype(jnp.float32), grads_acc, grads)
+        with jax.named_scope("grad_accum"):
+            grads_acc = jax.tree.map(
+                lambda a, g: a + g.astype(jnp.float32), grads_acc, grads)
         if stats is not None:
             stats_acc = jax.tree.map(
                 lambda a, s: a + jax.lax.stop_gradient(s), stats_acc, stats)
@@ -326,9 +329,6 @@ def train_step(cfg: RuntimeConfig, state: TrainState, batch: dict,
     lr = schedule.learning_rate(cfg.optimizer, sched_it, train_iters)
     wd = schedule.weight_decay(cfg.optimizer, sched_it, train_iters)
 
-    new_params, new_opt = opt_lib.optimizer_step(
-        cfg.optimizer, state.params, grads, state.opt, lr, wd)
-
     # Skipped-iteration semantics on any anomalous step — non-finite grads
     # (reference: optimizer/optimizer.py:418-432), non-finite loss, or an
     # EWMA loss spike: keep params & moments bitwise.
@@ -336,18 +336,23 @@ def train_step(cfg: RuntimeConfig, state: TrainState, batch: dict,
         return jax.tree.map(
             lambda n, o: jnp.where(anomalous, o, n), new, old)
 
-    new_params = pick(new_params, state.params)
-    new_opt = opt_lib.OptState(
-        step=jnp.where(anomalous, state.opt.step, new_opt.step),
-        mu=pick(new_opt.mu, state.opt.mu),
-        nu=pick(new_opt.nu, state.opt.nu),
-        master=(pick(new_opt.master, state.opt.master)
-                if state.opt.master is not None else None),
-        # the loss scaler reacts to overflow only — a data anomaly says
-        # nothing about the fp16 dynamic range
-        scaler=(opt_lib.scaler_update(scaler, found_inf, cfg.optimizer)
-                if scaler is not None else None),
-    )
+    # one scope over the update and its undoing: XLA fuses the two, and a
+    # fusion carries the name of its root (the select)
+    with jax.named_scope("optimizer"):
+        new_params, new_opt = opt_lib.optimizer_step(
+            cfg.optimizer, state.params, grads, state.opt, lr, wd)
+        new_params = pick(new_params, state.params)
+        new_opt = opt_lib.OptState(
+            step=jnp.where(anomalous, state.opt.step, new_opt.step),
+            mu=pick(new_opt.mu, state.opt.mu),
+            nu=pick(new_opt.nu, state.opt.nu),
+            master=(pick(new_opt.master, state.opt.master)
+                    if state.opt.master is not None else None),
+            # the loss scaler reacts to overflow only — a data anomaly
+            # says nothing about the fp16 dynamic range
+            scaler=(opt_lib.scaler_update(scaler, found_inf, cfg.optimizer)
+                    if scaler is not None else None),
+        )
 
     new_state = TrainState(
         params=new_params,

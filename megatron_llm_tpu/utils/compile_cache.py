@@ -36,6 +36,24 @@ def enable_compile_cache() -> Optional[str]:
     intermittently abort when read back from a warm cache (jax 0.9.0;
     tests/conftest.py), and nothing there compiles for minutes.
     """
+    # The cache's key leaves an operation's metadata out by default, so a
+    # program whose scope names changed (jax.named_scope, a kernel's name)
+    # would be handed the executable compiled before the change — and a
+    # device profile of it would show the old names, or none.  The names
+    # are what profiles are read by (docs/observability.md): key on them.
+    # Metadata also holds source locations — by default a Python traceback
+    # of absolute paths and line numbers for every operation — so a cache
+    # filled from one checkout would miss from another, and an edit that
+    # shifts a line in any caller would miss everywhere.  So operations
+    # are lowered with their name path and no source location: the key
+    # holds the computation and its names, and nothing of where the code
+    # lies.  The price: a profile or an HLO dump names an operation by its
+    # path (``jit(step)/attention/flash_fwd``), not by file and line.
+    # (``jax_include_full_tracebacks_in_locations=False`` would keep one
+    # frame, but cuts the name path from the compiled operation's
+    # ``op_name``: a TPU profile then shows ``dot_general``, PR 24.)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_traceback_in_locations_limit", 0)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

@@ -22,8 +22,10 @@ def all_metrics(man):
 
 def test_manifest_keys_and_limits(man):
     doc = man.doc
-    assert set(doc) == {"command", "paths", "run_seconds", "configs",
-                        "workloads", "end_to_end", "per_layer"}
+    assert set(doc) - {"trace_in_run"} == {
+        "command", "paths", "run_seconds", "configs", "workloads",
+        "end_to_end", "per_layer"}
+    assert doc.get("trace_in_run", True) is True
     assert (ROOT / "BENCHMARK.json").stat().st_size <= 64 * 1024
     assert doc["command"] == ["python3", "benchmarks/run.py"]
     assert 1 <= doc["run_seconds"] <= 51 and isinstance(doc["run_seconds"], int)

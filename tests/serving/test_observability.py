@@ -241,3 +241,131 @@ def test_no_trace_escape_hatch(model):
         assert not svc.engine.trace.enabled
     finally:
         svc.close()
+
+
+# --- POST /profile: the operator's handle on obs/profile.py -----------------
+
+def _post_profile(port, body):
+    import urllib.error
+
+    req = urllib.request.Request(
+        f"http://127.0.0.1:{port}/profile", data=json.dumps(body).encode(),
+        method="POST", headers={"Content-Type": "application/json"})
+    try:
+        with urllib.request.urlopen(req, timeout=120) as resp:
+            return resp.status, json.loads(resp.read())
+    except urllib.error.HTTPError as e:
+        return e.code, json.loads(e.read())
+
+
+def test_profile_route_traces_a_serving_engine_twice(model, tmp_path):
+    """Two profiles of one running server, each under the configured
+    directory; GET /trace then carries the last one's clock-sync pair,
+    which maps the engine's spans onto the profile's clock."""
+    import glob
+
+    cfg, params = model
+    server = MegatronServer(cfg, params,
+                            NullTokenizer(vocab_size=cfg.vocab_size),
+                            max_batch_size=2, profile_dir=str(tmp_path))
+    server.run("127.0.0.1", 0, block=False)
+    try:
+        _generate(server.port, ["5 9 3"], ttg=3)
+        replies = []
+        for _ in range(2):
+            status, reply = _post_profile(server.port, {"seconds": 1.1})
+            assert status == 200, reply
+            replies.append(reply)
+        with urllib.request.urlopen(
+                f"http://127.0.0.1:{server.port}/trace", timeout=60) as resp:
+            other = json.loads(resp.read())["otherData"]
+    finally:
+        server.shutdown()
+    a, b = replies
+    assert a["dir"] != b["dir"]
+    for reply in replies:
+        assert reply["dir"].startswith(str(tmp_path))
+        assert glob.glob(reply["dir"] + "/plugins/profile/*/*.xplane.pb")
+        sync = reply["clock_sync"]
+        assert sync["annotation"] == "obs_clock_sync"
+        assert sync["stop_perf_counter"] - sync["perf_counter"] >= 1.0
+    assert other["clock_sync"] == b["clock_sync"]
+    assert other["epoch_perf_counter"] < b["clock_sync"]["perf_counter"]
+
+
+@pytest.mark.parametrize("profile_dir,body,active,status", [
+    (None, {"seconds": 1}, False, 403),          # no directory configured
+    ("d", {"seconds": 1}, True, 409),            # a session is running
+    ("d", {"seconds": 0}, False, 400),
+    ("d", {"seconds": 1e9}, False, 400),
+    ("d", {"seconds": "soon"}, False, 400),
+])
+def test_profile_route_refusals(model, tmp_path, profile_dir, body, active,
+                                status):
+    from megatron_llm_tpu.obs import profile
+
+    cfg, params = model
+    service = GenerationService(
+        cfg, params, NullTokenizer(vocab_size=cfg.vocab_size),
+        profile_dir=profile_dir and str(tmp_path / profile_dir))
+    if active:
+        profile.start(tmp_path / "other")
+    try:
+        got, _payload = service.profile(body)
+    finally:
+        if active:
+            profile.stop()
+    assert got == status
+    assert profile.active() is None
+    assert not (tmp_path / "d").exists()
+
+
+# --- the spans a token's gap is put down to ------------------------------------
+
+@pytest.mark.parametrize("prefill_chunk", [None, 4])
+def test_admit_and_first_token_spans_share_the_scheduler_iteration(
+        model, prefill_chunk):
+    """``admit`` (track 0) covers an ``_admit()`` call that admitted at
+    least one request and says how many and how many prompt tokens;
+    ``engine_step``, ``prefill`` and ``decode`` carry the same ``iter``,
+    so a stretched gap is traced to the admission that caused it."""
+    from megatron_llm_tpu.serving import EngineConfig, ServingEngine
+
+    cfg, params = model
+    engine = ServingEngine(cfg, params, EngineConfig(
+        max_batch_size=2, max_seq_len=64, kv_block_size=8,
+        prefill_chunk=prefill_chunk)).start()
+    try:
+        prompts = [[5, 9, 3, 7, 2, 8], [7, 2, 4], [11, 12, 13, 14, 15]]
+        handles = [engine.submit(p, 4, use_eos_stop=False, seed=0)
+                   for p in prompts]
+        for h in handles:
+            h.result(timeout=300)
+    finally:
+        engine.shutdown(timeout=60.0)
+    events = [e for e in engine.trace.chrome_trace()["traceEvents"]
+              if e["ph"] == "X"]
+    by = {}
+    for e in events:
+        by.setdefault(e["name"], []).append(e)
+    admits = by["admit"]
+    assert all(e["tid"] == 0 for e in admits)
+    assert sum(e["args"]["admitted"] for e in admits) == len(prompts)
+    assert sum(e["args"]["prompt_tokens"] for e in admits) == \
+        sum(len(p) for p in prompts)
+    iters = [e["args"]["iter"] for e in admits]
+    assert iters == sorted(set(iters)) and iters[0] >= 1
+    steps = {e["args"]["iter"]: e for e in by["engine_step"]}
+    assert len(steps) == len(by["engine_step"])      # one a scheduler turn
+    for e in by["decode"]:
+        assert e["args"]["iter"] in steps
+    if prefill_chunk is None:
+        # whole-prompt admission: the prefill and the first token's own
+        # cost lie inside their admit span, under its iteration
+        assert len(by["first_token"]) == len(prompts)
+        for name in ("prefill", "first_token"):
+            for e in by[name]:
+                (a,) = [a for a in admits if a["ts"] <= e["ts"] and
+                        e["ts"] + e["dur"] <= a["ts"] + a["dur"] + 1]
+                if name == "prefill":
+                    assert e["args"]["iter"] == a["args"]["iter"]

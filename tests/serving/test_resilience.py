@@ -156,7 +156,13 @@ def test_server_graceful_shutdown_drains(tiny):
 
         t = threading.Thread(target=client)
         t.start()
-        time.sleep(0.05)  # let the request reach the engine
+        # let the request reach the engine (built lazily by the first one)
+        deadline = time.time() + 60
+        while time.time() < deadline:
+            eng = server.service._engine
+            if eng is not None and eng.metrics.counters["submitted"]:
+                break
+            time.sleep(0.005)
         assert server.graceful_shutdown(drain_timeout_s=600) is True
         t.join(timeout=600)
         status, payload = results["resp"]

@@ -13,11 +13,49 @@ from megatron_llm_tpu.parallel import mesh as mesh_lib
 from megatron_llm_tpu.utils import compile_cache, native
 
 
-def test_compile_cache_env_var_wins_and_sets_nothing(monkeypatch, tmp_path):
+_KEY_OPTIONS = ("jax_compilation_cache_include_metadata_in_key",
+                "jax_traceback_in_locations_limit")
+
+
+@pytest.fixture
+def key_options():
+    """What ``enable_compile_cache`` sets for the process, put back."""
+    before = {k: getattr(jax.config, k) for k in _KEY_OPTIONS}
+    yield
+    for k, v in before.items():
+        jax.config.update(k, v)
+
+
+def test_compile_cache_env_var_wins_and_sets_nothing(monkeypatch, tmp_path,
+                                                     key_options):
     before = jax.config.jax_compilation_cache_dir
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     assert compile_cache.enable_compile_cache() == str(tmp_path)
     assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_keys_on_names_not_on_where_the_code_lies(
+        monkeypatch, tmp_path, key_options):
+    """Scope and kernel names are part of the key (a profile never shows
+    the names of an executable compiled before they changed), and they
+    reach the compiled operation's ``op_name``, which a TPU profile
+    shows; no file, line or caller is: another checkout, or an edit that
+    shifts lines, finds the same entry."""
+    from megatron_llm_tpu.ops import activations
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    compile_cache.enable_compile_cache()
+    assert jax.config.jax_compilation_cache_include_metadata_in_key
+
+    def caller(x):
+        with jax.named_scope("mlp"):
+            return activations.gelu(x)
+
+    lowered = jax.jit(caller).lower(np.ones(4, np.float32))
+    asm = lowered.compiler_ir().operation.get_asm(enable_debug_info=True)
+    assert '"jit(caller)/mlp/tanh"' in asm
+    assert ".py" not in asm and "callsite" not in asm
+    assert 'op_name="jit(caller)/mlp/' in lowered.compile().as_text()
 
 
 @pytest.mark.parametrize("backend,want", [
