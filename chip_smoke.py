@@ -640,6 +640,7 @@ def server_phase(sz: Sizes, seed: int, on_tpu: bool, reduced: list,
             snap = json.loads(resp.read())
         say(f"GET /metrics: completed={snap['completed']} "
             f"fused_steps={snap['fused_steps']} "
+            f"paged_steps={snap['paged_steps']} "
             f"fallback_steps={snap['fallback_steps']} "
             f"prefix_hits={snap['prefix_hits']} "
             f"cow_copies_total={snap['cow_copies_total']} "

@@ -12,17 +12,27 @@ Layout contract (models/model.py:init_kv_cache): cache [b, kv, max_len, d],
 q [b, kv·group, d] for a single new token.
 
 Paged mode (``flash_decode_paged*``): the cache operands are one layer's
-view of the serving block pool — ``[n_blocks, kv, block, d]`` — plus a
-per-row int32 block table ``[b, T]`` mapping each row's logical block j
-to a physical pool block.  The kernel bodies are IDENTICAL (the mask is
-over logical columns ``j*block + lane`` exactly as in the dense walk);
-only the BlockSpec index maps change: the cache block for grid tick
-``ki`` is ``table[bi, min(ki, last_bi)]``, where ``last_bi`` clamps at
-row bi's own fill — so HBM traffic is the sum of per-row fills, not
-``b * max_len``.  Entries past a row's fill point at the pool's trash
-block; their scores are replaced with NEG_INF before the softmax, so
+view of the serving block pool — ``[n_blocks, kv, block, d]``, or the
+whole ``[L, ...]`` pool with a layer index — plus a per-row int32 block
+table ``[b, T]`` mapping each row's logical block j to a physical pool
+block.  The per-block arithmetic is the dense walk's, in a body of its
+own (``_paged_kernel`` / ``_attend_block``: the mask is over logical
+columns ``j*block + lane``), and the BlockSpec index maps differ: the
+cache block for grid tick ``ki`` is ``table[bi, min(ki, last_bi)]``,
+where ``last_bi`` clamps at row bi's own fill — so HBM traffic is the
+sum of per-row fills, not ``b * max_len``.  Entries past a row's fill point at the pool's trash
+block; a block wholly past the fill is skipped and the rest of a partly
+filled one has its scores replaced with NEG_INF before the softmax, so
 trash contents can never reach the output (exp underflows to exactly
 0.0 and 0.0 x finite = 0.0).
+
+Who calls what: ``ops/attention.py:decode_attention`` the dense kernels
+(head width 128·n, ``generation/`` and the engine's gather route);
+``ops/attention.py:paged_decode_attention`` the paged ones with
+``new_rows`` — the serving engine's composed decode step on a TPU
+(``models/model.py:forward_cached_paged``), head width 64·n, where the
+new token's row is folded in as one more softmax term because the pool
+is written once, after the layer loop.
 """
 
 from __future__ import annotations
@@ -204,50 +214,160 @@ def _scale_block_spec(block_k):
                         lambda bi, hi, ki, lens: (bi, hi, ki, 0))
 
 
-def _paged_body(kernel_fn):
-    """Adapter for the paged harness: the block-table scalar operand is
-    consumed only by the BlockSpec index maps, so it is dropped before
-    the refs reach the shared kernel body."""
-    def body(scale, nk, block_k, len_ref, tbl_ref, *refs):
-        return kernel_fn(scale, nk, block_k, len_ref, *refs)
-    return body
+def _attend_block(scale, col0, n_valid, q, k, v, ks, vs,
+                  m_scr, l_scr, acc_scr, *, kt: bool):
+    """One online-softmax term over a cache block: ``k``/``v`` are
+    [block_k, d] rows at logical columns ``col0..`` (``kt``: [d, block_k],
+    the block as the pool holds it at head width 64); columns at or past
+    ``n_valid`` are masked by score replacement.  ``ks``/``vs`` are the
+    int8 form's per-row fp32 scales as [1, block_k] (``None`` for a float
+    cache): they fold into the score columns (K) and the probability rows
+    (V) — algebraically exact dequantization, int8 HBM traffic."""
+    k_dims = (((1,), (0 if kt else 1,)), ((), ()))
+    v_dims = (((1,), (1 if kt else 0,)), ((), ()))
+    if ks is None:
+        s = jax.lax.dot_general(
+            q, k, k_dims, preferred_element_type=jnp.float32,
+        ) * scale                                      # [g_pad, block_k]
+    else:
+        s = jax.lax.dot_general(
+            q.astype(jnp.float32), k.astype(jnp.float32), k_dims,
+            preferred_element_type=jnp.float32,
+        ) * ks * scale
+    cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + col0
+    s = jnp.where(cols < n_valid, s, NEG_INF)
+
+    m_prev = m_scr[:, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)
+    l_scr[:] = jnp.broadcast_to(
+        alpha * l_scr[:, :1] + jnp.sum(p, axis=-1, keepdims=True),
+        l_scr.shape)
+    if vs is None:
+        pv = jax.lax.dot_general(
+            p.astype(v.dtype), v, v_dims,
+            preferred_element_type=jnp.float32,
+        )
+    else:
+        pv = jax.lax.dot_general(
+            p * vs, v.astype(jnp.float32), v_dims,
+            preferred_element_type=jnp.float32,
+        )
+    acc_scr[:] = acc_scr[:] * alpha + pv
+    m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
 
 
-def _paged_cache_spec(block_k, d):
-    # tick ki fetches row bi's logical block ki via its table, clamped at
-    # the row's own last live block — blocks past the fill (and the whole
-    # walk of an empty row, which lands on the trash block) cost no extra
-    # bytes beyond one block and are fully masked in the kernel
-    def idx(bi, hi, ki, lens, tbl):
+def _attend_new_row(scale, q, k_row, v_row, m_scr, l_scr, acc_scr):
+    """The new token's own K/V row ([1, d] each) as one more
+    online-softmax term, in float32 on the VPU: the row is not in the
+    pool yet when its layer's attention runs (the paged route writes all
+    layers' rows once, after the layer loop)."""
+    s = jnp.sum(q.astype(jnp.float32) * k_row.astype(jnp.float32),
+                axis=-1, keepdims=True) * scale        # [g_pad, 1]
+    m_prev = m_scr[:, :1]
+    m_new = jnp.maximum(m_prev, s)
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)
+    l_scr[:] = jnp.broadcast_to(alpha * l_scr[:, :1] + p, l_scr.shape)
+    acc_scr[:] = acc_scr[:] * alpha + p * v_row.astype(jnp.float32)
+    m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+
+
+def _paged_kernel(scale: float, nk: int, block_k: int, int8: bool,
+                  kt: bool, has_new: bool,
+                  len_ref, tbl_ref, lyr_ref, q_ref, *refs):
+    """Paged walk: tick ``ki`` of row ``bi`` holds its logical block
+    ``ki`` (the table and layer scalars are consumed by the BlockSpec
+    index maps only).  ``refs`` is the block's cache refs — (k, v), or
+    (k, k_scale, v, v_scale) for the int8 pool — then the new token's
+    (k_row, v_row) when ``has_new``, the output, and the three softmax
+    scratches.  A block wholly past the row's fill is skipped, not
+    masked: its tick costs the grid step and nothing else."""
+    n_cache = 4 if int8 else 2
+    cache, new_refs = refs[:n_cache], refs[n_cache:-4]
+    o_ref, m_scr, l_scr, acc_scr = refs[-4:]
+    hi, ki = pl.program_id(1), pl.program_id(2)
+    n = len_ref[pl.program_id(0)]
+
+    @pl.when(ki == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when(ki * block_k < n)
+    def _live():
+        if int8:
+            k_ref, ks_ref, v_ref, vs_ref = cache
+            ks = ks_ref[0, 0, pl.ds(hi, 1), :]            # [1, block_k]
+            vs = vs_ref[0, 0, pl.ds(hi, 1), :]
+        else:
+            (k_ref, v_ref), ks, vs = cache, None, None
+        _attend_block(scale, ki * block_k, n, q_ref[0, 0], k_ref[0, 0, 0],
+                      v_ref[0, 0, 0], ks, vs, m_scr, l_scr, acc_scr, kt=kt)
+
+    @pl.when(ki == nk - 1)
+    def _finalize():
+        if has_new:
+            kn_ref, vn_ref = new_refs
+            _attend_new_row(scale, q_ref[0, 0], kn_ref[0, 0], vn_ref[0, 0],
+                            m_scr, l_scr, acc_scr)
+        l = l_scr[:, :1]
+        o_ref[0, 0] = (acc_scr[:] / jnp.where(l == 0.0, 1.0, l)
+                       ).astype(o_ref.dtype)
+
+
+def _paged_index(block_k: int, per_head: bool):
+    """Index map over a ``[L, n_blocks, kv, block_k(, d)]`` pool leaf:
+    tick ``ki`` fetches row ``bi``'s logical block ``ki`` via its table,
+    clamped at the row's own last live block — blocks past the fill (and
+    the whole walk of an empty row, which lands on the trash block) keep
+    the block index of the tick before, so the pipeline copies nothing
+    for them."""
+    def idx(bi, hi, ki, lens, tbl, lyr):
         last = jnp.maximum(lens[bi] - 1, 0) // block_k
-        return (tbl[bi, jnp.minimum(ki, last)], hi, 0, 0)
-    return pl.BlockSpec((1, 1, block_k, d), idx)
+        blk = tbl[bi, jnp.minimum(ki, last)]
+        return (lyr[0], blk, hi, 0, 0) if per_head else (lyr[0], blk, 0, 0)
+    return idx
 
 
-def _paged_scale_spec(block_k):
-    # same walk as _paged_cache_spec; trailing unit dim as _scale_block_spec
-    def idx(bi, hi, ki, lens, tbl):
-        last = jnp.maximum(lens[bi] - 1, 0) // block_k
-        return (tbl[bi, jnp.minimum(ki, last)], hi, 0, 0)
-    return pl.BlockSpec((1, 1, block_k, 1), idx)
+def _paged_decode_call(q, leaves, tables, cache_len, *, layer=None,
+                       new_rows=None, softmax_scale=None, interpret=None):
+    """Paged twin of _decode_call.  ``leaves`` is (k, v) or the int8
+    pool's (k_q, k_scale, v_q, v_scale): one layer's pool view
+    ``[n_blocks, kv, block_k(, d)]``, or with ``layer`` (an int32 scalar,
+    traced in a layer scan) the whole pool ``[L, n_blocks, ...]`` of
+    which the index maps address layer ``layer`` — a scan body that
+    slices its layer out first makes XLA copy that slice for the custom
+    call.  The grid's k axis walks the ``T`` block-table columns; fills,
+    tables and the layer prefetch to SMEM so the index maps can resolve
+    physical blocks.  (Several blocks a tick bought nothing on the chip:
+    2.34 ms over 32 layers at one block a tick, 2.39-2.45 at 2-16, 16
+    slots of 50-700 rows; PERF.md, PR 25.)
 
-
-def _paged_decode_call(kernel_fn, q, caches, tables, cache_len,
-                       softmax_scale, interpret, extra_in_specs):
-    """Paged twin of _decode_call: cache operands are pool-layer views
-    ``[n_blocks, kv, block_k, d]``, the grid's k axis walks the ``T``
-    block-table columns, and both scalars (per-row fills AND the block
-    tables) prefetch so the index maps can resolve physical blocks."""
+    At a head width under 128 the blocks are handed over transposed,
+    ``[d, block_k]``: XLA:TPU keeps a ``[..., 128·n, 64]`` array with
+    the 128-multiple as the minor (lane) dimension, so the transpose is
+    a relabelling of the pool as it lies in HBM, where the row-major
+    block Mosaic would otherwise ask for costs a copy of the whole pool
+    in every call."""
     b, n_heads, d = q.shape
-    kv_heads = caches[0].shape[1]
-    block_k = caches[0].shape[2]
+    int8 = len(leaves) == 4
+    if layer is None:
+        leaves, layer = [a[None] for a in leaves], 0
+    kv_heads, block_k = leaves[0].shape[2:4]
+    kt = d % 128 != 0
+    if kt:
+        leaves = [jnp.swapaxes(a, -1, -2) if a.ndim == 5 else a
+                  for a in leaves]
     group = n_heads // kv_heads
     if softmax_scale is None:
         softmax_scale = 1.0 / float(np.sqrt(d))
     if interpret is None:
         interpret = kernels.default_interpret()
     if not interpret:
-        assert block_k % 128 == 0, block_k
+        assert block_k % 128 == 0 and d % 64 == 0, (block_k, d)
     nk = tables.shape[1]
 
     g_pad = max(8, -(-group // 8) * 8)
@@ -258,21 +378,30 @@ def _paged_decode_call(kernel_fn, q, caches, tables, cache_len,
     lens = jnp.broadcast_to(
         jnp.reshape(jnp.asarray(cache_len, jnp.int32), (-1,)), (b,))
     tbl = jnp.asarray(tables, jnp.int32)
+    lyr = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
 
-    grid = (b, kv_heads, nk)
+    row_spec = lambda rows: pl.BlockSpec(  # noqa: E731
+        (1, 1, rows, d), lambda bi, hi, ki, *s: (bi, hi, 0, 0))
+    data = pl.BlockSpec(
+        (1, 1, 1, d, block_k) if kt else (1, 1, 1, block_k, d),
+        _paged_index(block_k, True))
+    # a scale block holds every kv head's row scales [kv, block_k] (its
+    # last two dims are the leaf's own: a legal tile as it lies,
+    # lane-major like the score columns it multiplies); the kernel picks
+    # its head's row
+    sc = pl.BlockSpec((1, 1, kv_heads, block_k), _paged_index(block_k, False))
+    new_rows = list(new_rows or ())
     out = pl.pallas_call(
-        functools.partial(_paged_body(kernel_fn), float(softmax_scale),
-                          nk, block_k),
+        functools.partial(_paged_kernel, float(softmax_scale), nk, block_k,
+                          int8, kt, bool(new_rows)),
         name="flash_decode",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, 1, g_pad, d),
-                             lambda bi, hi, ki, *s: (bi, hi, 0, 0)),
-            ] + extra_in_specs(block_k, d),
-            out_specs=pl.BlockSpec((1, 1, g_pad, d),
-                                   lambda bi, hi, ki, *s: (bi, hi, 0, 0)),
+            num_scalar_prefetch=3,
+            grid=(b, kv_heads, nk),
+            in_specs=([row_spec(g_pad)]
+                      + ([data, sc, data, sc] if int8 else [data, data])
+                      + [row_spec(1)] * len(new_rows)),
+            out_specs=row_spec(g_pad),
             scratch_shapes=[
                 pltpu.VMEM((g_pad, 128), jnp.float32),
                 pltpu.VMEM((g_pad, 128), jnp.float32),
@@ -284,26 +413,30 @@ def _paged_decode_call(kernel_fn, q, caches, tables, cache_len,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(lens, tbl, qg, *caches)
+    )(lens, tbl, lyr, qg, *leaves, *new_rows)
     return out[:, :, :group].reshape(b, n_heads, d)
 
 
 def flash_decode_paged(
     q: jax.Array,        # [b, n_heads, d] — ONE new token's queries
-    k_pool: jax.Array,   # [n_blocks, kv_heads, block, d] — one layer's pool
-    v_pool: jax.Array,
+    k_pool: jax.Array,   # [n_blocks, kv_heads, block, d] — one layer's
+    v_pool: jax.Array,   # pool; with ``layer``, the whole [L, ...] pool
     tables: jax.Array,   # [b, T] int32 block tables (pad entries = trash)
-    cache_len: jax.Array,  # [b] (or scalar) valid rows incl. the new token
+    cache_len: jax.Array,  # [b] (or scalar) valid rows IN THE POOL
     *,
+    new_rows: tuple | None = None,  # (k, v) [b, kv_heads, 1, d]: the new
+    #                      token's own rows, attended but not in the pool
+    layer=None,
     softmax_scale: float | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
     """→ [b, n_heads, d]: decode attention gathered straight from the
-    paged block pool — no dense [b, max_len] cache is ever materialized."""
+    paged block pool — no dense [b, max_len] cache is ever materialized.
+    Without ``new_rows`` the new token's row is expected in the pool and
+    counted in ``cache_len``."""
     return _paged_decode_call(
-        _decode_kernel, q, [k_pool, v_pool], tables, cache_len,
-        softmax_scale, interpret,
-        lambda bk, d: [_paged_cache_spec(bk, d), _paged_cache_spec(bk, d)])
+        q, [k_pool, v_pool], tables, cache_len, layer=layer,
+        new_rows=new_rows, softmax_scale=softmax_scale, interpret=interpret)
 
 
 def flash_decode_paged_int8(
@@ -315,16 +448,16 @@ def flash_decode_paged_int8(
     tables: jax.Array,     # [b, T] int32
     cache_len: jax.Array,
     *,
+    new_rows: tuple | None = None,  # float rows, as the pool will hold
+    #                        them (kv_quant.fake_quantize_rows)
+    layer=None,
     softmax_scale: float | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Paged decode attention over the int8 ``{q, scale}`` pool form."""
     return _paged_decode_call(
-        _decode_kernel_int8, q,
-        [k_q, k_scale[..., None], v_q, v_scale[..., None]], tables,
-        cache_len, softmax_scale, interpret,
-        lambda bk, d: [_paged_cache_spec(bk, d), _paged_scale_spec(bk),
-                       _paged_cache_spec(bk, d), _paged_scale_spec(bk)])
+        q, [k_q, k_scale, v_q, v_scale], tables, cache_len, layer=layer,
+        new_rows=new_rows, softmax_scale=softmax_scale, interpret=interpret)
 
 
 def flash_decode(

@@ -25,6 +25,7 @@ from .transformer import (
     rope_tables,
     stack_forward,
     stack_forward_cached,
+    stack_forward_paged,
 )
 from ..ops.norms import norm_apply
 
@@ -293,6 +294,20 @@ def forward_cached(
     return logits, new_k, new_v
 
 
+def paged_decode_eligible(cfg: ModelConfig, k_pool, s: int = 1,
+                          mesh=None) -> bool:
+    """Whether ``forward_cached_paged``'s composed route takes the paged
+    kernel for this pool (ops/attention.py:paged_decode_route): asked by
+    the route itself under the trace's own mesh, and by the engine at
+    ``start()`` — with its mesh — to label its decode steps."""
+    from ..ops import attention as attn_ops
+
+    block = jax.tree.leaves(k_pool)[0].shape[3]
+    return attn_ops.paged_decode_route(
+        s, cfg.num_attention_heads, cfg.kv_heads, cfg.head_dim, block,
+        mesh if mesh is not None else attn_ops._active_mesh())
+
+
 def forward_cached_paged(
     cfg: ModelConfig,
     params: Params,
@@ -304,6 +319,7 @@ def forward_cached_paged(
     *,
     rope: Optional[tuple] = None,
     use_fused: bool = False,
+    allow_paged: bool = True,
     lora=None,
 ):
     """Single-token decode over the paged block pool.
@@ -311,7 +327,7 @@ def forward_cached_paged(
     The paged analogue of ``forward_cached`` for the serving engine's
     slot batch: each slot's token attends the blocks its table names and
     its new K/V row is scattered into block ``tables[s, fill//bk]`` at
-    offset ``fill % bk``.  Two routes, one caller-visible contract:
+    offset ``fill % bk``.  Three routes, one caller-visible contract:
 
     * ``use_fused=True`` — the whole-stack Pallas kernel's paged gather
       mode (kernels/decode_step.py:fused_decode_step_paged): per-row
@@ -320,11 +336,27 @@ def forward_cached_paged(
       ``b * max_seq_len``.  For an int8 pool the kernel's
       pre-requantized fp rows are re-quantized losslessly before the
       scatter (``fake_quantize_rows`` idempotence).
-    * ``use_fused=False`` — gather the tables into a dense working view
+    * the composed *paged* route, where ``paged_decode_route`` says the
+      paged attention kernel runs (a TPU, block size a multiple of 128,
+      head width a multiple of 64, a mesh whose tp divides the heads —
+      bf16 and int8 pools alike): the layers are scanned with every
+      layer's attention reading its KV out of the pool through the
+      tables (``stack_forward_paged``), and the step's new rows are
+      appended in place afterwards.  Nothing of the pool's size, or of
+      slots × ``max_seq_len``, is built or copied.
+    * the composed *gather* route everywhere else (the CPU, odd block
+      sizes): gather the tables into a dense working view
       (``cache_gather_blocks``) and run the ordinary ``forward_cached``
       path over it, then scatter back only the appended rows.  Gathered
       garbage beyond a slot's fill is masked by score replacement, so
-      both routes are bitwise-identical to a contiguously grown cache.
+      this route and the fused one are bitwise-identical to a
+      contiguously grown cache.
+
+    The composed route decides between the two itself
+    (``paged_decode_eligible``).  ``allow_paged=False`` holds it to the
+    gather route: the engine passes it while it speculates, because
+    ``forward_cached_paged_verify`` walks the gather route's arithmetic
+    and a verify step must round as a decode step does.
 
     Returns ``(logits [b, 1, vocab] fp32, new_k_pool, new_v_pool)``.
     """
@@ -354,6 +386,19 @@ def forward_cached_paged(
                        impl=cfg.norm_impl)
         logits = unembed(cfg, params, x)
         return logits, k_pool, v_pool
+    if allow_paged and paged_decode_eligible(cfg, k_pool, tokens.shape[1]):
+        x = embed(cfg, params, tokens, fills[:, None])
+        side = AttnSideInputs(rope_cos=cos, rope_sin=sin,
+                              position_ids=fills[:, None],
+                              deterministic=True)
+        x, k_rows, v_rows = stack_forward_paged(
+            cfg, params["layers"], x, side, k_pool, v_pool, tables, fills,
+            lora=lora)
+        k_pool = cache_append_rows(k_pool, k_rows, bids, offs)
+        v_pool = cache_append_rows(v_pool, v_rows, bids, offs)
+        x = norm_apply(cfg.norm_type, x, params["final_norm"], cfg.norm_eps,
+                       impl=cfg.norm_impl)
+        return unembed(cfg, params, x), k_pool, v_pool
     k_dense = cache_gather_blocks(k_pool, tables)
     v_dense = cache_gather_blocks(v_pool, tables)
     logits, k_dense, v_dense = forward_cached(
@@ -426,7 +471,10 @@ def forward_cached_paged_verify(
     the attention sums and drifts ~1e-7).  The gather/append pool
     round-trip equals in-place dense updates leaf-for-leaf (int8 rows
     requantize through the identical ``quantize_rows``), so walking a
-    persistent dense view matches re-gathering every step.
+    persistent dense view matches re-gathering every step.  "The
+    sequential step" is ``forward_cached_paged``'s *gather* route: an
+    engine that speculates passes ``allow_paged=False`` to its plain decode
+    steps too, so that decode and verify stay one arithmetic.
 
     The window writes land at ``fills[s] .. fills[s]+W-1``, which the
     caller must keep inside the table capacity (the engine reserves
@@ -640,21 +688,31 @@ def cache_scatter_blocks(pool, dense, bids):
 
 @jax.named_scope("kv_cache")
 def cache_append_rows(pool, rows, bids, offs):
-    """Scatter one new K/V row per slot into the pool.
+    """Write one new K/V row per slot into the pool, in place.
 
     ``rows`` leaves are [L, S, kv, 1(, d)] (the rows a decode step
-    appended, extracted from the dense working view or returned by the
-    fused kernel); slot s's row lands at offset ``offs[s]`` of pool block
-    ``bids[s]``.  Inactive slots target (trash, 0).  The int8 {q, scale}
-    pytree scatters leaf-wise, so quantized rows move verbatim."""
+    appended: the layer scan's ys, the fused kernel's, or extracted from
+    the dense working view); slot s's row lands at offset ``offs[s]`` of
+    pool block ``bids[s]``.  Inactive slots target (trash, 0) and
+    overwrite each other there, in slot order.  The int8 {q, scale}
+    pytree is written leaf-wise, so quantized rows move verbatim.
+
+    One ``dynamic_update_slice`` of ``[L, 1, kv, 1(, d)]`` a slot, and
+    not one scatter ``p.at[:, bids, :, offs].set``: XLA:TPU answers a row
+    scatter across all layers with a re-layout of the whole (donated)
+    pool into the order the scatter wants, and back — two pool-sized
+    copies a leaf in every decode step (2.86 ms against 0.19 ms a pool at
+    Falcon-7B's 32 x 513 blocks; PERF.md finding 25)."""
     bids = jnp.asarray(bids, jnp.int32)
     offs = jnp.asarray(offs, jnp.int32)
+    zero = jnp.int32(0)
 
     def ap(p, r):
-        # p[:, bids, :, offs]: non-adjacent advanced indices put the
-        # broadcast (slot) axis first — update shape [S, L, kv(, d)]
-        upd = jnp.moveaxis(r[:, :, :, 0], 1, 0)
-        return p.at[:, bids, :, offs].set(upd.astype(p.dtype))
+        r = r.astype(p.dtype)
+        for s_ in range(r.shape[1]):
+            start = (zero, bids[s_], zero, offs[s_]) + (zero,) * (p.ndim - 4)
+            p = jax.lax.dynamic_update_slice(p, r[:, s_:s_ + 1], start)
+        return p
 
     return jax.tree.map(ap, pool, rows)
 

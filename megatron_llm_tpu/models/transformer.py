@@ -25,7 +25,7 @@ recompute.  Key TPU-first departures from the reference:
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -165,6 +165,22 @@ class AttnSideInputs:
     cache_is_empty: bool = False
 
 
+class PagedKV(NamedTuple):
+    """The paged form of ``attention_block``'s ``kv_cache``: the serving
+    block pool read through per-slot block tables, one new token a slot
+    (ops/attention.py:paged_decode_attention).  ``k_pool``/``v_pool`` are
+    the whole pool ``[L, n_blocks, nkv, block, d]`` (int8 ``{"q",
+    "scale"}`` dicts for a quantized pool), of which ``layer`` — a traced
+    int32 inside the layer scan — names the layer attended, holding
+    ``fills[s]`` rows of slot ``s``."""
+
+    k_pool: object
+    v_pool: object
+    tables: jax.Array            # [b, T] int32
+    fills: jax.Array             # [b] int32
+    layer: jax.Array             # int32 scalar
+
+
 def seq_constrain(x: jax.Array, axes: tuple):
     """Constrain [b, s, h] activations to seq-sharding over ``axes``.
 
@@ -250,7 +266,10 @@ def attention_block(cfg: ModelConfig, p: Params, x: jax.Array,
     decoding (the reference's InferenceParams KV cache,
     transformer.py:423-496).  When given, the return value is
     ``(out, (new_k_rows, new_v_rows))`` — the new tokens' [b, nkv, s, d]
-    rows, NOT an updated cache; the caller owns the write-back.
+    rows, NOT an updated cache; the caller owns the write-back.  Its
+    paged form is a :class:`PagedKV` (one new token a slot, KV read
+    through the block tables by the paged kernel); the rows then come
+    back in the form the pool stores them (``kv_quant.rows_as_stored``).
 
     ``lora`` is the per-layer ``(factors, mask)`` bundle (see
     :func:`_lora_add`); deltas land right after each base projection,
@@ -293,7 +312,19 @@ def attention_block(cfg: ModelConfig, p: Params, x: jax.Array,
     if not side.deterministic and cfg.attention_dropout > 0.0:
         drop_rng = jax.random.fold_in(layer_rng, 1)
 
-    if kv_cache is not None:
+    if isinstance(kv_cache, PagedKV):
+        from ..ops.attention import paged_decode_attention
+        from ..ops.kv_quant import rows_as_stored
+
+        new_k = rows_as_stored(kv_cache.k_pool,
+                               jnp.transpose(k, (0, 2, 1, 3)))
+        new_v = rows_as_stored(kv_cache.v_pool,
+                               jnp.transpose(v, (0, 2, 1, 3)))
+        ctx = paged_decode_attention(
+            q, kv_cache.k_pool, kv_cache.v_pool, kv_cache.tables,
+            kv_cache.fills, new_k, new_v, kv_cache.layer,
+            softmax_scale=softmax_scale)
+    elif kv_cache is not None:
         from ..ops.attention import decode_attention
         from ..ops.kv_quant import cache_update
 
@@ -525,6 +556,32 @@ def stack_forward(cfg: ModelConfig, stacked: Params, x: jax.Array,
     return x, aux
 
 
+def _scan_layers_cached(cfg: ModelConfig, stacked: Params, x: jax.Array,
+                        side: AttnSideInputs, xs_extra: tuple, kv_of,
+                        lora=None):
+    """The decode paths' layer scan: ``kv_of(idx, *extra_l)`` builds layer
+    ``idx``'s ``kv_cache`` argument from its slices of ``xs_extra`` (read-
+    only xs beside the stacked parameters); each layer returns only its
+    new token rows, which stack on a leading layer axis as ys.  Returns
+    ``(hidden, (rows_k, rows_v))``."""
+    arenas, mask = lora if lora is not None else (None, None)
+    n_extra = len(xs_extra)
+
+    def body(carry, inp):
+        h, idx = carry
+        layer_params, extra = inp[0], inp[1:1 + n_extra]
+        layer_lora = (inp[-1], mask) if arenas is not None else None
+        h, _aux, rows = layer_forward(
+            cfg, layer_params, h, side, None,
+            kv_cache=kv_of(idx, *extra), lora=layer_lora)
+        return (h, idx + 1), rows
+
+    xs = (stacked,) + tuple(xs_extra) + (() if arenas is None
+                                          else (arenas,))
+    (x, _), rows = jax.lax.scan(body, (x, jnp.int32(0)), xs)
+    return x, rows
+
+
 def stack_forward_cached(cfg: ModelConfig, stacked: Params, x: jax.Array,
                          side: AttnSideInputs,
                          k_cache: jax.Array,  # [L, b, nkv, max_len, d]
@@ -544,23 +601,9 @@ def stack_forward_cached(cfg: ModelConfig, stacked: Params, x: jax.Array,
     ``cache_len``.  Parity: the reference's InferenceParams threading
     through ParallelTransformer (transformer.py:423-496,1158-1246).
     """
-    arenas, mask = lora if lora is not None else (None, None)
-
-    def body(h, inp):
-        if arenas is not None:
-            layer_params, k_l, v_l, ar_l = inp
-            layer_lora = (ar_l, mask)
-        else:
-            layer_params, k_l, v_l = inp  # per-layer slices, read-only xs
-            layer_lora = None
-        h, _aux, (k_rows, v_rows) = layer_forward(
-            cfg, layer_params, h, side, None,
-            kv_cache=(k_l, v_l, cache_len), lora=layer_lora)
-        return h, (k_rows, v_rows)
-
-    xs = ((stacked, k_cache, v_cache) if arenas is None
-          else (stacked, k_cache, v_cache, arenas))
-    x, (rows_k, rows_v) = jax.lax.scan(body, x, xs)
+    x, (rows_k, rows_v) = _scan_layers_cached(
+        cfg, stacked, x, side, (k_cache, v_cache),
+        lambda _idx, k_l, v_l: (k_l, v_l, cache_len), lora=lora)
     # one batched row write [L, b, nkv, s_new, d] — XLA aliases the DUS
     # with the loop-carried cache buffer, so decode writes s_new rows
     # instead of round-tripping the whole cache.  cache_update also
@@ -570,6 +613,29 @@ def stack_forward_cached(cfg: ModelConfig, stacked: Params, x: jax.Array,
     new_k = cache_update(k_cache, rows_k, cache_len)
     new_v = cache_update(v_cache, rows_v, cache_len)
     return x, new_k, new_v
+
+
+def stack_forward_paged(cfg: ModelConfig, stacked: Params, x: jax.Array,
+                        side: AttnSideInputs,
+                        k_pool,              # [L, n_blocks, nkv, bk, d]
+                        v_pool,
+                        tables: jax.Array,   # [b, T] int32
+                        fills: jax.Array,    # [b] int32
+                        lora=None):
+    """The paged twin of ``stack_forward_cached``: one new token a slot,
+    each layer's attention reading its KV out of the block pool through
+    the tables (:class:`PagedKV`).  The pool is never written here and no
+    dense view of it is built: returns ``(hidden, rows_k, rows_v)``, the
+    layers' new rows ``[L, b, nkv, 1(, d)]`` in the form the pool stores
+    them, for the caller's one ``cache_append_rows``.
+
+    The scan closes over the whole pool and each layer's kernel addresses
+    its own layer through the index maps: a per-layer slice taken by the
+    scan is a copy of that slice for every custom call."""
+    x, (rows_k, rows_v) = _scan_layers_cached(
+        cfg, stacked, x, side, (),
+        lambda idx: PagedKV(k_pool, v_pool, tables, fills, idx), lora=lora)
+    return x, rows_k, rows_v
 
 
 def rope_tables(cfg: ModelConfig, dtype=jnp.float32):

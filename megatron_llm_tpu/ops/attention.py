@@ -157,51 +157,65 @@ def _head_shard_axes(mesh, n_heads: int, kv_heads: int):
     return None
 
 
-def _sharded_paged_flash_decode(q, k_pool, v_pool, tables, cache_len,
-                                softmax_scale, mesh):
+def _paged_kernel_decode(q, k_pool, v_pool, tables, fills, k_new, v_new,
+                         layer, softmax_scale):
+    """The single call site of the paged Pallas decode kernels: [b,1,h,d]
+    in/out; ``k_new``/``v_new`` are the new token's rows in the form the
+    pool stores them (int8 ``{"q", "scale"}`` dicts for a quantized
+    pool: the kernel then attends their dequantized values, what a later
+    step will read back)."""
+    from ..kernels import flash_decode as fd
+    from .kv_quant import dequantize_cache, is_quantized_cache
+
+    if is_quantized_cache(k_pool):
+        return fd.flash_decode_paged_int8(
+            q[:, 0], k_pool["q"], k_pool["scale"], v_pool["q"],
+            v_pool["scale"], tables, fills,
+            new_rows=(dequantize_cache(k_new), dequantize_cache(v_new)),
+            layer=layer, softmax_scale=softmax_scale)[:, None]
+    return fd.flash_decode_paged(
+        q[:, 0], k_pool, v_pool, tables, fills, new_rows=(k_new, v_new),
+        layer=layer, softmax_scale=softmax_scale)[:, None]
+
+
+def _sharded_paged_flash_decode(q, k_pool, v_pool, tables, fills, k_new,
+                                v_new, layer, softmax_scale, mesh):
     """Run the PAGED Pallas decode kernel under an active mesh, or None.
 
     The paged analogue of ``_sharded_flash_decode``: attention is
-    embarrassingly parallel over kv heads, so each shard walks its own
-    head slice of every pool block; the int32 block tables and fill
-    levels are replicated (``P(None, None)`` / ``P()``) — block ids stay
-    global, no table translation — and an int8 pool's ``{"q", "scale"}``
-    leaves move verbatim with the same head-axis spec the pool was placed
-    with (models/sharding.py:kv_pool_specs).
+    head-local, so q, out and the whole ``[L, n_blocks, kv, block, d]``
+    pool split on their head axis over tp — the axis the pool was placed
+    with (models/sharding.py:kv_pool_specs), so the shard_map moves
+    nothing — while the block tables, the fill levels and the layer index
+    are replicated (``P(None, None)`` / ``P()``): block ids stay global,
+    no table translation.  An int8 pool's ``{"q", "scale"}`` leaves move
+    verbatim with the same head-axis spec, as do the new token's rows
+    ``[b, kv, 1(, d)]``.  The layer axis must be whole on every device
+    (``paged_decode_route`` declines a pp mesh).
     """
     from jax.sharding import PartitionSpec as P
     from .kv_quant import is_quantized_cache
 
     kv_q = is_quantized_cache(k_pool)
-
-    def _call(q_, kp, vp, tbl, ln):
-        if kv_q:
-            from ..kernels.flash_decode import flash_decode_paged_int8
-
-            return flash_decode_paged_int8(
-                q_[:, 0], kp["q"], kp["scale"], vp["q"], vp["scale"],
-                tbl, ln + 1, softmax_scale=softmax_scale)[:, None]
-        from ..kernels.flash_decode import flash_decode_paged
-
-        return flash_decode_paged(
-            q_[:, 0], kp, vp, tbl, ln + 1,
-            softmax_scale=softmax_scale)[:, None]
-
     n_heads = q.shape[2]
-    kv_heads = (k_pool["q"] if kv_q else k_pool).shape[1]
+    kv_heads = (k_pool["q"] if kv_q else k_pool).shape[2]
     axes = _head_shard_axes(mesh, n_heads, kv_heads)
     if axes is None:
         return None
-    pool_spec = ({"q": P(None, axes, None, None), "scale": P(None, axes,
-                                                             None)}
-                 if kv_q else P(None, axes, None, None))
+
+    def kv_spec(lead):      # leaves with ``lead`` axes before the heads
+        head = (None,) * lead + (axes, None)
+        return ({"q": P(*head, None), "scale": P(*head)} if kv_q
+                else P(*head, None))
+
     wrapped = _manual_over_mesh(
-        _call, mesh,
-        in_specs=(P(None, None, axes, None), pool_spec, pool_spec,
-                  P(None, None), P()),
+        lambda q_, kp, vp, tbl, ln, kn, vn, ly: _paged_kernel_decode(
+            q_, kp, vp, tbl, ln, kn, vn, ly, softmax_scale),
+        mesh,
+        in_specs=(P(None, None, axes, None), kv_spec(2), kv_spec(2),
+                  P(None, None), P(), kv_spec(1), kv_spec(1), P()),
         out_specs=P(None, None, axes, None))
-    return wrapped(q, k_pool, v_pool, tables,
-                   jnp.asarray(cache_len, jnp.int32))
+    return wrapped(q, k_pool, v_pool, tables, fills, k_new, v_new, layer)
 
 
 def _sharded_flash_attention(q, k, v, segment_ids, mesh, **kw):
@@ -363,81 +377,75 @@ def paged_decode_kernel_eligible(s: int, d: int, block: int,
                                  platform: str) -> bool:
     """Shape/platform predicate for the paged Pallas decode path: the
     kernel's cache tile is one pool block, so the block itself must be a
-    legal Mosaic tile."""
-    return (s == 1 and d % 128 == 0 and block % 128 == 0
+    legal Mosaic tile — 128 rows a multiple, and a head width that is a
+    multiple of 64 (a width-64 block equals the array's last dim)."""
+    return (s == 1 and d % 64 == 0 and block % 128 == 0
             and platform == "tpu")
 
 
+def paged_decode_route(s: int, n_heads: int, kv_heads: int, d: int,
+                       block: int, mesh=None) -> bool:
+    """Whether a decode step of this geometry reads its KV through the
+    block tables inside the paged kernel (``paged_decode_attention``)
+    rather than from a gathered dense view — decided from what the trace
+    can observe: the backend, one new token a row, the pool's block and
+    head width, and under a mesh whether its tp axis divides the heads
+    (``_head_shard_axes``; MQA under tp > 1 keeps the gather route, which
+    GSPMD partitions from the pool's sharding) and the pool's layer axis
+    is whole on every device (pp shards it: the gather route stays)."""
+    if not paged_decode_kernel_eligible(s, d, block, _backend()):
+        return False
+    if mesh is None:
+        return True
+    from ..parallel.mesh import PIPELINE_AXIS
+
+    return (dict(mesh.shape).get(PIPELINE_AXIS, 1) == 1
+            and _head_shard_axes(mesh, n_heads, kv_heads) is not None)
+
+
 def paged_decode_attention(
-    q: jax.Array,        # [b, s, n_heads, d] — the new tokens' queries
-    k_pool,              # [n_blocks, kv_heads, block, d] — ONE layer's
-    v_pool,              # pool view, or int8 {"q", "scale"} dicts
+    q: jax.Array,        # [b, 1, n_heads, d] — the new tokens' queries
+    k_pool,              # [L, n_blocks, kv_heads, block, d] — the whole
+    v_pool,              # pool, or int8 {"q", "scale"} dicts
     tables: jax.Array,   # [b, T] int32 block tables (pad entries = trash)
-    cache_len,           # int32 scalar or [b]: position of q's first token
+    fills,               # [b] int32: rows each slot holds IN THE POOL
+    k_new,               # [b, kv_heads, 1, d] — the new token's own rows,
+    v_new,               # in the form the pool stores them
+    layer,               # int32 scalar (traced in a layer scan): the
+    #                      layer of the pool that is attended
     *,
     softmax_scale: float | None = None,
 ) -> jax.Array:
     """Decode attention over a paged KV pool via per-slot block tables.
 
-    On an eligible TPU shape this dispatches the paged Pallas kernels
-    (kernels/flash_decode.py:flash_decode_paged*), which resolve blocks
-    inside the BlockSpec index maps — no dense cache is materialized and
-    HBM traffic is the sum of per-row fills.  Everywhere else it gathers
-    the tables into the dense ``[b, kv, T*block, d]`` view (one take per
-    leaf) and reuses ``decode_attention`` verbatim, so both routes share
-    the masking/softmax math bit-for-bit.  Entries past a row's fill
-    point at the pool's trash block; the masks replace their scores
-    before the softmax, so trash contents can never reach the output.
+    Dispatches the paged Pallas kernels (kernels/flash_decode.py:
+    flash_decode_paged*), which resolve blocks inside the BlockSpec index
+    maps — no dense cache is materialized and HBM traffic is the sum of
+    per-row fills.  The new token's row is not in the pool yet: the
+    kernel folds it in as one more softmax term, and the caller appends
+    every layer's rows in one write after its layer loop
+    (models/model.py:forward_cached_paged).  Entries past a row's fill
+    point at the pool's trash block; the walk skips them.
+
+    The caller asks ``paged_decode_route`` first: this is the kernel
+    route only (interpret mode off the TPU); the gather route lives in
+    ``forward_cached_paged``.  Under a mesh the kernel runs per shard
+    inside a shard_map manual over the head axis (replicated tables,
+    head-sharded pool).  Either way the kernel is handed the whole pool
+    and the layer index — a layer sliced out first is a copy of that
+    slice for every custom call.
     """
-    from .kv_quant import is_quantized_cache
-
-    kv_q = is_quantized_cache(k_pool)
-    k_arr = k_pool["q"] if kv_q else k_pool
-    b, s, n_heads, d = q.shape
-    _, kv_heads, block, _ = k_arr.shape
-
-    if paged_decode_kernel_eligible(s, d, block, _backend()):
-        mesh = _active_mesh()
-        if mesh is not None:
-            # sharded pool: the kernel runs per-shard inside a shard_map
-            # manual over the head axes (replicated tables, head-sharded
-            # pool); head counts dividing nothing fall through to the
-            # gather path, which GSPMD partitions from the pool sharding
-            out = _sharded_paged_flash_decode(
-                q, k_pool, v_pool, tables, cache_len, softmax_scale, mesh)
-            if out is not None:
-                return out
-        elif kv_q:
-            from ..kernels.flash_decode import flash_decode_paged_int8
-
-            out = flash_decode_paged_int8(
-                q[:, 0], k_pool["q"], k_pool["scale"],
-                v_pool["q"], v_pool["scale"], tables,
-                jnp.asarray(cache_len, jnp.int32) + 1,
-                softmax_scale=softmax_scale)
-            return out[:, None]
-        else:
-            from ..kernels.flash_decode import flash_decode_paged
-
-            out = flash_decode_paged(
-                q[:, 0], k_pool, v_pool, tables,
-                jnp.asarray(cache_len, jnp.int32) + 1,
-                softmax_scale=softmax_scale)
-            return out[:, None]
-
-    # fallback: gather the dense per-row view and reuse decode_attention
-    t = tables.shape[1]
-
-    def gather(a):  # [nb, kv, block(,d)] → [b, kv, t*block(,d)]
-        x = jnp.take(a, tables.reshape(-1), axis=0)
-        x = x.reshape((b, t) + a.shape[1:])
-        x = jnp.moveaxis(x, 1, 2)
-        return x.reshape((b, a.shape[1], t * block) + a.shape[3:])
-
-    k_dense = jax.tree.map(gather, k_pool)
-    v_dense = jax.tree.map(gather, v_pool)
-    return decode_attention(q, k_dense, v_dense, cache_len,
-                            softmax_scale=softmax_scale)
+    fills = jnp.asarray(fills, jnp.int32)
+    layer = jnp.asarray(layer, jnp.int32)
+    mesh = _active_mesh()
+    if mesh is None:
+        return _paged_kernel_decode(q, k_pool, v_pool, tables, fills,
+                                    k_new, v_new, layer, softmax_scale)
+    out = _sharded_paged_flash_decode(q, k_pool, v_pool, tables, fills,
+                                      k_new, v_new, layer, softmax_scale,
+                                      mesh)
+    assert out is not None, "paged_decode_route() declines this mesh"
+    return out
 
 
 def dot_product_attention(

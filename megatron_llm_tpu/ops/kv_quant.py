@@ -86,6 +86,14 @@ def dequantize_cache(cache: dict, dtype=jnp.float32) -> jax.Array:
             * cache["scale"][..., None]).astype(dtype)
 
 
+def rows_as_stored(cache, rows):
+    """New-token ``rows`` in the form ``cache`` stores them: the int8
+    ``{"q", "scale"}`` dict for a quantized cache, else its dtype."""
+    if is_quantized_cache(cache):
+        return quantize_rows(rows)
+    return rows.astype(cache.dtype)
+
+
 def cache_update(cache, rows, pos):
     """Write new-token ``rows`` [..., s, d] into ``cache`` at position
     ``pos`` along the -2 (sequence) axis.  Handles both plain arrays and

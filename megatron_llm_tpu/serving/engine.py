@@ -522,7 +522,8 @@ def _first_token_impl(cfg: ModelConfig, last_logits, seeds, counters,
 def _decode_impl(cfg: ModelConfig, params, k_pool, v_pool, tables, pending,
                  fills, seeds, counters, greedy, temps, top_ks, top_ps,
                  lora_arenas=None, lora_slots=None, *,
-                 use_fused: bool, lora_rank: int = 0):
+                 use_fused: bool, allow_paged: bool = True,
+                 lora_rank: int = 0):
     """One batched decode step over every slot: feed each slot's pending
     token at its own fill position, scatter its K/V row into the pool
     block its table names, sample the next token per slot.  Free slots
@@ -533,7 +534,7 @@ def _decode_impl(cfg: ModelConfig, params, k_pool, v_pool, tables, pending,
     rope = model_lib.rope_tables(cfg)
     logits, k_pool, v_pool = model_lib.forward_cached_paged(
         cfg, params, pending[:, None], k_pool, v_pool, tables, fills,
-        rope=rope, use_fused=use_fused,
+        rope=rope, use_fused=use_fused, allow_paged=allow_paged,
         lora=_lora_operand(lora_arenas, lora_slots, lora_rank))
     tok, tok_lp = _sample_slots(logits[:, 0], seeds, counters, greedy,
                                 temps, top_ks, top_ps, cfg.vocab_size)
@@ -541,10 +542,13 @@ def _decode_impl(cfg: ModelConfig, params, k_pool, v_pool, tables, pending,
 
 
 _decode_donated = functools.partial(
-    jax.jit, static_argnames=("cfg", "use_fused", "lora_rank"),
+    jax.jit,
+    static_argnames=("cfg", "use_fused", "allow_paged", "lora_rank"),
     donate_argnums=(2, 3))(_decode_impl)
 _decode_plain = functools.partial(
-    jax.jit, static_argnames=("cfg", "use_fused", "lora_rank"))(_decode_impl)
+    jax.jit,
+    static_argnames=("cfg", "use_fused", "allow_paged", "lora_rank"))(
+        _decode_impl)
 
 
 def _verify_impl(cfg: ModelConfig, params, k_pool, v_pool, tables, window,
@@ -1069,6 +1073,14 @@ class ServingEngine:
         # predicate is static in cfg/params/cache shape) and used to
         # attribute each decode iteration to fused_steps/fallback_steps
         self._fused_decode = False
+        # the composed decode step decides for itself whether it reads KV
+        # through the block tables inside the paged attention kernel
+        # (models/model.py:forward_cached_paged) — except while
+        # speculating: the verify step walks the gather route's
+        # arithmetic, and decode must round as it does.  _paged_decode is
+        # the same predicate asked at start(), to label the steps.
+        self._allow_paged = self.config.spec_draft_len == 0
+        self._paged_decode = False
         self._fused_verify = False  # same, for the multi-token verify step
         self._fused_draft = False   # same, for the draft model's forwards
         # draft model actually engaged: resident params AND speculation on
@@ -1140,6 +1152,10 @@ class ServingEngine:
                     self.cfg, self.params, pool.k_pool,
                     cfg_e.max_batch_size, self.slots.table_blocks,
                     jax.default_backend(), mesh=self.mesh, lora_sr=lsr)
+                self._paged_decode = (
+                    not self._fused_decode and self._allow_paged
+                    and model_lib.paged_decode_eligible(
+                        self.cfg, pool.k_pool, mesh=self.mesh))
                 if cfg_e.spec_draft_len > 0:
                     from ..kernels.decode_step import (
                         fused_paged_verify_eligible)
@@ -2004,8 +2020,16 @@ class ServingEngine:
         self.trace.add(
             "engine_step", it0, time.perf_counter(), tid=0,
             args={"iter": self._iter, "batch": len(inflight.slots),
-                  "route": "fused" if self._fused_decode else "fallback",
+                  "route": self._decode_route,
                   "pipelined": self.config.pipeline_decode})
+
+    @property
+    def _decode_route(self) -> str:
+        """The plain decode step's route, as the ``engine_step`` span and
+        the ``<route>_steps`` counters name it."""
+        if self._fused_decode:
+            return "fused"
+        return "paged" if self._paged_decode else "fallback"
 
     def _spec_budget(self, st: _SlotState) -> int:
         """Draft-token budget from the slot's acceptance EWMA; a slot
@@ -2298,7 +2322,9 @@ class ServingEngine:
                 gap = min(wall, t0 - self._last_ready_t)
                 self.metrics.observe_step_breakdown(gap_frac=gap / wall)
         self._last_dispatch_t = t0
-        self.metrics.inc_step(self._fused_verify, self._precision_route)
+        self.metrics.inc_step(
+            "fused" if self._fused_verify else "fallback",
+            self._precision_route)
         with device_annotation("verify"):
             g_tok, g_lp, k_pool, v_pool = self._verify(
                 self.cfg, self.params, self.slots.k_pool,
@@ -2506,7 +2532,9 @@ class ServingEngine:
                 gap = min(wall, t0 - self._last_ready_t)
                 self.metrics.observe_step_breakdown(gap_frac=gap / wall)
         self._last_dispatch_t = t0
-        self.metrics.inc_step(self._fused_verify, self._precision_route)
+        self.metrics.inc_step(
+            "fused" if self._fused_verify else "fallback",
+            self._precision_route)
         with device_annotation("verify_tree"):
             g_tok, g_lp, k_pool, v_pool = self._verify_tree(
                 self.cfg, self.params, self.slots.k_pool,
@@ -2679,7 +2707,7 @@ class ServingEngine:
                 self.metrics.observe_step_breakdown(gap_frac=gap / wall)
         self._last_dispatch_t = t0
 
-        self.metrics.inc_step(self._fused_decode, self._precision_route)
+        self.metrics.inc_step(self._decode_route, self._precision_route)
         # Microbatch-interleaved dispatch: the slot batch is split into
         # G contiguous groups (G = pp on a pp>1 mesh, else 1) whose
         # decode calls chain through the donated KV pool — group g+1's
@@ -2720,6 +2748,7 @@ class ServingEngine:
                     jnp.asarray(greedy[sl]), jnp.asarray(temps[sl]),
                     jnp.asarray(top_ks[sl]), jnp.asarray(top_ps[sl]),
                     use_fused=self._fused_decode,
+                    allow_paged=self._allow_paged,
                     **self._lora_args(aslots[sl]))
                 toks.append(tok)
                 tok_lps.append(tok_lp)

@@ -116,7 +116,12 @@ _COUNTERS = (
     # through the fused whole-stack kernel vs the composed per-op path.
     # An int8 config silently losing eligibility shows up here as
     # fallback_steps climbing where fused_steps should.
-    "fused_steps", "fallback_steps",
+    # paged_steps: composed decode steps whose attention read the KV
+    # pool through the block tables inside the paged kernel
+    # (models/model.py:forward_cached_paged) — on a TPU every composed
+    # decode step of a non-speculating engine should land here, not in
+    # fallback_steps (the gather route).
+    "fused_steps", "fallback_steps", "paged_steps",
     # automatic prefix caching (serving/prefix_cache.py): admissions that
     # reused cached shared-prefix K/V vs prefilled cold, and blocks LRU-
     # evicted under the prefix_cache_blocks budget.  A workload expected
@@ -261,15 +266,16 @@ class ServingMetrics:
         with self._lock:
             self.counters[name] += by
 
-    def inc_step(self, fused: bool, route: str = "fp32") -> None:
-        """One decode/verify iteration: bumps the aggregate
-        fused_steps/fallback_steps counter AND its per-precision-route
-        breakdown (``route`` from ops/quant.py:precision_route)."""
+    def inc_step(self, route: str, precision: str = "fp32") -> None:
+        """One decode/verify iteration by the ``route`` it took —
+        ``"fused"``, ``"paged"`` or ``"fallback"``: bumps the aggregate
+        ``<route>_steps`` counter AND its per-precision breakdown
+        (``precision`` from ops/quant.py:precision_route)."""
         with self._lock:
-            self.counters["fused_steps" if fused else "fallback_steps"] += 1
-            r = self.step_routes.setdefault(route,
-                                            {"fused": 0, "fallback": 0})
-            r["fused" if fused else "fallback"] += 1
+            self.counters[f"{route}_steps"] += 1
+            r = self.step_routes.setdefault(
+                precision, {"fused": 0, "paged": 0, "fallback": 0})
+            r[route] += 1
 
     def set_gauges(self, *, slots_active: Optional[int] = None,
                    queue_depth: Optional[int] = None,
@@ -446,6 +452,9 @@ class ServingMetrics:
                 "fallback_steps_by_precision": {
                     route: r["fallback"]
                     for route, r in sorted(self.step_routes.items())},
+                "paged_steps_by_precision": {
+                    route: r["paged"]
+                    for route, r in sorted(self.step_routes.items())},
             })
         out["slo"] = self.slo.snapshot()
         return out
@@ -472,10 +481,15 @@ class ServingMetrics:
                     "serving_fallback_steps_by_precision_total", "counter",
                     "composed-path decode iterations by weight precision "
                     "route")
+                paged_fam = MetricFamily(
+                    "serving_paged_steps_by_precision_total", "counter",
+                    "composed decode iterations through the paged "
+                    "attention kernel by weight precision route")
                 for route, r in sorted(self.step_routes.items()):
                     fused_fam.add(r["fused"], labels={"precision": route})
                     fb_fam.add(r["fallback"], labels={"precision": route})
-                fams.extend([fused_fam, fb_fam])
+                    paged_fam.add(r["paged"], labels={"precision": route})
+                fams.extend([fused_fam, fb_fam, paged_fam])
             if self.spec_by_source:
                 by_src = {
                     "steps": MetricFamily(

@@ -184,3 +184,158 @@ def test_kv_heads_not_divisible_by_pp_tp_falls_back(monkeypatch):
     assert called.get("yes"), "tp-only kernel layout was not taken"
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# The composed decode step's paged route (forward_cached_paged): what it
+# observes, and that each arm of the decision is reachable from the CPU
+# ---------------------------------------------------------------------------
+
+from megatron_llm_tpu.config import tiny_config  # noqa: E402
+from megatron_llm_tpu.models import model as model_lib  # noqa: E402
+
+
+@pytest.mark.parametrize(
+    "platform,s,block,d,tp,heads,kv,want", [
+        ("tpu", 1, 128, 64, 0, 71, 1, True),     # Falcon-7B, one chip
+        ("tpu", 1, 256, 128, 0, 32, 8, True),    # Llama width, GQA
+        ("cpu", 1, 128, 64, 0, 71, 1, False),    # platform
+        ("tpu", 2, 128, 64, 0, 71, 1, False),    # more than one new token
+        ("tpu", 1, 64, 64, 0, 71, 1, False),     # block not 128·n
+        ("tpu", 1, 128, 96, 0, 8, 8, False),     # head width not 64·n
+        ("tpu", 1, 128, 64, 2, 128, 8, True),    # Falcon-40B under tp 2
+        ("tpu", 1, 128, 64, 2, 8, 1, False),     # MQA: tp cannot split kv
+        ("tpu", 1, 128, 128, 4, 6, 6, False),    # tp divides no head count
+        ("tpu", 1, 128, 128, 1, 8, 8, False),    # a mesh with nothing to split
+        ("tpu", 1, 128, 64, -2, 128, 8, False),  # pp shards the pool's layers
+    ])
+def test_paged_decode_route_truth_table(monkeypatch, platform, s, block, d,
+                                        tp, heads, kv, want):
+    """``tp`` 0: no mesh; -2: a pp=2 x tp=2 mesh."""
+    monkeypatch.setattr(attn_mod, "_backend", lambda: platform)
+    mesh = (mesh_lib.build_mesh(ParallelConfig(
+        tensor_parallel=abs(tp), pipeline_parallel=2 if tp < 0 else 1))
+        if tp else None)
+    assert attn_mod.paged_decode_route(s, heads, kv, d, block, mesh) is want
+    if not tp:      # without a mesh the route is the kernel's own predicate
+        assert attn_mod.paged_decode_kernel_eligible(
+            s, d, block, platform) is want
+
+
+def _paged_problem(kv_quant, heads, kv, d, seed):
+    cfg = tiny_config(hidden_size=heads * d, num_attention_heads=heads,
+                      num_kv_heads=kv, num_layers=2, vocab_size=64,
+                      max_position_embeddings=512,
+                      kv_cache_quant=kv_quant)
+    params = model_lib.init_params(jax.random.key(seed), cfg)
+    slots, t, bk = 4, 3, 128
+    rng = np.random.default_rng(seed)
+    k_pool, v_pool = model_lib.init_kv_pool(cfg, 1 + slots * t, bk)
+
+    def fill(a):
+        if a.dtype == jnp.int8:
+            return jnp.asarray(rng.integers(-127, 128, a.shape), jnp.int8)
+        if a.ndim == 4:                            # int8 row scales
+            return jnp.asarray(rng.uniform(0.001, 0.01, a.shape), a.dtype)
+        return jnp.asarray(rng.normal(size=a.shape) * 0.3, a.dtype)
+
+    k_pool, v_pool = jax.tree.map(fill, (k_pool, v_pool))
+    tables = jnp.asarray((rng.permutation(slots * t) + 1).reshape(slots, t),
+                         jnp.int32)
+    fills = jnp.asarray([0, 1, 128, 383], jnp.int32)   # a block boundary
+    tokens = jnp.asarray(rng.integers(0, 64, (slots, 1)), jnp.int32)
+    return cfg, (params, tokens, k_pool, v_pool, tables, fills)
+
+
+def _spy_paged_kernel(monkeypatch):
+    calls = []
+    real = attn_mod._paged_kernel_decode
+
+    def spy(*a):
+        calls.append(a[7])                         # the layer operand
+        return real(*a)
+
+    monkeypatch.setattr(attn_mod, "_paged_kernel_decode", spy)
+    return calls
+
+
+def _assert_same_step(got, want, *, exact):
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        if exact or g.dtype == jnp.int8:
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+        else:
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       rtol=1e-3, atol=1e-5)
+
+
+@pytest.mark.parametrize("kv_quant", ["none", "int8"])
+@pytest.mark.parametrize("heads,kv,d", [(2, 1, 64), (4, 2, 128)],
+                         ids=["mqa64", "gqa128"])
+def test_paged_route_unsharded_matches_gather_route(monkeypatch, kv_quant,
+                                                    heads, kv, d):
+    """platform=tpu, no mesh: the composed step scans the layers over the
+    WHOLE pool (each layer's kernel call carries a layer index), folds
+    the new row in, appends in place — and returns the gather route's
+    logits and pools to float32 rounding (int8 codes exactly)."""
+    cfg, args = _paged_problem(kv_quant, heads, kv, d, seed=5)
+    step = lambda **kw: jax.jit(  # noqa: E731
+        lambda *a: model_lib.forward_cached_paged(cfg, *a, **kw))(*args)
+    want = step(allow_paged=False)
+    calls = _spy_paged_kernel(monkeypatch)
+    _assert_same_step(step(), want, exact=True)    # cpu: the gather route
+    assert not calls
+    monkeypatch.setattr(attn_mod, "_backend", lambda: "tpu")
+    _assert_same_step(step(), want, exact=False)
+    assert len(calls) == 1 and calls[0] is not None    # one scan body
+    calls.clear()
+    # (at head width 128 the gather route's own attention is the dense
+    # Pallas kernel on a TPU: the same step, not the same bits)
+    _assert_same_step(step(allow_paged=False), want, exact=d == 64)
+    assert not calls, "allow_paged=False must keep the gather route"
+
+
+@pytest.mark.parametrize("heads,kv,routes", [(4, 2, True), (2, 1, False)],
+                         ids=["gqa_sharded_kernel", "mqa_stays_gather"])
+def test_paged_route_under_tp_mesh(monkeypatch, heads, kv, routes):
+    """Under a tp=2 mesh a head count tp divides runs the same scan —
+    the whole pool and a layer index — with the kernel inside a
+    shard_map over the heads; MQA, whose one KV head tp cannot split,
+    keeps the gather route bit for bit."""
+    cfg, args = _paged_problem("none", heads, kv, 64, seed=6)
+    step = lambda **kw: jax.jit(  # noqa: E731
+        lambda *a: model_lib.forward_cached_paged(cfg, *a, **kw))(*args)
+    want = step(allow_paged=False)
+    calls = _spy_paged_kernel(monkeypatch)
+    monkeypatch.setattr(attn_mod, "_backend", lambda: "tpu")
+    mesh = mesh_lib.build_mesh(ParallelConfig(tensor_parallel=2))
+    with mesh_lib.use_mesh(mesh):
+        assert model_lib.paged_decode_eligible(cfg, args[2]) is routes
+        got = step()
+    assert len(calls) == (1 if routes else 0)      # one scan body
+    _assert_same_step(got, want, exact=not routes)
+
+
+@pytest.mark.parametrize("kv_quant", ["none", "int8"])
+def test_cache_append_rows_equals_the_row_scatter(kv_quant):
+    """``cache_append_rows`` writes one slice a slot; what lands in the
+    pool is what the row scatter ``p.at[:, bids, :, offs].set`` it
+    replaced put there, bit for bit — live rows at distinct targets, and
+    the idle slots' shared (trash, 0) row taking the last slot's value."""
+    cfg, (_, _, k_pool, _, tables, fills) = _paged_problem(
+        kv_quant, 2, 1, 64, seed=9)
+    rng = np.random.default_rng(9)
+    bids = jnp.take_along_axis(tables, (fills // 128)[:, None], axis=1)[:, 0]
+    bids = bids.at[1:3].set(0)                     # two idle slots → trash
+    offs = (fills % 128).at[1:3].set(0)
+    rows = jax.tree.map(
+        lambda a: jnp.asarray(rng.integers(-100, 100, (
+            a.shape[0], 4, a.shape[2], 1) + a.shape[4:]), a.dtype), k_pool)
+    got = jax.jit(model_lib.cache_append_rows)(k_pool, rows, bids, offs)
+    want = jax.tree.map(
+        lambda p, r: p.at[:, bids, :, offs].set(
+            jnp.moveaxis(r[:, :, :, 0], 1, 0)), k_pool, rows)
+    for g, w, r in zip(*map(jax.tree.leaves, (got, want, rows))):
+        np.testing.assert_array_equal(np.asarray(g)[:, 1:],
+                                      np.asarray(w)[:, 1:])
+        np.testing.assert_array_equal(np.asarray(g)[:, 0, :, 0],
+                                      np.asarray(r)[:, 2, :, 0])

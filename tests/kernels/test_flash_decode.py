@@ -139,3 +139,120 @@ def test_paged_bitwise_equals_dense_int8():
                                   jnp.asarray(tables), lens,
                                   interpret=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+# ---------------------------------------------------------------------------
+# Paged walk with the new token's row folded in (the composed decode
+# route's kernel): vs the gathered-einsum reference
+# ---------------------------------------------------------------------------
+
+from megatron_llm_tpu.ops.kv_quant import (  # noqa: E402
+    cache_update,
+    dequantize_cache,
+    quantize_rows,
+)
+
+# heads, kv heads, head width, slots
+_FALCON = (71, 1, 64, 16)   # MQA: one query group of 71 rows, g_pad 72
+_GQA128 = (32, 8, 128, 6)   # the edge fills alone: interpret mode is slow
+
+
+def _ragged_fills(b, t, bk, rng):
+    """Empty, one row, one short of a block, a block boundary, one past
+    it, a full table (the new row is the table's last), random rest."""
+    edge = [0, 1, bk - 1, bk, bk + 1, t * bk - 1]
+    rest = rng.integers(0, t * bk, max(0, b - len(edge))).tolist()
+    return np.asarray((edge + rest)[:b], np.int32)
+
+
+@pytest.mark.parametrize("pool", ["fp32", "bf16", "int8"])
+@pytest.mark.parametrize("geometry", [_FALCON, _GQA128],
+                         ids=["falcon71x64mqa", "gqa32x128kv8"])
+def test_paged_new_row_matches_gathered_einsum(geometry, pool):
+    """Slots × a 16-block table of 128-row blocks, shuffled physical
+    ids, trash and unowned blocks holding large finite values: the paged
+    kernel reading ``fills`` rows through the tables, the new token's row
+    handed over beside the pool, equals the masked einsum over the
+    gathered dense view into which that row was written first."""
+    heads, kv, d, b = geometry
+    t, bk = 16, 128
+    rng = np.random.default_rng(heads)
+    dt = jnp.float32 if pool == "fp32" else jnp.bfloat16
+    tol = 2e-5 if pool == "fp32" else 0.03
+    q = jnp.asarray(rng.normal(size=(b, heads, d)), dt)
+    fills = _ragged_fills(b, t, bk, rng)
+    # a table names only the blocks its slot has reached; the rest of
+    # the row is the trash block, as the engine's allocator leaves it
+    tables = _shuffled_tables(b, t, rng)
+    owned = np.arange(t)[None, :] <= (fills[:, None] // bk)
+    tables = np.where(owned, tables, 0).astype(np.int32)
+    k_new = jnp.asarray(rng.normal(size=(b, kv, 1, d)), dt)
+    v_new = jnp.asarray(rng.normal(size=(b, kv, 1, d)), dt)
+    dense_shape = (b, kv, t * bk, d)
+
+    if pool == "int8":
+        kq = rng.integers(-127, 128, dense_shape).astype(np.int8)
+        vq = rng.integers(-127, 128, dense_shape).astype(np.int8)
+        ks = rng.uniform(0.01, 0.1, dense_shape[:3]).astype(np.float32)
+        vs = rng.uniform(0.01, 0.1, dense_shape[:3]).astype(np.float32)
+        kq_p, vq_p = _paged_layout([kq, vq], bk, tables, 127)
+        ks_p, vs_p = _paged_layout([ks, vs], bk, tables, 1e4)
+        kn, vn = quantize_rows(k_new), quantize_rows(v_new)
+        got = flash_decode_paged_int8(
+            q, kq_p, ks_p, vq_p, vs_p, jnp.asarray(tables),
+            jnp.asarray(fills),
+            new_rows=(dequantize_cache(kn), dequantize_cache(vn)),
+            interpret=True)
+        k_dense = {"q": jnp.asarray(kq), "scale": jnp.asarray(ks)}
+        v_dense = {"q": jnp.asarray(vq), "scale": jnp.asarray(vs)}
+    else:
+        k = rng.normal(size=dense_shape).astype(np.float32)
+        v = rng.normal(size=dense_shape).astype(np.float32)
+        k_dense, v_dense = jnp.asarray(k, dt), jnp.asarray(v, dt)
+        k_p, v_p = _paged_layout([np.asarray(k_dense), np.asarray(v_dense)],
+                                 bk, tables, 1e4)
+        got = flash_decode_paged(
+            q, k_p, v_p, jnp.asarray(tables), jnp.asarray(fills),
+            new_rows=(k_new, v_new), interpret=True)
+    assert np.isfinite(np.asarray(got, np.float32)).all()
+
+    # reference: one spare block behind the table so a full table's new
+    # row has a place in the dense view; the einsum masks everything else
+    pad = lambda a: jnp.pad(  # noqa: E731
+        a, ((0, 0), (0, 0), (0, bk)) + ((0, 0),) * (a.ndim - 3))
+    f = jnp.asarray(fills)
+    k_ref = cache_update(jax.tree.map(pad, k_dense), k_new, f)
+    v_ref = cache_update(jax.tree.map(pad, v_dense), v_new, f)
+    want = decode_attention(q[:, None], k_ref, v_ref, f)[:, 0]
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=tol, atol=tol)
+    # an empty slot attends its own row alone: the output is that V row
+    vn_seen = dequantize_cache(vn) if pool == "int8" else v_new
+    np.testing.assert_allclose(
+        np.asarray(got[0], np.float32).reshape(kv, heads // kv, d),
+        np.broadcast_to(np.asarray(vn_seen[0], np.float32),
+                        (kv, heads // kv, d)), rtol=tol, atol=tol)
+
+
+def test_paged_whole_pool_layer_index_equals_layer_view():
+    """``layer=`` addresses one layer of the whole [L, ...] pool through
+    the index maps: bitwise what the kernel returns for that layer's
+    view handed over alone."""
+    heads, kv, d, _ = _FALCON
+    b, t, bk, layers = 4, 4, 128, 3
+    rng = np.random.default_rng(9)
+    q = jnp.asarray(rng.normal(size=(b, heads, d)), jnp.float32)
+    pools = [jnp.asarray(rng.normal(size=(layers, 1 + b * t, kv, bk, d)),
+                         jnp.float32) for _ in range(2)]
+    rows = [jnp.asarray(rng.normal(size=(b, kv, 1, d)), jnp.float32)
+            for _ in range(2)]
+    tables = jnp.asarray(_shuffled_tables(b, t, rng))
+    fills = jnp.asarray([0, 129, 300, 511], jnp.int32)
+    for layer in (0, 2):
+        want = flash_decode_paged(q, pools[0][layer], pools[1][layer],
+                                  tables, fills, new_rows=rows,
+                                  interpret=True)
+        got = flash_decode_paged(q, *pools, tables, fills, new_rows=rows,
+                                 layer=jnp.int32(layer), interpret=True)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

@@ -13,7 +13,9 @@ Whole-step compiles (15-25 s each) live in ``chip_smoke.py``'s own
 planning pass, not here.
 """
 
+import contextlib
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 
@@ -120,12 +122,62 @@ def test_flash_attention_fwd_bwd(topo, heads, kv, d, segs):
     assert text.count("tpu_custom_call") >= 3  # fwd, dq, dkv
 
 
+def _no_copy_of(text, shape):
+    """No instruction of the compiled module other than a parameter, a
+    tuple access, a relabelling ``bitcast`` or an in-place
+    ``dynamic-update-slice`` produces ``shape`` (an HLO shape prefix such
+    as ``bf16[2,513,1,128,64]``)."""
+    bad = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (\S+) ([\w-]+)\(", line)
+        if m and m.group(2).startswith(shape) and m.group(3) not in (
+                "parameter", "get-tuple-element", "bitcast",
+                "dynamic-update-slice"):
+            bad.append(f"{m.group(1)}: {m.group(3)} {m.group(2)}")
+    assert not bad, bad
+
+
+# (heads, KV heads, head width) the composed paged route serves: Falcon-7B
+# (71 query heads over one KV head of width 64), Falcon-40B's GQA at that
+# width, and Llama-2-7B, whose layers the fused kernel's VMEM budget declines
+_POOL_GEOMETRY = {"mqa64": (71, 1, 64), "gqa64": (128, 8, 64),
+                  "mha128": (32, 32, 128)}
+
+
 @pytest.mark.parametrize("variant", ["dense", "dense_int8", "paged",
-                                     "paged_int8"])
+                                     "paged_int8", "paged_pool_mqa64",
+                                     "paged_pool_int8_mqa64",
+                                     "paged_pool_gqa64",
+                                     "paged_pool_mha128"])
 def test_flash_decode(topo, variant):
     b, h, kv, d, max_len, bk = 8, 32, 32, 128, 2048, 128
     nb, t = 64, max_len // bk
     one = SingleDeviceSharding(topo.devices[0])
+    if variant.startswith("paged_pool"):
+        # the composed decode route's call, 16 slots × 16 blocks: the
+        # whole [L, ...] pool, a traced layer index, the new token's rows
+        # beside it.  At head width 64 the pool lies with its 128-row
+        # dimension as lanes, and the kernel must take it as it lies: no
+        # copy of the pool
+        h, kv, d = _POOL_GEOMETRY[variant.rsplit("_", 1)[1]]
+        b, layers, nb = 16, 2, 513
+        int8 = "int8" in variant
+        pool = [_sds((layers, nb, kv, bk, d), jnp.int8 if int8 else BF16)]
+        if int8:
+            pool.append(_sds((layers, nb, kv, bk), jnp.float32))
+        rows = _sds((b, kv, 1, d), jnp.float32 if int8 else BF16)
+        call = fd.flash_decode_paged_int8 if int8 else fd.flash_decode_paged
+
+        def fn(q, *rest):
+            *leaves, tb, n, kn, vn, layer = rest
+            return call(q, *leaves, tb, n, new_rows=(kn, vn),
+                        layer=layer[0], interpret=False)
+
+        text = _compile(fn, (_sds((b, h, d)), *pool, *pool,
+                             _sds((b, t), jnp.int32), _sds((b,), jnp.int32),
+                             rows, rows, _sds((1,), jnp.int32)), one)
+        _no_copy_of(text, f"{'s8' if int8 else 'bf16'}[{layers},{nb},")
+        return
     q, lens = _sds((b, h, d)), _sds((b,), jnp.int32)
     if variant == "dense":
         fn = lambda q, k, v, n: fd.flash_decode(  # noqa: E731
@@ -275,22 +327,93 @@ def _tp2_mesh(topo):
                                devices=topo.devices[:2])
 
 
-def test_sharded_paged_decode_attention(topo, monkeypatch):
+@pytest.mark.parametrize("h,kv,d", [(32, 32, 128), (128, 8, 64)],
+                         ids=["mha128", "falcon40b_gqa64"])
+def test_sharded_paged_decode_attention(topo, monkeypatch, h, kv, d):
     mesh = _tp2_mesh(topo)
     monkeypatch.setattr(attn_ops, "_backend", lambda: "tpu")
     monkeypatch.setattr(kernels, "default_interpret", lambda: False)
-    b, h, kv, d, bk, nb, t = 8, 32, 32, 128, 128, 64, 16
+    b, bk, nb, t = 8, 128, 64, 16
+    assert attn_ops.paged_decode_route(1, h, kv, d, bk, mesh)
+    # MQA's one KV head is nothing tp can split: the route declines
+    assert not attn_ops.paged_decode_route(1, 71, 1, 64, bk, mesh)
     heads = NamedSharding(mesh, P(None, None, "tp", None))
-    pool = NamedSharding(mesh, P(None, "tp", None, None))
+    pool = NamedSharding(mesh, P(None, None, "tp", None, None))
+    rows = NamedSharding(mesh, P(None, "tp", None, None))
     rep = NamedSharding(mesh, P())
+    layers = 4
 
-    def fn(q, k, v, tables, fills):
+    def fn(q, k, v, tables, fills, k_new, v_new, layer):
         with mesh_lib.use_mesh(mesh):
-            return attn_ops.paged_decode_attention(q, k, v, tables, fills)
+            return attn_ops.paged_decode_attention(q, k, v, tables, fills,
+                                                   k_new, v_new, layer)
 
-    _compile(fn, (_sds((b, 1, h, d)), _sds((nb, kv, bk, d)),
-                  _sds((nb, kv, bk, d)), _sds((b, t), jnp.int32),
-                  _sds((b,), jnp.int32)), (heads, pool, pool, rep, rep))
+    text = _compile(
+        fn, (_sds((b, 1, h, d)), _sds((layers, nb, kv, bk, d)),
+             _sds((layers, nb, kv, bk, d)), _sds((b, t), jnp.int32),
+             _sds((b,), jnp.int32), _sds((b, kv, 1, d)),
+             _sds((b, kv, 1, d)), _sds((), jnp.int32)),
+        (heads, pool, pool, rep, rep, rows, rows, rep))
+    _no_copy_of(text, f"bf16[{layers},{nb},{kv // 2},{bk},{d}]")
+
+
+@pytest.mark.parametrize("size,tp", [("7b", 0), ("40b", 2)],
+                         ids=["falcon7b_one_chip", "falcon40b_width_tp2"])
+def test_composed_decode_step_touches_only_live_kv(topo, monkeypatch, size,
+                                                   tp):
+    """The engine's decode executable on the composed paged route, at 2
+    layers of Falcon-7B width on one chip (MQA) and of Falcon-40B width
+    under a tp=2 mesh (GQA 8 x 64, the pool split over its KV heads),
+    over 16 slots × a 16-block table: the paged kernel is in it, and
+    nothing but the pool arguments and the in-place row writes has the
+    pool's shape or the shape of the gathered dense view
+    ``[L, S·T, kv, bk, d]`` — the re-layouts a row scatter draws and the
+    gather are gone."""
+    from megatron_llm_tpu.config import falcon_config
+    from megatron_llm_tpu.models import sharding as sharding_lib
+    from megatron_llm_tpu.serving import engine as engine_lib
+
+    monkeypatch.setattr(attn_ops, "_backend", lambda: "tpu")
+    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
+    layers, slots, t, bk = 2, 16, 16, 128
+    # a pool too large for XLA to stage in fast memory, as the 32-layer
+    # pool is: else the module copies it there and back
+    nb = 1 + 16 * (slots * t + 256)
+    cfg = falcon_config(size, num_layers=layers, attention_impl="flash")
+    params = jax.eval_shape(
+        lambda k: model_lib.init_params(k, cfg), jax.random.key(0))
+    pools = jax.eval_shape(lambda: model_lib.init_kv_pool(cfg, nb, bk))
+    if tp:
+        par = ParallelConfig(tensor_parallel=tp)
+        mesh = mesh_lib.build_mesh(par, devices=topo.devices[:tp])
+        at = lambda spec: NamedSharding(mesh, spec)  # noqa: E731
+        param_at = jax.tree.map(
+            at, sharding_lib.serving_param_specs(cfg, par))
+        pool_at = tuple(map(at, sharding_lib.kv_pool_specs(cfg, mesh)))
+        rest_at = at(P())
+    else:
+        mesh = None
+        rest_at = SingleDeviceSharding(topo.devices[0])
+        param_at = jax.tree.map(lambda _: rest_at, params)
+        pool_at = (rest_at, rest_at)
+    place = lambda tree, where: jax.tree.map(  # noqa: E731
+        lambda a, w: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=w),
+        tree, where)
+    vec = lambda dtype, *s: place(  # noqa: E731
+        _sds(s or (slots,), dtype), rest_at)
+    i32, f32 = jnp.int32, jnp.float32
+    assert model_lib.paged_decode_eligible(cfg, pools[0], mesh=mesh)
+    with mesh_lib.use_mesh(mesh) if tp else contextlib.nullcontext():
+        text = engine_lib._decode_donated.lower(
+            cfg, place(params, param_at), *place(pools, pool_at),
+            vec(i32, slots, t), vec(i32), vec(i32), vec(i32), vec(i32),
+            vec(jnp.bool_), vec(f32), vec(i32), vec(f32),
+            use_fused=False).compile().as_text()
+    assert "tpu_custom_call" in text
+    kv = cfg.kv_heads // max(tp, 1)             # a device's share
+    _no_copy_of(text, f"bf16[{layers},{nb},{kv},{bk},64]")
+    _no_copy_of(text, f"bf16[{layers},{slots * t},{kv},{bk},64]")
+    assert text.count("dynamic-update-slice(") >= 2 * slots
 
 
 def test_sharded_flash_attention_fwd_bwd(topo, monkeypatch):

@@ -345,3 +345,99 @@ def test_pause_resume_with_pipeline(tiny):
     finally:
         engine.shutdown()
     assert r.tokens == _reference(cfg, params, prompt, 16)
+
+
+# ---------------------------------------------------------------------------
+# The composed decode step's third route: KV read through the block
+# tables inside the paged attention kernel (forward_cached_paged)
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def tiny_d64():
+    """A geometry the paged route accepts: head width 64, MQA; the engines
+    below give it pool blocks of 128 rows."""
+    cfg = tiny_config(num_layers=2, vocab_size=64, hidden_size=128,
+                      num_attention_heads=2, num_kv_heads=1,
+                      max_position_embeddings=256)
+    return cfg, model_lib.init_params(jax.random.key(3), cfg)
+
+
+def _paged_geometry_requests(cfg):
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(1, cfg.vocab_size,
+                            int(rng.integers(3, 12))).tolist()
+               for _ in range(4)]
+    return prompts, [int(rng.integers(4, 10)) for _ in range(4)]
+
+
+def _paged_geometry_run(cfg, params, **overrides):
+    """Four ragged greedy requests through a 4-slot engine with 128-row
+    blocks → (results, metrics snapshot, engine_step routes, Prometheus
+    text, final pools)."""
+    from megatron_llm_tpu.obs import REGISTRY
+
+    prompts, max_news = _paged_geometry_requests(cfg)
+    # not pipelined: a pipelined engine's last, masked step leaves a row
+    # behind or not as the threads fall, and the pools are compared below
+    engine = _engine(cfg, params, max_seq_len=256, kv_block_size=128,
+                     pipeline_decode=False, **overrides).start()
+    results = _run_batch(engine, prompts, max_news)
+    routes = [e["args"]["route"]
+              for e in engine.trace.chrome_trace()["traceEvents"]
+              if e["name"] == "engine_step"]
+    pools = jax.tree.map(np.asarray,
+                         (engine.slots.k_pool, engine.slots.v_pool))
+    return (results, engine.metrics.snapshot(), routes,
+            REGISTRY.prometheus_text(), pools, engine)
+
+
+def test_cpu_decode_keeps_the_gather_route(tiny_d64):
+    """On the CPU backend the route predicate declines (platform), so a
+    geometry the paged kernel would take still decodes over the gathered
+    dense view: every step counts and is traced as ``fallback``, and the
+    tokens are the one-shot trajectory bit for bit."""
+    cfg, params = tiny_d64
+    results, snap, routes, prom, _, engine = _paged_geometry_run(cfg, params)
+    assert not engine._paged_decode and engine._decode_route == "fallback"
+    assert routes and set(routes) == {"fallback"}
+    assert snap["paged_steps"] == 0 == snap["fused_steps"]
+    assert snap["fallback_steps"] >= snap["decode_iterations"] > 0
+    assert snap["paged_steps_by_precision"] == {"fp32": 0}
+    assert "serving_paged_steps_total 0" in prom
+    for p, n, r in zip(*_paged_geometry_requests(cfg), results):
+        assert r.finish_reason == "length"
+        assert r.tokens == _reference(cfg, params, p, n)
+
+
+@pytest.mark.parametrize("spec_draft_len,route", [(0, "paged"),
+                                                  (2, "fallback")])
+def test_engine_reports_the_paged_route(tiny_d64, monkeypatch,
+                                        spec_draft_len, route):
+    """With the backend reported as a TPU (the kernel itself runs in
+    interpret mode) the engine takes the paged route — every decode step
+    says so in its ``engine_step`` span, in ``paged_steps`` and in
+    ``/metrics`` — and commits the tokens the gather route commits, with
+    the same rows in the pool.  An engine that speculates keeps the
+    gather route for decode and verify alike."""
+    from megatron_llm_tpu.ops import attention as attn_ops
+
+    cfg, params = tiny_d64
+    want, _, _, _, want_pools, _ = _paged_geometry_run(
+        cfg, params, spec_draft_len=spec_draft_len)
+    monkeypatch.setattr(attn_ops, "_backend", lambda: "tpu")
+    got, snap, routes, prom, pools, engine = _paged_geometry_run(
+        cfg, params, spec_draft_len=spec_draft_len)
+    assert engine._decode_route == route
+    assert [r.tokens for r in got] == [r.tokens for r in want]
+    plain = [r for r in routes if not r.startswith("spec")]
+    assert plain and set(plain) == {route}
+    other = "fallback" if route == "paged" else "paged"
+    assert snap[f"{other}_steps"] == 0
+    assert snap[f"{route}_steps"] >= snap["decode_iterations"] > 0
+    assert (f'serving_{route}_steps_by_precision_total{{precision="fp32"}} '
+            f'{snap[f"{route}_steps"]}') in prom
+    # live rows agree to float32 rounding (the kernel and the einsum sum
+    # in different orders); the trash block holds whatever idle slots wrote
+    for a, b in zip(jax.tree.leaves(want_pools), jax.tree.leaves(pools)):
+        np.testing.assert_allclose(b[:, 1:], a[:, 1:], rtol=1e-4, atol=1e-5)

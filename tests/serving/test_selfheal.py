@@ -174,7 +174,7 @@ def test_supervisor_rebuilds_crashed_replica(tiny):
 
 def test_watchdog_kills_wedged_replica(tiny):
     cfg, params = tiny
-    specs = [dict(prompt=p, max_new_tokens=10, seed=i, use_eos_stop=False)
+    specs = [dict(prompt=p, max_new_tokens=24, seed=i, use_eos_stop=False)
              for i, p in enumerate(_prompts(cfg, 4, seed=2))]
     ref = _reference_tokens(cfg, params, specs)
     router = build_cluster(
@@ -183,7 +183,13 @@ def test_watchdog_kills_wedged_replica(tiny):
     sup = _supervise(router, hang_timeout_s=0.4)
     try:
         handles = router.submit_many(specs)
-        time.sleep(0.1)
+        # in flight, and far from done: a fixed sleep here let warm
+        # executables finish every request before the hang was armed
+        deadline = time.monotonic() + 60
+        while time.monotonic() < deadline and not any(
+                r.engine.metrics.snapshot()["decode_iterations"]
+                for r in router.replicas):
+            time.sleep(0.001)
         # wedge one dispatch: thread stays alive, the iteration
         # heartbeat goes stale — only the watchdog can see this
         chaos().hang_at("serve-dispatch", seconds=2.0)
