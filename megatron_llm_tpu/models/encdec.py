@@ -164,20 +164,22 @@ def bert_forward(cfg: ModelConfig, params: Params, tokens: jax.Array,
 
 
 def bert_loss(cfg: ModelConfig, params: Params, batch: dict,
-              rng=None, deterministic: bool = True):
+              rng=None, deterministic: bool = True, mean=masked_mean_loss):
     """Masked-LM + NSP loss (reference bert_model.py post_language_model_
-    processing + pretrain_bert.py forward_step)."""
+    processing + pretrain_bert.py forward_step).  Every mean over the batch
+    goes through ``mean``: the train step hands a rank's slice of a
+    microbatch its own (training/step.py:BatchAxisSum)."""
     mlm_logits, bin_logits = bert_forward(
         cfg, params, batch["tokens"], batch["pad_mask"],
         batch.get("tokentype_ids"), rng, deterministic)
     lm = cross_entropy(mlm_logits, batch["labels"],
                        vocab_size=cfg.vocab_size)
-    lm_loss = masked_mean_loss(lm, batch["loss_mask"])
+    lm_loss = mean(lm, batch["loss_mask"])
     total = lm_loss
     if "is_random" in batch:
         nsp = cross_entropy(bin_logits[:, None, :],
                             batch["is_random"][:, None], vocab_size=2)
-        total = total + jnp.mean(nsp)
+        total = total + mean(nsp, jnp.ones_like(nsp))
     return total
 
 
@@ -340,13 +342,13 @@ def t5_forward(cfg: ModelConfig, params: Params,
 
 
 def t5_loss(cfg: ModelConfig, params: Params, batch: dict,
-            rng=None, deterministic: bool = True):
+            rng=None, deterministic: bool = True, mean=masked_mean_loss):
     logits = t5_forward(cfg, params, batch["enc_tokens"],
                         batch["dec_tokens"], batch.get("enc_pad_mask"),
                         batch.get("dec_pad_mask"), rng, deterministic)
     per_tok = cross_entropy(logits, batch["labels"],
                             vocab_size=cfg.vocab_size)
-    return masked_mean_loss(per_tok, batch["loss_mask"])
+    return mean(per_tok, batch["loss_mask"])
 
 
 # ---------------------------------------------------------------------------

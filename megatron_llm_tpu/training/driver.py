@@ -48,7 +48,8 @@ from ..utils.timers import Timers
 from ..utils.writers import NullWriter, build_writer
 from . import optimizer as opt_lib
 from .microbatches import build_num_microbatches_calculator
-from .step import TrainState, init_train_state, make_train_step
+from .step import (TrainState, batch_axes, init_train_state,
+                   make_train_step)
 
 PyTree = Any
 
@@ -182,6 +183,48 @@ def setup_train_state(
         step_fn = make_train_step(cfg, mesh, state_sharding, batch_sharding)
     return TrainingArtifacts(cfg, mesh, state, state_sharding, batch_sharding,
                              step_fn, pspecs)
+
+
+def grad_collectives_of(art: TrainingArtifacts,
+                        global_batch_size: int) -> Optional[dict]:
+    """What ``art.step_fn``, compiled for ``global_batch_size``, does with
+    the gradients across the ranks that split the batch
+    (obs/collectives.py:grad_collectives): how many reductions a step run
+    inside the microbatch loop and after it, their kind and bytes.  None
+    where nothing splits the batch, and for the pipelined schedule (the
+    microbatch loop is the pipeline there).  The step is compiled under
+    the mesh context the train loop calls it in, for the batch the loop
+    will hand it: jit's cache is keyed on both, so the first train step
+    finds this very executable and the reading costs nothing but the
+    compile's being done early (another batch structure, extra keys say,
+    compiles once more)."""
+    from ..obs import collectives
+
+    mesh, cfg = art.mesh, art.cfg
+    axes = batch_axes(mesh, art.batch_sharding.spec)
+    if not axes or cfg.parallel.pipeline_parallel > 1:
+        return None
+    accum = global_batch_size // (
+        cfg.train.micro_batch_size * cfg.parallel.data_parallel)
+    shape = (accum, global_batch_size // accum, cfg.train.seq_length)
+    batch = {k: jax.ShapeDtypeStruct(shape, dt, sharding=art.batch_sharding)
+             for k, dt in (("tokens", jnp.int32), ("labels", jnp.int32),
+                           ("loss_mask", jnp.float32))}
+    state = jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        art.state, art.state_sharding)
+    with mesh:
+        hlo = art.step_fn.lower(
+            state, batch,
+            jax.eval_shape(jax.random.key, 0)).compile().as_text()
+    try:
+        return collectives.grad_collectives(
+            hlo, dict(mesh.shape), axes,
+            collectives.param_shard_shapes(art.state.params,
+                                           art.state_sharding.params), accum)
+    except Exception as e:  # noqa: BLE001
+        # an HLO form the reader has not met costs a log line, not the run
+        return {"unreadable": f"{type(e).__name__}: {e}"}
 
 
 def _shard_train_state(cfg: RuntimeConfig, mesh, params: PyTree,
@@ -392,6 +435,8 @@ class _LogState:
         self.anomaly_total = 0
         self.tokens = 0
         self.t_start = time.perf_counter()
+        # set-up facts that ride on the first log_window event
+        self.once: dict = {}
 
     def reset_window(self):
         self.total_loss = 0.0
@@ -479,7 +524,8 @@ def training_log(cfg: RuntimeConfig, log: _LogState, metrics: dict,
         tokens_per_sec=round(tokens_per_sec, 3),
         step_time_s=round(per_iter, 6), learning_rate=lr,
         grad_norm=round(grad_norm, 6), skipped=log.skipped_total,
-        anomalies=log.anomaly_total)
+        anomalies=log.anomaly_total, **log.once)
+    log.once = {}
     if writer is not None:
         if "moe_dropped_frac" in metrics:
             writer.add_scalar("train/moe_dropped_frac",
@@ -688,6 +734,12 @@ def pretrain(
 
     current_gbs = calculator.get_current_global_batch_size()
     train_iter = make_train_iter(consumed_samples, current_gbs)
+    first_event = {}      # set-up facts for the first log_window event
+    grad_reading = grad_collectives_of(art, current_gbs)
+    if grad_reading is not None:
+        print_rank_0(" dp_grad_collectives: " + " ".join(
+            f"{k}={v}" for k, v in grad_reading.items()))
+        first_event["dp_grad_collectives"] = grad_reading
 
     eval_step = None
     eval_flatten = True
@@ -710,6 +762,7 @@ def pretrain(
 
     base_rng = jax.random.key(cfg.train.seed)
     log = _LogState()
+    log.once = first_event
     skip_set = set(cfg.train.skip_iters)
     exit_reason = None
     # Traces are taken on request ("the next n steps into dir",

@@ -5,18 +5,27 @@ Reference mapping (megatron/training.py:393-459 ``train_step``):
 - zero grad buffer → fp32 grad accumulator initialized per step
 - forward_backward schedule (no pipelining) → ``lax.scan`` over microbatches
   accumulating fp32 grads (the schedule variants live in parallel/pipeline.py)
-- ``optimizer.reduce_model_grads``'s DP all-reduce → implicit: the batch is
-  sharded over 'dp', params are replicated over 'dp', so GSPMD emits the
-  gradient psum (or reduce-scatter under ZeRO-1 state sharding)
+- ``optimizer.reduce_model_grads``'s DP all-reduce → once a step, after the
+  microbatch loop (``BatchAxisSum``): the loop runs manual over the mesh
+  axes that split the batch (dp, cp), every rank adds its own microbatches'
+  gradients up in fp32, and each leaf is then reduce-scattered into the
+  distributed optimizer's shard of it (all-reduced without one).  Left to
+  GSPMD the loop's carry must hold a reduced gradient, so every leaf is
+  all-reduced in every microbatch (17.9 % of the Falcon-40B dp2 × tp2
+  step, PERF.md §6 PR 28).  One microbatch, a pipelined schedule, and a
+  loss that couples the ranks' samples (MoE's auxiliary loss, a custom
+  ``loss_fn`` that takes no ``mean``) keep GSPMD's order
 - unscale → check inf → clip → adam → copy params
   (optimizer/optimizer.py:407-466) → explicit jnp chain below, with the
   skipped-iteration semantics on non-finite grads
-- loss averaging across DP for logging (megatron/utils.py:70) → jnp.mean on
-  the dp-sharded per-microbatch losses
+- loss averaging across DP for logging (megatron/utils.py:70) → the masked
+  mean over the whole dp-sharded microbatch (under ``BatchAxisSum`` the psum
+  of the ranks' shares of it)
 """
 
 from __future__ import annotations
 
+import inspect
 from typing import Any, NamedTuple, Optional
 
 import jax
@@ -96,7 +105,7 @@ def zigzag_permute_batch(cfg: RuntimeConfig, batch: dict) -> dict:
 
 def compute_loss(cfg: RuntimeConfig, params, batch: dict, rng=None,
                  deterministic: bool = True, rope=None,
-                 return_moe_stats: bool = False):
+                 return_moe_stats: bool = False, mean=None):
     """Forward + masked LM loss for one microbatch.
 
     ``batch``: tokens [b,s], labels [b,s], loss_mask [b,s] (float weights —
@@ -104,13 +113,18 @@ def compute_loss(cfg: RuntimeConfig, params, batch: dict, rng=None,
     finetune.py:148-161), optional position_ids/segment_ids.
     ``return_moe_stats`` additionally returns the layer-summed MoE stats
     dict (models/moe.py) for routing observability.
+
+    ``mean(per_token, mask)`` stands in for ``masked_mean_loss`` where
+    the batch is one rank's slice of the microbatch, already in its cp
+    layout (``BatchAxisSum``: the rank's share of the microbatch's mean).
     """
     # Fused linear+CE head: streams the unembedding matmul over vocab
     # blocks with an online logsumexp so the [b, s, vocab] fp32 logits are
     # never materialized — a large HBM saving when the head dominates.
     # Gated off under tp (vocab-sharded CE runs via GSPMD on the plain
     # path) and cp (flattening the cp-sharded seq would reshard).
-    batch = zigzag_permute_batch(cfg, batch)
+    if mean is None:
+        batch = zigzag_permute_batch(cfg, batch)
 
     use_fused = (cfg.model.fused_lm_head
                  and cfg.parallel.tensor_parallel == 1
@@ -143,7 +157,7 @@ def compute_loss(cfg: RuntimeConfig, params, batch: dict, rng=None,
         per_token = cross_entropy(
             logits, batch["labels"], vocab_size=cfg.model.vocab_size
         )
-    loss = masked_mean_loss(per_token, batch["loss_mask"])
+    loss = (mean or masked_mean_loss)(per_token, batch["loss_mask"])
     if cfg.model.num_experts > 0:
         from ..models.moe import aux_loss_of
 
@@ -153,22 +167,207 @@ def compute_loss(cfg: RuntimeConfig, params, batch: dict, rng=None,
     return loss
 
 
+def spec_axes(spec) -> set:
+    """Mesh axes a PartitionSpec names."""
+    return {a for part in spec if part is not None
+            for a in ((part,) if isinstance(part, str) else part)}
+
+
+def batch_axes(mesh, batch_spec) -> tuple:
+    """The mesh axes that split the batch: those ``batch_spec`` names, of
+    size > 1, in the mesh's order."""
+    named = spec_axes(batch_spec)
+    return tuple(a for a in mesh.axis_names
+                 if a in named and mesh.shape[a] > 1)
+
+
+class BatchAxisSum(NamedTuple):
+    """Where the gradient's sum over the ranks that split the batch is
+    taken: after the microbatch loop, once a step.
+
+    ``axes`` are the mesh axes of size > 1 that the batch sharding names
+    (dp, and cp when it is on).  A parameter leaf whose spec names none of
+    them is replicated there, so every such rank holds a partial gradient
+    of it.  ``wrap`` runs the microbatch loop manual over ``axes`` (tp, ep
+    and sp stay GSPMD's): each rank adds its own partial gradients up in
+    fp32 with no gradient crossing ``axes`` in the loop, and after it each
+    leaf is reduced once — a reduce-scatter onto the dim where
+    ``grad_specs`` (the optimizer moments' specs: ``zero1_specs`` under the
+    distributed optimizer, the parameter's own otherwise) names the axis,
+    an all-reduce where it names none.  Left to GSPMD, the loop's carry
+    has to hold a reduced gradient, which costs an all-reduce of every
+    leaf in every microbatch.
+
+    A rank sees its slice of a microbatch only, so the loss has to be one
+    that cuts into rank shares: every mean it takes over the batch goes
+    through the ``mean`` it is handed (``share_of_mean``), and samples meet
+    nowhere else.  ``compute_loss`` is such a loss; a custom ``loss_fn``
+    says so by taking a ``mean`` keyword (BERT, T5).
+    """
+
+    mesh: Any
+    axes: tuple
+    batch_spec: Any           # PartitionSpec of [accum, micro_batch, seq]
+    param_specs: PyTree
+    grad_specs: PyTree
+
+    @classmethod
+    def of(cls, cfg: RuntimeConfig, mesh, state_sharding, batch_sharding,
+           loss_fn) -> Optional["BatchAxisSum"]:
+        """From what the step is compiled with, or None where the loop
+        stays GSPMD's: nothing splits the batch; the pipelined schedule
+        (its own manual region); a loss that couples the ranks' samples —
+        a ``loss_fn`` that takes no ``mean`` (ICT's in-batch negatives),
+        or one whose sequences the batch axes cut (the cp layout is
+        ``compute_loss``'s business), and MoE, whose auxiliary loss and
+        capacity are functions of the whole microbatch's routing."""
+        if (mesh is None or state_sharding is None
+                or not hasattr(batch_sharding, "spec")
+                or cfg.parallel.pipeline_parallel > 1
+                or cfg.model.num_experts > 0):
+            return None
+        spec = batch_sharding.spec
+        axes = batch_axes(mesh, spec)
+        if loss_fn is not None and (
+                "mean" not in inspect.signature(loss_fn).parameters
+                or batch_axes(mesh, tuple(spec)[2:])):
+            return None
+        if not axes:
+            return None
+
+        def specs(shardings):
+            return jax.tree.map(lambda s: s.spec, shardings)
+
+        return cls(mesh, axes, spec, specs(state_sharding.params),
+                   specs(state_sharding.opt.mu))
+
+    def _manual(self, spec, keep=None):
+        """``spec`` with only this region's manual axes left in it."""
+        keep = set(self.axes) if keep is None else keep
+
+        def part(p):
+            names = tuple(a for a in ((p,) if isinstance(p, str)
+                                      else (p or ())) if a in keep)
+            return names[0] if len(names) == 1 else (names or None)
+
+        return jax.sharding.PartitionSpec(*(part(p) for p in spec))
+
+    def _scatter_dims(self, pspec, gspec) -> dict:
+        """axis -> dim of a leaf on which that axis' sum is scattered."""
+        todo = [a for a in self.axes if a not in spec_axes(pspec)]
+        return {a: list(gspec).index(a) for a in todo if a in list(gspec)}
+
+    def share_of_mean(self, per_sample, weights):
+        """``masked_mean_loss`` on one rank's slice: its weighted sum over
+        the whole microbatch's weight — the one batch-axis collective left
+        in the loop, a scalar.  The ranks' shares add up to the mean."""
+        weights = weights.astype(per_sample.dtype)
+        return jnp.sum(per_sample * weights) / jnp.maximum(
+            jax.lax.psum(jnp.sum(weights), self.axes), 1.0)
+
+    def wrap(self, loop):
+        """``loop(params, batch, rng, mean) -> (grad sums, loss sum)`` over
+        one rank's slice of every microbatch, then the one sum over the
+        ranks; takes and returns global arrays."""
+        P = jax.sharding.PartitionSpec
+        is_spec = lambda x: isinstance(x, P)  # noqa: E731
+
+        def reduce_leaf(g, pspec, gspec):
+            scatter = self._scatter_dims(pspec, gspec)
+            for axis, dim in scatter.items():
+                g = jax.lax.psum_scatter(g, axis, scatter_dimension=dim,
+                                         tiled=True)
+            rest = tuple(a for a in self.axes
+                         if a not in spec_axes(pspec) and a not in scatter)
+            return jax.lax.psum(g, rest) if rest else g
+
+        def out_spec(pspec, gspec):
+            own = spec_axes(pspec).intersection(self.axes)
+            return self._manual(
+                gspec, own.union(self._scatter_dims(pspec, gspec)))
+
+        # The rank sum runs manual over the other axes too, on each
+        # device's own tp / ep shard of the sums (the parameter's spec):
+        # handed a reduce-scatter whose operand it shards itself, GSPMD
+        # gathers the operand over tp first; handed the ranks' sums on a
+        # leading axis to add up, it all-reduces them whole (PERF.md §6,
+        # PR 28).
+        others = set(self.mesh.axis_names).difference(self.axes)
+        own_shard = jax.tree.map(lambda s: self._manual(s, others),
+                                 self.param_specs, is_leaf=is_spec)
+
+        def local(params, batch, rng):
+            if rng is not None:
+                # distinct dropout streams per rank (GSPMD got this from
+                # sharding one global mask)
+                for a in self.axes:
+                    rng = jax.random.fold_in(rng, jax.lax.axis_index(a))
+            grads, loss_sum = loop(params, batch, rng, self.share_of_mean)
+            with jax.named_scope("grad_accum"):
+                grads = jax.shard_map(
+                    lambda g: jax.tree.map(reduce_leaf, g, self.param_specs,
+                                           self.grad_specs),
+                    in_specs=(own_shard,), out_specs=own_shard,
+                    axis_names=others, check_vma=False)(grads)
+            return grads, jax.lax.psum(loss_sum, self.axes)
+
+        def run(params, batch, rng):
+            fn = jax.shard_map(
+                local, mesh=self.mesh,
+                in_specs=(
+                    jax.tree.map(self._manual, self.param_specs,
+                                 is_leaf=is_spec),
+                    {k: self._manual(P(*tuple(self.batch_spec)[:v.ndim]))
+                     for k, v in batch.items()},
+                    P()),
+                out_specs=(jax.tree.map(out_spec, self.param_specs,
+                                        self.grad_specs, is_leaf=is_spec),
+                           P()),
+                axis_names=set(self.axes), check_vma=False)
+            grads, loss_sum = fn(params, batch, rng)
+            # the tp / ep part of the target, which a manual region's
+            # out_specs cannot name
+            grads = jax.tree.map(
+                lambda g, s: jax.lax.with_sharding_constraint(
+                    g, jax.sharding.NamedSharding(self.mesh, s)),
+                grads, self.grad_specs)
+            return grads, loss_sum
+
+        return run
+
+    def cp_layout(self, cfg: RuntimeConfig, batch: dict) -> dict:
+        """``compute_loss``'s batch as the manual region takes it.  A rank
+        sees only its slice, so what is a function of the whole sequence
+        is applied here: the zigzag cp permutation, and RoPE's positions
+        where cp cuts the sequence."""
+        batch = zigzag_permute_batch(cfg, batch)
+        if (batch.get("position_ids") is None
+                and batch_axes(self.mesh, tuple(self.batch_spec)[2:])):
+            batch = dict(batch, position_ids=jnp.broadcast_to(
+                jnp.arange(batch["tokens"].shape[-1], dtype=jnp.int32),
+                batch["tokens"].shape))
+        return {k: v for k, v in batch.items() if v is not None}
+
+
 def _accumulate_grads(cfg: RuntimeConfig, params, batch, rng, rope,
-                      loss_scale, loss_fn=None):
+                      loss_scale, loss_fn=None, rank_sum=None):
     """Scan microbatches, accumulating fp32 grads and the mean loss.
 
     ``batch`` leaves are [accum, micro_batch, ...].  ``loss_fn(cfg, params,
     microbatch, rng, deterministic)`` overrides the decoder-LM loss — the
     analogue of the reference's ``forward_step_func`` argument to
     ``pretrain`` (training.py:55), used by the BERT/T5 entry points.
+    ``rank_sum`` (``BatchAxisSum``) moves the sum over the ranks that split
+    the batch from every microbatch to the end of the loop.
     """
     accum = jax.tree.leaves(batch)[0].shape[0]
     want_moe = loss_fn is None and cfg.model.num_experts > 0
 
-    def scaled_loss_fn(p, mb, mb_rng):
+    def scaled_loss_fn(p, mb, mb_rng, mean=None):
         # (shared by the accum==1 fast path below)
+        share = {} if mean is None else {"mean": mean}
         if loss_fn is not None:
-            loss = loss_fn(cfg, p, mb, mb_rng, mb_rng is None)
+            loss = loss_fn(cfg, p, mb, mb_rng, mb_rng is None, **share)
             stats = None
         elif want_moe:
             loss, stats = compute_loss(cfg, p, mb, rng=mb_rng,
@@ -176,7 +375,8 @@ def _accumulate_grads(cfg: RuntimeConfig, params, batch, rng, rope,
                                        rope=rope, return_moe_stats=True)
         else:
             loss = compute_loss(cfg, p, mb, rng=mb_rng,
-                                deterministic=(mb_rng is None), rope=rope)
+                                deterministic=(mb_rng is None), rope=rope,
+                                **share)
             stats = None
         return loss * loss_scale, (loss, stats)
 
@@ -198,29 +398,46 @@ def _accumulate_grads(cfg: RuntimeConfig, params, batch, rng, rope,
                 lambda s: jax.lax.stop_gradient(s) * norm, stats)
         return grads, loss, moe_stats
 
-    def body(carry, mb_and_idx):
-        grads_acc, loss_acc, stats_acc = carry
-        mb, idx = mb_and_idx
-        mb_rng = jax.random.fold_in(rng, idx) if rng is not None else None
-        (_, (loss, stats)), grads = grad_fn(params, mb, mb_rng)
-        with jax.named_scope("grad_accum"):
-            grads_acc = jax.tree.map(
-                lambda a, g: a + g.astype(jnp.float32), grads_acc, grads)
-        if stats is not None:
-            stats_acc = jax.tree.map(
-                lambda a, s: a + jax.lax.stop_gradient(s), stats_acc, stats)
-        return (grads_acc, loss_acc + loss, stats_acc), None
-
-    zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
     stats0 = None
     if want_moe:
         from ..models.moe import stats_zero
 
         stats0 = stats_zero(cfg.model)
-    (grads, loss_sum, stats_sum), _ = jax.lax.scan(
-        body, (zeros, jnp.zeros((), jnp.float32), stats0),
-        (batch, jnp.arange(accum)),
-    )
+
+    def loop(params, batch, rng, mean=None):
+        """The microbatch loop over ``batch`` — the whole of it, or inside
+        ``rank_sum``'s region one rank's slice → (fp32 grad sums, loss
+        sum, MoE stats sum)."""
+        def body(carry, mb_and_idx):
+            grads_acc, loss_acc, stats_acc = carry
+            mb, idx = mb_and_idx
+            mb_rng = (jax.random.fold_in(rng, idx) if rng is not None
+                      else None)
+            (_, (loss, stats)), grads = grad_fn(params, mb, mb_rng, mean)
+            with jax.named_scope("grad_accum"):
+                grads_acc = jax.tree.map(
+                    lambda a, g: a + g.astype(jnp.float32), grads_acc, grads)
+            if stats is not None:
+                stats_acc = jax.tree.map(
+                    lambda a, s: a + jax.lax.stop_gradient(s),
+                    stats_acc, stats)
+            return (grads_acc, loss_acc + loss, stats_acc), None
+
+        zeros = jax.tree.map(
+            lambda p: jnp.zeros(p.shape, jnp.float32), params)
+        carry, _ = jax.lax.scan(
+            body, (zeros, jnp.zeros((), jnp.float32), stats0),
+            (batch, jnp.arange(accum)))
+        return carry
+
+    if rank_sum is None:
+        grads, loss_sum, stats_sum = loop(params, batch, rng)
+    else:
+        stats_sum = None
+        if loss_fn is None:
+            batch = rank_sum.cp_layout(cfg, batch)
+        grads, loss_sum = rank_sum.wrap(
+            lambda *a: loop(*a)[:2])(params, batch, rng)
     inv = 1.0 / accum
     grads = jax.tree.map(lambda g: g * inv, grads)
     # normalize layer-and-microbatch sums to per-layer means
@@ -266,7 +483,7 @@ def _pipeline_grads(cfg: RuntimeConfig, params, batch, rng, rope,
 
 def train_step(cfg: RuntimeConfig, state: TrainState, batch: dict,
                base_rng: Optional[jax.Array] = None, rope=None, mesh=None,
-               loss_fn=None, pipeline_loss_fn=None):
+               loss_fn=None, pipeline_loss_fn=None, rank_sum=None):
     """One optimizer step over ``grad_accum`` microbatches.
 
     Returns (new_state, metrics).  Donate ``state`` when jitting.
@@ -303,7 +520,8 @@ def train_step(cfg: RuntimeConfig, state: TrainState, batch: dict,
                                       loss_scale, mesh, pipeline_loss_fn)
     else:
         grads, loss, moe_stats = _accumulate_grads(
-            cfg, state.params, batch, rng, rope, loss_scale, loss_fn)
+            cfg, state.params, batch, rng, rope, loss_scale, loss_fn,
+            rank_sum)
     # unscale (reference: optimizer.py:384-404 unscale-and-check-inf)
     grads = jax.tree.map(lambda g: g / loss_scale, grads)
     grad_norm = opt_lib.global_grad_norm(grads)
@@ -394,6 +612,8 @@ def make_train_step(cfg: RuntimeConfig, mesh=None, state_sharding=None,
     megatron/model/positional_embeddings.py).
     """
     rope = rope_tables(cfg.model)
+    rank_sum = BatchAxisSum.of(cfg, mesh, state_sharding, batch_sharding,
+                               loss_fn)
 
     def step(state, batch, base_rng):
         # Establish the mesh context at *trace* time: mesh-needing ops
@@ -409,7 +629,8 @@ def make_train_step(cfg: RuntimeConfig, mesh=None, state_sharding=None,
         with ctx:
             return train_step(cfg, state, batch, base_rng, rope=rope,
                               mesh=mesh, loss_fn=loss_fn,
-                              pipeline_loss_fn=pipeline_loss_fn)
+                              pipeline_loss_fn=pipeline_loss_fn,
+                              rank_sum=rank_sum)
 
     kwargs = {}
     if state_sharding is not None:

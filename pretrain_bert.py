@@ -95,8 +95,11 @@ def bert_runtime_config(args, vocab_size: int) -> RuntimeConfig:
     ).validate()
 
 
-def bert_loss_fn(cfg, params, mb, rng, deterministic):
-    return encdec.bert_loss(cfg.model, params, mb, rng, deterministic)
+def bert_loss_fn(cfg, params, mb, rng, deterministic,
+                 mean=encdec.masked_mean_loss):
+    # taking ``mean`` says: samples meet in this loss's means and nowhere
+    # else, so the step may hand it a rank's slice (step.py:BatchAxisSum)
+    return encdec.bert_loss(cfg.model, params, mb, rng, deterministic, mean)
 
 
 def main(argv=None):

@@ -101,8 +101,11 @@ def t5_runtime_config(args) -> RuntimeConfig:
     ).validate()
 
 
-def t5_loss_fn(cfg, params, mb, rng, deterministic):
-    return encdec.t5_loss(cfg.model, params, mb, rng, deterministic)
+def t5_loss_fn(cfg, params, mb, rng, deterministic,
+               mean=encdec.masked_mean_loss):
+    # taking ``mean`` says: samples meet in this loss's means and nowhere
+    # else, so the step may hand it a rank's slice (step.py:BatchAxisSum)
+    return encdec.t5_loss(cfg.model, params, mb, rng, deterministic, mean)
 
 
 def main(argv=None):
