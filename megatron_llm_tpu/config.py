@@ -125,7 +125,7 @@ class ModelConfig:
     # fails to import or compile.
     attention_impl: str = "dot"
     # Pallas flash-attention tile sizes (attention_impl="flash").  1024² is
-    # the validated default; the bench sweep (bench.py) tunes per shape.
+    # the validated default.
     flash_block_q: int = 1024
     flash_block_k: int = 1024
     # LIMA layer-dependent dropout (Zhou et al 2023; reference
@@ -208,9 +208,8 @@ class ModelConfig:
     num_decoder_layers: Optional[int] = None
     # Fused blockwise linear+CE training head (never materializes fp32
     # logits — parallel/cross_entropy.fused_linear_cross_entropy).  Opt-in:
-    # saves ~[b,s,vocab] fp32 of HBM when the head dominates memory, but
-    # the recompute-based backward benchmarked slightly slower than XLA's
-    # fused plain path at bench scale (0.394 vs 0.400 MFU).
+    # saves ~[b,s,vocab] fp32 of HBM when the head dominates memory, at
+    # the price of a backward that recomputes the head's matmul.
     fused_lm_head: bool = False
 
     @property

@@ -1,9 +1,9 @@
 """Prompt-lookup speculative decoding (greedy): multi-token decode steps.
 
 Small-batch decode on TPU is bound by the *sequential step chain*, not
-bytes (bench.py docstring records the measurements and the dead ends; the
-fused decode-step kernel attacks per-step cost, this module attacks step
-COUNT).  The way through is fewer sequential steps per generated token:
+bytes (the fused decode-step kernel attacks per-step cost, this module
+attacks step COUNT).  The way through is fewer sequential steps per
+generated token:
 prompt-lookup decoding (PLD) drafts the next ``draft_len`` tokens by
 matching the trailing n-gram of the context against its own history, then
 verifies all of them in ONE cached forward.  Every committed token is an

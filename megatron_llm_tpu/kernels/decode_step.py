@@ -1,11 +1,11 @@
 """Fused single-token decode step: the whole layer stack in ONE Pallas call.
 
-Why this kernel exists: small-batch decode on v5e is bound by the
-*sequential per-op chain*, not bytes — ~100 µs/layer/step against a
-~38 µs/layer weight-read floor, flat in KV-cache size, unchanged (as a
-roofline fraction) by int8 (bench.py docstring records the measurements
-and the dead ends: sibling-GEMV fusion bought 1.01x because XLA already
-overlaps independent matmuls).  The fix is to remove the chain: run the
+Why this kernel exists: small-batch decode of a narrow model is bound by
+the *sequential per-op chain*, not bytes: a layer's step time sits well
+above its weight-read floor, flat in KV-cache size and unchanged (as a
+roofline fraction) by int8, and fusing sibling GEMVs buys nothing because
+XLA already overlaps independent matmuls.  The fix is to remove the chain:
+run the
 entire decode step — every layer's norm → qkv GEMVs → RoPE → decode
 attention → output projection → norm → MLP GEMVs — as a single Pallas
 kernel with grid ``(num_layers, cache_blocks)``.  The Pallas pipeline
@@ -846,7 +846,7 @@ def _stack_eligible(cfg, params, platform: str):
     below, the ``[L, 1, out]`` form of the norm and int8 scales, and the
     ``[L, nm, groups, h]`` form of the w_down int4 group scales
     (``_chunk_down_scales`` — the number of group rows an MLP chunk
-    streams, ``f_chunk // gsz``, is 11 at the bench geometry and 43 at
+    streams, ``f_chunk // gsz``, is 11 at ffn 2816 and 43 at
     ffn 11008, neither a multiple of 8, so as a block of the rank-3 array
     it was refused).  An int4 group must not straddle an MLP chunk
     (``f_chunk % gsz``): the chunk's scales could not ride with it."""
@@ -1087,7 +1087,7 @@ def _chunk_down_scales(scale: jax.Array, nm: int) -> jax.Array:
     """w_down int4 group scales ``[L, ffn/gsz, h]`` → ``[L, nm, groups per
     chunk, h]`` (a free split of the group axis).  Each MLP tick streams
     one chunk's groups; as a block of the rank-3 array that is
-    ``(1, 11, h)`` of ``(L, 22, h)`` at the bench geometry (43 of 86 at
+    ``(1, 11, h)`` of ``(L, 22, h)`` at ffn 2816 (43 of 86 at
     ffn 11008), which breaks the TPU block rule — the last two block dims
     must divide by (8, 128) or equal the array's.  Split this way the
     block is ``(1, 1, groups, h)`` and its last two dims ARE the
@@ -1507,7 +1507,7 @@ def fused_decode_step(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             # the whole-layer weight blocks are double-buffered by the
-            # pipeline (~2x ~26 MB at the bench geometry), far past the
+            # pipeline (~2x ~26 MB at hidden 1024, ffn 2816), far past the
             # 16 MB default scoped-vmem limit; v5e has 128 MB physical
             vmem_limit_bytes=110 * 1024 * 1024,
         ),

@@ -233,9 +233,8 @@ def assert_serving_geometry(cfg: ModelConfig, parallel: ParallelConfig,
 def shard_for_serving(params: Params, cfg: ModelConfig,
                       parallel: ParallelConfig) -> tuple[Params, Mesh]:
     """One-call serving setup: build the mesh, re-layout ``params`` with
-    :func:`serving_param_specs`, return (sharded_params, mesh).  Shared by
-    the generation server CLI and the serving benchmark so the layout
-    logic lives in one place."""
+    :func:`serving_param_specs`, return (sharded_params, mesh): the
+    generation server CLI's layout logic, in one place."""
     from ..parallel import mesh as mesh_lib
 
     assert_serving_geometry(cfg, parallel)

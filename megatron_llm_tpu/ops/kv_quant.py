@@ -2,8 +2,8 @@
 weight-only int8 of ops/quant.py for a fully int8-resident decode).
 
 Decode streams the whole cache every step, so at long contexts the cache
-— not the weights — dominates HBM traffic (bench.py's decode roofline
-terms); int8 rows halve it.  Scheme: symmetric per-row scales, one fp32
+— not the weights — dominates HBM traffic; int8 rows halve it.  Scheme:
+symmetric per-row scales, one fp32
 scale per (batch, kv_head, position) row of [head_dim] values — K and V
 rows are written once at their position and never rewritten, so the scale
 granularity matches the write granularity exactly and requantization

@@ -4,9 +4,9 @@ per-tensor precision policy.
 The reference's low-precision story is optional TransformerEngine FP8 on
 H100 (megatron/model/transformer.py:932-951, off by default).  The TPU
 equivalent worth having first is *weight-only residency for decode*:
-bs=1..8 generation is HBM-bandwidth-bound (see bench.py's decode
-roofline), so halving (int8) or quartering (int4) weight bytes is a
-direct decode speedup on v5e, and the MXU reads int8 natively.  Training
+bs=1..8 generation streams every weight once a token, so halving (int8)
+or quartering (int4) weight bytes cuts the bytes a decode step must read,
+and the MXU reads int8 natively.  Training
 stays bf16/fp32 — this is a serving transform, applied after load.
 
 Three leaf schemes, all plain dict subtrees so pytree machinery
@@ -311,8 +311,7 @@ def embedding_lookup(word, tokens: jax.Array, dtype=None) -> jax.Array:
 
     Quantized path: gather the int8 rows and their scales, dequantize
     only those — per step this touches ``b × h`` int8 bytes instead of
-    keeping a ``v × h`` fp table resident (the 62.5 MB/step untied-table
-    gap in bench.py's decode audit)."""
+    keeping a ``v × h`` fp table resident."""
     if is_quantized(word):
         rows = word["q"][tokens].astype(jnp.float32)
         x = rows * word["scale"][tokens][..., None]

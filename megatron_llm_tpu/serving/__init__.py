@@ -9,8 +9,6 @@
 - ``metrics.py`` — serving counters / gauges / latency histograms, plus
   the SLO tracker; registered into the shared ``obs.REGISTRY`` for
   Prometheus export (docs/observability.md).
-- ``bench.py`` — serving-throughput measurement (requests/s, token
-  latency), consumed by the repo-level ``bench.py``.
 - ``adapters/`` — multi-tenant LoRA: adapter registry + device-arena
   residency (LRU + ref pinning) so thousands of registered adapters
   share one base model, different adapters coexisting per-row in one

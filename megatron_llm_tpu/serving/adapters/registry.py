@@ -29,7 +29,7 @@ from __future__ import annotations
 
 import functools
 from collections import OrderedDict
-from typing import Callable, Dict, Optional, Union
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -62,7 +62,7 @@ class AdapterRegistry:
 
     def __init__(self, cfg: ModelConfig, n_slots: int, rank: int,
                  targets=None, *,
-                 metrics: Union[ServingMetrics, Callable, None] = None):
+                 metrics: Optional[ServingMetrics] = None):
         if n_slots < 1:
             raise ValueError("AdapterRegistry needs n_slots >= 1")
         if rank < 1:
@@ -88,10 +88,7 @@ class AdapterRegistry:
                     f"(num_experts={cfg.num_experts}); use attention "
                     "targets only")
         self._lock = sanitizers.make_lock("serving.adapters")
-        # like PrefixCache: the engine replaces its metrics object
-        # between warmup and measurement, so a zero-arg callable defers
-        # the lookup to use time
-        self._metrics = metrics
+        self._metrics = metrics  # bound by the engine it is handed to
         self._store: Dict[str, lora_lib.LoRAAdapter] = {}
         self._slot_of: Dict[str, int] = {}        # resident id -> slot
         self._ids: list = [None] * self.n_slots   # slot -> id | None
@@ -235,18 +232,13 @@ class AdapterRegistry:
 
     # -- metrics -----------------------------------------------------------
 
-    def _m(self) -> Optional[ServingMetrics]:
-        m = self._metrics
-        return m() if callable(m) and not isinstance(
-            m, ServingMetrics) else m
-
     def _inc(self, name: str) -> None:
-        m = self._m()
+        m = self._metrics
         if m is not None:
             m.inc(name)
 
     def _gauges(self) -> None:
-        m = self._m()
+        m = self._metrics
         if m is not None:
             m.set_gauges(
                 adapter_resident=len(self._slot_of),

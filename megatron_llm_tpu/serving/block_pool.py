@@ -375,9 +375,7 @@ class HostKVTier:
         self.pool = pool
         self.n_host_blocks = int(n_host_blocks)
         self.arity = int(arity)
-        self._metrics = metrics  # zero-arg callable or None (engine swaps
-        #                          its metrics object between warmup and
-        #                          measurement, same as PrefixCache)
+        self._metrics = metrics  # the engine's ServingMetrics, or None
         self.max_backlog_s = float(max_backlog_s)
         bk = pool.block_size
 
@@ -417,10 +415,6 @@ class HostKVTier:
 
     def can_store(self, n: int) -> bool:
         return len(self._free) >= n
-
-    def _m(self):
-        m = self._metrics
-        return m() if callable(m) else m
 
     def owners(self) -> dict:
         """owner label -> host block count (snapshot / sanitizer)."""
@@ -472,7 +466,7 @@ class HostKVTier:
         nbytes = self.block_nbytes * len(bids)
         self._pending.append(_PendingSwap(hids, k_dense, v_dense,
                                           nbytes, owner))
-        m = self._m()
+        m = self._metrics
         if m is not None:
             m.inc("swap_out_blocks_total", by=len(bids))
             m.inc("swap_bytes_total", by=nbytes)
@@ -543,7 +537,7 @@ class HostKVTier:
         scatter[:len(dest_bids)] = np.asarray(dest_bids, dtype=np.int32)
         self.pool.import_blocks(k_dense, v_dense, scatter)
         nbytes = self.block_nbytes * len(hids)
-        m = self._m()
+        m = self._metrics
         if m is not None:
             m.inc("swap_in_blocks_total", by=len(hids))
             m.inc("swap_bytes_total", by=nbytes)

@@ -205,7 +205,7 @@ class EngineConfig:
     #                               vs the old fixed-stride cache); set it
     #                               lower to trade worst-case headroom for
     #                               more concurrent mixed-length requests
-    #                               at the same HBM (bench serving_paged).
+    #                               at the same HBM.
     spec_draft_len: int = 0       # speculative decoding: max draft tokens
     #                               per slot per verify step, proposed by
     #                               a host-side n-gram matcher over the
@@ -967,10 +967,6 @@ class ServingEngine:
                 f"AdapterRegistry has {adapters.n_slots} arena slots but "
                 f"EngineConfig.adapter_cache_slots="
                 f"{self.config.adapter_cache_slots}")
-        if adapters is not None and adapters._metrics is None:
-            # late-bound: the engine (and bench harness) swaps its
-            # metrics object between warmup and measurement
-            adapters._metrics = lambda: self.metrics
         self._lora_rank = 0 if adapters is None else adapters.rank
         # sanitizer resolution comes first so every lock/condition the
         # engine (and its queue) creates below is order-tracked
@@ -980,6 +976,8 @@ class ServingEngine:
         self._sanitizer: Optional[sanitizers.LedgerSanitizer] = None
         self.sanitizer_report: List[dict] = []  # leaks found at shutdown
         self.metrics = metrics or ServingMetrics(self.config.max_batch_size)
+        if adapters is not None and adapters._metrics is None:
+            adapters._metrics = self.metrics
         self.metrics.set_gauges(num_slots=self.config.max_batch_size)
         self.trace = TraceRecorder(capacity=self.config.trace_capacity,
                                    enabled=self.config.trace)
@@ -1131,13 +1129,13 @@ class ServingEngine:
                     self.host_tier = HostKVTier(
                         pool, cfg_e.host_kv_blocks,
                         arity=self.slots.table_blocks,
-                        metrics=lambda: self.metrics)
+                        metrics=self.metrics)
                 if cfg_e.prefix_cache_blocks:
                     self.prefix_cache = PrefixCache(
                         self.cfg, pool=pool,
                         max_blocks=cfg_e.prefix_cache_blocks,
                         max_seq_len=cfg_e.max_seq_len,
-                        metrics=lambda: self.metrics,
+                        metrics=self.metrics,
                         host_tier=self.host_tier)
                 from ..ops.quant import precision_route
                 self._precision_route = precision_route(self.params)

@@ -251,7 +251,7 @@ class ServingMetrics:
         self.step_routes: dict = {}
         # speculative counters broken down by where the draft came from
         # ("ngram" = host prompt-lookup, "model" = resident draft model
-        # proposing trees) — the source label is how a bench run shows
+        # proposing trees) — the source label is how a run shows
         # the resident draft carrying random traffic that PLD cannot
         self.spec_by_source: dict = {}
         # per-slot acceptance EWMA gauges (the value the engine's budget

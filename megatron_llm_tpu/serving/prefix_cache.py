@@ -2,8 +2,8 @@
 
 Every admission used to recompute its prompt from token 0 even when the
 first few hundred tokens were the same system prompt every other request
-carried — and BENCH_r05 puts long-prompt prefill at 0.174 MFU, so that
-recompute dominates TTFT for exactly the traffic the engine targets.
+carried, and that recompute dominates TTFT for exactly the traffic the
+engine targets.
 This module is the RadixAttention / vLLM-automatic-prefix-caching idea
 over the paged block pool: a host-side trie over **block-aligned**
 token-id prefixes whose nodes hold *pool block ids*, consulted at
@@ -58,7 +58,7 @@ Host cost is O(prompt/block) dict lookups per admission; no device work.
 
 from __future__ import annotations
 
-from typing import Callable, List, Optional, Sequence, Tuple, Union
+from typing import List, Optional, Sequence, Tuple
 
 from ..config import ModelConfig
 from .block_pool import BlockPool
@@ -105,7 +105,7 @@ class PrefixCache:
 
     def __init__(self, cfg: ModelConfig, *, pool: BlockPool,
                  max_blocks: int, max_seq_len: int,
-                 metrics: Union[ServingMetrics, Callable, None] = None,
+                 metrics: Optional[ServingMetrics] = None,
                  host_tier=None):
         assert max_blocks >= 1
         self.cfg = cfg
@@ -113,9 +113,6 @@ class PrefixCache:
         self.block_tokens = int(pool.block_size)
         self.max_blocks = int(max_blocks)
         self.max_seq_len = int(max_seq_len)
-        # the engine replaces its metrics object between warmup and
-        # measurement (serving/bench.py), so accept a zero-arg callable
-        # resolved at use time rather than capturing one registry forever
         self._metrics = metrics
         # optional HostKVTier: eviction victims demote to host RAM
         # instead of being dropped, and re-promote on the next match
@@ -134,10 +131,6 @@ class PrefixCache:
     def host_blocks(self) -> int:
         """Spilled trie blocks resident in the host tier."""
         return self._host_blocks
-
-    def _m(self) -> Optional[ServingMetrics]:
-        m = self._metrics
-        return m() if callable(m) else m
 
     def _touch(self, node: _Node) -> None:
         self._tick += 1
@@ -173,7 +166,7 @@ class PrefixCache:
                 break
             nodes.append(child)
             cur = child
-        m = self._m()
+        m = self._metrics
         if not nodes:
             if m is not None:
                 m.inc("prefix_misses")
@@ -255,7 +248,7 @@ class PrefixCache:
         node.bid = bid
         self._host_blocks -= 1
         self._blocks += 1
-        m = self._m()
+        m = self._metrics
         if m is not None:
             m.inc("prefix_promotions_total")
         return True
@@ -347,7 +340,7 @@ class PrefixCache:
             self._blocks -= 1
             evicted += 1
         if evicted:
-            m = self._m()
+            m = self._metrics
             if m is not None:
                 m.inc("prefix_evicted_blocks", by=evicted)
         return evicted

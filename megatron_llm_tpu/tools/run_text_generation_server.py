@@ -148,11 +148,9 @@ def main(argv=None) -> int:
                          "under (obs/profile.py); unset refuses the route")
     ap.add_argument("--no_trace", action="store_true",
                     help="disable per-request span tracing (obs/trace.py, "
-                         "GET /trace).  Tracing is on by default and holds "
-                         "the serving_mixed ITL p50 within the bench.py "
-                         "--compare regression gate; this is the escape "
-                         "hatch if a deployment wants the last few "
-                         "microseconds back")
+                         "GET /trace).  Tracing is on by default; this "
+                         "is the escape hatch if a deployment wants the "
+                         "last few microseconds back")
     ap.add_argument("--log_json", action="store_true",
                     help="emit the structured JSON event log "
                          "(obs/logging.py: request lifecycle lines with "

@@ -384,9 +384,9 @@ def _accumulate_grads(cfg: RuntimeConfig, params, batch, rng, rope,
 
     if accum == 1:
         # Single-microbatch fast path: the scan's fp32 zero-init + add
-        # costs a full extra param-tree read/write per step (~1-2% of the
-        # bench step at 373M params) and buys nothing when there is only
-        # one gradient.  Cast once instead of accumulate.
+        # costs a full extra param-tree read/write per step and buys
+        # nothing when there is only one gradient.  Cast once instead of
+        # accumulate.
         mb = jax.tree.map(lambda x: x[0], batch)
         mb_rng = jax.random.fold_in(rng, 0) if rng is not None else None
         (_, (loss, stats)), grads = grad_fn(params, mb, mb_rng)

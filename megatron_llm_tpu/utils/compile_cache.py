@@ -1,7 +1,7 @@
 """Where the program keeps XLA's persistent compilation cache.
 
 Every entry point (finetune.py, pretrain_{bert,t5,ict}.py, the generation
-server CLI, ``bench.py --point``, chip_smoke.py) calls
+server CLI, chip_smoke.py, the benchmark) calls
 :func:`enable_compile_cache` once, before its first compile.  A whole-step
 program of a 7B-width model compiles for a quarter of a minute to a
 minute and a half on a TPU, and a process that starts with no compiled

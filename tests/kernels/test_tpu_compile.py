@@ -226,7 +226,7 @@ def test_norm_fwd_bwd(topo, norm, hidden):
 
 def _stack_cfg(kv_quant="none", wide=False):
     # Llama stacks the fused kernels accept (7B-width layers exceed their
-    # VMEM budget).  wide: bench.py's 374M geometry.  Else a quarter of
+    # VMEM budget).  wide: the 374M Llama geometry.  Else a quarter of
     # its hidden size — Mosaic's compile time grows with the square of it
     # (int4 at 1024: 40 s) — at the SAME ffn, so w_down still streams 11
     # int4 scale groups per MLP chunk, the count that broke the block rule

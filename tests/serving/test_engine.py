@@ -564,28 +564,57 @@ class TestSpeculative:
 
         assert run(4) == run(0)
 
-    def test_block_boundary_rollback(self, tiny):
-        """Rejected drafts across block edges: with 4-token blocks and
-        draft windows of 4, verify windows constantly straddle block
-        boundaries and imperfect acceptance leaves rejected rows in
-        freshly allocated blocks.  Rollback is fill arithmetic — the
-        trajectory stays exact, no COW copies fire (no sharing here),
-        and the sanitizer's block ledger stays balanced through
-        drain."""
+    def _rejecting_prompt(self, cfg, params, length, rng):
+        """A prompt whose first draft is wrong by construction.
+
+        ``[a, b, c, x] + filler + [a, b]``: once the model has produced
+        its first token, the context's trailing 3-gram is ``(a, b,
+        first)``.  ``c`` is scanned until the reference's first token IS
+        ``c``, so the drafter's match is the planted one and its draft
+        starts with ``x`` — a token the reference does not produce next.
+        ``a`` and ``b`` appear nowhere else, so no other match exists."""
+        a, b, x = cfg.vocab_size - 1, cfg.vocab_size - 2, 1
+        for _ in range(8):
+            filler = rng.integers(2, cfg.vocab_size - 2,
+                                  length - 6).tolist()
+            for c in range(2, cfg.vocab_size - 2):
+                prompt = [a, b, c, x] + filler + [a, b]
+                first, second = _reference(cfg, params, prompt, 2)[length:]
+                if first == c and second != x:
+                    return prompt
+        pytest.fail(f"no rejecting prompt of length {length} found")
+
+    @pytest.mark.parametrize("block,draft_len",
+                             [(4, 3), (4, 2), (8, 3), (8, 5)])
+    def test_block_boundary_rollback(self, tiny, block, draft_len):
+        """Rejected drafts across block edges.  Every prompt ends one or
+        two rows short of a block edge and carries a planted n-gram whose
+        continuation the model does not produce, so each slot's first
+        verify window straddles the edge, is rejected at its first
+        token, and leaves its rows in a freshly allocated block; the
+        cycle the model then settles into supplies the accepted windows.
+        Rollback is fill arithmetic — the trajectory stays exact, no
+        COW copies fire (no sharing here), and the sanitizer's block
+        ledger stays balanced through drain."""
         cfg, params = tiny
+        rng = np.random.default_rng(block * 16 + draft_len)
+        prompts = [self._rejecting_prompt(cfg, params, n, rng)
+                   for n in (3 * block - 1, 3 * block - 2,
+                             4 * block - 1, 4 * block - 2)]
         engine = ServingEngine(cfg, params, EngineConfig(
             max_batch_size=4, max_seq_len=64, max_queue_size=16,
-            kv_block_size=4, spec_draft_len=3, sanitize=True)).start()
+            kv_block_size=block, spec_draft_len=draft_len,
+            sanitize=True)).start()
         try:
             handles = [engine.submit(p, max_new_tokens=self.MAX_NEW,
                                      use_eos_stop=False)
-                       for p in self.REP_PROMPTS]
+                       for p in prompts]
             results = [h.result(timeout=600) for h in handles]
             engine.drain(timeout=60)
             assert engine.sanitizer_report == []
         finally:
             engine.shutdown()
-        for p, r in zip(self.REP_PROMPTS, results):
+        for p, r in zip(prompts, results):
             assert r.tokens == _reference(cfg, params, p, self.MAX_NEW)
         snap = engine.metrics.snapshot()
         assert snap["spec_steps"] > 0

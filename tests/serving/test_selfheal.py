@@ -23,7 +23,7 @@ self-healing"): the supervised replica lifecycle under injected faults.
   burning a slot on a dead-on-arrival resubmit; a live budget is passed
   through as the *remaining* time, never a fresh one.
 - **compound-fault soak** — the randomized kill/hang/ship-fault storm
-  over ≥ 64 mixed requests (serving/bench.py:run_chaos_soak_bench):
+  over ≥ 64 mixed requests (tests/serving/chaos_soak.py):
   exactly-once delivery, balanced ledgers on every incarnation, cluster
   back at full strength.
 """
@@ -391,16 +391,16 @@ def test_failover_passes_remaining_deadline(tiny):
 # ---------------------------------------------------------------------------
 
 def test_chaos_soak_compound_faults(tiny):
-    from megatron_llm_tpu.serving.bench import run_chaos_soak_bench
+    from chaos_soak import run_chaos_soak
 
     cfg, params = tiny
     # hang_timeout_s must clear the worst-case iteration latency of 3
     # schedulers sharing the host CPU, or slow-but-healthy iterations
     # trip the watchdog (docs/robustness.md: sizing the hang timeout)
-    out = run_chaos_soak_bench(cfg, params, num_requests=64, gen_len=10,
-                               slots=2, max_prompt_len=32, replicas=3,
-                               n_adapters=2, rank=4, draft_len=2,
-                               hang_timeout_s=2.0, hang_s=6.0, seed=0)
+    out = run_chaos_soak(cfg, params, num_requests=64, gen_len=10,
+                         slots=2, max_prompt_len=32, replicas=3,
+                         n_adapters=2, rank=4, draft_len=2,
+                         hang_timeout_s=2.0, hang_s=6.0, seed=0)
     # every accepted token delivered exactly once, across every crash,
     # replay, shipment, and migration
     assert out["serving_chaos_delivery_violations"] == 0
