@@ -101,12 +101,25 @@ def unembed(cfg: ModelConfig, params: Params, x: jax.Array) -> jax.Array:
     """Project hidden states to (padded-)vocab logits, float32
     (reference: parallel_lm_logits, megatron/model/language_model.py:24-53).
     The cast is made here, under the scope: XLA fuses it into the matmul,
-    and a fusion is named after its root."""
-    return (x @ unembed_weight(cfg, params)).astype(jnp.float32)
+    and a fusion is named after its root.
+
+    A tied head contracts the hidden axis of ``x`` with the hidden axis of
+    the table as it is stored: the program holds no transposed table
+    (serving's rule that a weight is read once, where it lies —
+    docs/inference.md; tests/serving/test_decode_weights.py)."""
+    if cfg.tie_embed_logits:
+        logits = jax.lax.dot_general(
+            x, params["embedding"]["word"],
+            (((x.ndim - 1,), (1,)), ((), ())))
+    else:
+        logits = x @ params["lm_head"]
+    return logits.astype(jnp.float32)
 
 
 def unembed_weight(cfg: ModelConfig, params: Params) -> jax.Array:
-    """[h, padded_vocab] unembedding matrix (tied or untied)."""
+    """[h, padded_vocab] unembedding matrix (tied or untied), for the
+    training loss's fused linear + cross-entropy head, which streams it
+    over the vocabulary; ``unembed`` reads a tied table as it lies."""
     if cfg.tie_embed_logits:
         return params["embedding"]["word"].T
     return params["lm_head"]

@@ -32,10 +32,10 @@ import jax.numpy as jnp
 
 from ..config import ModelConfig, PositionEmbeddingType
 from ..ops.activations import get_activation, is_glu
-from ..ops.attention import attention
+from ..ops.attention import _mesh_active, attention
 from ..ops.norms import norm_apply, norm_init
 from ..ops.quant import int8_training_matmul, is_quantized, mm
-from ..ops.rope import apply_rope, precompute_rope_freqs
+from ..ops.rope import apply_rope, apply_rope_flat, precompute_rope_freqs
 
 Params = dict
 
@@ -288,16 +288,24 @@ def attention_block(cfg: ModelConfig, p: Params, x: jax.Array,
         q = q + p["bq"]
         k = k + p["bk"]
         v = v + p["bv"]
-    q = q.reshape(b, s, nq, d)
-    k = k.reshape(b, s, nkv, d)
-    v = v.reshape(b, s, nkv, d)
-
     position_ids = side.position_ids
     if kv_cache is not None and position_ids is None:
         raise ValueError("kv_cache requires explicit position_ids "
                          "(forward_cached supplies them)")
 
-    if cfg.position_embedding_type == PositionEmbeddingType.ROTARY:
+    rotary = cfg.position_embedding_type == PositionEmbeddingType.ROTARY
+    # the paged route's few rows are rotated as the matmul leaves them:
+    # cut into heads first, the q projection re-lays wq in every call
+    # (apply_rope_flat).  Not under a mesh, where tp splits the row and
+    # the shift along it would cross shards in every layer.
+    flat = rotary and isinstance(kv_cache, PagedKV) and not _mesh_active()
+    if flat:
+        q = apply_rope_flat(q, side.rope_cos, side.rope_sin, position_ids, d)
+        k = apply_rope_flat(k, side.rope_cos, side.rope_sin, position_ids, d)
+    q = q.reshape(b, s, nq, d)
+    k = k.reshape(b, s, nkv, d)
+    v = v.reshape(b, s, nkv, d)
+    if rotary and not flat:
         q = apply_rope(q, side.rope_cos, side.rope_sin, position_ids)
         k = apply_rope(k, side.rope_cos, side.rope_sin, position_ids)
 
@@ -631,7 +639,12 @@ def stack_forward_paged(cfg: ModelConfig, stacked: Params, x: jax.Array,
 
     The scan closes over the whole pool and each layer's kernel addresses
     its own layer through the index maps: a per-layer slice taken by the
-    scan is a copy of that slice for every custom call."""
+    scan is a copy of that slice for every custom call.  The weights are
+    the scan's xs, and every projection takes its layer's slice inside
+    its matmul's fusion, read once where it lies; that holds while no
+    operation wants the weight laid out another way, which is why
+    ``attention_block`` rotates this route's q and k before it cuts them
+    into heads (obs/hlo_audit.py audits the executable for it)."""
     x, (rows_k, rows_v) = _scan_layers_cached(
         cfg, stacked, x, side, (),
         lambda idx: PagedKV(k_pool, v_pool, tables, fills, idx), lora=lora)

@@ -58,6 +58,13 @@ def _shapes(type_text: str) -> list:
             for d, dims in _SHAPE.findall(type_text)]
 
 
+def _nbytes(shape) -> int:
+    """Bytes of one ``(dtype, dims)`` of ``_shapes``."""
+    dtype, dims = shape
+    return (1 if dtype.startswith("f8") else _ITEMSIZE.get(dtype, 4)) * int(
+        np.prod(dims, dtype=np.int64))
+
+
 def _trip_count(while_line: str, comps: dict):
     """A ``while``'s trip count: XLA:CPU writes it on the instruction;
     XLA:TPU does not, and there it is the one s32 constant that the
@@ -184,9 +191,7 @@ def grad_collectives(hlo_text: str, mesh_shape: dict, batch_axes,
             else:
                 out["after_loop"] += times
                 out["leaves"] += times * len(shaped)
-                out["bytes"] += times * sum(
-                    (1 if d.startswith("f8") else _ITEMSIZE.get(d, 4))
-                    * int(np.prod(dims)) for d, dims in shaped)
+                out["bytes"] += times * sum(map(_nbytes, shaped))
                 kinds.add(kind)
 
     walk(entry, 1, False, False)

@@ -306,16 +306,42 @@ def quantize_embedding(word: jax.Array) -> dict:
     return {"q": q, "scale": scale}
 
 
+# the most tokens ``embedding_lookup`` reads row by row: a decode step's
+# slots.  A row costs ~2 us (16 of them 0.033 ms a step where the copy
+# the gather drew cost 1.9; PERF.md, PR 30) and each is an operation of
+# its own: a prefill's hundreds stay one gather
+_SLICED_LOOKUP_TOKENS = 64
+
+
 def embedding_lookup(word, tokens: jax.Array, dtype=None) -> jax.Array:
     """``word[tokens]`` for a plain or int8-quantized embedding table.
 
     Quantized path: gather the int8 rows and their scales, dequantize
     only those — per step this touches ``b × h`` int8 bytes instead of
-    keeping a ``v × h`` fp table resident."""
+    keeping a ``v × h`` fp table resident.
+
+    A few tokens of a plain table are read by one ``dynamic_slice`` each,
+    not by a gather.  XLA:TPU keeps a ``[v, h]`` table whose ``v`` divides
+    by 128 and whose ``h`` does not (Falcon-7B: 65024 × 4544) with the
+    vocabulary as the minor dimension — the layout the tied head reads —
+    and its gather wants rows: it copied the whole table first, 591 MB
+    read and written in every decode step (PERF.md, PR 30).  A slice
+    reads its row where it lies, whichever way the table lies; the rows
+    and the clamping of an index out of range are the gather's.  Not
+    under a mesh: there the table's rows are split over tp and GSPMD
+    partitions the gather (each shard its own rows, then an all-reduce of
+    ``b × h``), where a slice makes it all-gather the table."""
     if is_quantized(word):
         rows = word["q"][tokens].astype(jnp.float32)
         x = rows * word["scale"][tokens][..., None]
         return x.astype(dtype) if dtype is not None else x
+    from .attention import _mesh_active
+
+    if tokens.size <= _SLICED_LOOKUP_TOKENS and not _mesh_active():
+        flat = tokens.reshape(-1)
+        rows = [jax.lax.dynamic_slice_in_dim(word, flat[i], 1, axis=0)
+                for i in range(flat.size)]
+        return jnp.concatenate(rows).reshape(*tokens.shape, word.shape[1])
     return word[tokens]
 
 

@@ -182,6 +182,35 @@ def apply_rope(
     return out.astype(x.dtype)
 
 
+def apply_rope_flat(
+    x: jax.Array,
+    cos: jax.Array,
+    sin: jax.Array,
+    position_ids: jax.Array,
+    head_dim: int,
+) -> jax.Array:
+    """``apply_rope`` on ``x`` [batch, seq, heads * head_dim], before it is
+    cut into heads: the same rotation in the same float32 arithmetic, the
+    pair's partner taken by a shift along the row and no strided slice.
+
+    For the few rows of a decode step.  A projection whose output is
+    reshaped to ``[.., heads, head_dim]`` at once is compiled by XLA:TPU as
+    one dot with two output dimensions, which wants its weight with the
+    contraction dimension minor and re-lays the whole weight in every call
+    (PERF.md, PR 30); rotating the row as the matmul leaves it keeps the
+    weight where it lies.  A pair never straddles two heads (``head_dim``
+    is even), so the shift needs no head boundaries."""
+    n = x.shape[-1] // head_dim
+    cos_t = jnp.tile(jnp.repeat(cos[position_ids], 2, axis=-1), n)
+    sin_t = jnp.tile(jnp.repeat(sin[position_ids], 2, axis=-1), n)
+    xf = x.astype(jnp.float32)
+    even = jnp.arange(x.shape[-1]) % 2 == 0
+    partner = jnp.where(even, -jnp.roll(xf, -1, axis=-1),
+                        jnp.roll(xf, 1, axis=-1))
+    out = xf * cos_t.astype(jnp.float32) + partner * sin_t.astype(jnp.float32)
+    return out.astype(x.dtype)
+
+
 @partial(jax.jit, static_argnums=(3,))
 def apply_rope_single(x, cos, sin, position: int):
     """Single-position variant for incremental decoding."""

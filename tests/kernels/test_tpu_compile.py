@@ -39,6 +39,7 @@ from megatron_llm_tpu.kernels.rmsnorm import (  # noqa: E402
 )
 from megatron_llm_tpu.models import model as model_lib  # noqa: E402
 from megatron_llm_tpu.models.transformer import rope_tables  # noqa: E402
+from megatron_llm_tpu.obs.hlo_audit import relayout_bytes  # noqa: E402
 from megatron_llm_tpu.ops import attention as attn_ops  # noqa: E402
 from megatron_llm_tpu.ops import lora as lora_ops  # noqa: E402
 from megatron_llm_tpu.ops import quant  # noqa: E402
@@ -368,7 +369,9 @@ def test_composed_decode_step_touches_only_live_kv(topo, monkeypatch, size,
     nothing but the pool arguments and the in-place row writes has the
     pool's shape or the shape of the gathered dense view
     ``[L, S·T, kv, bk, d]`` — the re-layouts a row scatter draws and the
-    gather are gone."""
+    gather are gone — and on one chip no copy, transpose or stand-alone
+    slice writes 8 MiB or more: every weight is read once, where it
+    lies."""
     from megatron_llm_tpu.config import falcon_config
     from megatron_llm_tpu.models import sharding as sharding_lib
     from megatron_llm_tpu.serving import engine as engine_lib
@@ -414,6 +417,12 @@ def test_composed_decode_step_touches_only_live_kv(topo, monkeypatch, size,
     _no_copy_of(text, f"bf16[{layers},{nb},{kv},{bk},64]")
     _no_copy_of(text, f"bf16[{layers},{slots * t},{kv},{bk},64]")
     assert text.count("dynamic-update-slice(") >= 2 * slots
+    # nor is a weight moved before it is read (obs/hlo_audit.py): the q
+    # projection takes its layer's wq inside its fusion, the embedding
+    # rows are read where the table lies.  Under tp the step keeps the
+    # parent's rotary and lookup and still re-lays wq (ROADMAP S3)
+    assert set(relayout_bytes(text)) <= ({"bf16[1,8192,4096]"} if tp
+                                         else set())
 
 
 def test_sharded_flash_attention_fwd_bwd(topo, monkeypatch):

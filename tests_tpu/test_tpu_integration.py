@@ -214,3 +214,56 @@ def test_int8_decode_runs_and_kernel_matches_einsum():
     delta = np.abs(np.asarray(kernel_out, np.float32) - ref).max()
     print(f"int8 kernel vs independent einsum max|delta|: {delta:.5f}")
     assert delta < 0.05, delta
+
+
+def test_decode_step_moves_no_weight_and_matches_reference():
+    """The engine's decode executable at Falcon-7B width (2 layers, 16
+    slots, the composed paged route), compiled on the chip: no copy,
+    transpose or stand-alone slice of 8 MiB or more is in it — every
+    weight is read once, where it lies (obs/hlo_audit.py; before PR 30
+    each layer's ``wq`` was sliced out and re-laid, 2 x 41.3 MB, and the
+    tied table copied for the embedding gather, 591 MB) — and the
+    log-probs the same executable gives its greedy tokens are the plain
+    reference's, inside the serving cells' tolerance."""
+    from benchmarks.reference import falcon as reference
+    from benchmarks.serving import LOGPROB_MAX_TOL, LOGPROB_MEAN_TOL
+    from megatron_llm_tpu.config import falcon_config
+    from megatron_llm_tpu.models import model as model_lib
+    from megatron_llm_tpu.obs.hlo_audit import relayout_bytes
+    from megatron_llm_tpu.serving import engine as engine_lib
+
+    slots, t, bk, steps = 16, 16, 128, 12
+    cfg = falcon_config("7b", num_layers=2, attention_impl="flash")
+    params = jax.jit(lambda k: model_lib.init_params(k, cfg))(
+        jax.random.key(0))
+    pools = model_lib.init_kv_pool(cfg, 1 + slots * t, bk)
+    assert model_lib.paged_decode_eligible(cfg, pools[0])
+    tables = 1 + jnp.arange(slots * t, dtype=jnp.int32).reshape(slots, t)
+    zeros = jnp.zeros((slots,), jnp.int32)
+    knobs = (zeros, zeros, jnp.ones((slots,), bool),          # greedy
+             jnp.ones((slots,), jnp.float32), zeros,
+             jnp.zeros((slots,), jnp.float32))
+    pending = jax.random.randint(jax.random.key(1), (slots,), 1,
+                                 cfg.vocab_size - 1)
+    step = engine_lib._decode_donated.lower(
+        cfg, params, *pools, tables, pending, zeros, *knobs,
+        use_fused=False).compile()
+    text = step.as_text()
+    assert "tpu_custom_call" in text
+    assert relayout_bytes(text) == {}
+
+    tokens, logprobs = [np.asarray(pending)], []
+    for i in range(steps):
+        pending, lp, *pools = step(params, *pools, tables, pending,
+                                   jnp.full((slots,), i, jnp.int32), *knobs)
+        tokens.append(np.asarray(pending))
+        logprobs.append(np.asarray(lp, np.float32))
+    tokens, logprobs = np.stack(tokens, 1), np.stack(logprobs, 1)
+    meta = reference.meta_of(cfg)
+    d = np.stack([
+        np.abs(logprobs[s] - np.asarray(
+            reference.token_logprobs(params, tokens[s], meta)))
+        for s in range(3)])
+    assert np.isfinite(d).all()
+    assert d.max() <= LOGPROB_MAX_TOL and d.mean() <= LOGPROB_MEAN_TOL, (
+        d.max(), d.mean())
