@@ -11,14 +11,19 @@ request, so the instrument is a reading of the executable:
 ``relayout_bytes`` sums what the instructions that only move bytes write.
 It compiles nothing itself; tests/kernels/test_tpu_compile.py and
 tests_tpu/ hold the engine's decode step to 0.
+
+The second rule (same document): work that only some requests need lies
+under a ``cond`` on what the step is handed.  XLA turns a small
+``conditional`` into selects that run both sides; ``ops_by_conditional``
+says on which side of the executable's conditionals an opcode ended up.
 """
 
 from __future__ import annotations
 
 import re
 
-from .collectives import (_CALLEE, _OPCODE, _computations, _nbytes, _shapes,
-                          _trip_count)
+from .collectives import (_BRANCHES, _CALLEE, _OPCODE, _computations,
+                          _nbytes, _shapes, _trip_count)
 
 _MOVES = {"copy", "transpose", "dynamic-slice"}
 _RELABELS = {"parameter", "bitcast", "reshape", "get-tuple-element", "tuple"}
@@ -80,3 +85,30 @@ def relayout_bytes(hlo_text: str, min_bytes: int = 8 << 20) -> dict:
 
     walk(entry, 1)
     return out
+
+
+def ops_by_conditional(hlo_text: str, opcode: str):
+    """``(inside, outside)``: the names of a compiled program's
+    ``opcode`` instructions that run only when a ``conditional`` takes
+    their branch, and of those that run in every call.  An instruction
+    of a fusion, a loop body or a call counts where its caller lies."""
+    comps, entry = _computations(hlo_text)
+    inside, outside = [], []
+
+    def walk(name: str, conditional: bool) -> None:
+        for line in comps.get(name, ()):
+            lhs, _, rhs = line.partition(" = ")
+            m = _OPCODE.search(rhs)
+            if not m:
+                continue
+            if m.group(1) == opcode:
+                (inside if conditional else outside).append(
+                    lhs.split()[-1].lstrip("%"))
+            for callee in _CALLEE.findall(line):
+                walk(callee, conditional or m.group(1) == "conditional")
+            for group in _BRANCHES.findall(line):
+                for callee in group.split(","):
+                    walk(callee.strip().lstrip("%"), True)
+
+    walk(entry, False)
+    return inside, outside

@@ -37,7 +37,7 @@ SYNC_NAME = "obs_clock_sync"
 DEVICE_SCOPES = (
     "embed", "attention", "mlp", "lm_head", "cross_entropy", "grad_accum",
     "optimizer", "kv_cache", "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
-    "flash_decode", "rmsnorm", "decode_step_fused")
+    "flash_decode", "rmsnorm", "decode_step_fused", "sample")
 
 
 @dataclasses.dataclass

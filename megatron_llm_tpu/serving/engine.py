@@ -435,20 +435,54 @@ def _sample_slots(logits, seeds, counters, greedy, temps, top_ks, top_ps,
     - randomness is a per-REQUEST stream: key(seed_i) folded on the
       request's own generated-token counter, so a request's trajectory is
       independent of slot placement and batch composition.
-    """
-    S, V = logits.shape
-    pad = jnp.arange(V) >= vocab
-    logits = jnp.where(pad[None, :], NEG_INF, logits)
-    greedy_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
+    What only a sampling slot needs (``_draw_slots``: the ordering of the
+    vocabulary, the nucleus, the keys, the draw) sits under a ``cond`` on
+    the ``greedy`` vector the step is handed: the device reads the
+    predicate, so it is still ONE executable for any mix, and a batch
+    whose every slot is greedy pays for the argmax and the log-prob alone.
+    """
+    with jax.named_scope("sample"):
+        pad = jnp.arange(logits.shape[-1]) >= vocab
+        logits = jnp.where(pad[None, :], NEG_INF, logits)
+        greedy_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        tok = jax.lax.cond(
+            jnp.any(~greedy),
+            lambda: jnp.where(
+                greedy, greedy_tok,
+                _draw_slots(logits, seeds, counters, temps, top_ks, top_ps)),
+            lambda: greedy_tok)
+        lp = jax.nn.log_softmax(logits, axis=-1)
+        tok_lp = jnp.take_along_axis(lp, tok[:, None], axis=-1)[:, 0]
+    return tok, tok_lp
+
+
+def _draw_slots(logits, seeds, counters, temps, top_ks, top_ps):
+    """One categorical draw a row of pad-masked ``[S, V]`` logits under
+    each row's temperature, top-k and top-p.  ONE stable sort orders the
+    vocabulary: it gives the descending values top-p accumulates over and
+    the order whose ``k_i``-th entry closes top-k — a row keeps what is
+    larger than its ``k_i``-th value and, of the ties at that value, the
+    indices up to the ``k_i``-th entry's own, which is what a stable rank
+    ``< k_i`` keeps."""
+    S, V = logits.shape
     scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
-    # dynamic per-slot top-k: rank 0 = largest
-    ranks = jnp.argsort(jnp.argsort(-scaled, axis=-1), axis=-1)
-    kmask = (top_ks[:, None] > 0) & (ranks >= top_ks[:, None])
+    idx = jax.lax.broadcasted_iota(jnp.int32, (S, V), 1)
+    neg_desc, order = jax.lax.sort((-scaled, idx), dimension=1,
+                                   is_stable=True, num_keys=1)
+    desc = -neg_desc
+    # dynamic per-slot top-k: position 0 = largest
+    k = top_ks[:, None]
+    kth = jnp.clip(k - 1, 0, V - 1)
+    kth_val = jnp.take_along_axis(desc, kth, axis=-1)
+    kth_idx = jnp.take_along_axis(order, kth, axis=-1)
+    kmask = (k > 0) & ((scaled < kth_val)
+                       | ((scaled == kth_val) & (idx > kth_idx)))
     scaled = jnp.where(kmask, NEG_INF, scaled)
-    # per-slot top-p (inline nucleus filter with a [S, 1] threshold)
+    # per-slot top-p (inline nucleus filter with a [S, 1] threshold) over
+    # the descending values of what top-k kept
     p_eff = jnp.where(top_ps > 0.0, top_ps, 1.0)[:, None]
-    sorted_logits = jnp.sort(scaled, axis=-1)[..., ::-1]
+    sorted_logits = jnp.where((k > 0) & (idx >= k), NEG_INF, desc)
     sorted_probs = jax.nn.softmax(sorted_logits, axis=-1)
     cum = jnp.cumsum(sorted_probs, axis=-1)
     remove_sorted = (cum - sorted_probs) > p_eff
@@ -461,10 +495,7 @@ def _sample_slots(logits, seeds, counters, greedy, temps, top_ks, top_ps,
                                                                counters)
     sampled = jax.vmap(
         lambda row, key: jax.random.categorical(key, row))(scaled, keys)
-    tok = jnp.where(greedy, greedy_tok, sampled.astype(jnp.int32))
-    lp = jax.nn.log_softmax(logits, axis=-1)
-    tok_lp = jnp.take_along_axis(lp, tok[:, None], axis=-1)[:, 0]
-    return tok, tok_lp
+    return sampled.astype(jnp.int32)
 
 
 def _lora_operand(arenas, slots, rank: int):
@@ -881,13 +912,14 @@ class _Inflight:
     chain through the KV pool, so while group g's tokens stream back the
     later groups keep the other pipeline stages busy (bubble fill)."""
 
-    __slots__ = ("tok", "tok_lp", "slots", "t_dispatch")
+    __slots__ = ("tok", "tok_lp", "slots", "t_dispatch", "sampling")
 
-    def __init__(self, tok, tok_lp, slots, t_dispatch):
+    def __init__(self, tok, tok_lp, slots, t_dispatch, sampling):
         self.tok = tok            # [S] device array (or per-group list)
         self.tok_lp = tok_lp      # [S] logprobs, same layout as ``tok``
         self.slots = slots
         self.t_dispatch = t_dispatch
+        self.sampling = sampling  # a slot of the step was not greedy
 
 
 class _PrefillState:
@@ -2019,6 +2051,7 @@ class ServingEngine:
             "engine_step", it0, time.perf_counter(), tid=0,
             args={"iter": self._iter, "batch": len(inflight.slots),
                   "route": self._decode_route,
+                  "sampling": inflight.sampling,
                   "pipelined": self.config.pipeline_decode})
 
     @property
@@ -2320,9 +2353,11 @@ class ServingEngine:
                 gap = min(wall, t0 - self._last_ready_t)
                 self.metrics.observe_step_breakdown(gap_frac=gap / wall)
         self._last_dispatch_t = t0
+        # what the device's predicate in _sample_slots will read
+        sampling = not greedy.all()
         self.metrics.inc_step(
             "fused" if self._fused_verify else "fallback",
-            self._precision_route)
+            self._precision_route, sampling)
         with device_annotation("verify"):
             g_tok, g_lp, k_pool, v_pool = self._verify(
                 self.cfg, self.params, self.slots.k_pool,
@@ -2400,6 +2435,7 @@ class ServingEngine:
             args={"iter": self._iter, "batch": len(drafts),
                   "route": ("spec_fused" if self._fused_verify
                             else "spec_fallback"),
+                  "sampling": sampling,
                   "pipelined": False, "proposed": proposed,
                   "accepted": accepted_total})
 
@@ -2530,9 +2566,11 @@ class ServingEngine:
                 gap = min(wall, t0 - self._last_ready_t)
                 self.metrics.observe_step_breakdown(gap_frac=gap / wall)
         self._last_dispatch_t = t0
+        # what the device's predicate in _sample_slots will read
+        sampling = not greedy.all()
         self.metrics.inc_step(
             "fused" if self._fused_verify else "fallback",
-            self._precision_route)
+            self._precision_route, sampling)
         with device_annotation("verify_tree"):
             g_tok, g_lp, k_pool, v_pool = self._verify_tree(
                 self.cfg, self.params, self.slots.k_pool,
@@ -2657,6 +2695,7 @@ class ServingEngine:
             args={"iter": self._iter, "batch": len(plans),
                   "route": ("spec_fused" if self._fused_verify
                             else "spec_fallback"),
+                  "sampling": sampling,
                   "pipelined": False, "tree": True, "proposed": proposed,
                   "accepted": accepted_total})
 
@@ -2705,7 +2744,11 @@ class ServingEngine:
                 self.metrics.observe_step_breakdown(gap_frac=gap / wall)
         self._last_dispatch_t = t0
 
-        self.metrics.inc_step(self._decode_route, self._precision_route)
+        # what the device's predicate in _sample_slots will read (on a pp
+        # mesh each group's own: a step counts once if any group samples)
+        sampling = not greedy.all()
+        self.metrics.inc_step(self._decode_route, self._precision_route,
+                              sampling)
         # Microbatch-interleaved dispatch: the slot batch is split into
         # G contiguous groups (G = pp on a pp>1 mesh, else 1) whose
         # decode calls chain through the donated KV pool — group g+1's
@@ -2762,8 +2805,8 @@ class ServingEngine:
             st.fill += 1   # the fed token's K/V row lands this step
             st.count += 1  # one more token sampled (possibly speculative)
         if G == 1:
-            return _Inflight(toks[0], tok_lps[0], snapshot, t0)
-        return _Inflight(toks, tok_lps, snapshot, t0)
+            return _Inflight(toks[0], tok_lps[0], snapshot, t0, sampling)
+        return _Inflight(toks, tok_lps, snapshot, t0, sampling)
 
     # tpulint: hot-path
     def _process_step_results(self, step: _Inflight) -> float:

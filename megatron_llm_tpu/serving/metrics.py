@@ -122,6 +122,12 @@ _COUNTERS = (
     # decode step of a non-speculating engine should land here, not in
     # fallback_steps (the gather route).
     "fused_steps", "fallback_steps", "paged_steps",
+    # decode/verify iterations in which at least one slot sampled, so the
+    # step's ``cond`` took the sampler's branch (engine.py:_sample_slots:
+    # one ordering of the vocabulary, the nucleus, the draw).  All-greedy
+    # traffic must leave it 0; sampled_steps over the route counters' sum
+    # is the share of steps that pay for sampling.
+    "sampled_steps",
     # automatic prefix caching (serving/prefix_cache.py): admissions that
     # reused cached shared-prefix K/V vs prefilled cold, and blocks LRU-
     # evicted under the prefix_cache_blocks budget.  A workload expected
@@ -266,13 +272,16 @@ class ServingMetrics:
         with self._lock:
             self.counters[name] += by
 
-    def inc_step(self, route: str, precision: str = "fp32") -> None:
+    def inc_step(self, route: str, precision: str = "fp32",
+                 sampling: bool = False) -> None:
         """One decode/verify iteration by the ``route`` it took —
         ``"fused"``, ``"paged"`` or ``"fallback"``: bumps the aggregate
         ``<route>_steps`` counter AND its per-precision breakdown
-        (``precision`` from ops/quant.py:precision_route)."""
+        (``precision`` from ops/quant.py:precision_route), and
+        ``sampled_steps`` where a slot of it ``sampling``."""
         with self._lock:
             self.counters[f"{route}_steps"] += 1
+            self.counters["sampled_steps"] += bool(sampling)
             r = self.step_routes.setdefault(
                 precision, {"fused": 0, "paged": 0, "fallback": 0})
             r[route] += 1

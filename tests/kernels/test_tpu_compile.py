@@ -39,7 +39,10 @@ from megatron_llm_tpu.kernels.rmsnorm import (  # noqa: E402
 )
 from megatron_llm_tpu.models import model as model_lib  # noqa: E402
 from megatron_llm_tpu.models.transformer import rope_tables  # noqa: E402
-from megatron_llm_tpu.obs.hlo_audit import relayout_bytes  # noqa: E402
+from megatron_llm_tpu.obs.hlo_audit import (  # noqa: E402
+    ops_by_conditional,
+    relayout_bytes,
+)
 from megatron_llm_tpu.ops import attention as attn_ops  # noqa: E402
 from megatron_llm_tpu.ops import lora as lora_ops  # noqa: E402
 from megatron_llm_tpu.ops import quant  # noqa: E402
@@ -371,7 +374,9 @@ def test_composed_decode_step_touches_only_live_kv(topo, monkeypatch, size,
     ``[L, S·T, kv, bk, d]`` — the re-layouts a row scatter draws and the
     gather are gone — and on one chip no copy, transpose or stand-alone
     slice writes 8 MiB or more: every weight is read once, where it
-    lies."""
+    lies.  The sampler's ordering of the vocabulary stayed under a
+    ``conditional`` (XLA turns small ones into selects that run both
+    sides): a step whose every slot is greedy sorts nothing."""
     from megatron_llm_tpu.config import falcon_config
     from megatron_llm_tpu.models import sharding as sharding_lib
     from megatron_llm_tpu.serving import engine as engine_lib
@@ -423,6 +428,8 @@ def test_composed_decode_step_touches_only_live_kv(topo, monkeypatch, size,
     # parent's rotary and lookup and still re-lays wq (ROADMAP S3)
     assert set(relayout_bytes(text)) <= ({"bf16[1,8192,4096]"} if tp
                                          else set())
+    sorts, always = ops_by_conditional(text, "sort")
+    assert 1 <= len(sorts) <= 2 and not always, (sorts, always)
 
 
 def test_sharded_flash_attention_fwd_bwd(topo, monkeypatch):

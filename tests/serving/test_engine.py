@@ -220,6 +220,57 @@ def test_sampled_trajectory_independent_of_batch(tiny):
     assert reseeded.tokens != alone.tokens  # overwhelmingly
 
 
+def test_sampling_request_rides_the_greedy_executable_and_is_counted(tiny):
+    """One decode executable serves greedy, sampling and mixed batches:
+    the device reads ``any(~greedy)`` from the vector the step is handed.
+    So a sampling request that joins greedy ones compiles nothing, and
+    the host, which filled that vector, counts the steps it rode
+    (``sampled_steps``, the ``engine_step`` span's ``sampling``); an
+    all-greedy run leaves the counter 0 and every span false."""
+    from megatron_llm_tpu.analysis.sanitizers import no_recompiles
+    from megatron_llm_tpu.obs import REGISTRY
+
+    cfg, params = tiny
+    # not pipelined: a pipelined engine dispatches one more (masked) step
+    # for a request before it learns the request is done
+    engine = _engine(cfg, params, pipeline_decode=False).start()
+
+    def run(first, last):
+        """Three greedy requests and ``last``; prompts from ``first`` on,
+        so that a second round shares no prefix with the first (a prefix
+        hit prefills through another executable)."""
+        engine.pause()   # one admission round, so the four share steps
+        hs = [engine.submit([first + i, 11, 3], max_new_tokens=12,
+                            use_eos_stop=False) for i in range(3)]
+        hs.append(engine.submit([first + 3, 9, 3], use_eos_stop=False,
+                                **last))
+        engine.resume()
+        return [h.result(timeout=600) for h in hs]
+
+    def steps():
+        return [e["args"]["sampling"]
+                for e in engine.trace.chrome_trace()["traceEvents"]
+                if e["name"] == "engine_step"]
+
+    try:
+        run(7, dict(max_new_tokens=5))
+        greedy_steps = steps()
+        assert greedy_steps and not any(greedy_steps)
+        assert engine.metrics.snapshot()["sampled_steps"] == 0
+        with no_recompiles():
+            out = run(20, dict(max_new_tokens=5, temperature=0.8, top_k=8,
+                               seed=123))
+        snap = engine.metrics.snapshot()
+        mixed_steps = steps()[len(greedy_steps):]
+    finally:
+        engine.shutdown()
+    assert all(r.finish_reason == "length" for r in out)
+    # the first of its 5 tokens is the prefill's; 4 decode steps carried it
+    assert snap["sampled_steps"] == 4 == sum(mixed_steps)
+    assert len(mixed_steps) == 11 and mixed_steps[:4] == [True] * 4
+    assert "serving_sampled_steps_total 4" in REGISTRY.prometheus_text()
+
+
 def test_admission_validation(tiny):
     cfg, params = tiny
     engine = _engine(cfg, params)

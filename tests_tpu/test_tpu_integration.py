@@ -224,12 +224,18 @@ def test_decode_step_moves_no_weight_and_matches_reference():
     each layer's ``wq`` was sliced out and re-laid, 2 x 41.3 MB, and the
     tied table copied for the embedding gather, 591 MB) — and the
     log-probs the same executable gives its greedy tokens are the plain
-    reference's, inside the serving cells' tolerance."""
+    reference's, inside the serving cells' tolerance.  The sampler's
+    sort of the vocabulary lies under the ``conditional`` XLA kept, and
+    the same executable, handed one sampling slot, gives the greedy rows
+    the tokens of the all-greedy run."""
     from benchmarks.reference import falcon as reference
     from benchmarks.serving import LOGPROB_MAX_TOL, LOGPROB_MEAN_TOL
     from megatron_llm_tpu.config import falcon_config
     from megatron_llm_tpu.models import model as model_lib
-    from megatron_llm_tpu.obs.hlo_audit import relayout_bytes
+    from megatron_llm_tpu.obs.hlo_audit import (
+        ops_by_conditional,
+        relayout_bytes,
+    )
     from megatron_llm_tpu.serving import engine as engine_lib
 
     slots, t, bk, steps = 16, 16, 128, 12
@@ -240,25 +246,32 @@ def test_decode_step_moves_no_weight_and_matches_reference():
     assert model_lib.paged_decode_eligible(cfg, pools[0])
     tables = 1 + jnp.arange(slots * t, dtype=jnp.int32).reshape(slots, t)
     zeros = jnp.zeros((slots,), jnp.int32)
-    knobs = (zeros, zeros, jnp.ones((slots,), bool),          # greedy
-             jnp.ones((slots,), jnp.float32), zeros,
-             jnp.zeros((slots,), jnp.float32))
-    pending = jax.random.randint(jax.random.key(1), (slots,), 1,
-                                 cfg.vocab_size - 1)
+    first = jax.random.randint(jax.random.key(1), (slots,), 1,
+                               cfg.vocab_size - 1)
+    all_greedy = (jnp.ones((slots,), bool), jnp.ones((slots,), jnp.float32),
+                  zeros, jnp.zeros((slots,), jnp.float32))
     step = engine_lib._decode_donated.lower(
-        cfg, params, *pools, tables, pending, zeros, *knobs,
-        use_fused=False).compile()
+        cfg, params, *pools, tables, first, zeros, zeros, zeros,
+        *all_greedy, use_fused=False).compile()
     text = step.as_text()
     assert "tpu_custom_call" in text
     assert relayout_bytes(text) == {}
+    sorts, always = ops_by_conditional(text, "sort")
+    assert 1 <= len(sorts) <= 2 and not always, (sorts, always)
 
-    tokens, logprobs = [np.asarray(pending)], []
-    for i in range(steps):
-        pending, lp, *pools = step(params, *pools, tables, pending,
-                                   jnp.full((slots,), i, jnp.int32), *knobs)
-        tokens.append(np.asarray(pending))
-        logprobs.append(np.asarray(lp, np.float32))
-    tokens, logprobs = np.stack(tokens, 1), np.stack(logprobs, 1)
+    def rollout(knobs):
+        pending, pools = first, model_lib.init_kv_pool(cfg, 1 + slots * t, bk)
+        tokens, logprobs = [np.asarray(pending)], []
+        for i in range(steps):
+            counters = jnp.full((slots,), i, jnp.int32)
+            pending, lp, *pools = step(params, *pools, tables, pending,
+                                       counters, zeros + 7, counters, *knobs)
+            tokens.append(np.asarray(pending))
+            logprobs.append(np.asarray(lp, np.float32))
+        return np.stack(tokens, 1), np.stack(logprobs, 1)
+
+    del pools
+    tokens, logprobs = rollout(all_greedy)
     meta = reference.meta_of(cfg)
     d = np.stack([
         np.abs(logprobs[s] - np.asarray(
@@ -267,3 +280,17 @@ def test_decode_step_moves_no_weight_and_matches_reference():
     assert np.isfinite(d).all()
     assert d.max() <= LOGPROB_MAX_TOL and d.mean() <= LOGPROB_MEAN_TOL, (
         d.max(), d.mean())
+
+    row = 5
+    one_samples = (all_greedy[0].at[row].set(False),
+                   all_greedy[1].at[row].set(0.8),
+                   all_greedy[2].at[row].set(50),
+                   all_greedy[3].at[row].set(0.9))
+    mixed_tokens, mixed_logprobs = rollout(one_samples)
+    rest = np.arange(slots) != row
+    np.testing.assert_array_equal(mixed_tokens[rest], tokens[rest])
+    np.testing.assert_allclose(mixed_logprobs[rest], logprobs[rest],
+                               rtol=0, atol=1e-5)
+    assert (mixed_tokens[row] != tokens[row]).any()
+    assert (mixed_tokens[row] < cfg.vocab_size).all()
+    assert np.isfinite(mixed_logprobs[row]).all()
