@@ -42,6 +42,9 @@ class Result:
     end_to_end: Dict[str, float]           # every metric the run took
     evidence: Dict[str, Any]               # what the per-layer readers read
     notes: List[str] = dataclasses.field(default_factory=list)
+    # what decided ``correct``: name -> (the number, the limit it may
+    # not pass); printed last on standard error and in the result line
+    compared: Dict[str, tuple] = dataclasses.field(default_factory=dict)
 
 
 # --- the program's spans and its profile session (--trace 2) ----------------

@@ -117,4 +117,7 @@ def run(ctx: Ctx):
         attempted=len(done), failed=failed,
         end_to_end={"serve_tokens_per_s": tokens / ctx.seconds,
                     "setup_s": t_start - ctx.t0},
-        evidence=evidence, notes=notes)
+        evidence=evidence, notes=notes,
+        compared={**sv.compared, "compiles_in_window": (compiles, 0),
+                  "bad_finishes": (failed, 0),
+                  "backlog_ran_out": (int(unfinished == 0), 0)})

@@ -87,9 +87,11 @@ def traced_replay(ctx: Ctx, sv: serving.Serving, requests, served):
 def sweep(ctx: Ctx, sv: serving.Serving) -> None:
     mix = sv.mix
     seconds = float(mix["sweep_seconds"])
+    cols = ("ttft_p50_ms", "ttft_p95_ms", "itl_p50_ms", "itl_p95_ms")
+    more = ("ttft_p90_ms", "itl_p99_ms")
     say("sweep: rate_rps offered completed_in_window completed_rps "
-        "queue_start queue_end ttft_p50_ms ttft_p95_ms itl_p50_ms "
-        "itl_p95_ms unfinished late_max_ms")
+        f"queue_start queue_end {' '.join(cols)} unfinished late_max_ms "
+        f"{' '.join(more)} n_ttft n_gaps")
     for rate in mix["sweep_rates"]:
         reqs = traffic.serve_requests(mix, ctx.seed, seconds,
                                       sv.model.vocab_size, rate_rps=rate)
@@ -100,13 +102,15 @@ def sweep(ctx: Ctx, sv: serving.Serving) -> None:
         in_window = sum(1 for s in served if s.done
                         and t_start <= s.stamps[-1] < t_start + seconds)
         rep = serving.latency_report(counted)
+
+        def ms(names):
+            return " ".join(f"{rep.get(n, float('nan')):.1f}" for n in names)
+
         say(f"sweep: {rate} {len(counted)} {in_window} "
             f"{in_window / seconds:.3f} {depth['start']} {depth['end']} "
-            f"{rep.get('ttft_p50_ms', float('nan')):.1f} "
-            f"{rep.get('ttft_p95_ms', float('nan')):.1f} "
-            f"{rep.get('itl_p50_ms', float('nan')):.1f} "
-            f"{rep.get('itl_p95_ms', float('nan')):.1f} "
-            f"{sum(not s.done for s in counted)} {1e3 * max(late):.1f}")
+            f"{ms(cols)} {sum(not s.done for s in counted)} "
+            f"{1e3 * max(late):.1f} {ms(more)} {rep['n_ttft']} "
+            f"{rep['n_gaps']}")
         sv.wait_idle(served, time.perf_counter() + 120.0)
 
 
@@ -136,25 +140,37 @@ def run(ctx: Ctx):
         evidence = serving.layer_evidence(
             sv, sl, (t_start, t_start + ctx.seconds))
         rep = serving.latency_report(counted)
+        spans = serving.recorder_spans(sv.engine, t_start,
+                                       t_start + ctx.seconds)
+        stalls = serving.stall_report(served, spans, t_start, ctx.seconds)
         if ctx.trace == 2:
             untouched = common.before_traced_phase()
-            evidence = serving.window_evidence(
-                sv, serving.recorder_spans(sv.engine, t_start,
-                                           t_start + ctx.seconds))
+            evidence = serving.window_evidence(sv, spans)
             evidence.update(serving.traced_phase_evidence(
                 sv, traced_replay(ctx, sv, requests, served)))
+        evidence["latency"] = rep      # the percentiles a cell only records
     finally:
         sv.close(served)
+    judged = [m["name"] for m in ctx.manifest.metrics_of(
+        ctx.cell["name"], "end_to_end") if m["name"] != "setup_s"]
+
+    def ladder(kind, fmt):
+        return " ".join([f"p{p} {rep[f'{kind}_p{p}_ms']:{fmt}}"
+                         for p in serving.PERCENTILES]
+                        + [f"mean {rep[f'{kind}_mean_ms']:.3f}"])
+
     say(f"window: {len(counted)} requests due in {ctx.seconds:.0f} s at "
-        f"{mix['rate_rps']} a second (of {len(served)} sent); ttft p50 "
-        f"{rep['ttft_p50_ms']:.1f} ms p95 {rep['ttft_p95_ms']:.1f} ms over "
-        f"{rep['n_ttft']} requests ({stats.samples_beyond(rep['n_ttft'], 95)}"
-        f" beyond the 95th); gap p50 {rep['itl_p50_ms']:.2f} ms p95 "
-        f"{rep['itl_p95_ms']:.2f} ms over {rep['n_gaps']} gaps")
+        f"{mix['rate_rps']} a second (of {len(served)} sent); ttft "
+        f"{ladder('ttft', '.1f')} ms; gap {ladder('itl', '.2f')} ms")
     say(f"load generator ran late by at most {1e3 * max(late):.2f} ms "
         f"(p95 {1e3 * stats.percentile(late, 95):.2f} ms); queue depth "
         f"{depth['start']} at the window's start, {depth['end']} at its end")
+    worst = max(range(len(late)), key=late.__getitem__)
+    say(f"its latest submission was the one due at "
+        f"{served[worst].due - t_start:.1f} s; {stalls}")
     notes = sv.correct_notes + [
+        f"n_ttft {rep['n_ttft']}, n_gaps {rep['n_gaps']}; "
+        + serving.tail_counts(rep, judged),
         f"compilations inside the window: {compiles}",
         f"requests that did not end 'length' with every token inside the "
         f"drain limit: {failed} of {len(counted)}"]
@@ -163,7 +179,8 @@ def run(ctx: Ctx):
     return Result(
         correct=sv.correct and compiles == 0 and failed == 0,
         attempted=len(counted), failed=failed,
-        end_to_end={"ttft_p95_ms": rep["ttft_p95_ms"],
-                    "itl_p95_ms": rep["itl_p95_ms"],
+        end_to_end={**{name: rep[name] for name in judged},
                     "setup_s": t_start - ctx.t0},
-        evidence=evidence, notes=notes)
+        evidence=evidence, notes=notes,
+        compared={**sv.compared, "compiles_in_window": (compiles, 0),
+                  "bad_finishes": (failed, 0)})

@@ -225,8 +225,9 @@ def run(ctx: Ctx) -> Result:
              f"{LOSS_TOL})",
              f"compilations inside the window: {compiles}",
              f"skipped or anomalous or non-finite steps: {bad_steps}"]
+    not_finite = sum(not math.isfinite(x) for x in losses)
     correct = (loss_gap <= LOSS_TOL and compiles == 0 and bad_steps == 0
-               and all(math.isfinite(x) for x in losses))
+               and not_finite == 0)
     sizes = flops.sizes_of(model)
     traced = {}
     if ctx.trace == 2:
@@ -254,4 +255,8 @@ def run(ctx: Ctx) -> Result:
                   "train_flops_per_token": flops.train_flops_per_token(
                       sizes, seq),
                   "step_module": "jit_step"},
-        notes=notes)
+        notes=notes,
+        compared={"first_batch_loss_gap": (loss_gap, LOSS_TOL),
+                  "compiles_in_window": (compiles, 0),
+                  "bad_steps": (bad_steps, 0),
+                  "losses_not_finite": (not_finite, 0)})

@@ -28,6 +28,7 @@ _T0 = time.perf_counter()     # process start, for setup_s
 
 import argparse
 import json
+import math
 import os
 import shutil
 import sys
@@ -126,6 +127,17 @@ def main(argv=None) -> int:
 
         layer_report.fill(ctx, result, line)
     shutil.rmtree(trace_dir, ignore_errors=True)
+    # what decided ``correct``, each number beside its limit: the last key
+    # of the line and the last lines on standard error
+    # (a number that is not finite has failed; 1e300 keeps the line JSON)
+    line["compared"] = {
+        k: {"value": float(v) if math.isfinite(v) else 1e300,
+            "limit": float(lim)}
+        for k, (v, lim) in result.compared.items()}
+    for k, c in line["compared"].items():
+        print(f"compared: {k} {c['value']:.6g} (limit {c['limit']:.6g})",
+              file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(line), flush=True)
     return 0
 

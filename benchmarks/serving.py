@@ -80,6 +80,7 @@ class Serving:
         self.pool_blocks = pool.usable_blocks
         self.gauges: Dict[str, List[int]] = {"blocks_used": []}
         self.correct_notes: List[str] = []
+        self.compared: Dict[str, tuple] = {}   # name: (number, its limit)
         self.correct = True
 
     # -- requests ----------------------------------------------------------
@@ -170,7 +171,7 @@ class Serving:
                                         size=s.prompt_len).tolist(),
                         logprobs=True)
             served.append(s)
-        worst, total, count = 0.0, 0.0, 0
+        worst, total, count, bad = 0.0, 0.0, 0, 0
         meta = ref.meta_of(self.model)
         for s in served:
             got = s.handle.result(timeout=900)
@@ -178,12 +179,15 @@ class Serving:
                   and len(got.tokens) == s.prompt_len + s.max_new)
             want = np.asarray(ref.token_logprobs(self.params, got.tokens, meta))
             d = np.abs(np.asarray(got.logprobs, np.float32) - want)
-            ok = ok and bool(np.all(np.isfinite(d)))
-            self.correct = self.correct and ok
+            bad += not (ok and bool(np.all(np.isfinite(d))))
             worst = max(worst, float(d.max()))
             total, count = total + float(d.sum()), count + d.size
         mean = total / count
-        self.correct = (self.correct and worst <= LOGPROB_MAX_TOL
+        self.compared = {"logprob_max_gap": (worst, LOGPROB_MAX_TOL),
+                         "logprob_mean_gap": (mean, LOGPROB_MEAN_TOL),
+                         "check_sequences_cut_or_not_finite": (bad, 0)}
+        self.correct = (self.correct and bad == 0
+                        and worst <= LOGPROB_MAX_TOL
                         and mean <= LOGPROB_MEAN_TOL)
         self.correct_notes.append(
             f"engine vs reference log-probs over {count} positions of "
@@ -309,20 +313,72 @@ def traced_phase_evidence(sv: Serving, session, window=None) -> dict:
         lambda trace: common.on_trace_clock(trace, session, ())[0])
 
 
+# the percentiles a cell may judge or record: an end-to-end metric of a
+# ``serve_open`` cell is ``ttft_p<NN>_ms`` or ``itl_p<NN>_ms`` with NN
+# here, or the mean over all of the window's samples, ``<kind>_mean_ms``
+PERCENTILES = (25, 50, 75, 90, 95, 97, 98, 99)
+
+
 def latency_report(served: List[Served]) -> dict:
     """TTFT from when a request was due, and the gaps between its
-    tokens, pooled over the counted requests."""
+    tokens, pooled over the counted requests: ``ttft_p<NN>_ms`` and
+    ``itl_p<NN>_ms`` for every NN of ``PERCENTILES``, ``ttft_mean_ms``
+    and ``itl_mean_ms``, and the counts ``n_ttft`` and ``n_gaps`` they
+    were taken over."""
     ttft = [1e3 * (s.stamps[0] - s.due) for s in served if s.stamps]
     gaps = [1e3 * (b - a) for s in served
             for a, b in zip(s.stamps, s.stamps[1:])]
     out = {"n_ttft": len(ttft), "n_gaps": len(gaps)}
-    if ttft:
-        out.update(ttft_p50_ms=stats.percentile(ttft, 50),
-                   ttft_p95_ms=stats.percentile(ttft, 95))
-    if gaps:
-        out.update(itl_p50_ms=stats.percentile(gaps, 50),
-                   itl_p95_ms=stats.percentile(gaps, 95))
+    for name, values in (("ttft", ttft), ("itl", gaps)):
+        if values:
+            xs = sorted(values)
+            out.update({f"{name}_p{p}_ms": stats.percentile(xs, p)
+                        for p in PERCENTILES})
+            out[f"{name}_mean_ms"] = sum(xs) / len(xs)
     return out
+
+
+def tail_counts(rep: dict, names) -> str:
+    """For each latency metric in ``names`` (``<kind>_p<NN>_ms`` or
+    ``<kind>_mean_ms``): how many samples it was taken over and, for a
+    percentile, how many lie beyond it (``stats.samples_beyond``; under
+    ten, a maximum and no tail)."""
+    out = []
+    for name in names:
+        kind, stat = name.split("_")[:2]
+        n = rep["n_ttft" if kind == "ttft" else "n_gaps"]
+        beyond = (f", {stats.samples_beyond(n, int(stat[1:]))} beyond it"
+                  if stat != "mean" else "")
+        out.append(f"{name} {rep[name]:.3f} ms over {n} samples{beyond}")
+    return "; ".join(out)
+
+
+def stall_report(served: List[Served], spans, t_start: float,
+                 seconds: float, top: int = 3) -> str:
+    """Where a window's tail came from: the longest silences between any
+    two tokens of the window (all streams pooled; with streams running a
+    silence is a step, or a step and the prefills of one admission) and
+    the engine's longest spans, each with its offset into the window.
+    A run that stalled is reported as one; no bound is widened for it."""
+    t_end = t_start + seconds
+    stamps = sorted(t for s in served for t in s.stamps
+                    if t_start <= t < t_end)
+    quiet = sorted(((b - a, a - t_start)
+                    for a, b in zip(stamps, stamps[1:])), reverse=True)
+    # (a step's span is recorded once a stream: one entry a start)
+    longest = sorted({(round(d, 4), round(t0 - t_start, 2), n)
+                      for n, t0, d, _a in spans if n != "queued"},
+                     reverse=True)
+
+    def some(rows, fmt):
+        return ", ".join(fmt(r) for r in rows[:top]) or "none"
+
+    return ("longest silences between tokens: "
+            + some(quiet, lambda r: f"{1e3 * r[0]:.0f} ms at {r[1]:.1f} s")
+            + f" ({sum(1 for r in quiet if r[0] > 0.5)} over 500 ms); "
+            + "longest engine spans: "
+            + some(longest, lambda r: f"{r[2]} {1e3 * r[0]:.0f} ms at "
+                                      f"{r[1]:.1f} s"))
 
 
 def bad_finishes(served: List[Served]) -> int:

@@ -128,7 +128,7 @@ def test_additions_need_no_edit_of_an_existing_file(tmp_path):
     cfg = json.loads((b / "configs" / "falcon-7b.json").read_text())
     cfg["by_kind"]["serve_open"]["num_hidden_layers"] = 16
     (b / "configs" / "falcon-7b-half.json").write_text(json.dumps(cfg))
-    mix = json.loads((b / "traffic" / "chat.json").read_text())
+    mix = json.loads((b / "traffic" / "chat-knee.json").read_text())
     mix["rate_rps"] = 0.5
     (b / "traffic" / "chat-slow.json").write_text(json.dumps(mix))
     (b / "readers" / "always_seven.py").write_text(
@@ -136,7 +136,7 @@ def test_additions_need_no_edit_of_an_existing_file(tmp_path):
     (b / "layer_metrics" / "sevens.chat-slow.json").write_text(json.dumps({
         "layer": "scheduler (engine.py:_loop_body)", "unit": "ms",
         "better": "lower", "source": "program_counter",
-        "moves": "itl_p95_ms", "workloads": ["half-chat-slow"],
+        "moves": "itl_p50_ms", "workloads": ["half-chat-slow"],
         "reader": "always_seven", "params": {"times": 3}}))
     doc = json.loads((root / "BENCHMARK.json").read_text())
     doc["configs"].append({
@@ -147,12 +147,12 @@ def test_additions_need_no_edit_of_an_existing_file(tmp_path):
         "name": "half-chat-slow", "config": "falcon-7b-half",
         "traffic": "chat-slow", "chips": 1, "why": "test"})
     for m in doc["end_to_end"]:
-        if m["name"] in ("ttft_p95_ms", "itl_p95_ms"):
+        if m["name"] in ("ttft_mean_ms", "itl_p50_ms", "itl_p98_ms"):
             m["workloads"].append("half-chat-slow")
     doc["per_layer"].append({
         "name": "sevens.chat-slow", "unit": "ms", "better": "lower",
         "source": "program_counter",
-        "layer": "scheduler (engine.py:_loop_body)", "moves": "itl_p95_ms",
+        "layer": "scheduler (engine.py:_loop_body)", "moves": "itl_p50_ms",
         "workloads": ["half-chat-slow"]})
     (root / "BENCHMARK.json").write_text(json.dumps(doc))
 
@@ -170,5 +170,5 @@ def test_additions_need_no_edit_of_an_existing_file(tmp_path):
     loaded.loader.exec_module(mod)
     assert mod.read({}, spec["params"]) == 21.0
     assert {m["name"] for m in man.metrics_of("half-chat-slow", "end_to_end")
-            } == {"ttft_p95_ms", "itl_p95_ms", "setup_s"}
+            } == {"ttft_mean_ms", "itl_p50_ms", "itl_p98_ms", "setup_s"}
     assert all(p.read_bytes() == data for p, data in before.items())

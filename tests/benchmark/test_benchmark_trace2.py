@@ -57,8 +57,16 @@ def test_trace2_line_has_the_keys_of_a_trace0_line(workload):
     plain, _ = rehearse(workload, 0)
     both, _ = rehearse(workload, 2)
     assert set(plain) == {"correct", "attempted", "failed", "metrics",
-                          "device"}
+                          "device", "compared"}
     assert set(both) - {"breakdown"} == set(plain)
+    # what decided ``correct``, each number beside its limit, comes last
+    assert list(plain)[-1] == list(both)[-1] == "compared"
+    assert "compiles_in_window" in plain["compared"]
+    for line in (plain, both):
+        assert all(set(c) == {"value", "limit"}
+                   for c in line["compared"].values())
+        assert line["correct"] == all(
+            c["value"] <= c["limit"] for c in line["compared"].values())
     man = Manifest(ROOT)
     e2e = {m["name"] for m in man.metrics_of(workload, "end_to_end")}
     assert set(plain["metrics"]) == e2e

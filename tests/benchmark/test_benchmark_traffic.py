@@ -10,7 +10,7 @@ BIG = 3_000_000_019          # the driver's seeds pass 2**31
 
 @pytest.fixture(scope="module")
 def chat():
-    return Manifest(ROOT).traffic("chat")
+    return Manifest(ROOT).traffic("chat-knee")
 
 
 def shape(reqs):
