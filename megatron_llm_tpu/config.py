@@ -211,6 +211,47 @@ class ModelConfig:
     # saves ~[b,s,vocab] fp32 of HBM when the head dominates memory, at
     # the price of a backward that recomputes the head's matmul.
     fused_lm_head: bool = False
+    # Width of an attention head where it is not hidden / heads.
+    kv_channels: Optional[int] = None
+    # Hybrid stacks: the block kinds of one period of layers, "full"
+    # (softmax attention) or "linear" (Gated DeltaNet,
+    # models/gated_deltanet.py); the stack is scanned by period
+    # (models/transformer.py) and num_layers is a multiple of it.
+    # () = every layer "full": the one-kind stack.
+    layer_pattern: tuple = ()
+    # Gated DeltaNet geometry: key heads x key width, value heads x value
+    # width (value heads a multiple of key heads), causal depthwise
+    # convolution taps over the q|k|v channels.
+    linear_num_key_heads: int = 16
+    linear_num_value_heads: int = 32
+    linear_key_head_dim: int = 128
+    linear_value_head_dim: int = 128
+    linear_conv_kernel: int = 4
+    # softmax-attention variants: share of each head's width that is
+    # rotated (rotate-half over the first rotary_percent * head_dim
+    # dimensions; 1.0 = the interleaved full-width rotation of ops/rope.py),
+    # per-head RMSNorm of q and k, and a sigmoid gate on the attention
+    # output projected beside q (wq is then [h, heads * 2 * head_dim]:
+    # per head, query then gate).
+    rotary_percent: float = 1.0
+    qk_norm: bool = False
+    attn_output_gate: bool = False
+    # Dropless routing (softmax over the router's outputs, top-k,
+    # renormalise; no capacity, no drop) beside the capacity routing above.
+    # The router keeps moe_router_experts outputs (0 = num_experts) of which
+    # this parameter tree holds num_experts consecutive ones starting at
+    # moe_expert_offset: one expert-parallel rank's share, whose partial sum
+    # the layer returns.  moe_shared_expert_size > 0 adds a sigmoid-gated
+    # shared expert of that width, whole on every rank.
+    moe_dropless: bool = False
+    moe_router_experts: int = 0
+    moe_expert_offset: int = 0
+    moe_shared_expert_size: int = 0
+
+    def __post_init__(self):
+        # a JSON list (checkpointed arguments, a benchmark's overrides):
+        # the config is a static argument of every jitted step, so hashed
+        object.__setattr__(self, "layer_pattern", tuple(self.layer_pattern))
 
     @property
     def kv_heads(self) -> int:
@@ -218,7 +259,27 @@ class ModelConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.hidden_size // self.num_attention_heads
+        return self.kv_channels or self.hidden_size // self.num_attention_heads
+
+    @property
+    def router_experts(self) -> int:
+        return self.moe_router_experts or self.num_experts
+
+    @property
+    def layer_kinds(self) -> tuple:
+        """The block kind of every layer, in order."""
+        period = self.layer_pattern or ("full",)
+        return period * (self.num_layers // len(period))
+
+    @property
+    def kv_layers(self) -> int:
+        """Layers that keep keys and values: the KV pool's layer axis."""
+        return self.layer_kinds.count("full")
+
+    @property
+    def linear_layers(self) -> int:
+        """Layers that keep a recurrent state (serving/slots.py)."""
+        return self.layer_kinds.count("linear")
 
     @property
     def ffn_size(self) -> int:
@@ -245,11 +306,26 @@ class ModelConfig:
         return ((self.vocab_size + multiple - 1) // multiple) * multiple
 
     def validate(self) -> "ModelConfig":
-        assert self.hidden_size % self.num_attention_heads == 0
+        assert self.kv_channels or (
+            self.hidden_size % self.num_attention_heads == 0)
         assert self.num_attention_heads % self.kv_heads == 0
+        if self.layer_pattern:
+            assert set(self.layer_pattern) <= {"full", "linear"}, (
+                f"unknown block kind in {self.layer_pattern!r}")
+            assert self.num_layers % len(self.layer_pattern) == 0, (
+                f"num_layers {self.num_layers} is not whole periods of "
+                f"{len(self.layer_pattern)} layers")
+            assert (self.linear_num_value_heads
+                    % self.linear_num_key_heads == 0)
+        if self.moe_dropless:
+            assert self.num_experts > 0
+            assert (0 <= self.moe_expert_offset and self.moe_expert_offset
+                    + self.num_experts <= self.router_experts), (
+                "the held experts lie outside the router's outputs")
+            assert self.moe_top_k <= self.router_experts
         if self.parallel_layernorm:
             assert self.parallel_attn, "parallel_layernorm requires parallel_attn"
-        if self.num_experts > 0:
+        if self.num_experts > 0 and not self.moe_dropless:
             assert 1 <= self.moe_top_k <= self.num_experts, (
                 f"moe_top_k {self.moe_top_k} must be in "
                 f"[1, num_experts={self.num_experts}]")
@@ -721,6 +797,61 @@ def falcon_config(size: str = "7b", **overrides) -> ModelConfig:
     return ModelConfig(**base).validate()
 
 
+def qwen3_next_config(size: str = "80b-a3b", **overrides) -> ModelConfig:
+    """Qwen3-Next: three Gated DeltaNet layers to one gated softmax
+    attention layer (256-wide heads, a quarter of each rotated, q and k
+    normalised per head), zero-centred RMSNorm, and in every layer 512
+    softmax-routed experts (top 10, renormalised, no drop) beside a
+    sigmoid-gated shared one; untied head.  Served only: the training step
+    does not run the chunked delta rule or the dropless experts.
+
+    ``80b-a3b`` is the published model.  ``80b-a3b-ep2-rank0`` is what one
+    chip of an expert-parallel pair holds: experts 0-255 of the 512 (the
+    router keeps its 512 outputs and 10 choices) and rows 0-75967 of the
+    151936-row embedding and head."""
+    base = dict(
+        norm_type="rmsnorm_zero",
+        norm_eps=1e-6,
+        activation="swiglu",
+        position_embedding_type=PositionEmbeddingType.ROTARY,
+        rope_theta=1.0e7,
+        rotary_percent=0.25,
+        use_bias=False,
+        tie_embed_logits=False,
+        hidden_size=2048,
+        num_layers=48,
+        num_attention_heads=16,
+        num_kv_heads=2,
+        kv_channels=256,
+        qk_norm=True,
+        attn_output_gate=True,
+        layer_pattern=("linear", "linear", "linear", "full"),
+        ffn_hidden_size=512,
+        num_experts=512,
+        moe_top_k=10,
+        moe_dropless=True,
+        moe_shared_expert_size=512,
+        # a whole 16k-position prompt is routed at once: a held expert
+        # then multiplies some hundreds of rows and not some tens
+        moe_group_size=16384,
+        vocab_size=151936,
+        max_position_embeddings=262144,
+        seq_length=4096,
+        fused_decode=False,
+        recompute="none",
+    )
+    sizes = {
+        "80b-a3b": dict(),
+        # (75968 = 1187 x 64: the half table is not whole 128-row tiles)
+        "80b-a3b-ep2-rank0": dict(num_experts=256, moe_router_experts=512,
+                                  moe_expert_offset=0, vocab_size=75968,
+                                  make_vocab_size_divisible_by=64),
+    }
+    base.update(sizes[size])
+    base.update(overrides)
+    return ModelConfig(**base).validate()
+
+
 def gpt_config(size: str = "345m", **overrides) -> ModelConfig:
     """GPT-2/3 style: learned absolute positions, LayerNorm, gelu, tied
     embeddings, biases (reference: megatron/model/gpt_model.py)."""
@@ -779,6 +910,7 @@ PRESETS = {
     "falcon-7b": lambda: falcon_config("7b"),
     "falcon-40b": lambda: falcon_config("40b"),
     "gpt-345m": lambda: gpt_config("345m"),
+    "qwen3-next-80b-a3b": lambda: qwen3_next_config("80b-a3b"),
     "tiny": tiny_config,
 }
 

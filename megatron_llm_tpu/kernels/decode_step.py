@@ -861,7 +861,7 @@ def _stack_eligible(cfg, params, platform: str):
         # sharded caches/params: the kernel is single-device; the mesh
         # paths keep the composed stack (ops/attention shard_map kernels)
         return None
-    if (cfg.norm_type != "rmsnorm" or cfg.parallel_attn
+    if (cfg.layer_pattern or cfg.norm_type != "rmsnorm" or cfg.parallel_attn
             or cfg.num_experts > 0 or cfg.use_bias or cfg.qkv_bias
             or not is_glu(cfg.activation)
             or cfg.activation not in _GLU_BASE

@@ -1,0 +1,248 @@
+"""Gated DeltaNet: the linear-attention mixer of a hybrid stack.
+
+A layer keeps, for each of its value heads, a matrix state ``S`` (key
+width x value width, float32) in place of keys and values, and updates it
+by the gated delta rule (Yang, Kautz, Hatamizadeh: "Gated Delta Networks",
+arXiv 2412.06464), a position at a time::
+
+    S <- exp(g_t) S;  d_t = beta_t (v_t - S^T k_t);  S <- S + k_t (x) d_t
+    o_t = S^T q_t
+
+with ``q | k | v`` first passed through a short causal depthwise
+convolution and SiLU, q and k L2-normalised, ``beta = sigmoid(b)`` and
+``g = -exp(A_log) softplus(a + dt_bias)`` a value head.  The output is
+RMS-normalised a head, gated by ``SiLU(z)`` and projected back.
+
+Two forms of the same recurrence: ``delta_rule_step`` for the one new
+position of a decode step, and ``delta_rule_chunked`` for a prompt, which
+rearranges ``CHUNK`` positions at a time into matrix products (the WY
+form of the paper's section 3) and carries ``S`` from chunk to chunk in a
+``lax.scan``.  A position whose ``valid`` is false (the padded tail of a
+prefill bucket) has ``beta = 0`` and ``g = 0``: it changes neither ``S``
+nor the convolution's tail, whatever it holds.
+
+The fused input projection is laid out flat, ``[q | k | v | z]`` (key
+heads x key width twice, value heads x value width twice) and ``[b | a]``;
+the published checkpoint groups the same columns by key head, which is a
+fixed permutation of the columns of ``w_qkvz`` and ``w_ba``.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import NamedTuple, Optional
+
+import jax
+import jax.numpy as jnp
+
+from ..config import ModelConfig
+from ..ops.precision import dot_f32
+
+Params = dict
+
+CHUNK = 64
+L2_EPS = 1e-6
+# the state and everything that meets it is float32, and its products are
+# taken at full float32 precision: XLA:TPU's default for float32 operands
+# is one bfloat16 pass
+_PREC = jax.lax.Precision.HIGHEST
+
+
+class GDNState(NamedTuple):
+    """What a linear layer keeps of a sequence: ``S`` [b, value heads,
+    key width, value width], and ``conv`` [b, taps - 1, channels]: the
+    convolution's last inputs; both float32 (a tail rounded to bfloat16
+    would round the next positions' q, k and v before the rule)."""
+
+    S: jax.Array
+    conv: jax.Array
+
+
+def dims(cfg: ModelConfig):
+    """(key heads, value heads, key width, value width, conv channels)."""
+    nk, nv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
+    dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+    return nk, nv, dk, dv, 2 * nk * dk + nv * dv
+
+
+def init_gdn_params(key: jax.Array, cfg: ModelConfig) -> Params:
+    h, dtype, std = cfg.hidden_size, cfg.dtype, cfg.init_method_std
+    nk, nv, dk, dv, ch = dims(cfg)
+    taps = cfg.linear_conv_kernel
+    out_std = (std / (2.0 * cfg.num_layers) ** 0.5 if cfg.use_scaled_init
+               else std)
+    ks = jax.random.split(key, 6)
+
+    def normal(k, shape, s):
+        return (s * jax.random.normal(k, shape, jnp.float32)).astype(dtype)
+
+    # the decay's initialisation is the Mamba-2 / Gated DeltaNet
+    # convention: A uniform in [1, 16], the step dt log-uniform in
+    # [0.001, 0.1] and dt_bias its inverse softplus, so that a head's
+    # memory spans from about one position to about a thousand
+    dt = jnp.exp(jax.random.uniform(ks[4], (nv,), jnp.float32,
+                                    math.log(1e-3), math.log(1e-1)))
+    bound = 1.0 / math.sqrt(taps)      # a depthwise Conv1d's default
+    return {
+        "w_qkvz": normal(ks[0], (h, 2 * nk * dk + 2 * nv * dv), std),
+        "w_ba": normal(ks[1], (h, 2 * nv), std),
+        "conv": jax.random.uniform(ks[2], (taps, ch), jnp.float32,
+                                   -bound, bound).astype(dtype),
+        "A_log": jnp.log(jax.random.uniform(ks[3], (nv,), jnp.float32,
+                                            1.0, 16.0)),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "norm": {"scale": jnp.ones((dv,), dtype)},
+        "w_out": normal(ks[5], (nv * dv, h), out_std),
+    }
+
+
+def init_state(cfg: ModelConfig, batch: int) -> GDNState:
+    _nk, nv, dk, dv, ch = dims(cfg)
+    return GDNState(jnp.zeros((batch, nv, dk, dv), jnp.float32),
+                    jnp.zeros((batch, cfg.linear_conv_kernel - 1, ch),
+                              jnp.float32))
+
+
+def _l2norm(x):
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + L2_EPS)
+
+
+@jax.named_scope("gdn_step")
+def delta_rule_step(q, k, v, g, beta, S):
+    """One position.  ``q k`` [b, h, dk], ``v`` [b, h, dv], ``g beta``
+    [b, h], ``S`` [b, h, dk, dv], all float32 → ``(o [b, h, dv], S)``."""
+    S = S * jnp.exp(g)[..., None, None]
+    d = beta[..., None] * (v - jnp.einsum("bhk,bhkv->bhv", k, S,
+                                          precision=_PREC))
+    S = S + k[..., :, None] * d[..., None, :]
+    return jnp.einsum("bhk,bhkv->bhv", q, S, precision=_PREC), S
+
+
+@jax.named_scope("gdn_scan")
+def delta_rule_chunked(q, k, v, g, beta, S):
+    """``CHUNK`` positions at a time.  ``q k`` [b, s, h, dk], ``v``
+    [b, s, h, dv], ``g beta`` [b, s, h], ``S`` [b, h, dk, dv], float32,
+    ``s`` a multiple of ``CHUNK`` → ``(o [b, s, h, dv], S)``.
+
+    Within a chunk the rule's ``d_t`` solve ``(I + L) D = beta V -
+    (beta K e^G) S0``, ``L`` strictly lower triangular with ``L_ij =
+    beta_i (k_i . k_j) e^{G_i - G_j}`` and ``G`` the running sum of ``g``;
+    ``L`` is nilpotent, so ``(I + L)^-1 = prod_m (I + (-L)^(2^m))``: six
+    products of ``CHUNK``-square matrices and no row-by-row substitution.
+    Everything that does not depend on ``S`` is computed for all chunks at
+    once; the scan carries ``S`` alone."""
+    b, s, h, dk = q.shape
+    dv, n, c = v.shape[-1], s // CHUNK, CHUNK
+
+    def chunks(x):       # [b, s, h, ...] -> [n, b, h, c, ...]
+        x = x.reshape((b, n, c, h) + x.shape[3:])
+        return jnp.moveaxis(jnp.moveaxis(x, 3, 2), 1, 0)
+
+    q, k, v, g, beta = map(chunks, (q, k, v, g, beta))
+    G = jnp.cumsum(g, axis=-1)                          # [n, b, h, c]
+    diff = G[..., :, None] - G[..., None, :]
+    lower = jnp.tril(jnp.ones((c, c), bool))
+    decay = jnp.exp(jnp.where(lower, diff, -jnp.inf))   # i >= j, else 0
+    strict = jnp.tril(jnp.ones((c, c), bool), -1)
+    kb, vb = k * beta[..., None], v * beta[..., None]
+    kk = jnp.einsum("nbhik,nbhjk->nbhij", kb, k, precision=_PREC)
+    m = jnp.where(strict, -kk * decay, 0.0)             # -L
+    eye = jnp.eye(c, dtype=jnp.float32)
+    t = eye + m
+    for _ in range(int(math.log2(c)) - 1):
+        m = jnp.einsum("nbhij,nbhjl->nbhil", m, m, precision=_PREC)
+        t = t + jnp.einsum("nbhij,nbhjl->nbhil", t, m, precision=_PREC)
+    u = jnp.einsum("nbhij,nbhjv->nbhiv", t, vb, precision=_PREC)
+    w = jnp.einsum("nbhij,nbhjk->nbhik", t, kb * jnp.exp(G)[..., None],
+                   precision=_PREC)
+    qk = jnp.einsum("nbhik,nbhjk->nbhij", q, k, precision=_PREC) * decay
+    q_in = q * jnp.exp(G)[..., None]
+    k_out = k * jnp.exp(G[..., -1:] - G)[..., None]
+    g_end = jnp.exp(G[..., -1])                         # [n, b, h]
+
+    def step(S, xs):
+        u_c, w_c, qk_c, q_c, k_c, ge = xs
+        d = u_c - jnp.einsum("bhik,bhkv->bhiv", w_c, S, precision=_PREC)
+        o = (jnp.einsum("bhik,bhkv->bhiv", q_c, S, precision=_PREC)
+             + jnp.einsum("bhij,bhjv->bhiv", qk_c, d, precision=_PREC))
+        S = S * ge[..., None, None] + jnp.einsum(
+            "bhik,bhiv->bhkv", k_c, d, precision=_PREC)
+        return S, o
+
+    S, o = jax.lax.scan(step, S, (u, w, qk, q_in, k_out, g_end))
+    o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 2, 3)       # [b, n, c, h, dv]
+    return o.reshape(b, s, h, dv), S
+
+
+@jax.named_scope("gdn_conv")
+def _conv(p: Params, mixed, tail, lengths):
+    """Causal depthwise convolution of ``mixed`` [b, s, ch] continuing
+    ``tail`` [b, taps - 1, ch], then SiLU → ``(out [b, s, ch], the tail
+    after each row's ``lengths`` positions)``."""
+    taps, s = p["conv"].shape[0], mixed.shape[1]
+    full = jnp.concatenate([tail, mixed], axis=1)          # float32
+    w = p["conv"].astype(jnp.float32)
+    out = sum(full[:, j:j + s] * w[j] for j in range(taps))
+    new_tail = jax.vmap(lambda f, n: jax.lax.dynamic_slice_in_dim(
+        f, n, taps - 1, axis=0))(full, lengths)
+    return jax.nn.silu(out), new_tail
+
+
+@jax.named_scope("gdn")
+def gdn_block(cfg: ModelConfig, p: Params, x: jax.Array,
+              state: Optional[GDNState] = None,
+              valid: Optional[jax.Array] = None):
+    """The mixer over ``x`` [b, s, h] continuing ``state`` (None: the
+    start of a sequence) → ``(out [b, s, h], the state after each row's
+    valid positions)``.  ``valid`` [b, s] bool marks the positions that
+    are there, a prefix of each row (None: all)."""
+    b, s, _ = x.shape
+    nk, nv, dk, dv, _ch = dims(cfg)
+    kd, vd = nk * dk, nv * dv
+    if state is None:
+        state = init_state(cfg, b)
+    if valid is None:
+        valid = jnp.ones((b, s), bool)
+    with jax.named_scope("gdn_proj"):
+        # the float32 stream in two bf16 passes, results left in float32
+        # for the convolution, the norms and the rule: the rule takes
+        # differences of near-equal quantities (``v - S^T k``), so one
+        # bf16 rounding of the mixer's input comes out of it three times
+        # as large (0.33 % of its output at the published widths, and
+        # 0.17 % more from rounding ``o`` before ``w_out``), and the next
+        # router's near-ties turn that into other experts (PERF.md, PR 35)
+        qkvz = dot_f32(x, p["w_qkvz"])
+        ba = dot_f32(x, p["w_ba"])
+    mixed, z = qkvz[..., :2 * kd + vd], qkvz[..., 2 * kd + vd:]
+    mixed, conv = _conv(p, mixed, state.conv,
+                        jnp.sum(valid, axis=1, dtype=jnp.int32))
+    q = _l2norm(mixed[..., :kd].reshape(b, s, nk, dk)) * dk ** -0.5
+    k = _l2norm(mixed[..., kd:2 * kd].reshape(b, s, nk, dk))
+    v = mixed[..., 2 * kd:].reshape(b, s, nv, dv)
+    # each key head serves value heads / key heads value heads
+    q = jnp.repeat(q, nv // nk, axis=2)
+    k = jnp.repeat(k, nv // nk, axis=2)
+    live = valid[..., None].astype(jnp.float32)
+    beta = jax.nn.sigmoid(ba[..., :nv]) * live
+    g = -jnp.exp(p["A_log"]) * jax.nn.softplus(
+        ba[..., nv:] + p["dt_bias"]) * live
+    if s == 1:
+        o, S = delta_rule_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                               beta[:, 0], state.S)
+        o = o[:, None]
+    else:
+        pad = -s % CHUNK       # padded positions: beta = g = 0, no-ops
+
+        def padded(a):
+            return jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2))
+
+        o, S = delta_rule_chunked(*map(padded, (q, k, v, g, beta)), state.S)
+        o = o[:, :s]
+    # RMSNorm a head first, the gate after
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                          + cfg.norm_eps)
+    o = o * p["norm"]["scale"].astype(jnp.float32) * jax.nn.silu(
+        z.reshape(b, s, nv, dv))
+    with jax.named_scope("gdn_proj"):
+        out = dot_f32(o.reshape(b, s, vd), p["w_out"]).astype(x.dtype)
+    return out, GDNState(S, conv)

@@ -20,9 +20,11 @@ from .transformer import (
     AttnSideInputs,
     Params,
     _dropout,
+    PagedKV,
     init_stack_params,
     norm_init,
     rope_tables,
+    scan_periods_cached,
     stack_forward,
     stack_forward_cached,
     stack_forward_paged,
@@ -175,8 +177,9 @@ def forward_hidden(
     )
     x, moe_aux = stack_forward(cfg, params["layers"], x, side, stack_rng,
                                lora=lora)
+    # (a hybrid stack hands its float32 stream to the final norm)
     x = norm_apply(cfg.norm_type, x, params["final_norm"], cfg.norm_eps,
-                   impl=cfg.norm_impl)
+                   impl=cfg.norm_impl).astype(cfg.dtype)
     return x, moe_aux
 
 
@@ -621,12 +624,107 @@ def init_kv_cache(cfg: ModelConfig, batch_size: int, max_len: int,
     if cfg.kv_cache_quant == "int8":
         from ..ops.kv_quant import init_quantized_cache
 
-        shape = (cfg.num_layers, batch_size, cfg.kv_heads, max_len,
+        shape = (cfg.kv_layers, batch_size, cfg.kv_heads, max_len,
                  cfg.head_dim)
         return init_quantized_cache(shape), init_quantized_cache(shape)
     dtype = dtype or cfg.dtype
-    shape = (cfg.num_layers, batch_size, cfg.kv_heads, max_len, cfg.head_dim)
+    shape = (cfg.kv_layers, batch_size, cfg.kv_heads, max_len, cfg.head_dim)
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+
+
+def init_rec_state(cfg: ModelConfig, batch_size: int) -> dict:
+    """What a hybrid stack keeps a sequence beside its keys and values,
+    for ``batch_size`` sequences (the serving engine: one a slot): every
+    linear layer's recurrent state ``S`` [linear layers, b, value heads,
+    key width, value width] float32 and convolution tail ``conv``
+    [linear layers, b, taps - 1, channels], zero at a sequence's start;
+    and ``load`` [layers, router outputs] int32, how often each expert
+    was chosen by the positions these states were advanced over (a
+    counter carried on the device and read when somebody asks)."""
+    from .gated_deltanet import init_state
+
+    one = init_state(cfg, batch_size)
+    n = cfg.linear_layers
+    return {"S": jnp.zeros((n,) + one.S.shape, one.S.dtype),
+            "conv": jnp.zeros((n,) + one.conv.shape, one.conv.dtype),
+            "load": jnp.zeros((cfg.num_layers, cfg.router_experts),
+                              jnp.int32)}
+
+
+def _counted(rec: dict, new: dict, load) -> dict:
+    return {**new, "load": rec["load"] + load.astype(jnp.int32)}
+
+
+def forward_cached_hybrid(cfg: ModelConfig, params: Params, tokens,
+                          k_cache, v_cache, cache_len, rec: dict, *,
+                          valid=None, empty_cache: bool = False,
+                          logit_rows=None):
+    """``forward_cached`` for a hybrid stack (``cfg.layer_pattern``): the
+    full layers append to the dense cache ``[full layers, b, kv heads,
+    max_len, d]`` as there, the linear layers continue ``rec``
+    (``init_rec_state``) over the positions ``valid`` [b, s] marks (None:
+    all; a prefix of each row) and leave it untouched over the others.
+    → ``(logits, new_k_cache, new_v_cache, new_rec)``."""
+    from ..ops.kv_quant import cache_update
+
+    b, s = tokens.shape
+    cache_len = jnp.asarray(cache_len, jnp.int32)
+    start = cache_len[:, None] if cache_len.ndim == 1 else cache_len
+    position_ids = jnp.broadcast_to(
+        start + jnp.arange(s, dtype=jnp.int32)[None, :], (b, s))
+    x = embed(cfg, params, tokens, position_ids)
+    side = AttnSideInputs(position_ids=position_ids, deterministic=True,
+                          cache_is_empty=empty_cache, valid=valid)
+    x, (rows_k, rows_v), new, load = scan_periods_cached(
+        cfg, params["layers"], x, side,
+        lambda _idx, k_l, v_l: (k_l, v_l, cache_len), rec,
+        kv_xs=(k_cache, v_cache))
+    k_cache = cache_update(k_cache, rows_k, cache_len)
+    v_cache = cache_update(v_cache, rows_v, cache_len)
+    x = norm_apply(cfg.norm_type, x, params["final_norm"], cfg.norm_eps,
+                   impl=cfg.norm_impl).astype(cfg.dtype)
+    if logit_rows is not None:
+        x = jnp.take_along_axis(
+            x, logit_rows.astype(jnp.int32)[:, None, None], axis=1)
+    return unembed(cfg, params, x), k_cache, v_cache, _counted(rec, new, load)
+
+
+def forward_paged_hybrid(cfg: ModelConfig, params: Params, tokens, k_pool,
+                         v_pool, tables, fills, rec: dict, live):
+    """``forward_cached_paged`` for a hybrid stack: one new token a slot,
+    the full layers reading their keys and values out of the block pool
+    (``[full layers, n_blocks, ...]``) by the composed routes there (the
+    paged kernel where ``paged_decode_eligible``, else the gathered dense
+    view), the linear layers advancing slot ``i``'s row of ``rec`` where
+    ``live[i]`` and leaving it where not (a free slot rides along with
+    whatever its row holds).  → ``(logits [b, 1, vocab], new_k_pool,
+    new_v_pool, new_rec)``."""
+    fills = jnp.asarray(fills, jnp.int32)
+    tables = jnp.asarray(tables, jnp.int32)
+    bk = jax.tree.leaves(k_pool)[0].shape[3]
+    bids = jnp.take_along_axis(tables, (fills // bk)[:, None], axis=1)[:, 0]
+    offs = fills % bk
+    valid = live[:, None]
+    if paged_decode_eligible(cfg, k_pool, tokens.shape[1]):
+        x = embed(cfg, params, tokens, fills[:, None])
+        side = AttnSideInputs(position_ids=fills[:, None],
+                              deterministic=True, valid=valid)
+        x, (rows_k, rows_v), new, load = scan_periods_cached(
+            cfg, params["layers"], x, side,
+            lambda idx: PagedKV(k_pool, v_pool, tables, fills, idx), rec)
+        x = norm_apply(cfg.norm_type, x, params["final_norm"],
+                       cfg.norm_eps, impl=cfg.norm_impl).astype(cfg.dtype)
+        logits, rec = unembed(cfg, params, x), _counted(rec, new, load)
+    else:
+        k_dense = cache_gather_blocks(k_pool, tables)
+        v_dense = cache_gather_blocks(v_pool, tables)
+        logits, k_dense, v_dense, rec = forward_cached_hybrid(
+            cfg, params, tokens, k_dense, v_dense, fills, rec, valid=valid)
+        rows_k = cache_rows_at(k_dense, fills)
+        rows_v = cache_rows_at(v_dense, fills)
+    k_pool = cache_append_rows(k_pool, rows_k, bids, offs)
+    v_pool = cache_append_rows(v_pool, rows_v, bids, offs)
+    return logits, k_pool, v_pool, rec
 
 
 def init_kv_pool(cfg: ModelConfig, n_blocks: int, block_size: int,

@@ -36,6 +36,7 @@ import jax.numpy as jnp
 
 from ..config import ModelConfig
 from ..ops.activations import get_activation, is_glu
+from ..ops.precision import dot_f32
 
 Params = dict
 
@@ -55,12 +56,20 @@ def init_moe_params(key: jax.Array, cfg: ModelConfig) -> Params:
 
     p: Params = {
         # router kept in fp32: routing decisions are precision-sensitive
-        "router": std * jax.random.normal(keys[0], (h, E), jnp.float32),
+        "router": std * jax.random.normal(
+            keys[0], (h, cfg.router_experts), jnp.float32),
         "w_up": normal(keys[2], (E, h, f), std),
         "w_down": normal(keys[3], (E, f, h), out_std),
     }
     if is_glu(cfg.activation):
         p["w_gate"] = normal(keys[1], (E, h, f), std)
+    if cfg.moe_shared_expert_size:
+        fs = cfg.moe_shared_expert_size
+        ks = jax.random.split(jax.random.fold_in(key, 1), 4)
+        p["shared"] = {"w_gate": normal(ks[0], (h, fs), std),
+                       "w_up": normal(ks[1], (h, fs), std),
+                       "w_down": normal(ks[2], (fs, h), out_std),
+                       "gate": normal(ks[3], (h, 1), std)}
     return p
 
 
@@ -82,7 +91,7 @@ def stats_zero(cfg: ModelConfig) -> dict:
     """Zero MoE stats tree (the per-layer scan accumulator shape)."""
     return {"aux": jnp.zeros((), jnp.float32),
             "dropped": jnp.zeros((), jnp.float32),
-            "load": jnp.zeros((cfg.num_experts,), jnp.float32)}
+            "load": jnp.zeros((cfg.router_experts,), jnp.float32)}
 
 
 def aux_loss_of(aux) -> jax.Array:
@@ -154,3 +163,102 @@ def moe_block(cfg: ModelConfig, p: Params, x: jax.Array):
     out = jnp.einsum("ebch,bsec->bsh", xout, combine.astype(x.dtype))
     return out.reshape(b_in, s_in, h), {
         "aux": aux, "dropped": dropped, "load": f_e}
+
+
+# ---------------------------------------------------------------------------
+# Dropless routing over the experts this rank holds (serving)
+# ---------------------------------------------------------------------------
+
+
+def _held_experts(cfg: ModelConfig, p: Params, x, local, weight):
+    """``x`` [g, h] through the held experts each token chose: ``local``
+    [g, k] is a choice's index among the held experts, or ``num_experts``
+    for one that is not here; ``weight`` [g, k] its gate (0 where not
+    here) → [g, h] float32.  The (token, choice) pairs are sorted by
+    expert and each expert multiplies its own rows (``lax.ragged_dot``):
+    no one-hot over the experts, no capacity.  The rows of the choices
+    that are not here sort last, past the last group, and add nothing."""
+    g, k = local.shape
+    E = cfg.num_experts
+    act = get_activation(cfg.activation)
+    with jax.named_scope("moe_dispatch"):
+        flat = local.reshape(-1)
+        order = jnp.argsort(flat, stable=True)
+        sizes = jnp.zeros((E + 1,), jnp.int32).at[flat].add(1)[:E]
+        rows = x[order // k]                              # [g * k, h]
+    with jax.named_scope("moe_experts"):
+        gate = jax.lax.ragged_dot(rows, p["w_gate"], sizes)
+        up = jax.lax.ragged_dot(rows, p["w_up"], sizes)
+        hidden = act(jnp.concatenate([gate, up], axis=-1))
+        out = jax.lax.ragged_dot(hidden, p["w_down"], sizes)
+    with jax.named_scope("moe_dispatch"):
+        # back in (token, choice) order as the products left them, half
+        # the bytes of the float32 sum; what ragged_dot leaves in the
+        # rows past its last group is not defined: those are replaced,
+        # not multiplied by their zero gate
+        back = jnp.zeros_like(order).at[order].set(
+            jnp.arange(g * k, dtype=order.dtype))
+        out = out[back].reshape(g, k, -1)
+        out = jnp.where((local < E)[..., None], out, 0).astype(jnp.float32)
+        return (out * weight[..., None]).sum(axis=1)
+
+
+def moe_dropless_block(cfg: ModelConfig, p: Params, x: jax.Array,
+                       valid=None):
+    """Routed MLP without capacity: ``softmax`` over the router's
+    ``cfg.router_experts`` outputs in float32, the ``moe_top_k`` largest,
+    their weights divided by their sum; the sum over the chosen experts
+    that this tree holds (``cfg.moe_expert_offset`` onwards; what the
+    absent ones would add is another rank's to compute) plus the shared
+    expert under its sigmoid gate → ``(out [b, s, h], stats)``.
+
+    ``stats["load"]`` [router_experts] counts the choices of the
+    positions ``valid`` [b, s] marks (None: all), held or not: the
+    engine's per-layer, per-expert counter.  Tokens are routed in chunks
+    of at most ``cfg.moe_group_size`` so that a 16k-position prefill
+    never holds ten rows a token at once."""
+    b, s, h = x.shape
+    k, E, R = cfg.moe_top_k, cfg.num_experts, cfg.router_experts
+    xt = x.reshape(b * s, h)
+    with jax.named_scope("moe_router"):
+        logits = jnp.dot(xt.astype(jnp.float32),
+                         p["router"].astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        weight, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+        weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
+        counted = (jnp.ones((b * s,), jnp.float32) if valid is None
+                   else valid.reshape(-1).astype(jnp.float32))
+        load = jnp.zeros((R,), jnp.float32).at[idx.reshape(-1)].add(
+            jnp.repeat(counted, k))
+        local = idx - cfg.moe_expert_offset
+        here = (local >= 0) & (local < E)
+        local = jnp.where(here, local, E)
+        weight = jnp.where(here, weight, 0.0)
+    # the router and the shared expert read ``x`` as it comes (a float32
+    # residual stream is not rounded first); the routed experts' products
+    # take their operands in the weights' precision
+    xf, xt = xt, xt.astype(p["w_up"].dtype)
+    g = group_size(cfg, b * s)
+    if g == b * s:
+        out = _held_experts(cfg, p, xt, local, weight)
+    else:
+        n = b * s // g
+        out = jax.lax.map(
+            lambda c: _held_experts(cfg, p, *c),
+            (xt.reshape(n, g, h), local.reshape(n, g, k),
+             weight.reshape(n, g, k))).reshape(b * s, h)
+    if "shared" in p:
+        with jax.named_scope("moe_shared"):
+            sp = p["shared"]
+            act = get_activation(cfg.activation)
+            # the largest part of the layer's output (its gate is ~1/2,
+            # a routed expert's ~1/10), so its rounding is what the next
+            # layer's router sees: the stream in two passes (dot_f32)
+            hidden = act(jnp.concatenate(
+                [dot_f32(xf, sp["w_gate"]), dot_f32(xf, sp["w_up"])],
+                axis=-1))
+            gate = jax.nn.sigmoid(dot_f32(xf, sp["gate"]))
+            out = out + gate * dot_f32(hidden, sp["w_down"])
+    return out.astype(x.dtype).reshape(b, s, h), {
+        "aux": jnp.zeros((), jnp.float32),
+        "dropped": jnp.zeros((), jnp.float32), "load": load}

@@ -35,7 +35,13 @@ from ..ops.activations import get_activation, is_glu
 from ..ops.attention import _mesh_active, attention
 from ..ops.norms import norm_apply, norm_init
 from ..ops.quant import int8_training_matmul, is_quantized, mm
-from ..ops.rope import apply_rope, apply_rope_flat, precompute_rope_freqs
+from ..ops.rope import (
+    apply_rope,
+    apply_rope_flat,
+    apply_rope_partial,
+    precompute_rope_freqs,
+)
+from .gated_deltanet import GDNState, gdn_block, init_gdn_params
 
 Params = dict
 
@@ -59,8 +65,11 @@ def _normal(key, shape, std, dtype):
     return (std * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
 
 
-def init_layer_params(key: jax.Array, cfg: ModelConfig) -> Params:
-    """Parameters of one transformer layer (unstacked)."""
+def init_layer_params(key: jax.Array, cfg: ModelConfig,
+                      kind: str = "full") -> Params:
+    """Parameters of one transformer layer (unstacked); a ``"linear"``
+    layer of a hybrid stack holds a Gated DeltaNet mixer (``"gdn"``) where
+    a ``"full"`` one holds ``"attn"``."""
     h = cfg.hidden_size
     d = cfg.head_dim
     nq = cfg.num_attention_heads
@@ -73,7 +82,9 @@ def init_layer_params(key: jax.Array, cfg: ModelConfig) -> Params:
 
     keys = jax.random.split(key, 8)
     attn: Params = {
-        "wq": _normal(keys[0], (h, nq * d), std, dtype),
+        # with an output gate: per head, the query's columns then the gate's
+        "wq": _normal(keys[0], (h, nq * d * (2 if cfg.attn_output_gate
+                                             else 1)), std, dtype),
         "wk": _normal(keys[1], (h, nkv * d), std, dtype),
         "wv": _normal(keys[2], (h, nkv * d), std, dtype),
         "wo": _normal(keys[3], (nq * d, h), out_std, dtype),
@@ -84,6 +95,9 @@ def init_layer_params(key: jax.Array, cfg: ModelConfig) -> Params:
         attn["bv"] = jnp.zeros((nkv * d,), dtype)
     if cfg.use_bias:
         attn["bo"] = jnp.zeros((h,), dtype)
+    if cfg.qk_norm:
+        attn["q_norm"] = norm_init(cfg.norm_type, d, dtype)
+        attn["k_norm"] = norm_init(cfg.norm_type, d, dtype)
 
     if cfg.num_experts > 0:
         from .moe import init_moe_params
@@ -105,9 +119,12 @@ def init_layer_params(key: jax.Array, cfg: ModelConfig) -> Params:
 
     layer: Params = {
         "input_norm": norm_init(cfg.norm_type, h, dtype),
-        "attn": attn,
         "mlp": mlp,
     }
+    if kind == "linear":
+        layer["gdn"] = init_gdn_params(keys[7], cfg)
+    else:
+        layer["attn"] = attn
     if cfg.parallel_attn:
         if cfg.parallel_layernorm:
             # Falcon-40B: separate LN for the MLP branch
@@ -120,8 +137,16 @@ def init_layer_params(key: jax.Array, cfg: ModelConfig) -> Params:
 
 def init_stack_params(key: jax.Array, cfg: ModelConfig,
                       num_layers: Optional[int] = None) -> Params:
-    """All layers, stacked on a leading axis (scan/pipeline layout)."""
+    """All layers, stacked on a leading axis (scan/pipeline layout).  A
+    hybrid stack (``cfg.layer_pattern``) is a list with one such tree a
+    position of the period, each stacked over the periods."""
     n = num_layers if num_layers is not None else cfg.num_layers
+    if cfg.layer_pattern:
+        kinds = cfg.layer_pattern
+        keys = jax.random.split(key, n)
+        return [jax.vmap(lambda k, kind=kind: init_layer_params(
+            k, cfg, kind))(keys[j::len(kinds)])
+            for j, kind in enumerate(kinds)]
     keys = jax.random.split(key, n)
     return jax.vmap(lambda k: init_layer_params(k, cfg))(keys)
 
@@ -163,6 +188,12 @@ class AttnSideInputs:
     # the window (flash kernel) instead of contracting against the whole
     # cache buffer (model.py:forward_cached(empty_cache=True)).
     cache_is_empty: bool = False
+    # [b, s] bool: the positions that are there, a prefix of each row (a
+    # prefill bucket's padded tail and a decode step's free slots are
+    # not).  Where a layer keeps state that every position advances (the
+    # Gated DeltaNet state, the expert counters) the others leave it as
+    # it was; attention needs no such mask, its cache is masked by fill.
+    valid: Optional[jax.Array] = None
 
 
 class PagedKV(NamedTuple):
@@ -284,6 +315,11 @@ def attention_block(cfg: ModelConfig, p: Params, x: jax.Array,
     q = _lora_add(proj(cfg, x, p["wq"]), x, lora, "wq")
     k = _lora_add(proj(cfg, x, p["wk"]), x, lora, "wk")
     v = _lora_add(proj(cfg, x, p["wv"]), x, lora, "wv")
+    gate = None
+    if cfg.attn_output_gate:
+        q = q.reshape(b, s, nq, 2 * d)
+        gate = q[..., d:].reshape(b, s, nq * d)
+        q = q[..., :d].reshape(b, s, nq * d)
     if "bq" in p:
         q = q + p["bq"]
         k = k + p["bk"]
@@ -294,18 +330,29 @@ def attention_block(cfg: ModelConfig, p: Params, x: jax.Array,
                          "(forward_cached supplies them)")
 
     rotary = cfg.position_embedding_type == PositionEmbeddingType.ROTARY
+    partial = rotary and cfg.rotary_percent < 1.0
     # the paged route's few rows are rotated as the matmul leaves them:
     # cut into heads first, the q projection re-lays wq in every call
     # (apply_rope_flat).  Not under a mesh, where tp splits the row and
     # the shift along it would cross shards in every layer.
-    flat = rotary and isinstance(kv_cache, PagedKV) and not _mesh_active()
+    flat = (rotary and not partial and isinstance(kv_cache, PagedKV)
+            and not _mesh_active())
     if flat:
         q = apply_rope_flat(q, side.rope_cos, side.rope_sin, position_ids, d)
         k = apply_rope_flat(k, side.rope_cos, side.rope_sin, position_ids, d)
     q = q.reshape(b, s, nq, d)
     k = k.reshape(b, s, nkv, d)
     v = v.reshape(b, s, nkv, d)
-    if rotary and not flat:
+    if cfg.qk_norm:
+        q = norm_apply(cfg.norm_type, q, p["q_norm"], cfg.norm_eps)
+        k = norm_apply(cfg.norm_type, k, p["k_norm"], cfg.norm_eps)
+    if partial:
+        pos = position_ids if position_ids is not None else \
+            jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None, :], (b, s))
+        rot = int(d * cfg.rotary_percent)
+        q = apply_rope_partial(q, pos, rot, cfg.rope_theta)
+        k = apply_rope_partial(k, pos, rot, cfg.rope_theta)
+    elif rotary and not flat:
         q = apply_rope(q, side.rope_cos, side.rope_sin, position_ids)
         k = apply_rope(k, side.rope_cos, side.rope_sin, position_ids)
 
@@ -378,6 +425,9 @@ def attention_block(cfg: ModelConfig, p: Params, x: jax.Array,
             block_k=cfg.flash_block_k,
         )
     ctx2d = ctx.reshape(b, s, nq * d)
+    if gate is not None:
+        ctx2d = (ctx2d * jax.nn.sigmoid(gate.astype(jnp.float32))
+                 ).astype(ctx2d.dtype)
     out = _lora_add(proj(cfg, ctx2d, p["wo"]), ctx2d, lora, "wo")
     if "bo" in p:
         out = out + p["bo"]
@@ -418,12 +468,17 @@ def mlp_block(cfg: ModelConfig, p: Params, x: jax.Array,
 
 
 @jax.named_scope("mlp")
-def _mlp_dispatch(cfg: ModelConfig, p: Params, x: jax.Array, lora=None):
+def _mlp_dispatch(cfg: ModelConfig, p: Params, x: jax.Array, lora=None,
+                  valid=None):
     """Dense or routed MLP → ``(out, aux)``.
 
     ``aux`` is a scalar 0 for dense models and the MoE stats dict
     {aux, dropped, load} for routed ones (models/moe.py); accumulate with
     ``jax.tree.map`` and read the loss term via ``moe.aux_loss_of``."""
+    if cfg.moe_dropless:
+        from .moe import moe_dropless_block
+
+        return moe_dropless_block(cfg, p, x, valid=valid)
     if cfg.num_experts > 0:
         from .moe import moe_block
 
@@ -476,7 +531,16 @@ def layer_forward(cfg: ModelConfig, p: Params, x: jax.Array,
     h1 = norm_apply(cfg.norm_type, x, p["input_norm"], cfg.norm_eps,
                     impl=cfg.norm_impl)
     new_cache = None
-    if kv_cache is not None:
+    if cfg.layer_pattern:
+        # a hybrid stack's residual stream is float32 (``stream_dtype``);
+        # attention computes in the model's own precision
+        h1 = h1 if "gdn" in p else h1.astype(cfg.dtype)
+    if "gdn" in p:
+        # a linear layer: its "cache" is the recurrent state, carried or
+        # (None) started at zero; the new one is dropped with no cache
+        attn_out, new_cache = gdn_block(cfg, p["gdn"], h1, kv_cache,
+                                        side.valid)
+    elif kv_cache is not None:
         attn_out, new_cache = attention_block(cfg, p["attn"], h1, side,
                                               layer_rng, kv_cache,
                                               lora=lora)
@@ -496,7 +560,8 @@ def layer_forward(cfg: ModelConfig, p: Params, x: jax.Array,
         x = residual + branch_drop(attn_out, 2)
         h2 = norm_apply(cfg.norm_type, x, p["post_attn_norm"],
                         cfg.norm_eps, impl=cfg.norm_impl)
-        m, aux = _mlp_dispatch(cfg, p["mlp"], h2, lora=lora)
+        m, aux = _mlp_dispatch(cfg, p["mlp"], h2, lora=lora,
+                               valid=side.valid)
         result = x + branch_drop(m, 3)
     result = seq_constrain(result, side.seq_shard_axes)
     if kv_cache is not None:
@@ -529,6 +594,10 @@ def stack_forward(cfg: ModelConfig, stacked: Params, x: jax.Array,
     (leading L axis, joining the scan xs) — the LoRA finetune path runs
     through here with the factors as the differentiable operand.
     """
+    if cfg.layer_pattern:
+        assert lora is None, "a hybrid stack takes no adapters"
+        return _stack_forward_periods(cfg, stacked, x, side, base_rng,
+                                      layer_offset)
     arenas, mask = lora if lora is not None else (None, None)
 
     def body(carry, inp):
@@ -553,15 +622,105 @@ def stack_forward(cfg: ModelConfig, stacked: Params, x: jax.Array,
     elif cfg.recompute != "none":
         body = jax.checkpoint(body, prevent_cse=False)
 
+    xs = (stacked,) if arenas is None else (stacked, arenas)
+    (x, _, aux), _ = jax.lax.scan(body, (x, 0, _aux_zero(cfg)), xs)
+    return x, aux
+
+
+def _aux_zero(cfg: ModelConfig):
+    """The layer scan's accumulator for ``_mlp_dispatch``'s ``aux``."""
     if cfg.num_experts > 0:
         from .moe import stats_zero
 
-        aux0 = stats_zero(cfg)
-    else:
-        aux0 = jnp.zeros((), jnp.float32)
-    xs = (stacked,) if arenas is None else (stacked, arenas)
-    (x, _, aux), _ = jax.lax.scan(body, (x, 0, aux0), xs)
+        return stats_zero(cfg)
+    return jnp.zeros((), jnp.float32)
+
+
+# A hybrid stack carries its residual stream in float32 from the embedding
+# to the final norm, and its mixers and experts add float32 results to it:
+# every product still takes its operands in the weights' precision.  Each
+# layer's router picks ten of 512 nearly level scores from that stream,
+# and a pick that differs moves the token's output by a tenth at once, so
+# rounding the stream to bfloat16 a few times a layer shows in the logits
+# as it does not in a dense stack (PERF.md, PR 35).
+STREAM_DTYPE = jnp.float32
+
+
+def _stack_forward_periods(cfg: ModelConfig, stacked, x, side, base_rng,
+                           layer_offset):
+    """``stack_forward`` for a hybrid stack: the scan runs over the
+    periods, and its body runs one period's layers in order, each of the
+    kind its position has.  Every linear layer starts from a zero state
+    and drops the one it ends with: a whole sequence, no cache.  (No
+    rematerialisation: such a stack is served, not trained.)"""
+    n_pos = len(cfg.layer_pattern)
+    x = x.astype(STREAM_DTYPE)
+
+    def body(carry, period):
+        h, idx, aux_sum = carry
+        for j, layer_params in enumerate(period):
+            layer = idx * n_pos + j
+            rng = (None if base_rng is None
+                   else jax.random.fold_in(base_rng, layer))
+            h, aux = layer_forward(cfg, layer_params, h, side, rng,
+                                   layer_idx=layer_offset + layer)[:2]
+            aux_sum = jax.tree.map(jnp.add, aux_sum, aux)
+        return (h, idx + 1, aux_sum), None
+
+    (x, _, aux), _ = jax.lax.scan(body, (x, 0, _aux_zero(cfg)),
+                                  tuple(stacked))
     return x, aux
+
+
+def scan_periods_cached(cfg: ModelConfig, stacked, x, side, kv_of, rec,
+                        kv_xs: tuple = ()):
+    """The cached forms of a hybrid stack, prefill and decode alike: a
+    scan over the periods whose body gives each ``"full"`` layer its
+    ``kv_cache`` (``kv_of(kv_layer, *slices of kv_xs)``, ``kv_layer``
+    counting the full layers alone: the KV cache's own layer axis) and
+    each ``"linear"`` layer its recurrent state out of ``rec`` (``{"S":
+    [linear layers, b, ...], "conv": [...]}``).
+
+    → ``(hidden, (rows_k, rows_v) stacked over the full layers, rec with
+    every state advanced over the positions ``side.valid`` marks, load
+    [layers, router outputs]: the experts those positions chose)``."""
+    kinds = cfg.layer_pattern
+    n_per = cfg.num_layers // len(kinds)
+    n_full, n_lin = kinds.count("full"), kinds.count("linear")
+    x = x.astype(STREAM_DTYPE)
+
+    def by_period(a, n):
+        return a.reshape((n_per, n) + a.shape[1:])
+
+    xs = (tuple(stacked), tuple(by_period(a, n_full) for a in kv_xs),
+          jax.tree.map(lambda a: by_period(a, n_lin),
+                       {"S": rec["S"], "conv": rec["conv"]}))
+
+    def body(carry, inp):
+        h, idx = carry
+        period, kv_p, rec_p = inp
+        rows, states, loads, f, l = [], [], [], 0, 0
+        for layer_params, kind in zip(period, kinds):
+            if kind == "full":
+                cache = kv_of(idx * n_full + f, *(a[f] for a in kv_p))
+                f += 1
+            else:
+                cache = GDNState(rec_p["S"][l], rec_p["conv"][l])
+                l += 1
+            h, aux, new = layer_forward(cfg, layer_params, h, side, None,
+                                        kv_cache=cache)
+            (rows if kind == "full" else states).append(new)
+            loads.append(aux["load"] if isinstance(aux, dict)
+                         else jnp.zeros((0,), jnp.float32))
+        stack = lambda xs_: jax.tree.map(lambda *a: jnp.stack(a), *xs_)
+        return (h, idx + 1), (stack(rows), stack(states), jnp.stack(loads))
+
+    (x, _), (rows, states, loads) = jax.lax.scan(
+        body, (x, jnp.int32(0)), xs)
+    flat = lambda a: a.reshape((a.shape[0] * a.shape[1],) + a.shape[2:])
+    rows = jax.tree.map(flat, rows)
+    return x, rows, {"S": flat(states.S), "conv": flat(states.conv)}, \
+        flat(loads)
 
 
 def _scan_layers_cached(cfg: ModelConfig, stacked: Params, x: jax.Array,
@@ -652,7 +811,8 @@ def stack_forward_paged(cfg: ModelConfig, stacked: Params, x: jax.Array,
 
 
 def rope_tables(cfg: ModelConfig, dtype=jnp.float32):
-    if cfg.position_embedding_type != PositionEmbeddingType.ROTARY:
+    if (cfg.position_embedding_type != PositionEmbeddingType.ROTARY
+            or cfg.rotary_percent < 1.0):   # rotated from the positions
         return None, None
     return precompute_rope_freqs(
         cfg.head_dim,

@@ -37,7 +37,12 @@ SYNC_NAME = "obs_clock_sync"
 DEVICE_SCOPES = (
     "embed", "attention", "mlp", "lm_head", "cross_entropy", "grad_accum",
     "optimizer", "kv_cache", "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
-    "flash_decode", "rmsnorm", "decode_step_fused", "sample")
+    "flash_decode", "rmsnorm", "decode_step_fused", "sample",
+    # a hybrid stack (models/gated_deltanet.py, models/moe.py): the Gated
+    # DeltaNet mixer and its parts, the dropless experts' parts ("mlp"
+    # holds them all)
+    "gdn", "gdn_proj", "gdn_conv", "gdn_scan", "gdn_step",
+    "moe_router", "moe_dispatch", "moe_experts", "moe_shared")
 
 
 @dataclasses.dataclass

@@ -49,6 +49,9 @@ def norm_apply(norm_type: str, x, params: dict, eps: float,
                impl: str = "xla") -> jax.Array:
     if impl not in ("xla", "pallas"):
         raise ValueError(f"unknown norm impl {impl!r} (want 'xla'|'pallas')")
+    if norm_type == "rmsnorm_zero":
+        # zero-centred: the stored weight is the distance from 1
+        return rmsnorm_ref(x, 1.0 + params["scale"].astype(jnp.float32), eps)
     if norm_type == "rmsnorm":
         if impl == "pallas":
             from ..kernels.rmsnorm import rmsnorm_pallas
@@ -64,6 +67,8 @@ def norm_apply(norm_type: str, x, params: dict, eps: float,
 
 
 def norm_init(norm_type: str, hidden: int, dtype=jnp.float32) -> dict:
+    if norm_type == "rmsnorm_zero":
+        return {"scale": jnp.zeros((hidden,), dtype=dtype)}
     if norm_type == "rmsnorm":
         return {"scale": jnp.ones((hidden,), dtype=dtype)}
     elif norm_type == "layernorm":

@@ -211,6 +211,26 @@ def apply_rope_flat(
     return out.astype(x.dtype)
 
 
+def apply_rope_partial(x: jax.Array, position_ids: jax.Array, rot_dim: int,
+                       theta: float) -> jax.Array:
+    """Rotate the first ``rot_dim`` of the last axis of ``x`` [batch, seq,
+    heads, head_dim] and leave the rest: the rotate-half convention
+    (dimension ``i`` pairs with ``i + rot_dim / 2``), angles computed from
+    ``position_ids`` [batch, seq] in float32, no table (a partial rotation
+    goes with contexts whose table would be hundreds of thousands of
+    rows)."""
+    half = rot_dim // 2
+    inv_freq = 1.0 / (theta ** (
+        jnp.arange(0, rot_dim, 2, dtype=jnp.float32) / rot_dim))
+    ang = position_ids.astype(jnp.float32)[..., None] * inv_freq
+    cos, sin = jnp.cos(ang)[:, :, None, :], jnp.sin(ang)[:, :, None, :]
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., :half], xf[..., half:rot_dim]
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin, xf[..., rot_dim:]],
+        axis=-1).astype(x.dtype)
+
+
 @partial(jax.jit, static_argnums=(3,))
 def apply_rope_single(x, cos, sin, position: int):
     """Single-position variant for incremental decoding."""

@@ -527,20 +527,29 @@ def _prefill_impl(cfg: ModelConfig, params, tokens, length,
     rope = model_lib.rope_tables(cfg)
     lora = _lora_operand(lora_arenas, lora_slots, lora_rank)
     k, v = model_lib.init_kv_cache(cfg, 1, max_seq_len)
-    if want_logprobs:
+    rows = None if want_logprobs else length - 1
+    rec = None
+    if cfg.layer_pattern:
+        # a hybrid stack: the recurrent state this prompt ends with, at
+        # its TRUE last position (the bucket's padded tail advances
+        # nothing), comes back beside the K/V for ``slots.insert``
+        valid = jnp.arange(tokens.shape[1])[None, :] < length[:, None]
+        logits, k, v, rec = model_lib.forward_cached_hybrid(
+            cfg, params, tokens, k, v, jnp.int32(0),
+            model_lib.init_rec_state(cfg, 1), valid=valid,
+            empty_cache=True, logit_rows=rows)
+    else:
         logits, k, v = model_lib.forward_cached(
             cfg, params, tokens, k, v, jnp.int32(0), rope=rope,
-            empty_cache=True, lora=lora)
+            empty_cache=True, logit_rows=rows, lora=lora)
+    if want_logprobs:
         lp = jax.nn.log_softmax(logits, axis=-1)
         picked = jnp.take_along_axis(
             lp[:, :-1], tokens[:, 1:, None], axis=-1)[..., 0]  # [1, L-1]
         last = jnp.take_along_axis(
             logits, (length - 1)[:, None, None], axis=1)[:, 0]
-        return last, picked, k, v
-    logits, k, v = model_lib.forward_cached(
-        cfg, params, tokens, k, v, jnp.int32(0), rope=rope,
-        empty_cache=True, logit_rows=length - 1, lora=lora)
-    return logits[:, 0], None, k, v
+        return last, picked, k, v, rec
+    return logits[:, 0], None, k, v, rec
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
@@ -552,7 +561,7 @@ def _first_token_impl(cfg: ModelConfig, last_logits, seeds, counters,
 
 def _decode_impl(cfg: ModelConfig, params, k_pool, v_pool, tables, pending,
                  fills, seeds, counters, greedy, temps, top_ks, top_ps,
-                 lora_arenas=None, lora_slots=None, *,
+                 lora_arenas=None, lora_slots=None, rec=None, live=None, *,
                  use_fused: bool, allow_paged: bool = True,
                  lora_rank: int = 0):
     """One batched decode step over every slot: feed each slot's pending
@@ -561,21 +570,30 @@ def _decode_impl(cfg: ModelConfig, params, k_pool, v_pool, tables, pending,
     ride along (fixed shapes = one compiled executable); their reads and
     writes target the trash block and are masked.  Only the integer
     ``tables``/``fills`` change between steps — the pool shape is static,
-    so this stays ONE compiled executable."""
+    so this stays ONE compiled executable.
+
+    A hybrid stack also carries ``rec`` (``SlotAllocator.rec``) through
+    the step as it carries the pools: the slots ``live`` marks advance
+    their recurrent state by the fed token, the others keep theirs."""
     rope = model_lib.rope_tables(cfg)
-    logits, k_pool, v_pool = model_lib.forward_cached_paged(
-        cfg, params, pending[:, None], k_pool, v_pool, tables, fills,
-        rope=rope, use_fused=use_fused, allow_paged=allow_paged,
-        lora=_lora_operand(lora_arenas, lora_slots, lora_rank))
+    if cfg.layer_pattern:
+        logits, k_pool, v_pool, rec = model_lib.forward_paged_hybrid(
+            cfg, params, pending[:, None], k_pool, v_pool, tables, fills,
+            rec, live)
+    else:
+        logits, k_pool, v_pool = model_lib.forward_cached_paged(
+            cfg, params, pending[:, None], k_pool, v_pool, tables, fills,
+            rope=rope, use_fused=use_fused, allow_paged=allow_paged,
+            lora=_lora_operand(lora_arenas, lora_slots, lora_rank))
     tok, tok_lp = _sample_slots(logits[:, 0], seeds, counters, greedy,
                                 temps, top_ks, top_ps, cfg.vocab_size)
-    return tok, tok_lp, k_pool, v_pool
+    return tok, tok_lp, k_pool, v_pool, rec
 
 
 _decode_donated = functools.partial(
     jax.jit,
     static_argnames=("cfg", "use_fused", "allow_paged", "lora_rank"),
-    donate_argnums=(2, 3))(_decode_impl)
+    donate_argnums=(2, 3), donate_argnames=("rec",))(_decode_impl)
 _decode_plain = functools.partial(
     jax.jit,
     static_argnames=("cfg", "use_fused", "allow_paged", "lora_rank"))(
@@ -942,6 +960,48 @@ class _PrefillState:
         self.adapter_slot = -1    # pinned LoRA arena slot (-1 = base)
 
 
+def _refuse_for_hybrid(cfg: ModelConfig, config: EngineConfig, mesh,
+                       draft_cfg, adapters) -> None:
+    """What the engine cannot do for a hybrid stack (``cfg.layer_pattern``:
+    a recurrent state a slot beside its K/V blocks), refused at
+    construction so that none of it is served wrongly.  Each of these
+    paths moves, shares or rolls back K/V blocks alone."""
+    refused = [
+        (config.prefix_cache_blocks,
+         "prefix_cache_blocks > 0: a prefix hit would need a snapshot of "
+         "the recurrent state at the shared boundary, and a hit on K/V "
+         "alone would start the linear layers from zero mid-prompt"),
+        (config.spec_draft_len or draft_cfg is not None,
+         "speculation (spec_draft_len, a draft model): a rejected draft "
+         "rolls back by fill arithmetic, and the recurrent state has "
+         "already been advanced over it"),
+        (cfg.kv_cache_quant != "none",
+         "kv_cache_quant=int8: the hybrid decode route reads a bf16 pool"),
+        (mesh is not None and mesh.size > 1,
+         "a tp/pp serving mesh: serving_param_specs has no layout for "
+         "the period-stacked layers, the experts or the recurrent state"),
+        (adapters is not None or config.adapter_cache_slots,
+         "adapters: no LoRA epilogue on the DeltaNet or expert matmuls"),
+        (cfg.fused_decode,
+         "fused_decode=True: the whole-stack decode kernel runs dense "
+         "RMSNorm+GLU attention layers only"),
+        (config.prefill_chunk,
+         "prefill_chunk: the recurrent state is not carried from chunk "
+         "to chunk"),
+        (config.host_kv_blocks,
+         "host_kv_blocks: preemption to the host tier moves K/V blocks "
+         "and would leave the recurrent state behind"),
+        (config.role != "mixed",
+         f"role={config.role!r}: a shipment between replicas carries K/V "
+         "blocks and no recurrent state"),
+    ]
+    for hit, why in refused:
+        if hit:
+            raise ValueError(
+                f"a hybrid stack (layer_pattern {cfg.layer_pattern}) is "
+                f"not served with {why}")
+
+
 class ServingEngine:
     """Continuous-batching engine over a fixed set of KV slots.
 
@@ -982,6 +1042,8 @@ class ServingEngine:
         # = the unchanged single-chip engine.
         self.mesh = mesh
         self.config = engine_config or EngineConfig()
+        if cfg.layer_pattern:
+            _refuse_for_hybrid(cfg, self.config, mesh, draft_cfg, adapters)
         assert self.config.max_seq_len <= cfg.max_position_embeddings, (
             f"max_seq_len {self.config.max_seq_len} exceeds the model's "
             f"max_position_embeddings {cfg.max_position_embeddings}")
@@ -1222,6 +1284,11 @@ class ServingEngine:
                         self.slots.table_blocks, jax.default_backend(),
                         mesh=self.mesh)
                 self._update_pool_gauges()
+                if self.slots.rec is not None:
+                    self.metrics.set_gauges(
+                        rec_state_bytes=self.slots.rec_state_bytes,
+                        rec_state_slots=cfg_e.max_batch_size)
+                    self.metrics.expert_load = self.expert_load
                 if self._sanitize:
                     self._sanitizer = sanitizers.LedgerSanitizer()
                 self._thread = threading.Thread(
@@ -1246,6 +1313,23 @@ class ServingEngine:
                 self.sanitizer_report = self._sanitizer.leak_report(self)
                 for leak in self.sanitizer_report:
                     EVENT_LOG.emit("sanitizer", "kv_block_leak", **leak)
+
+    def expert_load(self):
+        """→ (counts [layers, router outputs] of how often each expert was
+        chosen since the engine started, the first held expert, the held
+        experts), fetched from the device now.  From any thread: the
+        decode step donates the tree the counts ride in, so while the
+        scheduler runs it is the scheduler that reads them, between two
+        iterations; this is for ``/metrics`` and the benchmark, not for a
+        step."""
+        def fetch():
+            # tpulint: allow[host-sync] asked for, outside any step
+            return np.asarray(self.slots.rec["load"])
+        try:
+            counts = self.call_in_scheduler(fetch)
+        except RuntimeError:        # not running: nothing donates it
+            counts = fetch()
+        return counts, self.cfg.moe_expert_offset, self.cfg.num_experts
 
     def pause(self) -> None:
         """Stop admitting and decoding (requests keep queueing) — used for
@@ -1890,7 +1974,7 @@ class ServingEngine:
         # their K/V rows carry the adapter's wk/wv deltas, so sharing
         # them with base-model (or other-adapter) requests would be
         # numerically wrong in both directions.
-        lease = None
+        lease = rec_small = None
         if (self.prefix_cache is not None and not req.return_logprobs
                 and req.adapter_id is None):
             t_pm = time.perf_counter()
@@ -1942,7 +2026,8 @@ class ServingEngine:
             tokens = np.zeros((1, padded), np.int32)
             tokens[0, :plen] = req.prompt
             with device_annotation("prefill"):
-                last_logits, picked, k_small, v_small = _prefill_impl(
+                (last_logits, picked, k_small, v_small,
+                 rec_small) = _prefill_impl(
                     self.cfg, self.params, jnp.asarray(tokens),
                     jnp.asarray([plen], jnp.int32),
                     max_seq_len=self.slots.width,
@@ -1952,7 +2037,8 @@ class ServingEngine:
                 req.logprobs.extend(
                     np.asarray(picked)[0, :plen - 1].tolist())
         self.slots.insert(slot, k_small, v_small, plen,
-                          lease.bids if lease is not None else ())
+                          lease.bids if lease is not None else (),
+                          rec_small=rec_small)
 
         # first generated token: same per-request sampling rule as decode
         t_ft = time.perf_counter()
@@ -2713,7 +2799,9 @@ class ServingEngine:
         top_ks = np.zeros((S,), np.int32)
         top_ps = np.zeros((S,), np.float32)
         aslots = np.full((S,), -1, np.int32)  # -1 rows: zero LoRA delta
+        live = np.zeros((S,), bool)   # hybrid: whose state the step moves
         for slot, st in self._active.items():
+            live[slot] = True
             fills[slot] = st.fill
             seeds[slot] = st.req.seed
             counters[slot] = st.count
@@ -2762,6 +2850,9 @@ class ServingEngine:
         G = self._decode_groups
         gs = S // G
         k_pool, v_pool = self.slots.k_pool, self.slots.v_pool
+        rec = self.slots.rec
+        state = ({} if rec is None       # (a hybrid stack has no groups)
+                 else dict(rec=rec, live=jnp.asarray(live)))
         toks, tok_lps = [], []
         with device_annotation("decode"):
             for g in range(G):
@@ -2781,7 +2872,7 @@ class ServingEngine:
                                              jnp.asarray(overrides[sl]))
                 else:
                     pending = prev_tok  # pure device->device handoff
-                tok, tok_lp, k_pool, v_pool = self._decode(
+                tok, tok_lp, k_pool, v_pool, rec = self._decode(
                     self.cfg, self.params, k_pool, v_pool,
                     jnp.asarray(self.slots.tables[sl]),
                     pending, jnp.asarray(fills[sl]),
@@ -2790,10 +2881,10 @@ class ServingEngine:
                     jnp.asarray(top_ks[sl]), jnp.asarray(top_ps[sl]),
                     use_fused=self._fused_decode,
                     allow_paged=self._allow_paged,
-                    **self._lora_args(aslots[sl]))
+                    **self._lora_args(aslots[sl]), **state)
                 toks.append(tok)
                 tok_lps.append(tok_lp)
-        self.slots.set_pools(k_pool, v_pool)
+        self.slots.set_pools(k_pool, v_pool, rec)
         try:  # start the host copies now so they overlap the next dispatch
             for tok, tok_lp in zip(toks, tok_lps):
                 tok.copy_to_host_async()
@@ -3053,6 +3144,10 @@ class ServingEngine:
         without a prefix-cache ``offer`` — the request is moving, not
         retiring — so shared prefix blocks stay pinned only by the cache
         itself (the shipment carries a verbatim copy of their rows)."""
+        if self.slots.rec is not None:
+            raise RuntimeError(
+                "a hybrid stack's slot cannot be shipped: the shipment "
+                "carries K/V blocks and no recurrent state")
         self._flush_inflight()
         st = self._active[slot]
         req = st.req
@@ -3104,6 +3199,10 @@ class ServingEngine:
         verbatim, and the sampling RNG folds on the request's own
         (seed, counter) — both in ``ship.meta`` — never on slot index,
         batch composition, or which engine runs the step."""
+        if self.slots.rec is not None:
+            raise RuntimeError(
+                "a hybrid stack cannot adopt a shipment: it carries K/V "
+                "blocks and no recurrent state")
         req: _Request = ship.meta["req"]
         pool = self.slots.pool
         if req.adapter_id is not None:

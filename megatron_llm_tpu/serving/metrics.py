@@ -249,6 +249,16 @@ class ServingMetrics:
         self.host_blocks_used = 0
         self.host_blocks_free = 0
         self.resume_latency = LatencyHistogram()
+        # hybrid stacks (serving/slots.py): bytes of per-slot recurrent
+        # and convolution state beside the pool, and the slots it is for
+        self.rec_state_bytes = 0
+        self.rec_state_slots = 0
+        # per-layer, per-expert assignment counts, carried on the device
+        # in the step's own state: ``expert_load`` (set by the engine)
+        # fetches them → (counts [layers, router outputs], first held
+        # expert, held experts), and is called only when somebody asks
+        # (``snapshot``, ``collect``), never by a step
+        self.expert_load = None
         # fused/fallback decode iterations keyed by the weight precision
         # route (ops/quant.py:precision_route: fp32/int8/int4/mixed) —
         # a per-precision regression to the composed path (e.g. an int4
@@ -296,8 +306,14 @@ class ServingMetrics:
                    adapter_resident: Optional[int] = None,
                    adapter_resident_bytes: Optional[int] = None,
                    host_blocks_used: Optional[int] = None,
-                   host_blocks_free: Optional[int] = None) -> None:
+                   host_blocks_free: Optional[int] = None,
+                   rec_state_bytes: Optional[int] = None,
+                   rec_state_slots: Optional[int] = None) -> None:
         with self._lock:
+            if rec_state_bytes is not None:
+                self.rec_state_bytes = rec_state_bytes
+            if rec_state_slots is not None:
+                self.rec_state_slots = rec_state_slots
             if num_slots is not None:
                 self.num_slots = num_slots
             if slots_active is not None:
@@ -438,6 +454,9 @@ class ServingMetrics:
                 "host_blocks_used": self.host_blocks_used,
                 "host_blocks_free": self.host_blocks_free,
                 "resume_latency": self.resume_latency.snapshot(),
+                # hybrid stacks: per-slot recurrent state beside the pool
+                "rec_state_bytes": self.rec_state_bytes,
+                "rec_state_slots": self.rec_state_slots,
                 # speculative decoding (histogram samples are token
                 # counts per participating slot per verify step)
                 "spec_acceptance_rate": (
@@ -466,7 +485,22 @@ class ServingMetrics:
                     for route, r in sorted(self.step_routes.items())},
             })
         out["slo"] = self.slo.snapshot()
+        load = self._held_expert_load()
+        if load is not None:
+            counts, lo, held = load
+            here = counts[:, lo:lo + held].astype(float)
+            out["expert_load"] = {
+                "assignments": int(counts.sum()),
+                "held_share": float(here.sum() / max(1, counts.sum())),
+                "max_over_mean_by_layer": [
+                    float(row.max() / row.mean()) if row.sum() else 0.0
+                    for row in here]}
         return out
+
+    def _held_expert_load(self):
+        """``expert_load()`` outside the lock (it may wait for the
+        scheduler thread), or None where no engine counts experts."""
+        return self.expert_load() if self.expert_load is not None else None
 
     def collect(self) -> List[MetricFamily]:
         """obs.REGISTRY collector: every counter, gauge, and reservoir
@@ -567,6 +601,12 @@ class ServingMetrics:
                      "host-RAM tier KV blocks in use", self.host_blocks_used),
                     ("serving_host_blocks_free",
                      "host-RAM tier KV blocks free", self.host_blocks_free),
+                    ("serving_rec_state_bytes",
+                     "bytes of per-slot recurrent and convolution state",
+                     self.rec_state_bytes),
+                    ("serving_rec_state_slots",
+                     "slots that keep a recurrent state beside their KV",
+                     self.rec_state_slots),
                     ("serving_spec_acceptance_rate",
                      "speculative draft tokens accepted / proposed",
                      self.counters["spec_accepted"]
@@ -578,6 +618,19 @@ class ServingMetrics:
                     pname, help_, count=hist.total_count, total=hist.total,
                     quantiles=hist.quantiles()))
         fams.extend(self.slo.collect(prefix="serving_slo"))
+        load = self._held_expert_load()
+        if load is not None:
+            counts, lo, held = load
+            fam = MetricFamily(
+                "serving_expert_assignments_total", "counter",
+                "times a layer's router chose an expert (held: this "
+                "engine's parameter tree holds it)")
+            for layer, row in enumerate(counts):
+                for e, n in enumerate(row):
+                    fam.add(float(n), labels={
+                        "layer": str(layer), "expert": str(e),
+                        "held": str(int(lo <= e < lo + held))})
+            fams.append(fam)
         return fams
 
     def write(self, writer, iteration: int,

@@ -15,6 +15,10 @@ Memory therefore scales with actual fill, not ``max_seq_len``: a
 and blocks shared with the prefix cache appear in many tables at once
 under ref counting — retirement decrements refs instead of copying rows.
 
+A hybrid stack (``cfg.layer_pattern``) keeps a second kind of state a
+slot, ``SlotAllocator.rec``: fixed-size, slot-indexed, beside the pool
+(see ``__init__``); ``insert`` installs it with the prefill's K/V.
+
 ``insert`` publishes an admission prefill's dense batch-1 cache into
 freshly allocated pool blocks in ONE fixed-arity scatter; shared prefix
 blocks are skipped (their scatter target is the trash block), so a
@@ -58,6 +62,19 @@ def _insert_plain(k_pool, v_pool, k_small, v_small, scatter):
             model_lib.cache_scatter_blocks(v_pool, v_small, scatter))
 
 
+def _install_rec_impl(rec, one, slot):
+    """Replace slot ``slot``'s recurrent state by ``one``'s (batch 1: what
+    a prefill ended with) and add the experts that prefill counted."""
+    out = model_lib.cache_slot_update(
+        {"S": rec["S"], "conv": rec["conv"]},
+        {"S": one["S"], "conv": one["conv"]}, slot)
+    return {**out, "load": rec["load"] + one["load"]}
+
+
+_install_rec_donated = jax.jit(_install_rec_impl, donate_argnums=(0,))
+_install_rec_plain = jax.jit(_install_rec_impl)
+
+
 class SlotAllocator:
     """Tracks slot occupancy and per-slot block tables over a BlockPool.
 
@@ -86,6 +103,20 @@ class SlotAllocator:
         self._free = list(range(num_slots - 1, -1, -1))  # pop() -> slot 0 first
         self._insert = (_insert_plain if jax.default_backend() == "cpu"
                         else _insert_donated)
+        # A hybrid stack's second kind of slot state (cfg.layer_pattern;
+        # models/model.py:init_rec_state): for every linear layer a
+        # fixed-size recurrent state and convolution tail a SLOT, indexed
+        # by slot and not paged, allocated once beside the pool.  A slot's
+        # row is replaced whole when a prefill is installed (``insert``),
+        # advanced by every decode step the slot is live in, and dead
+        # between a retirement and the next install: nothing reads it, so
+        # release() moves no device memory.  The decode step consumes and
+        # re-emits the tree like the pools (donated on a TPU).
+        self.rec = (model_lib.init_rec_state(cfg, num_slots)
+                    if cfg.layer_pattern else None)
+        self._install_rec = (
+            _install_rec_plain if jax.default_backend() == "cpu"
+            else _install_rec_donated)
 
     # -- occupancy ------------------------------------------------------
     @property
@@ -143,15 +174,23 @@ class SlotAllocator:
     def v_pool(self):
         return self.pool.v_pool
 
-    def set_pools(self, k_pool, v_pool) -> None:
-        """Adopt the pools returned by a decode step (the step consumes and
-        re-emits them; on TPU they are donated through)."""
+    def set_pools(self, k_pool, v_pool, rec=None) -> None:
+        """Adopt the pools (and a hybrid stack's ``rec``) returned by a
+        decode step (the step consumes and re-emits them; on TPU they are
+        donated through)."""
         self.pool.k_pool = k_pool
         self.pool.v_pool = v_pool
+        if rec is not None:
+            self.rec = rec
 
     # -- admission ------------------------------------------------------
+    @property
+    def rec_state_bytes(self) -> int:
+        return 0 if self.rec is None else sum(
+            int(a.nbytes) for a in (self.rec["S"], self.rec["conv"]))
+
     def insert(self, slot: int, k_small, v_small, n_tokens: int,
-               shared_bids: Sequence[int] = ()) -> None:
+               shared_bids: Sequence[int] = (), rec_small=None) -> None:
         """Publish a dense batch-1 cache (leaves ``[L, 1, kv, width(,d)]``)
         into the slot's table.
 
@@ -184,6 +223,11 @@ class SlotAllocator:
         pool.k_pool, pool.v_pool = self._insert(
             pool.k_pool, pool.v_pool, k_small, v_small,
             np.ascontiguousarray(scatter))
+        if self.rec is not None:
+            # the prefill's own end state (at the prompt's true last
+            # position) replaces whatever the slot's last tenant left
+            self.rec = self._install_rec(self.rec, rec_small,
+                                         np.int32(slot))
 
     # -- decode-time lazy growth ---------------------------------------
     def append_block_id(self, slot: int, fill: int) -> int:

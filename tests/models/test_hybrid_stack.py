@@ -1,0 +1,204 @@
+"""The hybrid stack's own mechanisms at tiny widths, float32: the two
+forms of the gated delta rule, a padded prefill, dropless routing, the
+attention variants, and the preset's sizes."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from megatron_llm_tpu.config import qwen3_next_config
+from megatron_llm_tpu.models import gated_deltanet as gdn
+from megatron_llm_tpu.models import model as model_lib
+from megatron_llm_tpu.models import moe
+from megatron_llm_tpu.ops.norms import norm_apply, norm_init
+from megatron_llm_tpu.ops.precision import dot_f32
+from megatron_llm_tpu.ops.rope import apply_rope_partial
+
+TINY = dict(num_layers=4, hidden_size=64, num_attention_heads=4,
+            num_kv_heads=2, kv_channels=32, ffn_hidden_size=32,
+            moe_shared_expert_size=32, num_experts=8, moe_router_experts=16,
+            moe_top_k=4, vocab_size=512, linear_num_key_heads=2,
+            linear_num_value_heads=4, linear_key_head_dim=16,
+            linear_value_head_dim=16, params_dtype="float32",
+            max_position_embeddings=1024, make_vocab_size_divisible_by=8,
+            moe_group_size=64)
+
+
+def tiny(**kw):
+    return qwen3_next_config("80b-a3b-ep2-rank0", **{**TINY, **kw})
+
+
+@pytest.fixture(scope="module")
+def model():
+    cfg = tiny()
+    return cfg, jax.jit(lambda k: model_lib.init_params(k, cfg))(
+        jax.random.key(0))
+
+
+def test_the_preset_is_the_published_model_and_its_share():
+    full = qwen3_next_config("80b-a3b")
+    assert (full.num_layers, full.hidden_size, full.head_dim) == (48, 2048,
+                                                                   256)
+    assert full.layer_kinds.count("full") == 12 == full.kv_layers
+    assert full.layer_kinds[:4] == ("linear", "linear", "linear", "full")
+    assert (full.num_experts, full.router_experts, full.moe_top_k) == (
+        512, 512, 10)
+    share = qwen3_next_config("80b-a3b-ep2-rank0", num_layers=4)
+    assert (share.num_experts, share.router_experts,
+            share.moe_expert_offset) == (256, 512, 0)
+    assert share.vocab_size == share.padded_vocab_size() == 75968
+    assert (share.kv_layers, share.linear_layers) == (1, 3)
+    # a JSON list is as good as a tuple, and the config stays hashable
+    assert hash(tiny(layer_pattern=["linear", "full"])) == hash(
+        tiny(layer_pattern=("linear", "full")))
+    with pytest.raises(AssertionError, match="whole periods"):
+        tiny(num_layers=6)
+
+
+@pytest.mark.parametrize("s", [64, 100, 192])
+def test_the_chunked_rule_is_the_recurrence_across_chunk_edges(s):
+    """64 positions a chunk: one whole chunk, one and a ragged second
+    (padded inside ``gdn_block``, here by hand), three."""
+    b, h, dk, dv = 2, 3, 16, 8
+    ks = jax.random.split(jax.random.key(s), 6)
+    q = jax.random.normal(ks[0], (b, s, h, dk))
+    k = jax.random.normal(ks[1], (b, s, h, dk))
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    v = jax.random.normal(ks[2], (b, s, h, dv))
+    g = -jax.nn.softplus(jax.random.normal(ks[3], (b, s, h)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, s, h)))
+    S0 = jax.random.normal(ks[5], (b, h, dk, dv))
+
+    def step(S, x):
+        o, S = gdn.delta_rule_step(*x, S)
+        return S, o
+
+    S_want, o_want = jax.jit(lambda S, xs: jax.lax.scan(step, S, xs))(
+        S0, jax.tree.map(lambda a: jnp.moveaxis(a, 1, 0),
+                         (q, k, v, g, beta)))
+    pad = -s % gdn.CHUNK
+    padded = [jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2))
+              for a in (q, k, v, g, beta)]
+    o_got, S_got = jax.jit(gdn.delta_rule_chunked)(*padded, S0)
+    np.testing.assert_allclose(o_got[:, :s], jnp.moveaxis(o_want, 0, 1),
+                               atol=2e-5)
+    np.testing.assert_allclose(S_got, S_want, atol=2e-5)
+
+
+def test_bf16_weights_read_a_float32_activation_in_two_passes():
+    """The mixer's projections (and the shared expert's) take the float32
+    stream as it is: against
+    the same bf16 weights in float32 the product is off by ~2^-17, where
+    an activation rounded to bf16 first is off by ~2^-9."""
+    ks = jax.random.split(jax.random.key(7), 2)
+    x = jax.random.normal(ks[0], (96, 256))
+    w = jax.random.normal(ks[1], (256, 128)).astype(jnp.bfloat16)
+    want = jnp.dot(x, w.astype(jnp.float32), precision="highest")
+
+    def off(got):
+        return float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+
+    one_pass = jnp.dot(x.astype(jnp.bfloat16), w,
+                       preferred_element_type=jnp.float32)
+    assert off(jax.jit(dot_f32)(x, w)) < 2e-5 < 1e-3 < off(one_pass)
+    # an activation that has the weight's precision already: one product
+    xb = x.astype(jnp.bfloat16)
+    np.testing.assert_array_equal(dot_f32(xb, w), one_pass)
+    assert dot_f32(xb, w).dtype == jnp.float32
+
+
+def test_a_padded_prefill_is_the_unpadded_one(model):
+    """A bucket's padded tail changes neither the logits of the real
+    positions nor the state and convolution tail handed to decode."""
+    cfg, params = model
+    n, width = 70, 128
+    toks = jax.random.randint(jax.random.key(2), (1, width), 1, 500)
+
+    @jax.jit
+    def prefill(tokens, valid):
+        k, v = model_lib.init_kv_cache(cfg, 1, 256)
+        return model_lib.forward_cached_hybrid(
+            cfg, params, tokens, k, v, jnp.int32(0),
+            model_lib.init_rec_state(cfg, 1), valid=valid, empty_cache=True)
+
+    exact = prefill(toks[:, :n], None)
+    padded = prefill(toks, (jnp.arange(width) < n)[None])
+    np.testing.assert_allclose(padded[0][:, :n], exact[0], atol=2e-5)
+    for key in ("S", "conv"):
+        np.testing.assert_allclose(padded[3][key], exact[3][key], atol=2e-5)
+    np.testing.assert_array_equal(padded[3]["load"], exact[3]["load"])
+    assert int(exact[3]["load"].sum()) == cfg.num_layers * n * cfg.moe_top_k
+    # and the state is not the one after the padded tail
+    through = prefill(toks, None)
+    assert float(jnp.abs(through[3]["S"] - exact[3]["S"]).max()) > 1e-3
+
+
+def test_decode_continues_the_prefill_and_skips_dead_rows(model):
+    cfg, params = model
+    toks = jax.random.randint(jax.random.key(3), (2, 34), 1, 500)
+    want = jax.jit(lambda t: model_lib.forward(cfg, params, t))(toks)
+    k, v = model_lib.init_kv_cache(cfg, 2, 64)
+    _, k, v, rec = jax.jit(lambda t, k, v: model_lib.forward_cached_hybrid(
+        cfg, params, t, k, v, jnp.int32(0),
+        model_lib.init_rec_state(cfg, 2), empty_cache=True))(
+            toks[:, :30], k, v)
+    live = jnp.asarray([[True], [False]])
+    step = jax.jit(lambda t, k, v, n, rec: model_lib.forward_cached_hybrid(
+        cfg, params, t, k, v, n, rec, valid=live))
+    for i in range(30, 34):
+        before = rec
+        logits, k, v, rec = step(toks[:, i:i + 1], k, v,
+                                 jnp.full((2,), i, jnp.int32), rec)
+        np.testing.assert_allclose(logits[0, 0], want[0, i], atol=2e-5)
+        for key in ("S", "conv"):     # the row that is not live keeps its
+            np.testing.assert_array_equal(rec[key][:, 1], before[key][:, 1])
+            assert float(jnp.abs(rec[key][:, 0]
+                                 - before[key][:, 0]).max()) > 0
+    assert int(rec["load"].sum()) == cfg.num_layers * cfg.moe_top_k * (
+        2 * 30 + 4)
+
+
+def test_a_tokens_experts_do_not_depend_on_its_batch(model):
+    """Dropless: no capacity, so no neighbour can take a token's place;
+    and routed in chunks or at once is the same."""
+    cfg, params = model
+    p = jax.tree.map(lambda a: a[0], params["layers"][0]["mlp"])
+    x = jax.random.normal(jax.random.key(4), (1, 96, cfg.hidden_size))
+    run = jax.jit(moe.moe_dropless_block, static_argnums=0)
+    alone, _ = run(cfg, p, x[:, :1])
+    # the same token among 95 others that all prefer its experts
+    crowd = x.at[:, 1:].set(x[:, :1] + 1e-3 * x[:, 1:])
+    among, stats = run(cfg, p, crowd)
+    np.testing.assert_allclose(among[:, 0], alone[:, 0], atol=1e-6)
+    assert float(stats["load"].max()) >= 90      # a capacity would drop
+    at_once, _ = run(dataclasses.replace(cfg, moe_group_size=96), p, crowd)
+    in_chunks, _ = run(dataclasses.replace(cfg, moe_group_size=32), p, crowd)
+    np.testing.assert_allclose(in_chunks, at_once, atol=1e-6)
+
+
+def test_rotary_turns_a_quarter_of_the_head_and_keeps_the_norm():
+    x = jax.random.normal(jax.random.key(5), (1, 7, 2, 32))
+    pos = jnp.arange(7)[None] + 1000
+    y = apply_rope_partial(x, pos, 8, 1e7)
+    np.testing.assert_array_equal(y[..., 8:], x[..., 8:])
+    np.testing.assert_allclose(jnp.linalg.norm(y[..., :8], axis=-1),
+                               jnp.linalg.norm(x[..., :8], axis=-1),
+                               rtol=1e-5)
+    assert float(jnp.abs(y[..., :8] - x[..., :8]).max()) > 0.1
+    np.testing.assert_array_equal(
+        apply_rope_partial(x, jnp.zeros((1, 7), jnp.int32), 8, 1e7), x)
+
+
+def test_the_zero_centred_norm_scales_by_one_plus_its_weight():
+    p = norm_init("rmsnorm_zero", 16)
+    assert float(jnp.abs(p["scale"]).max()) == 0.0
+    x = jax.random.normal(jax.random.key(6), (3, 16))
+    unit = norm_apply("rmsnorm_zero", x, p, 1e-6)
+    np.testing.assert_allclose(jnp.mean(unit * unit, axis=-1), 1.0,
+                               rtol=1e-4)
+    np.testing.assert_allclose(
+        norm_apply("rmsnorm_zero", x, {"scale": p["scale"] + 0.5}, 1e-6),
+        1.5 * unit, rtol=1e-6)
