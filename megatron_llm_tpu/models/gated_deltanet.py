@@ -16,10 +16,22 @@ RMS-normalised a head, gated by ``SiLU(z)`` and projected back.
 Two forms of the same recurrence: ``delta_rule_step`` for the one new
 position of a decode step, and ``delta_rule_chunked`` for a prompt, which
 rearranges ``CHUNK`` positions at a time into matrix products (the WY
-form of the paper's section 3) and carries ``S`` from chunk to chunk in a
-``lax.scan``.  A position whose ``valid`` is false (the padded tail of a
-prefill bucket) has ``beta = 0`` and ``g = 0``: it changes neither ``S``
-nor the convolution's tail, whatever it holds.
+form of the paper's section 3).  The chunked form runs as one Pallas
+kernel (``kernels/gdn_scan.py``) for every ``s > 1``: it reads q, k and v
+as the ``[b, s, heads x width]`` rows the projection leaves, picks a
+value head's key head itself, keeps ``S`` in VMEM from a prompt's first
+chunk to its last and writes nothing else it computes to HBM.  The
+state, the kernel's operands and everything that meets them are float32,
+and every product of the rule is taken at ``Precision.HIGHEST``: the rule
+takes differences of near-equal quantities (``v - S^T k``), so one bf16
+rounding comes out of it three times as large and the next router's
+near-ties turn that into other experts (PERF.md, PR 35).  The kernel is
+forward only, as the einsum form it replaced was only ever run: training
+through the rule is not there yet (tests/kernels/test_gdn_scan.py keeps
+the einsum form as an oracle beside the recurrence).  A position whose
+``valid`` is false (the padded tail of a prefill bucket) has ``beta = 0``
+and ``g = 0``: it changes neither ``S`` nor the convolution's tail,
+whatever it holds.
 
 The fused input projection is laid out flat, ``[q | k | v | z]`` (key
 heads x key width twice, value heads x value width twice) and ``[b | a]``;
@@ -36,11 +48,11 @@ import jax
 import jax.numpy as jnp
 
 from ..config import ModelConfig
+from ..kernels.gdn_scan import CHUNK, gdn_scan
 from ..ops.precision import dot_f32
 
 Params = dict
 
-CHUNK = 64
 L2_EPS = 1e-6
 # the state and everything that meets it is float32, and its products are
 # taken at full float32 precision: XLA:TPU's default for float32 operands
@@ -120,58 +132,12 @@ def delta_rule_step(q, k, v, g, beta, S):
 
 @jax.named_scope("gdn_scan")
 def delta_rule_chunked(q, k, v, g, beta, S):
-    """``CHUNK`` positions at a time.  ``q k`` [b, s, h, dk], ``v``
-    [b, s, h, dv], ``g beta`` [b, s, h], ``S`` [b, h, dk, dv], float32,
-    ``s`` a multiple of ``CHUNK`` → ``(o [b, s, h, dv], S)``.
-
-    Within a chunk the rule's ``d_t`` solve ``(I + L) D = beta V -
-    (beta K e^G) S0``, ``L`` strictly lower triangular with ``L_ij =
-    beta_i (k_i . k_j) e^{G_i - G_j}`` and ``G`` the running sum of ``g``;
-    ``L`` is nilpotent, so ``(I + L)^-1 = prod_m (I + (-L)^(2^m))``: six
-    products of ``CHUNK``-square matrices and no row-by-row substitution.
-    Everything that does not depend on ``S`` is computed for all chunks at
-    once; the scan carries ``S`` alone."""
-    b, s, h, dk = q.shape
-    dv, n, c = v.shape[-1], s // CHUNK, CHUNK
-
-    def chunks(x):       # [b, s, h, ...] -> [n, b, h, c, ...]
-        x = x.reshape((b, n, c, h) + x.shape[3:])
-        return jnp.moveaxis(jnp.moveaxis(x, 3, 2), 1, 0)
-
-    q, k, v, g, beta = map(chunks, (q, k, v, g, beta))
-    G = jnp.cumsum(g, axis=-1)                          # [n, b, h, c]
-    diff = G[..., :, None] - G[..., None, :]
-    lower = jnp.tril(jnp.ones((c, c), bool))
-    decay = jnp.exp(jnp.where(lower, diff, -jnp.inf))   # i >= j, else 0
-    strict = jnp.tril(jnp.ones((c, c), bool), -1)
-    kb, vb = k * beta[..., None], v * beta[..., None]
-    kk = jnp.einsum("nbhik,nbhjk->nbhij", kb, k, precision=_PREC)
-    m = jnp.where(strict, -kk * decay, 0.0)             # -L
-    eye = jnp.eye(c, dtype=jnp.float32)
-    t = eye + m
-    for _ in range(int(math.log2(c)) - 1):
-        m = jnp.einsum("nbhij,nbhjl->nbhil", m, m, precision=_PREC)
-        t = t + jnp.einsum("nbhij,nbhjl->nbhil", t, m, precision=_PREC)
-    u = jnp.einsum("nbhij,nbhjv->nbhiv", t, vb, precision=_PREC)
-    w = jnp.einsum("nbhij,nbhjk->nbhik", t, kb * jnp.exp(G)[..., None],
-                   precision=_PREC)
-    qk = jnp.einsum("nbhik,nbhjk->nbhij", q, k, precision=_PREC) * decay
-    q_in = q * jnp.exp(G)[..., None]
-    k_out = k * jnp.exp(G[..., -1:] - G)[..., None]
-    g_end = jnp.exp(G[..., -1])                         # [n, b, h]
-
-    def step(S, xs):
-        u_c, w_c, qk_c, q_c, k_c, ge = xs
-        d = u_c - jnp.einsum("bhik,bhkv->bhiv", w_c, S, precision=_PREC)
-        o = (jnp.einsum("bhik,bhkv->bhiv", q_c, S, precision=_PREC)
-             + jnp.einsum("bhij,bhjv->bhiv", qk_c, d, precision=_PREC))
-        S = S * ge[..., None, None] + jnp.einsum(
-            "bhik,bhiv->bhkv", k_c, d, precision=_PREC)
-        return S, o
-
-    S, o = jax.lax.scan(step, S, (u, w, qk, q_in, k_out, g_end))
-    o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 2, 3)       # [b, n, c, h, dv]
-    return o.reshape(b, s, h, dv), S
+    """``CHUNK`` positions at a time, as ``kernels/gdn_scan.py`` arranges
+    them.  ``q k`` [b, s, key heads x dk], ``v`` [b, s, value heads x dv]
+    (rows as the projection leaves them), ``g beta`` [b, s, value heads],
+    ``S`` [b, value heads, dk, dv], float32, ``s`` a multiple of ``CHUNK``
+    → ``(o [b, s, value heads x dv], S)``."""
+    return gdn_scan(q, k, v, g, beta, S)
 
 
 @jax.named_scope("gdn_conv")
@@ -218,26 +184,28 @@ def gdn_block(cfg: ModelConfig, p: Params, x: jax.Array,
                         jnp.sum(valid, axis=1, dtype=jnp.int32))
     q = _l2norm(mixed[..., :kd].reshape(b, s, nk, dk)) * dk ** -0.5
     k = _l2norm(mixed[..., kd:2 * kd].reshape(b, s, nk, dk))
-    v = mixed[..., 2 * kd:].reshape(b, s, nv, dv)
-    # each key head serves value heads / key heads value heads
-    q = jnp.repeat(q, nv // nk, axis=2)
-    k = jnp.repeat(k, nv // nk, axis=2)
+    v = mixed[..., 2 * kd:]
     live = valid[..., None].astype(jnp.float32)
     beta = jax.nn.sigmoid(ba[..., :nv]) * live
     g = -jnp.exp(p["A_log"]) * jax.nn.softplus(
         ba[..., nv:] + p["dt_bias"]) * live
     if s == 1:
-        o, S = delta_rule_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
-                               beta[:, 0], state.S)
-        o = o[:, None]
+        # each key head serves value heads / key heads value heads
+        o, S = delta_rule_step(jnp.repeat(q[:, 0], nv // nk, axis=1),
+                               jnp.repeat(k[:, 0], nv // nk, axis=1),
+                               v.reshape(b, nv, dv), g[:, 0], beta[:, 0],
+                               state.S)
     else:
         pad = -s % CHUNK       # padded positions: beta = g = 0, no-ops
 
         def padded(a):
-            return jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2))
+            return jnp.pad(a, [(0, 0), (0, pad), (0, 0)])
 
-        o, S = delta_rule_chunked(*map(padded, (q, k, v, g, beta)), state.S)
+        o, S = delta_rule_chunked(
+            padded(q.reshape(b, s, kd)), padded(k.reshape(b, s, kd)),
+            *map(padded, (v, g, beta)), state.S)
         o = o[:, :s]
+    o = o.reshape(b, s, nv, dv)
     # RMSNorm a head first, the gate after
     o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
                           + cfg.norm_eps)

@@ -33,6 +33,7 @@ from megatron_llm_tpu.config import ParallelConfig, llama2_config  # noqa: E402
 from megatron_llm_tpu.kernels import decode_step as ds  # noqa: E402
 from megatron_llm_tpu.kernels import flash_decode as fd  # noqa: E402
 from megatron_llm_tpu.kernels.flash_attention import flash_attention  # noqa: E402
+from megatron_llm_tpu.kernels.gdn_scan import gdn_scan  # noqa: E402
 from megatron_llm_tpu.kernels.rmsnorm import (  # noqa: E402
     layernorm_pallas,
     rmsnorm_pallas,
@@ -224,6 +225,37 @@ def test_norm_fwd_bwd(topo, norm, hidden):
             return layernorm_pallas(x, w, b, 1e-5, False).astype(
                 jnp.float32).sum()
         _compile(jax.grad(loss, argnums=(0, 1, 2)), (x, w, w), one)
+
+
+@pytest.mark.parametrize("s", [2048, 16384])
+def test_gdn_scan(topo, s):
+    """The chunked delta rule at the published widths of Qwen3-Next (16
+    key and 32 value heads of width 128, one prompt): the kernel is the
+    whole of it.  No ``while`` is left of the scan over chunks, q, k, v
+    and o stay where they lie as ``[1, s, heads x width]`` rows (the
+    only arrays moved are ``g`` and ``beta``, 1/400 of the bytes), and
+    the kernel's blocks and temporaries take the VMEM stated here."""
+    one = SingleDeviceSharding(topo.devices[0])
+    nk, nv, d = 16, 32, 128
+    f32 = jnp.float32
+    text = _compile(
+        lambda *a: gdn_scan(*a, interpret=False),
+        (_sds((1, s, nk * d), f32), _sds((1, s, nk * d), f32),
+         _sds((1, s, nv * d), f32), _sds((1, s, nv), f32),
+         _sds((1, s, nv), f32), _sds((1, nv, d, d), f32)), one)
+    assert " while(" not in text
+    assert not relayout_bytes(text, min_bytes=4 * s * nk * d)
+    _no_copy_of(text, f"f32[1,{s},{nk * d}]")
+    _no_copy_of(text, f"f32[1,{s},{nv * d}]")
+    call, = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    vmem, = re.findall(r'"used_scoped_memory_configs":\[\{"memory_space":'
+                       r'"1","offset":"0","size":"(\d+)"', call)
+    # 512 rows a step of q, k (128 wide), v and o (256 wide), double
+    # buffered: 3 MiB; two heads' state in and out: 0.5 MiB; the rest is
+    # what a chunk's matrices spill.  Of 16 MiB a kernel may have by
+    # default
+    assert 3.5 * 2 ** 20 < int(vmem) < 8 * 2 ** 20, vmem
 
 
 # -- whole-stack fused decode kernels (the geometry that is eligible) ------

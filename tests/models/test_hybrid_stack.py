@@ -82,10 +82,26 @@ def test_the_chunked_rule_is_the_recurrence_across_chunk_edges(s):
     pad = -s % gdn.CHUNK
     padded = [jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2))
               for a in (q, k, v, g, beta)]
+    # the kernel reads rows as the projection leaves them, heads x width
+    padded[:3] = [a.reshape(b, s + pad, -1) for a in padded[:3]]
     o_got, S_got = jax.jit(gdn.delta_rule_chunked)(*padded, S0)
-    np.testing.assert_allclose(o_got[:, :s], jnp.moveaxis(o_want, 0, 1),
-                               atol=2e-5)
+    np.testing.assert_allclose(o_got.reshape(b, s + pad, h, dv)[:, :s],
+                               jnp.moveaxis(o_want, 0, 1), atol=2e-5)
     np.testing.assert_allclose(S_got, S_want, atol=2e-5)
+
+
+def test_the_mixer_runs_the_kernel_for_a_prompt_and_the_step_for_one():
+    """``gdn_block`` has one path a shape: every ``s > 1`` lowers to the
+    kernel, ``s == 1`` to the one-position rule."""
+    cfg = tiny()
+    p = gdn.init_gdn_params(jax.random.key(0), cfg)
+    for s, kernel in ((2, True), (70, True), (1, False)):
+        x = jax.ShapeDtypeStruct((1, s, cfg.hidden_size), jnp.float32)
+        text = str(jax.make_jaxpr(
+            lambda p, x: gdn.gdn_block(cfg, p, x))(p, x))
+        assert ("pallas_call" in text) == kernel, s
+        # the only loop left is the kernel's own, over a step's chunks
+        assert text.count("scan[") + text.count("while[") == kernel, s
 
 
 def test_bf16_weights_read_a_float32_activation_in_two_passes():
