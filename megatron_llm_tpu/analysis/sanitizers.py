@@ -6,10 +6,10 @@ primitives, zero extra work) when disabled, so the instrumentation
 stays in production code.  Four checkers:
 
 * **recompilation guard** — :class:`CompileCounter` /
-  :func:`no_recompiles` count actual backend compiles via jax's
-  monitoring events; serving tests wrap their steady-state phase in
-  ``with no_recompiles():`` to prove the fixed-shape-executable
-  invariant (zero post-warmup compiles).
+  :func:`no_recompiles` count the executables built or loaded, from the
+  process's compilation records (``obs/compile.py``); serving tests wrap
+  their steady-state phase in ``with no_recompiles():`` to prove the
+  fixed-shape-executable invariant (zero post-warmup compiles).
 * **lock-order checker** — :func:`make_lock` / :func:`make_condition`
   hand out :class:`TrackedLock` s that record the cross-thread lock
   acquisition graph; a cycle (thread A takes X then Y, thread B takes
@@ -26,8 +26,9 @@ stays in production code.  Four checkers:
   final token list (exactly-once delivery across crashes, failovers,
   shipments, and migrations); the cluster chaos tests are its consumer.
 
-This module imports jax lazily (only inside the compile counter) so the
-static-analysis side of the package stays importable on a bare host.
+This module imports jax lazily (only through the compile counter, which
+reads ``obs/compile.py``) so the static-analysis side of the package
+stays importable on a bare host.
 Sanitizers read private engine/pool fields by design — they are the
 auditors, not the API.
 """
@@ -37,7 +38,6 @@ from __future__ import annotations
 import contextlib
 import os
 import threading
-import time
 from typing import Dict, Iterator, List, Optional, Set
 
 __all__ = [
@@ -74,70 +74,58 @@ class RecompilationError(AssertionError):
     """A hot-path executable recompiled after warmup."""
 
 
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-_counters_mu = threading.Lock()
-_active_counters: List["CompileCounter"] = []
-_listener_installed = False
-# perf_counter of the last backend-compile completion, keyed by the
-# ident of the thread that ran the compile (compiles block the calling
-# thread, so the listener fires on it).  The cluster watchdog reads this
-# to tell "scheduler wedged" apart from "scheduler inside a legitimate
-# first-dispatch compile".
-_last_compile_end: Dict[int, float] = {}
+def _compiles():
+    """The process's compilation records (``obs/compile.py``), its
+    listener installed.  Imported here, not above: obs takes its locks
+    from this module."""
+    from ..obs import compile as obs_compile
 
-
-def _install_compile_listener() -> None:
-    global _listener_installed
-    with _counters_mu:
-        if _listener_installed:
-            return
-        _listener_installed = True
-    import jax
-
-    def _on_event(event: str, duration: float, **_kw) -> None:
-        if event != _COMPILE_EVENT:
-            return
-        with _counters_mu:
-            _last_compile_end[threading.get_ident()] = time.perf_counter()
-            for c in _active_counters:
-                c.count += 1
-
-    jax.monitoring.register_event_duration_secs_listener(_on_event)
+    return obs_compile.install()
 
 
 def install_compile_clock() -> None:
     """Start recording backend-compile completions (idempotent); read
     them back with :func:`last_backend_compile_s`."""
-    _install_compile_listener()
+    _compiles()
 
 
 def last_backend_compile_s(thread_ident: Optional[int] = None) -> float:
     """perf_counter time of the most recent backend-compile completion —
     on ``thread_ident`` if given, else across all threads; 0.0 if none
-    recorded.  Only meaningful after :func:`install_compile_clock`."""
-    with _counters_mu:
-        if thread_ident is not None:
-            return _last_compile_end.get(thread_ident, 0.0)
-        return max(_last_compile_end.values(), default=0.0)
+    recorded.  Only meaningful after :func:`install_compile_clock`.
+    The cluster watchdog reads this to tell "scheduler wedged" apart
+    from "scheduler inside a legitimate first-dispatch compile"
+    (compiles block the calling thread, so the listener fires on it)."""
+    return _compiles().last_backend_end(thread_ident)
 
 
 class CompileCounter:
-    """Counts actual backend compiles while active (cache hits emit
-    nothing, so ``count`` is exactly the number of fresh executables
-    built inside the ``with`` block)."""
+    """Counts the executables whose backend stage ended while active, on
+    any thread.  Under jax 0.9.0 that stage wraps a read from the
+    persistent compilation cache as it wraps a fresh XLA compile, so a
+    persistent-cache hit counts too; what emits nothing is a call that
+    finds its executable in the jitted function's own in-memory cache.
+    ``count`` is therefore the number of executables built *or loaded*
+    inside the ``with`` block — zero in a warmed-up steady state."""
 
     def __init__(self) -> None:
-        self.count = 0
+        self._log = None        # the records, while active
+        self._from = 0          # their executables at entry
+        self._count = 0
+
+    @property
+    def count(self) -> int:
+        if self._log is None:
+            return self._count
+        return self._count + self._log.executables - self._from
 
     def __enter__(self) -> "CompileCounter":
-        _install_compile_listener()
-        with _counters_mu:
-            _active_counters.append(self)
+        self._log = _compiles()
+        self._from = self._log.executables
         return self
 
     def __exit__(self, *exc) -> None:
-        with _counters_mu:
-            _active_counters.remove(self)
+        self._count, self._log = self.count, None
 
 
 @contextlib.contextmanager

@@ -14,6 +14,9 @@ emit, replacing the three disconnected registries that grew organically
   clock-sync pair.
 - ``profile``: the process's one ``jax.profiler`` session, started and
   stopped while it runs (trainer step windows, ``POST /profile``).
+- ``compile``: the process's one ``jax.monitoring`` listener; every
+  compilation is a record (program, stage seconds, cache outcome) that
+  the trace export, the registry and the event log each show.
 - ``logging``: rank-aware structured JSON event log carrying
   ``request_id`` correlation ids end-to-end.
 - ``slo``: rolling-window TTFT / ITL / availability objectives with

@@ -22,6 +22,17 @@ with ``profile.to_trace_ns``.  ``span(..., annotate=True)`` and
 ``device_annotation`` also write the span into the profile itself as a
 ``jax.profiler.TraceAnnotation``; ``add()`` spans are not mirrored.
 
+Compilations: ``chrome_trace()`` also carries the process's compilation
+records (``obs/compile.py``) that overlap what the recorder retains —
+from its oldest span's start to its newest one's end — as ``compile``
+complete events on a track of their own, each with its ``cause``: the
+innermost retained span that contains it (for the engine a ``prefill``
+with its request id and padded width, or the ``engine_step``; for the
+train loop the ``train-step`` or ``setup``).  Found at export, so the
+paths that record spans pay nothing; a compilation that ran while the
+recorder held nothing around it (``TRAIN_TRACE`` switched off) has no
+place on its timeline and is left out.
+
 ``TRAIN_TRACE`` is the train loop's recorder (``utils/timers.py`` feeds
 it from the loop's timers).  It is off unless a profile session or a
 reader switches it on.
@@ -41,7 +52,11 @@ from collections import deque
 from typing import Dict, Iterator, List, Optional
 
 from ..analysis.sanitizers import make_lock
+from . import compile as compiles
 from . import profile
+
+#: ``tid`` of the ``compile`` events' track (request ids count up from 0)
+COMPILE_TID = 1 << 30
 
 _PROFILER_SENTINEL = object()
 _profiler = _PROFILER_SENTINEL  # lazily resolved jax.profiler module (or None)
@@ -164,6 +179,7 @@ class TraceRecorder:
             if ev_args:
                 ev["args"] = ev_args
             out.append(ev)
+        out.extend(self._compile_events(events))
         other = {"dropped_events": dropped,
                  "epoch_perf_counter": self._epoch}
         session = profile.last()
@@ -171,6 +187,41 @@ class TraceRecorder:
             other["clock_sync"] = session.clock_sync()
         return {"traceEvents": out, "displayTimeUnit": "ms",
                 "otherData": other}
+
+
+    def _compile_events(self, events) -> List[Dict]:
+        """The compilation records that overlap ``events``' extent, as
+        complete events with their cause (see the module docstring)."""
+        spans = [e for e in events if e[1] == "X"]
+        if not spans:
+            return []
+        lo = min(e[2] for e in spans)
+        hi = max(e[2] + e[3] for e in spans)
+        out: List[Dict] = []
+        for rec in compiles.COMPILES.records():
+            c0, c1 = rec["t0"], rec["t1"]
+            if c1 < lo or c0 > hi:
+                continue
+            cause = None
+            for e in spans:
+                if e[2] <= c0 and c1 <= e[2] + e[3] \
+                        and (cause is None or e[3] < cause[3]):
+                    cause = e
+            args: Dict = {"program": rec["program"],
+                          "stage_s": compiles.stage_seconds(rec),
+                          "cache": rec["cache"]}
+            if cause is not None:
+                args["cause"] = {"span": cause[0], **(cause[6] or {})}
+                if cause[5] is not None:
+                    args["cause"]["request_id"] = cause[5]
+            out.append({"name": "compile", "ph": "X",
+                        "ts": round((c0 - self._epoch) * 1e6, 3),
+                        "dur": round((c1 - c0) * 1e6, 3), "pid": self._pid,
+                        "tid": COMPILE_TID, "args": args})
+        if out:
+            out.append({"name": "thread_name", "ph": "M", "pid": self._pid,
+                        "tid": COMPILE_TID, "args": {"name": "compile"}})
+        return out
 
 
 # the train loop's spans (driver.pretrain's timers); see the module docstring

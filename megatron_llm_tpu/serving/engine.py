@@ -130,6 +130,7 @@ from ..analysis import sanitizers
 from ..config import ModelConfig
 from ..generation.sampling import NEG_INF
 from ..models import model as model_lib
+from ..obs import compile as obs_compile
 from ..obs.logging import EVENT_LOG
 from ..obs.trace import TraceRecorder, device_annotation
 from ..ops.lora import arena_sr, slot_mask
@@ -1075,6 +1076,8 @@ class ServingEngine:
         self.metrics.set_gauges(num_slots=self.config.max_batch_size)
         self.trace = TraceRecorder(capacity=self.config.trace_capacity,
                                    enabled=self.config.trace)
+        # a compile inside a span of this recorder is exported with it
+        obs_compile.install()
         self.queue = RequestQueue(self.config.max_queue_size,
                                   self.config.retry_after_s)
         self.slots: Optional[SlotAllocator] = None  # allocated on start
@@ -2009,9 +2012,9 @@ class ServingEngine:
             matched = lease.tokens
             k_small, v_small = self._gather_lease(lease)
             suffix = plen - matched
-            width = min(-(-suffix // bucket) * bucket,
-                        self.config.max_seq_len - matched)
-            tokens = np.zeros((1, width), np.int32)
+            padded = min(-(-suffix // bucket) * bucket,
+                         self.config.max_seq_len - matched)
+            tokens = np.zeros((1, padded), np.int32)
             tokens[0, :suffix] = req.prompt[matched:]
             with device_annotation("prefill"):
                 last_logits, k_small, v_small = self._prefill_chunk_fn(
@@ -2054,7 +2057,7 @@ class ServingEngine:
         t.stop()
         self.trace.add("prefill", t_pf, time.perf_counter(),
                        request_id=req.rid, tid=req.id,
-                       args={"prompt_len": plen,
+                       args={"prompt_len": plen, "padded": padded,
                              "cached_tokens": lease.tokens if lease else 0,
                              "iter": self._iter})
         self._admit_count += 1

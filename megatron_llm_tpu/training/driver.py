@@ -37,6 +37,7 @@ from ..data.samplers import BatchIterator
 from ..models import model as model_lib
 from ..models import sharding as shard_lib
 from ..models.transformer import rope_tables
+from ..obs import compile as obs_compile
 from ..obs import profile as obs_profile
 from ..obs.logging import EVENT_LOG
 from ..obs.trace import TRAIN_TRACE
@@ -437,6 +438,8 @@ class _LogState:
         self.t_start = time.perf_counter()
         # set-up facts that ride on the first log_window event
         self.once: dict = {}
+        # the compilation records' sequence number at the last event
+        self.compile_seq = obs_compile.COMPILES.seq
 
     def reset_window(self):
         self.total_loss = 0.0
@@ -518,6 +521,12 @@ def training_log(cfg: RuntimeConfig, log: _LogState, metrics: dict,
         "per-iteration wall time over log windows",
         buckets=(0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0,
                  10.0, 30.0, 60.0)).observe(per_iter)
+    # the executables built or loaded since the last event, by program:
+    # which step recompiled is a grep for "compiles"
+    log.compile_seq, compiles = obs_compile.COMPILES.executables_since(
+        log.compile_seq)
+    if compiles:
+        log.once["compiles"] = compiles
     EVENT_LOG.emit(
         "training", "log_window", iteration=iteration,
         consumed_samples=consumed_samples, lm_loss=round(avg_loss, 6),
@@ -686,6 +695,7 @@ def pretrain(
     """
     cfg.validate()
     t_start = time.time()
+    obs_compile.install()
     # the loop's timers are also its spans (obs/trace.py:TRAIN_TRACE)
     timers = Timers(spans=TRAIN_TRACE)
     writer = NullWriter()

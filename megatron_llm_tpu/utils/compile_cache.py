@@ -17,6 +17,8 @@ from typing import Optional
 
 import jax
 
+from ..obs import compile as obs_compile
+
 logger = logging.getLogger(__name__)
 
 # fixed, never a temp name, pid or time: the directory is part of the
@@ -35,7 +37,12 @@ def enable_compile_cache() -> Optional[str]:
     it: XLA:CPU executables of collective-heavy ``shard_map`` programs
     intermittently abort when read back from a warm cache (jax 0.9.0;
     tests/conftest.py), and nothing there compiles for minutes.
+
+    Cache on or off, the process's compilations are recorded from here on
+    (``obs/compile.py``): what a start compiled, loaded or only traced
+    again is read from those records.
     """
+    obs_compile.install()
     # The cache's key leaves an operation's metadata out by default, so a
     # program whose scope names changed (jax.named_scope, a kernel's name)
     # would be handed the executable compiled before the change — and a

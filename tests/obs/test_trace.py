@@ -3,6 +3,8 @@ trace-event JSON schema, and the disabled-recorder fast path."""
 
 import json
 
+import pytest
+
 from megatron_llm_tpu.obs.trace import TraceRecorder, device_annotation
 
 
@@ -78,3 +80,51 @@ def test_negative_duration_clamped():
     tr.add("clock_skew", 2.0, 1.0)  # t1 < t0 must not export dur < 0
     (ev,) = tr.chrome_trace()["traceEvents"]
     assert ev["dur"] == 0
+
+
+def test_chrome_trace_carries_compilations_with_their_cause(monkeypatch):
+    """A compilation record (obs/compile.py) that overlaps what the
+    recorder retains is a ``compile`` event on the recorder's clock whose
+    ``cause`` is the innermost span around it; one that ran outside is
+    left out."""
+    import time
+
+    from megatron_llm_tpu.obs import compile as obs_compile
+    from megatron_llm_tpu.obs import trace as trace_mod
+
+    now = time.perf_counter()
+    log = obs_compile.CompileLog(clock=lambda: clock[0])
+    monkeypatch.setattr(obs_compile, "COMPILES", log)
+    backend = "/jax/core/compile/backend_compile_duration"
+    clock = [now + 4.0]
+    log.on_event("/jax/compilation_cache/cache_misses")
+    log.on_duration(backend, 2.0, fun_name="jit(_prefill_impl)")  # [2, 4]
+    clock[0] = now + 20.0
+    log.on_duration(backend, 1.0, fun_name="jit(later)")          # [19, 20]
+
+    tr = TraceRecorder()
+    tr.add("engine_step", now + 1.0, now + 6.0, args={"iter": 7})
+    tr.add("prefill", now + 1.5, now + 5.0, request_id="req-9", tid=9,
+           args={"prompt_len": 300, "padded": 512})
+    tr.add("decode", now + 2.5, now + 3.0, request_id="req-8", tid=8)
+    doc = tr.chrome_trace()
+    (ev,) = [e for e in doc["traceEvents"] if e["name"] == "compile"]
+    assert ev["ph"] == "X" and ev["tid"] == trace_mod.COMPILE_TID
+    assert ev["args"]["program"] == "jit(_prefill_impl)"
+    assert ev["args"]["stage_s"] == {"backend": 2.0}
+    assert ev["args"]["cache"] == "miss"
+    assert ev["args"]["cause"] == {"span": "prefill", "request_id": "req-9",
+                                   "prompt_len": 300, "padded": 512}
+    # on the recorder's clock: microseconds from its epoch
+    epoch = doc["otherData"]["epoch_perf_counter"]
+    assert epoch + ev["ts"] / 1e6 == pytest.approx(now + 2.0, abs=1e-5)
+    assert ev["dur"] == pytest.approx(2e6, abs=1.0)
+    (track,) = [e for e in doc["traceEvents"] if e["ph"] == "M"]
+    assert track["tid"] == ev["tid"] and track["args"] == {"name": "compile"}
+    json.dumps(doc)
+    # outside every span but inside the recorder's extent: no cause
+    tr.add("engine_step", now + 21.0, now + 22.0, args={"iter": 8})
+    late = [e for e in tr.chrome_trace()["traceEvents"]
+            if e["name"] == "compile"][-1]
+    assert late["args"]["program"] == "jit(later)"
+    assert "cause" not in late["args"]

@@ -369,3 +369,59 @@ def test_admit_and_first_token_spans_share_the_scheduler_iteration(
                         e["ts"] + e["dur"] <= a["ts"] + a["dur"] + 1]
                 if name == "prefill":
                     assert e["args"]["iter"] == a["args"]["iter"]
+
+
+def test_a_compile_inside_a_request_is_named_in_all_three_views():
+    """A prompt width that was never warmed compiles inside the request's
+    ``prefill``: ``GET /trace`` shows a ``compile`` event whose cause
+    carries the request's id, ``/metrics`` counts the program under
+    ``compilations_total`` and the event log holds its line
+    (obs/compile.py; docs/observability.md "Compilations")."""
+    # a width no other test serves, so that the executable is new here
+    cfg = tiny_config(num_layers=1, vocab_size=264,
+                      make_vocab_size_divisible_by=8)
+    params = model_lib.init_params(jax.random.key(1), cfg)
+    svc = GenerationService(cfg, params,
+                            NullTokenizer(vocab_size=cfg.vocab_size),
+                            max_batch_size=2, prefill_bucket=8)
+    try:
+        status, out = svc.handle({"prompts": ["5 9 3 4 1"],
+                                  "tokens_to_generate": 2,
+                                  "no_early_termination": True})
+        assert status == 200
+        (rid,) = out["request_ids"]
+        events = svc.trace_snapshot()["traceEvents"]
+        prefills = [e for e in events if e["name"] == "compile"
+                    and e["args"].get("cause", {}).get("span") == "prefill"]
+        assert prefills, [e["args"] for e in events
+                          if e["name"] == "compile"]
+        by_program = {e["args"]["program"]: e for e in prefills}
+        ev = by_program["jit(_prefill_impl)"]
+        assert ev["args"]["cause"]["request_id"] == rid
+        assert ev["args"]["cause"]["padded"] == 8
+        assert set(ev["args"]["stage_s"]) == {"trace", "lower", "backend"}
+        # the span lies inside its cause on the recorder's clock
+        (pf,) = [e for e in events if e["name"] == "prefill"
+                 and e["args"]["request_id"] == rid]
+        assert pf["ts"] <= ev["ts"] and \
+            ev["ts"] + ev["dur"] <= pf["ts"] + pf["dur"] + 1
+        _types, samples = parse_prometheus(svc.prometheus_metrics())
+        counted = {dict(k[1])["program"]: v for k, v in samples.items()
+                   if k[0] == "compilations_total"}
+        assert counted["jit(_prefill_impl)"] >= 1
+        assert any(k[0] == "compile_seconds_total"
+                   and dict(k[1]) == {"program": "jit(_prefill_impl)",
+                                      "stage": "backend"} for k in samples)
+        lines = [l for l in EVENT_LOG.recent(event="compile")
+                 if l["program"] == "jit(_prefill_impl)"]
+        assert lines and lines[-1]["component"] == "obs"
+        # the same width again: served from the executable, nothing new
+        n = len([e for e in events if e["name"] == "compile"])
+        status, _ = svc.handle({"prompts": ["7 2 6 8"],
+                                "tokens_to_generate": 2,
+                                "no_early_termination": True})
+        assert status == 200
+        assert len([e for e in svc.trace_snapshot()["traceEvents"]
+                    if e["name"] == "compile"]) == n
+    finally:
+        svc.close()

@@ -31,7 +31,9 @@ DOWNWARD = {
     "config": (),
     "utils": (),
     "obs": (),
-    "analysis": (),
+    # analysis/sanitizers.py reads the compilation records of
+    # obs/compile.py (the recompilation guard, the watchdog's clock)
+    "analysis": ("obs",),
     "metrics": ("analysis", "obs"),
     "resilience": ("analysis", "metrics"),
     "kernels": ("config",),

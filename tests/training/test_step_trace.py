@@ -153,3 +153,27 @@ def test_sigusr1_without_a_profile_dir_is_said_and_ignored(run, capsys):
                 train_iters=3)
     assert int(state.iteration) == 3
     assert "SIGUSR1 ignored" in capsys.readouterr().out
+
+
+def test_a_step_that_recompiles_is_named_in_its_log_window_event(
+        tmp_path, capsys):
+    """Batch ramp-up changes the step's batch shape once in this run: the
+    ``log_window`` event that covers that step carries ``compiles`` with
+    the step's program, the steady steps' events carry none
+    (obs/compile.py through driver.training_log)."""
+    cfg = _cfg(tmp_path, train_iters=6, save=None, eval_interval=1000,
+               log_interval=1, rampup_batch_size=(4, 4, 16))
+    ds = MockDataset(cfg.model.vocab_size, cfg.train.seq_length)
+    EVENT_LOG.clear()
+    pretrain(cfg, ds)
+    assert "global batch size ramped to 8" in capsys.readouterr().out
+    events = {e["iteration"]: e for e in EVENT_LOG.recent(event="log_window")}
+    assert sorted(events) == [1, 2, 3, 4, 5, 6]
+    # samples 0-15 at a global batch of 4, then 8: iteration 5 is the
+    # first with the new shape (the first step's executable was built at
+    # set-up, for the dp_grad_collectives reading)
+    recompiled = [it for it, e in events.items()
+                  if "jit(step)" in e.get("compiles", {})]
+    assert recompiled == [5]
+    assert events[5]["compiles"]["jit(step)"] == 1
+    assert all("compiles" not in events[it] for it in (2, 3, 4, 6))
