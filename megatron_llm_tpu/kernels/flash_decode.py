@@ -15,16 +15,27 @@ Paged mode (``flash_decode_paged*``): the cache operands are one layer's
 view of the serving block pool — ``[n_blocks, kv, block, d]``, or the
 whole ``[L, ...]`` pool with a layer index — plus a per-row int32 block
 table ``[b, T]`` mapping each row's logical block j to a physical pool
-block.  The per-block arithmetic is the dense walk's, in a body of its
-own (``_paged_kernel`` / ``_attend_block``: the mask is over logical
-columns ``j*block + lane``), and the BlockSpec index maps differ: the
-cache block for grid tick ``ki`` is ``table[bi, min(ki, last_bi)]``,
-where ``last_bi`` clamps at row bi's own fill — so HBM traffic is the
-sum of per-row fills, not ``b * max_len``.  Entries past a row's fill point at the pool's trash
-block; a block wholly past the fill is skipped and the rest of a partly
-filled one has its scores replaced with NEG_INF before the softmax, so
-trash contents can never reach the output (exp underflows to exactly
-0.0 and 0.0 x finite = 0.0).
+block.  The pool stays in HBM and the kernel walks a row's live blocks
+itself (``_paged_walk_kernel``): one grid step a slot, and in it a loop
+whose trip count comes from the row's fill, each iteration copying the
+next few pool blocks — every KV head of a block in one copy, as they lie
+side by side in the pool — into a double-buffered VMEM scratch while
+the blocks before them are attended as one online-softmax term
+(``_attend_blocks``: the mask is over logical columns).  So HBM traffic
+and the walk's length are the sum of per-row fills, not ``b * max_len``:
+a block past the fill is never copied, an empty row copies nothing.
+How many blocks an iteration takes follows from the shapes
+(``_walk_shape``).  Entries past a row's fill point at the pool's trash
+block and are never read; the rest of a partly filled block (and of a
+partly live iteration) has its scores replaced with NEG_INF before the
+softmax, so what lies there can never reach the output (exp underflows
+to exactly 0.0 and 0.0 x finite = 0.0).
+
+Measured alone on a v5e (PR 39; PERF.md §6): 44 slots of 4-16k rows at
+16 heads over 2 KV heads of width 256, table width 130 — 1.34 ms a call,
+85 % of the rows' HBM time (the grid this replaced, one tick a table
+column and KV head, 4.35 ms); 16 slots of 50-700 rows at Falcon's 71
+heads over one KV head of width 64, table width 16 — 28 us (67).
 
 Who calls what: ``ops/attention.py:decode_attention`` the dense kernels
 (head width 128·n, ``generation/`` and the engine's gather route);
@@ -214,122 +225,195 @@ def _scale_block_spec(block_k):
                         lambda bi, hi, ki, lens: (bi, hi, ki, 0))
 
 
-def _attend_block(scale, col0, n_valid, q, k, v, ks, vs,
-                  m_scr, l_scr, acc_scr, *, kt: bool):
-    """One online-softmax term over a cache block: ``k``/``v`` are
-    [block_k, d] rows at logical columns ``col0..`` (``kt``: [d, block_k],
-    the block as the pool holds it at head width 64); columns at or past
-    ``n_valid`` are masked by score replacement.  ``ks``/``vs`` are the
-    int8 form's per-row fp32 scales as [1, block_k] (``None`` for a float
-    cache): they fold into the score columns (K) and the probability rows
-    (V) — algebraically exact dequantization, int8 HBM traffic."""
+def _attend_blocks(scale, col0, n_valid, q, k, v, ks, vs,
+                   m_scr, l_scr, acc_scr, rows, *, kt: bool):
+    """One online-softmax term over consecutive cache blocks: ``k``/``v``
+    are lists of [block_k, d] rows, together the logical columns
+    ``col0..`` (``kt``: [d, block_k], the block as the pool holds it at
+    head width 64); columns at or past ``n_valid`` are masked by score
+    replacement.  ``ks``/``vs`` are the int8 form's per-row fp32 scales,
+    a [1, block_k] a block (``None`` for a float cache): they fold into
+    the score columns (K) and the probability rows (V) — algebraically
+    exact dequantization, int8 HBM traffic.  The softmax state is rows
+    ``rows`` of the three scratches (one query group's, of the several a
+    grid step holds)."""
     k_dims = (((1,), (0 if kt else 1,)), ((), ()))
     v_dims = (((1,), (1 if kt else 0,)), ((), ()))
     if ks is None:
-        s = jax.lax.dot_general(
-            q, k, k_dims, preferred_element_type=jnp.float32,
-        ) * scale                                      # [g_pad, block_k]
+        s = [jax.lax.dot_general(
+            q, kj, k_dims, preferred_element_type=jnp.float32,
+        ) * scale for kj in k]                         # [g_pad, block_k]
     else:
-        s = jax.lax.dot_general(
-            q.astype(jnp.float32), k.astype(jnp.float32), k_dims,
+        s = [jax.lax.dot_general(
+            q.astype(jnp.float32), kj.astype(jnp.float32), k_dims,
             preferred_element_type=jnp.float32,
-        ) * ks * scale
+        ) * ksj * scale for kj, ksj in zip(k, ks)]
+    block_k = s[0].shape[1]
+    s = jnp.concatenate(s, axis=1)
     cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + col0
     s = jnp.where(cols < n_valid, s, NEG_INF)
 
-    m_prev = m_scr[:, :1]
+    m_prev = m_scr[rows, :1]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
     p = jnp.exp(s - m_new)
-    l_scr[:] = jnp.broadcast_to(
-        alpha * l_scr[:, :1] + jnp.sum(p, axis=-1, keepdims=True),
-        l_scr.shape)
-    if vs is None:
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, v_dims,
-            preferred_element_type=jnp.float32,
-        )
-    else:
-        pv = jax.lax.dot_general(
-            p * vs, v.astype(jnp.float32), v_dims,
-            preferred_element_type=jnp.float32,
-        )
-    acc_scr[:] = acc_scr[:] * alpha + pv
-    m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+    l_scr[rows, :] = jnp.broadcast_to(
+        alpha * l_scr[rows, :1] + jnp.sum(p, axis=-1, keepdims=True),
+        (len(q), l_scr.shape[1]))
+    pv = None
+    for j, vj in enumerate(v):
+        pj = p[:, j * block_k:(j + 1) * block_k]
+        if vs is None:
+            term = jax.lax.dot_general(
+                pj.astype(vj.dtype), vj, v_dims,
+                preferred_element_type=jnp.float32,
+            )
+        else:
+            term = jax.lax.dot_general(
+                pj * vs[j], vj.astype(jnp.float32), v_dims,
+                preferred_element_type=jnp.float32,
+            )
+        pv = term if pv is None else pv + term
+    acc_scr[rows, :] = acc_scr[rows, :] * alpha + pv
+    m_scr[rows, :] = jnp.broadcast_to(m_new, (len(q), m_scr.shape[1]))
 
 
-def _attend_new_row(scale, q, k_row, v_row, m_scr, l_scr, acc_scr):
+def _attend_new_row(scale, q, k_row, v_row, m_scr, l_scr, acc_scr, rows):
     """The new token's own K/V row ([1, d] each) as one more
     online-softmax term, in float32 on the VPU: the row is not in the
     pool yet when its layer's attention runs (the paged route writes all
     layers' rows once, after the layer loop)."""
     s = jnp.sum(q.astype(jnp.float32) * k_row.astype(jnp.float32),
                 axis=-1, keepdims=True) * scale        # [g_pad, 1]
-    m_prev = m_scr[:, :1]
+    m_prev = m_scr[rows, :1]
     m_new = jnp.maximum(m_prev, s)
     alpha = jnp.exp(m_prev - m_new)
     p = jnp.exp(s - m_new)
-    l_scr[:] = jnp.broadcast_to(alpha * l_scr[:, :1] + p, l_scr.shape)
-    acc_scr[:] = acc_scr[:] * alpha + p * v_row.astype(jnp.float32)
-    m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+    l_scr[rows, :] = jnp.broadcast_to(alpha * l_scr[rows, :1] + p,
+                                      (len(q), l_scr.shape[1]))
+    acc_scr[rows, :] = acc_scr[rows, :] * alpha + p * v_row.astype(jnp.float32)
+    m_scr[rows, :] = jnp.broadcast_to(m_new, (len(q), m_scr.shape[1]))
 
 
-def _paged_kernel(scale: float, nk: int, block_k: int, int8: bool,
-                  kt: bool, has_new: bool,
-                  len_ref, tbl_ref, lyr_ref, q_ref, *refs):
-    """Paged walk: tick ``ki`` of row ``bi`` holds its logical block
-    ``ki`` (the table and layer scalars are consumed by the BlockSpec
-    index maps only).  ``refs`` is the block's cache refs — (k, v), or
-    (k, k_scale, v, v_scale) for the int8 pool — then the new token's
-    (k_row, v_row) when ``has_new``, the output, and the three softmax
-    scratches.  A block wholly past the row's fill is skipped, not
-    masked: its tick costs the grid step and nothing else."""
+# What the paged walk may hold and attend at once.  VMEM: the K and the V
+# copies, both halves of their double buffer (an int8 pool's scales ride
+# beside them, 1/16 of the bytes).  Columns: one online-softmax term; its
+# float32 score tile [g_pad, columns] should stay in the vector registers
+# (36 of 64 at Falcon's 72 query rows).  Measured on a v5e (PR 39), a call
+# at the two geometries of the module docstring: 2.73 ms / 40 us at 128
+# columns a term, 1.73 / 32 at 256, 1.34 / 28 at 512, 1.34 ms at 1024, 30
+# us at 2048; the same blocks attended one after another, a term each,
+# 2.6-2.8 ms / 41-43 us at every count (the chain of mask, maxima, exp and
+# scratch rewrites a term is latency: what PR 25 met as "several blocks a
+# tick bought nothing").
+_WALK_VMEM_BYTES = 2 * 2**20
+_WALK_COLUMNS = 512
+
+
+def _walk_shape(kv_heads, block_k, d, itemsize, t):
+    """(KV heads a copy, pool blocks an iteration) of the paged walk, from
+    what the trace sees: a pool block's bytes against the VMEM the walk
+    may hold, its rows against the columns of a term, the table's width."""
+    half = _WALK_VMEM_BYTES // 4
+    head_bytes = block_k * d * itemsize
+    kvg = max(g for g in range(1, kv_heads + 1)
+              if kv_heads % g == 0 and (g == 1 or g * head_bytes <= half))
+    n = min(half // (kvg * head_bytes), _WALK_COLUMNS // block_k, t)
+    return kvg, max(1, n)
+
+
+def _paged_walk_kernel(scale: float, n: int, block_k: int, int8: bool,
+                       kt: bool, has_new: bool,
+                       len_ref, tbl_ref, lyr_ref, q_ref, *refs):
+    """One grid step a slot (and group of ``kvg`` KV heads): the walk over
+    the row's live blocks is a loop in here, its trip count from the fill.
+    ``refs``: the pool leaves in HBM — (k, v), or (k, k_scale, v,
+    v_scale) for the int8 pool — the new token's (k_row, v_row) when
+    ``has_new``, the output, then the scratch: one double-buffered VMEM
+    copy ``[2, n, kvg, ...]`` a leaf, their DMA semaphores ``[2, leaves]``
+    and the three softmax scratches ``[kvg * g_pad, ...]``.
+
+    Iteration ``c`` waits for the row's logical blocks ``c*n .. c*n+n-1``
+    — those under the fill: a block past it is never copied, an empty row
+    starts no copy and runs no iteration — with iteration ``c+1``'s copies
+    already in flight, and attends them as ONE online-softmax term of
+    ``n * block_k`` columns a KV head.  A copy is a whole pool block,
+    every KV head of the group at once, as it lies in HBM."""
     n_cache = 4 if int8 else 2
-    cache, new_refs = refs[:n_cache], refs[n_cache:-4]
-    o_ref, m_scr, l_scr, acc_scr = refs[-4:]
-    hi, ki = pl.program_id(1), pl.program_id(2)
-    n = len_ref[pl.program_id(0)]
+    hbm, rest = refs[:n_cache], refs[n_cache:]
+    new_refs, rest = rest[:2 * has_new], rest[2 * has_new:]
+    o_ref, bufs = rest[0], rest[1:1 + n_cache]
+    sem, m_scr, l_scr, acc_scr = rest[1 + n_cache:]
+    bi, gi = pl.program_id(0), pl.program_id(1)
+    kvg, g_pad = q_ref.shape[1:3]
+    fill = len_ref[bi]
+    live = pl.cdiv(fill, block_k)
+    trips = pl.cdiv(live, n)
 
-    @pl.when(ki == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    @pl.when(ki * block_k < n)
-    def _live():
-        if int8:
-            k_ref, ks_ref, v_ref, vs_ref = cache
-            ks = ks_ref[0, 0, pl.ds(hi, 1), :]            # [1, block_k]
-            vs = vs_ref[0, 0, pl.ds(hi, 1), :]
-        else:
-            (k_ref, v_ref), ks, vs = cache, None, None
-        _attend_block(scale, ki * block_k, n, q_ref[0, 0], k_ref[0, 0, 0],
-                      v_ref[0, 0, 0], ks, vs, m_scr, l_scr, acc_scr, kt=kt)
+    def copies(c, half, go):
+        """``go`` (start or wait) the copies of iteration ``c``'s live
+        blocks into buffer half ``half``."""
+        def one(j, carry):
+            blk = tbl_ref[bi, c * n + j]
+            for i, (src, dst) in enumerate(zip(hbm, bufs)):
+                src = src.at[lyr_ref[0], blk]
+                if src.shape[0] != kvg:
+                    src = src.at[pl.ds(gi * kvg, kvg)]
+                go(pltpu.make_async_copy(src, dst.at[half, j],
+                                         sem.at[half, i]))
+            return carry
 
-    @pl.when(ki == nk - 1)
-    def _finalize():
+        jax.lax.fori_loop(0, jnp.minimum(n, live - c * n), one, 0)
+
+    @pl.when(trips > 0)
+    def _first():
+        copies(0, 0, lambda cp: cp.start())
+
+    def step(c, carry):
+        half = jax.lax.rem(c, 2)
+
+        @pl.when(c + 1 < trips)
+        def _next():
+            copies(c + 1, 1 - half, lambda cp: cp.start())
+
+        copies(c, half, lambda cp: cp.wait())
+        # a row's last iteration attends the buffers of its dead blocks
+        # too, masked: a probability of exactly 0 multiplies what they
+        # hold (V and its scales), which must be finite, and what they
+        # hold is VMEM noise or an earlier row's blocks
+        def zero(j, carry):
+            for buf in bufs[n_cache // 2:]:
+                buf[half, j] = jnp.zeros(buf.shape[2:], buf.dtype)
+            return carry
+
+        jax.lax.fori_loop(live - c * n, n, zero, 0)
+        for h in range(kvg):
+            # [K, V] blocks of KV head h, then the int8 pool's
+            # [K scale, V scale] rows, else None
+            blocks = [[buf[half, j, h] for j in range(n)]
+                      for buf in bufs[::n_cache // 2]]
+            scales = [[buf[half, j, pl.ds(h, 1), :] for j in range(n)]
+                      for buf in bufs[1::2]] if int8 else [None, None]
+            _attend_blocks(scale, c * n * block_k, fill, q_ref[0, h],
+                           *blocks, *scales, m_scr, l_scr, acc_scr,
+                           pl.ds(h * g_pad, g_pad), kt=kt)
+        return carry
+
+    jax.lax.fori_loop(0, trips, step, 0)
+
+    for h in range(kvg):
+        rows = pl.ds(h * g_pad, g_pad)
         if has_new:
             kn_ref, vn_ref = new_refs
-            _attend_new_row(scale, q_ref[0, 0], kn_ref[0, 0], vn_ref[0, 0],
-                            m_scr, l_scr, acc_scr)
-        l = l_scr[:, :1]
-        o_ref[0, 0] = (acc_scr[:] / jnp.where(l == 0.0, 1.0, l)
+            _attend_new_row(scale, q_ref[0, h], kn_ref[0, h], vn_ref[0, h],
+                            m_scr, l_scr, acc_scr, rows)
+        l = l_scr[rows, :1]
+        o_ref[0, h] = (acc_scr[rows, :] / jnp.where(l == 0.0, 1.0, l)
                        ).astype(o_ref.dtype)
-
-
-def _paged_index(block_k: int, per_head: bool):
-    """Index map over a ``[L, n_blocks, kv, block_k(, d)]`` pool leaf:
-    tick ``ki`` fetches row ``bi``'s logical block ``ki`` via its table,
-    clamped at the row's own last live block — blocks past the fill (and
-    the whole walk of an empty row, which lands on the trash block) keep
-    the block index of the tick before, so the pipeline copies nothing
-    for them."""
-    def idx(bi, hi, ki, lens, tbl, lyr):
-        last = jnp.maximum(lens[bi] - 1, 0) // block_k
-        blk = tbl[bi, jnp.minimum(ki, last)]
-        return (lyr[0], blk, hi, 0, 0) if per_head else (lyr[0], blk, 0, 0)
-    return idx
 
 
 def _paged_decode_call(q, leaves, tables, cache_len, *, layer=None,
@@ -338,13 +422,13 @@ def _paged_decode_call(q, leaves, tables, cache_len, *, layer=None,
     pool's (k_q, k_scale, v_q, v_scale): one layer's pool view
     ``[n_blocks, kv, block_k(, d)]``, or with ``layer`` (an int32 scalar,
     traced in a layer scan) the whole pool ``[L, n_blocks, ...]`` of
-    which the index maps address layer ``layer`` — a scan body that
-    slices its layer out first makes XLA copy that slice for the custom
-    call.  The grid's k axis walks the ``T`` block-table columns; fills,
-    tables and the layer prefetch to SMEM so the index maps can resolve
-    physical blocks.  (Several blocks a tick bought nothing on the chip:
-    2.34 ms over 32 layers at one block a tick, 2.39-2.45 at 2-16, 16
-    slots of 50-700 rows; PERF.md, PR 25.)
+    which the kernel addresses layer ``layer`` — a scan body that slices
+    its layer out first makes XLA copy that slice for the custom call.
+    The leaves stay in HBM; fills, tables and the layer prefetch to SMEM,
+    where the kernel's walk reads the physical block of each copy.  The
+    grid is one step a slot and group of KV heads; how many heads a copy
+    takes and how many blocks an iteration follow from the shapes
+    (``_walk_shape``), the same walk for every pool form.
 
     At a head width under 128 the blocks are handed over transposed,
     ``[d, block_k]``: XLA:TPU keeps a ``[..., 128·n, 64]`` array with
@@ -368,12 +452,13 @@ def _paged_decode_call(q, leaves, tables, cache_len, *, layer=None,
         interpret = kernels.default_interpret()
     if not interpret:
         assert block_k % 128 == 0 and d % 64 == 0, (block_k, d)
-    nk = tables.shape[1]
 
     g_pad = max(8, -(-group // 8) * 8)
     qg = q.reshape(b, kv_heads, group, d)
     if g_pad != group:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, g_pad - group), (0, 0)))
+    kvg, n = _walk_shape(kv_heads, block_k, d, leaves[0].dtype.itemsize,
+                         tables.shape[1])
 
     lens = jnp.broadcast_to(
         jnp.reshape(jnp.asarray(cache_len, jnp.int32), (-1,)), (b,))
@@ -381,36 +466,30 @@ def _paged_decode_call(q, leaves, tables, cache_len, *, layer=None,
     lyr = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
 
     row_spec = lambda rows: pl.BlockSpec(  # noqa: E731
-        (1, 1, rows, d), lambda bi, hi, ki, *s: (bi, hi, 0, 0))
-    data = pl.BlockSpec(
-        (1, 1, 1, d, block_k) if kt else (1, 1, 1, block_k, d),
-        _paged_index(block_k, True))
-    # a scale block holds every kv head's row scales [kv, block_k] (its
-    # last two dims are the leaf's own: a legal tile as it lies,
-    # lane-major like the score columns it multiplies); the kernel picks
-    # its head's row
-    sc = pl.BlockSpec((1, 1, kv_heads, block_k), _paged_index(block_k, False))
+        (1, kvg, rows, d), lambda bi, gi, *s: (bi, gi, 0, 0))
     new_rows = list(new_rows or ())
     out = pl.pallas_call(
-        functools.partial(_paged_kernel, float(softmax_scale), nk, block_k,
-                          int8, kt, bool(new_rows)),
+        functools.partial(_paged_walk_kernel, float(softmax_scale), n,
+                          block_k, int8, kt, bool(new_rows)),
         name="flash_decode",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(b, kv_heads, nk),
+            grid=(b, kv_heads // kvg),
             in_specs=([row_spec(g_pad)]
-                      + ([data, sc, data, sc] if int8 else [data, data])
+                      + [pl.BlockSpec(memory_space=pl.ANY)] * len(leaves)
                       + [row_spec(1)] * len(new_rows)),
             out_specs=row_spec(g_pad),
-            scratch_shapes=[
-                pltpu.VMEM((g_pad, 128), jnp.float32),
-                pltpu.VMEM((g_pad, 128), jnp.float32),
-                pltpu.VMEM((g_pad, d), jnp.float32),
-            ],
+            scratch_shapes=(
+                [pltpu.VMEM((2, n, kvg) + a.shape[3:], a.dtype)
+                 for a in leaves]
+                + [pltpu.SemaphoreType.DMA((2, len(leaves))),
+                   pltpu.VMEM((kvg * g_pad, 128), jnp.float32),
+                   pltpu.VMEM((kvg * g_pad, 128), jnp.float32),
+                   pltpu.VMEM((kvg * g_pad, d), jnp.float32)]),
         ),
         out_shape=jax.ShapeDtypeStruct((b, kv_heads, g_pad, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,
     )(lens, tbl, lyr, qg, *leaves, *new_rows)

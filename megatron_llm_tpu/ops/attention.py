@@ -419,13 +419,14 @@ def paged_decode_attention(
     """Decode attention over a paged KV pool via per-slot block tables.
 
     Dispatches the paged Pallas kernels (kernels/flash_decode.py:
-    flash_decode_paged*), which resolve blocks inside the BlockSpec index
-    maps — no dense cache is materialized and HBM traffic is the sum of
-    per-row fills.  The new token's row is not in the pool yet: the
-    kernel folds it in as one more softmax term, and the caller appends
-    every layer's rows in one write after its layer loop
-    (models/model.py:forward_cached_paged).  Entries past a row's fill
-    point at the pool's trash block; the walk skips them.
+    flash_decode_paged*), which walk each row's live blocks themselves,
+    copying them out of the pool by table entry — no dense cache is
+    materialized and HBM traffic is the sum of per-row fills.  The new
+    token's row is not in the pool yet: the kernel folds it in as one
+    more softmax term, and the caller appends every layer's rows in one
+    write after its layer loop (models/model.py:forward_cached_paged).
+    Entries past a row's fill point at the pool's trash block; the walk
+    never reaches them.
 
     The caller asks ``paged_decode_route`` first: this is the kernel
     route only (interpret mode off the TPU); the gather route lives in

@@ -60,10 +60,12 @@ def test_per_sample_fill_levels_match_einsum():
 
 
 # ---------------------------------------------------------------------------
-# Paged gather mode (block-table pool): bitwise vs the dense kernel
+# Paged gather mode (block-table pool): vs the dense kernel, and bitwise
+# whatever the physical layout
 # ---------------------------------------------------------------------------
 
 from megatron_llm_tpu.kernels.flash_decode import (  # noqa: E402
+    _walk_shape,
     flash_decode_int8,
     flash_decode_paged,
     flash_decode_paged_int8,
@@ -80,8 +82,8 @@ def _paged_layout(dense_leaves, bk, tables, garbage):
     """Scatter dense [b, kv, max_len, *] leaves into pool blocks at the
     physical ids named by ``tables``, trash block 0 filled with large
     finite garbage — the invariant under test is that table indirection
-    plus fill masking reproduces the dense kernel bitwise no matter the
-    physical layout."""
+    plus fill masking reproduces the dense cache's attention, bitwise the
+    same no matter the physical layout."""
     b, kv = dense_leaves[0].shape[:2]
     T = tables.shape[1]
     pools = []
@@ -95,10 +97,12 @@ def _paged_layout(dense_leaves, bk, tables, garbage):
     return pools
 
 
-def test_paged_bitwise_equals_dense_fp32():
+def test_paged_equals_dense_fp32_bitwise_across_layouts():
     """flash_decode_paged over a shuffled pool == flash_decode over the
-    dense cache, BITWISE, at the same block partition (online softmax is
-    not partition-invariant, so block_k must match the pool block)."""
+    dense cache to float32 rounding (the walk attends several pool blocks
+    as one online-softmax term, the dense kernel one ``block_k`` a term,
+    and online softmax is not partition-invariant), and BITWISE the same
+    over another shuffle of the same logical rows."""
     b, heads, kv_heads, max_len, d, bk = 3, 8, 2, 512, 128, 128
     rng = np.random.default_rng(3)
     q = jnp.asarray(rng.normal(size=(b, heads, d)), jnp.float32)
@@ -108,16 +112,20 @@ def test_paged_bitwise_equals_dense_fp32():
 
     want = flash_decode(q, jnp.asarray(k), jnp.asarray(v), lens,
                         block_k=bk, interpret=True)
-    tables = _shuffled_tables(b, max_len // bk, rng)
-    k_pool, v_pool = _paged_layout([k, v], bk, tables, 1e4)
-    got = flash_decode_paged(q, k_pool, v_pool, jnp.asarray(tables), lens,
-                             interpret=True)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    got = []
+    for _ in range(2):
+        tables = _shuffled_tables(b, max_len // bk, rng)
+        k_pool, v_pool = _paged_layout([k, v], bk, tables, 1e4)
+        got.append(np.asarray(flash_decode_paged(
+            q, k_pool, v_pool, jnp.asarray(tables), lens, interpret=True)))
+    np.testing.assert_allclose(got[0], np.asarray(want), rtol=1e-6,
+                               atol=1e-6)
+    np.testing.assert_array_equal(got[0], got[1])
 
 
-def test_paged_bitwise_equals_dense_int8():
+def test_paged_equals_dense_int8_bitwise_across_layouts():
     """Same bar for the int8 {q, scale} pool form: quantized codes and
-    per-row scales gathered through the table, bitwise-equal output."""
+    per-row scales gathered through the table."""
     b, heads, kv_heads, max_len, d, bk = 3, 4, 2, 512, 128, 128
     rng = np.random.default_rng(4)
     q = jnp.asarray(rng.normal(size=(b, heads, d)), jnp.float32)
@@ -132,13 +140,17 @@ def test_paged_bitwise_equals_dense_int8():
     want = flash_decode_int8(q, *(jnp.asarray(a) for a in
                                   (k_q, k_s, v_q, v_s)),
                              lens, block_k=bk, interpret=True)
-    tables = _shuffled_tables(b, max_len // bk, rng)
-    kq_p, vq_p = _paged_layout([k_q, v_q], bk, tables, 127)
-    ks_p, vs_p = _paged_layout([k_s, v_s], bk, tables, 1e4)
-    got = flash_decode_paged_int8(q, kq_p, ks_p, vq_p, vs_p,
-                                  jnp.asarray(tables), lens,
-                                  interpret=True)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    got = []
+    for _ in range(2):
+        tables = _shuffled_tables(b, max_len // bk, rng)
+        kq_p, vq_p = _paged_layout([k_q, v_q], bk, tables, 127)
+        ks_p, vs_p = _paged_layout([k_s, v_s], bk, tables, 1e4)
+        got.append(np.asarray(flash_decode_paged_int8(
+            q, kq_p, ks_p, vq_p, vs_p, jnp.asarray(tables), lens,
+            interpret=True)))
+    np.testing.assert_allclose(got[0], np.asarray(want), rtol=1e-5,
+                               atol=1e-5)
+    np.testing.assert_array_equal(got[0], got[1])
 
 
 # ---------------------------------------------------------------------------
@@ -154,20 +166,31 @@ from megatron_llm_tpu.ops.kv_quant import (  # noqa: E402
 
 # heads, kv heads, head width, slots
 _FALCON = (71, 1, 64, 16)   # MQA: one query group of 71 rows, g_pad 72
-_GQA128 = (32, 8, 128, 6)   # the edge fills alone: interpret mode is slow
+_GQA128 = (32, 8, 128, 9)   # the edge fills alone: interpret mode is slow
+_GQA256 = (16, 2, 256, 9)   # the long-document cell's full-attention layer
 
 
-def _ragged_fills(b, t, bk, rng):
-    """Empty, one row, one short of a block, a block boundary, one past
-    it, a full table (the new row is the table's last), random rest."""
-    edge = [0, 1, bk - 1, bk, bk + 1, t * bk - 1]
+def _walk_blocks(geometry, t, bk, dtype):
+    """Pool blocks one iteration of the kernel's walk attends."""
+    _, kv, d, _ = geometry
+    return _walk_shape(kv, bk, d, jnp.dtype(dtype).itemsize, t)[1]
+
+
+def _ragged_fills(b, t, bk, n, rng):
+    """Every edge of the walk: an empty row, one row, exactly one
+    iteration's ``n`` blocks, one row past them, every table column full
+    (the new row lies behind the table), one short of a block, a block
+    boundary, one past it, a table whose last row is the new one; random
+    rest, so that one call holds rows of very different fills."""
+    edge = [0, 1, n * bk, n * bk + 1, t * bk, bk - 1, bk, bk + 1, t * bk - 1]
     rest = rng.integers(0, t * bk, max(0, b - len(edge))).tolist()
     return np.asarray((edge + rest)[:b], np.int32)
 
 
 @pytest.mark.parametrize("pool", ["fp32", "bf16", "int8"])
-@pytest.mark.parametrize("geometry", [_FALCON, _GQA128],
-                         ids=["falcon71x64mqa", "gqa32x128kv8"])
+@pytest.mark.parametrize("geometry", [_FALCON, _GQA128, _GQA256],
+                         ids=["falcon71x64mqa", "gqa32x128kv8",
+                              "gqa16x256kv2"])
 def test_paged_new_row_matches_gathered_einsum(geometry, pool):
     """Slots × a 16-block table of 128-row blocks, shuffled physical
     ids, trash and unowned blocks holding large finite values: the paged
@@ -180,7 +203,9 @@ def test_paged_new_row_matches_gathered_einsum(geometry, pool):
     dt = jnp.float32 if pool == "fp32" else jnp.bfloat16
     tol = 2e-5 if pool == "fp32" else 0.03
     q = jnp.asarray(rng.normal(size=(b, heads, d)), dt)
-    fills = _ragged_fills(b, t, bk, rng)
+    fills = _ragged_fills(
+        b, t, bk, _walk_blocks(geometry, t, bk, jnp.int8 if pool == "int8"
+                               else dt), rng)
     # a table names only the blocks its slot has reached; the rest of
     # the row is the trash block, as the engine's allocator leaves it
     tables = _shuffled_tables(b, t, rng)
@@ -235,12 +260,51 @@ def test_paged_new_row_matches_gathered_einsum(geometry, pool):
                         (kv, heads // kv, d)), rtol=tol, atol=tol)
 
 
-def test_paged_whole_pool_layer_index_equals_layer_view():
-    """``layer=`` addresses one layer of the whole [L, ...] pool through
-    the index maps: bitwise what the kernel returns for that layer's
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+def test_paged_slot_never_sees_what_an_earlier_slot_copied(pool):
+    """The walk's VMEM buffers outlive a grid step: a slot whose blocks
+    hold NaN (K, V and the int8 pool's scales) fills them, and the next
+    slot's one live block leaves the rest of its iteration dead, masked
+    — and untouched by what lies there: its output is bitwise what it
+    is when the first slot's blocks are clean."""
+    heads, kv, d, _ = _GQA128
+    t, bk = 4, 128
+    assert _walk_blocks(_GQA128, t, bk,
+                        jnp.int8 if pool == "int8" else jnp.bfloat16) > 1
+    rng = np.random.default_rng(11)
+    q = jnp.asarray(rng.normal(size=(2, heads, d)), jnp.bfloat16)
+    fills = jnp.asarray([t * bk, 1], jnp.int32)
+    tables = jnp.asarray(1 + np.arange(2 * t).reshape(2, t), jnp.int32)
+    shape = (1 + 2 * t, kv, bk, d)
+    if pool == "int8":
+        clean = [jnp.asarray(rng.integers(-127, 128, shape), jnp.int8),
+                 jnp.asarray(rng.uniform(0.01, 0.1, shape[:3]), jnp.float32)]
+        clean = clean + [clean[0][::-1], clean[1][::-1]]
+        call = flash_decode_paged_int8
+    else:
+        clean = [jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
+                 for _ in range(2)]
+        call = flash_decode_paged
+    dirty = [a if a.dtype == jnp.int8 else a.at[1:1 + t].set(jnp.nan)
+             for a in clean]
+    want = call(q, *clean, tables, fills, interpret=True)
+    got = call(q, *dirty, tables, fills, interpret=True)
+    assert np.isfinite(np.asarray(got[1], np.float32)).all()
+    np.testing.assert_array_equal(np.asarray(got[1], np.float32),
+                                  np.asarray(want[1], np.float32))
+
+
+@pytest.mark.parametrize("geometry", [_FALCON, _GQA256],
+                         ids=["falcon71x64mqa", "gqa16x256kv2"])
+def test_paged_whole_pool_layer_index_equals_layer_view(geometry):
+    """``layer=`` addresses one layer of the whole [L, ...] pool inside
+    the kernel's copies: bitwise what the kernel returns for that layer's
     view handed over alone."""
-    heads, kv, d, _ = _FALCON
-    b, t, bk, layers = 4, 4, 128, 3
+    heads, kv, d, _ = geometry
+    t, bk, layers = 6, 128, 3
+    n = _walk_blocks(geometry, t, bk, jnp.float32)
+    fills = jnp.asarray([0, 1, 300, n * bk, n * bk + 1, t * bk], jnp.int32)
+    b = len(fills)
     rng = np.random.default_rng(9)
     q = jnp.asarray(rng.normal(size=(b, heads, d)), jnp.float32)
     pools = [jnp.asarray(rng.normal(size=(layers, 1 + b * t, kv, bk, d)),
@@ -248,7 +312,6 @@ def test_paged_whole_pool_layer_index_equals_layer_view():
     rows = [jnp.asarray(rng.normal(size=(b, kv, 1, d)), jnp.float32)
             for _ in range(2)]
     tables = jnp.asarray(_shuffled_tables(b, t, rng))
-    fills = jnp.asarray([0, 129, 300, 511], jnp.int32)
     for layer in (0, 2):
         want = flash_decode_paged(q, pools[0][layer], pools[1][layer],
                                   tables, fills, new_rows=rows,

@@ -142,30 +142,34 @@ def _no_copy_of(text, shape):
     assert not bad, bad
 
 
-# (heads, KV heads, head width) the composed paged route serves: Falcon-7B
-# (71 query heads over one KV head of width 64), Falcon-40B's GQA at that
-# width, and Llama-2-7B, whose layers the fused kernel's VMEM budget declines
-_POOL_GEOMETRY = {"mqa64": (71, 1, 64), "gqa64": (128, 8, 64),
-                  "mha128": (32, 32, 128)}
+# (heads, KV heads, head width, slots, table columns) the composed paged
+# route serves: Falcon-7B (71 query heads over one KV head of width 64),
+# Falcon-40B's GQA at that width, Llama-2-7B, whose layers the fused
+# kernel's VMEM budget declines, and Qwen3-Next's full-attention layer under
+# the long-document cell's 44 slots of up to 16 640 rows
+_POOL_GEOMETRY = {"mqa64": (71, 1, 64, 16, 16), "gqa64": (128, 8, 64, 16, 16),
+                  "mha128": (32, 32, 128, 16, 16),
+                  "gqa256": (16, 2, 256, 44, 130)}
 
 
 @pytest.mark.parametrize("variant", ["dense", "dense_int8", "paged",
                                      "paged_int8", "paged_pool_mqa64",
                                      "paged_pool_int8_mqa64",
                                      "paged_pool_gqa64",
-                                     "paged_pool_mha128"])
+                                     "paged_pool_mha128",
+                                     "paged_pool_gqa256"])
 def test_flash_decode(topo, variant):
     b, h, kv, d, max_len, bk = 8, 32, 32, 128, 2048, 128
     nb, t = 64, max_len // bk
     one = SingleDeviceSharding(topo.devices[0])
     if variant.startswith("paged_pool"):
-        # the composed decode route's call, 16 slots × 16 blocks: the
+        # the composed decode route's call, slots × table columns: the
         # whole [L, ...] pool, a traced layer index, the new token's rows
         # beside it.  At head width 64 the pool lies with its 128-row
         # dimension as lanes, and the kernel must take it as it lies: no
         # copy of the pool
-        h, kv, d = _POOL_GEOMETRY[variant.rsplit("_", 1)[1]]
-        b, layers, nb = 16, 2, 513
+        h, kv, d, b, t = _POOL_GEOMETRY[variant.rsplit("_", 1)[1]]
+        layers, nb = 2, 513
         int8 = "int8" in variant
         pool = [_sds((layers, nb, kv, bk, d), jnp.int8 if int8 else BF16)]
         if int8:

@@ -167,8 +167,14 @@ def test_trace_endpoint_schema_and_request_lifecycle(model):
     events = trace["traceEvents"]
     assert events, "multi-request run produced no trace events"
     for ev in events:
-        assert {"name", "ph", "ts", "pid", "tid"} <= set(ev)
-        assert ev["ph"] in ("X", "i")
+        assert {"name", "ph", "pid", "tid"} <= set(ev)
+        assert ev["ph"] in ("X", "i", "M")
+        # a metadata event (the compile row's ``thread_name``) names a
+        # row and has no time, as the Chrome trace format has it
+        if ev["ph"] == "M":
+            assert ev["name"] == "thread_name" and ev["args"]["name"]
+            continue
+        assert "ts" in ev
         if ev["ph"] == "X":
             assert ev["dur"] >= 0
 
