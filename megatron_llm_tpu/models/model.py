@@ -14,6 +14,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..config import ModelConfig, PositionEmbeddingType
 from .transformer import (
@@ -638,9 +639,12 @@ def init_rec_state(cfg: ModelConfig, batch_size: int) -> dict:
     linear layer's recurrent state ``S`` [linear layers, b, value heads,
     key width, value width] float32 and convolution tail ``conv``
     [linear layers, b, taps - 1, channels], zero at a sequence's start;
-    and ``load`` [layers, router outputs] int32, how often each expert
-    was chosen by the positions these states were advanced over (a
-    counter carried on the device and read when somebody asks)."""
+    and two counters carried on the device and read when somebody asks:
+    ``load`` [layers, router outputs] int32, how often each expert was
+    chosen by the positions these states were advanced over, and
+    ``rows`` [layers, 2, 2] int32, the (token, choice) rows the layer's
+    experts multiplied and skipped (``models/moe.py``), each count as
+    two words (``add_rows``: a long prompt adds 10^5 to it)."""
     from .gated_deltanet import init_state
 
     one = init_state(cfg, batch_size)
@@ -648,11 +652,33 @@ def init_rec_state(cfg: ModelConfig, batch_size: int) -> dict:
     return {"S": jnp.zeros((n,) + one.S.shape, one.S.dtype),
             "conv": jnp.zeros((n,) + one.conv.shape, one.conv.dtype),
             "load": jnp.zeros((cfg.num_layers, cfg.router_experts),
-                              jnp.int32)}
+                              jnp.int32),
+            "rows": jnp.zeros((cfg.num_layers, 2, 2), jnp.int32)}
 
 
-def _counted(rec: dict, new: dict, load) -> dict:
-    return {**new, "load": rec["load"] + load.astype(jnp.int32)}
+_ROWS_WORD = 30
+
+
+def add_rows(rows, more):
+    """``rows`` [..., 2] int32, counts as ``(high, low)`` words of
+    ``_ROWS_WORD`` bits, plus ``more`` (the same form) → their sum: a
+    count that outgrows a word carries over, it does not wrap."""
+    low = rows[..., 1] + more[..., 1]
+    return jnp.stack([rows[..., 0] + more[..., 0] + (low >> _ROWS_WORD),
+                      low & ((1 << _ROWS_WORD) - 1)], axis=-1)
+
+
+def rows_total(rows):
+    """``add_rows``'s words, fetched → the counts, int64."""
+    rows = np.asarray(rows).astype(np.int64)
+    return (rows[..., 0] << _ROWS_WORD) + rows[..., 1]
+
+
+def _counted(rec: dict, new: dict, counts: dict) -> dict:
+    one = counts["rows"].astype(jnp.int32)
+    return {**new, "load": rec["load"] + counts["load"].astype(jnp.int32),
+            "rows": add_rows(rec["rows"],
+                             jnp.stack([jnp.zeros_like(one), one], axis=-1))}
 
 
 def forward_cached_hybrid(cfg: ModelConfig, params: Params, tokens,
@@ -675,7 +701,7 @@ def forward_cached_hybrid(cfg: ModelConfig, params: Params, tokens,
     x = embed(cfg, params, tokens, position_ids)
     side = AttnSideInputs(position_ids=position_ids, deterministic=True,
                           cache_is_empty=empty_cache, valid=valid)
-    x, (rows_k, rows_v), new, load = scan_periods_cached(
+    x, (rows_k, rows_v), new, counts = scan_periods_cached(
         cfg, params["layers"], x, side,
         lambda _idx, k_l, v_l: (k_l, v_l, cache_len), rec,
         kv_xs=(k_cache, v_cache))
@@ -686,7 +712,8 @@ def forward_cached_hybrid(cfg: ModelConfig, params: Params, tokens,
     if logit_rows is not None:
         x = jnp.take_along_axis(
             x, logit_rows.astype(jnp.int32)[:, None, None], axis=1)
-    return unembed(cfg, params, x), k_cache, v_cache, _counted(rec, new, load)
+    return (unembed(cfg, params, x), k_cache, v_cache,
+            _counted(rec, new, counts))
 
 
 def forward_paged_hybrid(cfg: ModelConfig, params: Params, tokens, k_pool,
@@ -709,12 +736,12 @@ def forward_paged_hybrid(cfg: ModelConfig, params: Params, tokens, k_pool,
         x = embed(cfg, params, tokens, fills[:, None])
         side = AttnSideInputs(position_ids=fills[:, None],
                               deterministic=True, valid=valid)
-        x, (rows_k, rows_v), new, load = scan_periods_cached(
+        x, (rows_k, rows_v), new, counts = scan_periods_cached(
             cfg, params["layers"], x, side,
             lambda idx: PagedKV(k_pool, v_pool, tables, fills, idx), rec)
         x = norm_apply(cfg.norm_type, x, params["final_norm"],
                        cfg.norm_eps, impl=cfg.norm_impl).astype(cfg.dtype)
-        logits, rec = unembed(cfg, params, x), _counted(rec, new, load)
+        logits, rec = unembed(cfg, params, x), _counted(rec, new, counts)
     else:
         k_dense = cache_gather_blocks(k_pool, tables)
         v_dense = cache_gather_blocks(v_pool, tables)

@@ -29,12 +29,15 @@ not from shrinking these tensors.
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
 
+from .. import kernels
 from ..config import ModelConfig
+from ..kernels.grouped_matmul import grouped_mlp
 from ..ops.activations import get_activation, is_glu
 from ..ops.precision import dot_f32
 
@@ -89,9 +92,12 @@ def group_size(cfg: ModelConfig, seq_len: int) -> int:
 
 def stats_zero(cfg: ModelConfig) -> dict:
     """Zero MoE stats tree (the per-layer scan accumulator shape)."""
-    return {"aux": jnp.zeros((), jnp.float32),
+    zero = {"aux": jnp.zeros((), jnp.float32),
             "dropped": jnp.zeros((), jnp.float32),
             "load": jnp.zeros((cfg.router_experts,), jnp.float32)}
+    if cfg.moe_dropless:
+        zero["rows"] = jnp.zeros((2,), jnp.float32)
+    return zero
 
 
 def aux_loss_of(aux) -> jax.Array:
@@ -170,37 +176,45 @@ def moe_block(cfg: ModelConfig, p: Params, x: jax.Array):
 # ---------------------------------------------------------------------------
 
 
-def _held_experts(cfg: ModelConfig, p: Params, x, local, weight):
+def _held_experts(cfg: ModelConfig, interpret: bool, p: Params, x, local,
+                  weight):
     """``x`` [g, h] through the held experts each token chose: ``local``
     [g, k] is a choice's index among the held experts, or ``num_experts``
     for one that is not here; ``weight`` [g, k] its gate (0 where not
-    here) → [g, h] float32.  The (token, choice) pairs are sorted by
-    expert and each expert multiplies its own rows (``lax.ragged_dot``):
-    no one-hot over the experts, no capacity.  The rows of the choices
-    that are not here sort last, past the last group, and add nothing."""
+    here) → ``([g, h] float32, rows [2] int32)``: the sum over a token's
+    held choices, and how many (token, choice) rows the experts
+    multiplied and how many they skipped.
+
+    The pairs are sorted by expert once (a pair's key is its expert, its
+    token and its choice, so the keys are distinct), the groups' bounds are
+    read off the sorted keys, and ``kernels/grouped_matmul.py`` does the
+    rest: it fetches each held pair's row of ``x`` by index, multiplies a
+    group's rows by that expert's matrices and writes each result to its
+    pair's place.  The pairs of experts that are not here sort last, past
+    the last group: a sort key each and nothing else."""
     g, k = local.shape
-    E = cfg.num_experts
+    n, E = g * k, cfg.num_experts
+    cbits = (k - 1).bit_length()
+    bits = max(1, (g - 1).bit_length()) + cbits
+    assert (E + 1) << bits <= 2 ** 31, (E, g, k)
     act = get_activation(cfg.activation)
     with jax.named_scope("moe_dispatch"):
-        flat = local.reshape(-1)
-        order = jnp.argsort(flat, stable=True)
-        sizes = jnp.zeros((E + 1,), jnp.int32).at[flat].add(1)[:E]
-        rows = x[order // k]                              # [g * k, h]
+        pairs = ((jnp.arange(g, dtype=jnp.int32) << cbits)[:, None]
+                 | jnp.arange(k, dtype=jnp.int32))
+        keys = jnp.sort(((local << bits) | pairs).reshape(-1))
+        bounds = jnp.searchsorted(
+            keys, jnp.arange(E + 1, dtype=jnp.int32) << bits).astype(jnp.int32)
     with jax.named_scope("moe_experts"):
-        gate = jax.lax.ragged_dot(rows, p["w_gate"], sizes)
-        up = jax.lax.ragged_dot(rows, p["w_up"], sizes)
-        hidden = act(jnp.concatenate([gate, up], axis=-1))
-        out = jax.lax.ragged_dot(hidden, p["w_down"], sizes)
+        out = grouped_mlp(x, keys & ((1 << bits) - 1),
+                          bounds[1:] - bounds[:-1], p.get("w_gate"),
+                          p["w_up"], p["w_down"], act, choices=k,
+                          interpret=interpret)
     with jax.named_scope("moe_dispatch"):
-        # back in (token, choice) order as the products left them, half
-        # the bytes of the float32 sum; what ragged_dot leaves in the
-        # rows past its last group is not defined: those are replaced,
-        # not multiplied by their zero gate
-        back = jnp.zeros_like(order).at[order].set(
-            jnp.arange(g * k, dtype=order.dtype))
-        out = out[back].reshape(g, k, -1)
-        out = jnp.where((local < E)[..., None], out, 0).astype(jnp.float32)
-        return (out * weight[..., None]).sum(axis=1)
+        # the rows of the pairs that are not here were never written:
+        # replaced, not multiplied by their zero gate
+        out = jnp.where((local < E)[..., None, None], out, 0)
+        out = (out * weight[..., None, None]).sum(axis=1).reshape(g, -1)
+        return out, jnp.stack([bounds[E], n - bounds[E]])
 
 
 def moe_dropless_block(cfg: ModelConfig, p: Params, x: jax.Array,
@@ -214,9 +228,25 @@ def moe_dropless_block(cfg: ModelConfig, p: Params, x: jax.Array,
 
     ``stats["load"]`` [router_experts] counts the choices of the
     positions ``valid`` [b, s] marks (None: all), held or not: the
-    engine's per-layer, per-expert counter.  Tokens are routed in chunks
-    of at most ``cfg.moe_group_size`` so that a 16k-position prefill
-    never holds ten rows a token at once."""
+    engine's per-layer, per-expert counter.  ``stats["rows"]`` [2] counts
+    the (token, choice) rows of every position by what the experts did
+    with them: multiplied (a held expert's) or skipped.  Tokens are
+    routed in chunks of at most ``cfg.moe_group_size``: the sorted pairs
+    of a chunk have to fit the kernel's scalar memory.
+
+    Jitted by itself, so that the layers of a stack, which call it with
+    the same shapes, trace it and the kernel in it once a program (a
+    trace of the kernel's body is 0.25 s of a start on the sealed
+    machine: PERF.md, PR 42); ``kernels.default_interpret()`` is read
+    here, outside, so that what is cached follows it."""
+    if valid is None:
+        valid = jnp.ones(x.shape[:2], bool)
+    return _dropless(cfg, kernels.default_interpret(), p, x, valid)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _dropless(cfg: ModelConfig, interpret: bool, p: Params, x, valid):
+
     b, s, h = x.shape
     k, E, R = cfg.moe_top_k, cfg.num_experts, cfg.router_experts
     xt = x.reshape(b * s, h)
@@ -226,8 +256,7 @@ def moe_dropless_block(cfg: ModelConfig, p: Params, x: jax.Array,
                          precision=jax.lax.Precision.HIGHEST)
         weight, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
         weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
-        counted = (jnp.ones((b * s,), jnp.float32) if valid is None
-                   else valid.reshape(-1).astype(jnp.float32))
+        counted = valid.reshape(-1).astype(jnp.float32)
         load = jnp.zeros((R,), jnp.float32).at[idx.reshape(-1)].add(
             jnp.repeat(counted, k))
         local = idx - cfg.moe_expert_offset
@@ -235,18 +264,19 @@ def moe_dropless_block(cfg: ModelConfig, p: Params, x: jax.Array,
         local = jnp.where(here, local, E)
         weight = jnp.where(here, weight, 0.0)
     # the router and the shared expert read ``x`` as it comes (a float32
-    # residual stream is not rounded first); the routed experts' products
-    # take their operands in the weights' precision
-    xf, xt = xt, xt.astype(p["w_up"].dtype)
+    # residual stream is not rounded first); the routed experts' kernel
+    # rounds the rows it fetches to the weights' precision
     g = group_size(cfg, b * s)
     if g == b * s:
-        out = _held_experts(cfg, p, xt, local, weight)
+        out, rows = _held_experts(cfg, interpret, p, xt, local, weight)
     else:
         n = b * s // g
-        out = jax.lax.map(
-            lambda c: _held_experts(cfg, p, *c),
+        out, rows = jax.lax.map(
+            lambda c: _held_experts(cfg, interpret, p, *c),
             (xt.reshape(n, g, h), local.reshape(n, g, k),
-             weight.reshape(n, g, k))).reshape(b * s, h)
+             weight.reshape(n, g, k)))
+        out, rows = out.reshape(b * s, h), rows.sum(axis=0)
+    # tpulint: allow[tracer-leak] a key of the tree, no traced value
     if "shared" in p:
         with jax.named_scope("moe_shared"):
             sp = p["shared"]
@@ -255,10 +285,11 @@ def moe_dropless_block(cfg: ModelConfig, p: Params, x: jax.Array,
             # a routed expert's ~1/10), so its rounding is what the next
             # layer's router sees: the stream in two passes (dot_f32)
             hidden = act(jnp.concatenate(
-                [dot_f32(xf, sp["w_gate"]), dot_f32(xf, sp["w_up"])],
+                [dot_f32(xt, sp["w_gate"]), dot_f32(xt, sp["w_up"])],
                 axis=-1))
-            gate = jax.nn.sigmoid(dot_f32(xf, sp["gate"]))
+            gate = jax.nn.sigmoid(dot_f32(xt, sp["gate"]))
             out = out + gate * dot_f32(hidden, sp["w_down"])
     return out.astype(x.dtype).reshape(b, s, h), {
         "aux": jnp.zeros((), jnp.float32),
-        "dropped": jnp.zeros((), jnp.float32), "load": load}
+        "dropped": jnp.zeros((), jnp.float32), "load": load,
+        "rows": rows.astype(jnp.float32)}

@@ -682,8 +682,10 @@ def scan_periods_cached(cfg: ModelConfig, stacked, x, side, kv_of, rec,
     [linear layers, b, ...], "conv": [...]}``).
 
     → ``(hidden, (rows_k, rows_v) stacked over the full layers, rec with
-    every state advanced over the positions ``side.valid`` marks, load
-    [layers, router outputs]: the experts those positions chose)``."""
+    every state advanced over the positions ``side.valid`` marks, counts
+    ``{"load": [layers, router outputs], "rows": [layers, 2]}``: the
+    experts those positions chose, and the (token, choice) rows each
+    layer's experts multiplied and skipped)``."""
     kinds = cfg.layer_pattern
     n_per = cfg.num_layers // len(kinds)
     n_full, n_lin = kinds.count("full"), kinds.count("linear")
@@ -699,7 +701,7 @@ def scan_periods_cached(cfg: ModelConfig, stacked, x, side, kv_of, rec,
     def body(carry, inp):
         h, idx = carry
         period, kv_p, rec_p = inp
-        rows, states, loads, f, l = [], [], [], 0, 0
+        rows, states, counts, f, l = [], [], [], 0, 0
         for layer_params, kind in zip(period, kinds):
             if kind == "full":
                 cache = kv_of(idx * n_full + f, *(a[f] for a in kv_p))
@@ -710,17 +712,20 @@ def scan_periods_cached(cfg: ModelConfig, stacked, x, side, kv_of, rec,
             h, aux, new = layer_forward(cfg, layer_params, h, side, None,
                                         kv_cache=cache)
             (rows if kind == "full" else states).append(new)
-            loads.append(aux["load"] if isinstance(aux, dict)
-                         else jnp.zeros((0,), jnp.float32))
+            counts.append(
+                {name: aux[name] for name in ("load", "rows")}
+                if isinstance(aux, dict) else
+                {"load": jnp.zeros((0,), jnp.float32),
+                 "rows": jnp.zeros((2,), jnp.float32)})
         stack = lambda xs_: jax.tree.map(lambda *a: jnp.stack(a), *xs_)
-        return (h, idx + 1), (stack(rows), stack(states), jnp.stack(loads))
+        return (h, idx + 1), (stack(rows), stack(states), stack(counts))
 
-    (x, _), (rows, states, loads) = jax.lax.scan(
+    (x, _), (rows, states, counts) = jax.lax.scan(
         body, (x, jnp.int32(0)), xs)
     flat = lambda a: a.reshape((a.shape[0] * a.shape[1],) + a.shape[2:])
     rows = jax.tree.map(flat, rows)
     return x, rows, {"S": flat(states.S), "conv": flat(states.conv)}, \
-        flat(loads)
+        jax.tree.map(flat, counts)
 
 
 def _scan_layers_cached(cfg: ModelConfig, stacked: Params, x: jax.Array,

@@ -40,9 +40,11 @@ DEVICE_SCOPES = (
     "flash_decode", "rmsnorm", "decode_step_fused", "sample",
     # a hybrid stack (models/gated_deltanet.py, models/moe.py): the Gated
     # DeltaNet mixer and its parts, the dropless experts' parts ("mlp"
-    # holds them all)
+    # holds them all; the kernel "grouped_experts" runs under
+    # "moe_experts")
     "gdn", "gdn_proj", "gdn_conv", "gdn_scan", "gdn_step",
-    "moe_router", "moe_dispatch", "moe_experts", "moe_shared")
+    "moe_router", "moe_dispatch", "moe_experts", "moe_shared",
+    "grouped_experts")
 
 
 @dataclasses.dataclass

@@ -1161,6 +1161,10 @@ class ServingEngine:
         # / decode spans so a token's gap can be put down to the iteration
         # (and the admission) that caused it
         self._iter = 0
+        # how a dropless stack's experts run, on its prefill and step
+        # spans (kernels/grouped_matmul.py; no such field elsewhere)
+        self._experts_arg = ({"experts": "grouped"} if cfg.moe_dropless
+                             else {})
         self._admit_count = 0        # this iteration's admissions
         self._admit_tokens = 0       # and their prompt tokens
         # whether forward_cached routes this config's slot batch through
@@ -1292,6 +1296,7 @@ class ServingEngine:
                         rec_state_bytes=self.slots.rec_state_bytes,
                         rec_state_slots=cfg_e.max_batch_size)
                     self.metrics.expert_load = self.expert_load
+                    self.metrics.expert_rows = self.expert_rows
                 if self._sanitize:
                     self._sanitizer = sanitizers.LedgerSanitizer()
                 self._thread = threading.Thread(
@@ -1325,14 +1330,25 @@ class ServingEngine:
         scheduler runs it is the scheduler that reads them, between two
         iterations; this is for ``/metrics`` and the benchmark, not for a
         step."""
+        counts = self._fetch_counter("load")
+        return counts, self.cfg.moe_expert_offset, self.cfg.num_experts
+
+    def expert_rows(self) -> dict:
+        """→ the (token, choice) rows the experts' kernel was handed since
+        the engine started, by what it did with them (``multiplied``: a
+        held expert's; ``skipped``: a sort key and nothing else), over
+        all layers.  Fetched as ``expert_load`` is."""
+        rows = model_lib.rows_total(self._fetch_counter("rows")).sum(axis=0)
+        return {"multiplied": int(rows[0]), "skipped": int(rows[1])}
+
+    def _fetch_counter(self, name: str):
         def fetch():
             # tpulint: allow[host-sync] asked for, outside any step
-            return np.asarray(self.slots.rec["load"])
+            return np.asarray(self.slots.rec[name])
         try:
-            counts = self.call_in_scheduler(fetch)
+            return self.call_in_scheduler(fetch)
         except RuntimeError:        # not running: nothing donates it
-            counts = fetch()
-        return counts, self.cfg.moe_expert_offset, self.cfg.num_experts
+            return fetch()
 
     def pause(self) -> None:
         """Stop admitting and decoding (requests keep queueing) — used for
@@ -2059,7 +2075,7 @@ class ServingEngine:
                        request_id=req.rid, tid=req.id,
                        args={"prompt_len": plen, "padded": padded,
                              "cached_tokens": lease.tokens if lease else 0,
-                             "iter": self._iter})
+                             "iter": self._iter, **self._experts_arg})
         self._admit_count += 1
         self._admit_tokens += plen
         self.metrics.inc("admitted")
@@ -2141,7 +2157,8 @@ class ServingEngine:
             args={"iter": self._iter, "batch": len(inflight.slots),
                   "route": self._decode_route,
                   "sampling": inflight.sampling,
-                  "pipelined": self.config.pipeline_decode})
+                  "pipelined": self.config.pipeline_decode,
+                  **self._experts_arg})
 
     @property
     def _decode_route(self) -> str:

@@ -259,6 +259,9 @@ class ServingMetrics:
         # expert, held experts), and is called only when somebody asks
         # (``snapshot``, ``collect``), never by a step
         self.expert_load = None
+        # ``expert_rows`` likewise: the rows the experts' kernel
+        # multiplied and skipped
+        self.expert_rows = None
         # fused/fallback decode iterations keyed by the weight precision
         # route (ops/quant.py:precision_route: fp32/int8/int4/mixed) —
         # a per-precision regression to the composed path (e.g. an int4
@@ -494,7 +497,8 @@ class ServingMetrics:
                 "held_share": float(here.sum() / max(1, counts.sum())),
                 "max_over_mean_by_layer": [
                     float(row.max() / row.mean()) if row.sum() else 0.0
-                    for row in here]}
+                    for row in here],
+                "rows": self.expert_rows()}
         return out
 
     def _held_expert_load(self):
@@ -630,6 +634,13 @@ class ServingMetrics:
                     fam.add(float(n), labels={
                         "layer": str(layer), "expert": str(e),
                         "held": str(int(lo <= e < lo + held))})
+            fams.append(fam)
+            fam = MetricFamily(
+                "serving_expert_rows_total", "counter",
+                "(token, choice) rows handed to the experts' kernel, by "
+                "outcome (multiplied: a held expert's; skipped)")
+            for outcome, n in self.expert_rows().items():
+                fam.add(float(n), labels={"outcome": outcome})
             fams.append(fam)
         return fams
 

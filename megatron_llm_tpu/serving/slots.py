@@ -68,7 +68,8 @@ def _install_rec_impl(rec, one, slot):
     out = model_lib.cache_slot_update(
         {"S": rec["S"], "conv": rec["conv"]},
         {"S": one["S"], "conv": one["conv"]}, slot)
-    return {**out, "load": rec["load"] + one["load"]}
+    return {**out, "load": rec["load"] + one["load"],
+            "rows": model_lib.add_rows(rec["rows"], one["rows"])}
 
 
 _install_rec_donated = jax.jit(_install_rec_impl, donate_argnums=(0,))

@@ -34,6 +34,9 @@ from megatron_llm_tpu.kernels import decode_step as ds  # noqa: E402
 from megatron_llm_tpu.kernels import flash_decode as fd  # noqa: E402
 from megatron_llm_tpu.kernels.flash_attention import flash_attention  # noqa: E402
 from megatron_llm_tpu.kernels.gdn_scan import gdn_scan  # noqa: E402
+from megatron_llm_tpu.kernels.grouped_matmul import (  # noqa: E402
+    grouped_mlp,
+)
 from megatron_llm_tpu.kernels.rmsnorm import (  # noqa: E402
     layernorm_pallas,
     rmsnorm_pallas,
@@ -42,6 +45,7 @@ from megatron_llm_tpu.models import model as model_lib  # noqa: E402
 from megatron_llm_tpu.models.transformer import rope_tables  # noqa: E402
 from megatron_llm_tpu.obs.hlo_audit import (  # noqa: E402
     ops_by_conditional,
+    ops_under_scopes,
     relayout_bytes,
 )
 from megatron_llm_tpu.ops import attention as attn_ops  # noqa: E402
@@ -260,6 +264,74 @@ def test_gdn_scan(topo, s):
     # what a chunk's matrices spill.  Of 16 MiB a kernel may have by
     # default
     assert 3.5 * 2 ** 20 < int(vmem) < 8 * 2 ** 20, vmem
+
+
+@pytest.mark.parametrize("tokens", [16384, 44], ids=["prompt", "step"])
+def test_grouped_mlp(topo, tokens):
+    """The held experts' kernel at the published widths of Qwen3-Next (256
+    held experts of 2048 x 512, ten choices a token): a 16 384-token
+    prompt's 163 840 pairs in tiles of 128 rows, a 44-slot decode step's
+    440 in tiles of 16.  Mosaic takes the row copies (a row is two
+    (8, 128) tiles of float32 in a ``[rows x 16, 128]`` view), the sorted
+    pairs fit the scalar memory, and three whole matrices of an expert,
+    double buffered, fit the VMEM the kernel asks for."""
+    from megatron_llm_tpu.ops.activations import swiglu
+
+    one = SingleDeviceSharding(topo.devices[0])
+    E, h, f, k = 256, 2048, 512, 10
+    i32 = jnp.int32
+    text = _compile(
+        lambda x, order, sizes, *w: grouped_mlp(
+            x, order, sizes, *w, swiglu, choices=k, interpret=False),
+        (_sds((tokens, h), jnp.float32), _sds((tokens * k,), i32),
+         _sds((E,), i32), _sds((E, h, f)), _sds((E, h, f)),
+         _sds((E, f, h))), one)
+    assert f"f32[{tokens * k * 16},128]" in text        # rows as they lie
+    assert not relayout_bytes(text, min_bytes=2 * E * h * f)
+    call, = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    vmem, = re.findall(r'"used_scoped_memory_configs":\[\{"memory_space":'
+                       r'"1","offset":"0","size":"(\d+)"', call)
+    # an expert's three matrices twice: 12 MiB; the rows in and out, two
+    # tiles each: 4 MiB at 128 rows; what a tile's products spill
+    assert 12 * 2 ** 20 < int(vmem) <= 64 * 2 ** 20, vmem
+
+
+def test_a_dropless_prefill_routes_through_the_grouped_kernel(topo,
+                                                              monkeypatch):
+    """The engine's prefill executable for one period of Qwen3-Next at the
+    published widths, a 2048-token bucket: under the experts' two scopes
+    the kernel is there, and no scatter, no ragged product and no gather
+    of rows is left (``searchsorted``'s 257 looked-up keys are the only
+    gather): the pairs are sorted, the kernel fetches and places the
+    rows, one dense pass sums a token's choices."""
+    from megatron_llm_tpu.config import qwen3_next_config
+    from megatron_llm_tpu.serving import engine as engine_lib
+
+    monkeypatch.setattr(attn_ops, "_backend", lambda: "tpu")
+    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
+    one = SingleDeviceSharding(topo.devices[0])
+    s = 2048
+    cfg = qwen3_next_config("80b-a3b-ep2-rank0", num_layers=4,
+                            attention_impl="flash")
+    place = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
+    params = jax.eval_shape(
+        lambda key: model_lib.init_params(key, cfg), jax.random.key(0))
+    text = engine_lib._prefill_impl.lower(
+        cfg, place(params), place(_sds((1, s), jnp.int32)),
+        place(_sds((1,), jnp.int32)), max_seq_len=s + 256,
+        want_logprobs=False).compile().as_text()
+    scopes = ("moe_dispatch", "moe_experts")
+    kernels_there = [path for _op, _type, path in ops_under_scopes(
+        text, scopes, {"custom-call"}) if "grouped_experts" in path]
+    assert len(kernels_there) == cfg.num_layers
+    assert not ops_under_scopes(text, scopes, {"scatter", "ragged-dot"})
+    assert "ragged" not in text
+    rows = s * cfg.moe_top_k
+    for _op, result, path in ops_under_scopes(text, scopes, {"gather"}):
+        assert "searchsorted" in path and f"[{rows}" not in result, (
+            result, path)
 
 
 # -- whole-stack fused decode kernels (the geometry that is eligible) ------

@@ -175,6 +175,19 @@ def test_decode_continues_the_prefill_and_skips_dead_rows(model):
                                  - before[key][:, 0]).max()) > 0
     assert int(rec["load"].sum()) == cfg.num_layers * cfg.moe_top_k * (
         2 * 30 + 4)
+    # the experts' rows are every position's, live or not, by outcome
+    rows = model_lib.rows_total(rec["rows"])
+    assert rows.shape == (cfg.num_layers, 2) and (rows > 0).all()
+    assert int(rows.sum()) == cfg.num_layers * cfg.moe_top_k * 2 * (30 + 4)
+
+
+def test_a_row_count_carries_into_its_high_word():
+    word = 1 << model_lib._ROWS_WORD
+    rows = jnp.asarray([[0, word - 3], [2, 5]], jnp.int32)
+    more = jnp.asarray([[0, 163840], [0, 0]], jnp.int32)
+    total = model_lib.rows_total(model_lib.add_rows(rows, more))
+    assert total.tolist() == [word - 3 + 163840, 2 * word + 5]
+    assert model_lib.add_rows(rows, more)[0].tolist() == [1, 163837]
 
 
 def test_a_tokens_experts_do_not_depend_on_its_batch(model):
@@ -193,6 +206,55 @@ def test_a_tokens_experts_do_not_depend_on_its_batch(model):
     at_once, _ = run(dataclasses.replace(cfg, moe_group_size=96), p, crowd)
     in_chunks, _ = run(dataclasses.replace(cfg, moe_group_size=32), p, crowd)
     np.testing.assert_allclose(in_chunks, at_once, atol=1e-6)
+
+
+def former_held_experts(cfg, _interpret, p, x, local, weight):
+    """``moe._held_experts`` as it was before ``kernels/grouped_matmul.py``
+    (PR 42), the oracle: a stable argsort, all ``g * k`` rows gathered,
+    three ``lax.ragged_dot`` calls, the order inverted by a scatter."""
+    g, k = local.shape
+    E = cfg.num_experts
+    act = moe.get_activation(cfg.activation)
+    x = x.astype(p["w_up"].dtype)
+    flat = local.reshape(-1)
+    order = jnp.argsort(flat, stable=True)
+    sizes = jnp.zeros((E + 1,), jnp.int32).at[flat].add(1)[:E]
+    rows = x[order // k]
+    gate = jax.lax.ragged_dot(rows, p["w_gate"], sizes)
+    up = jax.lax.ragged_dot(rows, p["w_up"], sizes)
+    hidden = act(jnp.concatenate([gate, up], axis=-1))
+    out = jax.lax.ragged_dot(hidden, p["w_down"], sizes)
+    back = jnp.zeros_like(order).at[order].set(
+        jnp.arange(g * k, dtype=order.dtype))
+    out = out[back].reshape(g, k, -1)
+    out = jnp.where((local < E)[..., None], out, 0).astype(jnp.float32)
+    held = jnp.sum(sizes)
+    return (out * weight[..., None]).sum(axis=1), jnp.stack(
+        [held, g * k - held])
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-6), ("bfloat16", 3e-2)])
+@pytest.mark.parametrize("group", [256, 32], ids=["at_once", "in_chunks"])
+def test_the_grouped_kernel_is_the_former_ragged_products(
+        monkeypatch, group, dtype, tol):
+    """``moe_dropless_block`` at the rehearsal widths against itself with
+    the former formulation in the kernel's place, routed at once and in
+    chunks (the ``lax.map`` arm)."""
+    cfg = tiny(params_dtype=dtype, moe_group_size=group)
+    p = moe.init_moe_params(jax.random.key(7), cfg)
+    x = jax.random.normal(jax.random.key(8), (2, 48, cfg.hidden_size))
+    got, stats = jax.jit(moe.moe_dropless_block, static_argnums=0)(cfg, p, x)
+    monkeypatch.setattr(moe, "_held_experts", former_held_experts)
+    jax.clear_caches()      # the block is jitted: trace it again
+    want, former = jax.jit(moe.moe_dropless_block, static_argnums=0)(
+        cfg, p, x)
+    np.testing.assert_allclose(got, want, atol=tol, rtol=tol)
+    np.testing.assert_array_equal(stats["rows"], former["rows"])
+    np.testing.assert_array_equal(stats["load"], former["load"])
+    lo, n = cfg.moe_expert_offset, cfg.num_experts
+    assert float(stats["rows"][0]) == float(stats["load"][lo:lo + n].sum())
+    assert float(stats["rows"].sum()) == 2 * 48 * cfg.moe_top_k
+    assert 0 < float(stats["rows"][1])        # some choices are not here
 
 
 def test_rotary_turns_a_quarter_of_the_head_and_keeps_the_norm():

@@ -93,6 +93,35 @@ def test_the_expert_counter_rides_on_the_device_and_is_read_on_request(model):
                if s.labels["held"] == "1") == counts[:, :held].sum()
 
 
+def test_the_experts_rows_are_counted_by_outcome_and_the_spans_say_how(model):
+    """``serving_expert_rows_total``: every row of a held expert is
+    ``multiplied``, every other ``skipped`` (about half, by the share),
+    padded positions and free slots included; the ``prefill`` and
+    ``engine_step`` spans name the implementation."""
+    cfg, params = model
+    prompts = prompts_of([40, 50], seed=1)
+    _, eng = serve(cfg, params, prompts, new=5)
+    rows = eng.expert_rows()
+    counts, lo, held = eng.expert_load()
+    # a prompt is padded to whole buckets of 32 and a step feeds both
+    # slots: no fewer rows than counted choices, and held ones among both
+    assert rows["multiplied"] >= counts[:, lo:lo + held].sum() > 0
+    assert rows["skipped"] >= counts.sum() - counts[:, lo:lo + held].sum()
+    total, per_position = divmod(rows["multiplied"] + rows["skipped"],
+                                 cfg.num_layers * cfg.moe_top_k)
+    assert per_position == 0 and total >= (64 + 64) + 2 * 4
+    assert 0.2 < rows["skipped"] / (rows["multiplied"]
+                                    + rows["skipped"]) < 0.8
+    assert eng.metrics.snapshot()["expert_load"]["rows"] == rows
+    (fam,) = [f for f in REGISTRY.collect()
+              if f.name == "serving_expert_rows_total"]
+    assert {s.labels["outcome"]: s.value for s in fam.samples} == rows
+    spans = [e for e in eng.trace.chrome_trace()["traceEvents"]
+             if e["name"] in ("prefill", "engine_step")]
+    assert {e["name"] for e in spans} == {"prefill", "engine_step"}
+    assert all(e["args"]["experts"] == "grouped" for e in spans)
+
+
 def test_a_dense_engine_counts_no_experts_and_keeps_no_state():
     from megatron_llm_tpu.config import tiny_config
 
@@ -102,6 +131,9 @@ def test_a_dense_engine_counts_no_experts_and_keeps_no_state():
         max_batch_size=2, max_seq_len=64)).start()
     eng.shutdown()
     assert eng.slots.rec is None and eng.metrics.expert_load is None
+    assert eng.metrics.expert_rows is None
+    assert not any("experts" in e.get("args", {})
+                   for e in eng.trace.chrome_trace()["traceEvents"])
     assert "expert_load" not in eng.metrics.snapshot()
     assert eng.metrics.snapshot()["rec_state_bytes"] == 0
 
