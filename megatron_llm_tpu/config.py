@@ -30,6 +30,10 @@ class PositionEmbeddingType:
     NONE = "none"
 
 
+# the block kinds of ModelConfig.layer_pattern
+BLOCK_KINDS = ("full", "linear", "attention", "mamba", "mlp")
+
+
 class AttnMaskType:
     CAUSAL = "causal"
     PADDING = "padding"
@@ -213,11 +217,15 @@ class ModelConfig:
     fused_lm_head: bool = False
     # Width of an attention head where it is not hidden / heads.
     kv_channels: Optional[int] = None
-    # Hybrid stacks: the block kinds of one period of layers, "full"
-    # (softmax attention) or "linear" (Gated DeltaNet,
-    # models/gated_deltanet.py); the stack is scanned by period
-    # (models/transformer.py) and num_layers is a multiple of it.
-    # () = every layer "full": the one-kind stack.
+    # Hybrid stacks: the block kinds of one period of layers.  A block of
+    # two parts, a mixer and then a feed-forward part, each under a norm
+    # of its own: "full" (softmax attention) or "linear" (Gated DeltaNet,
+    # models/gated_deltanet.py).  A block of one part under one norm,
+    # ``h + f(norm(h))``: "attention" (softmax attention alone), "mamba"
+    # (a Mamba-2 mixer alone, models/mamba2.py) or "mlp" (the feed-forward
+    # part alone: the experts where num_experts > 0).  The stack is
+    # scanned by period (models/transformer.py) and num_layers is a
+    # multiple of it.  () = every layer "full": the one-kind stack.
     layer_pattern: tuple = ()
     # Gated DeltaNet geometry: key heads x key width, value heads x value
     # width (value heads a multiple of key heads), causal depthwise
@@ -227,6 +235,16 @@ class ModelConfig:
     linear_key_head_dim: int = 128
     linear_value_head_dim: int = 128
     linear_conv_kernel: int = 4
+    # Mamba-2 geometry: heads x head width (their product is the inner
+    # width), B/C groups x state width (a head reads group
+    # head // (heads / groups)), causal depthwise convolution taps over
+    # the x|B|C channels, positions a chunk of the chunked (SSD) form.
+    mamba_num_heads: int = 128
+    mamba_head_dim: int = 64
+    mamba_n_groups: int = 8
+    mamba_state_size: int = 128
+    mamba_conv_kernel: int = 4
+    mamba_chunk_size: int = 128
     # softmax-attention variants: share of each head's width that is
     # rotated (rotate-half over the first rotary_percent * head_dim
     # dimensions; 1.0 = the interleaved full-width rotation of ops/rope.py),
@@ -241,12 +259,25 @@ class ModelConfig:
     # The router keeps moe_router_experts outputs (0 = num_experts) of which
     # this parameter tree holds num_experts consecutive ones starting at
     # moe_expert_offset: one expert-parallel rank's share, whose partial sum
-    # the layer returns.  moe_shared_expert_size > 0 adds a sigmoid-gated
-    # shared expert of that width, whole on every rank.
+    # the layer returns.  moe_shared_expert_size > 0 adds a shared expert
+    # of that width, whole on every rank, under a sigmoid gate of its own
+    # unless moe_shared_expert_gated is off.
+    # moe_router_scoring "sigmoid": each score is a sigmoid of its own
+    # output; the top-k are chosen by score + a per-expert bias (kept in
+    # the tree, ``router_bias``), weighted by the scores alone divided by
+    # their sum.  Either way the weights are then multiplied by
+    # moe_routed_scaling.  moe_latent_size > 0: the routed experts live
+    # in a latent of that width, between one down- and one up-projection
+    # shared by all of them and whole on every rank; a rank's partial sum
+    # is taken in the latent and goes through the up-projection.
     moe_dropless: bool = False
     moe_router_experts: int = 0
     moe_expert_offset: int = 0
     moe_shared_expert_size: int = 0
+    moe_shared_expert_gated: bool = True
+    moe_router_scoring: str = "softmax"
+    moe_routed_scaling: float = 1.0
+    moe_latent_size: int = 0
 
     def __post_init__(self):
         # a JSON list (checkpointed arguments, a benchmark's overrides):
@@ -274,12 +305,37 @@ class ModelConfig:
     @property
     def kv_layers(self) -> int:
         """Layers that keep keys and values: the KV pool's layer axis."""
-        return self.layer_kinds.count("full")
+        kinds = self.layer_kinds
+        return kinds.count("full") + kinds.count("attention")
 
     @property
     def linear_layers(self) -> int:
-        """Layers that keep a recurrent state (serving/slots.py)."""
+        """Layers that keep a delta-rule state (serving/slots.py)."""
         return self.layer_kinds.count("linear")
+
+    @property
+    def mamba_layers(self) -> int:
+        """Layers that keep a state-space state (serving/slots.py)."""
+        return self.layer_kinds.count("mamba")
+
+    @property
+    def moe_layer_ids(self) -> tuple:
+        """The layers that route: every layer with a feed-forward part,
+        where the model has experts."""
+        if self.num_experts == 0:
+            return ()
+        return tuple(i for i, kind in enumerate(self.layer_kinds)
+                     if kind in ("full", "linear", "mlp"))
+
+    @property
+    def mamba_inner(self) -> int:
+        return self.mamba_num_heads * self.mamba_head_dim
+
+    @property
+    def mamba_conv_channels(self) -> int:
+        """x | B | C: what the convolution runs over."""
+        return (self.mamba_inner
+                + 2 * self.mamba_n_groups * self.mamba_state_size)
 
     @property
     def ffn_size(self) -> int:
@@ -310,13 +366,22 @@ class ModelConfig:
             self.hidden_size % self.num_attention_heads == 0)
         assert self.num_attention_heads % self.kv_heads == 0
         if self.layer_pattern:
-            assert set(self.layer_pattern) <= {"full", "linear"}, (
+            assert set(self.layer_pattern) <= set(BLOCK_KINDS), (
                 f"unknown block kind in {self.layer_pattern!r}")
             assert self.num_layers % len(self.layer_pattern) == 0, (
                 f"num_layers {self.num_layers} is not whole periods of "
                 f"{len(self.layer_pattern)} layers")
             assert (self.linear_num_value_heads
                     % self.linear_num_key_heads == 0)
+            assert self.mamba_num_heads % self.mamba_n_groups == 0
+        assert self.moe_router_scoring in ("softmax", "sigmoid"), (
+            f"unknown moe_router_scoring {self.moe_router_scoring!r}")
+        if not self.moe_dropless:
+            assert (self.moe_router_scoring == "softmax"
+                    and self.moe_routed_scaling == 1.0
+                    and not self.moe_latent_size), (
+                "sigmoid scoring, a routed scaling factor and latent "
+                "experts are the dropless route's (moe_dropless)")
         if self.moe_dropless:
             assert self.num_experts > 0
             assert (0 <= self.moe_expert_offset and self.moe_expert_offset
@@ -846,6 +911,82 @@ def qwen3_next_config(size: str = "80b-a3b", **overrides) -> ModelConfig:
         "80b-a3b-ep2-rank0": dict(num_experts=256, moe_router_experts=512,
                                   moe_expert_offset=0, vocab_size=75968,
                                   make_vocab_size_divisible_by=64),
+    }
+    base.update(sizes[size])
+    base.update(overrides)
+    return ModelConfig(**base).validate()
+
+
+# hybrid_override_pattern of nvidia/NVIDIA-Nemotron-3-Super-120B-A12B-BF16
+# (M: Mamba-2, E: experts, *: attention).  Not periodic: between attention
+# layers lie runs of 7, 8, 8, 10, 10, 10, 10, 8 and 9 layers.
+NEMOTRON_3_SUPER_PATTERN = (
+    "MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM"
+    "*EMEMEMEMEM*EMEMEMEM*EMEMEMEME")
+_NEMOTRON_KINDS = {"M": "mamba", "E": "mlp", "*": "attention"}
+
+
+def nemotron_h_config(size: str = "3-super-120b-a12b",
+                      **overrides) -> ModelConfig:
+    """Nemotron-H (``model_type: nemotron_h``): every layer is one part
+    under one RMSNorm, a Mamba-2 mixer, softmax attention without any
+    position rotation (32 heads on 2 KV heads of 128) or LatentMoE: 512
+    sigmoid-scored experts (top 22 by score + bias, weights renormalised
+    and scaled by 5) of two matrices with relu^2 between, in a 1024-wide
+    latent, beside an un-gated shared expert on the full stream; untied
+    head.  Served only, as every hybrid stack here.
+
+    ``3-super-120b-a12b`` is the published model; its 88-layer pattern is
+    not periodic, so it is written out whole as one period (any other
+    ``num_layers`` fails ``validate``).  ``3-super-120b-a12b-ep4-rank0``
+    is what one chip of an expert-parallel four holds of one pipeline
+    stage: the pattern's layers 25-35 (``*EMEMEMEMEM``, which recurs at
+    36, 47 and 58), experts 0-127 of the 512 (the router keeps its 512
+    outputs and 22 choices) and rows 0-32767 of the 131072-row embedding
+    and head.  The multi-token-prediction module is left out."""
+    kinds = tuple(_NEMOTRON_KINDS[c] for c in NEMOTRON_3_SUPER_PATTERN)
+    base = dict(
+        norm_type="rmsnorm",
+        norm_eps=1e-5,
+        activation="squared_relu",
+        position_embedding_type=PositionEmbeddingType.NONE,
+        use_bias=False,
+        tie_embed_logits=False,
+        hidden_size=4096,
+        num_layers=88,
+        layer_pattern=kinds,
+        num_attention_heads=32,
+        num_kv_heads=2,
+        kv_channels=128,
+        mamba_num_heads=128,
+        mamba_head_dim=64,
+        mamba_n_groups=8,
+        mamba_state_size=128,
+        mamba_conv_kernel=4,
+        mamba_chunk_size=128,
+        ffn_hidden_size=2688,
+        num_experts=512,
+        moe_top_k=22,
+        moe_dropless=True,
+        moe_router_scoring="sigmoid",
+        moe_routed_scaling=5.0,
+        moe_latent_size=1024,
+        moe_shared_expert_size=5376,
+        moe_shared_expert_gated=False,
+        # a prefill bucket is routed at once (22 pairs a token: 2048
+        # tokens are 45056 sorted pairs in the kernel's scalar memory)
+        moe_group_size=2048,
+        vocab_size=131072,
+        max_position_embeddings=262144,
+        seq_length=4096,
+        fused_decode=False,
+        recompute="none",
+    )
+    sizes = {
+        "3-super-120b-a12b": dict(),
+        "3-super-120b-a12b-ep4-rank0": dict(
+            num_layers=11, layer_pattern=kinds[25:36], num_experts=128,
+            moe_router_experts=512, moe_expert_offset=0, vocab_size=32768),
     }
     base.update(sizes[size])
     base.update(overrides)

@@ -17,12 +17,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..config import ModelConfig, PositionEmbeddingType
+from . import mamba2
 from .transformer import (
+    STREAM_DTYPE,
     AttnSideInputs,
     Params,
     _dropout,
     PagedKV,
     init_stack_params,
+    layer_forward,
     norm_init,
     rope_tables,
     scan_periods_cached,
@@ -72,7 +75,51 @@ def init_params(key: jax.Array, cfg: ModelConfig, tp: int = 1) -> Params:
             cfg.init_method_std
             * jax.random.normal(k_head, (h, v), jnp.float32)
         ).astype(dtype)
+    if cfg.layer_pattern and cfg.moe_router_scoring == "sigmoid":
+        params = level_router_bias(cfg, params, jax.random.fold_in(key, 1))
     return params
+
+
+def level_router_bias(cfg: ModelConfig, params: Params, key: jax.Array,
+                      tokens: int = 2048) -> Params:
+    """A tree made from a seed has no training behind its routers'
+    selection bias, and without one a random stack routes unevenly: what
+    its relu^2 and SiLU parts add to the stream has a direction every
+    token shares, which lifts the same experts' scores for all of them
+    (the busiest held expert of Nemotron-3-Super's 11-layer run drew 8
+    times the mean: PERF.md, PR 44).  Training sets the bias against just
+    that, so ``init_params`` does what it would have done: one sequence
+    of ``tokens`` seeded tokens goes through the stack, and each
+    feed-forward block's bias is set on the way (``moe.level_bias``)
+    before the block runs, so that the blocks after it see a levelled
+    layer's output.  Blocks that hold a mixer too keep the bias they
+    drew."""
+    from .moe import level_bias
+
+    kinds = cfg.layer_pattern
+    toks = jax.random.randint(key, (1, tokens), 1, cfg.vocab_size - 1)
+    position_ids = jnp.arange(tokens, dtype=jnp.int32)[None]
+    cos, sin = rope_tables(cfg)
+    side = AttnSideInputs(rope_cos=cos, rope_sin=sin,
+                          position_ids=position_ids, deterministic=True)
+    x = embed(cfg, params, toks, position_ids).astype(STREAM_DTYPE)
+    stacks = list(params["layers"])
+
+    def layer_of(j, i):
+        return jax.tree.map(lambda a: a[i], stacks[j])
+
+    for layer in range(cfg.num_layers):
+        j, i = layer % len(kinds), layer // len(kinds)
+        mlp = stacks[j].get("mlp", {})
+        if kinds[j] == "mlp" and "router_bias" in mlp:
+            p = layer_of(j, i)
+            h1 = norm_apply(cfg.norm_type, x, p["input_norm"], cfg.norm_eps,
+                            impl=cfg.norm_impl)
+            bias = level_bias(cfg, p["mlp"], h1[0])
+            stacks[j] = {**stacks[j], "mlp": {
+                **mlp, "router_bias": mlp["router_bias"].at[i].set(bias)}}
+        x = layer_forward(cfg, layer_of(j, i), x, side)[0]
+    return {**params, "layers": stacks}
 
 
 @jax.named_scope("embed")
@@ -635,25 +682,45 @@ def init_kv_cache(cfg: ModelConfig, batch_size: int, max_len: int,
 
 def init_rec_state(cfg: ModelConfig, batch_size: int) -> dict:
     """What a hybrid stack keeps a sequence beside its keys and values,
-    for ``batch_size`` sequences (the serving engine: one a slot): every
-    linear layer's recurrent state ``S`` [linear layers, b, value heads,
-    key width, value width] float32 and convolution tail ``conv``
-    [linear layers, b, taps - 1, channels], zero at a sequence's start;
-    and two counters carried on the device and read when somebody asks:
-    ``load`` [layers, router outputs] int32, how often each expert was
-    chosen by the positions these states were advanced over, and
-    ``rows`` [layers, 2, 2] int32, the (token, choice) rows the layer's
-    experts multiplied and skipped (``models/moe.py``), each count as
-    two words (``add_rows``: a long prompt adds 10^5 to it)."""
-    from .gated_deltanet import init_state
+    for ``batch_size`` sequences (the serving engine: one a slot), zero
+    at a sequence's start, float32, each kind of recurrent mixer under
+    names of its own and only where the stack has such layers: every
+    Gated DeltaNet layer's state ``S`` [linear layers, b, value heads,
+    key width, value width] and convolution tail ``conv`` [linear layers,
+    b, taps - 1, channels]; every Mamba-2 layer's state ``ssm`` [mamba
+    layers, b, heads, head width, state width] and tail ``ssm_conv``
+    (``REC_STATE_KINDS`` names them by kind).  And two counters carried
+    on the device and read when somebody asks: ``load`` [layers, router
+    outputs] int32, how often each expert was chosen by the positions
+    these states were advanced over (zero rows for the layers that do
+    not route), and ``rows`` [layers, 2, 2] int32, the (token, choice)
+    rows the layer's experts multiplied and skipped (``models/moe.py``),
+    each count as two words (``add_rows``: a long prompt adds 10^5 to
+    it)."""
+    from . import gated_deltanet
 
-    one = init_state(cfg, batch_size)
-    n = cfg.linear_layers
-    return {"S": jnp.zeros((n,) + one.S.shape, one.S.dtype),
-            "conv": jnp.zeros((n,) + one.conv.shape, one.conv.dtype),
+    rec = {}
+    for kind, n, init in (("linear", cfg.linear_layers, gated_deltanet),
+                          ("mamba", cfg.mamba_layers, mamba2)):
+        if n:
+            one = init.init_state(cfg, batch_size)
+            for name, a in zip(REC_STATE_KINDS[kind], one):
+                rec[name] = jnp.zeros((n,) + a.shape, a.dtype)
+    return {**rec,
             "load": jnp.zeros((cfg.num_layers, cfg.router_experts),
                               jnp.int32),
             "rows": jnp.zeros((cfg.num_layers, 2, 2), jnp.int32)}
+
+
+# the names of ``init_rec_state``'s state arrays, by the block kind
+# (``config.BLOCK_KINDS``) that keeps them
+REC_STATE_KINDS = {"linear": ("S", "conv"), "mamba": mamba2.STATE_NAMES}
+
+
+def rec_states(rec: dict) -> dict:
+    """The state arrays of ``rec`` (its counters left out)."""
+    return {name: rec[name] for names in REC_STATE_KINDS.values()
+            for name in names if name in rec}
 
 
 _ROWS_WORD = 30
@@ -687,7 +754,7 @@ def forward_cached_hybrid(cfg: ModelConfig, params: Params, tokens,
                           logit_rows=None):
     """``forward_cached`` for a hybrid stack (``cfg.layer_pattern``): the
     full layers append to the dense cache ``[full layers, b, kv heads,
-    max_len, d]`` as there, the linear layers continue ``rec``
+    max_len, d]`` as there, the recurrent mixers continue ``rec``
     (``init_rec_state``) over the positions ``valid`` [b, s] marks (None:
     all; a prefix of each row) and leave it untouched over the others.
     → ``(logits, new_k_cache, new_v_cache, new_rec)``."""
@@ -722,7 +789,7 @@ def forward_paged_hybrid(cfg: ModelConfig, params: Params, tokens, k_pool,
     the full layers reading their keys and values out of the block pool
     (``[full layers, n_blocks, ...]``) by the composed routes there (the
     paged kernel where ``paged_decode_eligible``, else the gathered dense
-    view), the linear layers advancing slot ``i``'s row of ``rec`` where
+    view), the recurrent mixers advancing slot ``i``'s row of ``rec`` where
     ``live[i]`` and leaving it where not (a free slot rides along with
     whatever its row holds).  → ``(logits [b, 1, vocab], new_k_pool,
     new_v_pool, new_rec)``."""

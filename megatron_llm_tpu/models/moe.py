@@ -39,7 +39,7 @@ from .. import kernels
 from ..config import ModelConfig
 from ..kernels.grouped_matmul import grouped_mlp
 from ..ops.activations import get_activation, is_glu
-from ..ops.precision import dot_f32
+from ..ops.precision import dot_f32, dot_rounded
 
 Params = dict
 
@@ -57,23 +57,52 @@ def init_moe_params(key: jax.Array, cfg: ModelConfig) -> Params:
     def normal(k, shape, s):
         return (s * jax.random.normal(k, shape, jnp.float32)).astype(dtype)
 
+    # the routed experts' input: the stream, or the latent they live in
+    d = cfg.moe_latent_size or h
     p: Params = {
         # router kept in fp32: routing decisions are precision-sensitive
         "router": std * jax.random.normal(
             keys[0], (h, cfg.router_experts), jnp.float32),
-        "w_up": normal(keys[2], (E, h, f), std),
-        "w_down": normal(keys[3], (E, f, h), out_std),
+        "w_up": normal(keys[2], (E, d, f), std),
+        "w_down": normal(keys[3], (E, f, d), out_std),
     }
     if is_glu(cfg.activation):
-        p["w_gate"] = normal(keys[1], (E, h, f), std)
+        p["w_gate"] = normal(keys[1], (E, d, f), std)
+    if cfg.moe_router_scoring == "sigmoid":
+        # the selection bias (a trained model balances its load with it):
+        # small and not zero, so that a path that ignores it is found out
+        # (a feed-forward block's is set again, against the load this
+        # stack really has: models/model.py:level_router_bias)
+        p["router_bias"] = 0.05 * jax.random.normal(
+            jax.random.fold_in(key, 2), (cfg.router_experts,), jnp.float32)
+    if cfg.moe_latent_size:
+        ks = jax.random.split(jax.random.fold_in(key, 3), 2)
+        p["latent_down"] = normal(ks[0], (h, d), std)
+        p["latent_up"] = normal(ks[1], (d, h), out_std)
     if cfg.moe_shared_expert_size:
         fs = cfg.moe_shared_expert_size
         ks = jax.random.split(jax.random.fold_in(key, 1), 4)
-        p["shared"] = {"w_gate": normal(ks[0], (h, fs), std),
-                       "w_up": normal(ks[1], (h, fs), std),
-                       "w_down": normal(ks[2], (fs, h), out_std),
-                       "gate": normal(ks[3], (h, 1), std)}
+        p["shared"] = {"w_up": normal(ks[1], (h, fs), std),
+                       "w_down": normal(ks[2], (fs, h), out_std)}
+        if is_glu(cfg.activation):
+            p["shared"]["w_gate"] = normal(ks[0], (h, fs), std)
+        if cfg.moe_shared_expert_gated:
+            p["shared"]["gate"] = normal(ks[3], (h, 1), std)
     return p
+
+
+def level_bias(cfg: ModelConfig, p: Params, x: jax.Array) -> jax.Array:
+    """The selection bias that levels a sigmoid router's load over the
+    tokens ``x`` [T, h]: each expert's bias lifts the score it exceeds
+    for ``top_k / router_experts`` of them to one common threshold, so
+    every expert is among a token's ``top_k`` about equally often.  The
+    bias moves the choice alone; the weights stay the scores'."""
+    score = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), p["router"].astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    level = jnp.quantile(score, 1.0 - cfg.moe_top_k / cfg.router_experts,
+                         axis=0)
+    return jnp.mean(level) - level
 
 
 def capacity(cfg: ModelConfig, group_len: int) -> int:
@@ -219,12 +248,16 @@ def _held_experts(cfg: ModelConfig, interpret: bool, p: Params, x, local,
 
 def moe_dropless_block(cfg: ModelConfig, p: Params, x: jax.Array,
                        valid=None):
-    """Routed MLP without capacity: ``softmax`` over the router's
-    ``cfg.router_experts`` outputs in float32, the ``moe_top_k`` largest,
-    their weights divided by their sum; the sum over the chosen experts
-    that this tree holds (``cfg.moe_expert_offset`` onwards; what the
-    absent ones would add is another rank's to compute) plus the shared
-    expert under its sigmoid gate → ``(out [b, s, h], stats)``.
+    """Routed MLP without capacity: the router's ``cfg.router_experts``
+    outputs scored in float32 (``cfg.moe_router_scoring``: a softmax
+    over them, or a sigmoid each with the choice made by score + bias),
+    the ``moe_top_k`` largest, their scores divided by their sum and
+    multiplied by ``cfg.moe_routed_scaling``; the sum over the chosen
+    experts that this tree holds (``cfg.moe_expert_offset`` onwards; what
+    the absent ones would add is another rank's to compute), taken in the
+    experts' latent and brought back through the shared up-projection
+    where ``cfg.moe_latent_size``, plus the shared expert (under its
+    sigmoid gate where it has one) → ``(out [b, s, h], stats)``.
 
     ``stats["load"]`` [router_experts] counts the choices of the
     positions ``valid`` [b, s] marks (None: all), held or not: the
@@ -254,8 +287,17 @@ def _dropless(cfg: ModelConfig, interpret: bool, p: Params, x, valid):
         logits = jnp.dot(xt.astype(jnp.float32),
                          p["router"].astype(jnp.float32),
                          precision=jax.lax.Precision.HIGHEST)
-        weight, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
-        weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
+        if cfg.moe_router_scoring == "sigmoid":
+            score = jax.nn.sigmoid(logits)
+            _, idx = jax.lax.top_k(score + p["router_bias"], k)
+            weight = jnp.take_along_axis(score, idx, axis=-1)
+            weight = weight / (jnp.sum(weight, axis=-1, keepdims=True)
+                               + 1e-20)
+        else:
+            weight, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+            weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
+        if cfg.moe_routed_scaling != 1.0:
+            weight = weight * cfg.moe_routed_scaling
         counted = valid.reshape(-1).astype(jnp.float32)
         load = jnp.zeros((R,), jnp.float32).at[idx.reshape(-1)].add(
             jnp.repeat(counted, k))
@@ -266,16 +308,25 @@ def _dropless(cfg: ModelConfig, interpret: bool, p: Params, x, valid):
     # the router and the shared expert read ``x`` as it comes (a float32
     # residual stream is not rounded first); the routed experts' kernel
     # rounds the rows it fetches to the weights' precision
+    xe = xt
+    if cfg.moe_latent_size:
+        with jax.named_scope("moe_latent"):
+            xe = dot_rounded(xt, p["latent_down"])
     g = group_size(cfg, b * s)
     if g == b * s:
-        out, rows = _held_experts(cfg, interpret, p, xt, local, weight)
+        out, rows = _held_experts(cfg, interpret, p, xe, local, weight)
     else:
         n = b * s // g
         out, rows = jax.lax.map(
             lambda c: _held_experts(cfg, interpret, p, *c),
-            (xt.reshape(n, g, h), local.reshape(n, g, k),
+            (xe.reshape(n, g, -1), local.reshape(n, g, k),
              weight.reshape(n, g, k)))
-        out, rows = out.reshape(b * s, h), rows.sum(axis=0)
+        out, rows = out.reshape(b * s, -1), rows.sum(axis=0)
+    if cfg.moe_latent_size:
+        # the held partial sum, taken in the latent, through the
+        # up-projection every rank holds whole
+        with jax.named_scope("moe_latent"):
+            out = dot_rounded(out, p["latent_up"])
     # tpulint: allow[tracer-leak] a key of the tree, no traced value
     if "shared" in p:
         with jax.named_scope("moe_shared"):
@@ -284,11 +335,20 @@ def _dropless(cfg: ModelConfig, interpret: bool, p: Params, x, valid):
             # the largest part of the layer's output (its gate is ~1/2,
             # a routed expert's ~1/10), so its rounding is what the next
             # layer's router sees: the stream in two passes (dot_f32)
-            hidden = act(jnp.concatenate(
-                [dot_f32(xt, sp["w_gate"]), dot_f32(xt, sp["w_up"])],
-                axis=-1))
-            gate = jax.nn.sigmoid(dot_f32(xt, sp["gate"]))
-            out = out + gate * dot_f32(hidden, sp["w_down"])
+            # tpulint: allow[tracer-leak] keys of the tree
+            if "w_gate" in sp:
+                hidden = jnp.concatenate(
+                    [dot_f32(xt, sp["w_gate"]), dot_f32(xt, sp["w_up"])],
+                    axis=-1)
+            else:
+                hidden = dot_f32(xt, sp["w_up"])
+            hidden = act(hidden)
+            # tpulint: allow[tracer-leak] a key of the tree
+            if "gate" in sp:
+                gate = jax.nn.sigmoid(dot_f32(xt, sp["gate"]))
+                out = out + gate * dot_f32(hidden, sp["w_down"])
+            else:
+                out = out + dot_f32(hidden, sp["w_down"])
     return out.astype(x.dtype).reshape(b, s, h), {
         "aux": jnp.zeros((), jnp.float32),
         "dropped": jnp.zeros((), jnp.float32), "load": load,

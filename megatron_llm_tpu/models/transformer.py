@@ -42,6 +42,7 @@ from ..ops.rope import (
     precompute_rope_freqs,
 )
 from .gated_deltanet import GDNState, gdn_block, init_gdn_params
+from . import mamba2
 
 Params = dict
 
@@ -67,9 +68,11 @@ def _normal(key, shape, std, dtype):
 
 def init_layer_params(key: jax.Array, cfg: ModelConfig,
                       kind: str = "full") -> Params:
-    """Parameters of one transformer layer (unstacked); a ``"linear"``
-    layer of a hybrid stack holds a Gated DeltaNet mixer (``"gdn"``) where
-    a ``"full"`` one holds ``"attn"``."""
+    """Parameters of one transformer layer (unstacked), of one of
+    ``config.BLOCK_KINDS``.  A block of two parts holds a mixer
+    (``"attn"``, or ``"gdn"`` for a ``"linear"`` layer), ``"mlp"`` and a
+    norm for each; a block of one part holds that part (``"attn"``,
+    ``"mamba"`` or ``"mlp"``) under ``"input_norm"`` alone."""
     h = cfg.hidden_size
     d = cfg.head_dim
     nq = cfg.num_attention_heads
@@ -81,23 +84,33 @@ def init_layer_params(key: jax.Array, cfg: ModelConfig,
     out_std = std / (2.0 * cfg.num_layers) ** 0.5 if cfg.use_scaled_init else std
 
     keys = jax.random.split(key, 8)
-    attn: Params = {
-        # with an output gate: per head, the query's columns then the gate's
-        "wq": _normal(keys[0], (h, nq * d * (2 if cfg.attn_output_gate
-                                             else 1)), std, dtype),
-        "wk": _normal(keys[1], (h, nkv * d), std, dtype),
-        "wv": _normal(keys[2], (h, nkv * d), std, dtype),
-        "wo": _normal(keys[3], (nq * d, h), out_std, dtype),
-    }
-    if cfg.use_bias or cfg.qkv_bias:
-        attn["bq"] = jnp.zeros((nq * d,), dtype)
-        attn["bk"] = jnp.zeros((nkv * d,), dtype)
-        attn["bv"] = jnp.zeros((nkv * d,), dtype)
-    if cfg.use_bias:
-        attn["bo"] = jnp.zeros((h,), dtype)
-    if cfg.qk_norm:
-        attn["q_norm"] = norm_init(cfg.norm_type, d, dtype)
-        attn["k_norm"] = norm_init(cfg.norm_type, d, dtype)
+    layer: Params = {"input_norm": norm_init(cfg.norm_type, h, dtype)}
+    if kind in ("full", "attention"):
+        attn: Params = {
+            # with an output gate: per head, the query's columns then the
+            # gate's
+            "wq": _normal(keys[0], (h, nq * d * (2 if cfg.attn_output_gate
+                                                 else 1)), std, dtype),
+            "wk": _normal(keys[1], (h, nkv * d), std, dtype),
+            "wv": _normal(keys[2], (h, nkv * d), std, dtype),
+            "wo": _normal(keys[3], (nq * d, h), out_std, dtype),
+        }
+        if cfg.use_bias or cfg.qkv_bias:
+            attn["bq"] = jnp.zeros((nq * d,), dtype)
+            attn["bk"] = jnp.zeros((nkv * d,), dtype)
+            attn["bv"] = jnp.zeros((nkv * d,), dtype)
+        if cfg.use_bias:
+            attn["bo"] = jnp.zeros((h,), dtype)
+        if cfg.qk_norm:
+            attn["q_norm"] = norm_init(cfg.norm_type, d, dtype)
+            attn["k_norm"] = norm_init(cfg.norm_type, d, dtype)
+        layer["attn"] = attn
+    elif kind == "linear":
+        layer["gdn"] = init_gdn_params(keys[7], cfg)
+    elif kind == "mamba":
+        layer["mamba"] = mamba2.init_mamba_params(keys[7], cfg)
+    if kind in ("attention", "mamba"):
+        return layer                     # a mixer alone
 
     if cfg.num_experts > 0:
         from .moe import init_moe_params
@@ -116,15 +129,9 @@ def init_layer_params(key: jax.Array, cfg: ModelConfig,
                 mlp["b_gate"] = jnp.zeros((ffn,), dtype)
             mlp["b_up"] = jnp.zeros((ffn,), dtype)
             mlp["b_down"] = jnp.zeros((h,), dtype)
-
-    layer: Params = {
-        "input_norm": norm_init(cfg.norm_type, h, dtype),
-        "mlp": mlp,
-    }
-    if kind == "linear":
-        layer["gdn"] = init_gdn_params(keys[7], cfg)
-    else:
-        layer["attn"] = attn
+    layer["mlp"] = mlp
+    if kind == "mlp":
+        return layer                     # the feed-forward part alone
     if cfg.parallel_attn:
         if cfg.parallel_layernorm:
             # Falcon-40B: separate LN for the MLP branch
@@ -500,7 +507,12 @@ def layer_forward(cfg: ModelConfig, p: Params, x: jax.Array,
 
     ``layer_idx`` (global layer number, may be traced) drives the LIMA
     dropout ramp and per-layer drop-path rate; None → flat rates.
+
+    A block of one part (a hybrid stack's ``"attention"``, ``"mamba"``
+    and ``"mlp"`` kinds) is ``_one_part_forward``'s.
     """
+    if "mlp" not in p or ("attn" not in p and "gdn" not in p):
+        return _one_part_forward(cfg, p, x, side, layer_rng, kv_cache)
     if layer_idx is not None and (cfg.lima_dropout
                                   or cfg.drop_path_rate > 0.0):
         hidden_dropout, dp_rate = _layer_rates(cfg, layer_idx)
@@ -564,6 +576,36 @@ def layer_forward(cfg: ModelConfig, p: Params, x: jax.Array,
                                valid=side.valid)
         result = x + branch_drop(m, 3)
     result = seq_constrain(result, side.seq_shard_axes)
+    if kv_cache is not None:
+        return result, aux, new_cache
+    return result, aux
+
+
+def _one_part_forward(cfg: ModelConfig, p: Params, x: jax.Array,
+                      side: AttnSideInputs, layer_rng, kv_cache):
+    """A block of one part under one norm, ``x + f(norm(x))``: softmax
+    attention, a Mamba-2 mixer or the feed-forward part alone, by what
+    ``p`` holds.  Returns as ``layer_forward`` does; the feed-forward
+    part keeps no cache (``kv_cache`` None, two results)."""
+    h1 = norm_apply(cfg.norm_type, x, p["input_norm"], cfg.norm_eps,
+                    impl=cfg.norm_impl)
+    aux, new_cache = _aux_zero(cfg), None
+    if "mamba" in p:
+        # its "cache" is the state-space state, carried or (None) started
+        # at zero; the new one is dropped with no cache
+        out, new_cache = mamba2.mamba_block(cfg, p["mamba"], h1, kv_cache,
+                                            side.valid)
+    elif "attn" in p:
+        # attention computes in the model's own precision
+        h1 = h1.astype(cfg.dtype)
+        if kv_cache is not None:
+            out, new_cache = attention_block(cfg, p["attn"], h1, side,
+                                             layer_rng, kv_cache)
+        else:
+            out = attention_block(cfg, p["attn"], h1, side, layer_rng)
+    else:
+        out, aux = _mlp_dispatch(cfg, p["mlp"], h1, valid=side.valid)
+    result = x + out
     if kv_cache is not None:
         return result, aux, new_cache
     return result, aux
@@ -650,7 +692,7 @@ def _stack_forward_periods(cfg: ModelConfig, stacked, x, side, base_rng,
                            layer_offset):
     """``stack_forward`` for a hybrid stack: the scan runs over the
     periods, and its body runs one period's layers in order, each of the
-    kind its position has.  Every linear layer starts from a zero state
+    kind its position has.  Every recurrent mixer starts from a zero state
     and drops the one it ends with: a whole sequence, no cache.  (No
     rematerialisation: such a stack is served, not trained.)"""
     n_pos = len(cfg.layer_pattern)
@@ -675,20 +717,27 @@ def _stack_forward_periods(cfg: ModelConfig, stacked, x, side, base_rng,
 def scan_periods_cached(cfg: ModelConfig, stacked, x, side, kv_of, rec,
                         kv_xs: tuple = ()):
     """The cached forms of a hybrid stack, prefill and decode alike: a
-    scan over the periods whose body gives each ``"full"`` layer its
-    ``kv_cache`` (``kv_of(kv_layer, *slices of kv_xs)``, ``kv_layer``
-    counting the full layers alone: the KV cache's own layer axis) and
-    each ``"linear"`` layer its recurrent state out of ``rec`` (``{"S":
-    [linear layers, b, ...], "conv": [...]}``).
+    scan over the periods whose body gives each layer that attends
+    (``"full"``, ``"attention"``) its ``kv_cache`` (``kv_of(kv_layer,
+    *slices of kv_xs)``, ``kv_layer`` counting those layers alone: the KV
+    cache's own layer axis) and each recurrent mixer its state out of
+    ``rec`` (``models/model.py:init_rec_state``): a ``"linear"`` layer
+    ``{"S": [linear layers, b, ...], "conv": [...]}``, a ``"mamba"``
+    layer ``{"ssm": [mamba layers, b, ...], "ssm_conv": [...]}``.  The
+    delta-rule states go through the scan as its xs and ys; the
+    state-space states, thirteen times their size a layer, ride in the
+    carry and each layer reads and rewrites its own slice in place.
 
-    → ``(hidden, (rows_k, rows_v) stacked over the full layers, rec with
-    every state advanced over the positions ``side.valid`` marks, counts
+    → ``(hidden, (rows_k, rows_v) stacked over the attending layers, rec's
+    states advanced over the positions ``side.valid`` marks, counts
     ``{"load": [layers, router outputs], "rows": [layers, 2]}``: the
     experts those positions chose, and the (token, choice) rows each
-    layer's experts multiplied and skipped)``."""
+    layer's experts multiplied and skipped; zero for a layer without
+    experts)``."""
     kinds = cfg.layer_pattern
     n_per = cfg.num_layers // len(kinds)
-    n_full, n_lin = kinds.count("full"), kinds.count("linear")
+    n_full = sum(kind in ("full", "attention") for kind in kinds)
+    n_lin, n_mam = kinds.count("linear"), kinds.count("mamba")
     x = x.astype(STREAM_DTYPE)
 
     def by_period(a, n):
@@ -696,36 +745,51 @@ def scan_periods_cached(cfg: ModelConfig, stacked, x, side, kv_of, rec,
 
     xs = (tuple(stacked), tuple(by_period(a, n_full) for a in kv_xs),
           jax.tree.map(lambda a: by_period(a, n_lin),
-                       {"S": rec["S"], "conv": rec["conv"]}))
+                       {name: rec[name] for name in ("S", "conv")
+                        if n_lin}))
+    ssm = {name: rec[name] for name in mamba2.STATE_NAMES if n_mam}
 
     def body(carry, inp):
-        h, idx = carry
+        h, idx, ssm = carry
         period, kv_p, rec_p = inp
-        rows, states, counts, f, l = [], [], [], 0, 0
+        rows, states, counts, f, l, m = [], [], [], 0, 0, 0
         for layer_params, kind in zip(period, kinds):
-            if kind == "full":
+            attends, cache = kind in ("full", "attention"), None
+            if attends:
                 cache = kv_of(idx * n_full + f, *(a[f] for a in kv_p))
                 f += 1
-            else:
+            elif kind == "linear":
                 cache = GDNState(rec_p["S"][l], rec_p["conv"][l])
                 l += 1
-            h, aux, new = layer_forward(cfg, layer_params, h, side, None,
-                                        kv_cache=cache)
-            (rows if kind == "full" else states).append(new)
+            elif kind == "mamba":
+                at = idx * n_mam + m
+                cache = mamba2.state_at(ssm, at)
+                m += 1
+            h, aux, *new = layer_forward(cfg, layer_params, h, side, None,
+                                         kv_cache=cache)
+            if attends:
+                rows += new
+            elif kind == "linear":
+                states += new
+            elif kind == "mamba":
+                ssm = mamba2.write_back(ssm, *new, at, h.shape[1] == 1)
             counts.append(
                 {name: aux[name] for name in ("load", "rows")}
                 if isinstance(aux, dict) else
                 {"load": jnp.zeros((0,), jnp.float32),
                  "rows": jnp.zeros((2,), jnp.float32)})
-        stack = lambda xs_: jax.tree.map(lambda *a: jnp.stack(a), *xs_)
-        return (h, idx + 1), (stack(rows), stack(states), stack(counts))
+        stack = lambda xs_: jax.tree.map(lambda *a: jnp.stack(a), *xs_) \
+            if xs_ else ()
+        return (h, idx + 1, ssm), (stack(rows), stack(states),
+                                   stack(counts))
 
-    (x, _), (rows, states, counts) = jax.lax.scan(
-        body, (x, jnp.int32(0)), xs)
+    (x, _, ssm), (rows, states, counts) = jax.lax.scan(
+        body, (x, jnp.int32(0), ssm), xs)
     flat = lambda a: a.reshape((a.shape[0] * a.shape[1],) + a.shape[2:])
     rows = jax.tree.map(flat, rows)
-    return x, rows, {"S": flat(states.S), "conv": flat(states.conv)}, \
-        jax.tree.map(flat, counts)
+    if n_lin:
+        ssm = {"S": flat(states.S), "conv": flat(states.conv), **ssm}
+    return x, rows, ssm, jax.tree.map(flat, counts)
 
 
 def _scan_layers_cached(cfg: ModelConfig, stacked: Params, x: jax.Array,

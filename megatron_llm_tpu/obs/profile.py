@@ -13,7 +13,10 @@ server's ``POST /profile`` and the benchmark's traced phase all call
   plane and ``to_trace_ns`` maps any ``perf_counter`` time (a
   ``TraceRecorder`` span, a log event) onto the trace's clock;
 * switches on the recorders registered with ``while_profiling`` (the
-  train loop's, ``obs.trace.TRAIN_TRACE``) and restores them at the stop.
+  train loop's, ``obs.trace.TRAIN_TRACE``, and every serving engine's)
+  and restores them at the stop; it keeps them (``Session.recorders``),
+  so that a reader of the profile finds the spans, arguments and all,
+  that lie on its clock.
 
 A running job is asked for a trace through ``request_steps``: the train
 loop polls ``take_step_request`` at the top of every iteration.  Nothing
@@ -25,6 +28,7 @@ from __future__ import annotations
 import dataclasses
 import threading
 import time
+import weakref
 from typing import List, Optional, Tuple
 
 SYNC_NAME = "obs_clock_sync"
@@ -44,7 +48,11 @@ DEVICE_SCOPES = (
     # "moe_experts")
     "gdn", "gdn_proj", "gdn_conv", "gdn_scan", "gdn_step",
     "moe_router", "moe_dispatch", "moe_experts", "moe_shared",
-    "grouped_experts")
+    "grouped_experts",
+    # the Mamba-2 mixer and its parts (models/mamba2.py), and the
+    # projections into and out of the experts' latent
+    "mamba", "mamba_proj", "mamba_conv", "mamba_scan", "mamba_step",
+    "moe_latent")
 
 
 @dataclasses.dataclass
@@ -52,6 +60,7 @@ class Session:
     dir: str
     t_sync: float                    # perf_counter inside the annotation
     t_stop: Optional[float] = None   # perf_counter when recording ended
+    recorders: tuple = ()            # the recorders it switched on
 
     def clock_sync(self) -> dict:
         """What ``TraceRecorder.chrome_trace()`` exports of the session."""
@@ -71,7 +80,8 @@ class StepRequest:
 _lock = threading.Lock()
 _active: Optional[Session] = None
 _last: Optional[Session] = None
-_recorders: List[object] = []          # on only while a session is active
+# on while a session is active; held weakly: an engine's goes with it
+_recorders: "weakref.WeakSet" = weakref.WeakSet()
 _restore: List[Tuple[object, bool]] = []
 _step_request: Optional[StepRequest] = None
 
@@ -80,8 +90,7 @@ def while_profiling(recorder) -> None:
     """Register a recorder (anything with ``enabled``) that a session
     switches on and its stop puts back as it was."""
     with _lock:
-        if recorder not in _recorders:
-            _recorders.append(recorder)
+        _recorders.add(recorder)
 
 
 def active() -> Optional[Session]:
@@ -110,8 +119,8 @@ def start(dir: str) -> Session:  # noqa: A002 — the profiler's own word
             jax.profiler.start_trace(str(dir))
         with jax.profiler.TraceAnnotation(SYNC_NAME):
             t_sync = time.perf_counter()
-        _active = Session(str(dir), t_sync)
-        for rec in _recorders:
+        _active = Session(str(dir), t_sync, recorders=tuple(_recorders))
+        for rec in _active.recorders:
             _restore.append((rec, rec.enabled))
             rec.enabled = True
         return _active

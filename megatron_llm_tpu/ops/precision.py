@@ -7,6 +7,12 @@ import jax
 import jax.numpy as jnp
 
 
+def dot_rounded(x: jax.Array, w: jax.Array) -> jax.Array:
+    """``x @ w`` → float32 in one pass: ``x`` rounded to the weight's
+    precision, float32 accumulation."""
+    return jnp.dot(x.astype(w.dtype), w, preferred_element_type=jnp.float32)
+
+
 def dot_f32(x: jax.Array, w: jax.Array) -> jax.Array:
     """``x @ w`` → float32 for a float32 ``x`` and a weight in a lower
     precision, in two passes of the weight's precision: ``x`` rounded to

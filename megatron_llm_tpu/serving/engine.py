@@ -131,6 +131,7 @@ from ..config import ModelConfig
 from ..generation.sampling import NEG_INF
 from ..models import model as model_lib
 from ..obs import compile as obs_compile
+from ..obs import profile as obs_profile
 from ..obs.logging import EVENT_LOG
 from ..obs.trace import TraceRecorder, device_annotation
 from ..ops.lora import arena_sr, slot_mask
@@ -964,14 +965,15 @@ class _PrefillState:
 def _refuse_for_hybrid(cfg: ModelConfig, config: EngineConfig, mesh,
                        draft_cfg, adapters) -> None:
     """What the engine cannot do for a hybrid stack (``cfg.layer_pattern``:
-    a recurrent state a slot beside its K/V blocks), refused at
+    a recurrent mixer's state a slot beside its K/V blocks, whatever the
+    mixer: a delta rule's or a state-space layer's), refused at
     construction so that none of it is served wrongly.  Each of these
     paths moves, shares or rolls back K/V blocks alone."""
     refused = [
         (config.prefix_cache_blocks,
          "prefix_cache_blocks > 0: a prefix hit would need a snapshot of "
          "the recurrent state at the shared boundary, and a hit on K/V "
-         "alone would start the linear layers from zero mid-prompt"),
+         "alone would start the recurrent mixers from zero mid-prompt"),
         (config.spec_draft_len or draft_cfg is not None,
          "speculation (spec_draft_len, a draft model): a rejected draft "
          "rolls back by fill arithmetic, and the recurrent state has "
@@ -982,7 +984,8 @@ def _refuse_for_hybrid(cfg: ModelConfig, config: EngineConfig, mesh,
          "a tp/pp serving mesh: serving_param_specs has no layout for "
          "the period-stacked layers, the experts or the recurrent state"),
         (adapters is not None or config.adapter_cache_slots,
-         "adapters: no LoRA epilogue on the DeltaNet or expert matmuls"),
+         "adapters: no LoRA epilogue on a recurrent mixer's or the "
+         "experts' matmuls"),
         (cfg.fused_decode,
          "fused_decode=True: the whole-stack decode kernel runs dense "
          "RMSNorm+GLU attention layers only"),
@@ -1076,6 +1079,9 @@ class ServingEngine:
         self.metrics.set_gauges(num_slots=self.config.max_batch_size)
         self.trace = TraceRecorder(capacity=self.config.trace_capacity,
                                    enabled=self.config.trace)
+        # a profile session keeps this recorder: its readers join the
+        # spans (prompt lengths, live slots) with the device's operations
+        obs_profile.while_profiling(self.trace)
         # a compile inside a span of this recorder is exported with it
         obs_compile.install()
         self.queue = RequestQueue(self.config.max_queue_size,
@@ -1165,6 +1171,13 @@ class ServingEngine:
         # spans (kernels/grouped_matmul.py; no such field elsewhere)
         self._experts_arg = ({"experts": "grouped"} if cfg.moe_dropless
                              else {})
+        # a hybrid stack's kinds of slot state, on its prefill and decode
+        # spans ("linear", "mamba", "linear+mamba"; no such field for a
+        # one-kind stack), and whether a state-space layer is among them
+        kinds = [kind for kind, n in (("linear", cfg.linear_layers),
+                                      ("mamba", cfg.mamba_layers)) if n]
+        self._state_arg = {"state_kinds": "+".join(kinds)} if kinds else {}
+        self._counts_ssm = cfg.mamba_layers > 0
         self._admit_count = 0        # this iteration's admissions
         self._admit_tokens = 0       # and their prompt tokens
         # whether forward_cached routes this config's slot batch through
@@ -1296,6 +1309,7 @@ class ServingEngine:
                         rec_state_bytes=self.slots.rec_state_bytes,
                         rec_state_slots=cfg_e.max_batch_size)
                     self.metrics.expert_load = self.expert_load
+                    self.metrics.expert_layers = self.cfg.moe_layer_ids
                     self.metrics.expert_rows = self.expert_rows
                 if self._sanitize:
                     self._sanitizer = sanitizers.LedgerSanitizer()
@@ -2075,7 +2089,10 @@ class ServingEngine:
                        request_id=req.rid, tid=req.id,
                        args={"prompt_len": plen, "padded": padded,
                              "cached_tokens": lease.tokens if lease else 0,
-                             "iter": self._iter, **self._experts_arg})
+                             "iter": self._iter, **self._experts_arg,
+                             **self._state_arg})
+        if self._counts_ssm:
+            self.metrics.add_ssm_positions("prefill", plen)
         self._admit_count += 1
         self._admit_tokens += plen
         self.metrics.inc("admitted")
@@ -2905,6 +2922,8 @@ class ServingEngine:
                 toks.append(tok)
                 tok_lps.append(tok_lp)
         self.slots.set_pools(k_pool, v_pool, rec)
+        if self._counts_ssm:
+            self.metrics.add_ssm_positions("decode", len(self._active))
         try:  # start the host copies now so they overlap the next dispatch
             for tok, tok_lp in zip(toks, tok_lps):
                 tok.copy_to_host_async()
@@ -2960,7 +2979,9 @@ class ServingEngine:
                 self.trace.add("decode", step.t_dispatch, t_ready,
                                request_id=st.req.rid, tid=st.req.id,
                                args={"slot": slot, "iter": self._iter,
-                                     "token_index": len(st.req.generated)})
+                                     "token_index": len(st.req.generated),
+                                     "live": len(step.slots),
+                                     **self._state_arg})
             # tpulint: allow[host-sync] tok_lp is host numpy; no device
             # round-trip
             self._commit_token(slot, st.pending, float(tok_lp[slot]))

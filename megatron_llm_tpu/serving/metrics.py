@@ -250,15 +250,23 @@ class ServingMetrics:
         self.host_blocks_free = 0
         self.resume_latency = LatencyHistogram()
         # hybrid stacks (serving/slots.py): bytes of per-slot recurrent
-        # and convolution state beside the pool, and the slots it is for
-        self.rec_state_bytes = 0
+        # and convolution state beside the pool, by the block kind that
+        # keeps it ("linear", "mamba"), and the slots it is for
+        self.rec_state_bytes: dict = {}
         self.rec_state_slots = 0
+        # positions the state-space (Mamba-2) layers advanced their
+        # states over, by phase: a prompt's length at its prefill, the
+        # live slots of every decode step
+        self.ssm_positions = {"prefill": 0, "decode": 0}
         # per-layer, per-expert assignment counts, carried on the device
         # in the step's own state: ``expert_load`` (set by the engine)
         # fetches them → (counts [layers, router outputs], first held
         # expert, held experts), and is called only when somebody asks
         # (``snapshot``, ``collect``), never by a step
         self.expert_load = None
+        # the layers that route (a hybrid stack's mixer-alone layers do
+        # not): the rows of ``expert_load``'s counts that are reported
+        self.expert_layers: tuple = ()
         # ``expert_rows`` likewise: the rows the experts' kernel
         # multiplied and skipped
         self.expert_rows = None
@@ -299,6 +307,10 @@ class ServingMetrics:
                 precision, {"fused": 0, "paged": 0, "fallback": 0})
             r[route] += 1
 
+    def add_ssm_positions(self, phase: str, n: int) -> None:
+        with self._lock:
+            self.ssm_positions[phase] += n
+
     def set_gauges(self, *, slots_active: Optional[int] = None,
                    queue_depth: Optional[int] = None,
                    prefix_blocks: Optional[int] = None,
@@ -310,7 +322,7 @@ class ServingMetrics:
                    adapter_resident_bytes: Optional[int] = None,
                    host_blocks_used: Optional[int] = None,
                    host_blocks_free: Optional[int] = None,
-                   rec_state_bytes: Optional[int] = None,
+                   rec_state_bytes: Optional[dict] = None,
                    rec_state_slots: Optional[int] = None) -> None:
         with self._lock:
             if rec_state_bytes is not None:
@@ -458,8 +470,10 @@ class ServingMetrics:
                 "host_blocks_free": self.host_blocks_free,
                 "resume_latency": self.resume_latency.snapshot(),
                 # hybrid stacks: per-slot recurrent state beside the pool
-                "rec_state_bytes": self.rec_state_bytes,
+                "rec_state_bytes": sum(self.rec_state_bytes.values()),
+                "rec_state_bytes_by_kind": dict(self.rec_state_bytes),
                 "rec_state_slots": self.rec_state_slots,
+                "ssm_positions": dict(self.ssm_positions),
                 # speculative decoding (histogram samples are token
                 # counts per participating slot per verify step)
                 "spec_acceptance_rate": (
@@ -491,7 +505,8 @@ class ServingMetrics:
         load = self._held_expert_load()
         if load is not None:
             counts, lo, held = load
-            here = counts[:, lo:lo + held].astype(float)
+            here = counts[list(self.expert_layers),
+                          lo:lo + held].astype(float)
             out["expert_load"] = {
                 "assignments": int(counts.sum()),
                 "held_share": float(here.sum() / max(1, counts.sum())),
@@ -605,9 +620,6 @@ class ServingMetrics:
                      "host-RAM tier KV blocks in use", self.host_blocks_used),
                     ("serving_host_blocks_free",
                      "host-RAM tier KV blocks free", self.host_blocks_free),
-                    ("serving_rec_state_bytes",
-                     "bytes of per-slot recurrent and convolution state",
-                     self.rec_state_bytes),
                     ("serving_rec_state_slots",
                      "slots that keep a recurrent state beside their KV",
                      self.rec_state_slots),
@@ -616,6 +628,21 @@ class ServingMetrics:
                      self.counters["spec_accepted"]
                      / max(1, self.counters["spec_proposed"]))):
                 fams.append(MetricFamily(gname, "gauge", help_).add(value))
+            fam = MetricFamily(
+                "serving_rec_state_bytes", "gauge",
+                "bytes of per-slot recurrent and convolution state, by "
+                "the block kind that keeps it (a one-kind stack: 0)")
+            for kind, n in sorted(self.rec_state_bytes.items()):
+                fam.add(n, labels={"kind": kind})
+            fams.append(fam if self.rec_state_bytes else fam.add(0))
+            if "mamba" in self.rec_state_bytes:
+                fam = MetricFamily(
+                    "serving_ssm_positions_total", "counter",
+                    "positions the state-space layers advanced their "
+                    "states over, by phase")
+                for phase, n in sorted(self.ssm_positions.items()):
+                    fam.add(n, labels={"phase": phase})
+                fams.append(fam)
             for attr, pname, help_ in _PROM_SUMMARIES:
                 hist: LatencyHistogram = getattr(self, attr)
                 fams.append(summary_family(
@@ -629,8 +656,8 @@ class ServingMetrics:
                 "serving_expert_assignments_total", "counter",
                 "times a layer's router chose an expert (held: this "
                 "engine's parameter tree holds it)")
-            for layer, row in enumerate(counts):
-                for e, n in enumerate(row):
+            for layer in self.expert_layers:
+                for e, n in enumerate(counts[layer]):
                     fam.add(float(n), labels={
                         "layer": str(layer), "expert": str(e),
                         "held": str(int(lo <= e < lo + held))})

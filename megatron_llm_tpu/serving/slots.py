@@ -15,7 +15,7 @@ Memory therefore scales with actual fill, not ``max_seq_len``: a
 and blocks shared with the prefix cache appear in many tables at once
 under ref counting — retirement decrements refs instead of copying rows.
 
-A hybrid stack (``cfg.layer_pattern``) keeps a second kind of state a
+A hybrid stack (``cfg.layer_pattern``) keeps further kinds of state a
 slot, ``SlotAllocator.rec``: fixed-size, slot-indexed, beside the pool
 (see ``__init__``); ``insert`` installs it with the prefill's K/V.
 
@@ -66,8 +66,7 @@ def _install_rec_impl(rec, one, slot):
     """Replace slot ``slot``'s recurrent state by ``one``'s (batch 1: what
     a prefill ended with) and add the experts that prefill counted."""
     out = model_lib.cache_slot_update(
-        {"S": rec["S"], "conv": rec["conv"]},
-        {"S": one["S"], "conv": one["conv"]}, slot)
+        model_lib.rec_states(rec), model_lib.rec_states(one), slot)
     return {**out, "load": rec["load"] + one["load"],
             "rows": model_lib.add_rows(rec["rows"], one["rows"])}
 
@@ -104,9 +103,10 @@ class SlotAllocator:
         self._free = list(range(num_slots - 1, -1, -1))  # pop() -> slot 0 first
         self._insert = (_insert_plain if jax.default_backend() == "cpu"
                         else _insert_donated)
-        # A hybrid stack's second kind of slot state (cfg.layer_pattern;
-        # models/model.py:init_rec_state): for every linear layer a
-        # fixed-size recurrent state and convolution tail a SLOT, indexed
+        # A hybrid stack's other kinds of slot state (cfg.layer_pattern;
+        # models/model.py:init_rec_state): for every recurrent mixer (a
+        # Gated DeltaNet layer, a Mamba-2 layer: each kind under names of
+        # its own) a fixed-size state and convolution tail a SLOT, indexed
         # by slot and not paged, allocated once beside the pool.  A slot's
         # row is replaced whole when a prefill is installed (``insert``),
         # advanced by every decode step the slot is live in, and dead
@@ -186,9 +186,14 @@ class SlotAllocator:
 
     # -- admission ------------------------------------------------------
     @property
-    def rec_state_bytes(self) -> int:
-        return 0 if self.rec is None else sum(
-            int(a.nbytes) for a in (self.rec["S"], self.rec["conv"]))
+    def rec_state_bytes(self) -> dict:
+        """Bytes of per-slot recurrent state, by the block kind that
+        keeps it (``model.REC_STATE_KINDS``); {} for a one-kind stack."""
+        if self.rec is None:
+            return {}
+        return {kind: sum(int(self.rec[name].nbytes) for name in names)
+                for kind, names in model_lib.REC_STATE_KINDS.items()
+                if names[0] in self.rec}
 
     def insert(self, slot: int, k_small, v_small, n_tokens: int,
                shared_bids: Sequence[int] = (), rec_small=None) -> None:

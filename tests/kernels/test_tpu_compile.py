@@ -297,6 +297,71 @@ def test_grouped_mlp(topo, tokens):
     assert 12 * 2 ** 20 < int(vmem) <= 64 * 2 ** 20, vmem
 
 
+@pytest.mark.parametrize("tokens", [2048, 128], ids=["prompt", "step"])
+def test_grouped_mlp_of_two_matrices_in_a_latent(topo, tokens):
+    """The same kernel at Nemotron-3-Super's widths: 128 held experts of
+    two matrices (1024 x 2688, relu^2 between: ``w_gate=None``) in the
+    1024-wide latent, 22 choices a token: a 2048-token bucket's 45 056
+    pairs, a 128-slot decode step's 2816.  A row is one (8, 128) tile."""
+    from megatron_llm_tpu.ops.activations import squared_relu
+
+    one = SingleDeviceSharding(topo.devices[0])
+    E, h, f, k = 128, 1024, 2688, 22
+    i32 = jnp.int32
+    text = _compile(
+        lambda x, order, sizes, *w: grouped_mlp(
+            x, order, sizes, None, *w, squared_relu, choices=k,
+            interpret=False),
+        (_sds((tokens, h), jnp.float32), _sds((tokens * k,), i32),
+         _sds((E,), i32), _sds((E, h, f)), _sds((E, f, h))), one)
+    assert f"f32[{tokens * k * 8},128]" in text         # rows as they lie
+    assert not relayout_bytes(text, min_bytes=2 * E * h * f)
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
+def test_a_state_space_decode_step_rewrites_its_states_in_place(
+        topo, monkeypatch):
+    """The engine's decode executable for Nemotron-3-Super's 11-layer run
+    at the published widths and 128 slots: 12.6 GB of arguments (weights,
+    pool, 2.7 GB of state-space states), every donated byte aliased to an
+    output and under 0.3 GB of temporaries: each Mamba-2 layer reads its
+    slice of the stacked states and writes it back where it lies.  A
+    second copy of the states would not fit the chip."""
+    from megatron_llm_tpu.config import nemotron_h_config
+    from megatron_llm_tpu.serving import engine as engine_lib
+
+    monkeypatch.setattr(attn_ops, "_backend", lambda: "tpu")
+    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
+    one = SingleDeviceSharding(topo.devices[0])
+    S, blocks, bk = 128, 32, 128
+    cfg = nemotron_h_config("3-super-120b-a12b-ep4-rank0", num_layers=11,
+                            attention_impl="flash")
+    place = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
+    params = jax.eval_shape(
+        lambda key: model_lib.init_params(key, cfg), jax.random.key(0))
+    pool = jax.eval_shape(
+        lambda: model_lib.init_kv_pool(cfg, S * blocks + 1, bk))
+    rec = jax.eval_shape(lambda: model_lib.init_rec_state(cfg, S))
+    assert rec["ssm"].shape == (5, S, 128, 64, 128)
+    i32, f32 = jnp.int32, jnp.float32
+    vec = lambda dtype: place(_sds((S,), dtype))  # noqa: E731
+    compiled = engine_lib._decode_donated.lower(
+        cfg, place(params), *place(pool), place(_sds((S, blocks), i32)),
+        vec(i32), vec(i32), vec(jnp.uint32), vec(i32), vec(bool), vec(f32),
+        vec(i32), vec(f32), use_fused=False, rec=place(rec),
+        live=vec(bool)).compile()
+    mem = compiled.memory_analysis()
+    donated = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree.leaves((pool, rec)))
+    assert 12.4e9 < mem.argument_size_in_bytes < 12.8e9
+    # (small arrays are padded to whole tiles)
+    assert donated <= mem.alias_size_in_bytes < donated + 2 ** 20
+    assert mem.temp_size_in_bytes < 0.3e9
+    text = compiled.as_text()
+    assert text.count("flash_decode") and text.count("grouped_experts")
+
+
 def test_a_dropless_prefill_routes_through_the_grouped_kernel(topo,
                                                               monkeypatch):
     """The engine's prefill executable for one period of Qwen3-Next at the
