@@ -13,15 +13,16 @@ a group, ``a`` and ``dt`` scalars a head.  The output is gated by
 ``SiLU(z)``, RMS-normalised over each group's channels and projected back.
 
 Two forms of the same recurrence: ``ssd_step`` for the one new position
-of a decode step, elementwise over the state (a step reads and writes
-every live slot's state once: it is bound by that traffic, not by
-arithmetic), and ``ssd_chunked`` for a prompt, which rearranges ``chunk``
+of a decode step (the kernel ``kernels/mamba_step.py``: a step reads and
+writes every live slot's state once, where it lies in the stacked states
+of the serving tree, and is bound by that traffic, not by arithmetic),
+and ``ssd_chunked`` for a prompt, which rearranges ``chunk``
 positions at a time into matrix products (the paper's state-space
 duality, section 6): inside a chunk ``(C B^T . L)(dt x)`` with ``L`` the
 lower-triangular products of ``a``, the chunk's own end state from
 ``B^T (dt x)`` decayed to the chunk's end, ``C S_prev`` decayed for what
-the earlier chunks left, and the state handed from chunk to chunk.  Plain
-``jax.numpy``: no kernel yet.  The state, the decays and everything after
+the earlier chunks left, and the state handed from chunk to chunk: plain
+``jax.numpy``.  The state, the decays and everything after
 the input projection are float32.  A position whose ``valid`` is false
 (the padded tail of a prefill bucket, a decode step's free slot) has
 ``dt = 0``: ``a = 1`` and nothing is added, so it changes neither ``S``
@@ -41,6 +42,7 @@ import jax
 import jax.numpy as jnp
 
 from ..config import ModelConfig
+from ..kernels.mamba_step import mamba_step
 from ..ops.precision import dot_rounded
 
 Params = dict
@@ -58,10 +60,15 @@ STATE_NAMES = ("ssm", "ssm_conv")
 class MambaState(NamedTuple):
     """What a Mamba-2 layer keeps of a sequence: ``S`` [b, heads, head
     width, state width], and ``conv`` [b, taps - 1, channels]: the
-    convolution's last inputs; both float32."""
+    convolution's last inputs; both float32.  With ``at`` (an int32
+    scalar, may be traced) ``S`` is the stacked states of all the layers,
+    [layers, b, ...], and this layer's is ``S[at]``: how a decode step
+    hands them through, since its kernel advances the layer where it
+    lies."""
 
     S: jax.Array
     conv: jax.Array
+    at: Optional[jax.Array] = None
 
 
 def dims(cfg: ModelConfig):
@@ -111,36 +118,45 @@ def init_state(cfg: ModelConfig, batch: int) -> MambaState:
                                 jnp.float32))
 
 
-def state_at(stacked: dict, at) -> MambaState:
+def state_at(stacked: dict, at, one_position: bool) -> MambaState:
     """Layer ``at`` of the stacked states ``{"ssm": [layers, b, ...],
-    "ssm_conv": [...]}`` (``models/model.py:init_rec_state``)."""
-    return MambaState(*(
-        jax.lax.dynamic_index_in_dim(stacked[name], at, 0, keepdims=False)
-        for name in STATE_NAMES))
+    "ssm_conv": [...]}`` (``models/model.py:init_rec_state``); for one
+    position the states stay stacked (``MambaState.at``)."""
+    S, conv = (stacked[name] for name in STATE_NAMES)
+    conv = jax.lax.dynamic_index_in_dim(conv, at, 0, keepdims=False)
+    if one_position:
+        return MambaState(S, conv, at)
+    return MambaState(
+        jax.lax.dynamic_index_in_dim(S, at, 0, keepdims=False), conv)
 
 
-def write_back(stacked: dict, new: MambaState, at, one_position: bool) -> dict:
-    """``new`` as layer ``at`` of the stacked states, in place (XLA fuses
-    the update into the write, whose operation is this one: so it stands
-    under the scope of the form that made the state)."""
+def write_back(stacked: dict, new: MambaState, at) -> dict:
+    """``new`` as layer ``at`` of the stacked states, in place: the tail,
+    and a prompt's end state (XLA fuses the update into the write, whose
+    operation is this one: so it stands under the scope of the form that
+    made the state).  One position's kernel has written its layer into
+    the stacked states already (``new.at``)."""
+    one_position = new.at is not None
     with jax.named_scope("mamba"), jax.named_scope(
             "mamba_step" if one_position else "mamba_scan"):
-        return {name: jax.lax.dynamic_update_index_in_dim(
+        put = lambda name, a: jax.lax.dynamic_update_index_in_dim(  # noqa: E731
             stacked[name], a, at, 0)
-            for name, a in zip(STATE_NAMES, new)}
+        S, conv = STATE_NAMES
+        return {S: new.S if one_position else put(S, new.S),
+                conv: put(conv, new.conv)}
 
 
 @jax.named_scope("mamba_step")
-def ssd_step(x, B, C, dt, A, S):
+def ssd_step(x, B, C, dt, A, S, at=None):
     """One position.  ``x`` [b, H, P], ``B C`` [b, G, N], ``dt`` [b, H],
-    ``A`` [H] (negative), ``S`` [b, H, P, N], all float32 → ``(y [b, H,
-    P], S)``.  Elementwise over the state: read once, written once."""
-    b, H, _P = x.shape
-    G = B.shape[1]
-    per = lambda a: jnp.repeat(a, H // G, axis=1)[:, :, None, :]  # noqa: E731
-    S = (S * jnp.exp(dt * A)[..., None, None]
-         + (dt[..., None] * x)[..., None] * per(B))
-    return jnp.sum(S * per(C), axis=-1), S
+    ``A`` [H] (negative), ``S`` [b, H, P, N], or with ``at`` the stacked
+    [layers, b, H, P, N] of which layer ``at`` is advanced and the others
+    are left as they lie; all float32 → ``(y [b, H, P], S)``.  One pass:
+    the state is read once and written once (``kernels/mamba_step.py``)."""
+    if at is not None:
+        return mamba_step(x, B, C, dt, A, S, at)
+    y, S = mamba_step(x, B, C, dt, A, S[None], jnp.int32(0))
+    return y, S[0]
 
 
 @jax.named_scope("mamba_scan")
@@ -222,7 +238,8 @@ def mamba_block(cfg: ModelConfig, p: Params, x: jax.Array,
     dt = jax.nn.softplus(dt + p["dt_bias"]) * valid[..., None]
     A = -jnp.exp(p["A_log"])
     if s == 1:
-        y, S = ssd_step(xs[:, 0], B[:, 0], C[:, 0], dt[:, 0], A, state.S)
+        y, S = ssd_step(xs[:, 0], B[:, 0], C[:, 0], dt[:, 0], A, state.S,
+                        state.at)
         y = y[:, None]
     else:
         pad = -s % cfg.mamba_chunk_size    # padded positions: dt = 0
@@ -230,6 +247,7 @@ def mamba_block(cfg: ModelConfig, p: Params, x: jax.Array,
         def padded(a):
             return jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2))
 
+        assert state.at is None, "a prompt takes one layer's state"
         y, S = ssd_chunked(*map(padded, (xs, B, C, dt)), A, state.S,
                            cfg.mamba_chunk_size)
         y = y[:, :s]
@@ -241,4 +259,4 @@ def mamba_block(cfg: ModelConfig, p: Params, x: jax.Array,
     y = y.reshape(b, s, di) * p["norm"]["scale"].astype(jnp.float32)
     with jax.named_scope("mamba_proj"):
         out = dot_rounded(y, p["w_out"]).astype(x.dtype)
-    return out, MambaState(S, conv)
+    return out, MambaState(S, conv, state.at)

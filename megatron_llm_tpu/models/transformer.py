@@ -726,7 +726,9 @@ def scan_periods_cached(cfg: ModelConfig, stacked, x, side, kv_of, rec,
     layer ``{"ssm": [mamba layers, b, ...], "ssm_conv": [...]}``.  The
     delta-rule states go through the scan as its xs and ys; the
     state-space states, thirteen times their size a layer, ride in the
-    carry and each layer reads and rewrites its own slice in place.
+    carry: a prompt's layer reads and rewrites its own slice in place, a
+    decode step's kernel takes them stacked and advances its layer where
+    it lies (``models/mamba2.py:MambaState.at``).
 
     → ``(hidden, (rows_k, rows_v) stacked over the attending layers, rec's
     states advanced over the positions ``side.valid`` marks, counts
@@ -763,7 +765,7 @@ def scan_periods_cached(cfg: ModelConfig, stacked, x, side, kv_of, rec,
                 l += 1
             elif kind == "mamba":
                 at = idx * n_mam + m
-                cache = mamba2.state_at(ssm, at)
+                cache = mamba2.state_at(ssm, at, h.shape[1] == 1)
                 m += 1
             h, aux, *new = layer_forward(cfg, layer_params, h, side, None,
                                          kv_cache=cache)
@@ -772,7 +774,7 @@ def scan_periods_cached(cfg: ModelConfig, stacked, x, side, kv_of, rec,
             elif kind == "linear":
                 states += new
             elif kind == "mamba":
-                ssm = mamba2.write_back(ssm, *new, at, h.shape[1] == 1)
+                ssm = mamba2.write_back(ssm, new[0], at)
             counts.append(
                 {name: aux[name] for name in ("load", "rows")}
                 if isinstance(aux, dict) else

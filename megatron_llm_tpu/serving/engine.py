@@ -1178,6 +1178,11 @@ class ServingEngine:
                                       ("mamba", cfg.mamba_layers)) if n]
         self._state_arg = {"state_kinds": "+".join(kinds)} if kinds else {}
         self._counts_ssm = cfg.mamba_layers > 0
+        # how a step's state-space layers ran, on its decode spans: each
+        # advanced where it lies in the stacked states by one kernel
+        # (kernels/mamba_step.py; no such field for a stack without them)
+        self._step_arg = dict(self._state_arg, **(
+            {"ssm_step": "fused"} if self._counts_ssm else {}))
         self._admit_count = 0        # this iteration's admissions
         self._admit_tokens = 0       # and their prompt tokens
         # whether forward_cached routes this config's slot batch through
@@ -2981,7 +2986,7 @@ class ServingEngine:
                                args={"slot": slot, "iter": self._iter,
                                      "token_index": len(st.req.generated),
                                      "live": len(step.slots),
-                                     **self._state_arg})
+                                     **self._step_arg})
             # tpulint: allow[host-sync] tok_lp is host numpy; no device
             # round-trip
             self._commit_token(slot, st.pending, float(tok_lp[slot]))

@@ -37,6 +37,7 @@ from megatron_llm_tpu.kernels.gdn_scan import gdn_scan  # noqa: E402
 from megatron_llm_tpu.kernels.grouped_matmul import (  # noqa: E402
     grouped_mlp,
 )
+from megatron_llm_tpu.kernels.mamba_step import mamba_step  # noqa: E402
 from megatron_llm_tpu.kernels.rmsnorm import (  # noqa: E402
     layernorm_pallas,
     rmsnorm_pallas,
@@ -319,14 +320,39 @@ def test_grouped_mlp_of_two_matrices_in_a_latent(topo, tokens):
     assert text.count('custom_call_target="tpu_custom_call"') == 1
 
 
+def test_mamba_step(topo):
+    """The state-space decode step's kernel alone at Nemotron-3-Super's
+    widths: 128 slots of 128 heads x 64 in 8 groups, state width 128, the
+    five layers' states stacked and donated, the layer a traced scalar.
+    The 2.7 GB are aliased to the output and nothing of their size is
+    made beside them."""
+    one = SingleDeviceSharding(topo.devices[0])
+    L, b, H, P, N, G = 5, 128, 128, 64, 128, 8
+    f32 = jnp.float32
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one) for s, d in (
+        ((b, H, P), f32), ((b, G, N), f32), ((b, G, N), f32), ((b, H), f32),
+        ((H,), f32), ((L, b, H, P, N), f32), ((1,), jnp.int32))]
+    compiled = jax.jit(
+        lambda x, B, C, dt, A, ssm, at: mamba_step(
+            x, B, C, dt, A, ssm, at[0], interpret=False),
+        donate_argnums=5).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 4 * L * b * H * P * N
+    assert mem.temp_size_in_bytes < 2 ** 20
+    _no_copy_of(text, f"f32[{L},{b},{H},{P},{N}]")
+
+
 def test_a_state_space_decode_step_rewrites_its_states_in_place(
         topo, monkeypatch):
     """The engine's decode executable for Nemotron-3-Super's 11-layer run
     at the published widths and 128 slots: 12.6 GB of arguments (weights,
     pool, 2.7 GB of state-space states), every donated byte aliased to an
-    output and under 0.3 GB of temporaries: each Mamba-2 layer reads its
-    slice of the stacked states and writes it back where it lies.  A
-    second copy of the states would not fit the chip."""
+    output and under 0.3 GB of temporaries: each Mamba-2 layer's kernel
+    (``kernels/mamba_step.py``) takes the stacked states and advances its
+    layer where it lies, and no other operation of the step touches
+    them.  A second copy of the states would not fit the chip."""
     from megatron_llm_tpu.config import nemotron_h_config
     from megatron_llm_tpu.serving import engine as engine_lib
 
@@ -360,6 +386,20 @@ def test_a_state_space_decode_step_rewrites_its_states_in_place(
     assert mem.temp_size_in_bytes < 0.3e9
     text = compiled.as_text()
     assert text.count("flash_decode") and text.count("grouped_experts")
+    # under the step's scope the kernel once a Mamba-2 layer; and whatever
+    # makes an array of a layer's states or of them all (the parameter,
+    # the kernels, the accesses to their results) is taken by those and
+    # the program's result alone
+    assert len(ops_under_scopes(text, ["mamba_step"], {"custom-call"})) \
+        == cfg.mamba_layers == 5
+    layer = re.escape("%d,%d,%d,%d]" % rec["ssm"].shape[1:])
+    made = set(re.findall(rf"(%\S+) = [^=]*{layer}[^=]* [\w-]+\(", text))
+    assert len(made) == 1 + 2 * cfg.mamba_layers, made
+    takers = [line for line in text.splitlines() if " = " in line
+              and made & set(re.findall(r"%[\w.-]+", line.split(" = ")[1]))
+              and not re.search(r" (custom-call|get-tuple-element|tuple)\(",
+                                line)]
+    assert not takers, takers
 
 
 def test_a_dropless_prefill_routes_through_the_grouped_kernel(topo,

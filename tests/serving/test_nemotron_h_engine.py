@@ -124,9 +124,27 @@ def test_the_state_is_gauged_by_kind_and_its_positions_counted(model):
     assert len(prefills) == 2 and decodes
     assert all(e["args"]["state_kinds"] == "mamba"
                for e in prefills + decodes)
-    # a decode span says how many slots its step moved
+    # a decode span says how many slots its step moved, and that the
+    # step's state-space layers ran as the kernel (a prefill's do not)
     assert {e["args"]["live"] for e in decodes} <= {1, 2}
     assert 2 in {e["args"]["live"] for e in decodes}
+    assert all(e["args"]["ssm_step"] == "fused" for e in decodes)
+    assert not any("ssm_step" in e["args"] for e in prefills)
+
+
+def test_a_stack_without_state_space_layers_says_nothing_of_their_step():
+    from megatron_llm_tpu.config import tiny_config
+
+    cfg = tiny_config(num_layers=1, vocab_size=64, params_dtype="float32",
+                      make_vocab_size_divisible_by=8)
+    params = model_lib.init_params(jax.random.key(0), cfg)
+    _, eng = serve(cfg, params, [list(range(1, 10))], new=3, max_seq_len=32,
+                   kv_block_size=8, prefill_bucket=16)
+    decodes = [e for e in eng.trace.chrome_trace()["traceEvents"]
+               if e["name"] == "decode"]
+    assert decodes and not any(
+        key in e["args"] for e in decodes
+        for key in ("ssm_step", "state_kinds"))
 
 
 def test_only_the_layers_that_route_are_counted(model):
