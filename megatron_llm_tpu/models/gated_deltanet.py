@@ -14,13 +14,19 @@ convolution and SiLU, q and k L2-normalised, ``beta = sigmoid(b)`` and
 RMS-normalised a head, gated by ``SiLU(z)`` and projected back.
 
 Two forms of the same recurrence: ``delta_rule_step`` for the one new
-position of a decode step, and ``delta_rule_chunked`` for a prompt, which
+position of a decode step, and the chunked form for a prompt, which
 rearranges ``CHUNK`` positions at a time into matrix products (the WY
-form of the paper's section 3).  The chunked form runs as one Pallas
-kernel (``kernels/gdn_scan.py``) for every ``s > 1``: it reads q, k and v
-as the ``[b, s, heads x width]`` rows the projection leaves, picks a
-value head's key head itself, keeps ``S`` in VMEM from a prompt's first
-chunk to its last and writes nothing else it computes to HBM.  The
+form of the paper's section 3).  For every ``s > 1`` everything between
+the two projections is one Pallas kernel (``kernels/gdn_scan.py``): it
+reads q, k, v and z out of the input projection's output where they
+lie, a key head's column blocks at a time, and takes the convolution,
+the L2 norms, the rule (``S`` in VMEM from a prompt's first chunk to its
+last), the output norm and the gate on those tiles; HBM sees the
+projection's output once going in and the gated ``o`` once coming out.
+What is left to XLA of a prompt is the convolution's new tail, a few
+rows.  The one position of a decode step runs the same stages as
+``jax.numpy`` (their definitions are the kernel's: ``conv_taps``,
+``l2norm``, ``gated_rmsnorm``).  The
 state, the kernel's operands and everything that meets them are float32,
 and every product of the rule is taken at ``Precision.HIGHEST``: the rule
 takes differences of near-equal quantities (``v - S^T k``), so one bf16
@@ -28,7 +34,8 @@ rounding comes out of it three times as large and the next router's
 near-ties turn that into other experts (PERF.md, PR 35).  The kernel is
 forward only, as the einsum form it replaced was only ever run: training
 through the rule is not there yet (tests/kernels/test_gdn_scan.py keeps
-the einsum form as an oracle beside the recurrence).  A position whose
+the einsum form and the ``jax.numpy`` stages as an oracle beside the
+recurrence).  A position whose
 ``valid`` is false (the padded tail of a prefill bucket) has ``beta = 0``
 and ``g = 0``: it changes neither ``S`` nor the convolution's tail,
 whatever it holds.
@@ -48,12 +55,17 @@ import jax
 import jax.numpy as jnp
 
 from ..config import ModelConfig
-from ..kernels.gdn_scan import CHUNK, gdn_scan
+from ..kernels.gdn_scan import (
+    CHUNK,
+    conv_taps,
+    gated_rmsnorm,
+    gdn_scan,
+    l2norm,
+)
 from ..ops.precision import dot_f32
 
 Params = dict
 
-L2_EPS = 1e-6
 # the state and everything that meets it is float32, and its products are
 # taken at full float32 precision: XLA:TPU's default for float32 operands
 # is one bfloat16 pass
@@ -115,10 +127,6 @@ def init_state(cfg: ModelConfig, batch: int) -> GDNState:
                               jnp.float32))
 
 
-def _l2norm(x):
-    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + L2_EPS)
-
-
 @jax.named_scope("gdn_step")
 def delta_rule_step(q, k, v, g, beta, S):
     """One position.  ``q k`` [b, h, dk], ``v`` [b, h, dv], ``g beta``
@@ -130,16 +138,6 @@ def delta_rule_step(q, k, v, g, beta, S):
     return jnp.einsum("bhk,bhkv->bhv", q, S, precision=_PREC), S
 
 
-@jax.named_scope("gdn_scan")
-def delta_rule_chunked(q, k, v, g, beta, S):
-    """``CHUNK`` positions at a time, as ``kernels/gdn_scan.py`` arranges
-    them.  ``q k`` [b, s, key heads x dk], ``v`` [b, s, value heads x dv]
-    (rows as the projection leaves them), ``g beta`` [b, s, value heads],
-    ``S`` [b, value heads, dk, dv], float32, ``s`` a multiple of ``CHUNK``
-    → ``(o [b, s, value heads x dv], S)``."""
-    return gdn_scan(q, k, v, g, beta, S)
-
-
 @jax.named_scope("gdn_conv")
 def _conv(p: Params, mixed, tail, lengths):
     """Causal depthwise convolution of ``mixed`` [b, s, ch] continuing
@@ -148,10 +146,80 @@ def _conv(p: Params, mixed, tail, lengths):
     taps, s = p["conv"].shape[0], mixed.shape[1]
     full = jnp.concatenate([tail, mixed], axis=1)          # float32
     w = p["conv"].astype(jnp.float32)
-    out = sum(full[:, j:j + s] * w[j] for j in range(taps))
+    out = conv_taps((full[:, j:j + s] for j in range(taps)),
+                    (w[j] for j in range(taps)))
     new_tail = jax.vmap(lambda f, n: jax.lax.dynamic_slice_in_dim(
         f, n, taps - 1, axis=0))(full, lengths)
     return jax.nn.silu(out), new_tail
+
+
+@jax.named_scope("gdn_conv")
+def _tail_after(tail, mixed, lengths):
+    """The tail ``_conv`` hands on, without its concatenation: of ``tail``
+    [b, taps - 1, ch] followed by the first ``ch`` columns of ``mixed``
+    [b, s, ch or wider], the ``taps - 1`` rows that end at each row's
+    ``lengths``."""
+    keep, ch = tail.shape[1:]
+    n = min(keep, mixed.shape[1])
+
+    def one(tail, mixed, length):
+        last = jax.lax.dynamic_slice(
+            mixed, (jnp.maximum(length - n, 0), 0), (n, ch))
+        return jax.lax.dynamic_slice_in_dim(
+            jnp.concatenate([tail, last]), jnp.minimum(length, keep), keep)
+
+    return jax.vmap(one)(tail, mixed, lengths)
+
+
+def _gates(p: Params, ba, valid, nv):
+    """``(beta, g)`` [b, s, value heads] of the positions that are there,
+    zeros of the others."""
+    live = valid[..., None].astype(jnp.float32)
+    beta = jax.nn.sigmoid(ba[..., :nv]) * live
+    g = -jnp.exp(p["A_log"]) * jax.nn.softplus(
+        ba[..., nv:] + p["dt_bias"]) * live
+    return beta, g
+
+
+def _one_position(cfg: ModelConfig, p: Params, qkvz, ba, state, valid):
+    """A decode step between the two projections, as ``jax.numpy``."""
+    b = qkvz.shape[0]
+    nk, nv, dk, dv, _ch = dims(cfg)
+    kd, vd = nk * dk, nv * dv
+    mixed, z = qkvz[..., :2 * kd + vd], qkvz[..., 2 * kd + vd:]
+    mixed, conv = _conv(p, mixed, state.conv,
+                        jnp.sum(valid, axis=1, dtype=jnp.int32))
+    q = l2norm(mixed[..., :kd].reshape(b, 1, nk, dk)) * dk ** -0.5
+    k = l2norm(mixed[..., kd:2 * kd].reshape(b, 1, nk, dk))
+    v = mixed[..., 2 * kd:]
+    beta, g = _gates(p, ba, valid, nv)
+    # each key head serves value heads / key heads value heads
+    o, S = delta_rule_step(jnp.repeat(q[:, 0], nv // nk, axis=1),
+                           jnp.repeat(k[:, 0], nv // nk, axis=1),
+                           v.reshape(b, nv, dv), g[:, 0], beta[:, 0],
+                           state.S)
+    o = gated_rmsnorm(o.reshape(b, 1, nv, dv), z, p["norm"]["scale"],
+                      cfg.norm_eps)
+    return o.reshape(b, 1, vd), GDNState(S, conv)
+
+
+def _prompt(cfg: ModelConfig, p: Params, qkvz, ba, state, valid):
+    """A prompt between the two projections: the kernel, and the
+    convolution's new tail."""
+    s = qkvz.shape[1]
+    beta, g = _gates(p, ba, valid, cfg.linear_num_value_heads)
+    pad = -s % CHUNK       # padded positions: beta = g = 0, no-ops
+
+    def padded(a):
+        return jnp.pad(a, [(0, 0), (0, pad), (0, 0)]) if pad else a
+
+    with jax.named_scope("gdn_scan"):
+        o, S = gdn_scan(*map(padded, (qkvz, g, beta)), state.S, state.conv,
+                        p["conv"].astype(jnp.float32),
+                        p["norm"]["scale"].astype(jnp.float32), cfg.norm_eps)
+    conv = _tail_after(state.conv, qkvz,
+                       jnp.sum(valid, axis=1, dtype=jnp.int32))
+    return (o[:, :s] if pad else o), GDNState(S, conv)
 
 
 @jax.named_scope("gdn")
@@ -163,8 +231,6 @@ def gdn_block(cfg: ModelConfig, p: Params, x: jax.Array,
     valid positions)``.  ``valid`` [b, s] bool marks the positions that
     are there, a prefix of each row (None: all)."""
     b, s, _ = x.shape
-    nk, nv, dk, dv, _ch = dims(cfg)
-    kd, vd = nk * dk, nv * dv
     if state is None:
         state = init_state(cfg, b)
     if valid is None:
@@ -179,38 +245,8 @@ def gdn_block(cfg: ModelConfig, p: Params, x: jax.Array,
         # router's near-ties turn that into other experts (PERF.md, PR 35)
         qkvz = dot_f32(x, p["w_qkvz"])
         ba = dot_f32(x, p["w_ba"])
-    mixed, z = qkvz[..., :2 * kd + vd], qkvz[..., 2 * kd + vd:]
-    mixed, conv = _conv(p, mixed, state.conv,
-                        jnp.sum(valid, axis=1, dtype=jnp.int32))
-    q = _l2norm(mixed[..., :kd].reshape(b, s, nk, dk)) * dk ** -0.5
-    k = _l2norm(mixed[..., kd:2 * kd].reshape(b, s, nk, dk))
-    v = mixed[..., 2 * kd:]
-    live = valid[..., None].astype(jnp.float32)
-    beta = jax.nn.sigmoid(ba[..., :nv]) * live
-    g = -jnp.exp(p["A_log"]) * jax.nn.softplus(
-        ba[..., nv:] + p["dt_bias"]) * live
-    if s == 1:
-        # each key head serves value heads / key heads value heads
-        o, S = delta_rule_step(jnp.repeat(q[:, 0], nv // nk, axis=1),
-                               jnp.repeat(k[:, 0], nv // nk, axis=1),
-                               v.reshape(b, nv, dv), g[:, 0], beta[:, 0],
-                               state.S)
-    else:
-        pad = -s % CHUNK       # padded positions: beta = g = 0, no-ops
-
-        def padded(a):
-            return jnp.pad(a, [(0, 0), (0, pad), (0, 0)])
-
-        o, S = delta_rule_chunked(
-            padded(q.reshape(b, s, kd)), padded(k.reshape(b, s, kd)),
-            *map(padded, (v, g, beta)), state.S)
-        o = o[:, :s]
-    o = o.reshape(b, s, nv, dv)
-    # RMSNorm a head first, the gate after
-    o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
-                          + cfg.norm_eps)
-    o = o * p["norm"]["scale"].astype(jnp.float32) * jax.nn.silu(
-        z.reshape(b, s, nv, dv))
+    between = _one_position if s == 1 else _prompt
+    o, state = between(cfg, p, qkvz, ba, state, valid)
     with jax.named_scope("gdn_proj"):
-        out = dot_f32(o.reshape(b, s, vd), p["w_out"]).astype(x.dtype)
-    return out, GDNState(S, conv)
+        out = dot_f32(o, p["w_out"]).astype(x.dtype)
+    return out, state

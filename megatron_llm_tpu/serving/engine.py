@@ -1183,6 +1183,11 @@ class ServingEngine:
         # (kernels/mamba_step.py; no such field for a stack without them)
         self._step_arg = dict(self._state_arg, **(
             {"ssm_step": "fused"} if self._counts_ssm else {}))
+        # how a prompt's Gated DeltaNet layers ran, on its prefill spans:
+        # between their projections as one kernel (kernels/gdn_scan.py;
+        # no such field for a stack without them)
+        self._prefill_arg = dict(self._state_arg, **(
+            {"gdn": "fused"} if cfg.linear_layers else {}))
         self._admit_count = 0        # this iteration's admissions
         self._admit_tokens = 0       # and their prompt tokens
         # whether forward_cached routes this config's slot batch through
@@ -2095,7 +2100,7 @@ class ServingEngine:
                        args={"prompt_len": plen, "padded": padded,
                              "cached_tokens": lease.tokens if lease else 0,
                              "iter": self._iter, **self._experts_arg,
-                             **self._state_arg})
+                             **self._prefill_arg})
         if self._counts_ssm:
             self.metrics.add_ssm_positions("prefill", plen)
         self._admit_count += 1

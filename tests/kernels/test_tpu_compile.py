@@ -33,7 +33,6 @@ from megatron_llm_tpu.config import ParallelConfig, llama2_config  # noqa: E402
 from megatron_llm_tpu.kernels import decode_step as ds  # noqa: E402
 from megatron_llm_tpu.kernels import flash_decode as fd  # noqa: E402
 from megatron_llm_tpu.kernels.flash_attention import flash_attention  # noqa: E402
-from megatron_llm_tpu.kernels.gdn_scan import gdn_scan  # noqa: E402
 from megatron_llm_tpu.kernels.grouped_matmul import (  # noqa: E402
     grouped_mlp,
 )
@@ -237,34 +236,56 @@ def test_norm_fwd_bwd(topo, norm, hidden):
 
 
 @pytest.mark.parametrize("s", [2048, 16384])
-def test_gdn_scan(topo, s):
-    """The chunked delta rule at the published widths of Qwen3-Next (16
-    key and 32 value heads of width 128, one prompt): the kernel is the
-    whole of it.  No ``while`` is left of the scan over chunks, q, k, v
-    and o stay where they lie as ``[1, s, heads x width]`` rows (the
-    only arrays moved are ``g`` and ``beta``, 1/400 of the bytes), and
-    the kernel's blocks and temporaries take the VMEM stated here."""
+def test_gdn_block(topo, monkeypatch, s):
+    """A prompt through the Gated DeltaNet mixer at the published widths
+    of Qwen3-Next (16 key and 32 value heads of width 128, conv 4, hidden
+    2048): between the two projections the kernel is the whole of it.
+    One custom call, no ``while`` left of the scan over chunks; the
+    projection's ``[1, s, 8192]`` output goes into the kernel as it lies
+    and the kernel's ``[1, s, 4096]`` output into ``w_out``: nothing
+    outside the projections' own fusions makes a float32 array of ``s``
+    rows by 2048, 4096 or 8192, and nothing is re-laid (the mixer of PR
+    37 moved 1664 MiB a 16k prompt around its kernel); ``g`` and ``beta``
+    (1/400 of the bytes) are the only arrays transposed.  The kernel's
+    blocks and temporaries take the VMEM stated here."""
+    from megatron_llm_tpu.config import qwen3_next_config
+    from megatron_llm_tpu.models import gated_deltanet as gdn
+
+    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
     one = SingleDeviceSharding(topo.devices[0])
-    nk, nv, d = 16, 32, 128
+    cfg = qwen3_next_config("80b-a3b-ep2-rank0", num_layers=4,
+                            attention_impl="flash")
+    assert gdn.dims(cfg) == (16, 32, 128, 128, 8192)
     f32 = jnp.float32
+    params = jax.eval_shape(lambda key: gdn.init_gdn_params(key, cfg),
+                            jax.random.key(0))
+    state = jax.eval_shape(lambda: gdn.init_state(cfg, 1))
     text = _compile(
-        lambda *a: gdn_scan(*a, interpret=False),
-        (_sds((1, s, nk * d), f32), _sds((1, s, nk * d), f32),
-         _sds((1, s, nv * d), f32), _sds((1, s, nv), f32),
-         _sds((1, s, nv), f32), _sds((1, nv, d, d), f32)), one)
+        lambda p, x, S, conv, valid: gdn.gdn_block(
+            cfg, p, x, gdn.GDNState(S, conv), valid),
+        (params, _sds((1, s, cfg.hidden_size), f32), *state,
+         _sds((1, s), jnp.bool_)), one)
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert " while(" not in text
-    assert not relayout_bytes(text, min_bytes=4 * s * nk * d)
-    _no_copy_of(text, f"f32[1,{s},{nk * d}]")
-    _no_copy_of(text, f"f32[1,{s},{nv * d}]")
+    assert not relayout_bytes(text, min_bytes=4 * s * 2048)
+    made = []
+    for line in text.splitlines():
+        m = re.match(rf"\s*(?:ROOT )?%(\S+) = f32\[1,{s},(?:2048|4096|8192)\]"
+                     r"\S* ([\w-]+)\(", line)
+        if m and "/gdn_proj/" not in line and m.group(2) not in (
+                "parameter", "get-tuple-element", "bitcast", "copy-start",
+                "copy-done"):
+            made.append(line.strip()[:160])
+    assert not made, made
     call, = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     vmem, = re.findall(r'"used_scoped_memory_configs":\[\{"memory_space":'
                        r'"1","offset":"0","size":"(\d+)"', call)
-    # 512 rows a step of q, k (128 wide), v and o (256 wide), double
-    # buffered: 3 MiB; two heads' state in and out: 0.5 MiB; the rest is
+    # 512 rows a step of q, k (128 wide), v, z and o (256 wide), double
+    # buffered: 4 MiB; two heads' state in and out: 0.5 MiB; the rest is
     # what a chunk's matrices spill.  Of 16 MiB a kernel may have by
     # default
-    assert 3.5 * 2 ** 20 < int(vmem) < 8 * 2 ** 20, vmem
+    assert 4.5 * 2 ** 20 < int(vmem) < 9 * 2 ** 20, vmem
 
 
 @pytest.mark.parametrize("tokens", [16384, 44], ids=["prompt", "step"])

@@ -59,35 +59,32 @@ def test_the_preset_is_the_published_model_and_its_share():
 
 
 @pytest.mark.parametrize("s", [64, 100, 192])
-def test_the_chunked_rule_is_the_recurrence_across_chunk_edges(s):
+def test_a_prompt_through_the_mixer_is_its_positions_one_by_one(s):
     """64 positions a chunk: one whole chunk, one and a ragged second
-    (padded inside ``gdn_block``, here by hand), three."""
-    b, h, dk, dv = 2, 3, 16, 8
-    ks = jax.random.split(jax.random.key(s), 6)
-    q = jax.random.normal(ks[0], (b, s, h, dk))
-    k = jax.random.normal(ks[1], (b, s, h, dk))
-    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
-    v = jax.random.normal(ks[2], (b, s, h, dv))
-    g = -jax.nn.softplus(jax.random.normal(ks[3], (b, s, h)))
-    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, s, h)))
-    S0 = jax.random.normal(ks[5], (b, h, dk, dv))
+    (padded inside ``gdn_block``), three.  The kernel's side of
+    ``gdn_block`` (``s > 1``) against the one-position side run ``s``
+    times, from a state and a tail that are not zero."""
+    cfg = tiny()
+    b = 2
+    nk, nv, dk, dv, ch = gdn.dims(cfg)
+    ks = jax.random.split(jax.random.key(s), 5)
+    p = gdn.init_gdn_params(ks[0], cfg)
+    p["norm"]["scale"] = 1.0 + 0.3 * jax.random.normal(ks[1], (dv,))
+    x = 3.0 * jax.random.normal(ks[2], (b, s, cfg.hidden_size))
+    state = gdn.GDNState(jax.random.normal(ks[3], (b, nv, dk, dv)),
+                         jax.random.normal(ks[4], (b, 3, ch)))
 
-    def step(S, x):
-        o, S = gdn.delta_rule_step(*x, S)
-        return S, o
+    def step(state, x_t):
+        out, state = gdn.gdn_block(cfg, p, x_t[:, None], state)
+        return state, out[:, 0]
 
-    S_want, o_want = jax.jit(lambda S, xs: jax.lax.scan(step, S, xs))(
-        S0, jax.tree.map(lambda a: jnp.moveaxis(a, 1, 0),
-                         (q, k, v, g, beta)))
-    pad = -s % gdn.CHUNK
-    padded = [jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2))
-              for a in (q, k, v, g, beta)]
-    # the kernel reads rows as the projection leaves them, heads x width
-    padded[:3] = [a.reshape(b, s + pad, -1) for a in padded[:3]]
-    o_got, S_got = jax.jit(gdn.delta_rule_chunked)(*padded, S0)
-    np.testing.assert_allclose(o_got.reshape(b, s + pad, h, dv)[:, :s],
-                               jnp.moveaxis(o_want, 0, 1), atol=2e-5)
-    np.testing.assert_allclose(S_got, S_want, atol=2e-5)
+    want_state, want = jax.jit(lambda st, xs: jax.lax.scan(step, st, xs))(
+        state, jnp.moveaxis(x, 1, 0))
+    got, got_state = jax.jit(
+        lambda x, st: gdn.gdn_block(cfg, p, x, st))(x, state)
+    np.testing.assert_allclose(got, jnp.moveaxis(want, 0, 1), atol=2e-5)
+    np.testing.assert_allclose(got_state.S, want_state.S, atol=2e-5)
+    np.testing.assert_allclose(got_state.conv, want_state.conv, atol=1e-6)
 
 
 def test_the_mixer_runs_the_kernel_for_a_prompt_and_the_step_for_one():

@@ -122,6 +122,33 @@ def test_the_experts_rows_are_counted_by_outcome_and_the_spans_say_how(model):
     assert all(e["args"]["experts"] == "grouped" for e in spans)
 
 
+def test_a_prompts_linear_layers_say_they_ran_as_the_kernel(model):
+    """A ``prefill`` span of a stack with Gated DeltaNet layers carries
+    ``gdn: "fused"`` (between their projections one kernel,
+    ``kernels/gdn_scan.py``); its decode spans, whose one position runs
+    as ``jax.numpy``, do not, nor does any span of a Falcon stack."""
+    from megatron_llm_tpu.config import falcon_config
+
+    cfg, params = model
+    _, eng = serve(cfg, params, prompts_of([40, 50], seed=2), new=3)
+    spans = eng.trace.chrome_trace()["traceEvents"]
+    prefills = [e for e in spans if e["name"] == "prefill"]
+    assert len(prefills) == 2
+    assert all(e["args"]["gdn"] == "fused" for e in prefills)
+    assert not any("gdn" in e.get("args", {}) for e in spans
+                   if e["name"] != "prefill")
+    falcon = falcon_config(
+        "7b", num_layers=1, hidden_size=64, num_attention_heads=4,
+        ffn_hidden_size=128, vocab_size=64, params_dtype="float32",
+        make_vocab_size_divisible_by=8, max_position_embeddings=128)
+    _, eng = serve(falcon, model_lib.init_params(jax.random.key(0), falcon),
+                   [list(range(1, 10))], new=3, max_seq_len=32,
+                   kv_block_size=8, prefill_bucket=16)
+    spans = eng.trace.chrome_trace()["traceEvents"]
+    assert [e for e in spans if e["name"] == "prefill"]
+    assert not any("gdn" in e.get("args", {}) for e in spans)
+
+
 def test_a_dense_engine_counts_no_experts_and_keeps_no_state():
     from megatron_llm_tpu.config import tiny_config
 
