@@ -17,7 +17,7 @@ a trainer and a server over one model library:
 
 Each phase is compared, not just completed: the same steps and the same
 token sequences go through the plain XLA path of the same params
-(``attention_impl="dot"``, ``fused_decode=False``, one-shot
+(``attention_impl="dot"``, one-shot
 ``generation.generate_tokens``).  Random weights make argmax ties, so the
 criterion is distance of loss and of log-probability, never token
 identity.
@@ -397,8 +397,6 @@ def plan_server(sz: Sizes, cfg, on_tpu: bool):
     engine will dispatch (serving/engine.py) over shapes only."""
     import jax
     import jax.numpy as jnp
-    from megatron_llm_tpu.kernels.decode_step import (
-        fused_paged_decode_eligible)
     from megatron_llm_tpu.models import model as model_lib
     from megatron_llm_tpu.serving import engine as eng
 
@@ -409,8 +407,6 @@ def plan_server(sz: Sizes, cfg, on_tpu: bool):
         lambda: model_lib.init_params(jax.random.key(0), cfg))
     k, v = jax.eval_shape(
         lambda: model_lib.init_kv_pool(cfg, n_blocks, BLOCK))
-    fused = fused_paged_decode_eligible(cfg, params, k, S, T,
-                                        jax.default_backend())
 
     def vec(dtype):
         return jax.ShapeDtypeStruct((S,), dtype)
@@ -418,8 +414,8 @@ def plan_server(sz: Sizes, cfg, on_tpu: bool):
     decode = compile_or_none(eng._decode_donated.lower(
         cfg, params, k, v, jax.ShapeDtypeStruct((S, T), jnp.int32),
         vec(jnp.int32), vec(jnp.int32), vec(jnp.uint32), vec(jnp.int32),
-        vec(jnp.bool_), vec(jnp.float32), vec(jnp.int32), vec(jnp.float32),
-        use_fused=fused), what + " decode")
+        vec(jnp.bool_), vec(jnp.float32), vec(jnp.int32), vec(jnp.float32)),
+        what + " decode")
     if decode is None:
         return None
     longest = -(-max(sz.prompt_lens) // PREFILL_BUCKET) * PREFILL_BUCKET
@@ -438,9 +434,8 @@ def plan_server(sz: Sizes, cfg, on_tpu: bool):
         f"{m.temp_size_in_bytes / 2**30:.2f} GiB (the composed path's "
         f"dense gather of every slot's table); needs "
         f"{need / 2**30:.2f} GiB of {bytes_limit() / 2**30:.2f} GiB")
-    say(f"plan {what}: fused whole-stack decode eligible: {fused}; "
-        f"tpu_custom_call in the decode step: {has_kernel(decode)}, in "
-        f"the prefill: {has_kernel(prefill)}")
+    say(f"plan {what}: tpu_custom_call in the decode step: "
+        f"{has_kernel(decode)}, in the prefill: {has_kernel(prefill)}")
     if on_tpu:
         check(has_kernel(decode) and has_kernel(prefill),
               f"{what}: prefill and decode step hold tpu_custom_call")
@@ -519,8 +514,7 @@ def reference_logprobs(cfg, params, sequences: dict) -> dict:
     import numpy as np
     from megatron_llm_tpu.generation import generation
 
-    ref_cfg = dataclasses.replace(cfg, attention_impl="dot",
-                                  fused_decode=False)
+    ref_cfg = dataclasses.replace(cfg, attention_impl="dot")
     names = list(sequences)
     lengths = [len(sequences[n]) for n in names]
     # one slot of room to "generate" into; a width that is no multiple of
@@ -639,7 +633,6 @@ def server_phase(sz: Sizes, seed: int, on_tpu: bool, reduced: list,
                 f"http://127.0.0.1:{port}/metrics", timeout=60) as resp:
             snap = json.loads(resp.read())
         say(f"GET /metrics: completed={snap['completed']} "
-            f"fused_steps={snap['fused_steps']} "
             f"paged_steps={snap['paged_steps']} "
             f"fallback_steps={snap['fallback_steps']} "
             f"prefix_hits={snap['prefix_hits']} "

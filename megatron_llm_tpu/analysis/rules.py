@@ -681,9 +681,10 @@ _DEQUANT_FNS = ("dequantize_weight", "dequantize_cache")
 
 def rule_dequant_hot_path(ctx: ModuleContext) -> List[Finding]:
     """The quantized-residency bytes win exists only while the packed
-    form is what streams from HBM: the fused decode kernels dequantize
-    int8/int4 *tiles* inside the tile load
-    (kernels/decode_step.py:_int4_tile), never the whole tensor.  A
+    form is what streams from HBM: ``ops/quant.py:mm`` dequantizes
+    into the matmul (XLA fuses the convert and the scale into the dot's
+    read) and the paged attention kernel dequantizes int8 KV *tiles* at
+    the tile load (kernels/flash_decode.py), never the whole tensor.  A
     ``dequantize_weight`` / ``dequantize_cache`` call in a kernels/
     file or a ``tpulint: hot-path`` function re-materializes the full
     fp tensor every step — the exact traffic quantization was bought

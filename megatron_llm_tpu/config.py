@@ -144,14 +144,6 @@ class ModelConfig:
     # math XLA fuses into neighbors; the default — XLA's fusion is already
     # near-bandwidth-bound for norms).
     norm_impl: str = "xla"
-    # Fused single-token decode: run the whole layer stack as ONE Pallas
-    # kernel per decode step (kernels/decode_step.py) when eligible —
-    # dense RMSNorm+GLU rotary layers, bf16 cache, no mesh.  Small-batch
-    # decode is otherwise bound by the sequential per-op chain (~100 µs/
-    # layer/step vs a ~38 µs/layer weight-read floor on v5e); the fused
-    # step streams weights+cache through VMEM once and removes the chain.
-    # False forces the composed stack_forward_cached path everywhere.
-    fused_decode: bool = True
     # Quantized TRAINING matmuls: "none" (default) | "int8" — the layer
     # projection matmuls (QKV/out, MLP up/gate/down) run W8A8 on the int8
     # MXU (per-token activation scales x per-channel weight scales,
@@ -705,8 +697,12 @@ class RuntimeConfig:
 
     @classmethod
     def from_dict(cls, d: dict) -> "RuntimeConfig":
+        # every checkpoint written before the whole-stack decode kernel
+        # went stores its switch; nothing reads it any more
+        model = {k: v for k, v in d.get("model", {}).items()
+                 if k != "fused_decode"}
         return cls(
-            model=ModelConfig(**d.get("model", {})),
+            model=ModelConfig(**model),
             parallel=ParallelConfig(**{k: tuple(v) if isinstance(v, list) else v
                                        for k, v in d.get("parallel", {}).items()}),
             optimizer=OptimizerConfig(**d.get("optimizer", {})),
@@ -902,7 +898,6 @@ def qwen3_next_config(size: str = "80b-a3b", **overrides) -> ModelConfig:
         vocab_size=151936,
         max_position_embeddings=262144,
         seq_length=4096,
-        fused_decode=False,
         recompute="none",
     )
     sizes = {
@@ -979,7 +974,6 @@ def nemotron_h_config(size: str = "3-super-120b-a12b",
         vocab_size=131072,
         max_position_embeddings=262144,
         seq_length=4096,
-        fused_decode=False,
         recompute="none",
     )
     sizes = {

@@ -1,9 +1,8 @@
 """Prompt-lookup speculative decoding (greedy): multi-token decode steps.
 
 Small-batch decode on TPU is bound by the *sequential step chain*, not
-bytes (the fused decode-step kernel attacks per-step cost, this module
-attacks step COUNT).  The way through is fewer sequential steps per
-generated token:
+bytes (this module attacks step COUNT).  The way through is fewer
+sequential steps per generated token:
 prompt-lookup decoding (PLD) drafts the next ``draft_len`` tokens by
 matching the trailing n-gram of the context against its own history, then
 verifies all of them in ONE cached forward.  Every committed token is an
@@ -27,7 +26,7 @@ continuous-batching serving engine carries TWO speculative paths over
 paged blocks, both with per-slot acceptance policies: the same host
 n-gram drafter verifying a linear window (docs/serving.md, "Speculative
 decoding"), and a resident draft MODEL proposing candidate trees that
-the target verifies in one fused forward — the path that still
+the target verifies in one forward — the path that still
 speculates on traffic with nothing to look up (serving/engine.py
 ``_spec_step_tree``; docs/serving.md, "Tree speculation & resident
 drafts").

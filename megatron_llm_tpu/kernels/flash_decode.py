@@ -528,7 +528,8 @@ def flash_decode_paged_int8(
     cache_len: jax.Array,
     *,
     new_rows: tuple | None = None,  # float rows, as the pool will hold
-    #                        them (kv_quant.fake_quantize_rows)
+    #                        them (kv_quant.dequantize_cache of the
+    #                        quantized rows)
     layer=None,
     softmax_scale: float | None = None,
     interpret: bool | None = None,

@@ -311,42 +311,12 @@ def forward_cached(
             (cache_len + jnp.arange(s, dtype=jnp.int32))[None, :], (b, s))
     x = embed(cfg, params, tokens, position_ids)
 
-    from ..kernels.decode_step import fused_decode_eligible
-
-    lora_sr = 0
-    if lora is not None:
-        from ..ops.lora import arena_sr
-
-        lora_sr = arena_sr(lora[0])
-    if fused_decode_eligible(cfg, params, k_cache, s,
-                             jax.default_backend(), lora_sr):
-        # single-token fast path: the whole stack in one Pallas kernel
-        # (kernels/decode_step.py) — the caller-visible contract (returned
-        # logits + updated caches) is identical to the composed path.
-        # ``cache_len`` may be a [b] per-sample fill vector (the serving
-        # engine's slot batch): the kernel masks each row at its own fill
-        # and cache_update lands each row's K/V at its own position.
-        # int8 weights and the int8 {"q", "scale"} cache dict both route
-        # through here too (eligibility checks all seven projections are
-        # consistently quantized); for a quantized cache the kernel
-        # returns pre-requantized fp rows that cache_update writes back
-        # losslessly.
-        from ..kernels.decode_step import fused_decode_step
-        from ..ops.kv_quant import cache_update
-
-        hidden, k_rows, v_rows = fused_decode_step(
-            cfg, params["layers"], x[:, 0], k_cache, v_cache, cache_len,
-            (cos, sin), lora=lora)
-        x = hidden[:, None, :]
-        new_k = cache_update(k_cache, k_rows, cache_len)
-        new_v = cache_update(v_cache, v_rows, cache_len)
-    else:
-        side = AttnSideInputs(rope_cos=cos, rope_sin=sin,
-                              position_ids=position_ids, deterministic=True,
-                              cache_is_empty=empty_cache)
-        x, new_k, new_v = stack_forward_cached(
-            cfg, params["layers"], x, side, k_cache, v_cache, cache_len,
-            lora=lora)
+    side = AttnSideInputs(rope_cos=cos, rope_sin=sin,
+                          position_ids=position_ids, deterministic=True,
+                          cache_is_empty=empty_cache)
+    x, new_k, new_v = stack_forward_cached(
+        cfg, params["layers"], x, side, k_cache, v_cache, cache_len,
+        lora=lora)
     x = norm_apply(cfg.norm_type, x, params["final_norm"], cfg.norm_eps,
                    impl=cfg.norm_impl)
     if last_logit_only:
@@ -382,7 +352,6 @@ def forward_cached_paged(
     fills: jax.Array,    # [b] int32 per-slot fill levels
     *,
     rope: Optional[tuple] = None,
-    use_fused: bool = False,
     allow_paged: bool = True,
     lora=None,
 ):
@@ -391,16 +360,9 @@ def forward_cached_paged(
     The paged analogue of ``forward_cached`` for the serving engine's
     slot batch: each slot's token attends the blocks its table names and
     its new K/V row is scattered into block ``tables[s, fill//bk]`` at
-    offset ``fill % bk``.  Three routes, one caller-visible contract:
+    offset ``fill % bk``.  Two routes, one caller-visible contract:
 
-    * ``use_fused=True`` — the whole-stack Pallas kernel's paged gather
-      mode (kernels/decode_step.py:fused_decode_step_paged): per-row
-      block walks read only each slot's live blocks from HBM, so decode
-      cache traffic scales with the sum of fills instead of
-      ``b * max_seq_len``.  For an int8 pool the kernel's
-      pre-requantized fp rows are re-quantized losslessly before the
-      scatter (``fake_quantize_rows`` idempotence).
-    * the composed *paged* route, where ``paged_decode_route`` says the
+    * the *paged* route, where ``paged_decode_route`` says the
       paged attention kernel runs (a TPU, block size a multiple of 128,
       head width a multiple of 64, a mesh whose tp divides the heads —
       bf16 and int8 pools alike): the layers are scanned with every
@@ -408,15 +370,14 @@ def forward_cached_paged(
       tables (``stack_forward_paged``), and the step's new rows are
       appended in place afterwards.  Nothing of the pool's size, or of
       slots × ``max_seq_len``, is built or copied.
-    * the composed *gather* route everywhere else (the CPU, odd block
+    * the *gather* route everywhere else (the CPU, odd block
       sizes): gather the tables into a dense working view
       (``cache_gather_blocks``) and run the ordinary ``forward_cached``
       path over it, then scatter back only the appended rows.  Gathered
       garbage beyond a slot's fill is masked by score replacement, so
-      this route and the fused one are bitwise-identical to a
-      contiguously grown cache.
+      this route is bitwise-identical to a contiguously grown cache.
 
-    The composed route decides between the two itself
+    The step decides between the two itself from what it observes
     (``paged_decode_eligible``).  ``allow_paged=False`` holds it to the
     gather route: the engine passes it while it speculates, because
     ``forward_cached_paged_verify`` walks the gather route's arithmetic
@@ -432,24 +393,6 @@ def forward_cached_paged(
     bk = jax.tree.leaves(k_pool)[0].shape[3]
     bids = jnp.take_along_axis(tables, (fills // bk)[:, None], axis=1)[:, 0]
     offs = fills % bk
-    if use_fused:
-        from ..kernels.decode_step import fused_decode_step_paged
-        from ..ops.kv_quant import is_quantized_cache, quantize_rows
-
-        x = embed(cfg, params, tokens, fills[:, None])
-        hidden, k_rows, v_rows = fused_decode_step_paged(
-            cfg, params["layers"], x[:, 0], k_pool, v_pool, tables, fills,
-            (cos, sin), lora=lora)
-        if is_quantized_cache(k_pool):
-            k_rows = quantize_rows(k_rows)
-            v_rows = quantize_rows(v_rows)
-        k_pool = cache_append_rows(k_pool, k_rows, bids, offs)
-        v_pool = cache_append_rows(v_pool, v_rows, bids, offs)
-        x = norm_apply(cfg.norm_type, hidden[:, None, :],
-                       params["final_norm"], cfg.norm_eps,
-                       impl=cfg.norm_impl)
-        logits = unembed(cfg, params, x)
-        return logits, k_pool, v_pool
     if allow_paged and paged_decode_eligible(cfg, k_pool, tokens.shape[1]):
         x = embed(cfg, params, tokens, fills[:, None])
         side = AttnSideInputs(rope_cos=cos, rope_sin=sin,
@@ -486,7 +429,6 @@ def forward_cached_paged_verify(
     offs: jax.Array,     # [S*W] int32 in-block offset per window row
     *,
     rope: Optional[tuple] = None,
-    use_fused: bool = False,
     tree: Optional[tuple] = None,
     lora=None,
 ):
@@ -506,10 +448,9 @@ def forward_cached_paged_verify(
     (entries at or past a node's depth are ignored and may be
     arbitrary).  Nodes must be in BFS order — node 0 is the root (the
     pending token, depth 0), parents precede children, and depths are
-    non-decreasing — so the deepest node is last and the kernel's
-    longest-row bookkeeping carries over.  Each node runs at position
-    ``fills[s] + depths[s, j]`` attending only to the committed prefix
-    plus its own root path, which makes every root-to-leaf path
+    non-decreasing — so the deepest node is last.  Each node runs at
+    position ``fills[s] + depths[s, j]`` attending only to the committed
+    prefix plus its own root path, which makes every root-to-leaf path
     bitwise-equal to sequentially decoding that path; K/V rows land
     *node-indexed* at the caller's ``(bids, offs)`` (the engine passes
     ``offs = fill + node``), and the caller compacts the accepted path
@@ -524,19 +465,17 @@ def forward_cached_paged_verify(
 
     Each verify position is bitwise-identical to the corresponding
     sequential single-token step, which is what makes
-    accept-longest-greedy-prefix exact rather than approximate.  The two
-    arms get there differently: the fused kernel replays the window as
-    per-row merged-tile splices inside one dispatch (kernels/
-    decode_step.py), while the composed fallback walks the window one
-    token at a time over a single gathered dense view — the same
-    fixed-arity buffer shape and op sequence as ``forward_cached_paged``'s
-    composed route, because XLA's reductions are only bitwise-stable
-    when the shapes match exactly (a one-pass W-token batch reassociates
-    the attention sums and drifts ~1e-7).  The gather/append pool
-    round-trip equals in-place dense updates leaf-for-leaf (int8 rows
-    requantize through the identical ``quantize_rows``), so walking a
-    persistent dense view matches re-gathering every step.  "The
-    sequential step" is ``forward_cached_paged``'s *gather* route: an
+    accept-longest-greedy-prefix exact rather than approximate.  The
+    window is walked one token at a time over a single gathered dense
+    view — the same fixed-arity buffer shape and op sequence as
+    ``forward_cached_paged``'s gather route, because XLA's reductions
+    are only bitwise-stable when the shapes match exactly (a one-pass
+    W-token batch reassociates the attention sums and drifts ~1e-7).
+    The gather/append pool round-trip equals in-place dense updates
+    leaf-for-leaf (int8 rows requantize through the identical
+    ``quantize_rows``), so walking a persistent dense view matches
+    re-gathering every step.  "The sequential step" is
+    ``forward_cached_paged``'s *gather* route: an
     engine that speculates passes ``allow_paged=False`` to its plain decode
     steps too, so that decode and verify stay one arithmetic.
 
@@ -559,27 +498,6 @@ def forward_cached_paged_verify(
     if tree is not None:
         depths = jnp.asarray(tree[0], jnp.int32)
         anc = jnp.asarray(tree[1], jnp.int32)
-    if use_fused:
-        from ..kernels.decode_step import fused_decode_verify_paged
-        from ..ops.kv_quant import is_quantized_cache, quantize_rows
-
-        if tree is None:
-            pos = fills[:, None] + jnp.arange(W, dtype=jnp.int32)[None, :]
-        else:
-            pos = fills[:, None] + depths
-        x = embed(cfg, params, window, pos)
-        hidden, k_rows, v_rows = fused_decode_verify_paged(
-            cfg, params["layers"], x, k_pool, v_pool, tables, fills, rope,
-            depths=depths, anc=anc, lora=lora)
-        if is_quantized_cache(k_pool):
-            k_rows = quantize_rows(k_rows)
-            v_rows = quantize_rows(v_rows)
-        k_pool = cache_append_rows(k_pool, k_rows, bids, offs)
-        v_pool = cache_append_rows(v_pool, v_rows, bids, offs)
-        x = norm_apply(cfg.norm_type, hidden, params["final_norm"],
-                       cfg.norm_eps, impl=cfg.norm_impl)
-        logits = unembed(cfg, params, x)
-        return logits, k_pool, v_pool
     k_dense = cache_gather_blocks(k_pool, tables)
     v_dense = cache_gather_blocks(v_pool, tables)
     if tree is None:
@@ -896,8 +814,8 @@ def cache_append_rows(pool, rows, bids, offs):
     """Write one new K/V row per slot into the pool, in place.
 
     ``rows`` leaves are [L, S, kv, 1(, d)] (the rows a decode step
-    appended: the layer scan's ys, the fused kernel's, or extracted from
-    the dense working view); slot s's row lands at offset ``offs[s]`` of
+    appended: the layer scan's ys, or extracted from the dense working
+    view); slot s's row lands at offset ``offs[s]`` of
     pool block ``bids[s]``.  Inactive slots target (trash, 0) and
     overwrite each other there, in slot order.  The int8 {q, scale}
     pytree is written leaf-wise, so quantized rows move verbatim.

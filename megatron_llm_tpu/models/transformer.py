@@ -277,8 +277,7 @@ def _lora_add(y: jax.Array, x: jax.Array, lora, target: str) -> jax.Array:
     ``{target: {"a": [in, Sr], "b": [Sr, out]}}`` plus the per-row column
     mask ``[b, Sr]`` (ops/lora.py:slot_mask).  The delta is fp32 with ±0
     contributions from masked columns, so rows whose slot is -1 (or whose
-    adapter differs) are bitwise-unaffected at the token level — the same
-    contract as the fused kernel's in-kernel epilogue."""
+    adapter differs) are bitwise-unaffected at the token level."""
     if lora is None:
         return y
     factors, mask = lora
@@ -311,8 +310,7 @@ def attention_block(cfg: ModelConfig, p: Params, x: jax.Array,
 
     ``lora`` is the per-layer ``(factors, mask)`` bundle (see
     :func:`_lora_add`); deltas land right after each base projection,
-    before bias/reshape/RoPE — the same insertion points as the fused
-    decode kernel's epilogue.
+    before bias/reshape/RoPE.
     """
     b, s, h = x.shape
     d = cfg.head_dim

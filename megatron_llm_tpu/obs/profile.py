@@ -41,7 +41,7 @@ SYNC_NAME = "obs_clock_sync"
 DEVICE_SCOPES = (
     "embed", "attention", "mlp", "lm_head", "cross_entropy", "grad_accum",
     "optimizer", "kv_cache", "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
-    "flash_decode", "rmsnorm", "decode_step_fused", "sample",
+    "flash_decode", "rmsnorm", "sample",
     # a hybrid stack (models/gated_deltanet.py, models/moe.py): the Gated
     # DeltaNet mixer and its parts, the dropless experts' parts ("mlp"
     # holds them all; the kernel "grouped_experts" runs under

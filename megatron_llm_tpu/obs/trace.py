@@ -4,7 +4,7 @@ A ``TraceRecorder`` is a lock-guarded bounded ring buffer of completed
 spans.  The serving engine records one span per request phase (queued,
 prefix_match, prefill / prefill_chunk[i], decode, retire) and one span
 per scheduler iteration (engine_step, carrying batch size and
-fused/fallback routing as args), so a single stalled chunked-prefill
+paged/fallback routing as args), so a single stalled chunked-prefill
 admission that aggregate p50s hide shows up as an obvious gap on the
 timeline.
 

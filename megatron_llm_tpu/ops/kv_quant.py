@@ -12,11 +12,10 @@ never occurs.
 A quantized cache is ``{"q": int8 [..., max_len, d],
 "scale": fp32 [..., max_len]}`` — a plain dict subtree, so the scan-xs /
 dynamic-update-slice / while-loop-carry plumbing of the decode path works
-unchanged on it (pytrees all the way down).  The fused decode-step
-kernel streams the int8 payload directly (dequant fused at the
-attention tile load) and hands back new rows it already passed through
-``fake_quantize_rows``, so the single host-side ``cache_update`` write
-reproduces the exact values the kernel attended over.
+unchanged on it (pytrees all the way down).  The paged attention
+kernel streams the int8 payload directly (dequant fused at the tile
+load, kernels/flash_decode.py) and attends the step's new rows as the
+pool will hold them (``dequantize_cache`` of ``quantize_rows``).
 
 The reference has no quantized inference cache; its InferenceParams holds
 compute-dtype tensors (megatron/model/transformer.py:423-496).
@@ -39,16 +38,16 @@ def init_quantized_cache(shape: tuple) -> dict:
             "scale": jnp.zeros(shape[:-1], jnp.float32)}
 
 
-# Scales are amax·(1/127), not amax/127: the speculative-verify kernel
-# recomputes row scales inside the fused kernel and must land on the very
-# same fp32 the host-side quantize_rows stored — a constant multiply is one
-# exactly-rounded op everywhere, while XLA lowers a constant *divide*
-# differently across fusion contexts (reciprocal-multiply rewrite), which
-# showed up as a 1-ulp scale split between the two paths.
-# numpy, not jnp: this module can be lazily imported from inside a jit
-# trace (models/model.py imports kernels.decode_step under jit), where a
-# module-level jnp op would be staged as a tracer; IEEE fp32 division is
-# exactly rounded, so the bits match the device computation either way.
+# Scales are amax·(1/127), not amax/127: a verify step's rows must land
+# on the very same fp32 scale a sequential step's did — a constant
+# multiply is one exactly-rounded op everywhere, while XLA lowers a
+# constant *divide* differently across fusion contexts
+# (reciprocal-multiply rewrite), which showed up as a 1-ulp scale split
+# between two programs.
+# numpy, not jnp: this module is lazily imported from inside a jit trace
+# (ops/attention.py), where a module-level jnp op would be staged as a
+# tracer; IEEE fp32 division is exactly rounded, so the bits match the
+# device computation either way.
 _RCP127 = float(np.float32(1.0) / np.float32(127.0))
 
 
@@ -60,25 +59,6 @@ def quantize_rows(rows: jax.Array) -> dict:
     q = jnp.clip(jnp.round(r32 / scale[..., None]),
                  -127, 127).astype(jnp.int8)
     return {"q": q, "scale": scale}
-
-
-def fake_quantize_rows(rows: jax.Array) -> jax.Array:
-    """dequantize(quantize(rows)) in one shot: the fp values an int8
-    cache will hold after ``cache_update`` writes ``rows``.
-
-    The fused decode kernel (kernels/decode_step.py) attends over the NEW
-    token's K/V in-register before the host writes them; running the rows
-    through this first makes the fused step see exactly what the composed
-    path reads back from the quantized cache.  The kernel then returns
-    these fp rows and the host-side ``quantize_rows`` reproduces the same
-    int8 payload — requantizing a dequantized row is idempotent (the row
-    max is exactly scale·127, so the recovered scale matches bitwise and
-    every q/scale quotient rounds back to the same integer)."""
-    r32 = rows.astype(jnp.float32)
-    scale = jnp.max(jnp.abs(r32), axis=-1, keepdims=True) * _RCP127
-    scale = jnp.where(scale == 0, 1.0, scale)
-    deq = jnp.clip(jnp.round(r32 / scale), -127, 127) * scale
-    return deq.astype(rows.dtype)
 
 
 def dequantize_cache(cache: dict, dtype=jnp.float32) -> jax.Array:

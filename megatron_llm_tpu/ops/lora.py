@@ -44,8 +44,8 @@ import jax.numpy as jnp
 
 from ..config import ModelConfig
 
-# Adapter-targetable projections, in the order the fused decode kernel
-# applies them.  Keys name leaves of the stacked layer tree:
+# Adapter-targetable projections, in the order a layer applies them.
+# Keys name leaves of the stacked layer tree:
 # wq/wk/wv/wo under ["attn"], w_gate/w_up/w_down under ["mlp"].
 LORA_TARGETS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
 

@@ -28,10 +28,8 @@ Three leaf schemes, all plain dict subtrees so pytree machinery
 
 :class:`PrecisionPolicy` names which class (attention projections / MLP
 projections / embedding table) gets which scheme; ``quantize_params`` /
-``quantize_specs`` honor it end-to-end, and the fused decode kernels
-(kernels/decode_step.py) read the same structural tags to pick their
-mixed-precision variant.  Norm scales, biases, and the lm_head always
-stay unquantized (fp logits matter for sampling quality).
+``quantize_specs`` honor it end-to-end.  Norm scales, biases, and the
+lm_head always stay unquantized (fp logits matter for sampling quality).
 
 ``mm(x, w)`` is the single matmul dispatch point used by the transformer
 blocks: plain arrays go straight to ``@``; quantized subtrees dequantize
@@ -88,7 +86,7 @@ def quantize_weight(w: jax.Array) -> dict:
 def pack_int4(q: jax.Array) -> jax.Array:
     """int8 values in [-8, 7], [..., in, out] → packed [..., in/2, out]:
     even input row in the low nibble, odd row in the high nibble of each
-    byte (the order kernels/decode_step.py unpacks in-register)."""
+    byte."""
     *lead, rows, cols = q.shape
     pairs = q.reshape(*lead, rows // 2, 2, cols).astype(jnp.int32)
     word = ((pairs[..., 1, :] & 0xF) << 4) | (pairs[..., 0, :] & 0xF)
@@ -100,8 +98,7 @@ def unpack_int4(packed: jax.Array) -> jax.Array:
     """Inverse of :func:`pack_int4`: [..., in/2, out] → int8 [..., in, out].
 
     Sign extension via int32 shifts (``(p << 28) >> 28``) rather than
-    nibble-table lookups — the same arithmetic Mosaic lowers inside the
-    fused decode kernels, so host and kernel dequant agree bitwise."""
+    nibble-table lookups."""
     p32 = packed.astype(jnp.int32)
     low = (p32 << 28) >> 28
     high = (p32 << 24) >> 28
@@ -353,9 +350,9 @@ def quantize_params(params: dict, policy=None) -> dict:
     layer-stacked 3D weights only — convert pipeline checkpoints with
     ``parallel.pipeline.from_pipeline_params`` first, exactly as serving
     already requires.  An int4 class whose input dim the group size does
-    not divide falls back to int8 for that leaf (tiny test configs); the
-    fused-kernel eligibility matrix reads the actual leaves, never the
-    policy, so the fallback is visible, not silent corruption."""
+    not divide falls back to int8 for that leaf (tiny test configs);
+    ``mm`` and ``weight_bits`` read the actual leaves, never the policy,
+    so the fallback is visible, not silent corruption."""
     pol = resolve_policy(policy)
     prec_of = {**{k: pol.attn for k in _ATTN_LEAF_NAMES},
                **{k: pol.mlp for k in _MLP_LEAF_NAMES}}
@@ -455,7 +452,7 @@ def precision_route(params: dict) -> str:
     """Label for the decode precision route a param tree selects:
     "fp32" (no quantized projections — full model dtype), "int8",
     "int4", or "mixed".  Used by the serving engine to tag its
-    fused/fallback step counters per precision."""
+    paged/fallback step counters per precision."""
     bits = set()
 
     def walk(tree):

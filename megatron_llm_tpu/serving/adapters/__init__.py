@@ -1,7 +1,7 @@
 """Multi-tenant LoRA serving: adapter residency over one base model.
 
 The registry (:class:`AdapterRegistry`) owns the stacked device arena
-the fused decode kernels and the composed fallback both read, plus the
+the decode, verify and prefill steps read, plus the
 LRU + ref-pinning residency manager that decides which of the
 (potentially thousands of) registered adapters occupy its
 ``EngineConfig.adapter_cache_slots`` arena slots at any moment —

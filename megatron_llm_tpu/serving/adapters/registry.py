@@ -55,7 +55,7 @@ class AdapterRegistry:
 
     ``n_slots`` arena slots (``EngineConfig.adapter_cache_slots``), all
     adapters sharing one ``rank`` and one target set — the price of a
-    single stacked arena and a single fused-kernel geometry.  Register
+    single stacked arena and one compiled step.  Register
     any number of adapters host-side; at most ``n_slots`` are device-
     resident at once.
     """

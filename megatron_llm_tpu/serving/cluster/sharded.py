@@ -72,8 +72,7 @@ def build_sharded_engine(cfg: ModelConfig, params,
     it with the serving re-layout, and ``draft_params`` (resident draft
     model, if any) follow with their own config's specs.  With
     pp·tp·fsdp == 1 and no explicit devices this returns the ordinary
-    single-chip engine (mesh=None) so the fused single-device kernels
-    stay eligible.
+    single-chip engine (mesh=None).
 
     ``adapters`` (multi-tenant LoRA registry) is handed to the engine
     as-is; the arenas are tiny (rank · hidden per slot per target) and

@@ -56,10 +56,10 @@ Admission can run **chunked** (``EngineConfig.prefill_chunk``): a long
 prompt prefills at most ``prefill_chunk`` tokens per scheduler
 iteration, interleaved between decode steps, so admission no longer
 freezes every active stream's inter-token latency for the whole prompt
-(the Sarathi-Serve argument).  On eligible TPU configs the batched
-decode step itself runs as the fused whole-stack Pallas kernel
-(kernels/decode_step.py) with a per-slot fill vector — see
-models/model.py:forward_cached, which routes it automatically.
+(the Sarathi-Serve argument).  The batched decode step reads each
+slot's KV out of the pool through its block table where the paged
+attention kernel runs (a TPU) and over a gathered dense view elsewhere —
+models/model.py:forward_cached_paged decides from what it observes.
 
 Admission also consults the **automatic prefix cache**
 (``EngineConfig.prefix_cache_blocks``, prefix_cache.py): a request whose
@@ -77,12 +77,11 @@ most recent earlier occurrence of the context's trailing n-gram, the
 PLD idea of generation/speculative.py applied per slot over the paged
 cache — and ONE batched verify forward scores every slot's
 ``[pending, draft...]`` window at its own fill positions
-(models/model.py:forward_cached_paged_verify; on eligible TPU configs
-the multi-token fused kernel).  The longest draft prefix matching
-greedy argmax commits in a single iteration; position 0 samples exactly
-like a plain step, so acceptance can only reproduce what sequential
-decode would have emitted, bitwise, and non-greedy requests ride the
-verify batch with an empty draft, their trajectories untouched.
+(models/model.py:forward_cached_paged_verify).  The longest draft
+prefix matching greedy argmax commits in a single iteration; position 0
+samples exactly like a plain step, so acceptance can only reproduce what
+sequential decode would have emitted, bitwise, and non-greedy requests
+ride the verify batch with an empty draft, their trajectories untouched.
 Rejected drafts roll back by fill arithmetic alone: their K/V rows sit
 past the slot's fill level, masked out of attention, and later steps
 overwrite them in place — no block frees, no copies, COW and prefix
@@ -564,8 +563,7 @@ def _first_token_impl(cfg: ModelConfig, last_logits, seeds, counters,
 def _decode_impl(cfg: ModelConfig, params, k_pool, v_pool, tables, pending,
                  fills, seeds, counters, greedy, temps, top_ks, top_ps,
                  lora_arenas=None, lora_slots=None, rec=None, live=None, *,
-                 use_fused: bool, allow_paged: bool = True,
-                 lora_rank: int = 0):
+                 allow_paged: bool = True, lora_rank: int = 0):
     """One batched decode step over every slot: feed each slot's pending
     token at its own fill position, scatter its K/V row into the pool
     block its table names, sample the next token per slot.  Free slots
@@ -585,7 +583,7 @@ def _decode_impl(cfg: ModelConfig, params, k_pool, v_pool, tables, pending,
     else:
         logits, k_pool, v_pool = model_lib.forward_cached_paged(
             cfg, params, pending[:, None], k_pool, v_pool, tables, fills,
-            rope=rope, use_fused=use_fused, allow_paged=allow_paged,
+            rope=rope, allow_paged=allow_paged,
             lora=_lora_operand(lora_arenas, lora_slots, lora_rank))
     tok, tok_lp = _sample_slots(logits[:, 0], seeds, counters, greedy,
                                 temps, top_ks, top_ps, cfg.vocab_size)
@@ -593,19 +591,17 @@ def _decode_impl(cfg: ModelConfig, params, k_pool, v_pool, tables, pending,
 
 
 _decode_donated = functools.partial(
-    jax.jit,
-    static_argnames=("cfg", "use_fused", "allow_paged", "lora_rank"),
+    jax.jit, static_argnames=("cfg", "allow_paged", "lora_rank"),
     donate_argnums=(2, 3), donate_argnames=("rec",))(_decode_impl)
 _decode_plain = functools.partial(
-    jax.jit,
-    static_argnames=("cfg", "use_fused", "allow_paged", "lora_rank"))(
+    jax.jit, static_argnames=("cfg", "allow_paged", "lora_rank"))(
         _decode_impl)
 
 
 def _verify_impl(cfg: ModelConfig, params, k_pool, v_pool, tables, window,
                  fills, bids, offs, seeds, counters, greedy, temps, top_ks,
                  top_ps, lora_arenas=None, lora_slots=None, *,
-                 use_fused: bool, lora_rank: int = 0):
+                 lora_rank: int = 0):
     """One speculative verify step over every slot: feed each slot's
     ``[pending, draft...]`` window at its own fill positions and score
     ALL window positions in one forward
@@ -620,8 +616,7 @@ def _verify_impl(cfg: ModelConfig, params, k_pool, v_pool, tables, window,
     rope = model_lib.rope_tables(cfg)
     logits, k_pool, v_pool = model_lib.forward_cached_paged_verify(
         cfg, params, window, k_pool, v_pool, tables, fills, bids, offs,
-        rope=rope, use_fused=use_fused,
-        lora=_lora_operand(lora_arenas, lora_slots, lora_rank))
+        rope=rope, lora=_lora_operand(lora_arenas, lora_slots, lora_rank))
     tok0, tok0_lp = _sample_slots(logits[:, 0], seeds, counters, greedy,
                                   temps, top_ks, top_ps, cfg.vocab_size)
     V = logits.shape[-1]
@@ -636,17 +631,17 @@ def _verify_impl(cfg: ModelConfig, params, k_pool, v_pool, tables, window,
 
 
 _verify_donated = functools.partial(
-    jax.jit, static_argnames=("cfg", "use_fused", "lora_rank"),
+    jax.jit, static_argnames=("cfg", "lora_rank"),
     donate_argnums=(2, 3))(_verify_impl)
 _verify_plain = functools.partial(
-    jax.jit, static_argnames=("cfg", "use_fused", "lora_rank"))(_verify_impl)
+    jax.jit, static_argnames=("cfg", "lora_rank"))(_verify_impl)
 
 
 def _verify_tree_impl(cfg: ModelConfig, params, k_pool, v_pool, tables,
                       window, depths, anc, fills, bids, offs, seeds,
                       counters, greedy, temps, top_ks, top_ps,
                       lora_arenas=None, lora_slots=None, *,
-                      use_fused: bool, lora_rank: int = 0):
+                      lora_rank: int = 0):
     """Tree-verify twin of ``_verify_impl``: the window columns are the
     nodes of a per-slot candidate tree (``depths``/``anc``, see
     forward_cached_paged_verify) instead of a linear run, so one forward
@@ -661,7 +656,7 @@ def _verify_tree_impl(cfg: ModelConfig, params, k_pool, v_pool, tables,
     rope = model_lib.rope_tables(cfg)
     logits, k_pool, v_pool = model_lib.forward_cached_paged_verify(
         cfg, params, window, k_pool, v_pool, tables, fills, bids, offs,
-        rope=rope, use_fused=use_fused, tree=(depths, anc),
+        rope=rope, tree=(depths, anc),
         lora=_lora_operand(lora_arenas, lora_slots, lora_rank))
     tok0, tok0_lp = _sample_slots(logits[:, 0], seeds, counters, greedy,
                                   temps, top_ks, top_ps, cfg.vocab_size)
@@ -677,11 +672,10 @@ def _verify_tree_impl(cfg: ModelConfig, params, k_pool, v_pool, tables,
 
 
 _verify_tree_donated = functools.partial(
-    jax.jit, static_argnames=("cfg", "use_fused", "lora_rank"),
+    jax.jit, static_argnames=("cfg", "lora_rank"),
     donate_argnums=(2, 3))(_verify_tree_impl)
 _verify_tree_plain = functools.partial(
-    jax.jit, static_argnames=("cfg", "use_fused", "lora_rank"))(
-        _verify_tree_impl)
+    jax.jit, static_argnames=("cfg", "lora_rank"))(_verify_tree_impl)
 
 
 # number of candidate branches the resident draft model surfaces per
@@ -692,7 +686,7 @@ _DRAFT_TOPK = 2
 
 
 def _draft_step_impl(cfg: ModelConfig, params, k_pool, v_pool, tables,
-                     window, fills, bids, offs, *, use_fused: bool):
+                     window, fills, bids, offs):
     """One resident-draft forward over the draft model's shadow pool:
     a chain verify of up to W tokens per slot at the slot's own draft
     positions, returning the top-``_DRAFT_TOPK`` candidate tokens per
@@ -707,7 +701,7 @@ def _draft_step_impl(cfg: ModelConfig, params, k_pool, v_pool, tables,
     rope = model_lib.rope_tables(cfg)
     logits, k_pool, v_pool = model_lib.forward_cached_paged_verify(
         cfg, params, window, k_pool, v_pool, tables, fills, bids, offs,
-        rope=rope, use_fused=use_fused)
+        rope=rope)
     V = logits.shape[-1]
     pad = jnp.arange(V) >= cfg.vocab_size
     masked = jnp.where(pad[None, None, :], NEG_INF, logits)
@@ -716,10 +710,10 @@ def _draft_step_impl(cfg: ModelConfig, params, k_pool, v_pool, tables,
 
 
 _draft_step_donated = functools.partial(
-    jax.jit, static_argnames=("cfg", "use_fused"),
+    jax.jit, static_argnames=("cfg",),
     donate_argnums=(2, 3))(_draft_step_impl)
 _draft_step_plain = functools.partial(
-    jax.jit, static_argnames=("cfg", "use_fused"))(_draft_step_impl)
+    jax.jit, static_argnames=("cfg",))(_draft_step_impl)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "max_seq_len"))
@@ -986,9 +980,6 @@ def _refuse_for_hybrid(cfg: ModelConfig, config: EngineConfig, mesh,
         (adapters is not None or config.adapter_cache_slots,
          "adapters: no LoRA epilogue on a recurrent mixer's or the "
          "experts' matmuls"),
-        (cfg.fused_decode,
-         "fused_decode=True: the whole-stack decode kernel runs dense "
-         "RMSNorm+GLU attention layers only"),
         (config.prefill_chunk,
          "prefill_chunk: the recurrent state is not carried from chunk "
          "to chunk"),
@@ -1190,12 +1181,7 @@ class ServingEngine:
             {"gdn": "fused"} if cfg.linear_layers else {}))
         self._admit_count = 0        # this iteration's admissions
         self._admit_tokens = 0       # and their prompt tokens
-        # whether forward_cached routes this config's slot batch through
-        # the fused decode kernel — resolved once at start() (the
-        # predicate is static in cfg/params/cache shape) and used to
-        # attribute each decode iteration to fused_steps/fallback_steps
-        self._fused_decode = False
-        # the composed decode step decides for itself whether it reads KV
+        # the decode step decides for itself whether it reads KV
         # through the block tables inside the paged attention kernel
         # (models/model.py:forward_cached_paged) — except while
         # speculating: the verify step walks the gather route's
@@ -1203,13 +1189,11 @@ class ServingEngine:
         # the same predicate asked at start(), to label the steps.
         self._allow_paged = self.config.spec_draft_len == 0
         self._paged_decode = False
-        self._fused_verify = False  # same, for the multi-token verify step
-        self._fused_draft = False   # same, for the draft model's forwards
         # draft model actually engaged: resident params AND speculation on
         self._draft_enabled = (self.draft_cfg is not None
                                and self.config.spec_draft_len > 0)
         # weight precision route (ops/quant.py:precision_route) labelling
-        # the fused/fallback counters per precision — resolved at start()
+        # the paged/fallback counters per precision — resolved at start()
         self._precision_route = "fp32"
 
     # -- lifecycle ---------------------------------------------------------
@@ -1263,34 +1247,10 @@ class ServingEngine:
                         host_tier=self.host_tier)
                 from ..ops.quant import precision_route
                 self._precision_route = precision_route(self.params)
-                from ..kernels.decode_step import fused_paged_decode_eligible
-                # adapter arenas ride inside the fused kernels as an
-                # epilogue; the stacked rank participates in the VMEM
-                # budget and the predicate declines to fuse (the composed
-                # path still applies the adapter — never silently
-                # dropped) when it doesn't fit or isn't lane-aligned
-                lsr = 0 if self.adapters is None else self.adapters.sr
-                self._fused_decode = fused_paged_decode_eligible(
-                    self.cfg, self.params, pool.k_pool,
-                    cfg_e.max_batch_size, self.slots.table_blocks,
-                    jax.default_backend(), mesh=self.mesh, lora_sr=lsr)
                 self._paged_decode = (
-                    not self._fused_decode and self._allow_paged
+                    self._allow_paged
                     and model_lib.paged_decode_eligible(
                         self.cfg, pool.k_pool, mesh=self.mesh))
-                if cfg_e.spec_draft_len > 0:
-                    from ..kernels.decode_step import (
-                        fused_paged_verify_eligible)
-                    # tree mode widens two splice temps to full (b, nkv,
-                    # block_k, d) broadcasts, so eligibility is resolved
-                    # against the stricter VMEM budget when a draft model
-                    # will be proposing trees
-                    self._fused_verify = fused_paged_verify_eligible(
-                        self.cfg, self.params, pool.k_pool,
-                        cfg_e.max_batch_size, cfg_e.spec_draft_len + 1,
-                        self.slots.table_blocks, jax.default_backend(),
-                        mesh=self.mesh, tree=self._draft_enabled,
-                        lora_sr=lsr)
                 if self._draft_enabled:
                     # shadow paged pool for the draft model: SAME block
                     # count and block size as the target pool so the
@@ -1306,13 +1266,6 @@ class ServingEngine:
                         dk, dv = model_lib.init_kv_pool(
                             self.draft_cfg, n_blocks, bk)
                     self._draft_kv = (dk, dv)
-                    from ..kernels.decode_step import (
-                        fused_paged_verify_eligible)
-                    self._fused_draft = fused_paged_verify_eligible(
-                        self.draft_cfg, self.draft_params, dk,
-                        cfg_e.max_batch_size, cfg_e.spec_draft_len + 1,
-                        self.slots.table_blocks, jax.default_backend(),
-                        mesh=self.mesh)
                 self._update_pool_gauges()
                 if self.slots.rec is not None:
                     self.metrics.set_gauges(
@@ -2191,8 +2144,6 @@ class ServingEngine:
     def _decode_route(self) -> str:
         """The plain decode step's route, as the ``engine_step`` span and
         the ``<route>_steps`` counters name it."""
-        if self._fused_decode:
-            return "fused"
         return "paged" if self._paged_decode else "fallback"
 
     def _spec_budget(self, st: _SlotState) -> int:
@@ -2375,8 +2326,7 @@ class ServingEngine:
                 cand, dk, dv = self._draft_step(
                     self.draft_cfg, self.draft_params, dk, dv, tables,
                     jnp.asarray(window), jnp.asarray(fills_d),
-                    jnp.asarray(bids_d), jnp.asarray(offs_d),
-                    use_fused=self._fused_draft)
+                    jnp.asarray(bids_d), jnp.asarray(offs_d))
             if finishing:
                 # tpulint: allow[host-sync] draft candidates feed the
                 # host-side tree packer; nothing to overlap
@@ -2416,8 +2366,7 @@ class ServingEngine:
             with device_annotation("draft_expand"):
                 cand, dk, dv = self._draft_step(
                     self.draft_cfg, self.draft_params, dk, dv, tables,
-                    jnp.asarray(window), jnp.asarray(fills_d), trash,
-                    trash, use_fused=self._fused_draft)
+                    jnp.asarray(window), jnp.asarray(fills_d), trash, trash)
             # tpulint: allow[host-sync] chain growth is host-driven
             cand = np.asarray(cand)
             for slot in growing:
@@ -2489,8 +2438,7 @@ class ServingEngine:
         # what the device's predicate in _sample_slots will read
         sampling = not greedy.all()
         self.metrics.inc_step(
-            "fused" if self._fused_verify else "fallback",
-            self._precision_route, sampling)
+            "fallback", self._precision_route, sampling)
         with device_annotation("verify"):
             g_tok, g_lp, k_pool, v_pool = self._verify(
                 self.cfg, self.params, self.slots.k_pool,
@@ -2499,7 +2447,6 @@ class ServingEngine:
                 jnp.asarray(seeds), jnp.asarray(counters),
                 jnp.asarray(greedy), jnp.asarray(temps),
                 jnp.asarray(top_ks), jnp.asarray(top_ps),
-                use_fused=self._fused_verify,
                 **self._lora_args(aslots))
         self.slots.set_pools(k_pool, v_pool)
         # tpulint: allow[host-sync] verify steps are synchronous by
@@ -2566,8 +2513,7 @@ class ServingEngine:
         self.trace.add(
             "engine_step", it0, time.perf_counter(), tid=0,
             args={"iter": self._iter, "batch": len(drafts),
-                  "route": ("spec_fused" if self._fused_verify
-                            else "spec_fallback"),
+                  "route": "spec_fallback",
                   "sampling": sampling,
                   "pipelined": False, "proposed": proposed,
                   "accepted": accepted_total})
@@ -2702,8 +2648,7 @@ class ServingEngine:
         # what the device's predicate in _sample_slots will read
         sampling = not greedy.all()
         self.metrics.inc_step(
-            "fused" if self._fused_verify else "fallback",
-            self._precision_route, sampling)
+            "fallback", self._precision_route, sampling)
         with device_annotation("verify_tree"):
             g_tok, g_lp, k_pool, v_pool = self._verify_tree(
                 self.cfg, self.params, self.slots.k_pool,
@@ -2713,7 +2658,6 @@ class ServingEngine:
                 jnp.asarray(seeds), jnp.asarray(counters),
                 jnp.asarray(greedy), jnp.asarray(temps),
                 jnp.asarray(top_ks), jnp.asarray(top_ps),
-                use_fused=self._fused_verify,
                 **self._lora_args(aslots))
         # tpulint: allow[host-sync] verify steps are synchronous by
         # design: the accepted path decides the next fill vector AND
@@ -2826,8 +2770,7 @@ class ServingEngine:
         self.trace.add(
             "engine_step", it0, time.perf_counter(), tid=0,
             args={"iter": self._iter, "batch": len(plans),
-                  "route": ("spec_fused" if self._fused_verify
-                            else "spec_fallback"),
+                  "route": "spec_fallback",
                   "sampling": sampling,
                   "pipelined": False, "tree": True, "proposed": proposed,
                   "accepted": accepted_total})
@@ -2926,7 +2869,6 @@ class ServingEngine:
                     jnp.asarray(seeds[sl]), jnp.asarray(counters[sl]),
                     jnp.asarray(greedy[sl]), jnp.asarray(temps[sl]),
                     jnp.asarray(top_ks[sl]), jnp.asarray(top_ps[sl]),
-                    use_fused=self._fused_decode,
                     allow_paged=self._allow_paged,
                     **self._lora_args(aslots[sl]), **state)
                 toks.append(tok)
@@ -3518,8 +3460,7 @@ class ServingEngine:
         sampled token is lost, duplicated, or recomputed; every later
         step runs the new weights.  The tree must match the resident
         params' structure/shapes/dtypes exactly, so every compiled
-        executable (and the fused-kernel eligibility resolved at
-        ``start()``) carries over with zero recompiles.  Adapter arenas
+        executable carries over with zero recompiles.  Adapter arenas
         are untouched: LoRA factors compose with whichever base is
         resident.  In-flight requests simply continue — mid-generation
         tokens after the fence come from the new weights, which is the

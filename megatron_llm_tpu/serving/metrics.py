@@ -112,16 +112,12 @@ _COUNTERS = (
     "submitted", "admitted", "completed", "cancelled", "timeouts",
     "rejected_queue_full", "rejected_invalid", "rejected_draining",
     "prefills", "prefill_chunks", "decode_iterations", "decode_tokens",
-    # fused-kernel routing (kernels/decode_step.py): decode iterations
-    # through the fused whole-stack kernel vs the composed per-op path.
-    # An int8 config silently losing eligibility shows up here as
-    # fallback_steps climbing where fused_steps should.
-    # paged_steps: composed decode steps whose attention read the KV
-    # pool through the block tables inside the paged kernel
-    # (models/model.py:forward_cached_paged) — on a TPU every composed
-    # decode step of a non-speculating engine should land here, not in
-    # fallback_steps (the gather route).
-    "fused_steps", "fallback_steps", "paged_steps",
+    # decode-step routing.  paged_steps: decode steps whose attention
+    # read the KV pool through the block tables inside the paged kernel
+    # (models/model.py:forward_cached_paged) — on a TPU every decode
+    # step of a non-speculating engine should land here, not in
+    # fallback_steps (the gather route, and every verify step).
+    "fallback_steps", "paged_steps",
     # decode/verify iterations in which at least one slot sampled, so the
     # step's ``cond`` took the sampler's branch (engine.py:_sample_slots:
     # one ordering of the vocabulary, the nucleus, the draw).  All-greedy
@@ -270,11 +266,8 @@ class ServingMetrics:
         # ``expert_rows`` likewise: the rows the experts' kernel
         # multiplied and skipped
         self.expert_rows = None
-        # fused/fallback decode iterations keyed by the weight precision
-        # route (ops/quant.py:precision_route: fp32/int8/int4/mixed) —
-        # a per-precision regression to the composed path (e.g. an int4
-        # config losing kernel eligibility after a geometry change) is
-        # invisible in the aggregate counters but obvious here
+        # paged/fallback decode iterations keyed by the weight precision
+        # route (ops/quant.py:precision_route: fp32/int8/int4/mixed)
         self.step_routes: dict = {}
         # speculative counters broken down by where the draft came from
         # ("ngram" = host prompt-lookup, "model" = resident draft model
@@ -296,7 +289,7 @@ class ServingMetrics:
     def inc_step(self, route: str, precision: str = "fp32",
                  sampling: bool = False) -> None:
         """One decode/verify iteration by the ``route`` it took —
-        ``"fused"``, ``"paged"`` or ``"fallback"``: bumps the aggregate
+        ``"paged"`` or ``"fallback"``: bumps the aggregate
         ``<route>_steps`` counter AND its per-precision breakdown
         (``precision`` from ops/quant.py:precision_route), and
         ``sampled_steps`` where a slot of it ``sampling``."""
@@ -304,7 +297,7 @@ class ServingMetrics:
             self.counters[f"{route}_steps"] += 1
             self.counters["sampled_steps"] += bool(sampling)
             r = self.step_routes.setdefault(
-                precision, {"fused": 0, "paged": 0, "fallback": 0})
+                precision, {"paged": 0, "fallback": 0})
             r[route] += 1
 
     def add_ssm_positions(self, phase: str, n: int) -> None:
@@ -491,9 +484,6 @@ class ServingMetrics:
                 "accepted_tokens_per_step":
                     self.accepted_per_step.snapshot(suffix=""),
                 # decode-step routing by weight precision (inc_step)
-                "fused_steps_by_precision": {
-                    route: r["fused"]
-                    for route, r in sorted(self.step_routes.items())},
                 "fallback_steps_by_precision": {
                     route: r["fallback"]
                     for route, r in sorted(self.step_routes.items())},
@@ -536,22 +526,18 @@ class ServingMetrics:
                     f"serving lifecycle counter: {name}").add(
                         self.counters[name]))
             if self.step_routes:
-                fused_fam = MetricFamily(
-                    "serving_fused_steps_by_precision_total", "counter",
-                    "fused decode iterations by weight precision route")
                 fb_fam = MetricFamily(
                     "serving_fallback_steps_by_precision_total", "counter",
-                    "composed-path decode iterations by weight precision "
-                    "route")
+                    "gather-route decode and verify iterations by weight "
+                    "precision route")
                 paged_fam = MetricFamily(
                     "serving_paged_steps_by_precision_total", "counter",
-                    "composed decode iterations through the paged "
-                    "attention kernel by weight precision route")
+                    "decode iterations through the paged attention "
+                    "kernel by weight precision route")
                 for route, r in sorted(self.step_routes.items()):
-                    fused_fam.add(r["fused"], labels={"precision": route})
                     fb_fam.add(r["fallback"], labels={"precision": route})
                     paged_fam.add(r["paged"], labels={"precision": route})
-                fams.extend([fused_fam, fb_fam, paged_fam])
+                fams.extend([fb_fam, paged_fam])
             if self.spec_by_source:
                 by_src = {
                     "steps": MetricFamily(

@@ -9,7 +9,7 @@ Then open ``trace.json`` in ``chrome://tracing`` or https://ui.perfetto.dev.
 Each request renders as its own track (``tid`` = request id) with its
 ``queued`` → ``prefix_match`` / ``prefill`` / ``prefill_chunk[i]`` →
 ``decode`` → ``retire`` spans; track 0 carries the engine's per-iteration
-``engine_step`` spans (batch size and fused/fallback routing in ``args``).
+``engine_step`` spans (batch size and paged/fallback routing in ``args``).
 See docs/observability.md.
 """
 

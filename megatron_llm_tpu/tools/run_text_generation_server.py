@@ -172,9 +172,8 @@ def main(argv=None) -> int:
                          "decode HBM traffic; int4 = group-wise int4 "
                          "projections + int8 embedding, quarters it; "
                          "mixed = int8 attention / int4 MLP / int8 "
-                         "embedding). All three stream through the fused "
-                         "decode kernels with dequant fused in the tile "
-                         "load (kernels/decode_step.py); compose with "
+                         "embedding). All three dequantize into the "
+                         "matmul (ops/quant.py:mm); compose with "
                          "--kv_quant int8 for full low-bit residency")
     ap.add_argument("--quant_group_size", type=int, default=None,
                     help="int4 group size (rows per scale group) for "
@@ -206,7 +205,7 @@ def main(argv=None) -> int:
                          "model lives on-device next to the target, "
                          "drafts top-k branch trees each iteration, and "
                          "the target verifies the whole tree in one "
-                         "fused forward (docs/serving.md, 'Tree "
+                         "forward (docs/serving.md, 'Tree "
                          "speculation & resident drafts').  Beats the "
                          "n-gram drafter on random traffic; requires "
                          "--draft_len > 0.  Draft vocab/positions are "

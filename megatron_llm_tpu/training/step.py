@@ -126,10 +126,10 @@ def compute_loss(cfg: RuntimeConfig, params, batch: dict, rng=None,
     if mean is None:
         batch = zigzag_permute_batch(cfg, batch)
 
-    use_fused = (cfg.model.fused_lm_head
-                 and cfg.parallel.tensor_parallel == 1
-                 and cfg.parallel.context_parallel == 1)
-    if use_fused:
+    fused_head = (cfg.model.fused_lm_head
+                  and cfg.parallel.tensor_parallel == 1
+                  and cfg.parallel.context_parallel == 1)
+    if fused_head:
         from ..models.model import forward_hidden, unembed_weight
         from ..parallel.cross_entropy import fused_linear_cross_entropy
 

@@ -97,7 +97,7 @@ def test_dequant_flagged_anywhere_in_kernels():
         def _launch(w):
             return dequantize_weight(w)
     """
-    findings = _analyze(src, path="megatron_llm_tpu/kernels/decode_step.py")
+    findings = _analyze(src, path="megatron_llm_tpu/kernels/flash_decode.py")
     assert [(f.line, f.rule) for f in findings] == [(5, "dequant-hot-path")]
 
 

@@ -30,7 +30,6 @@ from jax.sharding import (  # noqa: E402
 
 from megatron_llm_tpu import kernels  # noqa: E402
 from megatron_llm_tpu.config import ParallelConfig, llama2_config  # noqa: E402
-from megatron_llm_tpu.kernels import decode_step as ds  # noqa: E402
 from megatron_llm_tpu.kernels import flash_decode as fd  # noqa: E402
 from megatron_llm_tpu.kernels.flash_attention import flash_attention  # noqa: E402
 from megatron_llm_tpu.kernels.grouped_matmul import (  # noqa: E402
@@ -42,7 +41,6 @@ from megatron_llm_tpu.kernels.rmsnorm import (  # noqa: E402
     rmsnorm_pallas,
 )
 from megatron_llm_tpu.models import model as model_lib  # noqa: E402
-from megatron_llm_tpu.models.transformer import rope_tables  # noqa: E402
 from megatron_llm_tpu.obs.hlo_audit import (  # noqa: E402
     ops_by_conditional,
     ops_under_scopes,
@@ -396,8 +394,7 @@ def test_a_state_space_decode_step_rewrites_its_states_in_place(
     compiled = engine_lib._decode_donated.lower(
         cfg, place(params), *place(pool), place(_sds((S, blocks), i32)),
         vec(i32), vec(i32), vec(jnp.uint32), vec(i32), vec(bool), vec(f32),
-        vec(i32), vec(f32), use_fused=False, rec=place(rec),
-        live=vec(bool)).compile()
+        vec(i32), vec(f32), rec=place(rec), live=vec(bool)).compile()
     mem = compiled.memory_analysis()
     donated = sum(a.size * a.dtype.itemsize
                   for a in jax.tree.leaves((pool, rec)))
@@ -460,20 +457,20 @@ def test_a_dropless_prefill_routes_through_the_grouped_kernel(topo,
             result, path)
 
 
-# -- whole-stack fused decode kernels (the geometry that is eligible) ------
+# -- the decode and verify steps of a Llama-family stack, by operand kind ---
 
 def _stack_cfg(kv_quant="none", wide=False):
-    # Llama stacks the fused kernels accept (7B-width layers exceed their
-    # VMEM budget).  wide: the 374M Llama geometry.  Else a quarter of
-    # its hidden size — Mosaic's compile time grows with the square of it
-    # (int4 at 1024: 40 s) — at the SAME ffn, so w_down still streams 11
-    # int4 scale groups per MLP chunk, the count that broke the block rule
+    # Llama stacks (RMSNorm, SwiGLU, rotary, heads of 128) as an engine
+    # with quantised weights, an int8 pool, adapters or speculation runs
+    # them.  wide: the 374M Llama geometry.  Else a quarter of its hidden
+    # size at the SAME ffn, so w_down still has 11 int4 scale groups of
+    # 256 rows
     hidden, heads = (1024, 8) if wide else (256, 2)
     return llama2_config(
         "7b", hidden_size=hidden, num_layers=2, num_attention_heads=heads,
         num_kv_heads=heads, ffn_hidden_size=2816, seq_length=1024,
         max_position_embeddings=1024, params_dtype="bfloat16",
-        kv_cache_quant=kv_quant)
+        kv_cache_quant=kv_quant, attention_impl="flash", vocab_size=2048)
 
 
 def _stack_params(cfg, policy):
@@ -483,79 +480,114 @@ def _stack_params(cfg, policy):
     return jax.eval_shape(build)
 
 
-def _lora(cfg, rows):
-    arenas = jax.eval_shape(
-        lambda: lora_ops.make_arenas(cfg, 16, 8, lora_ops.LORA_TARGETS))
-    return arenas, _sds((rows, 128), jnp.float32)
-
-
 _PRECISIONS = {
     "bf16": (None, "none"), "int8": ("int8", "int8"),
     "int4": ("int4", "none"), "mixed": ("mixed", "none"),
     "lora": (None, "none"),
 }
+_LORA_SLOTS, _LORA_RANK = 16, 8
+
+
+def _step_args(cfg, params, slots, t, bk, nb, one, lora=False):
+    """Shape-only arguments of the engine's step programs on one chip,
+    in their order up to the sampling knobs, and the adapters' keywords."""
+    place = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
+    vec = lambda dtype, *s: place(_sds(s or (slots,), dtype))  # noqa: E731
+    pools = jax.eval_shape(lambda: model_lib.init_kv_pool(cfg, nb, bk))
+    knobs = (vec(jnp.uint32), vec(jnp.int32), vec(jnp.bool_),
+             vec(jnp.float32), vec(jnp.int32), vec(jnp.float32))
+    kw = {}
+    if lora:
+        arenas = jax.eval_shape(lambda: lora_ops.make_arenas(
+            cfg, _LORA_SLOTS, _LORA_RANK, lora_ops.LORA_TARGETS))
+        kw = dict(lora_arenas=place(arenas), lora_slots=vec(jnp.int32),
+                  lora_rank=_LORA_RANK)
+    return place(params), place(pools), vec, knobs, kw
 
 
 @pytest.mark.parametrize("precision", list(_PRECISIONS))
 @pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
-def test_fused_decode_step(topo, paged, precision):
+def test_composed_decode_step(topo, monkeypatch, paged, precision):
+    """The one decode step there is, compiled for the chip over each kind
+    of operand a Llama-family engine hands it: bf16, int8 weights over an
+    int8 cache, int4, mixed, and adapter arenas.  ``dense``:
+    ``forward_cached`` over per-slot fills (a verify walk's step, the
+    one-shot generator's).  ``paged``: the engine's decode executable,
+    whose attention reads the pool through the tables inside the paged
+    kernel — nothing gathers the pool or has its shape."""
+    from megatron_llm_tpu.serving import engine as engine_lib
+
+    monkeypatch.setattr(attn_ops, "_backend", lambda: "tpu")
+    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
     policy, kvq = _PRECISIONS[precision]
     cfg = _stack_cfg(kvq, wide=precision == "bf16")
     params = _stack_params(cfg, policy)
     one = SingleDeviceSharding(topo.devices[0])
-    b, max_len, bk = 8, 1024, 128
-    rope = rope_tables(cfg)
-    x, fills = _sds((b, cfg.hidden_size)), _sds((b,), jnp.int32)
-    lora = _lora(cfg, b) if precision == "lora" else None
-    lsr = 128 if lora else 0
-    if paged:
-        nb, t = 32, max_len // bk
-        k, v = jax.eval_shape(lambda: model_lib.init_kv_pool(cfg, nb, bk))
-        assert ds.fused_paged_decode_eligible(cfg, params, k, b, t, "tpu",
-                                              lora_sr=lsr)
-
-        def fn(layers, x, k, v, tables, fills, lora):
-            return ds.fused_decode_step_paged(
-                cfg, layers, x, k, v, tables, fills, rope, lora=lora,
-                interpret=False)
-        args = (params["layers"], x, k, v, _sds((b, t), jnp.int32), fills,
-                lora)
-    else:
-        k, v = jax.eval_shape(
+    # (a pool too large for XLA to stage in fast memory, as a served one
+    # is: else the module copies it there and back)
+    b, max_len, bk, nb = 8, 1024, 128, 2049
+    t = max_len // bk
+    lora = precision == "lora"
+    params, pools, vec, knobs, kw = _step_args(cfg, params, b, t, bk, nb,
+                                               one, lora)
+    if not paged:
+        cache = jax.eval_shape(
             lambda: model_lib.init_kv_cache(cfg, b, max_len))
-        assert ds.fused_decode_eligible(cfg, params, k, 1, "tpu", lsr)
 
-        def fn(layers, x, k, v, fills, lora):
-            return ds.fused_decode_step(
-                cfg, layers, x, k, v, fills, rope, lora=lora,
-                interpret=False)
-        args = (params["layers"], x, k, v, fills, lora)
-    _compile(fn, args, one)
+        def fn(params, tokens, k, v, fills, arenas, slots):
+            return model_lib.forward_cached(
+                cfg, params, tokens, k, v, fills, lora=(
+                    engine_lib._lora_operand(arenas, slots, _LORA_RANK)
+                    if lora else None))
+
+        text = _compile(fn, (params, _sds((b, 1), jnp.int32), *cache,
+                             _sds((b,), jnp.int32), kw.get("lora_arenas"),
+                             kw.get("lora_slots")), one)
+        assert "flash_decode" in text
+        return
+    assert model_lib.paged_decode_eligible(cfg, pools[0])
+    text = engine_lib._decode_donated.lower(
+        cfg, params, *pools, vec(jnp.int32, b, t), vec(jnp.int32),
+        vec(jnp.int32), *knobs, **kw).compile().as_text()
+    assert "tpu_custom_call" in text and "flash_decode" in text
+    pool = "s8" if kvq == "int8" else "bf16"
+    heads = cfg.kv_heads
+    _no_copy_of(text, f"{pool}[{cfg.num_layers},{nb},{heads},{bk}")
+    # (the gather route's dense view of every slot's table)
+    _no_copy_of(text, f"{pool}[{cfg.num_layers},{b * t},{heads},{bk}")
 
 
 @pytest.mark.parametrize("variant", ["linear", "linear_int8", "tree"])
-def test_fused_decode_verify(topo, variant):
+def test_composed_verify(topo, monkeypatch, variant):
+    """The engine's verify executables — a linear window over bf16 and
+    over int8 weights and pool, and a candidate tree — compile for the
+    chip: the window walked a token at a time over one gathered view,
+    each step's attention the dense decode kernel."""
+    from megatron_llm_tpu.serving import engine as engine_lib
+
+    monkeypatch.setattr(attn_ops, "_backend", lambda: "tpu")
+    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
     kvq = "int8" if variant == "linear_int8" else "none"
     cfg = _stack_cfg(kvq)
     params = _stack_params(cfg, "int8" if kvq == "int8" else None)
     one = SingleDeviceSharding(topo.devices[0])
-    s, w, max_len, bk, nb = 4, 4, 1024, 128, 32
+    s, w, max_len, bk, nb = 4, 4, 1024, 128, 33
     t = max_len // bk
-    tree = variant == "tree"
-    rope = rope_tables(cfg)
-    k, v = jax.eval_shape(lambda: model_lib.init_kv_pool(cfg, nb, bk))
-    assert ds.fused_paged_verify_eligible(cfg, params, k, s, w, t, "tpu",
-                                          tree=tree)
-
-    def fn(layers, x, k, v, tables, fills, depths, anc):
-        return ds.fused_decode_verify_paged(
-            cfg, layers, x, k, v, tables, fills, rope,
-            depths=depths if tree else None, anc=anc if tree else None,
-            interpret=False)
-
-    _compile(fn, (params["layers"], _sds((s, w, cfg.hidden_size)), k, v,
-                  _sds((s, t), jnp.int32), _sds((s,), jnp.int32),
-                  _sds((s, w), jnp.int32), _sds((s, w, w), jnp.int32)), one)
+    params, pools, vec, knobs, _ = _step_args(cfg, params, s, t, bk, nb,
+                                              one)
+    i32 = jnp.int32
+    window, rows = vec(i32, s, w), vec(i32, s * w)
+    if variant == "tree":
+        lowered = engine_lib._verify_tree_donated.lower(
+            cfg, params, *pools, vec(i32, s, t), window, vec(i32, s, w),
+            vec(i32, s, w, w), vec(i32), rows, rows, *knobs)
+    else:
+        lowered = engine_lib._verify_donated.lower(
+            cfg, params, *pools, vec(i32, s, t), window, vec(i32), rows,
+            rows, *knobs)
+    text = lowered.compile().as_text()
+    assert text.count("flash_decode") >= w * cfg.num_layers
 
 
 # -- kernels under a mesh: every pallas_call inside a fully manual shard_map
@@ -649,8 +681,8 @@ def test_composed_decode_step_touches_only_live_kv(topo, monkeypatch, size,
         text = engine_lib._decode_donated.lower(
             cfg, place(params, param_at), *place(pools, pool_at),
             vec(i32, slots, t), vec(i32), vec(i32), vec(i32), vec(i32),
-            vec(jnp.bool_), vec(f32), vec(i32), vec(f32),
-            use_fused=False).compile().as_text()
+            vec(jnp.bool_), vec(f32), vec(i32), vec(f32)
+            ).compile().as_text()
     assert "tpu_custom_call" in text
     kv = cfg.kv_heads // max(tp, 1)             # a device's share
     _no_copy_of(text, f"bf16[{layers},{nb},{kv},{bk},64]")

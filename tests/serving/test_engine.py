@@ -723,11 +723,9 @@ class TestPrecisionPolicies:
             assert r.tokens == _reference(cfg, qparams, p, 10)
 
         # decode iterations attributed to the policy's precision route
-        # (on CPU every step takes the composed path, so the fallback
+        # (on CPU every step takes the gather route, so the fallback
         # breakdown is where the label must land)
         snap = engine.metrics.snapshot()
-        routes = {**snap["fused_steps_by_precision"],
-                  **snap["fallback_steps_by_precision"]}
-        assert set(routes) == {policy}
-        assert sum(snap["fallback_steps_by_precision"].values()) + \
-            sum(snap["fused_steps_by_precision"].values()) > 0
+        assert set(snap["fallback_steps_by_precision"]) == {policy}
+        assert snap["fallback_steps_by_precision"][policy] > 0
+        assert snap["paged_steps_by_precision"] == {policy: 0}

@@ -80,9 +80,7 @@ def test_decode_matches_one_shot(tiny, pipeline):
 
 @pytest.fixture(scope="module")
 def tiny_int8(tiny):
-    """The tiny model fully int8-resident: quantized weights + int8 KV.
-    max_position_embeddings=128 keeps the fused kernel's block_k >= 128
-    constraint satisfiable when tests force the fused path on CPU."""
+    """The tiny model fully int8-resident: quantized weights + int8 KV."""
     import dataclasses
 
     from megatron_llm_tpu.ops.quant import quantize_params
@@ -95,8 +93,8 @@ def tiny_int8(tiny):
 def test_int8_decode_matches_one_shot_pipelined(tiny_int8):
     """Bitwise one-shot equivalence for a fully int8 model (int8 weights
     + int8 KV dict cache) under the pipelined scheduler, and the
-    fused/fallback routing counters: on CPU the static eligibility
-    predicate rejects (platform), so every step must count as fallback."""
+    routing counters: on CPU the route predicate declines (platform), so
+    every step must count as fallback."""
     cfg, params = tiny_int8
     rng = np.random.default_rng(7)
     prompts = [rng.integers(1, cfg.vocab_size,
@@ -110,57 +108,10 @@ def test_int8_decode_matches_one_shot_pipelined(tiny_int8):
         assert r.tokens == _reference(cfg, params, p, n)
     snap = engine.metrics.snapshot()
     assert snap["max_decode_batch"] >= 2
-    assert snap["fused_steps"] == 0
+    assert snap["paged_steps"] == 0
     # counts DISPATCHED steps: may exceed committed decode_iterations by
     # the pipeline's final speculative step, never undercount them
     assert snap["fallback_steps"] >= snap["decode_iterations"] > 0
-
-
-def test_int8_slot_batch_routes_through_fused_kernel(tiny_int8):
-    """The serving slot batch really runs the int8 fused kernel: with
-    eligibility forced (CPU would reject on platform alone; the kernel
-    itself runs in interpret mode), a 4-slot pipelined batch must commit
-    the same tokens as a 1-slot engine — the kernel's rows are
-    independent, so slot batching may not perturb any trajectory — and
-    the fused_steps counter must attribute the iterations."""
-    import megatron_llm_tpu.kernels.decode_step as ds
-
-    cfg, params = tiny_int8
-    rng = np.random.default_rng(8)
-    prompts = [rng.integers(1, cfg.vocab_size,
-                            int(rng.integers(3, 9))).tolist()
-               for _ in range(4)]
-    max_news = [int(rng.integers(4, 10)) for _ in range(4)]
-    orig_eligible = ds.fused_paged_decode_eligible
-    try:
-        # force the fused paged path (CPU would reject on platform alone;
-        # fused_decode_step_paged defaults to interpret mode off-TPU);
-        # kv_block_size keeps the interpret-mode attend grid small
-        ds.fused_paged_decode_eligible = lambda *a, **k: True
-
-        # one-slot engine: each request decodes alone through the fused
-        # kernel — the committed-trajectory reference
-        single = []
-        engine = _engine(cfg, params, max_batch_size=1, max_seq_len=128,
-                         kv_block_size=32, pipeline_decode=True).start()
-        try:
-            for p, n in zip(prompts, max_news):
-                single.append(engine.submit(
-                    p, max_new_tokens=n,
-                    use_eos_stop=False).result(timeout=600))
-        finally:
-            engine.shutdown()
-        engine = _engine(cfg, params, max_batch_size=4, max_seq_len=128,
-                         kv_block_size=32, pipeline_decode=True).start()
-        batched = _run_batch(engine, prompts, max_news)
-        snap = engine.metrics.snapshot()
-    finally:
-        ds.fused_paged_decode_eligible = orig_eligible
-    for i, (s, b) in enumerate(zip(single, batched)):
-        assert b.finish_reason == "length"
-        assert b.tokens == s.tokens, f"slot batching perturbed request {i}"
-    assert snap["fused_steps"] >= snap["decode_iterations"] > 0
-    assert snap["fallback_steps"] == 0
 
 
 def test_chunked_prefill_matches_one_shot(tiny):
@@ -401,7 +352,7 @@ def test_cpu_decode_keeps_the_gather_route(tiny_d64):
     results, snap, routes, prom, _, engine = _paged_geometry_run(cfg, params)
     assert not engine._paged_decode and engine._decode_route == "fallback"
     assert routes and set(routes) == {"fallback"}
-    assert snap["paged_steps"] == 0 == snap["fused_steps"]
+    assert snap["paged_steps"] == 0
     assert snap["fallback_steps"] >= snap["decode_iterations"] > 0
     assert snap["paged_steps_by_precision"] == {"fp32": 0}
     assert "serving_paged_steps_total 0" in prom

@@ -9,7 +9,6 @@ import numpy as np
 import pytest
 
 from megatron_llm_tpu.config import qwen3_next_config
-from megatron_llm_tpu.kernels.decode_step import _stack_eligible
 from megatron_llm_tpu.models import model as model_lib
 from megatron_llm_tpu.obs.registry import REGISTRY
 from megatron_llm_tpu.serving import EngineConfig, ServingEngine
@@ -172,7 +171,6 @@ REFUSED = {
     "int8_pool": ({}, dict(model=dict(kv_cache_quant="int8")), "int8"),
     "mesh": ({}, dict(mesh=True), "mesh"),
     "adapters": ({}, dict(adapters=True), "adapters"),
-    "fused_step": ({}, dict(model=dict(fused_decode=True)), "fused_decode"),
     "chunked_prefill": (dict(prefill_chunk=32), {}, "prefill_chunk"),
     "host_tier": (dict(host_kv_blocks=8), {}, "host_kv_blocks"),
     "disaggregation": (dict(role="prefill"), {}, "role"),
@@ -201,7 +199,7 @@ def test_what_moves_kv_alone_is_refused_at_construction(model, case):
     assert said in str(err.value)
 
 
-def test_a_slot_is_not_shipped_and_the_fused_step_says_no(model):
+def test_a_slot_is_not_shipped(model):
     cfg, params = model
     eng = ServingEngine(cfg, params, EngineConfig(**ENGINE)).start()
     try:
@@ -211,5 +209,3 @@ def test_a_slot_is_not_shipped_and_the_fused_step_says_no(model):
             eng.call_in_scheduler(lambda: eng.install_shipment(None))
     finally:
         eng.shutdown()
-    on = dataclasses.replace(cfg, fused_decode=True)
-    assert _stack_eligible(on, params, "tpu") is None

@@ -192,7 +192,6 @@ REFUSED = {
     "int8_pool": ({}, dict(model=dict(kv_cache_quant="int8")), "int8"),
     "mesh": ({}, dict(mesh=True), "mesh"),
     "adapters": ({}, dict(adapters=True), "adapters"),
-    "fused_step": ({}, dict(model=dict(fused_decode=True)), "fused_decode"),
     "chunked_prefill": (dict(prefill_chunk=32), {}, "prefill_chunk"),
     "host_tier": (dict(host_kv_blocks=8), {}, "host_kv_blocks"),
     "disaggregation": (dict(role="prefill"), {}, "role"),

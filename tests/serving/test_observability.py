@@ -195,7 +195,7 @@ def test_trace_endpoint_schema_and_request_lifecycle(model):
     steps = [e for e in events if e["name"] == "engine_step"]
     assert steps, "no per-iteration engine_step spans"
     assert all(e["args"]["batch"] >= 1 for e in steps)
-    assert all(e["args"]["route"] in ("fused", "fallback") for e in steps)
+    assert all(e["args"]["route"] in ("paged", "fallback") for e in steps)
 
 
 def test_request_id_correlates_log_lines_and_spans(model):

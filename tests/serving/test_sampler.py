@@ -168,7 +168,7 @@ def test_decode_step_sorts_only_inside_its_cond_and_at_most_twice():
         s or (slots,), dtype)
     i32, f32 = jnp.int32, jnp.float32
     jaxpr = jax.make_jaxpr(functools.partial(
-        engine_lib._decode_impl, cfg, use_fused=False))(
+        engine_lib._decode_impl, cfg))(
             params, *pools, vec(i32, slots, t), vec(i32), vec(i32),
             vec(jnp.uint32), vec(i32), vec(jnp.bool_), vec(f32), vec(i32),
             vec(f32))

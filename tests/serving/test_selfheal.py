@@ -93,11 +93,14 @@ def _reference_tokens(cfg, params, specs, **cfg_overrides):
         engine.shutdown()
 
 
-def _heal(router, timeout=300.0) -> bool:
-    """Wait until every replica is alive again (supervisor rebuilt)."""
+def _heal(router, sup, rebuilt=1, timeout=300.0) -> bool:
+    """Wait until the supervisor has rebuilt ``rebuilt`` replicas and
+    every replica is alive again.  (Alive alone is also what a replica
+    looks like before the prober has seen its crash.)"""
     t0 = time.perf_counter()
     while time.perf_counter() - t0 < timeout:
-        if all(r.alive() and not r.dead for r in router.replicas):
+        if sup.rebuilt_total >= rebuilt and all(
+                r.alive() and not r.dead for r in router.replicas):
             return True
         time.sleep(0.02)
     return False
@@ -145,7 +148,7 @@ def test_supervisor_rebuilds_crashed_replica(tiny):
 
         # capacity restored: the dead replica rebuilt on its submesh and
         # rejoined at a bumped generation
-        assert _heal(router)
+        assert _heal(router, sup)
         assert sup.rebuilt_total >= 1
         assert sum(r.generation for r in router.replicas) \
             == sup.rebuilt_total
@@ -196,7 +199,7 @@ def test_watchdog_kills_wedged_replica(tiny):
         results = [h.result(300) for h in handles]
         assert [list(r.tokens) for r in results] == ref
         assert ("hang", "serve-dispatch") in chaos().events
-        assert _heal(router)
+        assert _heal(router, sup)
         assert sup.watchdog_trips_total >= 1
         assert sup.rebuilt_total >= 1
         assert EVENT_LOG.recent(event="watchdog_trip")
@@ -242,7 +245,7 @@ def test_poison_request_quarantined_then_full_capacity(tiny):
         assert router.quarantined_total == 1
 
         # both crashed replicas rebuilt; cluster back to 3/3
-        assert _heal(router)
+        assert _heal(router, sup, rebuilt=2)
         assert sup.rebuilt_total == 2
         assert sorted(r.generation for r in router.replicas) == [0, 1, 1]
         snap = router.snapshot()

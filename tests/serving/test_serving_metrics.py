@@ -62,6 +62,31 @@ def test_counters_gauges_and_decode_stats():
     assert snap["per_token_latency"]["count"] == 5
 
 
+def test_the_exposition_names_two_decode_routes():
+    """A decode or verify step is ``paged`` or ``fallback``: the
+    snapshot and the Prometheus families say so by weight precision, no
+    third route has a counter, and an unknown route is an error."""
+    import pytest
+
+    m = ServingMetrics(num_slots=2)
+    m.inc_step("paged", "int8")
+    m.inc_step("paged", "int8", sampling=True)
+    m.inc_step("fallback", "fp32")
+    snap = m.snapshot()
+    assert (snap["paged_steps"], snap["fallback_steps"]) == (2, 1)
+    assert snap["sampled_steps"] == 1
+    assert snap["paged_steps_by_precision"] == {"fp32": 0, "int8": 2}
+    assert snap["fallback_steps_by_precision"] == {"fp32": 1, "int8": 0}
+    assert not [k for k in snap if "fused" in k]
+    families = {f.name: f for f in m.collect()}
+    routed = sorted(n for n in families if "_steps_by_precision" in n)
+    assert routed == ["serving_fallback_steps_by_precision_total",
+                      "serving_paged_steps_by_precision_total"]
+    assert not [n for n in families if "fused" in n]
+    with pytest.raises(KeyError):
+        m.inc_step("fused")
+
+
 def test_prefix_cache_counters_and_hit_rate():
     m = ServingMetrics(num_slots=2)
     snap = m.snapshot()

@@ -30,6 +30,7 @@ from megatron_llm_tpu.analysis.sanitizers import no_recompiles
 from megatron_llm_tpu.config import tiny_config
 from megatron_llm_tpu.generation import generate_tokens
 from megatron_llm_tpu.models import model as model_lib
+from megatron_llm_tpu.obs import compile as obs_compile
 from megatron_llm_tpu.resilience.chaos import chaos
 from megatron_llm_tpu.serving import EngineConfig, ServingEngine
 from megatron_llm_tpu.serving.block_pool import BlockPool, HostKVTier
@@ -288,14 +289,35 @@ def test_oversubscribed_storm_ledgers_balanced(tiny):
         assert r.tokens == _reference(cfg, params, prompt, max_new)
 
 
+# What a second preempt/resume cycle builds once more in a process that
+# has not run one yet (ROADMAP S8): the first swap-in commits the pool to
+# its device (``BlockPool.import_blocks`` puts the host rows on the pool's
+# sharding), and these take a committed pool for the first time
+# (``_decode_impl`` does so within the first cycle, after its swap-in).  A
+# stall for whoever sets ``host_kv_blocks``; named here so that it can
+# neither grow nor hide.  The repair (commit the pool where it is built) empties
+# this set.
+_BUILT_AGAIN_FOR_A_COMMITTED_POOL = frozenset({
+    "jit(_insert_plain)", "jit(_export_gather)",
+    "jit(_import_scatter_plain)", "jit(_merge_pending)"})
+
+
 def test_tiered_zero_recompiles_after_warmup(tiny):
     """The tier adds no compiled programs: after one warmup
-    preempt/resume cycle, further cycles run on warm executables."""
+    preempt/resume cycle the second builds nothing but the programs
+    named above, each once, and every later cycle runs on warm
+    executables."""
     cfg, params = tiny
+    compiles = obs_compile.install()
     engine = _engine(cfg, params, **_PREEMPT_KW).start()
     try:
         _run_preemption(engine, cfg)  # warm: prefill/decode/export/import
         assert engine.metrics.snapshot()["preemptions_total"] >= 1
+        seq = compiles.seq
+        _run_preemption(engine, cfg)
+        _, again = compiles.executables_since(seq)
+        assert set(again) <= _BUILT_AGAIN_FOR_A_COMMITTED_POOL, again
+        assert set(again.values()) <= {1}, again
         with no_recompiles():
             r_low, r_hi, low_p, hi_p, low_n, hi_n = \
                 _run_preemption(engine, cfg)

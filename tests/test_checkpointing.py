@@ -91,6 +91,28 @@ def test_config_in_checkpoint(tmp_path):
     assert loaded.train.global_batch_size == cfg.train.global_batch_size
 
 
+def test_a_stored_config_with_the_retired_decode_switch_loads(tmp_path):
+    """Every checkpoint written while ``ModelConfig`` had ``fused_decode``
+    stores the key: ``RuntimeConfig.from_dict`` drops exactly that one,
+    and any other unknown key still fails."""
+    import json
+
+    from megatron_llm_tpu.checkpointing import checkpoint_dir
+
+    cfg = _cfg()
+    params = model_lib.init_params(jax.random.key(0), cfg.model)
+    ckpt.save_checkpoint(str(tmp_path), init_train_state(cfg, params), cfg)
+    stored = checkpoint_dir(str(tmp_path), 0) / "config.json"
+    d = json.loads(stored.read_text())
+    assert "fused_decode" not in d["model"]
+    d["model"]["fused_decode"] = True
+    stored.write_text(json.dumps(d))
+    assert ckpt.load_config_from_checkpoint(str(tmp_path)).model == cfg.model
+    d["model"]["no_such_field"] = 1
+    with pytest.raises(TypeError, match="no_such_field"):
+        RuntimeConfig.from_dict(d)
+
+
 def test_reshard_on_load(tmp_path, devices):
     """Save unsharded, load tp=8-sharded (and back) — values identical.
     This is the reference's checkpoint_util TP-resharding capability, free

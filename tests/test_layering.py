@@ -4,7 +4,8 @@ A unit is a subpackage, or one of the four top-level modules.  The table
 below is written by hand: it is the package's import graph as it stands,
 split into the edges that point down the layer order and the ones that do
 not (``KNOWN_UPWARD``, each a named debt, ROADMAP D15).  An import that is
-in neither fails its unit's case until someone edits the table on purpose.
+in neither fails its unit's case until someone edits the table on purpose,
+and an edge or a debt that no import needs any more fails until it is struck.
 Nothing in the package may import the benchmark, a test tier or a script
 of the repo's root: the program does not know how it is measured.
 
@@ -36,7 +37,7 @@ DOWNWARD = {
     "analysis": ("obs",),
     "metrics": ("analysis", "obs"),
     "resilience": ("analysis", "metrics"),
-    "kernels": ("config",),
+    "kernels": (),
     "parallel": ("config", "utils"),
     "ops": ("config", "kernels", "parallel"),
     "models": ("config", "kernels", "ops", "parallel"),
@@ -63,13 +64,6 @@ KNOWN_UPWARD = {
     # every obs module takes its lock from analysis.sanitizers.make_lock:
     # the tracked lock belongs below both
     ("obs", "analysis"),
-    # kernels/decode_step.py decides eligibility itself: it reads
-    # ops.attention._mesh_active, ops.quant and ops.kv_quant, while ops
-    # imports kernels
-    ("kernels", "ops"),
-    # kernels/decode_step.py asks parallel.mesh for the head-sharding
-    # submesh
-    ("kernels", "parallel"),
     # parallel/pipeline.py and pipeline_encdec.py run the model's layers
     # (models.transformer, ops.cross_entropy), while models imports
     # parallel: the schedules belong above the model
@@ -135,3 +129,33 @@ def test_unit_imports_only_what_the_table_allows(unit):
                 unlisted.append(f"{where}:{line} imports {module}")
     assert not outside, "the package imports its own measurement or tests"
     assert not unlisted, f"{unit} may import {sorted(allowed)}"
+
+
+def _imports_of(unit):
+    """The units of the package that ``unit``'s sources import."""
+    found = set()
+    for source in _sources(unit):
+        for module, _line in _imported_modules(source):
+            top, _, rest = module.partition(".")
+            if top == PACKAGE.name:
+                found.add(rest.partition(".")[0])
+    return found & (set(RANK) - {unit})
+
+
+def test_every_downward_edge_is_still_imported():
+    """A row of ``DOWNWARD`` lists what its unit imports today, not what
+    it once did: an edge nothing needs would let the import back in
+    unseen."""
+    unused = {unit: sorted(set(DOWNWARD[unit]) - _imports_of(unit))
+              for unit in LAYERS}
+    unused = {unit: edges for unit, edges in unused.items() if edges}
+    assert not unused, f"no longer imported, strike from DOWNWARD: {unused}"
+
+
+@pytest.mark.parametrize("pair", sorted(KNOWN_UPWARD), ids="->".join)
+def test_every_known_upward_pair_is_still_needed(pair):
+    """A repair removes its pair in the same change (ROADMAP D15): a pair
+    that no import of its unit needs is a debt already paid."""
+    unit, target = pair
+    assert target in _imports_of(unit), \
+        f"nothing in {unit} imports {target}: strike {pair} from KNOWN_UPWARD"

@@ -35,7 +35,11 @@ def tiny_hf_llama():
 
 @pytest.mark.incremental
 class TestConversionPipeline:
-    def test_hf_to_native(self, tmp_path_factory):
+    @pytest.fixture(scope="class")
+    def converted(self, tmp_path_factory):
+        """``(root, hf)``: a tiny HF Llama saved under ``root/hf_in`` and
+        converted to ``root/native`` through the CLI, built once for
+        whichever of the class's tests a worker is dealt."""
         root = tmp_path_factory.mktemp("conv")
         hf = tiny_hf_llama()
         hf.save_pretrained(str(root / "hf_in"))
@@ -44,13 +48,15 @@ class TestConversionPipeline:
             "--hf_path", str(root / "hf_in"),
             "--output", str(root / "native"),
         ])
+        return root, hf
+
+    def test_hf_to_native(self, converted):
+        root, _ = converted
         assert (root / "native" / "iter_release").exists() or any(
             (root / "native").iterdir())
-        type(self).root = root
-        type(self).hf = hf
 
-    def test_native_logit_parity(self):
-        root = type(self).root
+    def test_native_logit_parity(self, converted):
+        root, hf = converted
         from megatron_llm_tpu import checkpointing
 
         cfg = checkpointing.load_config_from_checkpoint(
@@ -58,11 +64,11 @@ class TestConversionPipeline:
         params = checkpointing.load_params_for_inference(
             str(root / "native"), cfg)
         batches = [np.random.default_rng(0).integers(0, 128, (2, 32))]
-        report = verify(cfg, params, type(self).hf, batches, tolerance=1e-3)
+        report = verify(cfg, params, hf, batches, tolerance=1e-3)
         assert report["passed"], report
 
-    def test_resave_roundtrip(self):
-        root = type(self).root
+    def test_resave_roundtrip(self, converted):
+        root, hf = converted
         checkpoint_util.main([
             "resave",
             "--load", str(root / "native"),
@@ -75,11 +81,11 @@ class TestConversionPipeline:
         params = checkpointing.load_params_for_inference(
             str(root / "resaved"), cfg)
         batches = [np.random.default_rng(1).integers(0, 128, (2, 32))]
-        report = verify(cfg, params, type(self).hf, batches, tolerance=1e-3)
+        report = verify(cfg, params, hf, batches, tolerance=1e-3)
         assert report["passed"], report
 
-    def test_native_to_hf_roundtrip(self):
-        root = type(self).root
+    def test_native_to_hf_roundtrip(self, converted):
+        root, hf = converted
         checkpoint_util.main([
             "native-to-hf",
             "--load", str(root / "native"),
@@ -88,7 +94,7 @@ class TestConversionPipeline:
         ])
         reloaded = transformers.AutoModelForCausalLM.from_pretrained(
             str(root / "hf_out")).eval()
-        orig_sd = type(self).hf.state_dict()
+        orig_sd = hf.state_dict()
         new_sd = reloaded.state_dict()
         for k, v in orig_sd.items():
             if k.endswith("rotary_emb.inv_freq"):

@@ -252,7 +252,7 @@ def test_decode_step_moves_no_weight_and_matches_reference():
                   zeros, jnp.zeros((slots,), jnp.float32))
     step = engine_lib._decode_donated.lower(
         cfg, params, *pools, tables, first, zeros, zeros, zeros,
-        *all_greedy, use_fused=False).compile()
+        *all_greedy).compile()
     text = step.as_text()
     assert "tpu_custom_call" in text
     assert relayout_bytes(text) == {}
