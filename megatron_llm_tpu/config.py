@@ -30,8 +30,13 @@ class PositionEmbeddingType:
     NONE = "none"
 
 
-# the block kinds of ModelConfig.layer_pattern
-BLOCK_KINDS = ("full", "linear", "attention", "mamba", "mlp")
+# the block kinds of ModelConfig.layer_pattern, and of them those that keep
+# keys and values, those that keep a state-space state, and those with a
+# feed-forward part
+BLOCK_KINDS = ("full", "linear", "ssm", "attention", "mamba", "mlp")
+KV_KINDS = ("full", "attention")
+MAMBA_KINDS = ("ssm", "mamba")
+FFN_KINDS = ("full", "linear", "ssm", "mlp")
 
 
 class AttnMaskType:
@@ -211,8 +216,9 @@ class ModelConfig:
     kv_channels: Optional[int] = None
     # Hybrid stacks: the block kinds of one period of layers.  A block of
     # two parts, a mixer and then a feed-forward part, each under a norm
-    # of its own: "full" (softmax attention) or "linear" (Gated DeltaNet,
-    # models/gated_deltanet.py).  A block of one part under one norm,
+    # of its own: "full" (softmax attention), "linear" (Gated DeltaNet,
+    # models/gated_deltanet.py) or "ssm" (a Mamba-2 mixer,
+    # models/mamba2.py).  A block of one part under one norm,
     # ``h + f(norm(h))``: "attention" (softmax attention alone), "mamba"
     # (a Mamba-2 mixer alone, models/mamba2.py) or "mlp" (the feed-forward
     # part alone: the experts where num_experts > 0).  The stack is
@@ -270,6 +276,14 @@ class ModelConfig:
     moe_router_scoring: str = "softmax"
     moe_routed_scaling: float = 1.0
     moe_latent_size: int = 0
+    # Scalar multipliers of the architecture (Granite's, after muP): on
+    # the embedding's output; on every part's result before it is added to
+    # the residual stream; the softmax scale in place of 1/sqrt(head_dim)
+    # (None: that); and what the head's logits are divided by.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    logits_scaling: float = 1.0
 
     def __post_init__(self):
         # a JSON list (checkpointed arguments, a benchmark's overrides):
@@ -297,8 +311,7 @@ class ModelConfig:
     @property
     def kv_layers(self) -> int:
         """Layers that keep keys and values: the KV pool's layer axis."""
-        kinds = self.layer_kinds
-        return kinds.count("full") + kinds.count("attention")
+        return sum(kind in KV_KINDS for kind in self.layer_kinds)
 
     @property
     def linear_layers(self) -> int:
@@ -308,7 +321,7 @@ class ModelConfig:
     @property
     def mamba_layers(self) -> int:
         """Layers that keep a state-space state (serving/slots.py)."""
-        return self.layer_kinds.count("mamba")
+        return sum(kind in MAMBA_KINDS for kind in self.layer_kinds)
 
     @property
     def moe_layer_ids(self) -> tuple:
@@ -317,7 +330,7 @@ class ModelConfig:
         if self.num_experts == 0:
             return ()
         return tuple(i for i, kind in enumerate(self.layer_kinds)
-                     if kind in ("full", "linear", "mlp"))
+                     if kind in FFN_KINDS)
 
     @property
     def mamba_inner(self) -> int:
@@ -382,6 +395,9 @@ class ModelConfig:
             assert self.moe_top_k <= self.router_experts
         if self.parallel_layernorm:
             assert self.parallel_attn, "parallel_layernorm requires parallel_attn"
+        assert not (self.fused_lm_head and self.logits_scaling != 1.0), (
+            "the fused training head does not divide its logits "
+            "(logits_scaling)")
         if self.num_experts > 0 and not self.moe_dropless:
             assert 1 <= self.moe_top_k <= self.num_experts, (
                 f"moe_top_k {self.moe_top_k} must be in "
@@ -987,6 +1003,50 @@ def nemotron_h_config(size: str = "3-super-120b-a12b",
     return ModelConfig(**base).validate()
 
 
+def granite_hybrid_config(size: str = "4.0-h-micro",
+                          **overrides) -> ModelConfig:
+    """Granite 4.0-H (``model_type: granitemoehybrid``): every layer is
+    two parts, a mixer and a gated SiLU MLP, each under an RMSNorm of its
+    own; the mixer is a Mamba-2 mixer (one B/C group shared by all its
+    heads, the gated norm over the whole inner width) and in every tenth
+    layer softmax attention (32 heads on 8 KV heads of 64) without any
+    position rotation.  Four scalars: the embedding's output times
+    ``embedding_multiplier``, every part's result times
+    ``residual_multiplier`` before it is added, ``attention_multiplier``
+    as the softmax scale in place of ``1/sqrt(head_dim)``, the tied
+    head's logits divided by ``logits_scaling``.  No experts
+    (``num_local_experts`` 0: the shared MLP is the whole feed-forward
+    part).  Served only, as every hybrid stack here.
+
+    ``4.0-h-micro`` is the published 40-layer model: attention at layers
+    5, 15, 25 and 35, a period of ten."""
+    base = dict(
+        norm_type="rmsnorm",
+        norm_eps=1e-5,
+        activation="swiglu",
+        position_embedding_type=PositionEmbeddingType.NONE,
+        use_bias=False,
+        tie_embed_logits=True,
+        recompute="none",
+        seq_length=4096,
+    )
+    sizes = {
+        "4.0-h-micro": dict(
+            hidden_size=2048, num_layers=40,
+            layer_pattern=("ssm",) * 5 + ("full",) + ("ssm",) * 4,
+            num_attention_heads=32, num_kv_heads=8, kv_channels=64,
+            ffn_hidden_size=8192,
+            mamba_num_heads=64, mamba_head_dim=64, mamba_n_groups=1,
+            mamba_state_size=128, mamba_conv_kernel=4, mamba_chunk_size=256,
+            embedding_multiplier=12.0, residual_multiplier=0.22,
+            attention_multiplier=0.015625, logits_scaling=8.0,
+            vocab_size=100352, max_position_embeddings=131072),
+    }
+    base.update(sizes[size])
+    base.update(overrides)
+    return ModelConfig(**base).validate()
+
+
 def gpt_config(size: str = "345m", **overrides) -> ModelConfig:
     """GPT-2/3 style: learned absolute positions, LayerNorm, gelu, tied
     embeddings, biases (reference: megatron/model/gpt_model.py)."""
@@ -1046,6 +1106,7 @@ PRESETS = {
     "falcon-40b": lambda: falcon_config("40b"),
     "gpt-345m": lambda: gpt_config("345m"),
     "qwen3-next-80b-a3b": lambda: qwen3_next_config("80b-a3b"),
+    "granite-4.0-h-micro": lambda: granite_hybrid_config("4.0-h-micro"),
     "tiny": tiny_config,
 }
 

@@ -20,7 +20,14 @@ block resident over its grid steps, and a head's column is spread over
 the lanes.  ``y``, a sum over the lanes with the head width left on the
 sublanes, goes out the same way and each head's column is put into its
 lane.  Both are a few KB a slot beside 4 MB of state; the caller's
-transposes are XLA's.
+transposes are XLA's, and so is the padding of their heads to whole
+128-lane registers where a layer has fewer (Mosaic rolls no narrower
+array along its lanes).
+
+A grid step takes ``heads_per_step`` heads, whatever the groups: blocks
+of at most 16 heads that share a group (a group of 16 is one block, a
+group of 64 four), as many of them as make 32 heads; a block's ``B`` and
+``C`` are its first head's group's.
 
 Everything is float32.  The update is the vector unit's; the sum over
 the state width is one product a group on the matrix unit at
@@ -43,34 +50,58 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .. import kernels
 
-# groups a grid step: two groups' 32 heads are a block of 1 MB at the
-# published widths, 4 MB in flight; with one the products no longer hide
-# under the copies (1.93 against 1.70 ms a layer of 128 slots, PERF.md)
-_STEP_GROUPS = 2
+# heads a grid step, in blocks that share a group: two blocks' 32 heads
+# are 1 MB of state at the published widths, 4 MB in flight; with one the
+# products no longer hide under the copies (1.93 against 1.70 ms a layer
+# of 128 slots, PERF.md)
+_BLOCK_HEADS = 16
+_STEP_HEADS = 32
+_LANES = 128
 _PREC = jax.lax.Precision.HIGHEST
 _NT = (((1,), (1,)), ((), ()))
 
 
-def _kernel(groups, per, at_ref, a_ref, x_ref, B_ref, C_ref, s_ref,
+def _tiling(H: int, G: int):
+    """→ (heads a block, blocks a grid step): the largest divisor of a
+    group's heads up to ``_BLOCK_HEADS``, and as many blocks as divide
+    the layer's and make no more than ``_STEP_HEADS`` heads."""
+    per = H // G
+    block = max(i for i in range(1, _BLOCK_HEADS + 1) if per % i == 0)
+    blocks = max(i for i in range(1, _STEP_HEADS // block + 1)
+                 if (H // block) % i == 0)
+    return block, blocks
+
+
+def heads_per_step(H: int, G: int) -> int:
+    """The heads of one slot a grid step advances, for ``H`` heads in
+    ``G`` groups."""
+    block, blocks = _tiling(H, G)
+    return block * blocks
+
+
+def _kernel(blocks, per, group, at_ref, a_ref, x_ref, B_ref, C_ref, s_ref,
             y_ref, out_ref):
-    """``groups`` groups of ``per`` heads of one slot.  ``a_ref`` [1,
-    heads] in SMEM, ``x_ref y_ref`` [head width, heads], ``B_ref C_ref``
-    [all groups, state width], ``s_ref out_ref`` [groups x per, head
-    width, state width].  Traced in every program that holds a decode
-    step: scalar arithmetic is ``lax`` on constants, the groups are a
-    loop (PERF.md, PR 42)."""
+    """``blocks`` blocks of ``per`` heads of one slot, each block inside
+    one group of ``group`` heads.  ``a_ref`` [1, heads] in SMEM, ``x_ref
+    y_ref`` [head width, heads (whole registers of lanes)], ``B_ref
+    C_ref`` [all groups, state width], ``s_ref out_ref`` [blocks x per,
+    head width, state width].  Traced in every program that holds a
+    decode step: scalar arithmetic is ``lax`` on constants, the blocks
+    are a loop (PERF.md, PR 42)."""
     del at_ref
     P, H = x_ref.shape
     N = s_ref.shape[-1]
     i32 = np.int32
-    first = jax.lax.mul(pl.program_id(1), i32(groups))
+    first = jax.lax.mul(pl.program_id(1), i32(blocks))
     lane = jax.lax.broadcasted_iota(jnp.int32, (P, H), 1)
     head = jax.lax.broadcasted_iota(jnp.int32, (per, P, H), 0)
     lanes = jax.lax.broadcasted_iota(jnp.int32, (per, P, H), 2)
 
-    def group(g, y):
+    def group_block(g, y):
         row = jax.lax.add(first, g)
         h0 = jax.lax.mul(row, i32(per))
+        if group != per:              # a block is a part of its group
+            row = jax.lax.div(h0, i32(group))
         B, C = B_ref[pl.ds(row, 1), :], C_ref[pl.ds(row, 1), :]
         # this group's heads to lanes 0, 1, ...: a head's column of dt x,
         # spread over the lanes, meets B along them
@@ -94,7 +125,7 @@ def _kernel(groups, per, at_ref, a_ref, x_ref, B_ref, C_ref, s_ref,
 
     # (the block of y stays in VMEM over a slot's grid steps; what the
     # first of them finds there is replaced lane by lane)
-    y_ref[...] = jax.lax.fori_loop(0, groups, group, y_ref[...],
+    y_ref[...] = jax.lax.fori_loop(0, blocks, group_block, y_ref[...],
                                    unroll=True)
 
 
@@ -102,33 +133,37 @@ def _kernel(groups, per, at_ref, a_ref, x_ref, B_ref, C_ref, s_ref,
 def _call(x, B, C, dt, A, ssm, at, *, interpret: bool):
     b, H, P = x.shape
     G, N = B.shape[1:]
-    per = H // G
-    groups = max(i for i in range(1, _STEP_GROUPS + 1) if G % i == 0)
+    per, blocks = _tiling(H, G)            # heads a block, blocks a step
+    lanes = -(-H // _LANES) * _LANES
+    at = jnp.reshape(at, (1,)).astype(jnp.int32)
+    a = jnp.exp(dt * A)[:, None]
+    dtx = jnp.swapaxes(dt[..., None] * x, 1, 2)
+    if lanes != H:
+        dtx = jnp.pad(dtx, ((0, 0), (0, 0), (0, lanes - H)))
     slot = lambda bi, gi, at: (bi, 0, 0)  # noqa: E731
-    rows = pl.BlockSpec((None, P, H), slot)
+    rows = pl.BlockSpec((None, P, lanes), slot)
     shared = pl.BlockSpec((None, G, N), slot)
-    state = pl.BlockSpec((None, None, groups * per, P, N),
-                         lambda bi, gi, at: (at[0], bi, gi, 0, 0))
+    state = pl.BlockSpec((None, None, blocks * per, P, N),
+                         lambda bi, gi, at_: (at_[0], bi, gi, 0, 0))
     y, ssm = pl.pallas_call(
-        functools.partial(_kernel, groups, per),
+        functools.partial(_kernel, blocks, per, H // G),
         name="mamba_step",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(b, G // groups),
+            grid=(b, H // (blocks * per)),
             in_specs=[pl.BlockSpec((None, 1, H), slot,
                                    memory_space=pltpu.SMEM),
                       rows, shared, shared, state],
             out_specs=[rows, state]),
-        out_shape=[jax.ShapeDtypeStruct((b, P, H), jnp.float32),
+        out_shape=[jax.ShapeDtypeStruct((b, P, lanes), jnp.float32),
                    jax.ShapeDtypeStruct(ssm.shape, jnp.float32)],
         # (operands count the prefetched scalar)
         input_output_aliases={5: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(jnp.reshape(at, (1,)).astype(jnp.int32), jnp.exp(dt * A)[:, None],
-      jnp.swapaxes(dt[..., None] * x, 1, 2), B, C, ssm)
-    return jnp.swapaxes(y, 1, 2), ssm
+    )(at, a, dtx, B, C, ssm)
+    return jnp.swapaxes(y[..., :H] if lanes != H else y, 1, 2), ssm
 
 
 def mamba_step(x, B, C, dt, A, ssm, at, interpret: Optional[bool] = None):

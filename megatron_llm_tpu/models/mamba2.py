@@ -59,8 +59,10 @@ STATE_NAMES = ("ssm", "ssm_conv")
 
 class MambaState(NamedTuple):
     """What a Mamba-2 layer keeps of a sequence: ``S`` [b, heads, head
-    width, state width], and ``conv`` [b, taps - 1, channels]: the
-    convolution's last inputs; both float32.  With ``at`` (an int32
+    width, state width], and ``conv``: the convolution's last ``taps - 1``
+    inputs, oldest first, [b, taps - 1, channels] or, where the stack's
+    scan has more than one period, flat [b, (taps - 1) x channels]
+    (``init_state``); both float32.  With ``at`` (an int32
     scalar, may be traced) ``S`` is the stacked states of all the layers,
     [layers, b, ...], and this layer's is ``S[at]``: how a decode step
     hands them through, since its kernel advances the layer where it
@@ -112,10 +114,21 @@ def init_mamba_params(key: jax.Array, cfg: ModelConfig) -> Params:
 
 
 def init_state(cfg: ModelConfig, batch: int) -> MambaState:
+    """A sequence's start.  The tail's three rows are padded to a tile of
+    four once the layers' tails are stacked.  Riding the carry of a
+    ``while`` (a scan of more than one period) XLA:TPU re-lays that whole
+    padded array between every two layers, 7 GB a decode step of a
+    40-layer stack, so there the tail is kept flat, whole tiles of (slots,
+    lanes); a one-period stack is unrolled, keeps the rows apart at no
+    cost, and kept flat would be copied whole at both ends of every step
+    (PERF.md, PR 49: both measured)."""
     H, P, _G, N, _di, ch = dims(cfg)
-    return MambaState(jnp.zeros((batch, H, P, N), jnp.float32),
-                      jnp.zeros((batch, cfg.mamba_conv_kernel - 1, ch),
-                                jnp.float32))
+    rows = cfg.mamba_conv_kernel - 1
+    periods = cfg.num_layers // max(len(cfg.layer_pattern), 1)
+    return MambaState(
+        jnp.zeros((batch, H, P, N), jnp.float32),
+        jnp.zeros((batch, rows * ch) if periods > 1 else (batch, rows, ch),
+                  jnp.float32))
 
 
 def state_at(stacked: dict, at, one_position: bool) -> MambaState:
@@ -199,16 +212,18 @@ def ssd_chunked(x, B, C, dt, A, S, chunk: int):
 @jax.named_scope("mamba_conv")
 def _conv(p: Params, mixed, tail, lengths):
     """Causal depthwise convolution of ``mixed`` [b, s, ch] continuing
-    ``tail`` [b, taps - 1, ch], its bias, then SiLU → ``(out [b, s, ch],
-    the tail after each row's ``lengths`` positions)``."""
-    taps, s = p["conv"].shape[0], mixed.shape[1]
-    full = jnp.concatenate([tail, mixed], axis=1)          # float32
+    ``tail`` ([b, taps - 1, ch], or flat), its bias, then SiLU → ``(out
+    [b, s, ch], the tail after each row's ``lengths`` positions, in the
+    form it came in)``."""
+    taps, (b, s, ch) = p["conv"].shape[0], mixed.shape
+    full = jnp.concatenate([tail.reshape(b, taps - 1, ch), mixed],
+                           axis=1)                         # float32
     w = p["conv"].astype(jnp.float32)
     out = sum(full[:, j:j + s] * w[j] for j in range(taps))
     out = out + p["conv_bias"].astype(jnp.float32)
     new_tail = jax.vmap(lambda f, n: jax.lax.dynamic_slice_in_dim(
         f, n, taps - 1, axis=0))(full, lengths)
-    return jax.nn.silu(out), new_tail
+    return jax.nn.silu(out), new_tail.reshape(tail.shape)
 
 
 @jax.named_scope("mamba")

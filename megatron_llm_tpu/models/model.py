@@ -136,6 +136,10 @@ def embed(cfg: ModelConfig, params: Params, tokens: jax.Array,
     from ..ops.quant import embedding_lookup
 
     x = embedding_lookup(params["embedding"]["word"], tokens, cfg.dtype)
+    if cfg.embedding_multiplier != 1.0:
+        # (a hybrid stack's stream is float32: scaled there, not rounded)
+        x = x.astype(STREAM_DTYPE if cfg.layer_pattern else x.dtype)
+        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
     if "position" in params["embedding"]:
         if position_ids is None:
             position_ids = jnp.arange(tokens.shape[1])[None, :]
@@ -151,7 +155,8 @@ def unembed(cfg: ModelConfig, params: Params, x: jax.Array) -> jax.Array:
     """Project hidden states to (padded-)vocab logits, float32
     (reference: parallel_lm_logits, megatron/model/language_model.py:24-53).
     The cast is made here, under the scope: XLA fuses it into the matmul,
-    and a fusion is named after its root.
+    and a fusion is named after its root.  Where the architecture scales
+    its logits (``cfg.logits_scaling``) they are divided here.
 
     A tied head contracts the hidden axis of ``x`` with the hidden axis of
     the table as it is stored: the program holds no transposed table
@@ -163,7 +168,10 @@ def unembed(cfg: ModelConfig, params: Params, x: jax.Array) -> jax.Array:
             (((x.ndim - 1,), (1,)), ((), ())))
     else:
         logits = x @ params["lm_head"]
-    return logits.astype(jnp.float32)
+    logits = logits.astype(jnp.float32)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits
 
 
 def unembed_weight(cfg: ModelConfig, params: Params) -> jax.Array:
@@ -607,6 +615,9 @@ def init_rec_state(cfg: ModelConfig, batch_size: int) -> dict:
     key width, value width] and convolution tail ``conv`` [linear layers,
     b, taps - 1, channels]; every Mamba-2 layer's state ``ssm`` [mamba
     layers, b, heads, head width, state width] and tail ``ssm_conv``
+    [mamba layers, b, taps - 1, channels] (flat, [.., (taps - 1) x
+    channels], where the scan has more than one period:
+    ``mamba2.init_state``)
     (``REC_STATE_KINDS`` names them by kind).  And two counters carried
     on the device and read when somebody asks: ``load`` [layers, router
     outputs] int32, how often each expert was chosen by the positions
@@ -630,8 +641,9 @@ def init_rec_state(cfg: ModelConfig, batch_size: int) -> dict:
             "rows": jnp.zeros((cfg.num_layers, 2, 2), jnp.int32)}
 
 
-# the names of ``init_rec_state``'s state arrays, by the block kind
-# (``config.BLOCK_KINDS``) that keeps them
+# the names of ``init_rec_state``'s state arrays, by the kind of mixer that
+# keeps them: "linear" a Gated DeltaNet layer's, "mamba" a Mamba-2 mixer's
+# (the block kinds ``config.MAMBA_KINDS``)
 REC_STATE_KINDS = {"linear": ("S", "conv"), "mamba": mamba2.STATE_NAMES}
 
 

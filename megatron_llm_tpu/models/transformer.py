@@ -30,7 +30,12 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from ..config import ModelConfig, PositionEmbeddingType
+from ..config import (
+    KV_KINDS,
+    MAMBA_KINDS,
+    ModelConfig,
+    PositionEmbeddingType,
+)
 from ..ops.activations import get_activation, is_glu
 from ..ops.attention import _mesh_active, attention
 from ..ops.norms import norm_apply, norm_init
@@ -70,9 +75,10 @@ def init_layer_params(key: jax.Array, cfg: ModelConfig,
                       kind: str = "full") -> Params:
     """Parameters of one transformer layer (unstacked), of one of
     ``config.BLOCK_KINDS``.  A block of two parts holds a mixer
-    (``"attn"``, or ``"gdn"`` for a ``"linear"`` layer), ``"mlp"`` and a
-    norm for each; a block of one part holds that part (``"attn"``,
-    ``"mamba"`` or ``"mlp"``) under ``"input_norm"`` alone."""
+    (``"attn"``, ``"gdn"`` for a ``"linear"`` layer, ``"mamba"`` for an
+    ``"ssm"`` layer), ``"mlp"`` and a norm for each; a block of one part
+    holds that part (``"attn"``, ``"mamba"`` or ``"mlp"``) under
+    ``"input_norm"`` alone."""
     h = cfg.hidden_size
     d = cfg.head_dim
     nq = cfg.num_attention_heads
@@ -85,7 +91,7 @@ def init_layer_params(key: jax.Array, cfg: ModelConfig,
 
     keys = jax.random.split(key, 8)
     layer: Params = {"input_norm": norm_init(cfg.norm_type, h, dtype)}
-    if kind in ("full", "attention"):
+    if kind in KV_KINDS:
         attn: Params = {
             # with an output gate: per head, the query's columns then the
             # gate's
@@ -107,7 +113,7 @@ def init_layer_params(key: jax.Array, cfg: ModelConfig,
         layer["attn"] = attn
     elif kind == "linear":
         layer["gdn"] = init_gdn_params(keys[7], cfg)
-    elif kind == "mamba":
+    elif kind in MAMBA_KINDS:
         layer["mamba"] = mamba2.init_mamba_params(keys[7], cfg)
     if kind in ("attention", "mamba"):
         return layer                     # a mixer alone
@@ -361,7 +367,8 @@ def attention_block(cfg: ModelConfig, p: Params, x: jax.Array,
         q = apply_rope(q, side.rope_cos, side.rope_sin, position_ids)
         k = apply_rope(k, side.rope_cos, side.rope_sin, position_ids)
 
-    softmax_scale = 1.0 / (d ** 0.5)
+    softmax_scale = (1.0 / (d ** 0.5) if cfg.attention_multiplier is None
+                     else cfg.attention_multiplier)
     if cfg.apply_query_key_layer_scaling:
         # reference scales by 1/layer inside softmax and compensates in the
         # matmul (transformer.py:191-236); net effect is standard scale, so
@@ -509,7 +516,7 @@ def layer_forward(cfg: ModelConfig, p: Params, x: jax.Array,
     A block of one part (a hybrid stack's ``"attention"``, ``"mamba"``
     and ``"mlp"`` kinds) is ``_one_part_forward``'s.
     """
-    if "mlp" not in p or ("attn" not in p and "gdn" not in p):
+    if "mlp" not in p or not any(m in p for m in ("attn", "gdn", "mamba")):
         return _one_part_forward(cfg, p, x, side, layer_rng, kv_cache)
     if layer_idx is not None and (cfg.lima_dropout
                                   or cfg.drop_path_rate > 0.0):
@@ -544,12 +551,16 @@ def layer_forward(cfg: ModelConfig, p: Params, x: jax.Array,
     if cfg.layer_pattern:
         # a hybrid stack's residual stream is float32 (``stream_dtype``);
         # attention computes in the model's own precision
-        h1 = h1 if "gdn" in p else h1.astype(cfg.dtype)
+        h1 = h1 if "attn" not in p else h1.astype(cfg.dtype)
     if "gdn" in p:
         # a linear layer: its "cache" is the recurrent state, carried or
         # (None) started at zero; the new one is dropped with no cache
         attn_out, new_cache = gdn_block(cfg, p["gdn"], h1, kv_cache,
                                         side.valid)
+    elif "mamba" in p:
+        # an ssm layer: the same, with the state-space state
+        attn_out, new_cache = mamba2.mamba_block(cfg, p["mamba"], h1,
+                                                 kv_cache, side.valid)
     elif kv_cache is not None:
         attn_out, new_cache = attention_block(cfg, p["attn"], h1, side,
                                               layer_rng, kv_cache,
@@ -565,14 +576,15 @@ def layer_forward(cfg: ModelConfig, p: Params, x: jax.Array,
         else:
             mlp_in = h1
         mlp_out, aux = _mlp_dispatch(cfg, p["mlp"], mlp_in, lora=lora)
-        result = residual + branch_drop(attn_out + mlp_out, 2)
+        result = residual + _scaled(
+            cfg, branch_drop(attn_out + mlp_out, 2))
     else:
-        x = residual + branch_drop(attn_out, 2)
+        x = residual + _scaled(cfg, branch_drop(attn_out, 2))
         h2 = norm_apply(cfg.norm_type, x, p["post_attn_norm"],
                         cfg.norm_eps, impl=cfg.norm_impl)
         m, aux = _mlp_dispatch(cfg, p["mlp"], h2, lora=lora,
                                valid=side.valid)
-        result = x + branch_drop(m, 3)
+        result = x + _scaled(cfg, branch_drop(m, 3))
     result = seq_constrain(result, side.seq_shard_axes)
     if kv_cache is not None:
         return result, aux, new_cache
@@ -603,10 +615,18 @@ def _one_part_forward(cfg: ModelConfig, p: Params, x: jax.Array,
             out = attention_block(cfg, p["attn"], h1, side, layer_rng)
     else:
         out, aux = _mlp_dispatch(cfg, p["mlp"], h1, valid=side.valid)
-    result = x + out
+    result = x + _scaled(cfg, out)
     if kv_cache is not None:
         return result, aux, new_cache
     return result, aux
+
+
+def _scaled(cfg: ModelConfig, out):
+    """A part's result as it is added to the residual stream: times
+    ``cfg.residual_multiplier`` where the architecture has one."""
+    if cfg.residual_multiplier == 1.0:
+        return out
+    return out * jnp.asarray(cfg.residual_multiplier, out.dtype)
 
 
 def _remat_policy(cfg: ModelConfig):
@@ -720,8 +740,9 @@ def scan_periods_cached(cfg: ModelConfig, stacked, x, side, kv_of, rec,
     *slices of kv_xs)``, ``kv_layer`` counting those layers alone: the KV
     cache's own layer axis) and each recurrent mixer its state out of
     ``rec`` (``models/model.py:init_rec_state``): a ``"linear"`` layer
-    ``{"S": [linear layers, b, ...], "conv": [...]}``, a ``"mamba"``
-    layer ``{"ssm": [mamba layers, b, ...], "ssm_conv": [...]}``.  The
+    ``{"S": [linear layers, b, ...], "conv": [...]}``, a ``"mamba"`` or
+    ``"ssm"`` layer ``{"ssm": [mamba layers, b, ...], "ssm_conv":
+    [...]}``.  The
     delta-rule states go through the scan as its xs and ys; the
     state-space states, thirteen times their size a layer, ride in the
     carry: a prompt's layer reads and rewrites its own slice in place, a
@@ -736,8 +757,9 @@ def scan_periods_cached(cfg: ModelConfig, stacked, x, side, kv_of, rec,
     experts)``."""
     kinds = cfg.layer_pattern
     n_per = cfg.num_layers // len(kinds)
-    n_full = sum(kind in ("full", "attention") for kind in kinds)
-    n_lin, n_mam = kinds.count("linear"), kinds.count("mamba")
+    n_full = sum(kind in KV_KINDS for kind in kinds)
+    n_lin = kinds.count("linear")
+    n_mam = sum(kind in MAMBA_KINDS for kind in kinds)
     x = x.astype(STREAM_DTYPE)
 
     def by_period(a, n):
@@ -754,14 +776,14 @@ def scan_periods_cached(cfg: ModelConfig, stacked, x, side, kv_of, rec,
         period, kv_p, rec_p = inp
         rows, states, counts, f, l, m = [], [], [], 0, 0, 0
         for layer_params, kind in zip(period, kinds):
-            attends, cache = kind in ("full", "attention"), None
+            attends, cache = kind in KV_KINDS, None
             if attends:
                 cache = kv_of(idx * n_full + f, *(a[f] for a in kv_p))
                 f += 1
             elif kind == "linear":
                 cache = GDNState(rec_p["S"][l], rec_p["conv"][l])
                 l += 1
-            elif kind == "mamba":
+            elif kind in MAMBA_KINDS:
                 at = idx * n_mam + m
                 cache = mamba2.state_at(ssm, at, h.shape[1] == 1)
                 m += 1
@@ -771,7 +793,7 @@ def scan_periods_cached(cfg: ModelConfig, stacked, x, side, kv_of, rec,
                 rows += new
             elif kind == "linear":
                 states += new
-            elif kind == "mamba":
+            elif kind in MAMBA_KINDS:
                 ssm = mamba2.write_back(ssm, new[0], at)
             counts.append(
                 {name: aux[name] for name in ("load", "rows")}

@@ -128,6 +128,7 @@ import numpy as np
 from ..analysis import sanitizers
 from ..config import ModelConfig
 from ..generation.sampling import NEG_INF
+from ..kernels.mamba_step import heads_per_step
 from ..models import model as model_lib
 from ..obs import compile as obs_compile
 from ..obs import profile as obs_profile
@@ -1170,15 +1171,24 @@ class ServingEngine:
         self._state_arg = {"state_kinds": "+".join(kinds)} if kinds else {}
         self._counts_ssm = cfg.mamba_layers > 0
         # how a step's state-space layers ran, on its decode spans: each
-        # advanced where it lies in the stacked states by one kernel
-        # (kernels/mamba_step.py; no such field for a stack without them)
+        # advanced where it lies in the stacked states by one kernel, so
+        # many heads of a slot a grid step (kernels/mamba_step.py; no such
+        # fields for a stack without them)
         self._step_arg = dict(self._state_arg, **(
-            {"ssm_step": "fused"} if self._counts_ssm else {}))
-        # how a prompt's Gated DeltaNet layers ran, on its prefill spans:
-        # between their projections as one kernel (kernels/gdn_scan.py;
-        # no such field for a stack without them)
+            {"ssm_step": "fused", "ssm_tile": heads_per_step(
+                cfg.mamba_num_heads, cfg.mamba_n_groups)}
+            if self._counts_ssm else {}))
+        # on a prompt's prefill spans: how its Gated DeltaNet layers ran
+        # (between their projections as one kernel, kernels/gdn_scan.py;
+        # no such field for a stack without them), and the bytes of slot
+        # state its install wrote beside the K/V (``slots.insert``)
         self._prefill_arg = dict(self._state_arg, **(
             {"gdn": "fused"} if cfg.linear_layers else {}))
+        if kinds:
+            self._prefill_arg["state_installed_bytes"] = sum(
+                a.size * a.dtype.itemsize for a in jax.tree.leaves(
+                    jax.eval_shape(lambda: model_lib.rec_states(
+                        model_lib.init_rec_state(cfg, 1)))))
         self._admit_count = 0        # this iteration's admissions
         self._admit_tokens = 0       # and their prompt tokens
         # the decode step decides for itself whether it reads KV

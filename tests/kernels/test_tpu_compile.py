@@ -420,6 +420,90 @@ def test_a_state_space_decode_step_rewrites_its_states_in_place(
     assert not takers, takers
 
 
+def test_mamba_step_at_one_group_of_64_heads(topo):
+    """The kernel at granite-4.0-h-micro's widths: 64 slots of 64 heads x
+    64 in ONE group, state width 128, 36 layers' states (4.8 GB) stacked
+    and donated.  Fewer heads than a register has lanes: the kernel's
+    ``dt x`` and ``y`` are padded to 128 around it (as [64, 64] Mosaic
+    refuses the roll: "unsupported unaligned shape", PR 49), and a group
+    of 64 is cut into blocks of 16, two a grid step."""
+    from megatron_llm_tpu.kernels.mamba_step import heads_per_step
+
+    one = SingleDeviceSharding(topo.devices[0])
+    L, b, H, P, N, G = 36, 64, 64, 64, 128, 1
+    assert heads_per_step(H, G) == heads_per_step(128, 8) == 32
+    f32 = jnp.float32
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one) for s, d in (
+        ((b, H, P), f32), ((b, G, N), f32), ((b, G, N), f32), ((b, H), f32),
+        ((H,), f32), ((L, b, H, P, N), f32), ((1,), jnp.int32))]
+    compiled = jax.jit(
+        lambda x, B, C, dt, A, ssm, at: mamba_step(
+            x, B, C, dt, A, ssm, at[0], interpret=False),
+        donate_argnums=5).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 4 * L * b * H * P * N
+    assert mem.temp_size_in_bytes < 2 ** 21
+    _no_copy_of(text, f"f32[{L},{b},{H},{P},{N}]")
+
+
+def test_a_whole_depth_hybrid_step_moves_no_stacked_state(topo, monkeypatch):
+    """The engine's decode executable and its state install for
+    granite-4.0-h-micro whole: 40 layers in four scanned periods, 64
+    slots, 12.9 GB of arguments of which the 6.6 GB of pool and slot state
+    are donated and aliased.  Inside the scan's ``while`` every
+    state-space layer advances its own layer of the stacked states where
+    they lie, and nothing copies or re-lays an array of all the layers'
+    states or tails: with the tail stacked as ``[36, 64, 3, 4352]`` (three
+    rows padded to a tile of four) XLA:TPU re-laid the whole 160 MB array
+    twice between every two layers of a period, 7 GB a step (PR 49)."""
+    from megatron_llm_tpu.config import granite_hybrid_config
+    from megatron_llm_tpu.serving import engine as engine_lib
+    from megatron_llm_tpu.serving import slots as slots_lib
+
+    monkeypatch.setattr(attn_ops, "_backend", lambda: "tpu")
+    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
+    one = SingleDeviceSharding(topo.devices[0])
+    S, blocks, bk = 64, 24, 128
+    cfg = granite_hybrid_config("4.0-h-micro", attention_impl="flash")
+    place = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
+    params = jax.eval_shape(
+        lambda key: model_lib.init_params(key, cfg), jax.random.key(0))
+    pool = jax.eval_shape(
+        lambda: model_lib.init_kv_pool(cfg, S * blocks + 1, bk))
+    rec = jax.eval_shape(lambda: model_lib.init_rec_state(cfg, S))
+    assert rec["ssm"].shape == (36, S, 64, 64, 128)
+    assert rec["ssm_conv"].shape == (36, S, 3 * 4352)
+    i32, f32 = jnp.int32, jnp.float32
+    vec = lambda dtype: place(_sds((S,), dtype))  # noqa: E731
+    compiled = engine_lib._decode_donated.lower(
+        cfg, place(params), *place(pool), place(_sds((S, blocks), i32)),
+        vec(i32), vec(i32), vec(jnp.uint32), vec(i32), vec(bool), vec(f32),
+        vec(i32), vec(f32), rec=place(rec), live=vec(bool)).compile()
+    mem = compiled.memory_analysis()
+    donated = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree.leaves((pool, rec)))
+    assert 12.8e9 < mem.argument_size_in_bytes < 13.1e9
+    assert donated <= mem.alias_size_in_bytes < donated + 2 ** 20
+    assert mem.temp_size_in_bytes < 0.15e9
+    text = compiled.as_text()
+    # the kernel once a state-space layer of a period's body
+    assert len(ops_under_scopes(text, ["mamba_step"], {"custom-call"})) \
+        == 9
+    assert not [k for k in relayout_bytes(text) if k.startswith("f32[36,")]
+    assert "remat_compressed" not in text
+    # and the install writes one slot's rows into the donated stack
+    slot = jax.eval_shape(lambda: model_lib.init_rec_state(cfg, 1))
+    mem = slots_lib._install_rec_donated.lower(
+        place(rec), place(slot), place(_sds((), i32))).compile(
+        ).memory_analysis()
+    states = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(rec))
+    assert states <= mem.alias_size_in_bytes < states + 2 ** 20
+    assert mem.temp_size_in_bytes < 2 ** 20
+
+
 def test_a_dropless_prefill_routes_through_the_grouped_kernel(topo,
                                                               monkeypatch):
     """The engine's prefill executable for one period of Qwen3-Next at the
