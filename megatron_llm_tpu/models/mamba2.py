@@ -13,10 +13,11 @@ a group, ``a`` and ``dt`` scalars a head.  The output is gated by
 ``SiLU(z)``, RMS-normalised over each group's channels and projected back.
 
 Two forms of the same recurrence: ``ssd_step`` for the one new position
-of a decode step (the kernel ``kernels/mamba_step.py``: a step reads and
-writes every live slot's state once, where it lies in the stacked states
-of the serving tree, and is bound by that traffic, not by arithmetic),
-and ``ssd_chunked`` for a prompt, which rearranges ``chunk``
+of a decode step (the kernel ``kernels/mamba_step.py``, which is the whole
+mixer between its two projections: a step reads and writes every live
+slot's state and tail once, where they lie in the stacked states of the
+serving tree, and is bound by that traffic, not by arithmetic), and
+``ssd_chunked`` for a prompt, which rearranges ``chunk``
 positions at a time into matrix products (the paper's state-space
 duality, section 6): inside a chunk ``(C B^T . L)(dt x)`` with ``L`` the
 lower-triangular products of ``a``, the chunk's own end state from
@@ -63,10 +64,10 @@ class MambaState(NamedTuple):
     inputs, oldest first, [b, taps - 1, channels] or, where the stack's
     scan has more than one period, flat [b, (taps - 1) x channels]
     (``init_state``); both float32.  With ``at`` (an int32
-    scalar, may be traced) ``S`` is the stacked states of all the layers,
-    [layers, b, ...], and this layer's is ``S[at]``: how a decode step
-    hands them through, since its kernel advances the layer where it
-    lies."""
+    scalar, may be traced) ``S`` and ``conv`` are the stacked states and
+    tails of all the layers, [layers, b, ...], and this layer's are
+    ``S[at]`` and ``conv[at]``: how a decode step hands them through,
+    since its kernel advances the layer where it lies."""
 
     S: jax.Array
     conv: jax.Array
@@ -134,42 +135,49 @@ def init_state(cfg: ModelConfig, batch: int) -> MambaState:
 def state_at(stacked: dict, at, one_position: bool) -> MambaState:
     """Layer ``at`` of the stacked states ``{"ssm": [layers, b, ...],
     "ssm_conv": [...]}`` (``models/model.py:init_rec_state``); for one
-    position the states stay stacked (``MambaState.at``)."""
+    position both stay stacked (``MambaState.at``): the kernel picks the
+    layer's state and tail where they lie."""
     S, conv = (stacked[name] for name in STATE_NAMES)
-    conv = jax.lax.dynamic_index_in_dim(conv, at, 0, keepdims=False)
     if one_position:
         return MambaState(S, conv, at)
-    return MambaState(
-        jax.lax.dynamic_index_in_dim(S, at, 0, keepdims=False), conv)
+    conv, S = (jax.lax.dynamic_index_in_dim(a, at, 0, keepdims=False)
+               for a in (conv, S))
+    return MambaState(S, conv)
 
 
 def write_back(stacked: dict, new: MambaState, at) -> dict:
-    """``new`` as layer ``at`` of the stacked states, in place: the tail,
-    and a prompt's end state (XLA fuses the update into the write, whose
+    """``new`` as layer ``at`` of the stacked states, in place: a prompt's
+    end state and tail (XLA fuses the update into the write, whose
     operation is this one: so it stands under the scope of the form that
     made the state).  One position's kernel has written its layer into
-    the stacked states already (``new.at``)."""
-    one_position = new.at is not None
-    with jax.named_scope("mamba"), jax.named_scope(
-            "mamba_step" if one_position else "mamba_scan"):
-        put = lambda name, a: jax.lax.dynamic_update_index_in_dim(  # noqa: E731
-            stacked[name], a, at, 0)
-        S, conv = STATE_NAMES
-        return {S: new.S if one_position else put(S, new.S),
-                conv: put(conv, new.conv)}
+    both stacked arrays already (``new.at``)."""
+    if new.at is not None:
+        return dict(zip(STATE_NAMES, new[:2]))
+    with jax.named_scope("mamba"), jax.named_scope("mamba_scan"):
+        return {name: jax.lax.dynamic_update_index_in_dim(
+            stacked[name], a, at, 0) for name, a in zip(STATE_NAMES, new)}
 
 
 @jax.named_scope("mamba_step")
-def ssd_step(x, B, C, dt, A, S, at=None):
-    """One position.  ``x`` [b, H, P], ``B C`` [b, G, N], ``dt`` [b, H],
-    ``A`` [H] (negative), ``S`` [b, H, P, N], or with ``at`` the stacked
-    [layers, b, H, P, N] of which layer ``at`` is advanced and the others
-    are left as they lie; all float32 → ``(y [b, H, P], S)``.  One pass:
-    the state is read once and written once (``kernels/mamba_step.py``)."""
-    if at is not None:
-        return mamba_step(x, B, C, dt, A, S, at)
-    y, S = mamba_step(x, B, C, dt, A, S[None], jnp.int32(0))
-    return y, S[0]
+def ssd_step(p: Params, zxbcdt, live, state: MambaState, eps: float):
+    """One position, everything between the two projections.  ``zxbcdt``
+    [b, z | x | B | C | dt] float32, ``live`` [b] bool, ``state`` one
+    layer's or (``state.at``) the stacked states and tails, of which layer
+    ``at`` is advanced where ``live`` and the others are left as they lie
+    → ``(y [b, inner width] float32, the state in the form it came)``.
+    One kernel (``kernels/mamba_step.py``): the state and the tail are
+    read once and written once; a state that is not stacked goes through
+    it as a stack of one."""
+    S, conv, at = state
+    if at is None:
+        S, conv = S[None], conv[None]
+    y, S, conv = mamba_step(
+        zxbcdt, p["conv"], p["conv_bias"], p["dt_bias"], p["A_log"], p["D"],
+        p["norm"]["scale"], live, S, conv,
+        jnp.int32(0) if at is None else at, eps=eps)
+    if at is None:
+        S, conv = S[0], conv[0]
+    return y, MambaState(S, conv, at)
 
 
 @jax.named_scope("mamba_scan")
@@ -235,13 +243,29 @@ def mamba_block(cfg: ModelConfig, p: Params, x: jax.Array,
     valid positions)``.  ``valid`` [b, s] bool marks the positions that
     are there, a prefix of each row (None: all)."""
     b, s, _ = x.shape
-    H, P, G, N, di, ch = dims(cfg)
     if state is None:
         state = init_state(cfg, b)
     if valid is None:
         valid = jnp.ones((b, s), bool)
     with jax.named_scope("mamba_proj"):
         zxbcdt = dot_rounded(x, p["w_in"])
+    if s == 1:
+        y, state = ssd_step(p, zxbcdt[:, 0], valid[:, 0], state,
+                            cfg.norm_eps)
+        y = y[:, None]
+    else:
+        assert state.at is None, "a prompt takes one layer's state"
+        y, state = _prompt(cfg, p, zxbcdt, state, valid)
+    with jax.named_scope("mamba_proj"):
+        out = dot_rounded(y, p["w_out"]).astype(x.dtype)
+    return out, state
+
+
+def _prompt(cfg: ModelConfig, p: Params, zxbcdt, state: MambaState, valid):
+    """A prompt between the two projections: the convolution, the chunked
+    form, the skip, the gate and the norm, plain ``jax.numpy``."""
+    b, s, _ = zxbcdt.shape
+    H, P, G, N, di, ch = dims(cfg)
     z, mixed, dt = (zxbcdt[..., :di], zxbcdt[..., di:di + ch],
                     zxbcdt[..., di + ch:])
     mixed, conv = _conv(p, mixed, state.conv,
@@ -252,26 +276,17 @@ def mamba_block(cfg: ModelConfig, p: Params, x: jax.Array,
     # (no clamp of the step: the published config has no time_step_limit)
     dt = jax.nn.softplus(dt + p["dt_bias"]) * valid[..., None]
     A = -jnp.exp(p["A_log"])
-    if s == 1:
-        y, S = ssd_step(xs[:, 0], B[:, 0], C[:, 0], dt[:, 0], A, state.S,
-                        state.at)
-        y = y[:, None]
-    else:
-        pad = -s % cfg.mamba_chunk_size    # padded positions: dt = 0
+    pad = -s % cfg.mamba_chunk_size    # padded positions: dt = 0
 
-        def padded(a):
-            return jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2))
+    def padded(a):
+        return jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2))
 
-        assert state.at is None, "a prompt takes one layer's state"
-        y, S = ssd_chunked(*map(padded, (xs, B, C, dt)), A, state.S,
-                           cfg.mamba_chunk_size)
-        y = y[:, :s]
-    y = y + p["D"][:, None] * xs
+    y, S = ssd_chunked(*map(padded, (xs, B, C, dt)), A, state.S,
+                       cfg.mamba_chunk_size)
+    y = y[:, :s] + p["D"][:, None] * xs
     # the gate first, RMSNorm over each group's channels after
     y = (y.reshape(b, s, di) * jax.nn.silu(z)).reshape(b, s, G, di // G)
     y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True)
                           + cfg.norm_eps)
     y = y.reshape(b, s, di) * p["norm"]["scale"].astype(jnp.float32)
-    with jax.named_scope("mamba_proj"):
-        out = dot_rounded(y, p["w_out"]).astype(x.dtype)
-    return out, MambaState(S, conv, state.at)
+    return y, MambaState(S, conv)

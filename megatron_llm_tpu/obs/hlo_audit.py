@@ -119,18 +119,19 @@ _OP_NAME = re.compile(r'op_name="([^"]*)"')
 
 def ops_under_scopes(hlo_text: str, scopes, opcodes) -> list:
     """``[(opcode, result type, scope path)]`` of a compiled program's
-    instructions with one of ``opcodes`` whose ``op_name`` holds one of
-    ``scopes`` as a whole step of its path (``jax.named_scope``), in any
-    computation: what a block was said to do without (a scatter, a row
-    gather, a ragged product) and still does (docs/serving.md, "How a
-    dropless layer routes")."""
+    instructions with one of ``opcodes`` (None: any) whose ``op_name``
+    holds one of ``scopes`` as a whole step of its path
+    (``jax.named_scope``), in any computation: what a block was said to
+    do without (a scatter, a row gather, a ragged product) and still does
+    (docs/serving.md, "How a dropless layer routes").  A fused
+    computation's instructions are listed beside the fusion itself."""
     comps, _ = _computations(hlo_text)
     found = []
     for lines in comps.values():
         for line in lines:
             _, _, rhs = line.partition(" = ")
             op, name = _OPCODE.search(rhs), _OP_NAME.search(line)
-            if (op and name and op.group(1) in opcodes
+            if (op and name and (opcodes is None or op.group(1) in opcodes)
                     and set(scopes) & set(name.group(1).split("/"))):
                 found.append((op.group(1), rhs[:op.start()].strip(),
                               name.group(1)))

@@ -1171,11 +1171,12 @@ class ServingEngine:
         self._state_arg = {"state_kinds": "+".join(kinds)} if kinds else {}
         self._counts_ssm = cfg.mamba_layers > 0
         # how a step's state-space layers ran, on its decode spans: each
-        # advanced where it lies in the stacked states by one kernel, so
-        # many heads of a slot a grid step (kernels/mamba_step.py; no such
-        # fields for a stack without them)
+        # between its two projections as one kernel, which advances the
+        # layer's states and tails where they lie stacked, so many heads
+        # of a slot a grid step (kernels/mamba_step.py; no such fields for
+        # a stack without them)
         self._step_arg = dict(self._state_arg, **(
-            {"ssm_step": "fused", "ssm_tile": heads_per_step(
+            {"ssm_step": "mixer", "ssm_tile": heads_per_step(
                 cfg.mamba_num_heads, cfg.mamba_n_groups)}
             if self._counts_ssm else {}))
         # on a prompt's prefill spans: how its Gated DeltaNet layers ran

@@ -339,28 +339,64 @@ def test_grouped_mlp_of_two_matrices_in_a_latent(topo, tokens):
     assert text.count('custom_call_target="tpu_custom_call"') == 1
 
 
-def test_mamba_step(topo):
-    """The state-space decode step's kernel alone at Nemotron-3-Super's
-    widths: 128 slots of 128 heads x 64 in 8 groups, state width 128, the
-    five layers' states stacked and donated, the layer a traced scalar.
-    The 2.7 GB are aliased to the output and nothing of their size is
-    made beside them."""
+def _mixer_alone(topo, L, b, H, P, N, G, flat):
+    """The one-position mixer's kernel alone for a described chip, the
+    stacked states and tails donated: ``(text, memory analysis, the two
+    stacked shapes)``."""
     one = SingleDeviceSharding(topo.devices[0])
-    L, b, H, P, N, G = 5, 128, 128, 64, 128, 8
+    di, taps = H * P, 4
+    ch = di + 2 * G * N
+    tail = (L, b, (taps - 1) * ch) if flat else (L, b, taps - 1, ch)
     f32 = jnp.float32
     args = [jax.ShapeDtypeStruct(s, d, sharding=one) for s, d in (
-        ((b, H, P), f32), ((b, G, N), f32), ((b, G, N), f32), ((b, H), f32),
-        ((H,), f32), ((L, b, H, P, N), f32), ((1,), jnp.int32))]
+        ((b, di + ch + H), f32), ((taps, ch), BF16), ((ch,), BF16),
+        ((H,), f32), ((H,), f32), ((H,), f32), ((di,), BF16), ((b,), bool),
+        ((L, b, H, P, N), f32), (tail, f32), ((1,), jnp.int32))]
     compiled = jax.jit(
-        lambda x, B, C, dt, A, ssm, at: mamba_step(
-            x, B, C, dt, A, ssm, at[0], interpret=False),
-        donate_argnums=5).lower(*args).compile()
-    text = compiled.as_text()
+        lambda zx, w, bias, dtb, alog, D, scale, live, ssm, tails, at:
+        mamba_step(zx, w, bias, dtb, alog, D, scale, live, ssm, tails,
+                   at[0], eps=1e-5, interpret=False),
+        donate_argnums=(8, 9)).lower(*args).compile()
+    return compiled.as_text(), compiled.memory_analysis(), (
+        (L, b, H, P, N), tail)
+
+
+def _shape(dims):
+    return "f32[" + ",".join(map(str, dims)) + "]"
+
+
+def test_mamba_step(topo):
+    """The one-position mixer's kernel alone at Nemotron-3-Super's
+    widths: 128 slots of 128 heads x 64 in 8 groups, state width 128, the
+    five layers' states and their tails of three rows stacked and
+    donated, the layer a traced scalar.  The 2.7 GB of states and the 79
+    MB of tails are aliased to the outputs and nothing of their size is
+    made beside them: XLA:TPU keeps the three rows outermost of a layer,
+    and the kernel takes them so (as ``[.., slots, 3, channels]`` blocks
+    the whole array was copied at both ends of the call)."""
+    L, b, H, P, N, G = 5, 128, 128, 64, 128, 8
+    text, mem, (ssm, tail) = _mixer_alone(topo, L, b, H, P, N, G, False)
     assert text.count('custom_call_target="tpu_custom_call"') == 1
-    mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes == 4 * L * b * H * P * N
+    assert mem.alias_size_in_bytes == 4 * L * b * (
+        H * P * N + 3 * (H * P + 2 * G * N))
     assert mem.temp_size_in_bytes < 2 ** 20
-    _no_copy_of(text, f"f32[{L},{b},{H},{P},{N}]")
+    _no_copy_of(text, _shape(ssm))
+    _no_copy_of(text, _shape(tail))
+    _no_copy_of(text, _shape((L, 3, b, tail[-1])))
+
+
+def _beside_the_mixer(text, slots):
+    """What a compiled step's state-space layers run at one position
+    between their two projections (under ``mamba``, outside
+    ``mamba_proj``) besides the kernel: nothing but relabellings and,
+    once a program, the ``live`` mask turned into the integers the kernel
+    prefetches."""
+    relabels = {"custom-call", "get-tuple-element", "bitcast", "tuple",
+                "constant", "parameter"}
+    return [(op, result, path)
+            for op, result, path in ops_under_scopes(text, ["mamba"], None)
+            if "mamba_proj" not in path.split("/") and op not in relabels
+            and not re.match(rf"s32\[{slots}(,1)?\]", result)]
 
 
 def test_a_state_space_decode_step_rewrites_its_states_in_place(
@@ -369,9 +405,10 @@ def test_a_state_space_decode_step_rewrites_its_states_in_place(
     at the published widths and 128 slots: 12.6 GB of arguments (weights,
     pool, 2.7 GB of state-space states), every donated byte aliased to an
     output and under 0.3 GB of temporaries: each Mamba-2 layer's kernel
-    (``kernels/mamba_step.py``) takes the stacked states and advances its
-    layer where it lies, and no other operation of the step touches
-    them.  A second copy of the states would not fit the chip."""
+    (``kernels/mamba_step.py``) takes the stacked states and tails and
+    advances its layer where it lies, no other operation of the step
+    touches them, and between the layer's two projections the kernel is
+    all that runs.  A second copy of the states would not fit the chip."""
     from megatron_llm_tpu.config import nemotron_h_config
     from megatron_llm_tpu.serving import engine as engine_lib
 
@@ -410,6 +447,8 @@ def test_a_state_space_decode_step_rewrites_its_states_in_place(
     # the program's result alone
     assert len(ops_under_scopes(text, ["mamba_step"], {"custom-call"})) \
         == cfg.mamba_layers == 5
+    assert _beside_the_mixer(text, S) == []
+    assert not [k for k in relayout_bytes(text) if k.startswith("f32[5,")]
     layer = re.escape("%d,%d,%d,%d]" % rec["ssm"].shape[1:])
     made = set(re.findall(rf"(%\S+) = [^=]*{layer}[^=]* [\w-]+\(", text))
     assert len(made) == 1 + 2 * cfg.mamba_layers, made
@@ -422,30 +461,21 @@ def test_a_state_space_decode_step_rewrites_its_states_in_place(
 
 def test_mamba_step_at_one_group_of_64_heads(topo):
     """The kernel at granite-4.0-h-micro's widths: 64 slots of 64 heads x
-    64 in ONE group, state width 128, 36 layers' states (4.8 GB) stacked
-    and donated.  Fewer heads than a register has lanes: the kernel's
-    ``dt x`` and ``y`` are padded to 128 around it (as [64, 64] Mosaic
-    refuses the roll: "unsupported unaligned shape", PR 49), and a group
-    of 64 is cut into blocks of 16, two a grid step."""
+    64 in ONE group, state width 128, 36 layers' states (4.8 GB) and flat
+    tails (120 MB) stacked and donated.  A group of 64 is cut into blocks
+    of 16, two a grid step; the group's norm spans both grid steps of a
+    slot; the projection's row of 8512 is no whole number of registers."""
     from megatron_llm_tpu.kernels.mamba_step import heads_per_step
 
-    one = SingleDeviceSharding(topo.devices[0])
     L, b, H, P, N, G = 36, 64, 64, 64, 128, 1
     assert heads_per_step(H, G) == heads_per_step(128, 8) == 32
-    f32 = jnp.float32
-    args = [jax.ShapeDtypeStruct(s, d, sharding=one) for s, d in (
-        ((b, H, P), f32), ((b, G, N), f32), ((b, G, N), f32), ((b, H), f32),
-        ((H,), f32), ((L, b, H, P, N), f32), ((1,), jnp.int32))]
-    compiled = jax.jit(
-        lambda x, B, C, dt, A, ssm, at: mamba_step(
-            x, B, C, dt, A, ssm, at[0], interpret=False),
-        donate_argnums=5).lower(*args).compile()
-    text = compiled.as_text()
+    text, mem, (ssm, tail) = _mixer_alone(topo, L, b, H, P, N, G, True)
     assert text.count('custom_call_target="tpu_custom_call"') == 1
-    mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes == 4 * L * b * H * P * N
+    assert mem.alias_size_in_bytes == 4 * L * b * (
+        H * P * N + 3 * (H * P + 2 * G * N))
     assert mem.temp_size_in_bytes < 2 ** 21
-    _no_copy_of(text, f"f32[{L},{b},{H},{P},{N}]")
+    _no_copy_of(text, _shape(ssm))
+    _no_copy_of(text, _shape(tail))
 
 
 def test_a_whole_depth_hybrid_step_moves_no_stacked_state(topo, monkeypatch):
@@ -454,8 +484,9 @@ def test_a_whole_depth_hybrid_step_moves_no_stacked_state(topo, monkeypatch):
     slots, 12.9 GB of arguments of which the 6.6 GB of pool and slot state
     are donated and aliased.  Inside the scan's ``while`` every
     state-space layer advances its own layer of the stacked states where
-    they lie, and nothing copies or re-lays an array of all the layers'
-    states or tails: with the tail stacked as ``[36, 64, 3, 4352]`` (three
+    they lie (between its two projections the kernel is all that runs),
+    and nothing copies or re-lays an array of all the layers' states or
+    tails: with the tail stacked as ``[36, 64, 3, 4352]`` (three
     rows padded to a tile of four) XLA:TPU re-laid the whole 160 MB array
     twice between every two layers of a period, 7 GB a step (PR 49)."""
     from megatron_llm_tpu.config import granite_hybrid_config
@@ -492,6 +523,7 @@ def test_a_whole_depth_hybrid_step_moves_no_stacked_state(topo, monkeypatch):
     # the kernel once a state-space layer of a period's body
     assert len(ops_under_scopes(text, ["mamba_step"], {"custom-call"})) \
         == 9
+    assert _beside_the_mixer(text, S) == []
     assert not [k for k in relayout_bytes(text) if k.startswith("f32[36,")]
     assert "remat_compressed" not in text
     # and the install writes one slot's rows into the donated stack

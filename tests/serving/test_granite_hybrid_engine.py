@@ -91,7 +91,7 @@ def test_the_spans_say_how_the_state_was_stepped_and_installed(model):
     # span says how many heads of a slot a grid step of the kernel took
     assert {e["args"]["state_installed_bytes"] for e in prefills} == {
         state // 2}
-    assert all(e["args"]["ssm_step"] == "fused"
+    assert all(e["args"]["ssm_step"] == "mixer"
                and e["args"]["ssm_tile"] == heads_per_step(8, 1) == 8
                for e in decodes)
     assert not any("ssm_tile" in e["args"] for e in prefills)

@@ -125,10 +125,11 @@ def test_the_state_is_gauged_by_kind_and_its_positions_counted(model):
     assert all(e["args"]["state_kinds"] == "mamba"
                for e in prefills + decodes)
     # a decode span says how many slots its step moved, and that the
-    # step's state-space layers ran as the kernel (a prefill's do not)
+    # step's state-space layers ran as one kernel between their two
+    # projections (a prefill's do not)
     assert {e["args"]["live"] for e in decodes} <= {1, 2}
     assert 2 in {e["args"]["live"] for e in decodes}
-    assert all(e["args"]["ssm_step"] == "fused" for e in decodes)
+    assert all(e["args"]["ssm_step"] == "mixer" for e in decodes)
     assert not any("ssm_step" in e["args"] for e in prefills)
 
 
