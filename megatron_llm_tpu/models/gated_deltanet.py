@@ -24,10 +24,14 @@ the L2 norms, the rule (``S`` in VMEM from a prompt's first chunk to its
 last), the output norm and the gate on those tiles; HBM sees the
 projection's output once going in and the gated ``o`` once coming out.
 What is left to XLA of a prompt is the convolution's new tail, a few
-rows.  The one position of a decode step runs the same stages as
-``jax.numpy`` (their definitions are the kernel's: ``conv_taps``,
-``l2norm``, ``gated_rmsnorm``).  The
-state, the kernel's operands and everything that meets them are float32,
+rows.  The one position of a decode step is one kernel too
+(``kernels/gdn_step.py``): it takes the same stages (their definitions
+are the prompt kernel's: ``conv_taps``, ``l2norm``, ``gated_rmsnorm``)
+and the rule on a head's tile of the stacked states where it lies, one
+read and one write of the state; ``one_position`` and
+``delta_rule_step`` are its plain ``jax.numpy`` form, which the tests
+hold it to.  The
+state, the kernels' operands and everything that meets them are float32,
 and every product of the rule is taken at ``Precision.HIGHEST``: the rule
 takes differences of near-equal quantities (``v - S^T k``), so one bf16
 rounding comes out of it three times as large and the next router's
@@ -62,6 +66,7 @@ from ..kernels.gdn_scan import (
     gdn_scan,
     l2norm,
 )
+from ..kernels.gdn_step import gdn_step
 from ..ops.precision import dot_f32
 
 Params = dict
@@ -76,10 +81,20 @@ class GDNState(NamedTuple):
     """What a linear layer keeps of a sequence: ``S`` [b, value heads,
     key width, value width], and ``conv`` [b, taps - 1, channels]: the
     convolution's last inputs; both float32 (a tail rounded to bfloat16
-    would round the next positions' q, k and v before the rule)."""
+    would round the next positions' q, k and v before the rule).  With
+    ``at`` (an int32 scalar, may be traced) ``S`` and ``conv`` are the
+    stacked states and tails of all the layers, [layers, b, ...], and
+    this layer's are ``S[at]`` and ``conv[at]``: how a decode step hands
+    them through, since its kernel advances the layer where it lies."""
 
     S: jax.Array
     conv: jax.Array
+    at: Optional[jax.Array] = None
+
+
+# the names ``models/model.py:init_rec_state`` keeps a layer's two arrays
+# under, stacked over the DeltaNet layers
+STATE_NAMES = ("S", "conv")
 
 
 def dims(cfg: ModelConfig):
@@ -127,10 +142,10 @@ def init_state(cfg: ModelConfig, batch: int) -> GDNState:
                               jnp.float32))
 
 
-@jax.named_scope("gdn_step")
 def delta_rule_step(q, k, v, g, beta, S):
-    """One position.  ``q k`` [b, h, dk], ``v`` [b, h, dv], ``g beta``
-    [b, h], ``S`` [b, h, dk, dv], all float32 → ``(o [b, h, dv], S)``."""
+    """One position, the plain form.  ``q k`` [b, h, dk], ``v`` [b, h,
+    dv], ``g beta`` [b, h], ``S`` [b, h, dk, dv], all float32 → ``(o [b,
+    h, dv], S)``."""
     S = S * jnp.exp(g)[..., None, None]
     d = beta[..., None] * (v - jnp.einsum("bhk,bhkv->bhv", k, S,
                                           precision=_PREC))
@@ -181,8 +196,30 @@ def _gates(p: Params, ba, valid, nv):
     return beta, g
 
 
-def _one_position(cfg: ModelConfig, p: Params, qkvz, ba, state, valid):
-    """A decode step between the two projections, as ``jax.numpy``."""
+@jax.named_scope("gdn_step")
+def _one_position(cfg: ModelConfig, p: Params, qkvz, ba,
+                  state: GDNState, valid):
+    """A decode step between the two projections: one kernel
+    (``kernels/gdn_step.py``).  ``state`` one layer's or (``state.at``)
+    the stacked states and tails, of which layer ``at`` is advanced where
+    ``valid`` and the others are left as they lie; it comes back in the
+    form it came.  The state and the tail are read once and written once;
+    a state that is not stacked goes through as a stack of one."""
+    S, conv, at = state
+    if at is None:
+        S, conv = S[None], conv[None]
+    o, S, conv = gdn_step(
+        qkvz[:, 0], ba[:, 0], p["conv"], p["A_log"], p["dt_bias"],
+        p["norm"]["scale"], valid[:, 0], S, conv,
+        jnp.int32(0) if at is None else at, eps=cfg.norm_eps)
+    if at is None:
+        S, conv = S[0], conv[0]
+    return o[:, None], GDNState(S, conv, at)
+
+
+def one_position(cfg: ModelConfig, p: Params, qkvz, ba, state, valid):
+    """``_one_position`` as ``jax.numpy``, a stage a line: what the
+    kernel is held to (tests/kernels/test_gdn_step.py)."""
     b = qkvz.shape[0]
     nk, nv, dk, dv, _ch = dims(cfg)
     kd, vd = nk * dk, nv * dv
@@ -206,6 +243,7 @@ def _one_position(cfg: ModelConfig, p: Params, qkvz, ba, state, valid):
 def _prompt(cfg: ModelConfig, p: Params, qkvz, ba, state, valid):
     """A prompt between the two projections: the kernel, and the
     convolution's new tail."""
+    assert state.at is None, "a prompt takes one layer's state"
     s = qkvz.shape[1]
     beta, g = _gates(p, ba, valid, cfg.linear_num_value_heads)
     pad = -s % CHUNK       # padded positions: beta = g = 0, no-ops

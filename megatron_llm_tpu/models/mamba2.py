@@ -132,32 +132,6 @@ def init_state(cfg: ModelConfig, batch: int) -> MambaState:
                   jnp.float32))
 
 
-def state_at(stacked: dict, at, one_position: bool) -> MambaState:
-    """Layer ``at`` of the stacked states ``{"ssm": [layers, b, ...],
-    "ssm_conv": [...]}`` (``models/model.py:init_rec_state``); for one
-    position both stay stacked (``MambaState.at``): the kernel picks the
-    layer's state and tail where they lie."""
-    S, conv = (stacked[name] for name in STATE_NAMES)
-    if one_position:
-        return MambaState(S, conv, at)
-    conv, S = (jax.lax.dynamic_index_in_dim(a, at, 0, keepdims=False)
-               for a in (conv, S))
-    return MambaState(S, conv)
-
-
-def write_back(stacked: dict, new: MambaState, at) -> dict:
-    """``new`` as layer ``at`` of the stacked states, in place: a prompt's
-    end state and tail (XLA fuses the update into the write, whose
-    operation is this one: so it stands under the scope of the form that
-    made the state).  One position's kernel has written its layer into
-    both stacked arrays already (``new.at``)."""
-    if new.at is not None:
-        return dict(zip(STATE_NAMES, new[:2]))
-    with jax.named_scope("mamba"), jax.named_scope("mamba_scan"):
-        return {name: jax.lax.dynamic_update_index_in_dim(
-            stacked[name], a, at, 0) for name, a in zip(STATE_NAMES, new)}
-
-
 @jax.named_scope("mamba_step")
 def ssd_step(p: Params, zxbcdt, live, state: MambaState, eps: float):
     """One position, everything between the two projections.  ``zxbcdt``

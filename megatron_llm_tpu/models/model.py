@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..config import ModelConfig, PositionEmbeddingType
-from . import mamba2
+from . import gated_deltanet, mamba2
 from .transformer import (
     STREAM_DTYPE,
     AttnSideInputs,
@@ -626,8 +626,6 @@ def init_rec_state(cfg: ModelConfig, batch_size: int) -> dict:
     rows the layer's experts multiplied and skipped (``models/moe.py``),
     each count as two words (``add_rows``: a long prompt adds 10^5 to
     it)."""
-    from . import gated_deltanet
-
     rec = {}
     for kind, n, init in (("linear", cfg.linear_layers, gated_deltanet),
                           ("mamba", cfg.mamba_layers, mamba2)):
@@ -644,7 +642,8 @@ def init_rec_state(cfg: ModelConfig, batch_size: int) -> dict:
 # the names of ``init_rec_state``'s state arrays, by the kind of mixer that
 # keeps them: "linear" a Gated DeltaNet layer's, "mamba" a Mamba-2 mixer's
 # (the block kinds ``config.MAMBA_KINDS``)
-REC_STATE_KINDS = {"linear": ("S", "conv"), "mamba": mamba2.STATE_NAMES}
+REC_STATE_KINDS = {"linear": gated_deltanet.STATE_NAMES,
+                   "mamba": mamba2.STATE_NAMES}
 
 
 def rec_states(rec: dict) -> dict:

@@ -46,8 +46,8 @@ from ..ops.rope import (
     apply_rope_partial,
     precompute_rope_freqs,
 )
+from . import gated_deltanet, mamba2
 from .gated_deltanet import GDNState, gdn_block, init_gdn_params
-from . import mamba2
 
 Params = dict
 
@@ -732,6 +732,49 @@ def _stack_forward_periods(cfg: ModelConfig, stacked, x, side, base_rng,
     return x, aux
 
 
+class _RecMixer(NamedTuple):
+    """A kind of recurrent mixer as ``scan_periods_cached`` carries its
+    state: the class of a layer's state (``S``, ``conv``, ``at``), the
+    names of the two stacked arrays in ``models/model.py:init_rec_state``'s
+    tree, and the scope of the form that makes a prompt's end state."""
+
+    state: type
+    names: tuple
+    scope: str
+
+
+_GDN = _RecMixer(GDNState, gated_deltanet.STATE_NAMES, "gdn/gdn_scan")
+_MAMBA = _RecMixer(mamba2.MambaState, mamba2.STATE_NAMES,
+                   "mamba/mamba_scan")
+_REC_KINDS = {"linear": _GDN, **{kind: _MAMBA for kind in MAMBA_KINDS}}
+
+
+def _rec_state_at(mixer: _RecMixer, stacked: dict, at, one_position: bool):
+    """Layer ``at`` of ``mixer``'s stacked states ``{name: [layers, b,
+    ...]}``; for one position both arrays stay stacked (the state's
+    ``at``): the kernel picks the layer's state and tail where they
+    lie."""
+    S, conv = (stacked[name] for name in mixer.names)
+    if one_position:
+        return mixer.state(S, conv, at)
+    conv, S = (jax.lax.dynamic_index_in_dim(a, at, 0, keepdims=False)
+               for a in (conv, S))
+    return mixer.state(S, conv)
+
+
+def _rec_write_back(mixer: _RecMixer, stacked: dict, new, at) -> dict:
+    """``new`` as layer ``at`` of ``mixer``'s stacked states, in place: a
+    prompt's end state and tail (XLA fuses the update into the write,
+    whose operation is this one: so it stands under the scope of the form
+    that made the state).  One position's kernel has written its layer
+    into both stacked arrays already (``new.at``)."""
+    if new.at is not None:
+        return dict(zip(mixer.names, new[:2]))
+    with jax.named_scope(mixer.scope):
+        return {name: jax.lax.dynamic_update_index_in_dim(
+            stacked[name], a, at, 0) for name, a in zip(mixer.names, new)}
+
+
 def scan_periods_cached(cfg: ModelConfig, stacked, x, side, kv_of, rec,
                         kv_xs: tuple = ()):
     """The cached forms of a hybrid stack, prefill and decode alike: a
@@ -742,12 +785,10 @@ def scan_periods_cached(cfg: ModelConfig, stacked, x, side, kv_of, rec,
     ``rec`` (``models/model.py:init_rec_state``): a ``"linear"`` layer
     ``{"S": [linear layers, b, ...], "conv": [...]}``, a ``"mamba"`` or
     ``"ssm"`` layer ``{"ssm": [mamba layers, b, ...], "ssm_conv":
-    [...]}``.  The
-    delta-rule states go through the scan as its xs and ys; the
-    state-space states, thirteen times their size a layer, ride in the
-    carry: a prompt's layer reads and rewrites its own slice in place, a
-    decode step's kernel takes them stacked and advances its layer where
-    it lies (``models/mamba2.py:MambaState.at``).
+    [...]}``.  The states of either kind ride in the scan's carry: a
+    prompt's layer reads and rewrites its own slice in place, a decode
+    step's kernel takes them stacked and advances its layer where it lies
+    (``_rec_state_at``, ``_rec_write_back``).
 
     → ``(hidden, (rows_k, rows_v) stacked over the attending layers, rec's
     states advanced over the positions ``side.valid`` marks, counts
@@ -758,43 +799,38 @@ def scan_periods_cached(cfg: ModelConfig, stacked, x, side, kv_of, rec,
     kinds = cfg.layer_pattern
     n_per = cfg.num_layers // len(kinds)
     n_full = sum(kind in KV_KINDS for kind in kinds)
-    n_lin = kinds.count("linear")
-    n_mam = sum(kind in MAMBA_KINDS for kind in kinds)
     x = x.astype(STREAM_DTYPE)
 
     def by_period(a, n):
         return a.reshape((n_per, n) + a.shape[1:])
 
-    xs = (tuple(stacked), tuple(by_period(a, n_full) for a in kv_xs),
-          jax.tree.map(lambda a: by_period(a, n_lin),
-                       {name: rec[name] for name in ("S", "conv")
-                        if n_lin}))
-    ssm = {name: rec[name] for name in mamba2.STATE_NAMES if n_mam}
+    xs = (tuple(stacked), tuple(by_period(a, n_full) for a in kv_xs))
+    # a layer's recurrent mixer (None: it keeps no such state), its place
+    # among that mixer's layers of a period, and how many those are
+    mixers = [_REC_KINDS.get(kind) for kind in kinds]
+    place = [mixers[:j].count(mixer) for j, mixer in enumerate(mixers)]
+    n_rec = {mixer: mixers.count(mixer) for mixer in mixers if mixer}
+    states = {name: rec[name] for mixer in n_rec for name in mixer.names}
 
     def body(carry, inp):
-        h, idx, ssm = carry
-        period, kv_p, rec_p = inp
-        rows, states, counts, f, l, m = [], [], [], 0, 0, 0
-        for layer_params, kind in zip(period, kinds):
+        h, idx, states = carry
+        period, kv_p = inp
+        rows, counts, f = [], [], 0
+        for layer_params, kind, mixer, j in zip(period, kinds, mixers, place):
             attends, cache = kind in KV_KINDS, None
             if attends:
                 cache = kv_of(idx * n_full + f, *(a[f] for a in kv_p))
                 f += 1
-            elif kind == "linear":
-                cache = GDNState(rec_p["S"][l], rec_p["conv"][l])
-                l += 1
-            elif kind in MAMBA_KINDS:
-                at = idx * n_mam + m
-                cache = mamba2.state_at(ssm, at, h.shape[1] == 1)
-                m += 1
+            elif mixer:
+                at = idx * n_rec[mixer] + j
+                cache = _rec_state_at(mixer, states, at, h.shape[1] == 1)
             h, aux, *new = layer_forward(cfg, layer_params, h, side, None,
                                          kv_cache=cache)
             if attends:
                 rows += new
-            elif kind == "linear":
-                states += new
-            elif kind in MAMBA_KINDS:
-                ssm = mamba2.write_back(ssm, new[0], at)
+            elif mixer:
+                states = {**states,
+                          **_rec_write_back(mixer, states, new[0], at)}
             counts.append(
                 {name: aux[name] for name in ("load", "rows")}
                 if isinstance(aux, dict) else
@@ -802,16 +838,12 @@ def scan_periods_cached(cfg: ModelConfig, stacked, x, side, kv_of, rec,
                  "rows": jnp.zeros((2,), jnp.float32)})
         stack = lambda xs_: jax.tree.map(lambda *a: jnp.stack(a), *xs_) \
             if xs_ else ()
-        return (h, idx + 1, ssm), (stack(rows), stack(states),
-                                   stack(counts))
+        return (h, idx + 1, states), (stack(rows), stack(counts))
 
-    (x, _, ssm), (rows, states, counts) = jax.lax.scan(
-        body, (x, jnp.int32(0), ssm), xs)
+    (x, _, states), (rows, counts) = jax.lax.scan(
+        body, (x, jnp.int32(0), states), xs)
     flat = lambda a: a.reshape((a.shape[0] * a.shape[1],) + a.shape[2:])
-    rows = jax.tree.map(flat, rows)
-    if n_lin:
-        ssm = {"S": flat(states.S), "conv": flat(states.conv), **ssm}
-    return x, rows, ssm, jax.tree.map(flat, counts)
+    return x, jax.tree.map(flat, rows), states, jax.tree.map(flat, counts)
 
 
 def _scan_layers_cached(cfg: ModelConfig, stacked: Params, x: jax.Array,

@@ -1170,15 +1170,17 @@ class ServingEngine:
                                       ("mamba", cfg.mamba_layers)) if n]
         self._state_arg = {"state_kinds": "+".join(kinds)} if kinds else {}
         self._counts_ssm = cfg.mamba_layers > 0
-        # how a step's state-space layers ran, on its decode spans: each
+        # how a step's recurrent mixers ran, on its decode spans: each
         # between its two projections as one kernel, which advances the
-        # layer's states and tails where they lie stacked, so many heads
-        # of a slot a grid step (kernels/mamba_step.py; no such fields for
-        # a stack without them)
-        self._step_arg = dict(self._state_arg, **(
-            {"ssm_step": "mixer", "ssm_tile": heads_per_step(
+        # layer's states and tails where they lie stacked
+        # (kernels/gdn_step.py; kernels/mamba_step.py, so many heads of a
+        # slot a grid step; no such fields for a stack without them)
+        self._step_arg = dict(
+            self._state_arg,
+            **({"gdn_step": "mixer"} if cfg.linear_layers else {}),
+            **({"ssm_step": "mixer", "ssm_tile": heads_per_step(
                 cfg.mamba_num_heads, cfg.mamba_n_groups)}
-            if self._counts_ssm else {}))
+               if self._counts_ssm else {}))
         # on a prompt's prefill spans: how its Gated DeltaNet layers ran
         # (between their projections as one kernel, kernels/gdn_scan.py;
         # no such field for a stack without them), and the bytes of slot

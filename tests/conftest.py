@@ -40,6 +40,8 @@ if _cache_dir and _cache_dir != "off":
 else:
     jax.config.update("jax_enable_compilation_cache", False)
 
+import gc  # noqa: E402
+
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
@@ -54,6 +56,33 @@ def devices():
 @pytest.fixture
 def rng():
     return np.random.default_rng(1234)
+
+
+# Every XLA:CPU executable a process holds maps a dozen regions of memory,
+# jax keeps every executable it ever compiled, and Linux gives a process
+# vm.max_map_count regions, 65 530 by default: past it the next
+# compilation's mmap fails and the worker dies of a segmentation fault
+# inside ``backend_compile_and_load``, in whatever test happens to compile
+# next.  A worker of the six-worker tier-1 run that draws the serving and
+# generation tests reached 65 230 (PR 51: three whole runs of three lost
+# a worker that way, each in another test).  Dropping jax's caches frees
+# the executables and their regions (2910 -> 600 for 200 small ones);
+# between two modules nothing that a test still holds is lost but compile
+# time.
+_MAPPED_REGIONS_HIGH = 44_000
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _bounded_mapped_regions():
+    yield
+    try:
+        with open("/proc/self/maps") as f:
+            regions = sum(1 for _ in f)
+    except OSError:      # no procfs: nothing to count, nothing to bound
+        return
+    if regions > _MAPPED_REGIONS_HIGH:
+        jax.clear_caches()
+        gc.collect()
 
 
 # ---------------------------------------------------------------------------

@@ -35,6 +35,7 @@ from megatron_llm_tpu.kernels.flash_attention import flash_attention  # noqa: E4
 from megatron_llm_tpu.kernels.grouped_matmul import (  # noqa: E402
     grouped_mlp,
 )
+from megatron_llm_tpu.kernels.gdn_step import gdn_step  # noqa: E402
 from megatron_llm_tpu.kernels.mamba_step import mamba_step  # noqa: E402
 from megatron_llm_tpu.kernels.rmsnorm import (  # noqa: E402
     layernorm_pallas,
@@ -261,7 +262,7 @@ def test_gdn_block(topo, monkeypatch, s):
     text = _compile(
         lambda p, x, S, conv, valid: gdn.gdn_block(
             cfg, p, x, gdn.GDNState(S, conv), valid),
-        (params, _sds((1, s, cfg.hidden_size), f32), *state,
+        (params, _sds((1, s, cfg.hidden_size), f32), state.S, state.conv,
          _sds((1, s), jnp.bool_)), one)
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert " while(" not in text
@@ -284,6 +285,39 @@ def test_gdn_block(topo, monkeypatch, s):
     # what a chunk's matrices spill.  Of 16 MiB a kernel may have by
     # default
     assert 4.5 * 2 ** 20 < int(vmem) < 9 * 2 ** 20, vmem
+
+
+def test_gdn_step(topo):
+    """The one-position DeltaNet mixer's kernel alone at the published
+    widths and the long-document cell's 44 slots (no whole blocks of
+    eight): 16 key / 32 value heads x 128, the three layers' states (277
+    MB) and their tails of three rows stacked and donated, the layer a
+    traced scalar.  Both are aliased to the outputs and nothing of their
+    size is made beside them: XLA:TPU keeps the three rows outermost of a
+    layer, and the kernel takes them so."""
+    one = SingleDeviceSharding(topo.devices[0])
+    L, b, nk, nv, dk, dv, taps = 3, 44, 16, 32, 128, 128, 4
+    ch = 2 * nk * dk + nv * dv
+    f32 = jnp.float32
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one) for s, d in (
+        ((b, ch + nv * dv), f32), ((b, 2 * nv), f32), ((taps, ch), BF16),
+        ((nv,), f32), ((nv,), f32), ((dv,), BF16), ((b,), bool),
+        ((L, b, nv, dk, dv), f32), ((L, b, taps - 1, ch), f32),
+        ((1,), jnp.int32))]
+    compiled = jax.jit(
+        lambda qkvz, ba, w, alog, dtb, scale, live, S, tails, at:
+        gdn_step(qkvz, ba, w, alog, dtb, scale, live, S, tails, at[0],
+                 eps=1e-6, interpret=False),
+        donate_argnums=(7, 8)).lower(*args).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    # (the tails' 44 slots are padded to whole tiles of eight)
+    assert mem.alias_size_in_bytes == 4 * L * (
+        b * nv * dk * dv + (taps - 1) * 48 * ch)
+    assert mem.temp_size_in_bytes < 2 ** 20
+    _no_copy_of(text, _shape((L, b, nv, dk, dv)))
+    _no_copy_of(text, _shape((L, b, taps - 1, ch)))
+    _no_copy_of(text, _shape((L, taps - 1, b, ch)))
 
 
 @pytest.mark.parametrize("tokens", [16384, 44], ids=["prompt", "step"])
@@ -534,6 +568,72 @@ def test_a_whole_depth_hybrid_step_moves_no_stacked_state(topo, monkeypatch):
     states = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(rec))
     assert states <= mem.alias_size_in_bytes < states + 2 ** 20
     assert mem.temp_size_in_bytes < 2 ** 20
+
+
+def test_a_delta_rule_decode_step_advances_its_states_where_they_lie(
+        topo, monkeypatch):
+    """The engine's decode executable for Qwen3-Next at the published
+    widths and the long-document cell's 44 slots, two periods with 64 held
+    experts: the scan is a ``while`` and a layer's place in the stacked
+    states a traced scalar (the cell's stage of one period unrolls; it
+    read the same under these assertions in a scratch compile and in the
+    chip's trace, PERF.md, PR 51).  Each DeltaNet layer's kernel
+    (``kernels/gdn_step.py``) takes the stacked states and tails from the
+    scan's carry and advances its layer where it lies: between the
+    layer's two projections the kernel is all that runs, and nothing else
+    makes, copies or re-lays an array of all the layers' states or tails
+    (the parent's step re-stacked the states after the scan, 554 MB a
+    step, and its update made four passes; PR 49's tails were re-laid
+    whole between the layers of a ``while``)."""
+    periods = 2
+    from megatron_llm_tpu.config import qwen3_next_config
+    from megatron_llm_tpu.serving import engine as engine_lib
+
+    monkeypatch.setattr(attn_ops, "_backend", lambda: "tpu")
+    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
+    one = SingleDeviceSharding(topo.devices[0])
+    S, blocks, bk = 44, 130, 128
+    cfg = qwen3_next_config(
+        "80b-a3b-ep2-rank0", num_layers=4 * periods, attention_impl="flash",
+        num_experts=64)
+    place = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
+    params = jax.eval_shape(
+        lambda key: model_lib.init_params(key, cfg), jax.random.key(0))
+    pool = jax.eval_shape(
+        lambda: model_lib.init_kv_pool(cfg, S * blocks + 1, bk))
+    rec = jax.eval_shape(lambda: model_lib.init_rec_state(cfg, S))
+    L = 3 * periods
+    assert rec["S"].shape == (L, S, 32, 128, 128)
+    assert rec["conv"].shape == (L, S, 3, 8192)
+    i32, f32 = jnp.int32, jnp.float32
+    vec = lambda dtype: place(_sds((S,), dtype))  # noqa: E731
+    compiled = engine_lib._decode_donated.lower(
+        cfg, place(params), *place(pool), place(_sds((S, blocks), i32)),
+        vec(i32), vec(i32), vec(jnp.uint32), vec(i32), vec(bool), vec(f32),
+        vec(i32), vec(f32), rec=place(rec), live=vec(bool)).compile()
+    mem = compiled.memory_analysis()
+    donated = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree.leaves((pool, rec)))
+    # (the tails' 44 slots are padded to whole tiles of eight)
+    assert donated <= mem.alias_size_in_bytes < donated + 2 ** 22
+    assert mem.temp_size_in_bytes < 0.5e9
+    text = compiled.as_text()
+    # the kernel once a DeltaNet layer of a period's body, and beside it
+    # between the projections relabellings and constants alone
+    assert len(ops_under_scopes(text, ["gdn_step"], {"custom-call"})) == 3
+    beside = {op for op, _, path in ops_under_scopes(text, ["gdn"], None)
+              if "gdn_proj" not in path.split("/")}
+    assert beside <= {"custom-call", "get-tuple-element", "bitcast",
+                      "constant", "convert", "tuple", "parameter"}, beside
+    assert not [k for k in relayout_bytes(text) if k.startswith(f"f32[{L},")]
+    assert "remat_compressed" not in text
+    # whatever makes an array of all the layers' states is a kernel, or
+    # hands a kernel's result on
+    made = {m.group(2) for m in re.finditer(
+        rf"%(\S+) = f32\[{L},{S},32,128,128\]\S* ([\w-]+)\(", text)}
+    assert made <= {"parameter", "custom-call", "get-tuple-element",
+                    "bitcast"}, made
 
 
 def test_a_dropless_prefill_routes_through_the_grouped_kernel(topo,

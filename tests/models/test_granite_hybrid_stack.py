@@ -196,12 +196,18 @@ OTHERS = {
 # kernel takes a state-space layer's whole step between its two
 # projections (the convolution and its tail, the step size, the skip, the
 # gate, the norm) where the parent's program held a dozen small
-# operations around the kernel; the other five are the parent's.
+# operations around the kernel; both ("qwen3_next", ...) are PR 51's: the
+# delta rule's states ride in the period scan's carry as the state-space
+# states do (a prompt's layer reads and rewrites its slice in place where
+# the states went through the scan as xs and ys) and a decode step's
+# DeltaNet layer is one kernel on the stacked states between its two
+# projections (kernels/gdn_step.py) where the parent's program held the
+# plain composition; the other three are PR 47's.
 LOWERED = {
     ("falcon", "decode"): "ba47a517f99fe833",
     ("falcon", "prefill"): "8cebe19aaf9ad16b",
-    ("qwen3_next", "decode"): "f18bebe8e86d2c26",
-    ("qwen3_next", "prefill"): "dc57635a1ae1b16e",
+    ("qwen3_next", "decode"): "62f1329e5e46e5af",
+    ("qwen3_next", "prefill"): "d2d18acfa961ecdf",
     ("nemotron_h", "decode"): "56941dabf9257fff",
     ("nemotron_h", "prefill"): "23ccf916d9425b0d",
 }

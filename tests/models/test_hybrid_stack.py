@@ -87,18 +87,129 @@ def test_a_prompt_through_the_mixer_is_its_positions_one_by_one(s):
     np.testing.assert_allclose(got_state.conv, want_state.conv, atol=1e-6)
 
 
-def test_the_mixer_runs_the_kernel_for_a_prompt_and_the_step_for_one():
-    """``gdn_block`` has one path a shape: every ``s > 1`` lowers to the
-    kernel, ``s == 1`` to the one-position rule."""
+def test_the_mixer_runs_a_kernel_for_a_prompt_and_another_for_one():
+    """``gdn_block`` has one path a shape, one kernel between its two
+    projections: every ``s > 1`` lowers to ``gdn_scan``, ``s == 1`` to
+    ``gdn_step``."""
     cfg = tiny()
     p = gdn.init_gdn_params(jax.random.key(0), cfg)
-    for s, kernel in ((2, True), (70, True), (1, False)):
+    for s, kernel in ((2, "gdn_scan"), (70, "gdn_scan"), (1, "gdn_step")):
         x = jax.ShapeDtypeStruct((1, s, cfg.hidden_size), jnp.float32)
         text = str(jax.make_jaxpr(
             lambda p, x: gdn.gdn_block(cfg, p, x))(p, x))
-        assert ("pallas_call" in text) == kernel, s
-        # the only loop left is the kernel's own, over a step's chunks
-        assert text.count("scan[") + text.count("while[") == kernel, s
+        assert text.count("pallas_call") == 1, s
+        assert f"name={kernel}" in text, s
+        # the only loop left is the prompt kernel's own, over a step's
+        # chunks
+        assert text.count("scan[") + text.count("while[") == (s > 1), s
+
+
+def _parents_step(cfg, p, qkvz, ba, state, valid):
+    """A decode step between the two projections as the parent ran it:
+    the layer's state out of the stack, the plain ``jax.numpy``
+    composition, and the result written into the stack."""
+    S, conv, at = state
+    o, new = gdn.one_position(cfg, p, qkvz, ba,
+                              gdn.GDNState(S[at], conv[at]), valid)
+    return o, gdn.GDNState(S.at[at].set(new.S), conv.at[at].set(new.conv),
+                           at)
+
+
+def test_a_step_through_the_carry_is_the_parents_form(monkeypatch):
+    """Two periods, so that a layer's place in the stacked states is a
+    traced scalar: three decode steps after a prompt, slot 1 dead in the
+    second of them, by the kernel on the carried stack and by the plain
+    composition on a layer's slice: the logits, and every state of every
+    layer after each step."""
+    cfg = tiny(num_layers=8)
+    params = jax.jit(lambda k: model_lib.init_params(k, cfg))(
+        jax.random.key(1))
+    toks = jax.random.randint(jax.random.key(5), (3, 23), 1, 500)
+
+    def run():
+        k, v = model_lib.init_kv_cache(cfg, 3, 32)
+        _, k, v, rec = jax.jit(
+            lambda t, k, v: model_lib.forward_cached_hybrid(
+                cfg, params, t, k, v, jnp.int32(0),
+                model_lib.init_rec_state(cfg, 3), empty_cache=True))(
+                    toks[:, :20], k, v)
+        step = jax.jit(lambda t, k, v, n, rec, live:
+                       model_lib.forward_cached_hybrid(
+                           cfg, params, t, k, v, n, rec, valid=live))
+        out = []
+        for i in range(20, 23):
+            live = jnp.asarray([[True], [i != 21], [True]])
+            logits, k, v, rec = step(toks[:, i:i + 1], k, v,
+                                     jnp.full((3,), i, jnp.int32), rec, live)
+            out.append((logits, rec))
+        return out
+
+    got = run()
+    monkeypatch.setattr(gdn, "_one_position", _parents_step)
+    want = run()
+    assert got[0][1]["S"].shape == (6, 3, 4, 16, 16)
+    for i, ((logits, rec), (want_logits, want_rec)) in enumerate(
+            zip(got, want)):
+        rows = [0, 2] if i == 1 else [0, 1, 2]
+        np.testing.assert_allclose(logits[rows, 0], want_logits[rows, 0],
+                                   atol=2e-5)
+        for key in ("S", "conv"):
+            np.testing.assert_allclose(rec[key], want_rec[key], atol=2e-5)
+        np.testing.assert_array_equal(rec["load"], want_rec["load"])
+    for key in ("S", "conv"):    # the dead slot's rows, every layer's
+        np.testing.assert_array_equal(got[1][1][key][:, 1],
+                                      got[0][1][key][:, 1])
+        assert float(jnp.abs(got[2][1][key][:, 1]
+                             - got[1][1][key][:, 1]).max()) > 0
+
+
+def _makers(jaxpr, shapes, path=()):
+    """``(primitive, the primitives around it)`` of every equation of
+    ``jaxpr`` and of the jaxprs inside it that makes an array of one of
+    ``shapes``."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if any(getattr(v.aval, "shape", None) in shapes
+               for v in eqn.outvars):
+            out.append((eqn.primitive.name, path))
+        if eqn.primitive.name == "pallas_call":
+            continue        # a kernel's body works on blocks
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out += _makers(sub, shapes, path + (eqn.primitive.name,))
+    return out
+
+
+def test_a_decode_step_makes_the_stacked_states_in_its_kernel_alone():
+    """The decode program of the toy at two periods: an array of the
+    stacked states' or tails' shape comes out of the one-position kernel
+    (and out of what only hands it on: the jitted call around the kernel,
+    the scan that carries it), and of nothing else: no slice is stacked
+    again, no layer written back beside the kernel.  What the chip's
+    compiler makes of the same program at the published widths is
+    audited in tests/kernels/test_tpu_compile.py (``relayout_bytes``)."""
+    cfg = tiny(num_layers=8)
+    slots = 2
+    params = jax.eval_shape(lambda k: model_lib.init_params(k, cfg),
+                            jax.random.key(0))
+    rec = jax.eval_shape(lambda: model_lib.init_rec_state(cfg, slots))
+    k, v = jax.eval_shape(lambda: model_lib.init_kv_cache(cfg, slots, 32))
+    shapes = {rec["S"].shape, rec["conv"].shape}
+    assert shapes == {(6, slots, 4, 16, 16), (6, slots, 3, 128)}
+    jaxpr = jax.make_jaxpr(
+        lambda p, t, k, v, n, rec, live: model_lib.forward_cached_hybrid(
+            cfg, p, t, k, v, n, rec, valid=live))(
+                params, jax.ShapeDtypeStruct((slots, 1), jnp.int32), k, v,
+                jax.ShapeDtypeStruct((slots,), jnp.int32), rec,
+                jax.ShapeDtypeStruct((slots, 1), bool))
+    made = _makers(jaxpr.jaxpr, shapes)
+    # a period's three linear layers; around each kernel its jitted call,
+    # which hands the tails over with the rows outermost (a relabelling on
+    # the chip: tests/kernels/test_tpu_compile.py::test_gdn_step)
+    assert [m for m in made if m[1] == ()] == [("scan", ())]
+    inside = [m for m in made if m[1] != ()]
+    assert sorted(inside) == sorted(
+        3 * [("jit", ("scan",)), ("pallas_call", ("scan", "jit")),
+             ("transpose", ("scan", "jit"))]), made
 
 
 def test_bf16_weights_read_a_float32_activation_in_two_passes():

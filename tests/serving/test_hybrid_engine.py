@@ -124,8 +124,10 @@ def test_the_experts_rows_are_counted_by_outcome_and_the_spans_say_how(model):
 def test_a_prompts_linear_layers_say_they_ran_as_the_kernel(model):
     """A ``prefill`` span of a stack with Gated DeltaNet layers carries
     ``gdn: "fused"`` (between their projections one kernel,
-    ``kernels/gdn_scan.py``); its decode spans, whose one position runs
-    as ``jax.numpy``, do not, nor does any span of a Falcon stack."""
+    ``kernels/gdn_scan.py``) and its decode spans ``gdn_step: "mixer"``
+    (the one position's kernel, ``kernels/gdn_step.py``, on the stacked
+    states where they lie); neither the other's field, nor any span of a
+    Falcon stack either."""
     from megatron_llm_tpu.config import falcon_config
 
     cfg, params = model
@@ -136,6 +138,11 @@ def test_a_prompts_linear_layers_say_they_ran_as_the_kernel(model):
     assert all(e["args"]["gdn"] == "fused" for e in prefills)
     assert not any("gdn" in e.get("args", {}) for e in spans
                    if e["name"] != "prefill")
+    decodes = [e for e in spans if e["name"] == "decode"]
+    assert decodes and all(e["args"]["gdn_step"] == "mixer"
+                           and "ssm_step" not in e["args"] for e in decodes)
+    assert not any("gdn_step" in e.get("args", {}) for e in spans
+                   if e["name"] != "decode")
     falcon = falcon_config(
         "7b", num_layers=1, hidden_size=64, num_attention_heads=4,
         ffn_hidden_size=128, vocab_size=64, params_dtype="float32",
@@ -145,7 +152,8 @@ def test_a_prompts_linear_layers_say_they_ran_as_the_kernel(model):
                    kv_block_size=8, prefill_bucket=16)
     spans = eng.trace.chrome_trace()["traceEvents"]
     assert [e for e in spans if e["name"] == "prefill"]
-    assert not any("gdn" in e.get("args", {}) for e in spans)
+    assert not any(key in e.get("args", {}) for e in spans
+                   for key in ("gdn", "gdn_step"))
 
 
 def test_a_dense_engine_counts_no_experts_and_keeps_no_state():
