@@ -284,6 +284,29 @@ class ModelConfig:
     residual_multiplier: float = 1.0
     attention_multiplier: Optional[float] = None
     logits_scaling: float = 1.0
+    # Latent attention (MLA, models/mla.py) as a "full" block's attention
+    # part, where kv_lora_rank > 0: the keys and values of all heads are
+    # expanded from ONE latent of that width a position, beside one rotated
+    # key part of qk_rope_head_dim shared by all heads; a head's query and
+    # key are qk_nope_head_dim + qk_rope_head_dim wide, its value
+    # v_head_dim.  What is cached a position a layer is the latent and
+    # the rotated part, ``latent_row_width`` values and no head axis.
+    # (0: the attention of attention_block.)  q_lora_rank, a
+    # down-projection of the query, is not carried: anything but None is
+    # refused.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    q_lora_rank: Optional[int] = None
+    # That many leading layers hold a dense MLP of moe_dense_ffn_size in
+    # place of the experts: they run before the layer scan, with
+    # parameters of their own beside the stack (``lead_layers``), and
+    # count among num_layers.  moe_n_group > 1 (a router that first picks
+    # groups of experts) is not carried and refused.
+    moe_first_dense_layers: int = 0
+    moe_dense_ffn_size: int = 0
+    moe_n_group: int = 1
 
     def __post_init__(self):
         # a JSON list (checkpointed arguments, a benchmark's overrides):
@@ -303,10 +326,34 @@ class ModelConfig:
         return self.moe_router_experts or self.num_experts
 
     @property
+    def latent_row_width(self) -> int:
+        """What a latent-attention layer caches a position: the latent
+        and the rotated key part (0: keys and values a KV head)."""
+        return self.kv_lora_rank and self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def scanned_layers(self) -> int:
+        """The layers of the scan: all but the leading dense ones."""
+        return self.num_layers - self.moe_first_dense_layers
+
+    @property
+    def lead_layer_config(self) -> "ModelConfig":
+        """The leading dense layers' configuration: this stack's layer
+        without experts, its MLP ``moe_dense_ffn_size`` wide."""
+        return dataclasses.replace(
+            self, num_experts=0, moe_dropless=False, moe_router_experts=0,
+            moe_expert_offset=0, moe_shared_expert_size=0,
+            moe_router_scoring="softmax", moe_routed_scaling=1.0,
+            moe_latent_size=0, moe_first_dense_layers=0,
+            ffn_hidden_size=self.moe_dense_ffn_size)
+
+    @property
     def layer_kinds(self) -> tuple:
-        """The block kind of every layer, in order."""
+        """The block kind of every layer, in order (the leading dense
+        layers are of the period's first kind)."""
         period = self.layer_pattern or ("full",)
-        return period * (self.num_layers // len(period))
+        return (period[:1] * self.moe_first_dense_layers
+                + period * (self.scanned_layers // len(period)))
 
     @property
     def kv_layers(self) -> int:
@@ -330,7 +377,8 @@ class ModelConfig:
         if self.num_experts == 0:
             return ()
         return tuple(i for i, kind in enumerate(self.layer_kinds)
-                     if kind in FFN_KINDS)
+                     if kind in FFN_KINDS
+                     and i >= self.moe_first_dense_layers)
 
     @property
     def mamba_inner(self) -> int:
@@ -373,12 +421,35 @@ class ModelConfig:
         if self.layer_pattern:
             assert set(self.layer_pattern) <= set(BLOCK_KINDS), (
                 f"unknown block kind in {self.layer_pattern!r}")
-            assert self.num_layers % len(self.layer_pattern) == 0, (
+            assert self.scanned_layers % len(self.layer_pattern) == 0, (
                 f"num_layers {self.num_layers} is not whole periods of "
                 f"{len(self.layer_pattern)} layers")
             assert (self.linear_num_value_heads
                     % self.linear_num_key_heads == 0)
             assert self.mamba_num_heads % self.mamba_n_groups == 0
+        if self.kv_lora_rank:
+            self._validate_latent_attention()
+        else:
+            assert not (self.qk_nope_head_dim or self.qk_rope_head_dim
+                        or self.v_head_dim or self.q_lora_rank), (
+                "qk_nope_head_dim, qk_rope_head_dim, v_head_dim and "
+                "q_lora_rank are latent attention's (kv_lora_rank > 0)")
+        if self.moe_first_dense_layers:
+            assert self.layer_pattern and self.num_experts > 0, (
+                "moe_first_dense_layers: leading dense layers stand "
+                "before a period-scanned stack of expert layers")
+            assert self.layer_pattern[0] == "full", (
+                "a leading dense layer is a two-part block (\"full\")")
+            assert 0 < self.moe_first_dense_layers < self.num_layers
+            assert self.moe_dense_ffn_size > 0, (
+                "moe_first_dense_layers needs moe_dense_ffn_size, the "
+                "dense MLP's width")
+        if self.moe_n_group != 1:
+            raise ValueError(
+                f"moe_n_group {self.moe_n_group}: group-limited routing "
+                "(the router first picks topk_group of n_group groups of "
+                "experts) is not carried; models/moe.py routes over all "
+                "experts at once")
         assert self.moe_router_scoring in ("softmax", "sigmoid"), (
             f"unknown moe_router_scoring {self.moe_router_scoring!r}")
         if not self.moe_dropless:
@@ -410,6 +481,46 @@ class ModelConfig:
         assert self.quantize_matmuls in ("none", "int8"), (
             f"unknown quantize_matmuls {self.quantize_matmuls!r}")
         return self
+
+    def _validate_latent_attention(self) -> None:
+        """Latent attention as models/mla.py carries it; what it does
+        not carry is refused by name (ValueError: survives -O)."""
+        if self.q_lora_rank is not None:
+            raise ValueError(
+                f"q_lora_rank {self.q_lora_rank}: a down-projected query "
+                "(q_a_proj, its norm, q_b_proj) is not carried; "
+                "models/mla.py projects the query directly (q_lora_rank "
+                "null)")
+        if (self.rope_scaling_type == "yarn"
+                or self.rope_scaling_factor != 1.0
+                or self.rope_attention_factor is not None):
+            raise ValueError(
+                "rope scaling with latent attention (YaRN's mscale on the "
+                "softmax scale and its interpolated frequencies) is not "
+                "carried; models/mla.py rotates at the plain frequencies "
+                "(rope_scaling null)")
+        if self.kv_cache_quant != "none":
+            raise ValueError(
+                "kv_cache_quant=int8: a latent row is kept in the "
+                "weights' precision, there is no 8-bit latent pool")
+        assert (self.qk_nope_head_dim > 0 and self.qk_rope_head_dim > 0
+                and self.v_head_dim > 0), (
+            "latent attention needs qk_nope_head_dim, qk_rope_head_dim "
+            "and v_head_dim")
+        assert self.qk_rope_head_dim % 2 == 0
+        assert self.layer_pattern == ("full",), (
+            "latent attention is the attention part of a period of one "
+            "\"full\" block (the period scan carries the float32 "
+            "stream and the expert counters)")
+        assert (self.position_embedding_type == PositionEmbeddingType.ROTARY
+                and not self.qk_norm and not self.attn_output_gate
+                and not self.use_bias and not self.qkv_bias
+                and not self.parallel_attn
+                and self.attention_dropout == 0.0
+                and self.context_parallel_axis is None), (
+            "latent attention: rotary positions on its rotated part, no "
+            "q/k norm, output gate, bias, parallel block, attention "
+            "dropout or context parallelism")
 
 
 # ---------------------------------------------------------------------------
@@ -1047,6 +1158,68 @@ def granite_hybrid_config(size: str = "4.0-h-micro",
     return ModelConfig(**base).validate()
 
 
+def deepseek_v3_config(size: str = "kanana-2-30b-a3b",
+                       **overrides) -> ModelConfig:
+    """``model_type: deepseek_v3`` without a query down-projection: every
+    layer is latent attention (MLA, models/mla.py: 32 heads whose keys
+    and values are expanded from one 512-wide latent a position, queries
+    and keys 128 + 64 wide of which the 64 are rotated, values 128) and a
+    feed-forward part, each under an RMSNorm of its own.  The first
+    ``first_k_dense_replace`` layers hold a dense gated SiLU MLP; every
+    other layer 128 sigmoid-scored experts (top 6 by score + bias over
+    all of them at once, weights renormalised and scaled by 2.448) of
+    three matrices, width 768, beside an un-gated shared expert of two
+    experts' width; untied head.  Served only.
+
+    ``kanana-2-30b-a3b`` is kakaocorp/kanana-2-30b-a3b-instruct-2601 as
+    published (48 layers).  ``kanana-2-30b-a3b-pp8-stage0`` is the first
+    of eight pipeline stages of six layers: the dense layer and five
+    expert layers, every expert and the whole vocabulary.  ``head_dim``
+    stays hidden / heads = 64 (what the published config calls
+    ``head_dim``: its rotary width); the attention's own widths are the
+    four latent-attention fields."""
+    base = dict(
+        norm_type="rmsnorm",
+        norm_eps=1e-6,
+        activation="swiglu",
+        position_embedding_type=PositionEmbeddingType.ROTARY,
+        rope_theta=1.0e6,
+        use_bias=False,
+        tie_embed_logits=False,
+        hidden_size=2048,
+        num_layers=48,
+        num_attention_heads=32,
+        kv_lora_rank=512,
+        qk_nope_head_dim=128,
+        qk_rope_head_dim=64,
+        v_head_dim=128,
+        layer_pattern=("full",),
+        moe_first_dense_layers=1,
+        moe_dense_ffn_size=6144,
+        ffn_hidden_size=768,
+        num_experts=128,
+        moe_top_k=6,
+        moe_dropless=True,
+        moe_router_scoring="sigmoid",
+        moe_routed_scaling=2.448,
+        moe_shared_expert_size=1536,
+        moe_shared_expert_gated=False,
+        # a whole 16k-position prompt is routed at once (6 pairs a token)
+        moe_group_size=16384,
+        vocab_size=128256,
+        max_position_embeddings=32768,
+        seq_length=4096,
+        recompute="none",
+    )
+    sizes = {
+        "kanana-2-30b-a3b": dict(),
+        "kanana-2-30b-a3b-pp8-stage0": dict(num_layers=6),
+    }
+    base.update(sizes[size])
+    base.update(overrides)
+    return ModelConfig(**base).validate()
+
+
 def gpt_config(size: str = "345m", **overrides) -> ModelConfig:
     """GPT-2/3 style: learned absolute positions, LayerNorm, gelu, tied
     embeddings, biases (reference: megatron/model/gpt_model.py)."""
@@ -1107,6 +1280,7 @@ PRESETS = {
     "gpt-345m": lambda: gpt_config("345m"),
     "qwen3-next-80b-a3b": lambda: qwen3_next_config("80b-a3b"),
     "granite-4.0-h-micro": lambda: granite_hybrid_config("4.0-h-micro"),
+    "kanana-2-30b-a3b": lambda: deepseek_v3_config("kanana-2-30b-a3b"),
     "tiny": tiny_config,
 }
 

@@ -140,9 +140,12 @@ def _fwd_kernel(cfg: _Config, nk: int, *refs):
 
 
 def _fwd(cfg: _Config, q, k, v, q_seg, k_seg):
-    """q [b, hq, sq_p, d]; k/v [b, hk, sk_p, d]; segs [b, s_p] or None."""
+    """q [b, hq, sq_p, d]; k [b, hk, sk_p, d]; v [b, hk, sk_p, dv] (dv
+    may differ from d: the output is as wide as v); segs [b, s_p] or
+    None."""
     b, hq, sq_p, d = q.shape
     _, hk, sk_p, _ = k.shape
+    dv = v.shape[-1]
     nq = sq_p // cfg.block_q
     nk = sk_p // cfg.block_k
     grid = (b, hq, nq, nk)
@@ -156,7 +159,7 @@ def _fwd(cfg: _Config, q, k, v, q_seg, k_seg):
     in_specs = [
         pl.BlockSpec((1, 1, cfg.block_q, d), qmap),
         pl.BlockSpec((1, 1, cfg.block_k, d), kvmap),
-        pl.BlockSpec((1, 1, cfg.block_k, d), kvmap),
+        pl.BlockSpec((1, 1, cfg.block_k, dv), kvmap),
     ]
     operands = [q, k, v]
     if cfg.use_segs:
@@ -173,11 +176,11 @@ def _fwd(cfg: _Config, q, k, v, q_seg, k_seg):
     # lse is [b, h, sq, 1]: the trailing singleton keeps the block's last
     # two dims (block_q, 1) legal for Mosaic.
     out_shape = [
-        jax.ShapeDtypeStruct((b, hq, sq_p, d), q.dtype),
+        jax.ShapeDtypeStruct((b, hq, sq_p, dv), q.dtype),
         jax.ShapeDtypeStruct((b, hq, sq_p, 1), jnp.float32),
     ]
     out_specs = [
-        pl.BlockSpec((1, 1, cfg.block_q, d), qmap),
+        pl.BlockSpec((1, 1, cfg.block_q, dv), qmap),
         pl.BlockSpec((1, 1, cfg.block_q, 1),
                      lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
     ]
@@ -191,7 +194,7 @@ def _fwd(cfg: _Config, q, k, v, q_seg, k_seg):
         scratch_shapes=[
             pltpu.VMEM((cfg.block_q, 128), jnp.float32),
             pltpu.VMEM((cfg.block_q, 128), jnp.float32),
-            pltpu.VMEM((cfg.block_q, d), jnp.float32),
+            pltpu.VMEM((cfg.block_q, dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
@@ -483,7 +486,7 @@ def prefill_block_sizes(cfg, vmem_budget_bytes: int = 8 * 1024 * 1024):
 def flash_attention(
     q: jax.Array,  # [b, sq, n_heads, d]
     k: jax.Array,  # [b, sk, kv_heads, d]
-    v: jax.Array,  # [b, sk, kv_heads, d]
+    v: jax.Array,  # [b, sk, kv_heads, dv]: dv != d runs forward only
     *,
     causal: bool = True,
     segment_ids: Optional[jax.Array] = None,  # [b, s] (sq == sk required)
@@ -523,6 +526,13 @@ def flash_attention(
     else:
         q_seg = k_seg = jnp.zeros((1, 1, 1), jnp.int32)  # ignored
 
-    o = _flash(cfg, qt, kt, vt, q_seg, k_seg)
+    if v.shape[-1] != d:
+        # a value narrower than the query and key (latent attention's
+        # expanded form): the forward kernel alone, which is as wide in
+        # its second product and its output as v; the backward kernels
+        # take one width
+        o, _lse = _fwd(cfg, qt, kt, vt, q_seg, k_seg)
+    else:
+        o = _flash(cfg, qt, kt, vt, q_seg, k_seg)
     o = o[:, :, :sq]
     return jnp.transpose(o, (0, 2, 1, 3))

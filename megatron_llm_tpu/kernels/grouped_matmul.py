@@ -242,7 +242,7 @@ def _kernel(tm, k, act, order_ref, offs_ref, visit_ref, nvis_ref, x_hbm,
 def grouped_mlp(x: jax.Array, order: jax.Array, sizes: jax.Array,
                 w_gate: Optional[jax.Array], w_up: jax.Array,
                 w_down: jax.Array, act: Callable, *, choices: int,
-                interpret: Optional[bool] = None) -> jax.Array:
+                interpret: Optional[bool] = None, layer=None) -> jax.Array:
     """``x`` [tokens, h]; ``order`` [pairs] int32, the (token, choice)
     pairs sorted by group, a pair as ``token << bits | choice`` with
     ``bits = (choices - 1).bit_length()``, the first ``sizes.sum()`` of
@@ -259,12 +259,19 @@ def grouped_mlp(x: jax.Array, order: jax.Array, sizes: jax.Array,
 
     On a TPU a row is copied as whole (8, 128) tiles of 32-bit words: ``h``
     a multiple of 1024 (interpreted, any ``h``; one piece where ``h`` is
-    no multiple of 128)."""
+    no multiple of 128).
+
+    With ``layer`` (an int32 scalar, traced in a layer scan) the matrices
+    are those of a whole stack, ``[layers, E, ...]``, of which the kernel
+    addresses layer ``layer`` through its index maps: a scan body that
+    slices its layer's matrices out first makes XLA copy them, every
+    expert of the layer, for the custom call (1.2 GB a layer at 128
+    experts of 2048 x 768)."""
     if interpret is None:
         interpret = kernels.default_interpret()
     n = order.shape[0]
     g = x.shape[0]
-    E, h, f = w_up.shape
+    E, h, f = w_up.shape[-3:]
     tm = tile_rows(n, E)
     lane = 128 if h % 128 == 0 else h
     pieces = h // lane
@@ -281,15 +288,26 @@ def grouped_mlp(x: jax.Array, order: jax.Array, sizes: jax.Array,
 
     weight = lambda a, b: pl.BlockSpec(    # noqa: E731
         (None, a, b), lambda i, order, offs, visit, nvis: (visit[i], 0, 0))
+    body = functools.partial(_kernel, tm, choices, act)
+    prefetch = (order.astype(jnp.int32), offs, visit, nvis.reshape(1))
+    if layer is not None:
+        # a fifth prefetched scalar, read by the index maps alone
+        weight = lambda a, b: pl.BlockSpec(    # noqa: E731
+            (None, None, a, b),
+            lambda i, order, offs, visit, nvis, layer: (
+                layer[0], visit[i], 0, 0))
+        body = lambda *refs: _kernel(     # noqa: E731
+            tm, choices, act, *refs[:4], *refs[5:])
+        prefetch += (jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)),)
     anywhere = pl.BlockSpec(memory_space=pl.ANY)
     weights = ([] if w_gate is None else [w_gate]) + [w_up, w_down]
     out = pl.pallas_call(
-        functools.partial(_kernel, tm, choices, act),
+        body,
         # not "..._mlp": the benchmark's scope table files an operation
         # under the last scope name its path holds, as a substring
         name="grouped_experts",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=len(prefetch),
             grid=(E,),
             in_specs=([anywhere] + [weight(h, f)] * (len(weights) - 1)
                       + [weight(f, h)]),
@@ -308,6 +326,5 @@ def grouped_mlp(x: jax.Array, order: jax.Array, sizes: jax.Array,
             vmem_limit_bytes=_VMEM_LIMIT,
         ),
         interpret=interpret,
-    )(order.astype(jnp.int32), offs, visit, nvis.reshape(1),
-      x.reshape(g * pieces, lane), *weights)
+    )(*prefetch, x.reshape(g * pieces, lane), *weights)
     return out.reshape(g, choices, pieces, lane)

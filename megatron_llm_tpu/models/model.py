@@ -24,6 +24,9 @@ from .transformer import (
     Params,
     _dropout,
     PagedKV,
+    _lead_layers,
+    ffn_input,
+    init_lead_params,
     init_stack_params,
     layer_forward,
     norm_init,
@@ -75,6 +78,9 @@ def init_params(key: jax.Array, cfg: ModelConfig, tp: int = 1) -> Params:
             cfg.init_method_std
             * jax.random.normal(k_head, (h, v), jnp.float32)
         ).astype(dtype)
+    if cfg.moe_first_dense_layers:
+        params["lead_layers"] = init_lead_params(
+            jax.random.fold_in(k_stack, 1), cfg)
     if cfg.layer_pattern and cfg.moe_router_scoring == "sigmoid":
         params = level_router_bias(cfg, params, jax.random.fold_in(key, 1))
     return params
@@ -92,8 +98,10 @@ def level_router_bias(cfg: ModelConfig, params: Params, key: jax.Array,
     of ``tokens`` seeded tokens goes through the stack, and each
     feed-forward block's bias is set on the way (``moe.level_bias``)
     before the block runs, so that the blocks after it see a levelled
-    layer's output.  Blocks that hold a mixer too keep the bias they
-    drew."""
+    layer's output: a block of the feed-forward part alone, and a
+    two-part block whose mixer is attention (its experts read the stream
+    after the attention part: ``ffn_input``).  Blocks that hold another
+    mixer keep the bias they drew."""
     from .moe import level_bias
 
     kinds = cfg.layer_pattern
@@ -108,13 +116,17 @@ def level_router_bias(cfg: ModelConfig, params: Params, key: jax.Array,
     def layer_of(j, i):
         return jax.tree.map(lambda a: a[i], stacks[j])
 
-    for layer in range(cfg.num_layers):
+    for p in _lead_layers(params.get("lead_layers")):
+        x = layer_forward(cfg.lead_layer_config, p, x, side)[0]
+    for layer in range(cfg.scanned_layers):
         j, i = layer % len(kinds), layer // len(kinds)
         mlp = stacks[j].get("mlp", {})
-        if kinds[j] == "mlp" and "router_bias" in mlp:
+        if "router_bias" in mlp and (kinds[j] == "mlp"
+                                     or "attn" in stacks[j]):
             p = layer_of(j, i)
-            h1 = norm_apply(cfg.norm_type, x, p["input_norm"], cfg.norm_eps,
-                            impl=cfg.norm_impl)
+            h1 = (norm_apply(cfg.norm_type, x, p["input_norm"],
+                             cfg.norm_eps, impl=cfg.norm_impl)
+                  if kinds[j] == "mlp" else ffn_input(cfg, p, x, side))
             bias = level_bias(cfg, p["mlp"], h1[0])
             stacks[j] = {**stacks[j], "mlp": {
                 **mlp, "router_bias": mlp["router_bias"].at[i].set(bias)}}
@@ -232,7 +244,7 @@ def forward_hidden(
         seq_shard_axes=seq_axes,
     )
     x, moe_aux = stack_forward(cfg, params["layers"], x, side, stack_rng,
-                               lora=lora)
+                               lora=lora, lead=params.get("lead_layers"))
     # (a hybrid stack hands its float32 stream to the final norm)
     x = norm_apply(cfg.norm_type, x, params["final_norm"], cfg.norm_eps,
                    impl=cfg.norm_impl).astype(cfg.dtype)
@@ -345,8 +357,12 @@ def paged_decode_eligible(cfg: ModelConfig, k_pool, s: int = 1,
     from ..ops import attention as attn_ops
 
     block = jax.tree.leaves(k_pool)[0].shape[3]
+    # (a pool of latent rows: one row of that width and no head axis,
+    # walked by kernels/mla_decode.py)
+    kv_heads, width = ((1, cfg.latent_row_width) if cfg.latent_row_width
+                       else (cfg.kv_heads, cfg.head_dim))
     return attn_ops.paged_decode_route(
-        s, cfg.num_attention_heads, cfg.kv_heads, cfg.head_dim, block,
+        s, cfg.num_attention_heads, kv_heads, width, block,
         mesh if mesh is not None else attn_ops._active_mesh())
 
 
@@ -594,7 +610,25 @@ def init_kv_cache(cfg: ModelConfig, batch_size: int, max_len: int,
 
     With ``cfg.kv_cache_quant == "int8"`` each side is the int8
     {"q", "scale"} form of ops/kv_quant.py — half the decode cache
-    traffic; the whole decode path threads it as a pytree."""
+    traffic; the whole decode path threads it as a pytree.
+
+    A latent-attention stack's cache is of another kind, latent rows:
+    ``[L, b, 1, max_len, kv_lora_rank]`` (the latent ``c``) and ``[L, b,
+    1, max_len, qk_rope_head_dim]`` (the shared rotated key part
+    ``k_pe``), together ``cfg.latent_row_width`` values a position."""
+    if cfg.latent_row_width:
+        # latent attention keeps ONE row a position a layer, with no head
+        # axis (models/mla.py), in the two leaves every cache-family
+        # helper maps over: the latent on the key side, the rotated key
+        # part all heads share on the value side.  Nothing is kept twice
+        # and no per-head K or V; the latent's 512 columns are whole
+        # lane tiles and XLA:TPU lays the 64-wide part out with the
+        # positions as lanes, so neither leaf is padded (one leaf of 576
+        # columns is padded to 640 in HBM)
+        shape = (cfg.kv_layers, batch_size, 1, max_len)
+        return (jnp.zeros(shape + (cfg.kv_lora_rank,), dtype or cfg.dtype),
+                jnp.zeros(shape + (cfg.qk_rope_head_dim,),
+                          dtype or cfg.dtype))
     if cfg.kv_cache_quant == "int8":
         from ..ops.kv_quant import init_quantized_cache
 
@@ -700,7 +734,7 @@ def forward_cached_hybrid(cfg: ModelConfig, params: Params, tokens,
     x, (rows_k, rows_v), new, counts = scan_periods_cached(
         cfg, params["layers"], x, side,
         lambda _idx, k_l, v_l: (k_l, v_l, cache_len), rec,
-        kv_xs=(k_cache, v_cache))
+        kv_xs=(k_cache, v_cache), lead=params.get("lead_layers"))
     k_cache = cache_update(k_cache, rows_k, cache_len)
     v_cache = cache_update(v_cache, rows_v, cache_len)
     x = norm_apply(cfg.norm_type, x, params["final_norm"], cfg.norm_eps,
@@ -734,7 +768,8 @@ def forward_paged_hybrid(cfg: ModelConfig, params: Params, tokens, k_pool,
                               deterministic=True, valid=valid)
         x, (rows_k, rows_v), new, counts = scan_periods_cached(
             cfg, params["layers"], x, side,
-            lambda idx: PagedKV(k_pool, v_pool, tables, fills, idx), rec)
+            lambda idx: PagedKV(k_pool, v_pool, tables, fills, idx), rec,
+            lead=params.get("lead_layers"))
         x = norm_apply(cfg.norm_type, x, params["final_norm"],
                        cfg.norm_eps, impl=cfg.norm_impl).astype(cfg.dtype)
         logits, rec = unembed(cfg, params, x), _counted(rec, new, counts)

@@ -220,7 +220,10 @@ def _held_experts(cfg: ModelConfig, interpret: bool, p: Params, x, local,
     rest: it fetches each held pair's row of ``x`` by index, multiplies a
     group's rows by that expert's matrices and writes each result to its
     pair's place.  The pairs of experts that are not here sort last, past
-    the last group: a sort key each and nothing else."""
+    the last group: a sort key each and nothing else.  Where ``p`` holds
+    ``expert_layer`` its three matrices are a whole stack's, of which the
+    kernel takes that layer (``models/transformer.py:
+    scan_periods_cached`` hands a scanned stack's experts over so)."""
     g, k = local.shape
     n, E = g * k, cfg.num_experts
     cbits = (k - 1).bit_length()
@@ -237,7 +240,7 @@ def _held_experts(cfg: ModelConfig, interpret: bool, p: Params, x, local,
         out = grouped_mlp(x, keys & ((1 << bits) - 1),
                           bounds[1:] - bounds[:-1], p.get("w_gate"),
                           p["w_up"], p["w_down"], act, choices=k,
-                          interpret=interpret)
+                          interpret=interpret, layer=p.get("expert_layer"))
     with jax.named_scope("moe_dispatch"):
         # the rows of the pairs that are not here were never written:
         # replaced, not multiplied by their zero gate

@@ -46,7 +46,7 @@ from ..ops.rope import (
     apply_rope_partial,
     precompute_rope_freqs,
 )
-from . import gated_deltanet, mamba2
+from . import gated_deltanet, mamba2, mla
 from .gated_deltanet import GDNState, gdn_block, init_gdn_params
 
 Params = dict
@@ -91,7 +91,9 @@ def init_layer_params(key: jax.Array, cfg: ModelConfig,
 
     keys = jax.random.split(key, 8)
     layer: Params = {"input_norm": norm_init(cfg.norm_type, h, dtype)}
-    if kind in KV_KINDS:
+    if kind in KV_KINDS and cfg.kv_lora_rank:
+        layer["attn"] = mla.init_mla_params(keys[0], cfg, std, out_std)
+    elif kind in KV_KINDS:
         attn: Params = {
             # with an output gate: per head, the query's columns then the
             # gate's
@@ -150,10 +152,11 @@ def init_layer_params(key: jax.Array, cfg: ModelConfig,
 
 def init_stack_params(key: jax.Array, cfg: ModelConfig,
                       num_layers: Optional[int] = None) -> Params:
-    """All layers, stacked on a leading axis (scan/pipeline layout).  A
-    hybrid stack (``cfg.layer_pattern``) is a list with one such tree a
-    position of the period, each stacked over the periods."""
-    n = num_layers if num_layers is not None else cfg.num_layers
+    """All layers of the scan, stacked on a leading axis (scan/pipeline
+    layout).  A hybrid stack (``cfg.layer_pattern``) is a list with one
+    such tree a position of the period, each stacked over the periods;
+    leading dense layers are not among them (``init_lead_params``)."""
+    n = num_layers if num_layers is not None else cfg.scanned_layers
     if cfg.layer_pattern:
         kinds = cfg.layer_pattern
         keys = jax.random.split(key, n)
@@ -162,6 +165,24 @@ def init_stack_params(key: jax.Array, cfg: ModelConfig,
             for j, kind in enumerate(kinds)]
     keys = jax.random.split(key, n)
     return jax.vmap(lambda k: init_layer_params(k, cfg))(keys)
+
+
+def init_lead_params(key: jax.Array, cfg: ModelConfig) -> Params:
+    """The ``cfg.moe_first_dense_layers`` leading layers, stacked on a
+    leading axis beside the scanned stack (``params["lead_layers"]``): the
+    period's first block with a dense MLP of ``cfg.moe_dense_ffn_size`` in
+    place of the experts (``cfg.lead_layer_config``)."""
+    keys = jax.random.split(key, cfg.moe_first_dense_layers)
+    return jax.vmap(lambda k: init_layer_params(
+        k, cfg.lead_layer_config, cfg.layer_pattern[0]))(keys)
+
+
+def _lead_layers(lead):
+    """The leading layers' trees, one a layer, in order."""
+    if lead is None:
+        return []
+    n = jax.tree.leaves(lead)[0].shape[0]
+    return [jax.tree.map(lambda a, i=i: a[i], lead) for i in range(n)]
 
 
 # ---------------------------------------------------------------------------
@@ -561,13 +582,9 @@ def layer_forward(cfg: ModelConfig, p: Params, x: jax.Array,
         # an ssm layer: the same, with the state-space state
         attn_out, new_cache = mamba2.mamba_block(cfg, p["mamba"], h1,
                                                  kv_cache, side.valid)
-    elif kv_cache is not None:
-        attn_out, new_cache = attention_block(cfg, p["attn"], h1, side,
-                                              layer_rng, kv_cache,
-                                              lora=lora)
     else:
-        attn_out = attention_block(cfg, p["attn"], h1, side, layer_rng,
-                                   lora=lora)
+        attn_out, new_cache = _attend(cfg, p["attn"], h1, side, layer_rng,
+                                      kv_cache, lora)
 
     if cfg.parallel_attn:
         if cfg.parallel_layernorm:
@@ -591,6 +608,21 @@ def layer_forward(cfg: ModelConfig, p: Params, x: jax.Array,
     return result, aux
 
 
+def _attend(cfg: ModelConfig, p: Params, h1: jax.Array,
+            side: AttnSideInputs, layer_rng, kv_cache, lora=None):
+    """A block's attention part, softmax attention over K/V a KV head or
+    latent attention (``cfg.kv_lora_rank``), → ``(out, the new rows or
+    None)``."""
+    if cfg.kv_lora_rank:
+        out = mla.mla_block(cfg, p, h1, side, kv_cache)
+    elif kv_cache is not None:
+        out = attention_block(cfg, p, h1, side, layer_rng, kv_cache,
+                              lora=lora)
+    else:
+        out = attention_block(cfg, p, h1, side, layer_rng, lora=lora)
+    return out if kv_cache is not None else (out, None)
+
+
 def _one_part_forward(cfg: ModelConfig, p: Params, x: jax.Array,
                       side: AttnSideInputs, layer_rng, kv_cache):
     """A block of one part under one norm, ``x + f(norm(x))``: softmax
@@ -607,18 +639,25 @@ def _one_part_forward(cfg: ModelConfig, p: Params, x: jax.Array,
                                             side.valid)
     elif "attn" in p:
         # attention computes in the model's own precision
-        h1 = h1.astype(cfg.dtype)
-        if kv_cache is not None:
-            out, new_cache = attention_block(cfg, p["attn"], h1, side,
-                                             layer_rng, kv_cache)
-        else:
-            out = attention_block(cfg, p["attn"], h1, side, layer_rng)
+        out, new_cache = _attend(cfg, p["attn"], h1.astype(cfg.dtype), side,
+                                 layer_rng, kv_cache)
     else:
         out, aux = _mlp_dispatch(cfg, p["mlp"], h1, valid=side.valid)
     result = x + _scaled(cfg, out)
     if kv_cache is not None:
         return result, aux, new_cache
     return result, aux
+
+
+def ffn_input(cfg: ModelConfig, p: Params, x: jax.Array,
+              side: AttnSideInputs) -> jax.Array:
+    """What the feed-forward part of a two-part attention block reads:
+    the stream with the attention part's result added, under the block's
+    second norm (``models/model.py:level_router_bias``)."""
+    x = _one_part_forward(cfg, {k: p[k] for k in ("input_norm", "attn")},
+                          x, side, None, None)[0]
+    return norm_apply(cfg.norm_type, x, p["post_attn_norm"], cfg.norm_eps,
+                      impl=cfg.norm_impl)
 
 
 def _scaled(cfg: ModelConfig, out):
@@ -642,7 +681,7 @@ def _remat_policy(cfg: ModelConfig):
 
 def stack_forward(cfg: ModelConfig, stacked: Params, x: jax.Array,
                   side: AttnSideInputs, base_rng=None, layer_offset=0,
-                  lora=None):
+                  lora=None, lead=None):
     """Run all layers with lax.scan over the stacked parameter pytree.
 
     Returns ``(hidden, moe_aux)`` — the aux load-balance loss summed over
@@ -653,11 +692,14 @@ def stack_forward(cfg: ModelConfig, stacked: Params, x: jax.Array,
     ``lora`` is ``(arenas, mask)`` with layer-stacked arena factors
     (leading L axis, joining the scan xs) — the LoRA finetune path runs
     through here with the factors as the differentiable operand.
+
+    ``lead``: the leading dense layers (``init_lead_params``), which run
+    before the scan.
     """
     if cfg.layer_pattern:
         assert lora is None, "a hybrid stack takes no adapters"
         return _stack_forward_periods(cfg, stacked, x, side, base_rng,
-                                      layer_offset)
+                                      layer_offset, lead)
     arenas, mask = lora if lora is not None else (None, None)
 
     def body(carry, inp):
@@ -707,19 +749,24 @@ STREAM_DTYPE = jnp.float32
 
 
 def _stack_forward_periods(cfg: ModelConfig, stacked, x, side, base_rng,
-                           layer_offset):
+                           layer_offset, lead=None):
     """``stack_forward`` for a hybrid stack: the scan runs over the
     periods, and its body runs one period's layers in order, each of the
     kind its position has.  Every recurrent mixer starts from a zero state
     and drops the one it ends with: a whole sequence, no cache.  (No
     rematerialisation: such a stack is served, not trained.)"""
     n_pos = len(cfg.layer_pattern)
+    n_lead = cfg.moe_first_dense_layers
     x = x.astype(STREAM_DTYPE)
+    for layer_params in _lead_layers(lead):
+        x = layer_forward(cfg.lead_layer_config, layer_params, x, side)[0]
 
     def body(carry, period):
         h, idx, aux_sum = carry
         for j, layer_params in enumerate(period):
             layer = idx * n_pos + j
+            if n_lead:
+                layer = layer + n_lead
             rng = (None if base_rng is None
                    else jax.random.fold_in(base_rng, layer))
             h, aux = layer_forward(cfg, layer_params, h, side, rng,
@@ -776,7 +823,7 @@ def _rec_write_back(mixer: _RecMixer, stacked: dict, new, at) -> dict:
 
 
 def scan_periods_cached(cfg: ModelConfig, stacked, x, side, kv_of, rec,
-                        kv_xs: tuple = ()):
+                        kv_xs: tuple = (), lead=None):
     """The cached forms of a hybrid stack, prefill and decode alike: a
     scan over the periods whose body gives each layer that attends
     (``"full"``, ``"attention"``) its ``kv_cache`` (``kv_of(kv_layer,
@@ -795,15 +842,45 @@ def scan_periods_cached(cfg: ModelConfig, stacked, x, side, kv_of, rec,
     ``{"load": [layers, router outputs], "rows": [layers, 2]}``: the
     experts those positions chose, and the (token, choice) rows each
     layer's experts multiplied and skipped; zero for a layer without
-    experts)``."""
+    experts)``.
+
+    The leading dense layers ``lead`` (``init_lead_params``) run before
+    the scan, each attending with the first of the KV cache's layers and
+    counting no expert; their rows and zero counts come first."""
     kinds = cfg.layer_pattern
-    n_per = cfg.num_layers // len(kinds)
+    n_per = cfg.scanned_layers // len(kinds)
     n_full = sum(kind in KV_KINDS for kind in kinds)
+    n_lead = cfg.moe_first_dense_layers
     x = x.astype(STREAM_DTYPE)
+    lead_rows = []
+    for i, layer_params in enumerate(_lead_layers(lead)):
+        x, _aux, new = layer_forward(
+            cfg.lead_layer_config, layer_params, x, side, None,
+            kv_cache=kv_of(jnp.int32(i), *(a[i] for a in kv_xs)))
+        lead_rows.append(new)
+    if n_lead:
+        kv_xs = tuple(a[n_lead:] for a in kv_xs)
 
     def by_period(a, n):
         return a.reshape((n_per, n) + a.shape[1:])
 
+    # Where the scan has more than one period, the routed experts'
+    # matrices do not ride in its xs: a per-layer slice of them is a copy
+    # of every expert for the kernel's custom call.  The scan closes over
+    # the stack's and the kernel addresses its layer (``expert_layer``).
+    # With one period XLA makes no copy, and the two expert cells'
+    # programs stay what they were (their digests are held to the
+    # parent's): one way for both is a change to those cells, to be
+    # measured on them (PERF.md section 7 w).
+    experts = {}
+    if n_per > 1 and cfg.moe_dropless:
+        stacked = list(stacked)
+        for j, tree in enumerate(stacked):
+            mlp = tree.get("mlp", {})
+            experts[j] = {k: mlp[k] for k in ("w_gate", "w_up", "w_down")
+                          if k in mlp}
+            stacked[j] = {**tree, "mlp": {k: v for k, v in mlp.items()
+                                          if k not in experts[j]}}
     xs = (tuple(stacked), tuple(by_period(a, n_full) for a in kv_xs))
     # a layer's recurrent mixer (None: it keeps no such state), its place
     # among that mixer's layers of a period, and how many those are
@@ -816,10 +893,18 @@ def scan_periods_cached(cfg: ModelConfig, stacked, x, side, kv_of, rec,
         h, idx, states = carry
         period, kv_p = inp
         rows, counts, f = [], [], 0
-        for layer_params, kind, mixer, j in zip(period, kinds, mixers, place):
+        for at_j, (layer_params, kind, mixer, j) in enumerate(
+                zip(period, kinds, mixers, place)):
+            if experts.get(at_j):
+                layer_params = {**layer_params, "mlp": {
+                    **layer_params["mlp"], **experts[at_j],
+                    "expert_layer": idx}}
             attends, cache = kind in KV_KINDS, None
             if attends:
-                cache = kv_of(idx * n_full + f, *(a[f] for a in kv_p))
+                kv_layer = idx * n_full + f
+                if n_lead:
+                    kv_layer = kv_layer + n_lead
+                cache = kv_of(kv_layer, *(a[f] for a in kv_p))
                 f += 1
             elif mixer:
                 at = idx * n_rec[mixer] + j
@@ -843,7 +928,13 @@ def scan_periods_cached(cfg: ModelConfig, stacked, x, side, kv_of, rec,
     (x, _, states), (rows, counts) = jax.lax.scan(
         body, (x, jnp.int32(0), states), xs)
     flat = lambda a: a.reshape((a.shape[0] * a.shape[1],) + a.shape[2:])
-    return x, jax.tree.map(flat, rows), states, jax.tree.map(flat, counts)
+    rows, counts = jax.tree.map(flat, rows), jax.tree.map(flat, counts)
+    if lead_rows:
+        rows = jax.tree.map(lambda *a: jnp.concatenate(
+            [jnp.stack(a[:-1]), a[-1]]), *lead_rows, rows)
+        counts = jax.tree.map(lambda a: jnp.concatenate(
+            [jnp.zeros((n_lead,) + a.shape[1:], a.dtype), a]), counts)
+    return x, rows, states, counts
 
 
 def _scan_layers_cached(cfg: ModelConfig, stacked: Params, x: jax.Array,

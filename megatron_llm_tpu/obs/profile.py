@@ -52,7 +52,11 @@ DEVICE_SCOPES = (
     # the Mamba-2 mixer and its parts (models/mamba2.py), and the
     # projections into and out of the experts' latent
     "mamba", "mamba_proj", "mamba_conv", "mamba_scan", "mamba_step",
-    "moe_latent")
+    "moe_latent",
+    # latent attention (models/mla.py), under "attention": its five
+    # projections and the absorption, and the absorbed form's kernel (the
+    # expanded form runs "flash_fwd")
+    "mla_proj", "mla_decode")
 
 
 @dataclasses.dataclass

@@ -449,6 +449,35 @@ def paged_decode_attention(
     return out
 
 
+def latent_decode_attention(
+    q_lat: jax.Array,    # [b, 1, n_heads, rank]: q_nope through W_uk
+    q_pe: jax.Array,     # [b, 1, n_heads, rope]: the rotated query part
+    c_pool: jax.Array,   # [L, n_blocks, 1, block, rank]: the pool of
+    pe_pool: jax.Array,  # latent rows (its kind: [.., rope] beside it)
+    tables: jax.Array,   # [b, T] int32 block tables
+    fills,               # [b] int32: rows each slot holds IN THE POOL
+    c_new: jax.Array,    # [b, 1, 1, rank]: the step's own row, as the
+    pe_new: jax.Array,   # [b, 1, 1, rope]  pool stores it
+    layer,               # int32 scalar: the layer of the pool attended
+    *,
+    softmax_scale: float,
+) -> jax.Array:
+    """Decode attention of a latent-attention layer (models/mla.py, the
+    absorbed form) over the paged pool of latent rows → ``o_lat`` [b, 1,
+    n_heads, rank] float32: every head attends the slot's one row a
+    position, whose latent is its value (kernels/mla_decode.py).  The
+    kernel route only, as ``paged_decode_attention`` is; the caller asks
+    ``paged_decode_route`` first (a pool of this kind is served on one
+    chip: no mesh)."""
+    from ..kernels.mla_decode import mla_decode
+
+    assert _active_mesh() is None, "the latent pool is served without a mesh"
+    return mla_decode(
+        q_lat[:, 0], q_pe[:, 0], c_pool, pe_pool, tables,
+        jnp.asarray(fills, jnp.int32), c_new[:, 0], pe_new[:, 0],
+        jnp.asarray(layer, jnp.int32), softmax_scale=softmax_scale)[:, None]
+
+
 def dot_product_attention(
     q: jax.Array,  # [b, sq, n_heads, d]
     k: jax.Array,  # [b, sk, kv_heads, d]
@@ -502,7 +531,8 @@ def dot_product_attention(
         probs = probs * keep / (1.0 - dropout_rate)
 
     out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v)
-    return out.reshape(b, sq, n_heads, d)
+    # (a value narrower than the query and key: latent attention's)
+    return out.reshape(b, sq, n_heads, v.shape[-1])
 
 
 def attention(

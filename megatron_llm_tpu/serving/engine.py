@@ -927,9 +927,12 @@ class _Inflight:
     chain through the KV pool, so while group g's tokens stream back the
     later groups keep the other pipeline stages busy (bubble fill)."""
 
-    __slots__ = ("tok", "tok_lp", "slots", "t_dispatch", "sampling")
+    __slots__ = ("tok", "tok_lp", "slots", "t_dispatch", "sampling",
+                 "positions")
 
-    def __init__(self, tok, tok_lp, slots, t_dispatch, sampling):
+    def __init__(self, tok, tok_lp, slots, t_dispatch, sampling,
+                 positions=0):
+        self.positions = positions  # cached positions its slots held
         self.tok = tok            # [S] device array (or per-group list)
         self.tok_lp = tok_lp      # [S] logprobs, same layout as ``tok``
         self.slots = slots
@@ -998,6 +1001,50 @@ def _refuse_for_hybrid(cfg: ModelConfig, config: EngineConfig, mesh,
                 f"not served with {why}")
 
 
+def _refuse_for_latent(cfg: ModelConfig, config: EngineConfig, mesh,
+                       draft_cfg, adapters) -> None:
+    """What the engine cannot do for a latent-attention stack
+    (``cfg.kv_lora_rank``: a pool of one kind of row, ``c | k_pe``, which
+    a prompt's prefill writes from the expanded form and a decode step
+    reads in the absorbed one), refused at construction so that none of
+    it is served wrongly."""
+    refused = [
+        (cfg.kv_cache_quant != "none",
+         "kv_cache_quant=int8: the pool holds latent rows in the "
+         "weights' precision; there is no 8-bit row"),
+        (config.spec_draft_len or draft_cfg is not None,
+         "speculation (spec_draft_len, a draft model): the verify paths "
+         "walk per-head K/V rows of a dense view, which this pool has "
+         "not"),
+        (config.prefill_chunk,
+         "prefill_chunk: a chunk after the first would have to expand "
+         "the cached latent rows of the chunks before it; the prefill "
+         "runs the expanded form on an empty cache only"),
+        (config.prefix_cache_blocks,
+         "prefix_cache_blocks > 0: a prefix hit starts a prefill whose "
+         "first rows lie in the pool, the same expansion of cached "
+         "latent rows that chunked prefill would need"),
+        (config.host_kv_blocks,
+         "host_kv_blocks: the host tier's arenas and swaps are laid out "
+         "for a pool of K and V leaves"),
+        (config.role != "mixed",
+         f"role={config.role!r}: a shipment between replicas carries "
+         "K/V blocks"),
+        (adapters is not None or config.adapter_cache_slots,
+         "adapters: no LoRA epilogue on the latent projections or the "
+         "experts' matmuls"),
+        (mesh is not None and mesh.size > 1,
+         "a tp/pp serving mesh: a latent row has no head axis to split "
+         "over tp, and serving_param_specs has no layout for the latent "
+         "projections, the leading dense layer or the experts"),
+    ]
+    for hit, why in refused:
+        if hit:
+            raise ValueError(
+                f"a latent-attention stack (kv_lora_rank "
+                f"{cfg.kv_lora_rank}) is not served with {why}")
+
+
 class ServingEngine:
     """Continuous-batching engine over a fixed set of KV slots.
 
@@ -1038,7 +1085,9 @@ class ServingEngine:
         # = the unchanged single-chip engine.
         self.mesh = mesh
         self.config = engine_config or EngineConfig()
-        if cfg.layer_pattern:
+        if cfg.kv_lora_rank:
+            _refuse_for_latent(cfg, self.config, mesh, draft_cfg, adapters)
+        elif cfg.layer_pattern:
             _refuse_for_hybrid(cfg, self.config, mesh, draft_cfg, adapters)
         assert self.config.max_seq_len <= cfg.max_position_embeddings, (
             f"max_seq_len {self.config.max_seq_len} exceeds the model's "
@@ -1192,6 +1241,13 @@ class ServingEngine:
                 a.size * a.dtype.itemsize for a in jax.tree.leaves(
                     jax.eval_shape(lambda: model_lib.rec_states(
                         model_lib.init_rec_state(cfg, 1)))))
+        # which form of a latent-attention layer ran (models/mla.py), on
+        # the prefill and decode spans; a decode span then also carries
+        # the cached positions its step attended, over all live slots
+        self._latent = bool(cfg.kv_lora_rank)
+        if self._latent:
+            self._prefill_arg["attn"] = "mla_expanded"
+            self._step_arg["attn"] = "mla_absorbed"
         self._admit_count = 0        # this iteration's admissions
         self._admit_tokens = 0       # and their prompt tokens
         # the decode step decides for itself whether it reads KV
@@ -1280,6 +1336,10 @@ class ServingEngine:
                             self.draft_cfg, n_blocks, bk)
                     self._draft_kv = (dk, dv)
                 self._update_pool_gauges()
+                self.metrics.set_gauges(kv_pool_bytes={
+                    "latent" if self._latent else "kv": sum(
+                        int(a.nbytes) for a in jax.tree.leaves(
+                            (pool.k_pool, pool.v_pool)))})
                 if self.slots.rec is not None:
                     self.metrics.set_gauges(
                         rec_state_bytes=self.slots.rec_state_bytes,
@@ -2899,9 +2959,12 @@ class ServingEngine:
         for st in snapshot.values():
             st.fill += 1   # the fed token's K/V row lands this step
             st.count += 1  # one more token sampled (possibly speculative)
+        # tpulint: allow[host-sync] fills is host numpy (built above)
+        positions = int(fills.sum())
         if G == 1:
-            return _Inflight(toks[0], tok_lps[0], snapshot, t0, sampling)
-        return _Inflight(toks, tok_lps, snapshot, t0, sampling)
+            return _Inflight(toks[0], tok_lps[0], snapshot, t0, sampling,
+                             positions)
+        return _Inflight(toks, tok_lps, snapshot, t0, sampling, positions)
 
     # tpulint: hot-path
     def _process_step_results(self, step: _Inflight) -> float:
@@ -2927,6 +2990,9 @@ class ServingEngine:
         self._last_ready_t = t_ready
         device_s = t_ready - step.t_dispatch
         committed = 0
+        step_arg = self._step_arg
+        if self._latent and self.trace.enabled:
+            step_arg = dict(step_arg, live_positions=step.positions)
         for slot, st in step.slots.items():
             if self._active.get(slot) is not st:
                 # the slot retired (EOS/budget/cancel/deadline) or was
@@ -2946,7 +3012,7 @@ class ServingEngine:
                                args={"slot": slot, "iter": self._iter,
                                      "token_index": len(st.req.generated),
                                      "live": len(step.slots),
-                                     **self._step_arg})
+                                     **step_arg})
             # tpulint: allow[host-sync] tok_lp is host numpy; no device
             # round-trip
             self._commit_token(slot, st.pending, float(tok_lp[slot]))

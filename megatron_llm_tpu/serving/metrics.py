@@ -250,6 +250,10 @@ class ServingMetrics:
         # keeps it ("linear", "mamba"), and the slots it is for
         self.rec_state_bytes: dict = {}
         self.rec_state_slots = 0
+        # bytes of the paged pool by the kind of row it holds: "kv" (keys
+        # and values a KV head) or "latent" (one row a position, latent
+        # attention's)
+        self.kv_pool_bytes: dict = {}
         # positions the state-space (Mamba-2) layers advanced their
         # states over, by phase: a prompt's length at its prefill, the
         # live slots of every decode step
@@ -316,8 +320,11 @@ class ServingMetrics:
                    host_blocks_used: Optional[int] = None,
                    host_blocks_free: Optional[int] = None,
                    rec_state_bytes: Optional[dict] = None,
-                   rec_state_slots: Optional[int] = None) -> None:
+                   rec_state_slots: Optional[int] = None,
+                   kv_pool_bytes: Optional[dict] = None) -> None:
         with self._lock:
+            if kv_pool_bytes is not None:
+                self.kv_pool_bytes = kv_pool_bytes
             if rec_state_bytes is not None:
                 self.rec_state_bytes = rec_state_bytes
             if rec_state_slots is not None:
@@ -466,6 +473,7 @@ class ServingMetrics:
                 "rec_state_bytes": sum(self.rec_state_bytes.values()),
                 "rec_state_bytes_by_kind": dict(self.rec_state_bytes),
                 "rec_state_slots": self.rec_state_slots,
+                "kv_pool_bytes_by_kind": dict(self.kv_pool_bytes),
                 "ssm_positions": dict(self.ssm_positions),
                 # speculative decoding (histogram samples are token
                 # counts per participating slot per verify step)
@@ -621,6 +629,14 @@ class ServingMetrics:
             for kind, n in sorted(self.rec_state_bytes.items()):
                 fam.add(n, labels={"kind": kind})
             fams.append(fam if self.rec_state_bytes else fam.add(0))
+            fam = MetricFamily(
+                "serving_kv_pool_bytes", "gauge",
+                "bytes of the paged pool, by the kind of row it holds "
+                "(kv: keys and values a KV head; latent: one row a "
+                "position)")
+            for kind, n in sorted(self.kv_pool_bytes.items()):
+                fam.add(n, labels={"kind": kind})
+            fams.append(fam if self.kv_pool_bytes else fam.add(0))
             if "mamba" in self.rec_state_bytes:
                 fam = MetricFamily(
                     "serving_ssm_positions_total", "counter",

@@ -158,6 +158,13 @@ def setup_train_state(
     externally supplied params, e.g. from an HF conversion), sharding
     placement, optimizer-state init with ZeRO-1 dp specs, jit compile.
     """
+    if cfg.model.kv_lora_rank:
+        raise ValueError(
+            f"a latent-attention stack (kv_lora_rank "
+            f"{cfg.model.kv_lora_rank}) is served, not trained: the "
+            "training step runs neither its dropless experts nor the "
+            "leading dense layers kept beside the scanned stack, and no "
+            "sharding rule places the latent projections (ROADMAP R4)")
     parallel = cfg.parallel
     if mesh is None:
         mesh = mesh_lib.build_mesh(parallel)

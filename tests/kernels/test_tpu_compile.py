@@ -130,6 +130,90 @@ def test_flash_attention_fwd_bwd(topo, heads, kv, d, segs):
     assert text.count("tpu_custom_call") >= 3  # fwd, dq, dkv
 
 
+def test_flash_attention_forward_at_a_value_width_of_its_own(topo):
+    """Latent attention's expanded form: 32 heads, queries and keys of
+    192 (1.5 lane tiles) beside values of 128; the forward kernel alone."""
+    s = 2048
+    text = _compile(
+        lambda q, k, v: flash_attention(q, k, v, interpret=False),
+        (_sds((1, s, 32, 192)), _sds((1, s, 32, 192)),
+         _sds((1, s, 32, 128))), SingleDeviceSharding(topo.devices[0]))
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
+def test_mla_decode(topo):
+    """The latent walk at the kanana cell's shapes: 44 slots of up to 132
+    blocks of 128 rows, 32 heads on one row of 512 + 64 a position, a
+    layer of a six-layer pool.  Neither leaf of the pool is copied or
+    re-laid: the 64-wide one lies with its 128 positions as lanes, and
+    the kernel takes it so."""
+    from megatron_llm_tpu.kernels.mla_decode import mla_decode
+
+    S, T, blocks = 44, 132, 44 * 132 + 1
+    text = _compile(
+        lambda *a: mla_decode(*a, softmax_scale=0.07, interpret=False),
+        (_sds((S, 32, 512)), _sds((S, 32, 64)),
+         _sds((6, blocks, 1, 128, 512)), _sds((6, blocks, 1, 128, 64)),
+         _sds((S, T), jnp.int32), _sds((S,), jnp.int32),
+         _sds((S, 1, 512)), _sds((S, 1, 64)), _sds((), jnp.int32)),
+        SingleDeviceSharding(topo.devices[0]))
+    _no_copy_of(text, f"bf16[6,{blocks},1,128,512]")
+    _no_copy_of(text, f"bf16[6,{blocks},1,128,64]")
+    _no_copy_of(text, f"bf16[6,{blocks},1,64,128]")
+    assert relayout_bytes(text) == {}
+
+
+def test_a_latent_attention_decode_step_copies_no_pool_and_no_expert(
+        topo, monkeypatch):
+    """The engine's decode executable for the kanana-2 stage whole: the
+    dense layer before the scan, five expert layers in its ``while``, 44
+    slots of 16 896 positions: 13.3 GB of arguments of which the 5.1 GB
+    pool of latent rows is donated and aliased.  The scan closes over the
+    stack's experts and the grouped kernel addresses its layer: sliced
+    out for the custom call, a layer's 128 experts were three copies of
+    0.4 GB, 6 GB a step (PR 52).  And ``wq`` lies where it lies: the
+    query is rotated as the matmul leaves it (cut into heads at once the
+    product re-laid 25 MB a layer)."""
+    from megatron_llm_tpu.config import deepseek_v3_config
+    from megatron_llm_tpu.serving import engine as engine_lib
+
+    monkeypatch.setattr(attn_ops, "_backend", lambda: "tpu")
+    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
+    one = SingleDeviceSharding(topo.devices[0])
+    S, T, bk = 44, 132, 128
+    cfg = deepseek_v3_config("kanana-2-30b-a3b-pp8-stage0",
+                             attention_impl="flash")
+    place = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
+    params = jax.eval_shape(
+        lambda key: model_lib.init_params(key, cfg), jax.random.key(0))
+    pool = jax.eval_shape(lambda: model_lib.init_kv_pool(cfg, S * T + 1, bk))
+    rec = jax.eval_shape(lambda: model_lib.init_rec_state(cfg, S))
+    i32, f32 = jnp.int32, jnp.float32
+    vec = lambda dtype: place(_sds((S,), dtype))  # noqa: E731
+    compiled = engine_lib._decode_donated.lower(
+        cfg, place(params), *place(pool), place(_sds((S, T), i32)),
+        vec(i32), vec(i32), vec(jnp.uint32), vec(i32), vec(bool), vec(f32),
+        vec(i32), vec(f32), rec=place(rec), live=vec(bool)).compile()
+    mem = compiled.memory_analysis()
+    held = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(pool))
+    assert held == 6 * (S * T + 1) * bk * 576 * 2
+    assert held <= mem.alias_size_in_bytes < held + 2 ** 20
+    assert 12.6e9 < mem.argument_size_in_bytes < 12.9e9
+    assert mem.temp_size_in_bytes < 0.2e9
+    text = compiled.as_text()
+    # the walk once in the dense layer and once in the scan's body
+    assert len(ops_under_scopes(text, ["mla_decode"], {"custom-call"})) == 2
+    moved = relayout_bytes(text)
+    assert not [k for k in moved if k.startswith(("bf16[128,", "bf16[5,128,",
+                                                  "bf16[1,2048,6144]"))]
+    # what is left: W_uk and W_uv, views of wkv_b taken in the step
+    assert sum(moved.values()) < 0.12e9, moved
+    for leaf in jax.tree.leaves(pool):
+        shape = ",".join(map(str, leaf.shape))
+        _no_copy_of(text, f"bf16[{shape}]")
+
+
 def _no_copy_of(text, shape):
     """No instruction of the compiled module other than a parameter, a
     tuple access, a relabelling ``bitcast`` or an in-place
