@@ -38,6 +38,7 @@ import jax.numpy as jnp
 from .. import kernels
 from ..config import ModelConfig
 from ..kernels.grouped_matmul import grouped_mlp
+from ..kernels.moe_router import router_top_k
 from ..ops.activations import get_activation, is_glu
 from ..ops.precision import dot_f32, dot_rounded
 
@@ -280,30 +281,41 @@ def moe_dropless_block(cfg: ModelConfig, p: Params, x: jax.Array,
     return _dropless(cfg, kernels.default_interpret(), p, x, valid)
 
 
+def _route(cfg: ModelConfig, interpret: bool, p: Params, xt, counted):
+    """The router over the tokens ``xt`` [g, h] → ``(idx [g, k] int32,
+    weight [g, k] float32, load [router_experts] float32)``: a token's
+    ``moe_top_k`` experts in falling order of score (of score + bias where
+    the scores are sigmoids; ties to the lower index), their scores
+    divided by their sum and multiplied by ``cfg.moe_routed_scaling``, and
+    ``counted`` [g] summed over the tokens that chose an expert.  The
+    product is float32 at ``Precision.HIGHEST``; the choice is k rounds of
+    max-and-mask on the scores where they lie (``kernels/moe_router.py``:
+    no sort, no gather, no scatter)."""
+    logits = jnp.dot(xt.astype(jnp.float32),
+                     p["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    sigmoid = cfg.moe_router_scoring == "sigmoid"
+    idx, weight, load = router_top_k(
+        jax.nn.sigmoid(logits) if sigmoid
+        else jax.nn.softmax(logits, axis=-1),
+        p["router_bias"] if sigmoid else None, counted, cfg.moe_top_k,
+        interpret=interpret)
+    total = jnp.sum(weight, axis=-1, keepdims=True)
+    weight = weight / (total + 1e-20 if sigmoid else total)
+    if cfg.moe_routed_scaling != 1.0:
+        weight = weight * cfg.moe_routed_scaling
+    return idx, weight, load
+
+
 @functools.partial(jax.jit, static_argnums=(0, 1))
 def _dropless(cfg: ModelConfig, interpret: bool, p: Params, x, valid):
 
     b, s, h = x.shape
-    k, E, R = cfg.moe_top_k, cfg.num_experts, cfg.router_experts
+    k, E = cfg.moe_top_k, cfg.num_experts
     xt = x.reshape(b * s, h)
     with jax.named_scope("moe_router"):
-        logits = jnp.dot(xt.astype(jnp.float32),
-                         p["router"].astype(jnp.float32),
-                         precision=jax.lax.Precision.HIGHEST)
-        if cfg.moe_router_scoring == "sigmoid":
-            score = jax.nn.sigmoid(logits)
-            _, idx = jax.lax.top_k(score + p["router_bias"], k)
-            weight = jnp.take_along_axis(score, idx, axis=-1)
-            weight = weight / (jnp.sum(weight, axis=-1, keepdims=True)
-                               + 1e-20)
-        else:
-            weight, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
-            weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
-        if cfg.moe_routed_scaling != 1.0:
-            weight = weight * cfg.moe_routed_scaling
-        counted = valid.reshape(-1).astype(jnp.float32)
-        load = jnp.zeros((R,), jnp.float32).at[idx.reshape(-1)].add(
-            jnp.repeat(counted, k))
+        idx, weight, load = _route(
+            cfg, interpret, p, xt, valid.reshape(-1).astype(jnp.float32))
         local = idx - cfg.moe_expert_offset
         here = (local >= 0) & (local < E)
         local = jnp.where(here, local, E)

@@ -29,7 +29,13 @@ from jax.sharding import (  # noqa: E402
 )
 
 from megatron_llm_tpu import kernels  # noqa: E402
-from megatron_llm_tpu.config import ParallelConfig, llama2_config  # noqa: E402
+from megatron_llm_tpu.config import (  # noqa: E402
+    ParallelConfig,
+    deepseek_v3_config,
+    llama2_config,
+    nemotron_h_config,
+    qwen3_next_config,
+)
 from megatron_llm_tpu.kernels import flash_decode as fd  # noqa: E402
 from megatron_llm_tpu.kernels.flash_attention import flash_attention  # noqa: E402
 from megatron_llm_tpu.kernels.grouped_matmul import (  # noqa: E402
@@ -37,6 +43,7 @@ from megatron_llm_tpu.kernels.grouped_matmul import (  # noqa: E402
 )
 from megatron_llm_tpu.kernels.gdn_step import gdn_step  # noqa: E402
 from megatron_llm_tpu.kernels.mamba_step import mamba_step  # noqa: E402
+from megatron_llm_tpu.kernels.moe_router import router_top_k  # noqa: E402
 from megatron_llm_tpu.kernels.rmsnorm import (  # noqa: E402
     layernorm_pallas,
     rmsnorm_pallas,
@@ -508,13 +515,14 @@ def _beside_the_mixer(text, slots):
     between their two projections (under ``mamba``, outside
     ``mamba_proj``) besides the kernel: nothing but relabellings and,
     once a program, the ``live`` mask turned into the integers the kernel
-    prefetches."""
+    prefetches (alone, or in one fusion with another conversion of the
+    same mask: the float the router's kernel counts by, PR 53)."""
     relabels = {"custom-call", "get-tuple-element", "bitcast", "tuple",
                 "constant", "parameter"}
     return [(op, result, path)
             for op, result, path in ops_under_scopes(text, ["mamba"], None)
             if "mamba_proj" not in path.split("/") and op not in relabels
-            and not re.match(rf"s32\[{slots}(,1)?\]", result)]
+            and not re.match(rf"\(?s32\[{slots}(,1)?\]", result)]
 
 
 def test_a_state_space_decode_step_rewrites_its_states_in_place(
@@ -755,6 +763,105 @@ def test_a_dropless_prefill_routes_through_the_grouped_kernel(topo,
     for _op, result, path in ops_under_scopes(text, scopes, {"gather"}):
         assert "searchsorted" in path and f"[{rows}" not in result, (
             result, path)
+
+
+# -- the dropless router's choice -------------------------------------------
+
+@pytest.mark.parametrize("tokens,experts,k,biased", [
+    (11264, 512, 10, False), (44, 512, 10, False),
+    (1280, 512, 22, True), (128, 512, 22, True),
+    (12288, 128, 6, True), (48, 128, 6, True),
+    (1531, 128, 6, True), (1, 512, 10, False),
+], ids=["longdoc_prompt", "longdoc_step", "reasoning_prompt",
+        "reasoning_step", "longqa_prompt", "longqa_step", "a_ragged_tile",
+        "one_token"])
+def test_moe_router(topo, tokens, experts, k, biased):
+    """The router's choice at the three expert cells' widths, a prompt's
+    tokens and a decode step's: Mosaic takes a round's two reductions down
+    the sublanes of an ``[experts, tokens]`` tile, a round's row stored at
+    a traced row of the ``[k, tokens]`` outputs, tiles of fewer lanes than
+    a register (44, 48, 1) and a last tile that reaches past the tokens;
+    XLA hands the scores over experts-major and takes the results back
+    tokens-major, and nothing under the call sorts, gathers or
+    scatters."""
+    one = SingleDeviceSharding(topo.devices[0])
+    f32 = jnp.float32
+    if biased:
+        fn, args = (lambda s, b, c: router_top_k(s, b, c, k, interpret=False),
+                    (_sds((tokens, experts), f32), _sds((experts,), f32),
+                     _sds((tokens,), f32)))
+    else:
+        fn, args = (lambda s, c: router_top_k(s, None, c, k, interpret=False),
+                    (_sds((tokens, experts), f32), _sds((tokens,), f32)))
+    text = _compile(fn, args, one)
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert not re.search(r" (sort|scatter|gather|topk)\(", text)
+
+
+_ROUTED = {
+    "qwen3_next": lambda: qwen3_next_config(
+        "80b-a3b-ep2-rank0", num_layers=4, attention_impl="flash"),
+    "nemotron_h": lambda: nemotron_h_config(
+        "3-super-120b-a12b-ep4-rank0", num_layers=11, attention_impl="flash"),
+    "deepseek_v3": lambda: deepseek_v3_config(
+        "kanana-2-30b-a3b-pp8-stage0", attention_impl="flash"),
+}
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+@pytest.mark.parametrize("preset", sorted(_ROUTED))
+def test_a_router_chooses_without_a_sort_a_gather_or_a_scatter(
+        topo, monkeypatch, preset, program):
+    """The engine's prefill and decode programs of the three expert
+    presets at their published widths, lowered for the chip (nothing is
+    compiled): under ``moe_router`` stand the float32 product at
+    ``Precision.HIGHEST``, the scoring and ONE call of the kernel
+    (``_dropless`` is traced once a program), and no ``topk`` (a sort on
+    this backend), no ``sort``, no ``gather`` and no ``scatter``: the
+    parent's programs held a ``topk`` and a ``scatter`` there, each
+    (PERF.md, PR 53)."""
+    from jax._src.lib import xla_client
+
+    from megatron_llm_tpu.serving import engine as engine_lib
+
+    monkeypatch.setattr(attn_ops, "_backend", lambda: "tpu")
+    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = _ROUTED[preset]()
+    place = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
+    params = jax.eval_shape(
+        lambda key: model_lib.init_params(key, cfg), jax.random.key(0))
+    i32, f32 = jnp.int32, jnp.float32
+    if program == "prefill":
+        lowered = engine_lib._prefill_impl.lower(
+            cfg, place(params), place(_sds((1, 2048), i32)),
+            place(_sds((1,), i32)), max_seq_len=2048 + 256,
+            want_logprobs=False)
+    else:
+        S, blocks, bk = 8, 4, 128
+        pool = jax.eval_shape(
+            lambda: model_lib.init_kv_pool(cfg, S * blocks + 1, bk))
+        rec = jax.eval_shape(lambda: model_lib.init_rec_state(cfg, S))
+        vec = lambda dtype: place(_sds((S,), dtype))  # noqa: E731
+        lowered = engine_lib._decode_donated.lower(
+            cfg, place(params), *place(pool), place(_sds((S, blocks), i32)),
+            vec(i32), vec(i32), vec(jnp.uint32), vec(i32), vec(bool),
+            vec(f32), vec(i32), vec(f32), rec=place(rec), live=vec(bool))
+    # the program as it was written, with every operation's scope path
+    options = xla_client._xla.HloPrintOptions()
+    options.print_metadata = True
+    options.print_large_constants = False
+    text = lowered.compiler_ir(dialect="hlo").as_hlo_module().to_string(
+        options)
+    under = ops_under_scopes(text, ["moe_router"], None)
+    ops = [op for op, _type, _path in under]
+    assert ops.count("custom-call") == 1 and ops.count("dot") == 1, ops
+    assert not {"sort", "topk", "gather", "scatter"} & set(ops), ops
+    product, = [line for line in text.splitlines()
+                if " dot(" in line and 'op_name="moe_router/' in line]
+    assert "= f32[" in product and \
+        "operand_precision={highest,highest}" in product, product
 
 
 # -- the decode and verify steps of a Llama-family stack, by operand kind ---

@@ -202,14 +202,20 @@ OTHERS = {
 # the states went through the scan as xs and ys) and a decode step's
 # DeltaNet layer is one kernel on the stacked states between its two
 # projections (kernels/gdn_step.py) where the parent's program held the
-# plain composition; the other three are PR 47's.
+# plain composition.  All four of ("qwen3_next", ...) and ("nemotron_h",
+# ...) are PR 53's: a dropless layer's router takes its k experts, their
+# scores and the load from one kernel's k rounds of max-and-mask
+# (kernels/moe_router.py) where the parent's programs held a ``top_k``,
+# on the sigmoid path a ``take_along_axis``, and a scatter-add of rows x
+# k single elements; the same experts in the same order
+# (tests/models/test_moe.py).  The two of "falcon" are PR 47's.
 LOWERED = {
     ("falcon", "decode"): "ba47a517f99fe833",
     ("falcon", "prefill"): "8cebe19aaf9ad16b",
-    ("qwen3_next", "decode"): "62f1329e5e46e5af",
-    ("qwen3_next", "prefill"): "d2d18acfa961ecdf",
-    ("nemotron_h", "decode"): "56941dabf9257fff",
-    ("nemotron_h", "prefill"): "23ccf916d9425b0d",
+    ("qwen3_next", "decode"): "b182f5e7cd4193b0",
+    ("qwen3_next", "prefill"): "ca62eb8f3f806c11",
+    ("nemotron_h", "decode"): "e40cbf71ee017751",
+    ("nemotron_h", "prefill"): "a0390066aa4053b5",
 }
 
 

@@ -19,6 +19,9 @@ from megatron_llm_tpu.config import (
     ParallelConfig,
     RuntimeConfig,
     TrainConfig,
+    deepseek_v3_config,
+    nemotron_h_config,
+    qwen3_next_config,
     tiny_config,
 )
 from megatron_llm_tpu.models import model as model_lib
@@ -234,3 +237,81 @@ def test_moe_through_pipeline():
     assert np.isfinite(float(loss))
     assert all(np.isfinite(np.asarray(l)).all()
                for l in jax.tree.leaves(grads))
+
+
+# --- the dropless router: k rounds of max-and-mask against top_k ---------
+
+ROUTERS = {
+    # router_experts / top k, the scoring, the scaling: the three presets'
+    "qwen3_next": lambda: qwen3_next_config("80b-a3b-ep2-rank0"),
+    "nemotron_h": lambda: nemotron_h_config("3-super-120b-a12b-ep4-rank0"),
+    "deepseek_v3": lambda: deepseek_v3_config("kanana-2-30b-a3b-pp8-stage0"),
+}
+
+
+def _route_by_sort(cfg, p, xt, counted):
+    """The router as it was composed before ``kernels/moe_router.py``: the
+    reference the kernel is held to (``lax.top_k``, the un-biased scores
+    by ``take_along_axis``, the load by a scatter-add of single
+    elements)."""
+    k = cfg.moe_top_k
+    logits = jnp.dot(xt.astype(jnp.float32), p["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    if cfg.moe_router_scoring == "sigmoid":
+        score = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(score + p["router_bias"], k)
+        weight = jnp.take_along_axis(score, idx, axis=-1)
+        weight = weight / (jnp.sum(weight, axis=-1, keepdims=True) + 1e-20)
+    else:
+        weight, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+        weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
+    if cfg.moe_routed_scaling != 1.0:
+        weight = weight * cfg.moe_routed_scaling
+    load = jnp.zeros((cfg.router_experts,), jnp.float32).at[
+        idx.reshape(-1)].add(jnp.repeat(counted, k))
+    return idx, weight, load
+
+
+def _ulps(a, b) -> int:
+    """The largest distance between two float32 arrays of one sign, in
+    units in the last place."""
+    a, b = (np.asarray(v, np.float32).view(np.int32).astype(np.int64)
+            for v in (a, b))
+    return int(np.abs(a - b).max())
+
+
+@pytest.mark.parametrize("rows", [1, 44, 128, 1500, 2048])
+@pytest.mark.parametrize("preset", sorted(ROUTERS))
+def test_the_router_chooses_as_top_k_does(preset, rows):
+    """``moe._route`` (the kernel, interpreted here) against the parent's
+    composition at the three presets' routers: the same experts in the
+    same order, the same load, the weights within 2 units in the last
+    place.  Among the tokens: two experts of one score in every row (two
+    equal columns of the router and equal biases), a row of zeros (every
+    logit equal, and with biases on a grid of sixteenths many equal
+    biased scores: the k-th and the (k+1)-th among them) and, past the
+    first two thirds, padded positions that are not counted."""
+    cfg = ROUTERS[preset]()
+    R, k, h = cfg.router_experts, cfg.moe_top_k, 64
+    assert (R, k) == {"qwen3_next": (512, 10), "nemotron_h": (512, 22),
+                      "deepseek_v3": (128, 6)}[preset]
+    ks = jax.random.split(jax.random.key(rows), 3)
+    router = jax.random.normal(ks[0], (h, R), jnp.float32)
+    router = router.at[:, 5].set(router[:, 3]).at[:, 0].set(router[:, R - 1])
+    p = {"router": router}
+    if cfg.moe_router_scoring == "sigmoid":
+        bias = jnp.round(0.05 * jax.random.normal(ks[1], (R,)) * 16) / 16
+        p["router_bias"] = bias.at[5].set(bias[3]).at[0].set(bias[R - 1])
+    xt = jax.random.normal(ks[2], (rows, h), jnp.float32).at[0].set(0.0)
+    counted = (jnp.arange(rows) <= 2 * rows // 3).astype(jnp.float32)
+    want = jax.jit(_route_by_sort, static_argnums=0)(cfg, p, xt, counted)
+    got = jax.jit(moe_lib._route, static_argnums=(0, 1))(
+        cfg, True, p, xt, counted)
+    # the cases are there: the zero row's k-th and (k+1)-th are one score
+    flat = jnp.full((R,), 0.5) + p.get("router_bias", 0.0)
+    ranked = jnp.sort(flat)[::-1]
+    assert ranked[k - 1] == ranked[k]
+    np.testing.assert_array_equal(got[0], want[0])
+    np.testing.assert_array_equal(got[2], want[2])
+    assert float(want[2].sum()) == k * float(counted.sum())
+    assert _ulps(got[1], want[1]) <= 2, _ulps(got[1], want[1])
