@@ -28,10 +28,16 @@ from its oldest span's start to its newest one's end — as ``compile``
 complete events on a track of their own, each with its ``cause``: the
 innermost retained span that contains it (for the engine a ``prefill``
 with its request id and padded width, or the ``engine_step``; for the
-train loop the ``train-step`` or ``setup``).  Found at export, so the
+train loop the ``train-step`` or ``setup``; never one of the recorder's
+``phases``, the spans that only divide another).  Found at export, so the
 paths that record spans pay nothing; a compilation that ran while the
 recorder held nothing around it (``TRAIN_TRACE`` switched off) has no
 place on its timeline and is left out.
+
+Collections: a ``GcWatch`` put on ``gc.callbacks`` records every Python
+collection of a millisecond or more as a ``gc`` span on a track of its
+own (``GC_TID``), whatever thread ran it: a collection holds the
+interpreter lock, so every thread of the process stands still under it.
 
 ``TRAIN_TRACE`` is the train loop's recorder (``utils/timers.py`` feeds
 it from the loop's timers).  It is off unless a profile session or a
@@ -45,6 +51,7 @@ tuples — dict construction is deferred to export time.
 from __future__ import annotations
 
 import contextlib
+import gc
 import os
 import time
 from collections import deque
@@ -57,6 +64,10 @@ from . import profile
 
 #: ``tid`` of the ``compile`` events' track (request ids count up from 0)
 COMPILE_TID = 1 << 30
+#: ``tid`` of the ``gc`` spans' track
+GC_TID = COMPILE_TID + 1
+#: a collection shorter than this leaves no span (seconds)
+GC_MIN_S = 1e-3
 
 _PROFILER_SENTINEL = object()
 _profiler = _PROFILER_SENTINEL  # lazily resolved jax.profiler module (or None)
@@ -86,9 +97,13 @@ def device_annotation(name: str):
 class TraceRecorder:
     """Bounded ring buffer of completed spans; Chrome-trace JSON export."""
 
-    def __init__(self, capacity: int = 8192, enabled: bool = True):
+    def __init__(self, capacity: int = 8192, enabled: bool = True,
+                 phases=()):
         self.enabled = enabled
         self.capacity = capacity
+        # names of spans that subdivide another span of this recorder: a
+        # compilation's cause is the span they divide, never one of them
+        self._phases = frozenset(phases)
         self._lock = make_lock("obs.trace")
         # (name, ph, t0, dur, tid, request_id, args) — compact on the hot
         # path; the ring drops the oldest spans once capacity is reached.
@@ -122,6 +137,18 @@ class TraceRecorder:
                 self._dropped += 1
             self._events.append((name, "X", t0, max(0.0, t1 - t0), tid,
                                  request_id, args))
+
+    def add_unlocked(self, name: str, t0: float, t1: float, *, tid: int = 0,
+                     args: Optional[Dict] = None) -> None:
+        """``add`` for a caller that may already hold this recorder's
+        lock: a ``gc`` callback runs in whatever thread allocated last —
+        inside ``add`` itself among other places — and would wait for a
+        lock its own thread holds.  The append alone, which the
+        interpreter lock makes atomic; the eviction it may cause is not
+        counted in ``dropped``."""
+        if self.enabled:
+            self._events.append((name, "X", t0, max(0.0, t1 - t0), tid,
+                                 None, args))
 
     def instant(self, name: str, *, request_id: Optional[str] = None,
                 tid: int = 0, args: Optional[Dict] = None) -> None:
@@ -161,7 +188,9 @@ class TraceRecorder:
             events = list(self._events)
             dropped = self._dropped
         out: List[Dict] = []
+        tids = set()
         for name, ph, t0, dur, tid, request_id, args in events:
+            tids.add(tid)
             ev: Dict = {
                 "name": name,
                 "ph": ph,
@@ -180,6 +209,9 @@ class TraceRecorder:
                 ev["args"] = ev_args
             out.append(ev)
         out.extend(self._compile_events(events))
+        if GC_TID in tids:
+            out.append({"name": "thread_name", "ph": "M", "pid": self._pid,
+                        "tid": GC_TID, "args": {"name": "gc"}})
         other = {"dropped_events": dropped,
                  "epoch_perf_counter": self._epoch}
         session = profile.last()
@@ -192,7 +224,8 @@ class TraceRecorder:
     def _compile_events(self, events) -> List[Dict]:
         """The compilation records that overlap ``events``' extent, as
         complete events with their cause (see the module docstring)."""
-        spans = [e for e in events if e[1] == "X"]
+        spans = [e for e in events
+                 if e[1] == "X" and e[0] not in self._phases]
         if not spans:
             return []
         lo = min(e[2] for e in spans)
@@ -222,6 +255,44 @@ class TraceRecorder:
             out.append({"name": "thread_name", "ph": "M", "pid": self._pid,
                         "tid": COMPILE_TID, "args": {"name": "compile"}})
         return out
+
+
+class GcWatch:
+    """A ``gc.callbacks`` entry that records the collections of
+    ``GC_MIN_S`` or more into ``recorder`` as ``gc`` spans (``args``:
+    ``generation``, ``collected``).  The interpreter calls it with
+    ``"start"`` and ``"stop"`` around every collection, in the thread
+    that triggered it and with the interpreter lock held, so one pending
+    start is all the state there is — and that thread may be inside the
+    recorder's own ``add`` (the ring's tuple is an allocation), holding
+    its lock: the span goes in by ``add_unlocked``.  ``install`` /
+    ``remove`` put it on and take it off the list; whoever owns the
+    recorder calls both."""
+
+    def __init__(self, recorder: TraceRecorder, clock=time.perf_counter):
+        self.recorder = recorder
+        self._clock = clock
+        self._t0: Optional[float] = None
+
+    def __call__(self, phase: str, info: Dict) -> None:
+        now = self._clock()
+        if phase == "start":
+            self._t0 = now
+        elif self._t0 is not None:
+            t0, self._t0 = self._t0, None
+            if now - t0 >= GC_MIN_S:
+                self.recorder.add_unlocked(
+                    "gc", t0, now, tid=GC_TID,
+                    args={"generation": info["generation"],
+                          "collected": info["collected"]})
+
+    def install(self) -> None:
+        if self not in gc.callbacks:
+            gc.callbacks.append(self)
+
+    def remove(self) -> None:
+        if self in gc.callbacks:
+            gc.callbacks.remove(self)
 
 
 # the train loop's spans (driver.pretrain's timers); see the module docstring

@@ -133,7 +133,7 @@ from ..models import model as model_lib
 from ..obs import compile as obs_compile
 from ..obs import profile as obs_profile
 from ..obs.logging import EVENT_LOG
-from ..obs.trace import TraceRecorder, device_annotation
+from ..obs.trace import GcWatch, TraceRecorder, device_annotation
 from ..ops.lora import arena_sr, slot_mask
 from ..resilience.chaos import chaos
 from .adapters.registry import AdapterRegistry
@@ -142,6 +142,28 @@ from .metrics import ServingMetrics
 from .prefix_cache import PrefixCache
 from .queue import QueueFull, RequestQueue  # noqa: F401  (re-exported)
 from .slots import SlotAllocator
+
+#: The scheduler thread's iteration in phases: span name -> ``"own"``
+#: (the host's own work) or ``"blocked"`` (the host waited for the
+#: device).  The spans lie on the scheduler's track (``tid`` 0) inside an
+#: ``engine_step`` or an ``admit`` and do not overlap; ``gc`` lies on a
+#: track of its own (``obs/trace.py:GcWatch``).  What of an iteration no
+#: phase covers is the loop's own overhead.  The one table of the
+#: vocabulary: the benchmark's reader (``benchmarks/readers/
+#: sched_phases.py``) imports it, ``docs/observability.md`` says which
+#: path records which, a test holds it to the ``trace.add`` calls below.
+SCHED_PHASES = {
+    "step_inputs": "own",        # a decode step's host arrays
+    "dispatch": "own",           # its jitted call(s), async copies started
+    "fetch": "blocked",          # np.asarray of a dispatched step's tokens
+    "commit": "own",             # tokens committed, callbacks, retirements
+    "admit_setup": "own",        # slot, adapter, prefix match, reservation
+    "prefill_dispatch": "own",   # padding, the prompt's copy, the call
+    "slot_insert": "own",        # the scatter into the pool, state install
+    "prefill_wait": "blocked",   # first token's dispatch and its fetch
+    "admit_commit": "own",       # slot state, event log, first commit
+    "gc": "own",                 # a Python collection of 1 ms or more
+}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -928,11 +950,12 @@ class _Inflight:
     later groups keep the other pipeline stages busy (bubble fill)."""
 
     __slots__ = ("tok", "tok_lp", "slots", "t_dispatch", "sampling",
-                 "positions")
+                 "positions", "iter")
 
     def __init__(self, tok, tok_lp, slots, t_dispatch, sampling,
-                 positions=0):
+                 positions=0, iter=0):
         self.positions = positions  # cached positions its slots held
+        self.iter = iter          # the scheduler iteration that dispatched it
         self.tok = tok            # [S] device array (or per-group list)
         self.tok_lp = tok_lp      # [S] logprobs, same layout as ``tok``
         self.slots = slots
@@ -1119,10 +1142,13 @@ class ServingEngine:
             adapters._metrics = self.metrics
         self.metrics.set_gauges(num_slots=self.config.max_batch_size)
         self.trace = TraceRecorder(capacity=self.config.trace_capacity,
-                                   enabled=self.config.trace)
+                                   enabled=self.config.trace,
+                                   phases=SCHED_PHASES)
         # a profile session keeps this recorder: its readers join the
         # spans (prompt lengths, live slots) with the device's operations
         obs_profile.while_profiling(self.trace)
+        # on gc.callbacks from start() to shutdown(), where tracing is on
+        self._gc_watch = GcWatch(self.trace) if self.config.trace else None
         # a compile inside a span of this recorder is exported with it
         obs_compile.install()
         self.queue = RequestQueue(self.config.max_queue_size,
@@ -1349,6 +1375,8 @@ class ServingEngine:
                     self.metrics.expert_rows = self.expert_rows
                 if self._sanitize:
                     self._sanitizer = sanitizers.LedgerSanitizer()
+                if self._gc_watch is not None:
+                    self._gc_watch.install()
                 self._thread = threading.Thread(
                     target=self._loop, name="serving-engine", daemon=True)
                 self._thread.start()
@@ -1365,6 +1393,8 @@ class ServingEngine:
                 self._wake.notify_all()
             self._thread.join(timeout)
             self._thread = None
+            if self._gc_watch is not None:
+                self._gc_watch.remove()
             with self._drain_cond:
                 self._drain_cond.notify_all()
             if self._sanitizer is not None:
@@ -2025,6 +2055,7 @@ class ServingEngine:
         """Whole-prompt admission.  Returns False (request parked in
         ``_held``, nothing allocated) when the pool cannot reserve the
         request's worst-case block count."""
+        t_in = time.perf_counter()
         slot = self.slots.alloc()
         assert slot is not None
         aslot = self._acquire_adapter(req)
@@ -2105,6 +2136,7 @@ class ServingEngine:
             if req.return_logprobs:
                 req.logprobs.extend(
                     np.asarray(picked)[0, :plen - 1].tolist())
+        t_ins = time.perf_counter()
         self.slots.insert(slot, k_small, v_small, plen,
                           lease.bids if lease is not None else (),
                           rec_small=rec_small)
@@ -2121,7 +2153,19 @@ class ServingEngine:
             jnp.asarray([req.top_p], jnp.float32))
         first = int(np.asarray(tok)[0])
         t.stop()
-        self.trace.add("prefill", t_pf, time.perf_counter(),
+        t_tok = time.perf_counter()
+        if self.trace.enabled:
+            # the admission's phases so far (SCHED_PHASES), ahead of the
+            # spans that enclose them: a reader that names an idle gap
+            # breaks a tie by the order of the ring
+            rid = req.rid
+            self.trace.add("admit_setup", t_in, t_pf, request_id=rid,
+                           args={"iter": self._iter})
+            self.trace.add("prefill_dispatch", t_pf, t_ins, request_id=rid,
+                           args={"padded": padded})
+            self.trace.add("slot_insert", t_ins, t_ft, request_id=rid)
+            self.trace.add("prefill_wait", t_ft, t_tok, request_id=rid)
+        self.trace.add("prefill", t_pf, t_tok,
                        request_id=req.rid, tid=req.id,
                        args={"prompt_len": plen, "padded": padded,
                              "cached_tokens": lease.tokens if lease else 0,
@@ -2152,6 +2196,8 @@ class ServingEngine:
         self.trace.add("first_token", t_ft, time.perf_counter(),
                        request_id=req.rid, tid=req.id)
         self._maybe_handoff(slot)
+        self.trace.add("admit_commit", t_tok, time.perf_counter(),
+                       request_id=req.rid)
         return True
 
     # tpulint: hot-path
@@ -2530,6 +2576,15 @@ class ServingEngine:
         t_ready = time.perf_counter()
         self._last_ready_t = t_ready
         device_s = t_ready - t0
+        if self.trace.enabled:
+            # a verify step is synchronous and takes no reading between
+            # its call and its fetch: ``fetch`` holds the call's dispatch
+            self.trace.add("step_inputs", it0, t0,
+                           args={"iter": self._iter,
+                                 "live": len(self._active)})
+            self.trace.add("fetch", t0, t_ready,
+                           args={"iter": self._iter,
+                                 "dispatched": self._iter})
 
         total_committed = 0
         proposed = 0
@@ -2580,9 +2635,14 @@ class ServingEngine:
                                        slot_ewmas=slot_ewmas)
         self.metrics.observe_decode_iteration(total_committed, device_s)
         self.metrics.observe_step_breakdown(device_s=device_s)
-        host_s = max(0.0, (time.perf_counter() - it0) - (t_ready - t0))
+        t_end = time.perf_counter()
+        host_s = max(0.0, (t_end - it0) - (t_ready - t0))
         self.metrics.observe_step_breakdown(host_s=host_s)
         self.metrics.set_gauges(slots_active=self.slots.active_slots)
+        if self.trace.enabled:
+            self.trace.add("commit", t_ready, t_end,
+                           args={"iter": self._iter,
+                                 "committed": total_committed})
         self.trace.add(
             "engine_step", it0, time.perf_counter(), tid=0,
             args={"iter": self._iter, "batch": len(drafts),
@@ -2740,6 +2800,12 @@ class ServingEngine:
         t_ready = time.perf_counter()
         self._last_ready_t = t_ready
         device_s = t_ready - t0
+        if self.trace.enabled:
+            # as on the n-gram verify step; no ``step_inputs``: what lies
+            # before ``t0`` holds the draft model's own calls and fetches
+            self.trace.add("fetch", t0, t_ready,
+                           args={"iter": self._iter,
+                                 "dispatched": self._iter})
 
         # accept walk (host): longest root path matching target argmax
         paths = {}
@@ -2837,9 +2903,14 @@ class ServingEngine:
                                        slot_ewmas=slot_ewmas)
         self.metrics.observe_decode_iteration(total_committed, device_s)
         self.metrics.observe_step_breakdown(device_s=device_s)
-        host_s = max(0.0, (time.perf_counter() - it0) - (t_ready - t0))
+        t_end = time.perf_counter()
+        host_s = max(0.0, (t_end - it0) - (t_ready - t0))
         self.metrics.observe_step_breakdown(host_s=host_s)
         self.metrics.set_gauges(slots_active=self.slots.active_slots)
+        if self.trace.enabled:
+            self.trace.add("commit", t_ready, t_end,
+                           args={"iter": self._iter,
+                                 "committed": total_committed})
         self.trace.add(
             "engine_step", it0, time.perf_counter(), tid=0,
             args={"iter": self._iter, "batch": len(plans),
@@ -2851,6 +2922,7 @@ class ServingEngine:
     # tpulint: hot-path
     def _dispatch_decode(self) -> _Inflight:
         assert self.slots is not None
+        t_in = time.perf_counter()
         S = self.config.max_batch_size
         overrides = np.zeros((S,), np.int32)
         override_mask = np.zeros((S,), bool)
@@ -2961,10 +3033,15 @@ class ServingEngine:
             st.count += 1  # one more token sampled (possibly speculative)
         # tpulint: allow[host-sync] fills is host numpy (built above)
         positions = int(fills.sum())
+        if self.trace.enabled:
+            self.trace.add("step_inputs", t_in, t0,
+                           args={"iter": self._iter, "live": len(snapshot)})
+            self.trace.add("dispatch", t0, time.perf_counter(),
+                           args={"iter": self._iter})
         if G == 1:
-            return _Inflight(toks[0], tok_lps[0], snapshot, t0, sampling,
-                             positions)
-        return _Inflight(toks, tok_lps, snapshot, t0, sampling, positions)
+            toks, tok_lps = toks[0], tok_lps[0]
+        return _Inflight(toks, tok_lps, snapshot, t0, sampling, positions,
+                         self._iter)
 
     # tpulint: hot-path
     def _process_step_results(self, step: _Inflight) -> float:
@@ -2989,6 +3066,14 @@ class ServingEngine:
         t_ready = time.perf_counter()
         self._last_ready_t = t_ready
         device_s = t_ready - step.t_dispatch
+        # ahead of this step's ``decode`` spans, which cover the same wait
+        # (dispatch -> tokens on the host) and more: an idle gap the host
+        # sat out here is named ``fetch`` by a reader that breaks a tie by
+        # the order of the ring
+        if self.trace.enabled:
+            self.trace.add("fetch", t_fetch, t_ready,
+                           args={"iter": self._iter,
+                                 "dispatched": step.iter})
         committed = 0
         step_arg = self._step_arg
         if self._latent and self.trace.enabled:
@@ -3018,6 +3103,9 @@ class ServingEngine:
             self._commit_token(slot, st.pending, float(tok_lp[slot]))
         self.metrics.observe_decode_iteration(committed, device_s)
         self.metrics.observe_step_breakdown(device_s=device_s)
+        if self.trace.enabled:
+            self.trace.add("commit", t_ready, time.perf_counter(),
+                           args={"iter": self._iter, "committed": committed})
         return t_ready - t_fetch
 
     # tpulint: hot-path

@@ -431,3 +431,289 @@ def test_a_compile_inside_a_request_is_named_in_all_three_views():
                     if e["name"] == "compile"]) == n
     finally:
         svc.close()
+
+
+# --- the scheduler's iteration in phases (engine.py:SCHED_PHASES) --------------
+
+def _serve(model, prompts, new_tokens, **engine_kw):
+    """A served batch → the engine (shut down) and its complete events."""
+    from megatron_llm_tpu.serving import EngineConfig, ServingEngine
+
+    cfg, params = model
+    kw = dict(max_batch_size=2, max_seq_len=64, kv_block_size=8)
+    kw.update(engine_kw)
+    engine = ServingEngine(cfg, params, EngineConfig(**kw)).start()
+    try:
+        handles = [engine.submit(p, new_tokens, use_eos_stop=False, seed=0)
+                   for p in prompts]
+        for h in handles:
+            h.result(timeout=300)
+    finally:
+        engine.shutdown(timeout=60.0)
+    return engine, [e for e in engine.trace.chrome_trace()["traceEvents"]
+                    if e["ph"] == "X"]
+
+
+def _inside(e, outer, slack=1.0):
+    """``e`` lies inside ``outer`` (microseconds; the export rounds)."""
+    return (outer["ts"] - slack <= e["ts"]
+            and e["ts"] + e["dur"] <= outer["ts"] + outer["dur"] + slack)
+
+
+def _phases_of(events, outer, names):
+    return [e for e in events if e["name"] in names and e["tid"] == 0
+            and _inside(e, outer)]
+
+
+STEP_PHASES = ("step_inputs", "dispatch", "fetch", "commit")
+ADMIT_PHASES = ("admit_setup", "prefill_dispatch", "slot_insert",
+                "prefill_wait", "admit_commit")
+PROMPTS = [[5, 9, 3, 7, 2, 8], [7, 2, 4], [11, 12, 13, 14, 15]]
+
+
+@pytest.mark.parametrize("pipelined", [True, False])
+def test_every_decode_step_holds_its_phases_in_order(model, pipelined):
+    """``step_inputs``, ``dispatch``, ``fetch`` and ``commit`` lie inside
+    their ``engine_step`` in that order, none overlapping another, all
+    under its ``iter``.  Pipelined, an iteration fetches the step the one
+    before dispatched (``fetch``'s ``dispatched``), so a step that found
+    no step in flight has no ``fetch``; not pipelined, every step fetches
+    its own."""
+    _engine, events = _serve(model, PROMPTS, 6, pipeline_decode=pipelined)
+    steps = sorted((e for e in events if e["name"] == "engine_step"),
+                   key=lambda e: e["ts"])
+    assert len(steps) >= 6
+    unfetched = []
+    for step in steps:
+        it = step["args"]["iter"]
+        inner = sorted(_phases_of(events, step, STEP_PHASES),
+                       key=lambda e: e["ts"])
+        names = [e["name"] for e in inner]
+        if names != list(STEP_PHASES):
+            assert names == ["step_inputs", "dispatch"], (it, names)
+            unfetched.append(it)
+        assert {e["args"]["iter"] for e in inner} == {it}
+        for a, b in zip(inner, inner[1:]):
+            assert a["ts"] + a["dur"] <= b["ts"] + 1.0, (a, b)
+        assert sum(e["dur"] for e in inner) <= step["dur"] + 2.0
+        by = {e["name"]: e for e in inner}
+        assert by["step_inputs"]["args"]["live"] == step["args"]["batch"]
+        if "fetch" in by:
+            assert by["fetch"]["args"]["dispatched"] == \
+                it - (1 if pipelined else 0)
+            assert 0 <= by["commit"]["args"]["committed"] <= 2
+    if pipelined:
+        # only the first step of a run of steps finds nothing in flight
+        assert steps[0]["args"]["iter"] in unfetched
+        assert all(it - 1 not in {s["args"]["iter"] for s in steps}
+                   for it in unfetched)
+    else:
+        assert not unfetched
+    committed = sum(e["args"]["committed"] for e in events
+                    if e["name"] == "commit")
+    # every token but each request's first (the admission's) is a step's
+    assert committed == len(PROMPTS) * (6 - 1)
+
+
+def test_the_phases_cover_most_of_the_median_step(model):
+    """Over a served batch of twenty steps and more, the median step's
+    phases cover four fifths of it: what no phase covers is the loop's
+    own overhead, and it is small.  (No bound in milliseconds: the test
+    machine is shared.)"""
+    _engine, events = _serve(model, PROMPTS[:2], 24)
+    steps = [e for e in events if e["name"] == "engine_step"]
+    assert len(steps) >= 20
+    shares = sorted(
+        sum(e["dur"] for e in _phases_of(events, s, STEP_PHASES)) / s["dur"]
+        for s in steps if s["dur"] > 0)
+    assert shares[len(shares) // 2] >= 0.8, shares
+
+
+@pytest.mark.parametrize("prefix_cache_blocks", [0, 16])
+def test_every_admission_holds_its_five_phases(model, prefix_cache_blocks):
+    """A whole-prompt admission: ``admit_setup``, ``prefill_dispatch``,
+    ``slot_insert``, ``prefill_wait`` and ``admit_commit`` follow one
+    another without a hole inside the ``admit`` span, under the request's
+    id; ``prefill_wait`` ends where the request's ``prefill`` ends, and
+    ``prefill_dispatch`` begins where it begins."""
+    prompts = PROMPTS + [PROMPTS[0] + [1, 2, 3, 4, 5, 6]]
+    _engine, events = _serve(model, prompts, 3,
+                             prefix_cache_blocks=prefix_cache_blocks)
+    admits = [e for e in events if e["name"] == "admit"]
+    prefills = {e["args"]["request_id"]: e for e in events
+                if e["name"] == "prefill"}
+    assert len(prefills) == len(prompts)
+    seen = 0
+    for rid, pf in prefills.items():
+        mine = sorted((e for e in events if e["name"] in ADMIT_PHASES
+                       and e["args"].get("request_id") == rid),
+                      key=lambda e: e["ts"])
+        assert [e["name"] for e in mine] == list(ADMIT_PHASES)
+        assert all(e["tid"] == 0 for e in mine)
+        (admit,) = [a for a in admits if _inside(pf, a)]
+        assert all(_inside(e, admit) for e in mine)
+        for a, b in zip(mine, mine[1:]):    # one reading ends a, begins b
+            assert a["ts"] + a["dur"] == pytest.approx(b["ts"], abs=1.0)
+        by = {e["name"]: e for e in mine}
+        assert by["prefill_dispatch"]["ts"] == pytest.approx(pf["ts"],
+                                                             abs=1.0)
+        assert by["prefill_wait"]["ts"] + by["prefill_wait"]["dur"] == \
+            pytest.approx(pf["ts"] + pf["dur"], abs=1.0)
+        assert by["admit_setup"]["args"]["iter"] == pf["args"]["iter"]
+        assert by["prefill_dispatch"]["args"]["padded"] == \
+            pf["args"]["padded"]
+        seen += 1
+    assert seen == len(prompts)
+
+
+def test_sched_phases_is_the_vocabulary_the_engine_records():
+    """``SCHED_PHASES`` is ``gc`` and the names ``engine.py`` records on
+    the scheduler's track other than ``engine_step`` and ``admit``: a
+    phase added to the source and left out of the table (or the other way
+    round) fails here, not in the benchmark's reader."""
+    from pathlib import Path
+
+    from megatron_llm_tpu.serving import engine as engine_mod
+
+    text = Path(engine_mod.__file__).read_text()
+    calls = re.findall(
+        r'self\.trace\.add\(\s*"(\w+)",((?:[^()]|\([^()]*\))*)\)', text)
+    assert len(calls) >= 20
+    track0 = {name for name, rest in calls
+              if "tid=req.id" not in rest and "tid=st.req.id" not in rest
+              and "tid=sus.req.id" not in rest}
+    assert {"engine_step", "admit"} <= track0
+    on_requests = {name for name, _rest in calls} - track0
+    assert on_requests >= {"queued", "prefill", "first_token", "decode"}
+    assert track0 - {"engine_step", "admit"} | {"gc"} == \
+        set(engine_mod.SCHED_PHASES)
+    assert set(engine_mod.SCHED_PHASES.values()) == {"own", "blocked"}
+    assert [k for k, v in engine_mod.SCHED_PHASES.items()
+            if v == "blocked"] == ["fetch", "prefill_wait"]
+
+
+def test_a_wait_is_recorded_ahead_of_the_spans_that_cover_it(model):
+    """``fetch`` enters the ring before its step's ``decode`` spans and
+    its ``engine_step``, and an admission's phases before its ``prefill``:
+    the benchmark's gap namer breaks a tie of overlaps by that order."""
+    _engine, events = _serve(model, PROMPTS[:2], 5)
+    order = {}
+    for i, e in enumerate(events):
+        order.setdefault((e["name"], e["args"].get("iter"),
+                          e["args"].get("request_id")), i)
+    fetches = [e for e in events if e["name"] == "fetch"]
+    assert fetches
+    for f in fetches:
+        it = f["args"]["iter"]
+        later = [i for (n, k, _r), i in order.items()
+                 if k == it and n in ("decode", "engine_step", "commit")]
+        assert later and order[("fetch", it, None)] < min(later)
+    for e in events:
+        if e["name"] == "prefill":
+            rid = e["args"]["request_id"]
+            assert order[("prefill_wait", None, rid)] < \
+                order[("prefill", e["args"]["iter"], rid)]
+
+
+def test_a_verify_step_carries_the_phases_its_readings_bound(model):
+    """The n-gram verify step (synchronous) takes no reading between its
+    call and its fetch: ``step_inputs``, ``fetch`` from the call to the
+    tokens on the host, ``commit``; no ``dispatch``."""
+    prompts = [[7, 7, 7, 7, 7, 7, 7], [5, 9, 3, 5, 9, 3, 5, 9, 3, 5, 9]]
+    _engine, events = _serve(model, prompts, 20, spec_draft_len=3)
+    spec = [e for e in events if e["name"] == "engine_step"
+            and e["args"]["route"] == "spec_fallback"]
+    assert spec
+    for step in spec:
+        inner = sorted(_phases_of(events, step, STEP_PHASES),
+                       key=lambda e: e["ts"])
+        assert [e["name"] for e in inner] == ["step_inputs", "fetch",
+                                              "commit"]
+        assert inner[0]["ts"] == pytest.approx(step["ts"], abs=1.0)
+        assert {e["args"]["iter"] for e in inner} == {step["args"]["iter"]}
+        assert inner[2]["args"]["committed"] >= 1
+
+
+def test_trace_off_records_no_phase_and_watches_no_collection(model):
+    """``trace=False``: nothing is recorded and nothing is put on
+    ``gc.callbacks``."""
+    import gc
+
+    before = list(gc.callbacks)
+    engine, events = _serve(model, PROMPTS[:1], 3, trace=False)
+    assert events == [] and len(engine.trace) == 0
+    assert engine._gc_watch is None
+    assert gc.callbacks == before
+
+
+def test_a_long_collection_under_a_running_engine_is_a_gc_span(model):
+    """A full collection over a few hundred thousand cyclic objects while
+    an engine runs leaves a ``gc`` span of generation 2 on the track of
+    its own; once the engine has shut down ``gc.callbacks`` is as long as
+    it was before the engine started."""
+    import gc
+
+    from megatron_llm_tpu.obs.trace import GC_TID, GcWatch
+    from megatron_llm_tpu.serving import EngineConfig, ServingEngine
+
+    cfg, params = model
+    n_before = len(gc.callbacks)
+    engine = ServingEngine(cfg, params, EngineConfig(
+        max_batch_size=2, max_seq_len=64, kv_block_size=8))
+    assert len(gc.callbacks) == n_before       # built, not yet started
+    engine.start()
+    try:
+        assert sum(isinstance(cb, GcWatch) for cb in gc.callbacks) >= 1
+        assert engine._gc_watch in gc.callbacks
+        h = engine.submit(PROMPTS[0], 4, use_eos_stop=False, seed=0)
+        junk = []
+        for _ in range(300_000):
+            a = []
+            a.append(a)                        # a cycle: the collector's
+            junk.append(a)
+        del junk, a
+        gc.collect()
+        h.result(timeout=300)
+    finally:
+        engine.shutdown(timeout=60.0)
+    assert len(gc.callbacks) == n_before
+    assert engine._gc_watch not in gc.callbacks
+    doc = engine.trace.chrome_trace()
+    spans = [e for e in doc["traceEvents"] if e["name"] == "gc"]
+    assert spans and all(e["tid"] == GC_TID and e["ph"] == "X"
+                         and e["dur"] >= 1000.0 for e in spans)
+    full = [e for e in spans if e["args"]["generation"] == 2]
+    assert full and max(e["args"]["collected"] for e in full) >= 300_000
+    assert {"name": "thread_name", "ph": "M", "pid": spans[0]["pid"],
+            "tid": GC_TID, "args": {"name": "gc"}} in doc["traceEvents"]
+    # a second start puts the watch back, a second shutdown takes it off
+    engine.start()
+    assert engine._gc_watch in gc.callbacks
+    engine.shutdown(timeout=60.0)
+    assert len(gc.callbacks) == n_before
+
+
+def test_collections_recorded_from_inside_the_engines_own_spans(model,
+                                                                 monkeypatch):
+    """Collections forced every few allocations, each one recorded (the
+    threshold at zero): many start while the scheduler is inside the
+    recorder's ``add``, holding its lock.  The batch is served to its end
+    and the ring holds their spans — a callback that took the lock again
+    stood the scheduler still here within a few steps."""
+    import gc
+
+    from megatron_llm_tpu.obs import trace as trace_mod
+
+    monkeypatch.setattr(trace_mod, "GC_MIN_S", 0.0)
+    old = gc.get_threshold()
+    gc.set_threshold(5, 1, 1)
+    try:
+        _engine, events = _serve(model, PROMPTS * 2, 8)
+    finally:
+        gc.set_threshold(*old)
+    spans = [e for e in events if e["name"] == "gc"]
+    assert len(spans) >= 20
+    assert {e["args"]["generation"] for e in spans} >= {0, 1}
+    # (every result came back, or ``_serve`` had raised; the ring is
+    # overrun by the collections, so early spans are gone)
+    assert _engine.metrics.snapshot()["completed"] == 6
