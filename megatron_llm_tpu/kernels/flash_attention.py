@@ -6,9 +6,12 @@ CUDA kernels (megatron/fused_kernels/scaled_masked_softmax*.cu).  Instead of
 translating those warp-level kernels, attention is computed block-tiled with
 the online-softmax recurrence so the [sq, sk] score matrix never touches HBM:
 
-  fwd:  for each (batch, q_head, q_block): stream k/v blocks through VMEM,
-        maintaining running max ``m``, normalizer ``l`` and the output
-        accumulator in fp32 scratch; emit O and the logsumexp per row.
+  fwd:  for each (batch, q_head): walk the LIVE tiles of the score matrix
+        row block by row block (``tile_plan``: under a causal mask the
+        tiles above the diagonal are no grid step at all, so nothing is
+        fetched for them), maintaining running max ``m``, normalizer ``l``
+        and the output accumulator in fp32 scratch; emit O and the
+        logsumexp per row.
   bwd:  recompute P = exp(S - lse) blockwise; one kernel accumulates dQ
         (k-blocks innermost), a second accumulates dK/dV (q-blocks
         innermost).  ``delta = rowsum(dO * O)`` is precomputed in XLA.
@@ -82,15 +85,76 @@ def _causal_block_live(cfg: _Config, qi, ki):
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(cfg: _Config, nk: int, *refs):
+def _cut(length: int, bound: int):
+    """``length`` in the fewest equal blocks of at most ``bound`` rows,
+    each a multiple of 128: ``(block, blocks)``.  1280 under 1024 is
+    2 x 640, not 2 x 1024."""
+    bound = max(128, bound // 128 * 128)
+    n = -(-length // bound)
+    return -(-length // (128 * n)) * 128, n
+
+
+def _tiles(sq: int, sk: int, block_q: int, block_k: int, causal: bool):
+    """The score matrix's tiles as two ``[nq, nk]`` boolean arrays:
+    which are ``live`` (hold a position that is kept) and which of those
+    are ``masked`` (hold one that is not: the diagonal crosses them, or
+    ``sk`` ends inside them).  A row block's first tile always counts as
+    live, so every output block is written (rows that see no key come
+    out 0, ``_finalize``)."""
+    nq, nk = -(-sq // block_q), -(-sk // block_k)
+    first_row = np.arange(nq)[:, None] * block_q + (sk - sq)
+    first_col = np.arange(nk)[None, :] * block_k
+    last_col = first_col + block_k - 1
+    live = np.ones((nq, nk), bool)
+    masked = np.broadcast_to(last_col >= sk, (nq, nk))
+    if causal:
+        # row i of the un-padded q keeps columns <= i + (sk - sq)
+        live = first_col <= first_row + block_q - 1
+        live[:, 0] = True
+        masked = masked | (last_col > first_row)
+    return live, live & masked
+
+
+class TilePlan(NamedTuple):
+    block_q: int
+    block_k: int
+    live: int           # tiles the forward kernel computes
+    masked: int         # of those, the ones the diagonal or the ragged
+    #                     end crosses (a count: the kernel masks every tile,
+    #                     which costs it nothing)
+    padded_rows: int    # q rows past ``sq``
+
+
+@functools.lru_cache(maxsize=None)
+def tile_plan(sq: int, sk: int, block_q: int = 1024, block_k: int = 1024,
+              causal: bool = True) -> TilePlan:
+    """The forward kernel's schedule for ``sq`` queries over ``sk`` keys
+    under the caller's bounds on the blocks: a pure function of shapes,
+    which ``flash_attention`` itself uses."""
+    bq, nq = _cut(sq, block_q)
+    bk, _ = _cut(sk, block_k)
+    live, masked = _tiles(sq, sk, bq, bk, causal)
+    return TilePlan(bq, bk, int(live.sum()), int(masked.sum()),
+                    nq * bq - sq)
+
+
+def _fwd_kernel(cfg: _Config, tabled: bool, *refs):
+    """One live tile a grid step.  ``tabled``: two tables give a step its
+    tile, and the next step's column says whether this one ends its row
+    block; else the walk is the grid itself (row block ``t``, its one
+    tile first and last)."""
+    t = pl.program_id(2)
+    if not tabled:
+        qi, ki, last = t, 0, True
+    else:
+        qi_ref, ki_ref, *refs = refs
+        qi, ki, last = qi_ref[t], ki_ref[t], ki_ref[t + 1] == 0
     if cfg.use_segs:
         (q_ref, k_ref, v_ref, qseg_ref, kseg_ref,
          o_ref, lse_ref, m_scr, l_scr, acc_scr) = refs
     else:
         (q_ref, k_ref, v_ref,
          o_ref, lse_ref, m_scr, l_scr, acc_scr) = refs
-    qi = pl.program_id(2)
-    ki = pl.program_id(3)
 
     @pl.when(ki == 0)
     def _init():
@@ -98,45 +162,45 @@ def _fwd_kernel(cfg: _Config, nk: int, *refs):
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    live = _causal_block_live(cfg, qi, ki) if cfg.causal else True
+    # inputs stay in their storage dtype (bf16): the MXU multiplies in
+    # bf16 with fp32 accumulation via preferred_element_type — casting
+    # to f32 first would force ~4x-slower fp32 MXU passes
+    q = q_ref[0, 0]                               # [bq, d]
+    k = k_ref[0, 0]                               # [bk, d]
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ) * cfg.scale                                 # [bq, bk]
+    s = _block_mask(cfg, qi, ki, s)
+    if cfg.use_segs:
+        s = _seg_mask(qseg_ref[0], kseg_ref[0], s)
 
-    @pl.when(live)
-    def _compute():
-        # inputs stay in their storage dtype (bf16): the MXU multiplies in
-        # bf16 with fp32 accumulation via preferred_element_type — casting
-        # to f32 first would force ~4x-slower fp32 MXU passes
-        q = q_ref[0, 0]                               # [bq, d]
-        k = k_ref[0, 0]                               # [bk, d]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * cfg.scale                                 # [bq, bk]
-        s = _block_mask(cfg, qi, ki, s)
-        if cfg.use_segs:
-            s = _seg_mask(qseg_ref[0], kseg_ref[0], s)
+    m_prev = m_scr[:, :1]                         # [bq, 1]
+    m_cur = jnp.max(s, axis=-1, keepdims=True)
+    m_new = jnp.maximum(m_prev, m_cur)
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)                        # [bq, bk]
+    l_new = alpha * l_scr[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
 
-        m_prev = m_scr[:, :1]                         # [bq, 1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)                        # [bq, bk]
-        l_new = alpha * l_scr[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
+    v = v_ref[0, 0]
+    pv = jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    acc_scr[:] = acc_scr[:] * alpha + pv
+    m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+    l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
-        v = v_ref[0, 0]
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        acc_scr[:] = acc_scr[:] * alpha + pv
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
-
-    @pl.when(ki == nk - 1)
+    @pl.when(last)
     def _finalize():
-        l = l_scr[:, :1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_scr[:, :1] + jnp.log(l_safe)  # [bq, 1]
+        # a row that saw no key (causal with more queries than keys) still
+        # holds the initial maximum, and its sums are of masked scores:
+        # it comes out 0 with an empty logsumexp, as if never computed
+        m, l = m_scr[:, :1], l_scr[:, :1]
+        seen = m > NEG_INF
+        o_ref[0, 0] = (acc_scr[:] / jnp.where(seen, l, jnp.inf)
+                       ).astype(o_ref.dtype)
+        lse_ref[0, 0] = jnp.where(seen, m + jnp.log(l), NEG_INF)  # [bq, 1]
 
 
 def _fwd(cfg: _Config, q, k, v, q_seg, k_seg):
@@ -146,15 +210,29 @@ def _fwd(cfg: _Config, q, k, v, q_seg, k_seg):
     b, hq, sq_p, d = q.shape
     _, hk, sk_p, _ = k.shape
     dv = v.shape[-1]
-    nq = sq_p // cfg.block_q
-    nk = sk_p // cfg.block_k
-    grid = (b, hq, nq, nk)
+    # the walk: the live tiles row block by row block, as two tables the
+    # index maps and the body read by grid step (one entry past the end,
+    # so the last step sees its row block end too)
+    live, _ = _tiles(cfg.q_len, cfg.kv_len, cfg.block_q, cfg.block_k,
+                     cfg.causal)
+    qi_tab, ki_tab = np.nonzero(live)
+    if sk_p == cfg.block_k:
+        # one column block: the plain grid over row blocks, whose indices
+        # the compiler knows (a single tile runs a fifth faster so than
+        # through the tables)
+        tables = []
+        row, col = (lambda t: t), (lambda t: 0)
+    else:
+        tables = [jnp.asarray(np.append(tab, 0), jnp.int32)
+                  for tab in (qi_tab, ki_tab)]
+        row = lambda t, qi_ref, ki_ref: qi_ref[t]  # noqa: E731
+        col = lambda t, qi_ref, ki_ref: ki_ref[t]  # noqa: E731
 
-    def qmap(bi, hi, qi, ki):
-        return (bi, hi, qi, 0)
+    def qmap(bi, hi, *at):
+        return (bi, hi, row(*at), 0)
 
-    def kvmap(bi, hi, qi, ki):
-        return (bi, hi // cfg.group, ki, 0)
+    def kvmap(bi, hi, *at):
+        return (bi, hi // cfg.group, col(*at), 0)
 
     in_specs = [
         pl.BlockSpec((1, 1, cfg.block_q, d), qmap),
@@ -167,9 +245,9 @@ def _fwd(cfg: _Config, q, k, v, q_seg, k_seg):
         # (1, block) satisfy the TPU (8, 128) tiling rule.
         in_specs += [
             pl.BlockSpec((1, 1, cfg.block_q),
-                         lambda bi, hi, qi, ki: (bi, 0, qi)),
+                         lambda bi, hi, *at: (bi, 0, row(*at))),
             pl.BlockSpec((1, 1, cfg.block_k),
-                         lambda bi, hi, qi, ki: (bi, 0, ki)),
+                         lambda bi, hi, *at: (bi, 0, col(*at))),
         ]
         operands += [q_seg, k_seg]
 
@@ -181,27 +259,28 @@ def _fwd(cfg: _Config, q, k, v, q_seg, k_seg):
     ]
     out_specs = [
         pl.BlockSpec((1, 1, cfg.block_q, dv), qmap),
-        pl.BlockSpec((1, 1, cfg.block_q, 1),
-                     lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+        pl.BlockSpec((1, 1, cfg.block_q, 1), qmap),
     ]
     o, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, cfg, nk),
+        functools.partial(_fwd_kernel, cfg, bool(tables)),
         name="flash_fwd",
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(tables),
+            grid=(b, hq, len(qi_tab)),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=[
+                pltpu.VMEM((cfg.block_q, 128), jnp.float32),
+                pltpu.VMEM((cfg.block_q, 128), jnp.float32),
+                pltpu.VMEM((cfg.block_q, dv), jnp.float32),
+            ],
+        ),
         out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((cfg.block_q, 128), jnp.float32),
-            pltpu.VMEM((cfg.block_q, 128), jnp.float32),
-            pltpu.VMEM((cfg.block_q, dv), jnp.float32),
-        ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=cfg.interpret,
-    )(*operands)
+    )(*tables, *operands)
     return o, lse
 
 
@@ -504,10 +583,11 @@ def flash_attention(
     if interpret is None:
         interpret = kernels.default_interpret()
 
-    block_q = min(block_q, max(128, 1 << (sq - 1).bit_length()))
-    block_k = min(block_k, max(128, 1 << (sk - 1).bit_length()))
-    sq_p = ((sq + block_q - 1) // block_q) * block_q
-    sk_p = ((sk + block_k - 1) // block_k) * block_k
+    # the caller's blocks are upper bounds: each length is cut into the
+    # fewest equal blocks under its bound and padded to them alone
+    plan = tile_plan(sq, sk, block_q, block_k, causal)
+    block_q, block_k = plan.block_q, plan.block_k
+    sq_p, sk_p = -(-sq // block_q) * block_q, -(-sk // block_k) * block_k
 
     cfg = _Config(
         causal=causal, scale=float(softmax_scale), block_q=block_q,

@@ -119,7 +119,7 @@ import functools
 import os
 import threading
 import time
-from typing import Callable, List, Optional, Sequence
+from typing import Callable, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -128,6 +128,7 @@ import numpy as np
 from ..analysis import sanitizers
 from ..config import ModelConfig
 from ..generation.sampling import NEG_INF
+from ..kernels.flash_attention import tile_plan
 from ..kernels.mamba_step import heads_per_step
 from ..models import model as model_lib
 from ..obs import compile as obs_compile
@@ -1274,6 +1275,9 @@ class ServingEngine:
         if self._latent:
             self._prefill_arg["attn"] = "mla_expanded"
             self._step_arg["attn"] = "mla_absorbed"
+        # a bucket's ``flash_tiles`` on its whole-prompt prefill spans, by
+        # padded length (``_flash_tiles_arg``)
+        self._flash_tiles: Dict[int, dict] = {}
         self._admit_count = 0        # this iteration's admissions
         self._admit_tokens = 0       # and their prompt tokens
         # the decode step decides for itself whether it reads KV
@@ -2075,6 +2079,7 @@ class ServingEngine:
         # them with base-model (or other-adapter) requests would be
         # numerically wrong in both directions.
         lease = rec_small = None
+        flash_arg = {}      # a whole-prompt prefill's alone
         if (self.prefix_cache is not None and not req.return_logprobs
                 and req.adapter_id is None):
             t_pm = time.perf_counter()
@@ -2123,6 +2128,7 @@ class ServingEngine:
         else:
             padded = -(-plen // bucket) * bucket
             padded = min(padded, self.config.max_seq_len)
+            flash_arg = self._flash_tiles_arg(padded)
             tokens = np.zeros((1, padded), np.int32)
             tokens[0, :plen] = req.prompt
             with device_annotation("prefill"):
@@ -2170,7 +2176,7 @@ class ServingEngine:
                        args={"prompt_len": plen, "padded": padded,
                              "cached_tokens": lease.tokens if lease else 0,
                              "iter": self._iter, **self._experts_arg,
-                             **self._prefill_arg})
+                             **self._prefill_arg, **flash_arg})
         if self._counts_ssm:
             self.metrics.add_ssm_positions("prefill", plen)
         self._admit_count += 1
@@ -2258,6 +2264,25 @@ class ServingEngine:
                   "sampling": inflight.sampling,
                   "pipelined": self.config.pipeline_decode,
                   **self._experts_arg})
+
+    def _flash_tiles_arg(self, padded: int) -> dict:
+        """What ``flash_fwd`` does for a whole-prompt prefill of ``padded``
+        rows, ``"live/masked/padded_rows"``: the tiles it computes, how
+        many of them the diagonal or the ragged end crosses and the rows
+        that are padding (kernels/flash_attention.py:tile_plan), reckoned
+        once a bucket and kept beside it.  No such field where another
+        attention runs (another ``attention_impl``; a prompt of one row,
+        which goes the decode way)."""
+        arg = self._flash_tiles.get(padded)
+        if arg is None:
+            arg = {}
+            if self.cfg.attention_impl == "flash" and padded > 1:
+                plan = tile_plan(padded, padded, self.cfg.flash_block_q,
+                                 self.cfg.flash_block_k)
+                arg = {"flash_tiles":
+                       f"{plan.live}/{plan.masked}/{plan.padded_rows}"}
+            self._flash_tiles[padded] = arg
+        return arg
 
     @property
     def _decode_route(self) -> str:

@@ -10,15 +10,105 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from megatron_llm_tpu.kernels.flash_attention import flash_attention
+from megatron_llm_tpu.kernels.flash_attention import (
+    flash_attention,
+    tile_plan,
+)
 from megatron_llm_tpu.ops.attention import dot_product_attention
 
 
-def _rand_qkv(rng, b, sq, sk, hq, hk, d, dtype=jnp.float32):
+def _rand_qkv(rng, b, sq, sk, hq, hk, d, dtype=jnp.float32, dv=None):
     q = jnp.asarray(rng.standard_normal((b, sq, hq, d)), dtype)
     k = jnp.asarray(rng.standard_normal((b, sk, hk, d)), dtype)
-    v = jnp.asarray(rng.standard_normal((b, sk, hk, d)), dtype)
+    v = jnp.asarray(rng.standard_normal((b, sk, hk, dv or d)), dtype)
     return q, k, v
+
+
+@pytest.mark.parametrize("sq,sk,bound,causal,want", [
+    # one length under the default bound: (block, live, masked, padded)
+    (1024, 1024, 1024, True, (1024, 1024, 1, 1, 0)),
+    (1280, 1280, 1024, True, (640, 640, 3, 2, 0)),
+    (1408, 1408, 1024, True, (768, 768, 3, 2, 128)),
+    (1536, 1536, 1024, True, (768, 768, 3, 2, 0)),
+    (1792, 1792, 1024, True, (896, 896, 3, 2, 0)),
+    (2048, 2048, 1024, True, (1024, 1024, 3, 2, 0)),
+    (300, 300, 1024, True, (384, 384, 1, 1, 84)),
+    # 16 row blocks: 136 tiles on or under the diagonal, 16 on it
+    (16384, 16384, 1024, True, (1024, 1024, 136, 16, 0)),
+    # a wider bound: one tile where it fits, four row blocks at 16 384
+    (300, 300, 4096, True, (384, 384, 1, 1, 84)),
+    (1024, 1024, 4096, True, (1024, 1024, 1, 1, 0)),
+    (1280, 1280, 4096, True, (1280, 1280, 1, 1, 0)),
+    (1536, 1536, 4096, True, (1536, 1536, 1, 1, 0)),
+    (1792, 1792, 4096, True, (1792, 1792, 1, 1, 0)),
+    (1408, 1408, 4096, True, (1408, 1408, 1, 1, 0)),
+    (2048, 2048, 4096, True, (2048, 2048, 1, 1, 0)),
+    (16384, 16384, 4096, True, (4096, 4096, 10, 4, 0)),
+    # the diagonal off the corner: row i keeps columns <= i + 128, so
+    # both column blocks are live and only the second is crossed
+    (128, 256, 128, True, (128, 128, 2, 1, 0)),
+    # and the other way: row i keeps columns <= i - 128, so the diagonal
+    # crosses the second row block's one tile (the first row block sees
+    # nothing and still runs its first tile, masked, to write its rows)
+    (256, 128, 128, True, (128, 128, 2, 2, 0)),
+    # nothing causal: every tile, a mask on the ragged last column alone
+    (1280, 1280, 1024, False, (640, 640, 4, 0, 0)),
+    (1300, 1300, 1024, False, (768, 768, 4, 2, 236)),
+], ids=lambda v: str(v).replace(" ", ""))
+def test_tile_plan_counts_what_the_kernel_walks(sq, sk, bound, causal, want):
+    """The schedule is a pure function of shapes: the fewest equal blocks
+    under the bound, each a multiple of 128, so the padding is under 128
+    rows a block; ``live`` and ``masked`` are the counts by hand."""
+    plan = tile_plan(sq, sk, bound, bound, causal)
+    assert tuple(plan) == want
+    blocks = -(-sq // plan.block_q)
+    assert plan.block_q % 128 == 0 and plan.block_q <= max(bound, 128)
+    assert 0 <= plan.padded_rows < 128 * blocks
+    assert blocks == -(-sq // bound)      # no more blocks than the old cut
+    assert plan.masked <= plan.live <= blocks * -(-sk // plan.block_k)
+
+
+@pytest.mark.parametrize("sq,sk,hq,hk,d,dv,segs", [
+    (1280, 1280, 2, 1, 64, 64, False),     # blocks of 640
+    (1792, 1792, 2, 1, 64, 64, False),     # blocks of 896
+    (1408, 1408, 1, 1, 64, 64, False),     # 2 x 768 with 128 padded rows
+    (640, 1408, 2, 2, 64, 64, False),      # sk > sq: diagonal off the corner
+    (1280, 1280, 2, 1, 64, 64, True),      # packed documents over 4 tiles
+    (1536, 1536, 2, 2, 192, 128, False),   # a value width of its own
+], ids=["1280", "1792", "1408_padded", "sk_gt_sq", "segment_ids",
+        "192_128"])
+def test_forward_on_tiles_cut_from_the_length(rng, sq, sk, hq, hk, d, dv,
+                                              segs):
+    """Under the default bound of 1024 these lengths run in blocks of 640,
+    896 and 768: the tiles under and on the diagonal and at the ragged
+    end, none above it."""
+    q, k, v = _rand_qkv(rng, 1, sq, sk, hq, hk, d, dv=dv)
+    seg = None
+    if segs:
+        seg = jnp.asarray(np.repeat([[0, 1, 2, 3, 4]], sq // 5, 1))
+    out = flash_attention(q, k, v, causal=True, segment_ids=seg,
+                          interpret=True)
+    ref = dot_product_attention(q, k, v, causal=True, segment_ids=seg)
+    assert out.shape == (1, sq, hq, dv)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("bound", [128, 256],
+                         ids=["a_dead_row_block", "dead_rows_in_a_live_tile"])
+def test_queries_that_see_no_key_come_out_zero(rng, bound):
+    """Causal with more queries than keys: the first ``sq - sk`` queries
+    lie before every key.  Their row block's first tile runs all the same
+    (every output block is written) and they come out 0; the rest is the
+    square problem."""
+    sq, sk = 256, 128
+    q, k, v = _rand_qkv(rng, 1, sq, sk, 2, 1, 64)
+    out = flash_attention(q, k, v, causal=True, block_q=bound,
+                          block_k=bound, interpret=True)
+    ref = dot_product_attention(q[:, sq - sk:], k, v, causal=True)
+    np.testing.assert_array_equal(np.asarray(out[:, :sq - sk]), 0.0)
+    np.testing.assert_allclose(np.asarray(out[:, sq - sk:]),
+                               np.asarray(ref), atol=2e-5, rtol=2e-5)
 
 
 @pytest.mark.parametrize(
@@ -58,14 +148,17 @@ def test_segment_ids_match_reference(rng):
                                atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.parametrize("hq,hk", [(4, 4), (8, 2)])
-def test_gradients_match_reference(rng, hq, hk):
-    b, s, d = 1, 256, 64
+@pytest.mark.parametrize("hq,hk,s,block", [
+    (4, 4, 256, 128), (8, 2, 256, 128),
+    (2, 1, 1280, 1024),     # no multiple of its old block: 2 x 640
+], ids=["mha", "gqa", "1280_at_640"])
+def test_gradients_match_reference(rng, hq, hk, s, block):
+    b, d = 1, 64
     q, k, v = _rand_qkv(rng, b, s, s, hq, hk, d)
 
     def loss_flash(q, k, v):
-        o = flash_attention(q, k, v, causal=True, block_q=128, block_k=128,
-                            interpret=True)
+        o = flash_attention(q, k, v, causal=True, block_q=block,
+                            block_k=block, interpret=True)
         return jnp.sum(o * jnp.cos(jnp.arange(o.size).reshape(o.shape)))
 
     def loss_ref(q, k, v):

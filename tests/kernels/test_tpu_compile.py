@@ -148,6 +148,29 @@ def test_flash_attention_forward_at_a_value_width_of_its_own(topo):
     assert text.count('custom_call_target="tpu_custom_call"') == 1
 
 
+@pytest.mark.parametrize("s,heads,kv,d,dv,block,live", [
+    (1280, 71, 1, 64, 64, 640, 3),          # a Falcon-7B bucket of 1280
+    (16384, 32, 32, 192, 128, 1024, 136),   # latent attention's longest
+], ids=["1280_d64", "16384_192_128"])
+def test_flash_attention_tiles_cut_from_the_length(topo, s, heads, kv, d,
+                                                   dv, block, live):
+    """The traced program holds the forward kernel's blocks and its walk
+    (a Mosaic kernel's body is bytecode in the lowered text): 1280 rows
+    run as 2 x 640 with no ``pad`` of ``q`` (they ran as 2048), 16 384
+    rows as 1024s over the 136 live tiles of 256."""
+    args = (_sds((1, s, heads, d)), _sds((1, s, kv, d)),
+            _sds((1, s, kv, dv)))
+    fn = lambda q, k, v: flash_attention(q, k, v, interpret=False)  # noqa: E731
+    traced = str(jax.make_jaxpr(fn)(*args))
+    assert f"grid=(1, {heads}, {live})" in traced
+    blocks = set(re.findall(r"Ref\{bf16\[1,1,(\d+),(\d+)\]\}", traced))
+    assert blocks == {(str(block), str(d)), (str(block), str(dv))}
+    assert " pad[" not in traced
+    text = _compile(fn, args, SingleDeviceSharding(topo.devices[0]))
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert " pad(" not in text
+
+
 def test_mla_decode(topo):
     """The latent walk at the kanana cell's shapes: 44 slots of up to 132
     blocks of 128 rows, 32 heads on one row of 512 + 64 a position, a

@@ -717,3 +717,44 @@ def test_collections_recorded_from_inside_the_engines_own_spans(model,
     # (every result came back, or ``_serve`` had raised; the ring is
     # overrun by the collections, so early spans are gone)
     assert _engine.metrics.snapshot()["completed"] == 6
+
+
+# --- the forward attention kernel's schedule on a prefill span -----------------
+
+@pytest.mark.parametrize("impl,bound,prompt_len", [
+    ("flash", 128, 100),    # a bucket of 128: one tile, masked
+    ("flash", 128, 130),    # a bucket of 256 under a bound of 128: 3 of 4
+    ("flash", 1024, 130),   # the same bucket in one tile
+    ("dot", 128, 130),      # no such kernel, no such field
+], ids=["one_tile", "two_row_blocks", "wide_bound", "dot"])
+def test_a_prefill_span_carries_its_buckets_tile_plan(impl, bound,
+                                                      prompt_len):
+    """A whole-prompt prefill's span says what ``flash_fwd`` did for its
+    bucket, ``flash_tiles = "live/masked/padded_rows"``, and that is
+    ``tile_plan`` of the padded length under the configuration's bounds."""
+    from megatron_llm_tpu.kernels.flash_attention import tile_plan
+
+    cfg = tiny_config(num_layers=1, vocab_size=256,
+                      make_vocab_size_divisible_by=8, attention_impl=impl,
+                      max_position_embeddings=384, flash_block_q=bound,
+                      flash_block_k=bound)
+    params = model_lib.init_params(jax.random.key(0), cfg)
+    prompt = [1 + i % 200 for i in range(prompt_len)]
+    _engine, events = _serve((cfg, params), [prompt, prompt[:prompt_len - 1]],
+                             2, max_seq_len=384, prefill_bucket=128,
+                             prefix_cache_blocks=0)
+    prefills = [e for e in events if e["name"] == "prefill"]
+    assert len(prefills) == 2
+    for pf in prefills:
+        padded = pf["args"]["padded"]
+        assert padded == -(-prompt_len // 128) * 128
+        if impl != "flash":
+            assert "flash_tiles" not in pf["args"]
+            continue
+        plan = tile_plan(padded, padded, bound, bound)
+        assert pf["args"]["flash_tiles"] == \
+            f"{plan.live}/{plan.masked}/{plan.padded_rows}"
+    if impl == "flash":
+        want = {(128, 100): "1/1/0", (128, 130): "3/2/0",
+                (1024, 130): "1/1/0"}[bound, prompt_len]
+        assert prefills[0]["args"]["flash_tiles"] == want
