@@ -37,6 +37,11 @@ BLOCK_KINDS = ("full", "linear", "ssm", "attention", "mamba", "mlp")
 KV_KINDS = ("full", "attention")
 MAMBA_KINDS = ("ssm", "mamba")
 FFN_KINDS = ("full", "linear", "ssm", "mlp")
+# the further block kinds of a stack of runs (ModelConfig.layer_runs),
+# beside "full", each a mixer and the MLP: a Mamba-1 mixer, attention over
+# a window kept as a ring a slot, a gated memory unit, attention with
+# another layer's keys and values
+RUN_KINDS = ("ssm1", "window", "gmu", "cross")
 
 
 class AttnMaskType:
@@ -307,11 +312,39 @@ class ModelConfig:
     moe_first_dense_layers: int = 0
     moe_dense_ffn_size: int = 0
     moe_n_group: int = 1
+    # A stack of runs: ``((period, times), ...)``, each run a period of
+    # block kinds scanned ``times`` times, one run after the other
+    # (models/transformer.py:scan_runs_cached).  ``layer_pattern`` is then
+    # the whole stack written out, one period of num_layers kinds, which
+    # is what every count of layers reads.  A run hands two things to the
+    # runs behind it: the last "ssm1" layer's memory (its scan's output
+    # before the gate, read by every "gmu" layer at the same position) and
+    # the one "full" layer's keys and values (read by every "cross"
+    # layer, which projects a query alone).
+    layer_runs: tuple = ()
+    # Differential attention (arXiv 2410.05258) as the attention part of
+    # the "full", "window" and "cross" kinds: adjacent query heads pair,
+    # adjacent key/value heads pair, query pair p reads key pair p // 2;
+    # each query head of a pair attends with its own key head over the
+    # pair's two value heads side by side (2 x head_dim wide), and the
+    # pair's output is RMSNorm(first - lambda * second) * (1 -
+    # lambda_init(layer)) (models/diff_attention.py).
+    diff_attention: bool = False
+    # keys a query of a "window" layer sees, its own among them
+    sliding_window: int = 0
+    # Mamba-1 geometry ("ssm1", models/mamba1.py): inner width, state
+    # columns a channel, convolution taps, the step's bottleneck
+    mamba1_inner: int = 0
+    mamba1_state_size: int = 16
+    mamba1_conv_kernel: int = 4
+    mamba1_dt_rank: int = 0
 
     def __post_init__(self):
         # a JSON list (checkpointed arguments, a benchmark's overrides):
         # the config is a static argument of every jitted step, so hashed
         object.__setattr__(self, "layer_pattern", tuple(self.layer_pattern))
+        object.__setattr__(self, "layer_runs", tuple(
+            (tuple(period), int(times)) for period, times in self.layer_runs))
 
     @property
     def kv_heads(self) -> int:
@@ -371,6 +404,32 @@ class ModelConfig:
         return sum(kind in MAMBA_KINDS for kind in self.layer_kinds)
 
     @property
+    def mamba1_layers(self) -> int:
+        """Layers that keep a Mamba-1 state (serving/slots.py)."""
+        return self.layer_kinds.count("ssm1")
+
+    @property
+    def window_layers(self) -> int:
+        """Layers that keep a ring of keys and values a slot."""
+        return self.layer_kinds.count("window")
+
+    @property
+    def cross_layers(self) -> int:
+        """Layers that attend with the one "full" layer's keys and
+        values: readers of the pool that write nothing to it."""
+        return self.layer_kinds.count("cross")
+
+    @property
+    def v_heads(self) -> int:
+        """Value heads a position: under differential attention the two
+        value heads of a pair lie side by side as one, twice as wide."""
+        return self.kv_heads // 2 if self.diff_attention else self.kv_heads
+
+    @property
+    def v_head_width(self) -> int:
+        return 2 * self.head_dim if self.diff_attention else self.head_dim
+
+    @property
     def moe_layer_ids(self) -> tuple:
         """The layers that route: every layer with a feed-forward part,
         where the model has experts."""
@@ -419,7 +478,9 @@ class ModelConfig:
             self.hidden_size % self.num_attention_heads == 0)
         assert self.num_attention_heads % self.kv_heads == 0
         if self.layer_pattern:
-            assert set(self.layer_pattern) <= set(BLOCK_KINDS), (
+            assert set(self.layer_pattern) <= set(
+                BLOCK_KINDS + RUN_KINDS if self.layer_runs
+                else BLOCK_KINDS), (
                 f"unknown block kind in {self.layer_pattern!r}")
             assert self.scanned_layers % len(self.layer_pattern) == 0, (
                 f"num_layers {self.num_layers} is not whole periods of "
@@ -427,6 +488,8 @@ class ModelConfig:
             assert (self.linear_num_value_heads
                     % self.linear_num_key_heads == 0)
             assert self.mamba_num_heads % self.mamba_n_groups == 0
+        if self.layer_runs:
+            self._validate_runs()
         if self.kv_lora_rank:
             self._validate_latent_attention()
         else:
@@ -481,6 +544,53 @@ class ModelConfig:
         assert self.quantize_matmuls in ("none", "int8"), (
             f"unknown quantize_matmuls {self.quantize_matmuls!r}")
         return self
+
+    def _validate_runs(self) -> None:
+        """A stack of runs as models/transformer.py carries it."""
+        flat = tuple(kind for period, times in self.layer_runs
+                     for kind in period * times)
+        assert flat == self.layer_pattern and len(flat) == self.num_layers, (
+            "layer_runs written out is layer_pattern, num_layers kinds")
+        kinds = set(flat)
+        assert kinds <= set(RUN_KINDS) | {"full"}, (
+            f"a stack of runs holds {RUN_KINDS} and \"full\": {kinds}")
+        assert self.diff_attention and self.kv_heads % 2 == 0 and (
+            self.num_attention_heads == 2 * self.kv_heads), (
+            "a stack of runs attends differentially: query heads pair, "
+            "key heads pair, two query pairs a key pair")
+        assert (self.position_embedding_type == PositionEmbeddingType.NONE
+                and self.num_experts == 0 and not self.parallel_attn
+                and self.kv_cache_quant == "none"
+                and self.context_parallel_axis is None
+                and not self.qk_norm and not self.attn_output_gate), (
+            "a stack of runs: no rotation, experts, parallel block, 8-bit "
+            "K/V, context parallelism, q/k norm or output gate")
+        if "window" in kinds:
+            assert self.sliding_window > 0, "\"window\" needs sliding_window"
+        if "ssm1" in kinds:
+            assert self.mamba1_inner > 0 and self.mamba1_dt_rank > 0
+        if "gmu" in kinds:
+            assert "ssm1" in flat[:flat.index("gmu")], (
+                "a gated memory unit reads an earlier \"ssm1\" layer's "
+                "memory")
+        if "cross" in kinds:
+            assert flat.count("full") == 1 and (
+                flat.index("full") < flat.index("cross")), (
+                "\"cross\" layers read the keys and values of the one "
+                "\"full\" layer before them")
+
+    @property
+    def row_cut_layer(self) -> Optional[int]:
+        """The layer at which a prefill may cut the rows it carries on to
+        the one whose logits are asked for: the "full" layer whose keys
+        and values every later layer reads, where no later layer keeps
+        anything of the positions in between ("gmu", "cross" alone).
+        None: no such layer."""
+        kinds = self.layer_kinds
+        if "cross" not in kinds:
+            return None
+        at = kinds.index("full")
+        return at if set(kinds[at + 1:]) <= {"gmu", "cross"} else None
 
     def _validate_latent_attention(self) -> None:
         """Latent attention as models/mla.py carries it; what it does
@@ -1220,6 +1330,60 @@ def deepseek_v3_config(size: str = "kanana-2-30b-a3b",
     return ModelConfig(**base).validate()
 
 
+def phi4flash_config(size: str = "mini-flash-reasoning",
+                     **overrides) -> ModelConfig:
+    """``model_type: phi4flash`` (SambaY, arXiv 2507.06607): a decoder of
+    Mamba-1 and window-attention layers in turn, one full-attention layer
+    whose keys and values are the only ones cached a position, and behind
+    it a second decoder of gated memory units (gated by the last Mamba-1
+    layer's memory at the same position) and cross-attention layers that
+    read the full layer's keys and values.  Every layer is a mixer and a
+    gated SiLU MLP under a LayerNorm (weight and bias) each; attention is
+    differential (``diff_attention``), biased on its projections; no
+    position rotation or table; tied head.  Served only.
+
+    ``mini-flash-reasoning`` is the published 32-layer model: three runs,
+    8 x (Mamba-1, window 512), (Mamba-1, full), 7 x (gated memory unit,
+    cross-attention).  ``layer_runs`` as an override rebuilds
+    ``layer_pattern`` and ``num_layers`` from the runs (a test's or a
+    rehearsal's small stack)."""
+    runs = ((("ssm1", "window"), 8), (("ssm1", "full"), 1),
+            (("gmu", "cross"), 7))
+    base = dict(
+        norm_type="layernorm",
+        norm_eps=1e-5,
+        activation="swiglu",
+        position_embedding_type=PositionEmbeddingType.NONE,
+        use_bias=False,
+        qkv_bias=True,
+        tie_embed_logits=True,
+        use_scaled_init=False,
+        diff_attention=True,
+        recompute="none",
+        seq_length=4096,
+    )
+    sizes = {
+        "mini-flash-reasoning": dict(
+            hidden_size=2560, num_attention_heads=40, num_kv_heads=20,
+            kv_channels=64, ffn_hidden_size=10240, sliding_window=512,
+            mamba1_inner=5120, mamba1_state_size=16, mamba1_conv_kernel=4,
+            mamba1_dt_rank=160, vocab_size=200064,
+            max_position_embeddings=262144),
+    }
+    base.update(sizes[size])
+    base.update(overrides)
+    runs = tuple((tuple(p), int(n)) for p, n in base.pop("layer_runs", runs))
+    flat = tuple(kind for period, times in runs for kind in period * times)
+    if "layer_runs" not in overrides and base.get(
+            "num_layers", len(flat)) != len(flat):
+        raise ValueError(
+            f"num_layers {base['num_layers']}: the published stack is "
+            f"{len(flat)} layers in three runs; another depth is another "
+            "layer_runs")
+    base.update(layer_runs=runs, layer_pattern=flat, num_layers=len(flat))
+    return ModelConfig(**base).validate()
+
+
 def gpt_config(size: str = "345m", **overrides) -> ModelConfig:
     """GPT-2/3 style: learned absolute positions, LayerNorm, gelu, tied
     embeddings, biases (reference: megatron/model/gpt_model.py)."""
@@ -1281,6 +1445,8 @@ PRESETS = {
     "qwen3-next-80b-a3b": lambda: qwen3_next_config("80b-a3b"),
     "granite-4.0-h-micro": lambda: granite_hybrid_config("4.0-h-micro"),
     "kanana-2-30b-a3b": lambda: deepseek_v3_config("kanana-2-30b-a3b"),
+    "phi-4-mini-flash-reasoning": lambda: phi4flash_config(
+        "mini-flash-reasoning"),
     "tiny": tiny_config,
 }
 

@@ -54,6 +54,12 @@ class _Config(NamedTuple):
     q_len: int          # un-padded q length
     use_segs: bool
     interpret: bool
+    # keys a query sees under ``causal``, its own among them (0: all
+    # before it); forward only
+    window: int = 0
+    # q heads a value head where the value has fewer heads than the key
+    # (0: as many, ``group``); forward only
+    v_group: int = 0
 
 
 def _block_mask(cfg: _Config, qi, ki, s_block):
@@ -66,6 +72,9 @@ def _block_mask(cfg: _Config, qi, ki, s_block):
         # query position i (0-based in the un-padded q) attends to kv
         # positions <= i + (kv_len - q_len): standard cross-length offset.
         keep = jnp.logical_and(keep, cols <= rows + (cfg.kv_len - cfg.q_len))
+    if cfg.window:
+        keep = jnp.logical_and(
+            keep, cols > rows + (cfg.kv_len - cfg.q_len - cfg.window))
     return jnp.where(keep, s_block, NEG_INF)
 
 
@@ -94,13 +103,17 @@ def _cut(length: int, bound: int):
     return -(-length // (128 * n)) * 128, n
 
 
-def _tiles(sq: int, sk: int, block_q: int, block_k: int, causal: bool):
+def _tiles(sq: int, sk: int, block_q: int, block_k: int, causal: bool,
+           window: int = 0):
     """The score matrix's tiles as two ``[nq, nk]`` boolean arrays:
     which are ``live`` (hold a position that is kept) and which of those
     are ``masked`` (hold one that is not: the diagonal crosses them, or
     ``sk`` ends inside them).  A row block's first tile always counts as
     live, so every output block is written (rows that see no key come
-    out 0, ``_finalize``)."""
+    out 0, ``_finalize``).  Under a ``window`` (causal: a query keeps the
+    ``window`` keys up to its own) the tiles wholly behind the band are
+    not live either, and the band's lower edge masks the tiles it
+    crosses; every row sees itself, so no first tile is forced."""
     nq, nk = -(-sq // block_q), -(-sk // block_k)
     first_row = np.arange(nq)[:, None] * block_q + (sk - sq)
     first_col = np.arange(nk)[None, :] * block_k
@@ -112,6 +125,11 @@ def _tiles(sq: int, sk: int, block_q: int, block_k: int, causal: bool):
         live = first_col <= first_row + block_q - 1
         live[:, 0] = True
         masked = masked | (last_col > first_row)
+    if window:
+        assert causal and sq <= sk, "a window is causal self-attention's"
+        last_row = first_row + block_q - 1
+        live = (first_col <= last_row) & (last_col > first_row - window)
+        masked = masked | (first_col <= last_row - window)
     return live, live & masked
 
 
@@ -127,13 +145,13 @@ class TilePlan(NamedTuple):
 
 @functools.lru_cache(maxsize=None)
 def tile_plan(sq: int, sk: int, block_q: int = 1024, block_k: int = 1024,
-              causal: bool = True) -> TilePlan:
+              causal: bool = True, window: int = 0) -> TilePlan:
     """The forward kernel's schedule for ``sq`` queries over ``sk`` keys
     under the caller's bounds on the blocks: a pure function of shapes,
     which ``flash_attention`` itself uses."""
     bq, nq = _cut(sq, block_q)
     bk, _ = _cut(sk, block_k)
-    live, masked = _tiles(sq, sk, bq, bk, causal)
+    live, masked = _tiles(sq, sk, bq, bk, causal, window)
     return TilePlan(bq, bk, int(live.sum()), int(masked.sum()),
                     nq * bq - sq)
 
@@ -149,6 +167,12 @@ def _fwd_kernel(cfg: _Config, tabled: bool, *refs):
     else:
         qi_ref, ki_ref, *refs = refs
         qi, ki, last = qi_ref[t], ki_ref[t], ki_ref[t + 1] == 0
+    first = ki == 0
+    if tabled and cfg.window:
+        # a row block's walk starts behind column 0: its ends are where
+        # the row table changes (one entry past the end holds -1)
+        first = jnp.logical_or(t == 0, qi_ref[jnp.maximum(t - 1, 0)] != qi)
+        last = qi_ref[t + 1] != qi
     if cfg.use_segs:
         (q_ref, k_ref, v_ref, qseg_ref, kseg_ref,
          o_ref, lse_ref, m_scr, l_scr, acc_scr) = refs
@@ -156,7 +180,7 @@ def _fwd_kernel(cfg: _Config, tabled: bool, *refs):
         (q_ref, k_ref, v_ref,
          o_ref, lse_ref, m_scr, l_scr, acc_scr) = refs
 
-    @pl.when(ki == 0)
+    @pl.when(first)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
@@ -214,7 +238,7 @@ def _fwd(cfg: _Config, q, k, v, q_seg, k_seg):
     # index maps and the body read by grid step (one entry past the end,
     # so the last step sees its row block end too)
     live, _ = _tiles(cfg.q_len, cfg.kv_len, cfg.block_q, cfg.block_k,
-                     cfg.causal)
+                     cfg.causal, cfg.window)
     qi_tab, ki_tab = np.nonzero(live)
     if sk_p == cfg.block_k:
         # one column block: the plain grid over row blocks, whose indices
@@ -223,8 +247,9 @@ def _fwd(cfg: _Config, q, k, v, q_seg, k_seg):
         tables = []
         row, col = (lambda t: t), (lambda t: 0)
     else:
-        tables = [jnp.asarray(np.append(tab, 0), jnp.int32)
-                  for tab in (qi_tab, ki_tab)]
+        tables = [jnp.asarray(np.append(tab, end), jnp.int32)
+                  for tab, end in ((qi_tab, -1 if cfg.window else 0),
+                                   (ki_tab, 0))]
         row = lambda t, qi_ref, ki_ref: qi_ref[t]  # noqa: E731
         col = lambda t, qi_ref, ki_ref: ki_ref[t]  # noqa: E731
 
@@ -234,10 +259,13 @@ def _fwd(cfg: _Config, q, k, v, q_seg, k_seg):
     def kvmap(bi, hi, *at):
         return (bi, hi // cfg.group, col(*at), 0)
 
+    def vmap(bi, hi, *at):
+        return (bi, hi // cfg.v_group, col(*at), 0)
+
     in_specs = [
         pl.BlockSpec((1, 1, cfg.block_q, d), qmap),
         pl.BlockSpec((1, 1, cfg.block_k, d), kvmap),
-        pl.BlockSpec((1, 1, cfg.block_k, dv), kvmap),
+        pl.BlockSpec((1, 1, cfg.block_k, dv), vmap if cfg.v_group else kvmap),
     ]
     operands = [q, k, v]
     if cfg.use_segs:
@@ -521,6 +549,27 @@ def _flash_bwd(cfg, res, do):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _flash_forward_only(cfg: _Config, q, k, v, q_seg, k_seg):
+    """A window, or a value with fewer heads than the key: the forward
+    kernel alone."""
+    return _fwd(cfg, q, k, v, q_seg, k_seg)[0]
+
+
+def _forward_only_fwd(cfg, q, k, v, q_seg, k_seg):
+    return _flash_forward_only(cfg, q, k, v, q_seg, k_seg), None
+
+
+def _forward_only_bwd(cfg, _res, _do):
+    raise NotImplementedError(
+        "flash_attention with a window or fewer value heads than key "
+        "heads runs forward only: the backward kernels walk the causal "
+        "triangle of one head count")
+
+
+_flash_forward_only.defvjp(_forward_only_fwd, _forward_only_bwd)
+
+
 def _pad_to(x, length: int, axis: int):
     if x.shape[axis] == length:
         return x
@@ -565,9 +614,13 @@ def prefill_block_sizes(cfg, vmem_budget_bytes: int = 8 * 1024 * 1024):
 def flash_attention(
     q: jax.Array,  # [b, sq, n_heads, d]
     k: jax.Array,  # [b, sk, kv_heads, d]
-    v: jax.Array,  # [b, sk, kv_heads, dv]: dv != d runs forward only
+    v: jax.Array,  # [b, sk, kv_heads, dv]: dv != d runs forward only;
+    #                fewer heads than k (a divisor), each shared by
+    #                consecutive key heads: forward only
     *,
     causal: bool = True,
+    window: int = 0,  # causal: a query keeps this many keys, its own
+    #                   among them (0: all before it); forward only
     segment_ids: Optional[jax.Array] = None,  # [b, s] (sq == sk required)
     softmax_scale: Optional[float] = None,
     block_q: int = 1024,
@@ -585,7 +638,7 @@ def flash_attention(
 
     # the caller's blocks are upper bounds: each length is cut into the
     # fewest equal blocks under its bound and padded to them alone
-    plan = tile_plan(sq, sk, block_q, block_k, causal)
+    plan = tile_plan(sq, sk, block_q, block_k, causal, window)
     block_q, block_k = plan.block_q, plan.block_k
     sq_p, sk_p = -(-sq // block_q) * block_q, -(-sk // block_k) * block_k
 
@@ -594,6 +647,11 @@ def flash_attention(
         block_k=block_k, group=hq // hk, kv_len=sk, q_len=sq,
         use_segs=segment_ids is not None, interpret=bool(interpret),
     )
+    hv = v.shape[2]
+    if window or hv != hk:
+        assert hk % hv == 0, f"{hk} key heads over {hv} value heads"
+        cfg = cfg._replace(window=int(window),
+                           v_group=hq // hv if hv != hk else 0)
 
     # [b, s, h, d] → [b, h, s, d]; pad seq to block multiples.
     qt = _pad_to(jnp.transpose(q, (0, 2, 1, 3)), sq_p, 2)
@@ -606,7 +664,9 @@ def flash_attention(
     else:
         q_seg = k_seg = jnp.zeros((1, 1, 1), jnp.int32)  # ignored
 
-    if v.shape[-1] != d:
+    if cfg.window or cfg.v_group:
+        o = _flash_forward_only(cfg, qt, kt, vt, q_seg, k_seg)
+    elif v.shape[-1] != d:
         # a value narrower than the query and key (latent attention's
         # expanded form): the forward kernel alone, which is as wide in
         # its second product and its output as v; the backward kernels

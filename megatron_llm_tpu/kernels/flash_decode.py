@@ -226,7 +226,7 @@ def _scale_block_spec(block_k):
 
 
 def _attend_blocks(scale, col0, n_valid, q, k, v, ks, vs,
-                   m_scr, l_scr, acc_scr, rows, *, kt: bool):
+                   m_scr, l_scr, acc_scr, rows, *, kt: bool, vt=None):
     """One online-softmax term over consecutive cache blocks: ``k``/``v``
     are lists of [block_k, d] rows, together the logical columns
     ``col0..`` (``kt``: [d, block_k], the block as the pool holds it at
@@ -236,9 +236,10 @@ def _attend_blocks(scale, col0, n_valid, q, k, v, ks, vs,
     the score columns (K) and the probability rows (V) — algebraically
     exact dequantization, int8 HBM traffic.  The softmax state is rows
     ``rows`` of the three scratches (one query group's, of the several a
-    grid step holds)."""
+    grid step holds).  ``vt``: the same for the value blocks where they
+    are of another width than the key's (None: as ``kt``)."""
     k_dims = (((1,), (0 if kt else 1,)), ((), ()))
-    v_dims = (((1,), (1 if kt else 0,)), ((), ()))
+    v_dims = (((1,), (1 if (kt if vt is None else vt) else 0,)), ((), ()))
     if ks is None:
         s = [jax.lax.dot_general(
             q, kj, k_dims, preferred_element_type=jnp.float32,
@@ -310,12 +311,29 @@ _WALK_VMEM_BYTES = 2 * 2**20
 _WALK_COLUMNS = 512
 
 
-def _walk_shape(kv_heads, block_k, d, itemsize, t):
+def _walk_shape(kv_heads, block_k, d, itemsize, t, packed: bool = False):
     """(KV heads a copy, pool blocks an iteration) of the paged walk, from
     what the trace sees: a pool block's bytes against the VMEM the walk
-    may hold, its rows against the columns of a term, the table's width."""
+    may hold, its rows against the columns of a term, the table's width.
+    ``packed`` (the key heads that share a value head packed into one,
+    ``_pack_shared``): half the columns a term, and as many heads a copy
+    as the same VMEM holds.  Measured on a v5e (PR 56), 64 slots of 1-6 k
+    rows at 40 heads on 20 key heads of 64 and 10 value heads of 128,
+    packed to 10 heads of 128, (heads, blocks) a call: (5, 8) 2.00 ms,
+    (10, 8) 1.95, (10, 4) 1.92, (10, 3) 2.09, (10, 2) 1.72 at twice this
+    VMEM, **(5, 2) 1.94**, (10, 1) 2.01, (2, 4) 2.61; a walk a key head,
+    unpacked, took 2.33 at its best, (10, 8) of its heads: there a pair's
+    two chains of mask, maxima and exp over two query rows each were
+    latency that wide terms paid less often; packed, the pair is one chain
+    and short terms keep the copies ahead.  (10, 2) is left where it is:
+    PERF.md, PR 56, says why."""
     half = _WALK_VMEM_BYTES // 4
     head_bytes = block_k * d * itemsize
+    if packed:
+        n = max(1, min(_WALK_COLUMNS // 2 // block_k, t))
+        fits = [g for g in range(1, kv_heads + 1)
+                if kv_heads % g == 0 and g * head_bytes * n <= half]
+        return (max(fits) if fits else 1), n
     kvg = max(g for g in range(1, kv_heads + 1)
               if kv_heads % g == 0 and (g == 1 or g * head_bytes <= half))
     n = min(half // (kvg * head_bytes), _WALK_COLUMNS // block_k, t)
@@ -324,7 +342,7 @@ def _walk_shape(kv_heads, block_k, d, itemsize, t):
 
 def _paged_walk_kernel(scale: float, n: int, block_k: int, int8: bool,
                        kt: bool, has_new: bool,
-                       len_ref, tbl_ref, lyr_ref, q_ref, *refs):
+                       len_ref, tbl_ref, lyr_ref, q_ref, *refs, vt=None):
     """One grid step a slot (and group of ``kvg`` KV heads): the walk over
     the row's live blocks is a loop in here, its trip count from the fill.
     ``refs``: the pool leaves in HBM — (k, v), or (k, k_scale, v,
@@ -338,7 +356,9 @@ def _paged_walk_kernel(scale: float, n: int, block_k: int, int8: bool,
     starts no copy and runs no iteration — with iteration ``c+1``'s copies
     already in flight, and attends them as ONE online-softmax term of
     ``n * block_k`` columns a KV head.  A copy is a whole pool block,
-    every KV head of the group at once, as it lies in HBM."""
+    every KV head of the group at once, as it lies in HBM.  ``vt`` says
+    whether the value blocks come transposed, as ``kt`` does for the
+    key's (None: as ``kt``)."""
     n_cache = 4 if int8 else 2
     hbm, rest = refs[:n_cache], refs[n_cache:]
     new_refs, rest = rest[:2 * has_new], rest[2 * has_new:]
@@ -400,7 +420,7 @@ def _paged_walk_kernel(scale: float, n: int, block_k: int, int8: bool,
                       for buf in bufs[1::2]] if int8 else [None, None]
             _attend_blocks(scale, c * n * block_k, fill, q_ref[0, h],
                            *blocks, *scales, m_scr, l_scr, acc_scr,
-                           pl.ds(h * g_pad, g_pad), kt=kt)
+                           pl.ds(h * g_pad, g_pad), kt=kt, vt=vt)
         return carry
 
     jax.lax.fori_loop(0, trips, step, 0)
@@ -414,6 +434,53 @@ def _paged_walk_kernel(scale: float, n: int, block_k: int, int8: bool,
         l = l_scr[rows, :1]
         o_ref[0, h] = (acc_scr[rows, :] / jnp.where(l == 0.0, 1.0, l)
                        ).astype(o_ref.dtype)
+
+
+def _pack_shared(q, leaves, new_rows):
+    """A value head shared by ``r`` consecutive key heads (differential
+    attention: a pair's two value heads side by side), as the plain walk
+    takes it: the ``r`` key heads become ONE head ``r`` times as wide.
+    Its queries are the ``r`` groups' rows, each with its own key head's
+    ``d`` columns and zeros in the others', so a row's score is its own
+    key head's; the key leaf, transposed as it lies at a width under 128,
+    is that head's ``[r * d, block_k]`` by a reshape; the value leaf and
+    the output are the shared head's.  So the ``r`` key heads' scores are
+    one product, their softmax one chain and their values read once, where
+    a walk a key head pays each ``r`` times for ``group`` rows of a tile.
+    ``q`` [b, n_heads, d], ``leaves`` (k [L, n, kv, block, d], v [L, n,
+    kv / r, block, r d]), ``new_rows`` (k [b, kv, 1, d], v [b, kv / r, 1,
+    r d]) or None -> the same three at ``kv / r`` heads of ``r d``, the
+    key leaf ``[.., r d, block]``."""
+    k, v = leaves
+    kv, heads = k.shape[2], v.shape[2]
+    assert v.shape[-1] == kv // heads * k.shape[-1], (k.shape, v.shape)
+    k = jnp.swapaxes(k, -1, -2)
+    k = k.reshape(k.shape[:2] + (heads, v.shape[-1]) + k.shape[-1:])
+    if new_rows:
+        new_rows = (pack_heads(new_rows[0][:, :, 0], heads)[:, :, None],
+                    new_rows[1])
+    return pack_queries(q, kv, heads), [k, v], new_rows
+
+
+def pack_heads(x, heads):
+    """``x`` [b, kv, d] -> [b, heads, kv / heads * d]: consecutive heads
+    side by side."""
+    return x.reshape(x.shape[0], heads, -1)
+
+
+def pack_queries(q, kv, heads):
+    """``q`` [b, n_heads, d], consecutive query heads on one of ``kv``
+    key heads, for ``heads`` packed heads of ``r = kv / heads`` key heads
+    each -> [b, n_heads, r d]: row ``(j, i)`` of a packed head holds query
+    ``i`` of its ``j``-th key head in columns ``j d .. (j + 1) d`` and
+    zeros elsewhere, so against the ``r`` key heads side by side its
+    score is its own key head's."""
+    b, n_heads, d = q.shape
+    r = kv // heads
+    assert kv == r * heads, (kv, heads)
+    qg = q.reshape(b, heads, r, n_heads // kv, d)
+    qg = jnp.einsum("bhjid,jk->bhjikd", qg, jnp.eye(r, dtype=q.dtype))
+    return qg.reshape(b, n_heads, r * d)
 
 
 def _paged_decode_call(q, leaves, tables, cache_len, *, layer=None,
@@ -436,18 +503,28 @@ def _paged_decode_call(q, leaves, tables, cache_len, *, layer=None,
     a relabelling of the pool as it lies in HBM, where the row-major
     block Mosaic would otherwise ask for costs a copy of the whole pool
     in every call."""
-    b, n_heads, d = q.shape
     int8 = len(leaves) == 4
+    if softmax_scale is None:
+        softmax_scale = 1.0 / float(np.sqrt(q.shape[-1]))
     if layer is None:
         leaves, layer = [a[None] for a in leaves], 0
-    kv_heads, block_k = leaves[0].shape[2:4]
-    kt = d % 128 != 0
-    if kt:
-        leaves = [jnp.swapaxes(a, -1, -2) if a.ndim == 5 else a
-                  for a in leaves]
+    block_k = leaves[0].shape[3]
+    packed = leaves[0].shape[2] != leaves[-1].shape[2]
+    vt = {}
+    if packed:
+        assert not int8, "a shared value head is served from a float pool"
+        q, leaves, new_rows = _pack_shared(q, leaves, new_rows)
+        kt, vt = True, {"vt": leaves[1].shape[-1] % 128 != 0}
+        if vt["vt"]:
+            leaves[1] = jnp.swapaxes(leaves[1], -1, -2)
+    b, n_heads, d = q.shape
+    kv_heads = leaves[-1].shape[2]
+    if not packed:
+        kt = d % 128 != 0
+        if kt:
+            leaves = [jnp.swapaxes(a, -1, -2) if a.ndim == 5 else a
+                      for a in leaves]
     group = n_heads // kv_heads
-    if softmax_scale is None:
-        softmax_scale = 1.0 / float(np.sqrt(d))
     if interpret is None:
         interpret = kernels.default_interpret()
     if not interpret:
@@ -458,7 +535,8 @@ def _paged_decode_call(q, leaves, tables, cache_len, *, layer=None,
     if g_pad != group:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, g_pad - group), (0, 0)))
     kvg, n = _walk_shape(kv_heads, block_k, d, leaves[0].dtype.itemsize,
-                         tables.shape[1])
+                         tables.shape[1], **({"packed": True} if packed
+                                             else {}))
 
     lens = jnp.broadcast_to(
         jnp.reshape(jnp.asarray(cache_len, jnp.int32), (-1,)), (b,))
@@ -470,7 +548,7 @@ def _paged_decode_call(q, leaves, tables, cache_len, *, layer=None,
     new_rows = list(new_rows or ())
     out = pl.pallas_call(
         functools.partial(_paged_walk_kernel, float(softmax_scale), n,
-                          block_k, int8, kt, bool(new_rows)),
+                          block_k, int8, kt, bool(new_rows), **vt),
         name="flash_decode",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,

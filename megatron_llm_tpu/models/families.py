@@ -19,6 +19,7 @@ from ..config import (
     gpt_config,
     llama1_config,
     llama2_config,
+    phi4flash_config,
 )
 from . import model as _model
 
@@ -78,6 +79,13 @@ def falcon(size: str = "7b", **overrides) -> CausalLM:
 
 def gpt(size: str = "345m", **overrides) -> CausalLM:
     return CausalLM(validate_gpt(gpt_config(size, **overrides)))
+
+
+def phi4flash(size: str = "mini-flash-reasoning", **overrides) -> CausalLM:
+    """Phi-4-mini-flash-reasoning (``model_type: phi4flash``): a stack of
+    runs, served only (docs/serving.md, "A stack of runs"); the engine
+    wants ``prefix_cache_blocks=0`` for it as for every hybrid stack."""
+    return CausalLM(phi4flash_config(size, **overrides))
 
 
 def draft_model(name: str, target: ModelConfig, **overrides) -> CausalLM:

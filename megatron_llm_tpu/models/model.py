@@ -17,8 +17,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..config import ModelConfig, PositionEmbeddingType
-from . import gated_deltanet, mamba2
+from . import gated_deltanet, mamba1, mamba2
 from .transformer import (
+    RING_NAMES,
     STREAM_DTYPE,
     AttnSideInputs,
     Params,
@@ -32,6 +33,7 @@ from .transformer import (
     norm_init,
     rope_tables,
     scan_periods_cached,
+    scan_runs_cached,
     stack_forward,
     stack_forward_cached,
     stack_forward_paged,
@@ -637,6 +639,12 @@ def init_kv_cache(cfg: ModelConfig, batch_size: int, max_len: int,
         return init_quantized_cache(shape), init_quantized_cache(shape)
     dtype = dtype or cfg.dtype
     shape = (cfg.kv_layers, batch_size, cfg.kv_heads, max_len, cfg.head_dim)
+    if cfg.diff_attention:
+        # the two value heads of a pair side by side as one: the same
+        # bytes a position, in the form every reader takes them
+        return (jnp.zeros(shape, dtype),
+                jnp.zeros(shape[:2] + (cfg.v_heads, max_len,
+                                       cfg.v_head_width), dtype))
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
 
 
@@ -662,11 +670,24 @@ def init_rec_state(cfg: ModelConfig, batch_size: int) -> dict:
     it)."""
     rec = {}
     for kind, n, init in (("linear", cfg.linear_layers, gated_deltanet),
-                          ("mamba", cfg.mamba_layers, mamba2)):
+                          ("mamba", cfg.mamba_layers, mamba2),
+                          ("ssm1", cfg.mamba1_layers, mamba1)):
         if n:
             one = init.init_state(cfg, batch_size)
             for name, a in zip(REC_STATE_KINDS[kind], one):
                 rec[name] = jnp.zeros((n,) + a.shape, a.dtype)
+    if cfg.window_layers:
+        # a "window" layer's ring: the last ``sliding_window`` keys and
+        # values a sequence, position t at row t % window, in the
+        # weights' precision and head-major: a row holds the keys (the
+        # values) of the key heads that share a value head side by side
+        # (``diff_attention.pair_rows``), so the two rings have one shape,
+        # a row is as wide as the lanes, and a step's new row is one
+        # contiguous write
+        ring = (cfg.window_layers, batch_size, cfg.v_heads,
+                cfg.sliding_window, cfg.v_head_width)
+        rec["win_k"] = jnp.zeros(ring, cfg.dtype)
+        rec["win_v"] = jnp.zeros(ring, cfg.dtype)
     return {**rec,
             "load": jnp.zeros((cfg.num_layers, cfg.router_experts),
                               jnp.int32),
@@ -677,7 +698,9 @@ def init_rec_state(cfg: ModelConfig, batch_size: int) -> dict:
 # keeps them: "linear" a Gated DeltaNet layer's, "mamba" a Mamba-2 mixer's
 # (the block kinds ``config.MAMBA_KINDS``)
 REC_STATE_KINDS = {"linear": gated_deltanet.STATE_NAMES,
-                   "mamba": mamba2.STATE_NAMES}
+                   "mamba": mamba2.STATE_NAMES,
+                   "ssm1": mamba1.STATE_NAMES,
+                   "window": RING_NAMES}
 
 
 def rec_states(rec: dict) -> dict:
@@ -731,6 +754,9 @@ def forward_cached_hybrid(cfg: ModelConfig, params: Params, tokens,
     x = embed(cfg, params, tokens, position_ids)
     side = AttnSideInputs(position_ids=position_ids, deterministic=True,
                           cache_is_empty=empty_cache, valid=valid)
+    if cfg.layer_runs:
+        return _forward_cached_runs(cfg, params, x, side, k_cache, v_cache,
+                                    cache_len, rec, logit_rows)
     x, (rows_k, rows_v), new, counts = scan_periods_cached(
         cfg, params["layers"], x, side,
         lambda _idx, k_l, v_l: (k_l, v_l, cache_len), rec,
@@ -744,6 +770,65 @@ def forward_cached_hybrid(cfg: ModelConfig, params: Params, tokens,
             x, logit_rows.astype(jnp.int32)[:, None, None], axis=1)
     return (unembed(cfg, params, x), k_cache, v_cache,
             _counted(rec, new, counts))
+
+
+def _forward_cached_runs(cfg: ModelConfig, params: Params, x, side, k_cache,
+                         v_cache, cache_len, rec: dict, logit_rows):
+    """``forward_cached_hybrid`` for a stack of runs.  A prompt into an
+    empty cache whose ``logit_rows`` names one row a sequence is cut to
+    that row at the boundary between the two decoders
+    (``cfg.row_cut_layer``): every row goes through the layers before it
+    and through the "full" layer's key and value projection, that row
+    alone through its attention, every later layer, the final norm and
+    the head; the states, the rings and the cache are what every row
+    left.  A step (one new position on the dense view of the gather
+    route) writes its rows into the rings here."""
+    from ..ops.kv_quant import cache_update
+
+    prompt = side.cache_is_empty
+    cut = (logit_rows.astype(jnp.int32)
+           if prompt and logit_rows is not None
+           and cfg.row_cut_layer is not None else None)
+    x, (rows_k, rows_v), states, rings = scan_runs_cached(
+        cfg, params["layers"], x, side,
+        lambda _idx, k_l, v_l: (k_l, v_l, cache_len), rec,
+        kv_xs=(k_cache, v_cache), cut_rows=cut)
+    k_cache = cache_update(k_cache, rows_k, cache_len)
+    v_cache = cache_update(v_cache, rows_v, cache_len)
+    if rings is not None and not prompt:
+        # a step's rows: written into the rings here, in place
+        rings = ring_append_rows(tuple(rec[n] for n in RING_NAMES), rings,
+                                 side.position_ids[:, 0])
+    if rings is not None:
+        states = {**states, **dict(zip(RING_NAMES, rings))}
+    x = norm_apply(cfg.norm_type, x, params["final_norm"], cfg.norm_eps,
+                   impl=cfg.norm_impl).astype(cfg.dtype)
+    if logit_rows is not None and cut is None:
+        x = jnp.take_along_axis(
+            x, logit_rows.astype(jnp.int32)[:, None, None], axis=1)
+    return (unembed(cfg, params, x), k_cache, v_cache,
+            {**states, "load": rec["load"], "rows": rec["rows"]})
+
+
+@jax.named_scope("swa")
+def ring_append_rows(rings, rows, positions):
+    """Write a step's new rows into the "window" layers' rings, in
+    place: ``rings`` (k and v, each ``[window layers, slots, heads, W,
+    width]``), ``rows`` the same with one position, slot ``s``'s at row
+    ``positions[s] % W`` (a free slot rewrites a row of its own dead
+    ring).  One ``dynamic_update_slice`` a slot over all the layers, as
+    ``cache_append_rows`` writes the pool."""
+    zero = jnp.int32(0)
+
+    def ap(ring, r):
+        at = positions % ring.shape[3]
+        for s_ in range(r.shape[1]):
+            ring = jax.lax.dynamic_update_slice(
+                ring, r[:, s_:s_ + 1].astype(ring.dtype),
+                [zero, jnp.int32(s_), zero, at[s_], zero])
+        return ring
+
+    return tuple(ap(ring, r) for ring, r in zip(rings, rows))
 
 
 def forward_paged_hybrid(cfg: ModelConfig, params: Params, tokens, k_pool,
@@ -762,7 +847,22 @@ def forward_paged_hybrid(cfg: ModelConfig, params: Params, tokens, k_pool,
     bids = jnp.take_along_axis(tables, (fills // bk)[:, None], axis=1)[:, 0]
     offs = fills % bk
     valid = live[:, None]
-    if paged_decode_eligible(cfg, k_pool, tokens.shape[1]):
+    if cfg.layer_runs and paged_decode_eligible(cfg, k_pool,
+                                                tokens.shape[1]):
+        x = embed(cfg, params, tokens, fills[:, None])
+        side = AttnSideInputs(position_ids=fills[:, None],
+                              deterministic=True, valid=valid)
+        x, (rows_k, rows_v), states, rows = scan_runs_cached(
+            cfg, params["layers"], x, side,
+            lambda idx: PagedKV(k_pool, v_pool, tables, fills, idx), rec)
+        if rows is not None:
+            states = {**states, **dict(zip(RING_NAMES, ring_append_rows(
+                tuple(rec[n] for n in RING_NAMES), rows, fills)))}
+        x = norm_apply(cfg.norm_type, x, params["final_norm"],
+                       cfg.norm_eps, impl=cfg.norm_impl).astype(cfg.dtype)
+        logits = unembed(cfg, params, x)
+        rec = {**states, "load": rec["load"], "rows": rec["rows"]}
+    elif paged_decode_eligible(cfg, k_pool, tokens.shape[1]):
         x = embed(cfg, params, tokens, fills[:, None])
         side = AttnSideInputs(position_ids=fills[:, None],
                               deterministic=True, valid=valid)

@@ -46,7 +46,7 @@ from ..ops.rope import (
     apply_rope_partial,
     precompute_rope_freqs,
 )
-from . import gated_deltanet, mamba2, mla
+from . import diff_attention, gated_deltanet, mamba1, mamba2, mla
 from .gated_deltanet import GDNState, gdn_block, init_gdn_params
 
 Params = dict
@@ -91,7 +91,14 @@ def init_layer_params(key: jax.Array, cfg: ModelConfig,
 
     keys = jax.random.split(key, 8)
     layer: Params = {"input_norm": norm_init(cfg.norm_type, h, dtype)}
-    if kind in KV_KINDS and cfg.kv_lora_rank:
+    if cfg.diff_attention and kind in ("full", "window", "cross"):
+        layer["attn"] = diff_attention.init_diff_attn_params(
+            keys[0], cfg, cross=kind == "cross")
+    elif kind == "ssm1":
+        layer["mamba1"] = mamba1.init_mamba1_params(keys[7], cfg)
+    elif kind == "gmu":
+        layer["gmu"] = diff_attention.init_gmu_params(keys[7], cfg)
+    elif kind in KV_KINDS and cfg.kv_lora_rank:
         layer["attn"] = mla.init_mla_params(keys[0], cfg, std, out_std)
     elif kind in KV_KINDS:
         attn: Params = {
@@ -157,6 +164,17 @@ def init_stack_params(key: jax.Array, cfg: ModelConfig,
     such tree a position of the period, each stacked over the periods;
     leading dense layers are not among them (``init_lead_params``)."""
     n = num_layers if num_layers is not None else cfg.scanned_layers
+    if cfg.layer_runs:
+        # a list of runs, each a list with one tree a position of the
+        # run's period, stacked over the run's periods
+        keys, runs, at = jax.random.split(key, cfg.num_layers), [], 0
+        for period, times in cfg.layer_runs:
+            mine = keys[at:at + len(period) * times]
+            runs.append([jax.vmap(lambda k, kind=kind: init_layer_params(
+                k, cfg, kind))(mine[j::len(period)])
+                for j, kind in enumerate(period)])
+            at += len(period) * times
+        return runs
     if cfg.layer_pattern:
         kinds = cfg.layer_pattern
         keys = jax.random.split(key, n)
@@ -696,6 +714,9 @@ def stack_forward(cfg: ModelConfig, stacked: Params, x: jax.Array,
     ``lead``: the leading dense layers (``init_lead_params``), which run
     before the scan.
     """
+    if cfg.layer_runs:
+        assert lora is None and lead is None
+        return scan_runs_cached(cfg, stacked, x, side)[0], _aux_zero(cfg)
     if cfg.layer_pattern:
         assert lora is None, "a hybrid stack takes no adapters"
         return _stack_forward_periods(cfg, stacked, x, side, base_rng,
@@ -935,6 +956,262 @@ def scan_periods_cached(cfg: ModelConfig, stacked, x, side, kv_of, rec,
         counts = jax.tree.map(lambda a: jnp.concatenate(
             [jnp.zeros((n_lead,) + a.shape[1:], a.dtype), a]), counts)
     return x, rows, states, counts
+
+
+class KVHand(NamedTuple):
+    """What the one "full" layer of a stack of runs hands to the "cross"
+    layers behind it: its keys and values, in the form the cross layers
+    attend them.  ``form`` "seq": ``k v`` head-major rows of the whole
+    sequence, a query at every position; "rows": dense ``k v`` [b, ., S,
+    .] and the positions ``last`` [b, r] of the few query rows; "paged":
+    ``paged`` the pool (:class:`PagedKV`) and ``k v`` the step's own
+    rows, which are not in it yet."""
+
+    form: str
+    k: jax.Array
+    v: jax.Array
+    last: Optional[jax.Array] = None
+    paged: Optional[PagedKV] = None
+
+
+def _diff_attend(cfg: ModelConfig, p: Params, u, layer, hand: KVHand,
+                 q_rows=None):
+    """A layer's differential attention with the keys and values of
+    ``hand``: its own query projection (of the rows ``q_rows`` [b] alone
+    where given), the form's attention, the pairs' difference and the
+    output projection."""
+    if q_rows is not None:
+        u = jnp.take_along_axis(u, q_rows[:, None, None], axis=1)
+    q = diff_attention.project_q(cfg, p, u)
+    if hand.form == "seq":
+        a = diff_attention.attend_seq(cfg, q, hand.k, hand.v)
+    elif hand.form == "rows":
+        a = diff_attention.attend_rows(cfg, q, hand.k, hand.v, hand.last)
+    else:
+        from ..ops.attention import paged_decode_attention
+
+        pg = hand.paged
+        a = paged_decode_attention(
+            q, pg.k_pool, pg.v_pool, pg.tables, pg.fills, hand.k, hand.v,
+            pg.layer, softmax_scale=diff_attention._scale(cfg))
+    return diff_attention.finish(cfg, p, a, layer)
+
+
+def _full_attend(cfg: ModelConfig, p: Params, u, side: AttnSideInputs,
+                 layer, cache, cut_rows):
+    """The "full" layer of a stack of runs: attention on its own keys and
+    values, all of them.  ``cache``: None (a whole sequence, nothing
+    kept), the dense ``(k_cache, v_cache, cache_len)`` or a
+    :class:`PagedKV`.  ``cut_rows`` [b] (a prompt into an empty cache
+    alone): the keys and values of every row, the query and the output
+    of that row only.  -> ``(out, the new rows or None, the hand)``."""
+    k, v = diff_attention.project_kv(cfg, p, u)
+    if isinstance(cache, PagedKV):
+        k, v = k.astype(cache.k_pool.dtype), v.astype(cache.v_pool.dtype)
+        hand = KVHand("paged", k, v, paged=cache)
+    elif cache is None or side.cache_is_empty:
+        hand = (KVHand("seq", k, v) if cut_rows is None
+                else KVHand("rows", k, v, last=cut_rows[:, None]))
+    else:
+        # one new position on the dense view of the gather route
+        from ..ops.kv_quant import cache_update
+
+        k_cache, v_cache, cache_len = cache
+        hand = KVHand("rows", cache_update(k_cache, k, cache_len),
+                      cache_update(v_cache, v, cache_len),
+                      last=side.position_ids)
+    out = _diff_attend(cfg, p, u, layer, hand, cut_rows)
+    return out, (None if cache is None else (k, v)), hand
+
+
+def _window_attend(cfg: ModelConfig, p: Params, u, side: AttnSideInputs,
+                   layer, ring):
+    """A "window" layer: a sequence on itself under the window (``ring``
+    None: nothing kept; True: a prompt, whose ring comes back), or one
+    new position a slot on the slot's ring ``(ring_k, ring_v, at)`` (the
+    window layers' rings stacked, and which of them), whose new rows come
+    back.  -> ``(out, None | the ring | the new rows)``."""
+    q = diff_attention.project_q(cfg, p, u)
+    k, v = diff_attention.project_kv(cfg, p, u)
+    kept = None
+    if ring is None or ring is True:
+        a = diff_attention.attend_seq(cfg, q, k, v, cfg.sliding_window)
+        if ring is True:
+            n = (jnp.full((u.shape[0],), u.shape[1], jnp.int32)
+                 if side.valid is None
+                 else jnp.sum(side.valid, axis=1, dtype=jnp.int32))
+            ring_k, ring_v = (diff_attention.ring_of(
+                a_, n, cfg.sliding_window) for a_ in (k, v))
+            kept = (diff_attention.pair_rows(cfg, ring_k), ring_v)
+    else:
+        a = diff_attention.attend_ring(cfg, q, *ring, k, v,
+                                       side.position_ids[:, 0])
+        kept = (diff_attention.pair_rows(cfg, k), v)
+    return diff_attention.finish(cfg, p, a, layer), kept
+
+
+# under these names the serving state tree keeps the "window" layers'
+# rings, stacked over those layers (models/model.py:init_rec_state)
+RING_NAMES = ("win_k", "win_v")
+
+
+def scan_runs_cached(cfg: ModelConfig, stacked, x, side: AttnSideInputs,
+                     kv_of=None, rec: Optional[dict] = None,
+                     kv_xs: tuple = (), cut_rows=None):
+    """A stack of runs (``cfg.layer_runs``), every form of it: each run a
+    scan over its periods (a run of one period is written out), one run
+    after the other.  Without ``rec``: a whole sequence, every Mamba-1
+    mixer from a zero state, nothing kept.  With ``rec``
+    (``models/model.py:init_rec_state``): a prompt into an empty cache
+    (``side.cache_is_empty``: the Mamba-1 states end at each row's last
+    valid position, every "window" layer's ring is what the prompt leaves
+    in it) or one new position a slot (the states advance where
+    ``side.valid``, the rings are read where they lie and their new rows
+    come back).  The "full" layer gets its cache from ``kv_of(kv layer,
+    *slices of kv_xs)`` as ``scan_periods_cached`` gives it.
+
+    Handed from run to run: the memory of the last "ssm1" layer before a
+    "gmu" layer, and the "full" layer's keys and values (:class:`KVHand`)
+    for the "cross" layers.  ``cut_rows`` [b]: from the "full" layer's
+    attention on, only that row of each sequence is carried
+    (``cfg.row_cut_layer``): its output is [b, 1, h].
+
+    -> ``(hidden, (rows_k, rows_v) of the "full" layers stacked or None,
+    the Mamba-1 states {name: array} or None, the "window" layers' (k,
+    v) or None: after a prompt their rings ``[window layers, b, ., W,
+    .]``, after a step their new ROWS ``[window layers, b, ., 1, .]``
+    for the caller's one write)``."""
+    x = x.astype(STREAM_DTYPE)
+    step = rec is not None and not side.cache_is_empty
+    assert not step or x.shape[1] == 1
+    assert cut_rows is None or cfg.row_cut_layer is not None
+    states = None if rec is None else {
+        name: rec[name] for name in mamba1.STATE_NAMES if name in rec}
+    kinds = cfg.layer_kinds
+    memory_in, hand_in = None, None    # what the runs before hand on
+    all_rows, rings = [], []
+    layer0 = 0
+    for (period, times), trees in zip(cfg.layer_runs, stacked):
+        n = len(period)
+        later = kinds[layer0 + n * times:]
+        # (a run's memory is carried on where a later run gates with it
+        # before making its own)
+        hands_memory = "ssm1" in period and "gmu" in later and (
+            "ssm1" not in later[:later.index("gmu")])
+        base = {kind: kinds[:layer0].count(kind)
+                for kind in ("ssm1", "window", "full")}
+        per = {kind: period.count(kind) for kind in base}
+
+        # (called in this turn of the loop, written out or under the scan:
+        # it reads the turn's own variables)
+        def body(carry, inp):
+            h, idx, states, memory = carry
+            period_params, kv_p = inp
+            memory = memory_in if memory is None else memory
+            hand = hand_in
+            rows, kept = [], []
+            seen = {kind: 0 for kind in per}
+            for j, (kind, p) in enumerate(zip(period, period_params)):
+                layer = layer0 + idx * n + j
+                at = base.get(kind, 0) + idx * per.get(kind, 0) \
+                    + seen.get(kind, 0)
+                if kind in seen:
+                    seen[kind] += 1
+                u = norm_apply(cfg.norm_type, h, p["input_norm"],
+                               cfg.norm_eps, impl=cfg.norm_impl)
+                if kind == "ssm1":
+                    state = None if states is None else mamba1.Mamba1State(
+                        *(jax.lax.dynamic_index_in_dim(
+                            states[name], at, 0, keepdims=False)
+                          for name in mamba1.STATE_NAMES))
+                    out, new, memory = mamba1.mamba1_block(
+                        cfg, p["mamba1"], u, state, side.valid)
+                    if states is not None:
+                        with jax.named_scope(
+                                "mamba1/" + ("mamba1_step" if step
+                                             else "mamba1_scan")):
+                            states = {name: jax.lax.
+                                      dynamic_update_index_in_dim(
+                                          states[name], a, at, 0)
+                                      for name, a in zip(
+                                          mamba1.STATE_NAMES, new)}
+                elif kind == "gmu":
+                    out = diff_attention.gmu_block(p["gmu"], u, memory)
+                elif kind == "window":
+                    with jax.named_scope("swa"):
+                        ring = (None if rec is None else True if not step
+                                else tuple(rec[name] for name in RING_NAMES)
+                                + (at,))
+                        out, keep = _window_attend(
+                            cfg, p["attn"], u.astype(cfg.dtype), side,
+                            layer, ring)
+                    if keep is not None:
+                        kept.append(keep)
+                elif kind == "full":
+                    cache = None if kv_of is None else kv_of(
+                        at, *(a[seen[kind] - 1] for a in kv_p))
+                    with jax.named_scope("attention"):
+                        out, new, hand = _full_attend(
+                            cfg, p["attn"], u.astype(cfg.dtype), side, layer,
+                            cache, cut_rows)
+                    if new is not None:
+                        rows.append(new)
+                    if cut_rows is not None:
+                        # the boundary between the decoders: from here
+                        # on, one row of each sequence
+                        cut = lambda a: jnp.take_along_axis(  # noqa: E731
+                            a, cut_rows[:, None, None], axis=1)
+                        h = cut(h)
+                        memory = None if memory is None else cut(memory)
+                else:                                   # "cross"
+                    with jax.named_scope("xattn"):
+                        out = _diff_attend(cfg, p["attn"],
+                                           u.astype(cfg.dtype), layer, hand)
+                h = h + out
+                u = norm_apply(cfg.norm_type, h, p["post_attn_norm"],
+                               cfg.norm_eps, impl=cfg.norm_impl)
+                h = h + _mlp_dispatch(cfg, p["mlp"], u)[0]
+            stack = lambda xs_: jax.tree.map(  # noqa: E731
+                lambda *a: jnp.stack(a), *xs_) if xs_ else ()
+            carry = (h, idx + 1, states, memory if hands_memory else None)
+            return carry, (stack(rows), stack(kept), hand)
+
+        def by_period(a, kind):
+            a = a[base[kind]:base[kind] + times * per[kind]]
+            return a.reshape((times, per[kind]) + a.shape[1:])
+
+        xs = (tuple(trees), tuple(by_period(a, "full") for a in kv_xs))
+        carry = (x, jnp.int32(0), states, None)
+        if times == 1:
+            # written out: the period may hand on what a scan could not
+            # (the keys and values, a stream cut to one row)
+            carry, (rows, kept, hand_in) = body(
+                carry, jax.tree.map(lambda a: a[0], xs))
+            rows, kept = jax.tree.map(lambda a: a[None], (rows, kept))
+        else:
+            assert "full" not in period or "cross" not in kinds, (
+                "the layer whose keys and values are handed on stands in "
+                "a run of one period")
+            if hands_memory:
+                carry = carry[:3] + (jnp.zeros(
+                    x.shape[:2] + (cfg.mamba1_inner,), jnp.float32),)
+
+            def scanned(c, i):
+                c, (rows, kept, _hand) = body(c, i)
+                return c, (rows, kept)
+
+            carry, (rows, kept) = jax.lax.scan(scanned, carry, xs)
+        x, _, states, memory_in = carry
+        flat = lambda a: a.reshape(  # noqa: E731
+            (a.shape[0] * a.shape[1],) + a.shape[2:])
+        if per["full"] and kv_of is not None:
+            all_rows.append(jax.tree.map(flat, rows))
+        if per["window"] and rec is not None:
+            rings.append(jax.tree.map(flat, kept))
+        layer0 += n * times
+    cat = lambda parts: jax.tree.map(  # noqa: E731
+        lambda *a: jnp.concatenate(a), *parts) if parts else None
+    return x, cat(all_rows), states, cat(rings)
 
 
 def _scan_layers_cached(cfg: ModelConfig, stacked: Params, x: jax.Array,

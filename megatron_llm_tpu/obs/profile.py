@@ -56,7 +56,15 @@ DEVICE_SCOPES = (
     # latent attention (models/mla.py), under "attention": its five
     # projections and the absorption, and the absorbed form's kernel (the
     # expanded form runs "flash_fwd")
-    "mla_proj", "mla_decode")
+    "mla_proj", "mla_decode",
+    # a stack of runs (models/transformer.py:scan_runs_cached): the
+    # Mamba-1 mixer and its parts (models/mamba1.py), the window layers
+    # ("swa": their flash_fwd under the window, the ring's attention, the
+    # kernel "ring_decode", and its row writes), the cross layers
+    # ("xattn": their walks of the one cached layer run "flash_decode")
+    # and the gated memory units
+    "mamba1", "mamba1_proj", "mamba1_conv", "mamba1_scan", "mamba1_step",
+    "swa", "ring_decode", "xattn", "gmu")
 
 
 @dataclasses.dataclass

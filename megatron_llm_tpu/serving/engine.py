@@ -130,6 +130,7 @@ from ..config import ModelConfig
 from ..generation.sampling import NEG_INF
 from ..kernels.flash_attention import tile_plan
 from ..kernels.mamba_step import heads_per_step
+from ..models.diff_attention import flash_blocks
 from ..models import model as model_lib
 from ..obs import compile as obs_compile
 from ..obs import profile as obs_profile
@@ -575,6 +576,29 @@ def _prefill_impl(cfg: ModelConfig, params, tokens, length,
             logits, (length - 1)[:, None, None], axis=1)[:, 0]
         return last, picked, k, v, rec
     return logits[:, 0], None, k, v, rec
+
+
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def _prompt_logprobs_impl(cfg: ModelConfig, params, tokens):
+    """The log-probability of every token of a (bucket-padded) prompt
+    given the tokens before it, ``[1, s - 1]``: every row through every
+    layer, nothing kept, the head a block of rows at a time (a whole
+    bucket's float32 logits are gigabytes at a 200k vocabulary).  The
+    second pass of a stack whose prefill cuts its rows
+    (``cfg.row_cut_layer``)."""
+    x, _aux = model_lib.forward_hidden(cfg, params, tokens)
+    s = tokens.shape[1]
+    block = next(b for b in (256, 128, 64, 32, 16, 8, 4, 2, 1) if s % b == 0)
+    targets = jnp.concatenate([tokens[0, 1:], tokens[0, :1]])
+
+    def picked(rows):
+        xs, ts = rows
+        lp = jax.nn.log_softmax(model_lib.unembed(cfg, params, xs), axis=-1)
+        return jnp.take_along_axis(lp, ts[:, None], axis=-1)[:, 0]
+
+    out = jax.lax.map(picked, (x[0].reshape(s // block, block, -1),
+                               targets.reshape(s // block, block)))
+    return out.reshape(1, s)[:, :-1]
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
@@ -1243,9 +1267,17 @@ class ServingEngine:
         # spans ("linear", "mamba", "linear+mamba"; no such field for a
         # one-kind stack), and whether a state-space layer is among them
         kinds = [kind for kind, n in (("linear", cfg.linear_layers),
-                                      ("mamba", cfg.mamba_layers)) if n]
+                                      ("mamba", cfg.mamba_layers),
+                                      ("ssm1", cfg.mamba1_layers),
+                                      ("window", cfg.window_layers)) if n]
         self._state_arg = {"state_kinds": "+".join(kinds)} if kinds else {}
-        self._counts_ssm = cfg.mamba_layers > 0
+        self._counts_ssm = cfg.mamba_layers + cfg.mamba1_layers > 0
+        # layers that walk a cache another layer wrote ("cross"), beside
+        # the one that wrote it: a step's walks are counted by kind, its
+        # decode spans carry the cached positions it attended
+        self._kv_readers = ({"full": cfg.kv_layers,
+                             "cross": cfg.cross_layers}
+                            if cfg.cross_layers else {})
         # how a step's recurrent mixers ran, on its decode spans: each
         # between its two projections as one kernel, which advances the
         # layer's states and tails where they lie stacked
@@ -1256,7 +1288,7 @@ class ServingEngine:
             **({"gdn_step": "mixer"} if cfg.linear_layers else {}),
             **({"ssm_step": "mixer", "ssm_tile": heads_per_step(
                 cfg.mamba_num_heads, cfg.mamba_n_groups)}
-               if self._counts_ssm else {}))
+               if cfg.mamba_layers else {}))
         # on a prompt's prefill spans: how its Gated DeltaNet layers ran
         # (between their projections as one kernel, kernels/gdn_scan.py;
         # no such field for a stack without them), and the bytes of slot
@@ -1275,6 +1307,11 @@ class ServingEngine:
         if self._latent:
             self._prefill_arg["attn"] = "mla_expanded"
             self._step_arg["attn"] = "mla_absorbed"
+        # a stack that cuts a prefill to one row at the boundary between
+        # its decoders (cfg.row_cut_layer): the rows its later layers ran
+        # on its prefill spans (1, or the bucket's where a request's
+        # prompt log-probs took a second pass over every row)
+        self._row_cut = cfg.row_cut_layer is not None
         # a bucket's ``flash_tiles`` on its whole-prompt prefill spans, by
         # padded length (``_flash_tiles_arg``)
         self._flash_tiles: Dict[int, dict] = {}
@@ -2132,13 +2169,23 @@ class ServingEngine:
             tokens = np.zeros((1, padded), np.int32)
             tokens[0, :plen] = req.prompt
             with device_annotation("prefill"):
+                # (a stack with a row cut: the caches and the first token
+                # come from the program every prefill runs, a request's
+                # prompt log-probs from a second pass that keeps nothing)
                 (last_logits, picked, k_small, v_small,
                  rec_small) = _prefill_impl(
                     self.cfg, self.params, jnp.asarray(tokens),
                     jnp.asarray([plen], jnp.int32),
                     max_seq_len=self.slots.width,
-                    want_logprobs=req.return_logprobs,
+                    want_logprobs=(req.return_logprobs
+                                   and not self._row_cut),
                     **self._lora_args([aslot]))
+                if req.return_logprobs and self._row_cut:
+                    picked = _prompt_logprobs_impl(
+                        self.cfg, self.params, jnp.asarray(tokens))
+            if self._row_cut:
+                flash_arg = dict(flash_arg, cross_rows=(
+                    padded if req.return_logprobs else 1))
             if req.return_logprobs:
                 req.logprobs.extend(
                     np.asarray(picked)[0, :plen - 1].tolist())
@@ -2277,8 +2324,11 @@ class ServingEngine:
         if arg is None:
             arg = {}
             if self.cfg.attention_impl == "flash" and padded > 1:
-                plan = tile_plan(padded, padded, self.cfg.flash_block_q,
-                                 self.cfg.flash_block_k)
+                # (a stack of runs: its "window" layers' band, the only
+                # flash_fwd of a prefill cut to one row)
+                w = self.cfg.sliding_window if self.cfg.window_layers else 0
+                plan = tile_plan(padded, padded,
+                                 *flash_blocks(self.cfg, w), True, w)
                 arg = {"flash_tiles":
                        f"{plan.live}/{plan.masked}/{plan.padded_rows}"}
             self._flash_tiles[padded] = arg
@@ -3058,6 +3108,8 @@ class ServingEngine:
             st.count += 1  # one more token sampled (possibly speculative)
         # tpulint: allow[host-sync] fills is host numpy (built above)
         positions = int(fills.sum())
+        if self._kv_readers:
+            self.metrics.add_kv_walks(positions, self._kv_readers)
         if self.trace.enabled:
             self.trace.add("step_inputs", t_in, t0,
                            args={"iter": self._iter, "live": len(snapshot)})
@@ -3101,7 +3153,7 @@ class ServingEngine:
                                  "dispatched": step.iter})
         committed = 0
         step_arg = self._step_arg
-        if self._latent and self.trace.enabled:
+        if (self._latent or self._kv_readers) and self.trace.enabled:
             step_arg = dict(step_arg, live_positions=step.positions)
         for slot, st in step.slots.items():
             if self._active.get(slot) is not st:

@@ -247,7 +247,8 @@ class ServingMetrics:
         self.resume_latency = LatencyHistogram()
         # hybrid stacks (serving/slots.py): bytes of per-slot recurrent
         # and convolution state beside the pool, by the block kind that
-        # keeps it ("linear", "mamba"), and the slots it is for
+        # keeps it ("linear", "mamba", "ssm1", "window": a ring of keys
+        # and values), and the slots it is for
         self.rec_state_bytes: dict = {}
         self.rec_state_slots = 0
         # bytes of the paged pool by the kind of row it holds: "kv" (keys
@@ -258,6 +259,12 @@ class ServingMetrics:
         # states over, by phase: a prompt's length at its prefill, the
         # live slots of every decode step
         self.ssm_positions = {"prefill": 0, "decode": 0}
+        # cached positions the decode steps' paged walks read, by the
+        # kind of layer that walked them: "full" (the layer whose keys
+        # and values the pool holds) and "cross" (a layer that reads
+        # another's): live positions x readers.  Counted where some layer
+        # reads a cache it does not write.
+        self.kv_walks: dict = {}
         # per-layer, per-expert assignment counts, carried on the device
         # in the step's own state: ``expert_load`` (set by the engine)
         # fetches them → (counts [layers, router outputs], first held
@@ -307,6 +314,14 @@ class ServingMetrics:
     def add_ssm_positions(self, phase: str, n: int) -> None:
         with self._lock:
             self.ssm_positions[phase] += n
+
+    def add_kv_walks(self, positions: int, readers: dict) -> None:
+        """A decode step over ``positions`` cached positions in all,
+        walked by ``readers[layer kind]`` layers of each kind."""
+        with self._lock:
+            for kind, n in readers.items():
+                self.kv_walks[kind] = (self.kv_walks.get(kind, 0)
+                                       + positions * n)
 
     def set_gauges(self, *, slots_active: Optional[int] = None,
                    queue_depth: Optional[int] = None,
@@ -475,6 +490,7 @@ class ServingMetrics:
                 "rec_state_slots": self.rec_state_slots,
                 "kv_pool_bytes_by_kind": dict(self.kv_pool_bytes),
                 "ssm_positions": dict(self.ssm_positions),
+                "kv_walks": dict(self.kv_walks),
                 # speculative decoding (histogram samples are token
                 # counts per participating slot per verify step)
                 "spec_acceptance_rate": (
@@ -637,7 +653,16 @@ class ServingMetrics:
             for kind, n in sorted(self.kv_pool_bytes.items()):
                 fam.add(n, labels={"kind": kind})
             fams.append(fam if self.kv_pool_bytes else fam.add(0))
-            if "mamba" in self.rec_state_bytes:
+            if self.kv_walks:
+                fam = MetricFamily(
+                    "serving_kv_walks_total", "counter",
+                    "cached positions the decode steps' paged walks read, "
+                    "by the kind of layer that walked them (live "
+                    "positions x readers)")
+                for kind, n in sorted(self.kv_walks.items()):
+                    fam.add(n, labels={"layer_kind": kind})
+                fams.append(fam)
+            if {"mamba", "ssm1"} & set(self.rec_state_bytes):
                 fam = MetricFamily(
                     "serving_ssm_positions_total", "counter",
                     "positions the state-space layers advanced their "

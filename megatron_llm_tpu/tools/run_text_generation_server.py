@@ -68,7 +68,11 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--load", required=True, help="checkpoint directory")
     ap.add_argument("--model", default="llama2",
-                    choices=["llama", "llama2", "codellama", "falcon", "gpt"])
+                    choices=["llama", "llama2", "codellama", "falcon", "gpt",
+                             "phi4flash"],
+                    help="phi4flash (--size mini-flash-reasoning) is a "
+                         "hybrid stack: start it with "
+                         "--prefix_cache_blocks 0 (docs/serving.md)")
     ap.add_argument("--size", default="7b")
     ap.add_argument("--tokenizer_type", default="SentencePieceTokenizer")
     ap.add_argument("--tokenizer_model", default=None)
@@ -293,7 +297,8 @@ def main(argv=None) -> int:
                "llama2": lambda s: families.llama(s, version=2),
                "codellama": families.code_llama,
                "falcon": families.falcon,
-               "gpt": families.gpt}[args.model]
+               "gpt": families.gpt,
+               "phi4flash": families.phi4flash}[args.model]
     lm = factory(args.size)
     if args.kv_quant:
         import dataclasses

@@ -171,6 +171,45 @@ def test_flash_attention_tiles_cut_from_the_length(topo, s, heads, kv, d,
     assert " pad(" not in text
 
 
+@pytest.mark.parametrize("s,live", [(1536, 5), (4096, 15)])
+def test_flash_attention_under_a_window_at_paired_value_heads(topo, s,
+                                                              live):
+    """The phi-4-mini-flash cell's window layers: 40 query heads of 64 on
+    20 key heads of 64 and 10 value heads of 128 (differential attention
+    as ordinary attention), a window of 512 in blocks of 512: the band is
+    the diagonal tile and the one before it, 2 n - 1 tiles of n (n + 1) /
+    2; forward only."""
+    args = (_sds((1, s, 40, 64)), _sds((1, s, 20, 64)),
+            _sds((1, s, 10, 128)))
+    fn = lambda q, k, v: flash_attention(  # noqa: E731
+        q, k, v, window=512, block_q=512, block_k=512, interpret=False)
+    traced = str(jax.make_jaxpr(fn)(*args))
+    assert f"grid=(1, 40, {live})" in traced
+    text = _compile(fn, args, SingleDeviceSharding(topo.devices[0]))
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
+def test_the_paged_walk_at_a_value_head_shared_by_two_key_heads(topo):
+    """The phi-4-mini-flash cell's eight readers of one pool layer: 64
+    slots of up to 64 blocks of 128 rows, 40 query heads on 20 key heads
+    of 64 and 10 value heads of 128, the step's own rows beside the pool.
+    The key leaf lies with its 128 positions as lanes and is taken so,
+    the value leaf row-major: neither is copied."""
+    S, T = 64, 64
+    blocks = S * T + 1
+    text = _compile(
+        lambda q, k, v, tb, n, kn, vn, layer: fd.flash_decode_paged(
+            q, k, v, tb, n, new_rows=(kn, vn), layer=layer[0],
+            softmax_scale=0.125, interpret=False),
+        (_sds((S, 40, 64)), _sds((1, blocks, 20, 128, 64)),
+         _sds((1, blocks, 10, 128, 128)), _sds((S, T), jnp.int32),
+         _sds((S,), jnp.int32), _sds((S, 20, 1, 64)),
+         _sds((S, 10, 1, 128)), _sds((1,), jnp.int32)),
+        SingleDeviceSharding(topo.devices[0]))
+    _no_copy_of(text, f"bf16[1,{blocks},")
+    assert relayout_bytes(text) == {}
+
+
 def test_mla_decode(topo):
     """The latent walk at the kanana cell's shapes: 44 slots of up to 132
     blocks of 128 rows, 32 heads on one row of 512 + 64 a position, a
@@ -683,6 +722,62 @@ def test_a_whole_depth_hybrid_step_moves_no_stacked_state(topo, monkeypatch):
     states = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(rec))
     assert states <= mem.alias_size_in_bytes < states + 2 ** 20
     assert mem.temp_size_in_bytes < 2 ** 20
+
+
+def test_a_stack_of_runs_decode_step_at_its_published_depth(topo,
+                                                            monkeypatch):
+    """The engine's decode executable for phi-4-mini-flash-reasoning
+    whole: 32 layers in three runs, 64 slots of 8192 positions, 11.96 GB
+    of arguments of which the 4.25 GB of pool, Mamba-1 states and window
+    rings are donated and aliased.  Three kernels in the program text
+    (the full layer's walk, written out, the cross layers' in their run's
+    ``while``, and the window layers' ring kernel in theirs, which takes
+    the stacked rings and the layer's index: no ring is sliced or
+    copied), no weight re-laid (the query and output projections are cut
+    into heads behind a barrier), the stacked Mamba-1 states advanced
+    where they lie."""
+    from megatron_llm_tpu.config import phi4flash_config
+    from megatron_llm_tpu.serving import engine as engine_lib
+
+    monkeypatch.setattr(attn_ops, "_backend", lambda: "tpu")
+    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
+    one = SingleDeviceSharding(topo.devices[0])
+    S, blocks, bk = 64, 64, 128
+    cfg = phi4flash_config(attention_impl="flash")
+    place = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
+    params = jax.eval_shape(
+        lambda key: model_lib.init_params(key, cfg), jax.random.key(0))
+    pool = jax.eval_shape(
+        lambda: model_lib.init_kv_pool(cfg, S * blocks + 1, bk))
+    rec = jax.eval_shape(lambda: model_lib.init_rec_state(cfg, S))
+    assert rec["ssm1"].shape == (9, S, 16, 5120)
+    assert rec["win_k"].shape == (8, S, 10, 512, 128)
+    assert rec["win_v"].shape == (8, S, 10, 512, 128)
+    assert [a.shape for a in pool] == [(1, S * blocks + 1, 20, bk, 64),
+                                       (1, S * blocks + 1, 10, bk, 128)]
+    i32, f32 = jnp.int32, jnp.float32
+    vec = lambda dtype: place(_sds((S,), dtype))  # noqa: E731
+    compiled = engine_lib._decode_donated.lower(
+        cfg, place(params), *place(pool), place(_sds((S, blocks), i32)),
+        vec(i32), vec(i32), vec(jnp.uint32), vec(i32), vec(bool), vec(f32),
+        vec(i32), vec(f32), rec=place(rec), live=vec(bool)).compile()
+    mem = compiled.memory_analysis()
+    donated = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree.leaves((pool, rec)))
+    assert 11.9e9 < mem.argument_size_in_bytes < 12.0e9
+    assert donated <= mem.alias_size_in_bytes < donated + 2 ** 20
+    assert mem.temp_size_in_bytes < 0.4e9
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert relayout_bytes(text) == {}
+    _no_copy_of(text, "bf16[8,64,10,512,128]")
+    _no_copy_of(text, "bf16[1,64,10,512,128]")
+    assert "remat_compressed" not in text
+    _no_copy_of(text, f"bf16[1,{S * blocks + 1},")
+    # (a layer's new state is computed in the fusion whose root writes it
+    # into the stacked array: in place, by the alias above)
+    assert not [k for k in relayout_bytes(text) if k.startswith("f32[9,")]
 
 
 def test_a_delta_rule_decode_step_advances_its_states_where_they_lie(
