@@ -24,6 +24,11 @@ the blocks before them are attended as one online-softmax term
 (``_attend_blocks``: the mask is over logical columns).  So HBM traffic
 and the walk's length are the sum of per-row fills, not ``b * max_len``:
 a block past the fill is never copied, an empty row copies nothing.
+The copies are one stream over the whole call (PR 57): the grid runs in
+order, and a grid step's last iteration starts the first blocks of the
+next grid step that has a live row, so that step finds them in flight
+and no slot's first copy is waited out bare (``walk_counts`` says how
+many steps of a call that holds for).
 How many blocks an iteration takes follows from the shapes
 (``_walk_shape``).  Entries past a row's fill point at the pool's trash
 block and are never read; the rest of a partly filled block (and of a
@@ -306,8 +311,12 @@ def _attend_new_row(scale, q, k_row, v_row, m_scr, l_scr, acc_scr, rows):
 # us at 2048; the same blocks attended one after another, a term each,
 # 2.6-2.8 ms / 41-43 us at every count (the chain of mask, maxima, exp and
 # scratch rewrites a term is latency: what PR 25 met as "several blocks a
-# tick bought nothing").
-_WALK_VMEM_BYTES = 2 * 2**20
+# tick bought nothing").  The VMEM was 2 MiB until PR 57: no plain walk of
+# a cell is bound by it (the columns bind first: Falcon (1, 4), 8 KV heads
+# of 64 (8, 4), 2 of 128 or of 256 (2, 4), the same at 2.5 MiB), and the
+# packed walk's ten heads of 128 at two blocks an iteration need 2.5 (what
+# that buys: ``_walk_shape``).
+_WALK_VMEM_BYTES = 5 * 2**19
 _WALK_COLUMNS = 512
 
 
@@ -317,16 +326,28 @@ def _walk_shape(kv_heads, block_k, d, itemsize, t, packed: bool = False):
     may hold, its rows against the columns of a term, the table's width.
     ``packed`` (the key heads that share a value head packed into one,
     ``_pack_shared``): half the columns a term, and as many heads a copy
-    as the same VMEM holds.  Measured on a v5e (PR 56), 64 slots of 1-6 k
-    rows at 40 heads on 20 key heads of 64 and 10 value heads of 128,
-    packed to 10 heads of 128, (heads, blocks) a call: (5, 8) 2.00 ms,
-    (10, 8) 1.95, (10, 4) 1.92, (10, 3) 2.09, (10, 2) 1.72 at twice this
-    VMEM, **(5, 2) 1.94**, (10, 1) 2.01, (2, 4) 2.61; a walk a key head,
-    unpacked, took 2.33 at its best, (10, 8) of its heads: there a pair's
-    two chains of mask, maxima and exp over two query rows each were
-    latency that wide terms paid less often; packed, the pair is one chain
-    and short terms keep the copies ahead.  (10, 2) is left where it is:
-    PERF.md, PR 56, says why."""
+    as the same VMEM holds.
+
+    Measured on a v5e with the copies one stream over the call (PR 57;
+    the kernel's own time in a profile, parent -> change at equal shape),
+    (heads, blocks) a call.  Packed, 64 slots of 1-6 k rows at 40 heads
+    on 20 key heads of 64 and 10 value heads of 128: (5, 2) 1.843 ->
+    1.732 ms, (5, 4) 1.986 -> 1.774, **(10, 2) 1.633 -> 1.536** (90 % of
+    the rows' HBM time), (10, 4) 1.827 -> 1.619, (10, 1) 1.909 -> 1.845,
+    (5, 3) 2.232 -> 2.053, (5, 1) 2.548 -> 2.476, (2, 2) 2.961 -> 2.804:
+    a pair is one chain of mask, maxima and exp, so short terms keep the
+    copies ahead, and every head in one copy halves the grid steps, each
+    of which still costs 0.6-0.75 us that no copy covers (the same rows
+    in half and a quarter as many slots: 1.517 and 1.505 ms; the parent
+    paid 1.5-2.3 us a grid step).  (PR 56: a walk a key
+    head, unpacked, 2.33 ms at its best.)  Plain, as the rule gives them:
+    64 slots of 64-3000 rows at 32 heads on 8 KV heads of 64, (8, 4)
+    0.691 -> 0.602 ms ((8, 2) 0.625 -> 0.572, (4, 4) 0.759 -> 0.678, and
+    (8, 8), twice the columns and past the VMEM, 0.538 -> 0.396: PERF.md
+    section 7 q); 16 slots of 50-700 rows at Falcon's 71 heads on one of
+    64, (1, 4) 26.9 -> 21.9 us ((1, 2) 31.3 -> 26.8, (1, 8) 26.1 -> 20.9);
+    44 slots of 4-16 k rows at 16 heads on 2 KV heads of 256, (2, 4) 1.311
+    -> 1.257 ms ((2, 2) 1.692 -> 1.654, (2, 8) 1.316 -> 1.241)."""
     half = _WALK_VMEM_BYTES // 4
     head_bytes = block_k * d * itemsize
     if packed:
@@ -340,6 +361,29 @@ def _walk_shape(kv_heads, block_k, d, itemsize, t, packed: bool = False):
     return kvg, max(1, n)
 
 
+def pool_walk(k, v, t):
+    """``(KV heads, heads a copy, blocks an iteration)`` of the walk over
+    a pool whose key leaf is ``k`` ``[.., kv, block, d]`` and value leaf
+    ``v`` (arrays or their shapes), under tables ``t`` wide: the grid
+    ``_paged_decode_call`` runs, ``(slots, KV heads / heads a copy)``."""
+    heads = v.shape[-3]
+    packed = {"packed": True} if k.shape[-3] != heads else {}
+    return (heads,) + _walk_shape(heads, k.shape[-2], v.shape[-1],
+                                  jnp.dtype(k.dtype).itemsize, t, **packed)
+
+
+def walk_counts(fills, kv_heads, kvg):
+    """For one call of the paged walk over rows of ``fills`` cached
+    positions at ``kvg`` of ``kv_heads`` KV heads a copy: ``(grid steps
+    with a live row, those of them that found their first iteration's
+    copies already started)``.  A row with any fill has a first
+    iteration whatever the blocks an iteration, and every live step but
+    the call's first is looked ahead to by the live step before it, so
+    the second count is the first less one."""
+    steps = int(np.count_nonzero(np.asarray(fills) > 0)) * (kv_heads // kvg)
+    return steps, max(0, steps - 1)
+
+
 def _paged_walk_kernel(scale: float, n: int, block_k: int, int8: bool,
                        kt: bool, has_new: bool,
                        len_ref, tbl_ref, lyr_ref, q_ref, *refs, vt=None):
@@ -348,24 +392,40 @@ def _paged_walk_kernel(scale: float, n: int, block_k: int, int8: bool,
     ``refs``: the pool leaves in HBM — (k, v), or (k, k_scale, v,
     v_scale) for the int8 pool — the new token's (k_row, v_row) when
     ``has_new``, the output, then the scratch: one double-buffered VMEM
-    copy ``[2, n, kvg, ...]`` a leaf, their DMA semaphores ``[2, leaves]``
-    and the three softmax scratches ``[kvg * g_pad, ...]``.
+    copy ``[2, n, kvg, ...]`` a leaf, their DMA semaphores ``[2, leaves]``,
+    the stream's phase ``[2]`` in SMEM and the three softmax scratches
+    ``[kvg * g_pad, ...]``.
 
     Iteration ``c`` waits for the row's logical blocks ``c*n .. c*n+n-1``
     — those under the fill: a block past it is never copied, an empty row
-    starts no copy and runs no iteration — with iteration ``c+1``'s copies
-    already in flight, and attends them as ONE online-softmax term of
-    ``n * block_k`` columns a KV head.  A copy is a whole pool block,
-    every KV head of the group at once, as it lies in HBM.  ``vt`` says
-    whether the value blocks come transposed, as ``kt`` does for the
-    key's (None: as ``kt``)."""
+    starts no copy and runs no iteration — and attends them as ONE
+    online-softmax term of ``n * block_k`` columns a KV head.  A copy is
+    a whole pool block, every KV head of the group at once, as it lies in
+    HBM.  ``vt`` says whether the value blocks come transposed, as ``kt``
+    does for the key's (None: as ``kt``).
+
+    The copies are ONE stream over the whole call: the grid runs in order
+    (slot by slot, a slot's head groups one after another) and the two
+    buffer halves alternate across grid steps as they do inside one.  At
+    the start of iteration ``c`` the copies of what comes next are
+    started into the other half: the row's iteration ``c + 1`` or, at its
+    last iteration, iteration 0 of the next grid step that has a live row
+    (the slot's next head group, else the first group of the next slot
+    whose fill is not 0: fills and tables of every slot are in SMEM).
+    ``ph_ref`` carries to that step which half its first iteration lies
+    in and that its copies were started; it only waits for them.  The
+    call's first live step finds nothing started and starts its own; its
+    last live step starts nothing, so every copy started is waited for
+    inside the call.  An empty row passes the phase on untouched: the
+    step before it has already looked past it."""
     n_cache = 4 if int8 else 2
     hbm, rest = refs[:n_cache], refs[n_cache:]
     new_refs, rest = rest[:2 * has_new], rest[2 * has_new:]
     o_ref, bufs = rest[0], rest[1:1 + n_cache]
-    sem, m_scr, l_scr, acc_scr = rest[1 + n_cache:]
+    sem, ph_ref, m_scr, l_scr, acc_scr = rest[1 + n_cache:]
     bi, gi = pl.program_id(0), pl.program_id(1)
     kvg, g_pad = q_ref.shape[1:3]
+    slots, groups = len_ref.shape[0], hbm[0].shape[2] // kvg
     fill = len_ref[bi]
     live = pl.cdiv(fill, block_k)
     trips = pl.cdiv(live, n)
@@ -374,37 +434,58 @@ def _paged_walk_kernel(scale: float, n: int, block_k: int, int8: bool,
     l_scr[:] = jnp.zeros_like(l_scr)
     acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    def copies(c, half, go):
-        """``go`` (start or wait) the copies of iteration ``c``'s live
-        blocks into buffer half ``half``."""
+    def copies(s, g, c, half, count, go):
+        """``go`` (start or wait) the copies of the ``count`` live blocks
+        of iteration ``c`` of grid step ``(s, g)`` into buffer half
+        ``half``."""
         def one(j, carry):
-            blk = tbl_ref[bi, c * n + j]
+            blk = tbl_ref[s, c * n + j]
             for i, (src, dst) in enumerate(zip(hbm, bufs)):
                 src = src.at[lyr_ref[0], blk]
                 if src.shape[0] != kvg:
-                    src = src.at[pl.ds(gi * kvg, kvg)]
+                    src = src.at[pl.ds(g * kvg, kvg)]
                 go(pltpu.make_async_copy(src, dst.at[half, j],
                                          sem.at[half, i]))
             return carry
 
-        jax.lax.fori_loop(0, jnp.minimum(n, live - c * n), one, 0)
+        jax.lax.fori_loop(0, count, one, 0)
 
-    @pl.when(trips > 0)
-    def _first():
-        copies(0, 0, lambda cp: cp.start())
+    # the phase the step before left (the call's first step: none)
+    first = (bi == 0) & (gi == 0)
+    half0 = jnp.where(first, 0, ph_ref[0])
+    started = jnp.where(first, 0, ph_ref[1])
+    # the next grid step with a live row: (s_next, g_next), if ``more``
+    same = gi + 1 < groups
+    s_next = jax.lax.while_loop(
+        lambda s: (s < slots) & (len_ref[jnp.minimum(s, slots - 1)] == 0),
+        lambda s: s + 1, jnp.where(same, bi, bi + 1))
+    g_next = jnp.where(same, gi + 1, 0)
+    more = s_next < slots
+    s_next = jnp.minimum(s_next, slots - 1)
+    # the live blocks of that step's first iteration (none: nothing starts)
+    first_next = jnp.where(
+        more, jnp.minimum(n, pl.cdiv(len_ref[s_next], block_k)), 0)
+
+    copies(bi, gi, 0, half0,
+           jnp.where(started == 1, 0, jnp.minimum(n, live)),
+           lambda cp: cp.start())
 
     def step(c, carry):
-        half = jax.lax.rem(c, 2)
+        half = (half0 + c) & 1
+        # what flies under this iteration's term
+        last = c + 1 == trips
+        copies(jnp.where(last, s_next, bi), jnp.where(last, g_next, gi),
+               jnp.where(last, 0, c + 1), 1 - half,
+               jnp.where(last, first_next, jnp.minimum(n, live - (c + 1) * n)),
+               lambda cp: cp.start())
 
-        @pl.when(c + 1 < trips)
-        def _next():
-            copies(c + 1, 1 - half, lambda cp: cp.start())
-
-        copies(c, half, lambda cp: cp.wait())
+        copies(bi, gi, c, half, jnp.minimum(n, live - c * n),
+               lambda cp: cp.wait())
         # a row's last iteration attends the buffers of its dead blocks
         # too, masked: a probability of exactly 0 multiplies what they
         # hold (V and its scales), which must be finite, and what they
-        # hold is VMEM noise or an earlier row's blocks
+        # hold is VMEM noise or an earlier row's blocks.  They lie in
+        # this iteration's half; the copies in flight fill the other
         def zero(j, carry):
             for buf in bufs[n_cache // 2:]:
                 buf[half, j] = jnp.zeros(buf.shape[2:], buf.dtype)
@@ -414,16 +495,20 @@ def _paged_walk_kernel(scale: float, n: int, block_k: int, int8: bool,
         for h in range(kvg):
             # [K, V] blocks of KV head h, then the int8 pool's
             # [K scale, V scale] rows, else None
-            blocks = [[buf[half, j, h] for j in range(n)]
-                      for buf in bufs[::n_cache // 2]]
+            blocks_h = [[buf[half, j, h] for j in range(n)]
+                        for buf in bufs[::n_cache // 2]]
             scales = [[buf[half, j, pl.ds(h, 1), :] for j in range(n)]
                       for buf in bufs[1::2]] if int8 else [None, None]
             _attend_blocks(scale, c * n * block_k, fill, q_ref[0, h],
-                           *blocks, *scales, m_scr, l_scr, acc_scr,
+                           *blocks_h, *scales, m_scr, l_scr, acc_scr,
                            pl.ds(h * g_pad, g_pad), kt=kt, vt=vt)
         return carry
 
     jax.lax.fori_loop(0, trips, step, 0)
+    # to the next grid step: where its first iteration lies, and whether
+    # it is in flight
+    ph_ref[0] = (half0 + trips) & 1
+    ph_ref[1] = jnp.where(trips > 0, more.astype(jnp.int32), started)
 
     for h in range(kvg):
         rows = pl.ds(h * g_pad, g_pad)
@@ -495,7 +580,14 @@ def _paged_decode_call(q, leaves, tables, cache_len, *, layer=None,
     where the kernel's walk reads the physical block of each copy.  The
     grid is one step a slot and group of KV heads; how many heads a copy
     takes and how many blocks an iteration follow from the shapes
-    (``_walk_shape``), the same walk for every pool form.
+    (``pool_walk``, ``_walk_shape``), the same walk for every pool form.
+    Both axes are ``"arbitrary"``: the steps run one after another, a
+    slot's head groups innermost, because each starts the next one's
+    first copies and hands it the buffer's phase in an SMEM scratch (on
+    a chip that split a ``"parallel"`` axis between cores a copy would be
+    started on one and waited for on the other; the v5e has one).  Who
+    waits for what: a step for the copies of its own iterations, which
+    it or the step before it started — never for another step's.
 
     At a head width under 128 the blocks are handed over transposed,
     ``[d, block_k]``: XLA:TPU keeps a ``[..., 128·n, 64]`` array with
@@ -509,7 +601,9 @@ def _paged_decode_call(q, leaves, tables, cache_len, *, layer=None,
     if layer is None:
         leaves, layer = [a[None] for a in leaves], 0
     block_k = leaves[0].shape[3]
-    packed = leaves[0].shape[2] != leaves[-1].shape[2]
+    kv_heads, kvg, n = pool_walk(leaves[0], leaves[len(leaves) // 2],
+                                 tables.shape[1])
+    packed = leaves[0].shape[2] != kv_heads
     vt = {}
     if packed:
         assert not int8, "a shared value head is served from a float pool"
@@ -518,7 +612,6 @@ def _paged_decode_call(q, leaves, tables, cache_len, *, layer=None,
         if vt["vt"]:
             leaves[1] = jnp.swapaxes(leaves[1], -1, -2)
     b, n_heads, d = q.shape
-    kv_heads = leaves[-1].shape[2]
     if not packed:
         kt = d % 128 != 0
         if kt:
@@ -534,9 +627,6 @@ def _paged_decode_call(q, leaves, tables, cache_len, *, layer=None,
     qg = q.reshape(b, kv_heads, group, d)
     if g_pad != group:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, g_pad - group), (0, 0)))
-    kvg, n = _walk_shape(kv_heads, block_k, d, leaves[0].dtype.itemsize,
-                         tables.shape[1], **({"packed": True} if packed
-                                             else {}))
 
     lens = jnp.broadcast_to(
         jnp.reshape(jnp.asarray(cache_len, jnp.int32), (-1,)), (b,))
@@ -561,13 +651,14 @@ def _paged_decode_call(q, leaves, tables, cache_len, *, layer=None,
                 [pltpu.VMEM((2, n, kvg) + a.shape[3:], a.dtype)
                  for a in leaves]
                 + [pltpu.SemaphoreType.DMA((2, len(leaves))),
+                   pltpu.SMEM((2,), jnp.int32),
                    pltpu.VMEM((kvg * g_pad, 128), jnp.float32),
                    pltpu.VMEM((kvg * g_pad, 128), jnp.float32),
                    pltpu.VMEM((kvg * g_pad, d), jnp.float32)]),
         ),
         out_shape=jax.ShapeDtypeStruct((b, kv_heads, g_pad, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel"),
+            dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interpret,
     )(lens, tbl, lyr, qg, *leaves, *new_rows)

@@ -129,6 +129,7 @@ from ..analysis import sanitizers
 from ..config import ModelConfig
 from ..generation.sampling import NEG_INF
 from ..kernels.flash_attention import tile_plan
+from ..kernels.flash_decode import pool_walk, walk_counts
 from ..kernels.mamba_step import heads_per_step
 from ..models.diff_attention import flash_blocks
 from ..models import model as model_lib
@@ -975,11 +976,12 @@ class _Inflight:
     later groups keep the other pipeline stages busy (bubble fill)."""
 
     __slots__ = ("tok", "tok_lp", "slots", "t_dispatch", "sampling",
-                 "positions", "iter")
+                 "positions", "walk", "iter")
 
     def __init__(self, tok, tok_lp, slots, t_dispatch, sampling,
-                 positions=0, iter=0):
+                 positions=0, iter=0, walk=None):
         self.positions = positions  # cached positions its slots held
+        self.walk = walk or {}    # a call of its paged walk, in grid steps
         self.iter = iter          # the scheduler iteration that dispatched it
         self.tok = tok            # [S] device array (or per-group list)
         self.tok_lp = tok_lp      # [S] logprobs, same layout as ``tok``
@@ -1278,6 +1280,9 @@ class ServingEngine:
         self._kv_readers = ({"full": cfg.kv_layers,
                              "cross": cfg.cross_layers}
                             if cfg.cross_layers else {})
+        # (KV heads, heads a copy) of the paged walk over its pool, read
+        # off the pool at the first step that counts by it (_walk_arg)
+        self._walk_grid = None
         # how a step's recurrent mixers ran, on its decode spans: each
         # between its two projections as one kernel, which advances the
         # layer's states and tails where they lie stacked
@@ -3110,6 +3115,8 @@ class ServingEngine:
         positions = int(fills.sum())
         if self._kv_readers:
             self.metrics.add_kv_walks(positions, self._kv_readers)
+        walk = (self._walk_arg(fills) if self.trace.enabled
+                and (self._latent or self._kv_readers) else None)
         if self.trace.enabled:
             self.trace.add("step_inputs", t_in, t0,
                            args={"iter": self._iter, "live": len(snapshot)})
@@ -3118,7 +3125,27 @@ class ServingEngine:
         if G == 1:
             toks, tok_lps = toks[0], tok_lps[0]
         return _Inflight(toks, tok_lps, snapshot, t0, sampling, positions,
-                         self._iter)
+                         self._iter, walk)
+
+    def _walk_arg(self, fills: np.ndarray) -> dict:
+        """``walk_steps`` / ``walk_prefetched`` of a step's decode spans,
+        beside ``live_positions``: ONE call of the step's paged walk over
+        ``fills`` in grid steps with a live row, and how many of them
+        found their first copies already in flight
+        (kernels/flash_decode.py:walk_counts; a stack that carries them
+        is served without a mesh, so the call's grid is the pool's).  The
+        latent rows' walk (kernels/mla_decode.py) is a grid step a slot,
+        each starting its own first copy."""
+        if self._latent:
+            return {"walk_steps": int(np.count_nonzero(fills)),
+                    "walk_prefetched": 0}
+        if self._walk_grid is None:
+            self._walk_grid = pool_walk(
+                jax.tree.leaves(self.slots.k_pool)[0],
+                jax.tree.leaves(self.slots.v_pool)[0],
+                self.slots.tables.shape[1])[:2]
+        steps, prefetched = walk_counts(fills, *self._walk_grid)
+        return {"walk_steps": steps, "walk_prefetched": prefetched}
 
     # tpulint: hot-path
     def _process_step_results(self, step: _Inflight) -> float:
@@ -3154,7 +3181,8 @@ class ServingEngine:
         committed = 0
         step_arg = self._step_arg
         if (self._latent or self._kv_readers) and self.trace.enabled:
-            step_arg = dict(step_arg, live_positions=step.positions)
+            step_arg = dict(step_arg, live_positions=step.positions,
+                            **step.walk)
         for slot, st in step.slots.items():
             if self._active.get(slot) is not st:
                 # the slot retired (EOS/budget/cancel/deadline) or was

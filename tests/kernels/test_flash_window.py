@@ -132,11 +132,14 @@ def test_the_paged_walk_at_a_value_head_shared_by_two_key_heads(fills):
 
 def test_the_walks_shape_at_a_shared_value_head():
     """The cell's geometry: 20 key heads of 64, packed by pairs into ten
-    of 128, in blocks of 128 rows take five heads and two blocks an
-    iteration (256 columns a term); the plain rule is what it was."""
-    assert fd._walk_shape(10, 128, 128, 2, 64, packed=True) == (5, 2)
+    of 128, in blocks of 128 rows take all ten heads and two blocks an
+    iteration (256 columns a term; a float32 pool's five: two grid steps
+    a slot); the plain rule is what it was, with two blocks where 2.5
+    MiB hold them and 2 did not (20 heads of 64 a copy: no cell's)."""
+    assert fd._walk_shape(10, 128, 128, 2, 64, packed=True) == (10, 2)
+    assert fd._walk_shape(10, 128, 128, 4, 64, packed=True) == (5, 2)
     assert fd._walk_shape(10, 128, 128, 2, 1, packed=True) == (10, 1)
-    assert fd._walk_shape(20, 128, 64, 2, 64) == (20, 1)
+    assert fd._walk_shape(20, 128, 64, 2, 64) == (20, 2)
     assert fd._walk_shape(1, 128, 64, 2, 16) == (1, 4)
 
 

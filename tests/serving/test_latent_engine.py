@@ -76,6 +76,9 @@ def test_five_requests_over_two_slots_against_the_reference(model):
     assert len(pre) == 5 and {a["attn"] for a in pre} == {"mla_expanded"}
     assert dec and {a["attn"] for a in dec} == {"mla_absorbed"}
     assert all(a["live_positions"] >= 21 * a["live"] for a in dec)
+    # the latent rows' walk: a grid step a slot, nothing started ahead
+    assert all((a["walk_steps"], a["walk_prefetched"]) == (a["live"], 0)
+               for a in dec)
     assert {a["experts"] for a in pre} == {"grouped"}
     # the experts were counted: none for the dense first layer
     assert load.shape == (3, 8) and load[0].sum() == 0

@@ -12,6 +12,7 @@ import pytest
 
 from benchmarks.reference import phi4flash as ref
 from megatron_llm_tpu.config import phi4flash_config
+from megatron_llm_tpu.kernels.flash_decode import pool_walk
 from megatron_llm_tpu.models import model as model_lib
 from megatron_llm_tpu.obs.registry import REGISTRY
 from megatron_llm_tpu.serving import EngineConfig, ServingEngine
@@ -143,6 +144,12 @@ def test_the_spans_say_what_a_prefill_and_a_step_did(model):
                == 3 * 7 * 128 * 4 + 2 * 8 * 64 * 4 for e in prefills)
     assert all(e["args"]["live"] == 1 for e in decodes)
     assert {e["args"]["live_positions"] for e in decodes} >= {40, 41, 50}
+    # a call of the step's paged walk: one live slot's head groups, every
+    # one but the call's first with its first copies already in flight
+    heads, kvg, _ = pool_walk(eng.slots.k_pool, eng.slots.v_pool,
+                              eng.slots.tables.shape[1])
+    assert all((e["args"]["walk_steps"], e["args"]["walk_prefetched"])
+               == (heads // kvg, heads // kvg - 1) for e in decodes)
     # no field of the other state-space mixer's kernel
     assert not any("ssm_step" in e["args"] or "gdn" in e["args"]
                    for e in prefills + decodes)

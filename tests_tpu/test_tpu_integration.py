@@ -150,6 +150,80 @@ def test_flash_decode_kernel_parity_on_hw():
         assert d < 0.02, (h, kv, M, cl, d)
 
 
+# slots, table width, query heads, key heads x width, value heads x width,
+# fills, softmax scale: the long-generation cell's packed pairs (two grid
+# steps a slot), the short-request cell's, the chat cell's
+_WALKS = {
+    "pairs40x64kv20v10": (64, 64, 40, (20, 64), (10, 128), (1024, 6000),
+                          0.125),
+    "gqa32x64kv8": (64, 24, 32, (8, 64), (8, 64), (64, 3000), 1.0 / 64),
+    "falcon71x64mqa": (16, 16, 71, (1, 64), (1, 64), (50, 700), 0.125),
+}
+
+
+@pytest.mark.parametrize("geometry", list(_WALKS))
+def test_paged_walk_matches_float32_composition_on_hw(geometry):
+    """The paged walk's copies cross grid steps (a step's last iteration
+    starts the next live step's first blocks), which interpret mode
+    orders trivially and the chip does not: at the cells' geometries,
+    with empty slots first, last, between live ones and two in a row,
+    one-iteration slots and whole iterations, every slot's output lies
+    as close to the plain float32 composition over its gathered rows as
+    bf16 allows, and an empty slot's is its own value row."""
+    from megatron_llm_tpu.kernels.flash_decode import (flash_decode_paged,
+                                                       pool_walk)
+
+    S, T, H, (KV, dk), (VH, dv), (lo, hi), scale = _WALKS[geometry]
+    bk, nb = 128, S * T + 1
+    key = jax.random.key(57)
+    rnd = lambda i, shape: jax.random.normal(  # noqa: E731
+        jax.random.fold_in(key, i), shape, jnp.float32).astype(jnp.bfloat16)
+    kp, vp = rnd(0, (1, nb, KV, bk, dk)), rnd(1, (1, nb, VH, bk, dv))
+    q, kn, vn = rnd(2, (S, H, dk)), rnd(3, (S, KV, 1, dk)), rnd(
+        4, (S, VH, 1, dv))
+    n = pool_walk(kp, vp, T)[2]
+    rng = np.random.default_rng(57)
+    fills = rng.integers(lo, hi, S).astype(np.int32)
+    fills[[0, 2, 9, 10, S - 1]] = 0
+    fills[3:9] = [n * bk, n * bk + 1, 1, bk, n * bk - 1, 7]
+    tables, ids, used = np.zeros((S, T), np.int32), rng.permutation(
+        np.arange(1, nb)), 0
+    for i, f in enumerate(fills):
+        own = min(T, -(-int(f + 1) // bk))
+        tables[i, :own] = ids[used:used + own]
+        used += own
+    got = jax.jit(lambda *a: flash_decode_paged(
+        *a[:5], new_rows=a[5:], layer=jnp.int32(0), softmax_scale=scale))(
+            q, kp, vp, jnp.asarray(tables), jnp.asarray(fills), kn, vn)
+
+    def one(args):
+        q, t, f, kn, vn = args
+        kd = jnp.moveaxis(kp[0][t], 1, 0).reshape(KV, -1, dk)
+        vd = jnp.moveaxis(vp[0][t], 1, 0).reshape(VH, -1, dv)
+        kd = jnp.concatenate([kd, kn], axis=1).astype(jnp.float32)
+        vd = jnp.concatenate([vd, vn], axis=1).astype(jnp.float32)
+        cols = jnp.arange(kd.shape[1])
+        keep = (cols < f) | (cols == kd.shape[1] - 1)
+        s = jnp.einsum("kgd,ktd->kgt", q.astype(jnp.float32).reshape(
+            KV, H // KV, dk), kd, precision="highest") * scale
+        p = jax.nn.softmax(jnp.where(keep, s, -jnp.inf), axis=-1)
+        return jnp.einsum("vgt,vtd->vgd", p.reshape(VH, H // VH, -1), vd,
+                          precision="highest").reshape(H, dv)
+
+    want = jax.jit(lambda *a: jax.lax.map(one, a))(
+        q, jnp.asarray(tables), jnp.asarray(fills), kn, vn)
+    got, want = np.asarray(got, np.float32), np.asarray(want)
+    assert np.isfinite(got).all()
+    worst = max(np.linalg.norm(got[i] - want[i]) / np.linalg.norm(want[i])
+                for i in range(S))
+    assert worst < 0.006, worst
+    for i in np.flatnonzero(fills == 0):
+        np.testing.assert_array_equal(
+            got[i].reshape(VH, H // VH, dv),
+            np.broadcast_to(np.asarray(vn[i], np.float32),
+                            (VH, H // VH, dv)))
+
+
 def test_int8_decode_runs_and_kernel_matches_einsum():
     """Full int8 decode (weights + KV cache) runs on the real chip, and
     the Pallas int8 decode kernel matches an independently-computed
