@@ -33,14 +33,17 @@ class PositionEmbeddingType:
 # the block kinds of ModelConfig.layer_pattern, and of them those that keep
 # keys and values, those that keep a state-space state, and those with a
 # feed-forward part
-BLOCK_KINDS = ("full", "linear", "ssm", "attention", "mamba", "mlp")
+BLOCK_KINDS = ("full", "linear", "ssm", "attention", "mamba", "mlp",
+               "window")
 KV_KINDS = ("full", "attention")
 MAMBA_KINDS = ("ssm", "mamba")
-FFN_KINDS = ("full", "linear", "ssm", "mlp")
+FFN_KINDS = ("full", "linear", "ssm", "mlp", "window")
 # the further block kinds of a stack of runs (ModelConfig.layer_runs),
 # beside "full", each a mixer and the MLP: a Mamba-1 mixer, attention over
-# a window kept as a ring a slot, a gated memory unit, attention with
-# another layer's keys and values
+# a window kept as a ring a slot ("window": the period scan's kind too,
+# one meaning for both: ``sliding_window`` keys a query, a ring a slot in
+# ``init_rec_state``'s ``win_k`` / ``win_v``, no pool layer), a gated
+# memory unit, attention with another layer's keys and values
 RUN_KINDS = ("ssm1", "window", "gmu", "cross")
 
 
@@ -257,6 +260,17 @@ class ModelConfig:
     rotary_percent: float = 1.0
     qk_norm: bool = False
     attn_output_gate: bool = False
+    # rotate-half at every share (a share under 1 is rotated so whatever
+    # this says), the angles from the positions and no table; under
+    # rope_scaling_type "yarn" the rotated dimensions take YaRN's
+    # frequencies and cos and sin its factor (ops/rope.py:rotation_of)
+    rope_rotate_half: bool = False
+    # a sigmoid gate a HEAD, from the layer's input (``wg`` [hidden,
+    # heads], kept and applied in float32 as the router is): a head's
+    # output is multiplied by its gate before the output projection (the
+    # head-wise variant of arXiv 2505.06708; attn_output_gate above is the
+    # element-wise one, projected beside q)
+    attn_head_gate: bool = False
     # Dropless routing (softmax over the router's outputs, top-k,
     # renormalise; no capacity, no drop) beside the capacity routing above.
     # The router keeps moe_router_experts outputs (0 = num_experts) of which
@@ -332,6 +346,17 @@ class ModelConfig:
     diff_attention: bool = False
     # keys a query of a "window" layer sees, its own among them
     sliding_window: int = 0
+    # What a "window" layer of the period scan has of its own (None: the
+    # model's): its query heads (the KV heads are the model's, so the
+    # query-to-KV-head ratio differs by kind), and its rotation ``(theta,
+    # share of the head rotated, scaling factor)`` in place of rope_theta,
+    # rotary_percent and rope_scaling_factor (``window_layer_config``).
+    window_attention_heads: Optional[int] = None
+    window_rope: Optional[tuple] = None
+    # the kind of the leading dense layers (moe_first_dense_layers) where
+    # it is not the period's first: "full" before a period that starts
+    # with "window"
+    lead_layer_kind: Optional[str] = None
     # Mamba-1 geometry ("ssm1", models/mamba1.py): inner width, state
     # columns a channel, convolution taps, the step's bottleneck
     mamba1_inner: int = 0
@@ -345,6 +370,9 @@ class ModelConfig:
         object.__setattr__(self, "layer_pattern", tuple(self.layer_pattern))
         object.__setattr__(self, "layer_runs", tuple(
             (tuple(period), int(times)) for period, times in self.layer_runs))
+        if self.window_rope is not None:
+            object.__setattr__(self, "window_rope", tuple(
+                float(x) for x in self.window_rope))
 
     @property
     def kv_heads(self) -> int:
@@ -381,11 +409,30 @@ class ModelConfig:
             ffn_hidden_size=self.moe_dense_ffn_size)
 
     @property
+    def window_layer_config(self) -> "ModelConfig":
+        """A "window" layer's attention as a configuration: this one with
+        the window layers' own head count and rotation."""
+        theta, share, factor = self.window_rope or (
+            self.rope_theta, self.rotary_percent, self.rope_scaling_factor)
+        return dataclasses.replace(
+            self, num_attention_heads=(self.window_attention_heads
+                                       or self.num_attention_heads),
+            rope_theta=theta, rotary_percent=share,
+            rope_scaling_factor=factor, window_attention_heads=None,
+            window_rope=None)
+
+    @property
+    def lead_kind(self) -> str:
+        """The block kind of the leading dense layers."""
+        return self.lead_layer_kind or (self.layer_pattern or ("full",))[0]
+
+    @property
     def layer_kinds(self) -> tuple:
         """The block kind of every layer, in order (the leading dense
-        layers are of the period's first kind)."""
+        layers are of ``lead_kind``: the period's first, or the one
+        stated)."""
         period = self.layer_pattern or ("full",)
-        return (period[:1] * self.moe_first_dense_layers
+        return ((self.lead_kind,) * self.moe_first_dense_layers
                 + period * (self.scanned_layers // len(period)))
 
     @property
@@ -490,6 +537,13 @@ class ModelConfig:
             assert self.mamba_num_heads % self.mamba_n_groups == 0
         if self.layer_runs:
             self._validate_runs()
+        elif "window" in self.layer_pattern:
+            self._validate_window()
+        else:
+            assert (self.window_attention_heads is None
+                    and self.window_rope is None), (
+                "window_attention_heads and window_rope are a \"window\" "
+                "layer's of the period scan")
         if self.kv_lora_rank:
             self._validate_latent_attention()
         else:
@@ -501,7 +555,7 @@ class ModelConfig:
             assert self.layer_pattern and self.num_experts > 0, (
                 "moe_first_dense_layers: leading dense layers stand "
                 "before a period-scanned stack of expert layers")
-            assert self.layer_pattern[0] == "full", (
+            assert self.lead_kind == "full", (
                 "a leading dense layer is a two-part block (\"full\")")
             assert 0 < self.moe_first_dense_layers < self.num_layers
             assert self.moe_dense_ffn_size > 0, (
@@ -578,6 +632,31 @@ class ModelConfig:
                 flat.index("full") < flat.index("cross")), (
                 "\"cross\" layers read the keys and values of the one "
                 "\"full\" layer before them")
+
+    def _validate_window(self) -> None:
+        """A "window" layer of the period scan as models/transformer.py
+        carries it: ordinary attention (grouped heads, a rotation, a gate
+        a head) on a ring of ``sliding_window`` rows a slot."""
+        assert self.sliding_window > 0, "\"window\" needs sliding_window"
+        assert not (self.diff_attention or self.kv_lora_rank
+                    or self.parallel_attn or self.attn_output_gate
+                    or self.kv_cache_quant != "none"
+                    or self.context_parallel_axis is not None), (
+            "a \"window\" layer of the period scan: no differential or "
+            "latent attention, parallel block, element-wise output gate, "
+            "8-bit K/V or context parallelism")
+        if self.position_embedding_type == PositionEmbeddingType.ROTARY:
+            assert self.rope_rotate_half, (
+                "a ring's rows are rotated at their own positions, from "
+                "the positions (rope_rotate_half), not from a table")
+        w = self.window_layer_config
+        assert w.num_attention_heads % self.kv_heads == 0, (
+            "the window layers' query heads are whole groups of KV heads")
+        assert 0.0 < w.rotary_percent <= 1.0
+        assert (w.rope_scaling_factor == 1.0
+                or self.rope_scaling_type == "yarn"), (
+            "a rotation from the positions is scaled by YaRN's "
+            "frequencies or not at all")
 
     @property
     def row_cut_layer(self) -> Optional[int]:
@@ -1384,6 +1463,97 @@ def phi4flash_config(size: str = "mini-flash-reasoning",
     return ModelConfig(**base).validate()
 
 
+def laguna_config(size: str = "xs.2-pp8-stage0", **overrides) -> ModelConfig:
+    """``model_type: laguna`` (poolside/Laguna-XS.2, "33B-A3B"): every
+    layer attention and a feed-forward part, each under an RMSNorm of its
+    own, no bias.  Attention is full in every fourth layer (0, 4, 8 ...:
+    48 query heads) and over a window of 512 keys in the others (64 query
+    heads), 8 key/value heads of 128 in both, each kind with a rotation
+    of its own: the window layers the whole head at theta 10 000, the
+    full layers the first half of the head at theta 500 000 with YaRN's
+    frequencies and factor (rotate-half, from the positions); one sigmoid
+    gate a head from the layer's input.  Layer 0 holds a dense gated SiLU
+    MLP of 8192; every other layer 256 sigmoid-scored experts of 512 (top
+    8 by score + bias, weights renormalised and scaled by 2.5) beside an
+    un-gated shared expert of 512; untied head.  Served only.
+
+    The stack is the leading dense layer (``lead_layer_kind`` "full") and
+    periods of ``("window", "window", "window", "full")``.
+    ``xs.2-pp8-stage0`` is the first of eight pipeline stages of five
+    layers: the dense layer and one whole period, every expert and the
+    whole vocabulary.  ``xs.2`` names the published 40 layers, which are
+    the leading layer, NINE periods and a last run of three window
+    layers: 39 scanned layers are not whole periods, so it is refused
+    until the period scan takes a last partial period (a stack of runs,
+    ``layer_runs`` ``(((w, w, w, f), 9), ((w, w, w), 1))``, with experts
+    and a leading layer: ROADMAP R3 a0).  ``layer_pattern`` as an
+    override (a test's or a rehearsal's small stack) with a depth that is
+    not whole periods gives the leading layer and one period."""
+    pattern = ("window", "window", "window", "full")
+    base = dict(
+        norm_type="rmsnorm",
+        norm_eps=1e-6,
+        activation="swiglu",
+        position_embedding_type=PositionEmbeddingType.ROTARY,
+        rope_rotate_half=True,
+        # the full layers' rotation: the model's
+        rope_theta=500000.0,
+        rotary_percent=0.5,
+        rope_scaling_type="yarn",
+        rope_scaling_factor=64.0,
+        rope_original_max_positions=4096,
+        rope_beta_fast=64.0,
+        rope_beta_slow=1.0,
+        rope_attention_factor=1.4158883083359672,
+        # the window layers': the whole head, theta 10 000, unscaled
+        window_rope=(10000.0, 1.0, 1.0),
+        window_attention_heads=64,
+        sliding_window=512,
+        attn_head_gate=True,
+        use_bias=False,
+        tie_embed_logits=False,
+        hidden_size=2048,
+        num_layers=40,
+        num_attention_heads=48,
+        num_kv_heads=8,
+        kv_channels=128,
+        layer_pattern=pattern,
+        lead_layer_kind="full",
+        moe_first_dense_layers=1,
+        moe_dense_ffn_size=8192,
+        ffn_hidden_size=512,
+        num_experts=256,
+        moe_top_k=8,
+        moe_dropless=True,
+        moe_router_scoring="sigmoid",
+        moe_routed_scaling=2.5,
+        moe_shared_expert_size=512,
+        moe_shared_expert_gated=False,
+        # a whole 16k-position prompt is routed at once (8 pairs a token)
+        moe_group_size=16384,
+        vocab_size=100352,
+        max_position_embeddings=262144,
+        seq_length=4096,
+        recompute="none",
+    )
+    sizes = {
+        "xs.2": dict(),
+        "xs.2-pp8-stage0": dict(num_layers=5),
+    }
+    base.update(sizes[size])
+    base.update(overrides)
+    lead, period = base["moe_first_dense_layers"], len(base["layer_pattern"])
+    if (base["num_layers"] - lead) % period:
+        if "layer_pattern" not in overrides:
+            raise ValueError(
+                f"num_layers {base['num_layers']}: the period scan takes "
+                f"the leading layer and whole periods of {period}; the "
+                "published 40 layers end in a run of three window layers "
+                "(a last partial period is not carried)")
+        base["num_layers"] = lead + period
+    return ModelConfig(**base).validate()
+
+
 def gpt_config(size: str = "345m", **overrides) -> ModelConfig:
     """GPT-2/3 style: learned absolute positions, LayerNorm, gelu, tied
     embeddings, biases (reference: megatron/model/gpt_model.py)."""
@@ -1447,6 +1617,7 @@ PRESETS = {
     "kanana-2-30b-a3b": lambda: deepseek_v3_config("kanana-2-30b-a3b"),
     "phi-4-mini-flash-reasoning": lambda: phi4flash_config(
         "mini-flash-reasoning"),
+    "laguna-xs.2-pp8-stage0": lambda: laguna_config("xs.2-pp8-stage0"),
     "tiny": tiny_config,
 }
 

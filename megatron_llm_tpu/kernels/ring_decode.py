@@ -3,23 +3,31 @@ and values: a "window" layer's decode step (models/diff_attention.py).
 
 The rings of all the window layers lie stacked, ``ring_k`` and ``ring_v``
 [L, slots, kv / r, W, r d]: a value head is shared by ``r`` consecutive
-key heads (differential attention's pair) and a row of the key ring holds
-those ``r`` heads' keys side by side, position ``t`` of a slot at row ``t
-% W``.  The kernel
+key heads and a row of the key ring holds those ``r`` heads' keys side by
+side, position ``t`` of a slot at row ``t % W``.  ``r = 2`` is
+differential attention's pair (a stack of runs); ``r = 1`` ordinary
+grouped heads, a ring row one key head's (the period scan's "window"
+kind: 8 KV heads of 128 with 8 query rows each at Laguna-XS.2's widths).
+The kernel
 takes the stacked arrays and the layer's index: a layer scan that slices
 its layer out for a plain product copies that layer's key ring in every
-step (0.67 GB a step at the published size; PERF.md, PR 56).  One grid
-step a slot: the slot's two blocks are the next grid step's while this
-one is attended (the block pipeline), the ``r`` key heads of a value head
-packed into one head ``r d`` wide (``flash_decode.pack_queries``), so a
-value head is one score product, one softmax over the ``W`` columns and
+step (0.67 GB a step at phi-4-mini-flash's size; PERF.md, PR 56).  One
+grid step a slot: the slot's two blocks are the next grid step's while
+this one is attended (the block pipeline), the ``r`` key heads of a value
+head packed into one head ``r d`` wide (``flash_decode.pack_queries``), so
+a value head is one score product, one softmax over the ``W`` columns and
 the new position's own, and one product with the values.
 
-No rotation, so the order of the rows does not matter: row ``c`` counts
-where it holds one of the ``W - 1`` positions before the new one, ``c <
-pos`` but for the row the new position will take; the new
+The order of the rows does not matter, under a rotation as without one:
+a softmax is a sum over the keys it counts, whatever order they lie in,
+and where the model rotates, a key was rotated at its OWN position before
+it went to the ring (by the prompt's install and by a step's write alike)
+and the query comes rotated at its own, so a score already carries the
+distance between the two and the kernel needs no positions.  Row ``c``
+counts where it holds one of the ``W - 1`` positions before the new one,
+``c < pos`` but for the row the new position will take; the new
 position's own row is attended beside the ring and written after the
-layer loop (models/model.py:ring_append_rows)."""
+layer loop (models/transformer.py:ring_append_rows)."""
 
 from __future__ import annotations
 

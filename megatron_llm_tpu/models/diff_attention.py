@@ -206,16 +206,20 @@ def attend_rows(cfg: ModelConfig, q, k, v, last):
 def attend_ring(cfg: ModelConfig, q, ring_k, ring_v, layer, k_new, v_new,
                 pos):
     """One new position a slot on its ring.  ``q`` [b, 1, heads, d]
-    (kernel order); ``ring_k ring_v`` [window layers, b, kv / 2, W, 2 d]
-    the stacked rings (``pair_rows``), of which ``layer``'s (a traced
-    scalar in a layer scan) is attended: it holds position ``t`` at row
-    ``t % W``; ``k_new`` [b, kv, 1, d] and ``v_new`` [b, kv / 2, 1, 2 d]
-    the new position ``pos`` [b], not in the ring yet.  No rotation, so
-    the order of the rows does not matter: row ``r`` counts where it holds
-    one of the ``W - 1`` positions before ``pos``, which is ``r < pos``
-    but for the row the new position will take.  The kernel
-    (kernels/ring_decode.py) where the configuration asks for kernels,
-    else the plain composition."""
+    (consecutive heads on a key head: kernel order); ``ring_k ring_v``
+    [window layers, b, kv / r, W, r d] the stacked rings (``r`` key heads
+    a value head: ``pair_rows``' 2 under differential attention, 1 for
+    ordinary grouped heads), of which ``layer``'s (a traced scalar in a
+    layer scan) is attended: it holds position ``t`` at row ``t % W``;
+    ``k_new`` [b, kv, 1, d] and ``v_new`` [b, kv / r, 1, r d] the new
+    position ``pos`` [b], not in the ring yet.  The order of the rows does
+    not matter: where the model rotates, a row holds its key rotated at
+    its own position already, and ``q`` comes rotated at ``pos``.  Row
+    ``c`` counts where it holds one of the ``W - 1`` positions before
+    ``pos``, which is ``c < pos`` but for the row the new position will
+    take.  The kernel (kernels/ring_decode.py) where the configuration
+    asks for kernels, else the plain composition.  ``cfg``: the
+    configuration whose heads ``q`` has (a window layer's view of it)."""
     if cfg.attention_impl == "flash":
         from ..kernels.ring_decode import ring_decode
 

@@ -17,6 +17,7 @@ from ..config import (
     codellama_config,
     falcon_config,
     gpt_config,
+    laguna_config,
     llama1_config,
     llama2_config,
     phi4flash_config,
@@ -86,6 +87,14 @@ def phi4flash(size: str = "mini-flash-reasoning", **overrides) -> CausalLM:
     runs, served only (docs/serving.md, "A stack of runs"); the engine
     wants ``prefix_cache_blocks=0`` for it as for every hybrid stack."""
     return CausalLM(phi4flash_config(size, **overrides))
+
+
+def laguna(size: str = "xs.2-pp8-stage0", **overrides) -> CausalLM:
+    """Laguna-XS.2 (``model_type: laguna``): window and full attention
+    layers in one scanned period, sparse experts; served only
+    (docs/serving.md, "The ring under rotation"); the engine wants
+    ``prefix_cache_blocks=0`` for it as for every hybrid stack."""
+    return CausalLM(laguna_config(size, **overrides))
 
 
 def draft_model(name: str, target: ModelConfig, **overrides) -> CausalLM:

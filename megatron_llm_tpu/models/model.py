@@ -20,6 +20,7 @@ from ..config import ModelConfig, PositionEmbeddingType
 from . import gated_deltanet, mamba1, mamba2
 from .transformer import (
     RING_NAMES,
+    ring_append_rows,
     STREAM_DTYPE,
     AttnSideInputs,
     Params,
@@ -128,11 +129,12 @@ def level_router_bias(cfg: ModelConfig, params: Params, key: jax.Array,
             p = layer_of(j, i)
             h1 = (norm_apply(cfg.norm_type, x, p["input_norm"],
                              cfg.norm_eps, impl=cfg.norm_impl)
-                  if kinds[j] == "mlp" else ffn_input(cfg, p, x, side))
+                  if kinds[j] == "mlp"
+                  else ffn_input(cfg, p, x, side, kinds[j]))
             bias = level_bias(cfg, p["mlp"], h1[0])
             stacks[j] = {**stacks[j], "mlp": {
                 **mlp, "router_bias": mlp["router_bias"].at[i].set(bias)}}
-        x = layer_forward(cfg, layer_of(j, i), x, side)[0]
+        x = layer_forward(cfg, layer_of(j, i), x, side, kind=kinds[j])[0]
     return {**params, "layers": stacks}
 
 
@@ -808,27 +810,6 @@ def _forward_cached_runs(cfg: ModelConfig, params: Params, x, side, k_cache,
             x, logit_rows.astype(jnp.int32)[:, None, None], axis=1)
     return (unembed(cfg, params, x), k_cache, v_cache,
             {**states, "load": rec["load"], "rows": rec["rows"]})
-
-
-@jax.named_scope("swa")
-def ring_append_rows(rings, rows, positions):
-    """Write a step's new rows into the "window" layers' rings, in
-    place: ``rings`` (k and v, each ``[window layers, slots, heads, W,
-    width]``), ``rows`` the same with one position, slot ``s``'s at row
-    ``positions[s] % W`` (a free slot rewrites a row of its own dead
-    ring).  One ``dynamic_update_slice`` a slot over all the layers, as
-    ``cache_append_rows`` writes the pool."""
-    zero = jnp.int32(0)
-
-    def ap(ring, r):
-        at = positions % ring.shape[3]
-        for s_ in range(r.shape[1]):
-            ring = jax.lax.dynamic_update_slice(
-                ring, r[:, s_:s_ + 1].astype(ring.dtype),
-                [zero, jnp.int32(s_), zero, at[s_], zero])
-        return ring
-
-    return tuple(ap(ring, r) for ring, r in zip(rings, rows))
 
 
 def forward_paged_hybrid(cfg: ModelConfig, params: Params, tokens, k_pool,

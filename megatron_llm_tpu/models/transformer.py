@@ -45,6 +45,7 @@ from ..ops.rope import (
     apply_rope_flat,
     apply_rope_partial,
     precompute_rope_freqs,
+    rotation_of,
 )
 from . import diff_attention, gated_deltanet, mamba1, mamba2, mla
 from .gated_deltanet import GDNState, gdn_block, init_gdn_params
@@ -81,7 +82,9 @@ def init_layer_params(key: jax.Array, cfg: ModelConfig,
     ``"input_norm"`` alone."""
     h = cfg.hidden_size
     d = cfg.head_dim
-    nq = cfg.num_attention_heads
+    # (a "window" layer of the period scan has a head count of its own)
+    nq = (cfg.window_layer_config if kind == "window"
+          else cfg).num_attention_heads
     nkv = cfg.kv_heads
     ffn = cfg.ffn_size
     dtype = cfg.dtype
@@ -100,7 +103,7 @@ def init_layer_params(key: jax.Array, cfg: ModelConfig,
         layer["gmu"] = diff_attention.init_gmu_params(keys[7], cfg)
     elif kind in KV_KINDS and cfg.kv_lora_rank:
         layer["attn"] = mla.init_mla_params(keys[0], cfg, std, out_std)
-    elif kind in KV_KINDS:
+    elif kind in KV_KINDS or kind == "window":
         attn: Params = {
             # with an output gate: per head, the query's columns then the
             # gate's
@@ -119,6 +122,10 @@ def init_layer_params(key: jax.Array, cfg: ModelConfig,
         if cfg.qk_norm:
             attn["q_norm"] = norm_init(cfg.norm_type, d, dtype)
             attn["k_norm"] = norm_init(cfg.norm_type, d, dtype)
+        if cfg.attn_head_gate:
+            # kept in float32, as the router is: one scalar a head
+            attn["wg"] = std * jax.random.normal(
+                jax.random.fold_in(keys[0], 1), (h, nq), jnp.float32)
         layer["attn"] = attn
     elif kind == "linear":
         layer["gdn"] = init_gdn_params(keys[7], cfg)
@@ -187,12 +194,13 @@ def init_stack_params(key: jax.Array, cfg: ModelConfig,
 
 def init_lead_params(key: jax.Array, cfg: ModelConfig) -> Params:
     """The ``cfg.moe_first_dense_layers`` leading layers, stacked on a
-    leading axis beside the scanned stack (``params["lead_layers"]``): the
-    period's first block with a dense MLP of ``cfg.moe_dense_ffn_size`` in
-    place of the experts (``cfg.lead_layer_config``)."""
+    leading axis beside the scanned stack (``params["lead_layers"]``): a
+    block of ``cfg.lead_kind`` (the period's first kind, or the one
+    stated) with a dense MLP of ``cfg.moe_dense_ffn_size`` in place of
+    the experts (``cfg.lead_layer_config``)."""
     keys = jax.random.split(key, cfg.moe_first_dense_layers)
     return jax.vmap(lambda k: init_layer_params(
-        k, cfg.lead_layer_config, cfg.layer_pattern[0]))(keys)
+        k, cfg.lead_layer_config, cfg.lead_kind))(keys)
 
 
 def _lead_layers(lead):
@@ -334,29 +342,13 @@ def _lora_add(y: jax.Array, x: jax.Array, lora, target: str) -> jax.Array:
     return (y + lora_delta(x, f["a"], f["b"], mask)).astype(y.dtype)
 
 
-@jax.named_scope("attention")
-def attention_block(cfg: ModelConfig, p: Params, x: jax.Array,
-                    side: AttnSideInputs, layer_rng,
-                    kv_cache: Optional[tuple] = None, lora=None):
-    """QKV projection → RoPE → attention → output projection.
-
-    Parity: megatron/model/transformer.py:412-565 (ParallelAttention) with
-    GQA/MQA handled inside the attention einsum rather than by tiling K/V.
-
-    ``kv_cache`` is an optional ``(k_cache, v_cache, length)`` triple
-    (head-major [b, nkv, max_len, d] ×2 + scalar int32) for incremental
-    decoding (the reference's InferenceParams KV cache,
-    transformer.py:423-496).  When given, the return value is
-    ``(out, (new_k_rows, new_v_rows))`` — the new tokens' [b, nkv, s, d]
-    rows, NOT an updated cache; the caller owns the write-back.  Its
-    paged form is a :class:`PagedKV` (one new token a slot, KV read
-    through the block tables by the paged kernel); the rows then come
-    back in the form the pool stores them (``kv_quant.rows_as_stored``).
-
-    ``lora`` is the per-layer ``(factors, mask)`` bundle (see
-    :func:`_lora_add`); deltas land right after each base projection,
-    before bias/reshape/RoPE.
-    """
+def _project_heads(cfg: ModelConfig, p: Params, x: jax.Array,
+                   side: AttnSideInputs, paged: bool = False, lora=None):
+    """An attention part's projections, cut into heads and rotated:
+    ``x`` [b, s, h] -> ``(q [b, s, heads, d], k, v [b, s, kv_heads, d],
+    the element-wise output gate [b, s, heads * d] or None)``.  ``paged``:
+    the paged route's few rows, which a table's rotation takes as the
+    matmul leaves them."""
     b, s, h = x.shape
     d = cfg.head_dim
     nq = cfg.num_attention_heads
@@ -375,18 +367,14 @@ def attention_block(cfg: ModelConfig, p: Params, x: jax.Array,
         k = k + p["bk"]
         v = v + p["bv"]
     position_ids = side.position_ids
-    if kv_cache is not None and position_ids is None:
-        raise ValueError("kv_cache requires explicit position_ids "
-                         "(forward_cached supplies them)")
 
     rotary = cfg.position_embedding_type == PositionEmbeddingType.ROTARY
-    partial = rotary and cfg.rotary_percent < 1.0
+    partial = rotary and (cfg.rotary_percent < 1.0 or cfg.rope_rotate_half)
     # the paged route's few rows are rotated as the matmul leaves them:
     # cut into heads first, the q projection re-lays wq in every call
     # (apply_rope_flat).  Not under a mesh, where tp splits the row and
     # the shift along it would cross shards in every layer.
-    flat = (rotary and not partial and isinstance(kv_cache, PagedKV)
-            and not _mesh_active())
+    flat = (rotary and not partial and paged and not _mesh_active())
     if flat:
         q = apply_rope_flat(q, side.rope_cos, side.rope_sin, position_ids, d)
         k = apply_rope_flat(k, side.rope_cos, side.rope_sin, position_ids, d)
@@ -399,14 +387,73 @@ def attention_block(cfg: ModelConfig, p: Params, x: jax.Array,
     if partial:
         pos = position_ids if position_ids is not None else \
             jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None, :], (b, s))
-        rot = int(d * cfg.rotary_percent)
-        q = apply_rope_partial(q, pos, rot, cfg.rope_theta)
-        k = apply_rope_partial(k, pos, rot, cfg.rope_theta)
+        rot, inv_freq, scale = rotation_of(cfg)
+        q = apply_rope_partial(q, pos, rot, cfg.rope_theta, inv_freq, scale)
+        k = apply_rope_partial(k, pos, rot, cfg.rope_theta, inv_freq, scale)
     elif rotary and not flat:
         q = apply_rope(q, side.rope_cos, side.rope_sin, position_ids)
         k = apply_rope(k, side.rope_cos, side.rope_sin, position_ids)
+    return q, k, v, gate
 
-    softmax_scale = (1.0 / (d ** 0.5) if cfg.attention_multiplier is None
+
+def _project_out(cfg: ModelConfig, p: Params, ctx: jax.Array, gate,
+                 gate_x, lora=None):
+    """An attention part's way out: ``ctx`` [b, s, heads, d] under its
+    gate (the element-wise one projected beside q, or one scalar a head
+    from the layer's input ``gate_x``, in float32) through the output
+    projection."""
+    b, s = ctx.shape[:2]
+    nq, d = cfg.num_attention_heads, cfg.head_dim
+    if cfg.attn_head_gate:
+        g = jax.nn.sigmoid(jnp.dot(
+            gate_x.astype(jnp.float32), p["wg"].astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))
+        ctx = (ctx.reshape(b, s, nq, d).astype(jnp.float32)
+               * g[..., None]).astype(ctx.dtype)
+    ctx2d = ctx.reshape(b, s, nq * d)
+    if gate is not None:
+        ctx2d = (ctx2d * jax.nn.sigmoid(gate.astype(jnp.float32))
+                 ).astype(ctx2d.dtype)
+    out = _lora_add(proj(cfg, ctx2d, p["wo"]), ctx2d, lora, "wo")
+    if "bo" in p:
+        out = out + p["bo"]
+    return out
+
+
+@jax.named_scope("attention")
+def attention_block(cfg: ModelConfig, p: Params, x: jax.Array,
+                    side: AttnSideInputs, layer_rng,
+                    kv_cache: Optional[tuple] = None, lora=None,
+                    gate_x=None):
+    """QKV projection → RoPE → attention → output projection.
+
+    Parity: megatron/model/transformer.py:412-565 (ParallelAttention) with
+    GQA/MQA handled inside the attention einsum rather than by tiling K/V.
+
+    ``kv_cache`` is an optional ``(k_cache, v_cache, length)`` triple
+    (head-major [b, nkv, max_len, d] ×2 + scalar int32) for incremental
+    decoding (the reference's InferenceParams KV cache,
+    transformer.py:423-496).  When given, the return value is
+    ``(out, (new_k_rows, new_v_rows))`` — the new tokens' [b, nkv, s, d]
+    rows, NOT an updated cache; the caller owns the write-back.  Its
+    paged form is a :class:`PagedKV` (one new token a slot, KV read
+    through the block tables by the paged kernel); the rows then come
+    back in the form the pool stores them (``kv_quant.rows_as_stored``).
+
+    ``lora`` is the per-layer ``(factors, mask)`` bundle (see
+    :func:`_lora_add`); deltas land right after each base projection,
+    before bias/reshape/RoPE.
+
+    ``gate_x``: what a gate a head (``cfg.attn_head_gate``) reads, the
+    layer's input as the float32 stream has it (None: ``x``).
+    """
+    if kv_cache is not None and side.position_ids is None:
+        raise ValueError("kv_cache requires explicit position_ids "
+                         "(forward_cached supplies them)")
+    q, k, v, gate = _project_heads(cfg, p, x, side,
+                                   isinstance(kv_cache, PagedKV), lora)
+    softmax_scale = (1.0 / (cfg.head_dim ** 0.5)
+                     if cfg.attention_multiplier is None
                      else cfg.attention_multiplier)
     if cfg.apply_query_key_layer_scaling:
         # reference scales by 1/layer inside softmax and compensates in the
@@ -440,7 +487,7 @@ def attention_block(cfg: ModelConfig, p: Params, x: jax.Array,
         new_v = jnp.transpose(v, (0, 2, 1, 3))
         k_cache = cache_update(k_cache, new_k, cache_len)
         v_cache = cache_update(v_cache, new_v, cache_len)
-        if side.cache_is_empty and s > 1:
+        if side.cache_is_empty and x.shape[1] > 1:
             # prefill fast path: no prior rows to attend, so this is
             # ordinary causal attention over the window — the flash
             # kernel at O(s²) instead of the cached-score einsum at
@@ -475,13 +522,8 @@ def attention_block(cfg: ModelConfig, p: Params, x: jax.Array,
             block_q=cfg.flash_block_q,
             block_k=cfg.flash_block_k,
         )
-    ctx2d = ctx.reshape(b, s, nq * d)
-    if gate is not None:
-        ctx2d = (ctx2d * jax.nn.sigmoid(gate.astype(jnp.float32))
-                 ).astype(ctx2d.dtype)
-    out = _lora_add(proj(cfg, ctx2d, p["wo"]), ctx2d, lora, "wo")
-    if "bo" in p:
-        out = out + p["bo"]
+    out = _project_out(cfg, p, ctx, gate, x if gate_x is None else gate_x,
+                       lora)
     if kv_cache is not None:
         # return only the NEW rows [b, nkv, s, d] — the caller writes them
         # into its persistent cache with a row-sized dynamic_update_slice,
@@ -542,7 +584,7 @@ def _mlp_dispatch(cfg: ModelConfig, p: Params, x: jax.Array, lora=None,
 def layer_forward(cfg: ModelConfig, p: Params, x: jax.Array,
                   side: AttnSideInputs, layer_rng=None,
                   kv_cache: Optional[tuple] = None,
-                  layer_idx=None, lora=None):
+                  layer_idx=None, lora=None, kind: str = "full"):
     """One pre-LN residual block, sequential or Falcon-parallel.
 
     Parity: megatron/model/transformer.py:695-817
@@ -553,7 +595,9 @@ def layer_forward(cfg: ModelConfig, p: Params, x: jax.Array,
     dropout ramp and per-layer drop-path rate; None → flat rates.
 
     A block of one part (a hybrid stack's ``"attention"``, ``"mamba"``
-    and ``"mlp"`` kinds) is ``_one_part_forward``'s.
+    and ``"mlp"`` kinds) is ``_one_part_forward``'s.  ``kind`` "window":
+    the block's attention part is ``_window_attend``'s, and its
+    ``kv_cache`` the ring's forms there (True or the stacked rings).
     """
     if "mlp" not in p or not any(m in p for m in ("attn", "gdn", "mamba")):
         return _one_part_forward(cfg, p, x, side, layer_rng, kv_cache)
@@ -587,6 +631,7 @@ def layer_forward(cfg: ModelConfig, p: Params, x: jax.Array,
     h1 = norm_apply(cfg.norm_type, x, p["input_norm"], cfg.norm_eps,
                     impl=cfg.norm_impl)
     new_cache = None
+    gate_x = h1          # a gate a head reads the stream's norm as it is
     if cfg.layer_pattern:
         # a hybrid stack's residual stream is float32 (``stream_dtype``);
         # attention computes in the model's own precision
@@ -600,9 +645,13 @@ def layer_forward(cfg: ModelConfig, p: Params, x: jax.Array,
         # an ssm layer: the same, with the state-space state
         attn_out, new_cache = mamba2.mamba_block(cfg, p["mamba"], h1,
                                                  kv_cache, side.valid)
+    elif kind == "window":
+        with jax.named_scope("swa"):
+            attn_out, new_cache = _window_attend(
+                cfg, p["attn"], h1, side, layer_idx, kv_cache, gate_x)
     else:
         attn_out, new_cache = _attend(cfg, p["attn"], h1, side, layer_rng,
-                                      kv_cache, lora)
+                                      kv_cache, lora, gate_x)
 
     if cfg.parallel_attn:
         if cfg.parallel_layernorm:
@@ -627,7 +676,8 @@ def layer_forward(cfg: ModelConfig, p: Params, x: jax.Array,
 
 
 def _attend(cfg: ModelConfig, p: Params, h1: jax.Array,
-            side: AttnSideInputs, layer_rng, kv_cache, lora=None):
+            side: AttnSideInputs, layer_rng, kv_cache, lora=None,
+            gate_x=None):
     """A block's attention part, softmax attention over K/V a KV head or
     latent attention (``cfg.kv_lora_rank``), → ``(out, the new rows or
     None)``."""
@@ -635,14 +685,16 @@ def _attend(cfg: ModelConfig, p: Params, h1: jax.Array,
         out = mla.mla_block(cfg, p, h1, side, kv_cache)
     elif kv_cache is not None:
         out = attention_block(cfg, p, h1, side, layer_rng, kv_cache,
-                              lora=lora)
+                              lora=lora, gate_x=gate_x)
     else:
-        out = attention_block(cfg, p, h1, side, layer_rng, lora=lora)
+        out = attention_block(cfg, p, h1, side, layer_rng, lora=lora,
+                              gate_x=gate_x)
     return out if kv_cache is not None else (out, None)
 
 
 def _one_part_forward(cfg: ModelConfig, p: Params, x: jax.Array,
-                      side: AttnSideInputs, layer_rng, kv_cache):
+                      side: AttnSideInputs, layer_rng, kv_cache,
+                      kind: str = "full"):
     """A block of one part under one norm, ``x + f(norm(x))``: softmax
     attention, a Mamba-2 mixer or the feed-forward part alone, by what
     ``p`` holds.  Returns as ``layer_forward`` does; the feed-forward
@@ -655,10 +707,15 @@ def _one_part_forward(cfg: ModelConfig, p: Params, x: jax.Array,
         # at zero; the new one is dropped with no cache
         out, new_cache = mamba2.mamba_block(cfg, p["mamba"], h1, kv_cache,
                                             side.valid)
+    elif kind == "window":
+        with jax.named_scope("swa"):
+            out, new_cache = _window_attend(
+                cfg, p["attn"], h1.astype(cfg.dtype), side, None, kv_cache,
+                h1)
     elif "attn" in p:
         # attention computes in the model's own precision
         out, new_cache = _attend(cfg, p["attn"], h1.astype(cfg.dtype), side,
-                                 layer_rng, kv_cache)
+                                 layer_rng, kv_cache, gate_x=h1)
     else:
         out, aux = _mlp_dispatch(cfg, p["mlp"], h1, valid=side.valid)
     result = x + _scaled(cfg, out)
@@ -668,12 +725,12 @@ def _one_part_forward(cfg: ModelConfig, p: Params, x: jax.Array,
 
 
 def ffn_input(cfg: ModelConfig, p: Params, x: jax.Array,
-              side: AttnSideInputs) -> jax.Array:
+              side: AttnSideInputs, kind: str = "full") -> jax.Array:
     """What the feed-forward part of a two-part attention block reads:
     the stream with the attention part's result added, under the block's
     second norm (``models/model.py:level_router_bias``)."""
     x = _one_part_forward(cfg, {k: p[k] for k in ("input_norm", "attn")},
-                          x, side, None, None)[0]
+                          x, side, None, None, kind)[0]
     return norm_apply(cfg.norm_type, x, p["post_attn_norm"], cfg.norm_eps,
                       impl=cfg.norm_impl)
 
@@ -791,7 +848,8 @@ def _stack_forward_periods(cfg: ModelConfig, stacked, x, side, base_rng,
             rng = (None if base_rng is None
                    else jax.random.fold_in(base_rng, layer))
             h, aux = layer_forward(cfg, layer_params, h, side, rng,
-                                   layer_idx=layer_offset + layer)[:2]
+                                   layer_idx=layer_offset + layer,
+                                   kind=cfg.layer_pattern[j])[:2]
             aux_sum = jax.tree.map(jnp.add, aux_sum, aux)
         return (h, idx + 1, aux_sum), None
 
@@ -856,7 +914,12 @@ def scan_periods_cached(cfg: ModelConfig, stacked, x, side, kv_of, rec,
     [...]}``.  The states of either kind ride in the scan's carry: a
     prompt's layer reads and rewrites its own slice in place, a decode
     step's kernel takes them stacked and advances its layer where it lies
-    (``_rec_state_at``, ``_rec_write_back``).
+    (``_rec_state_at``, ``_rec_write_back``).  A ``"window"`` layer
+    (``_window_attend``) keeps no cache layer and a ring a slot under
+    ``RING_NAMES``: a prompt's rings come back whole, a step attends the
+    stacked rings where they lie and its new rows are written behind the
+    scan, once (``ring_append_rows``); either way they are among the
+    states returned.
 
     → ``(hidden, (rows_k, rows_v) stacked over the attending layers, rec's
     states advanced over the positions ``side.valid`` marks, counts
@@ -909,11 +972,22 @@ def scan_periods_cached(cfg: ModelConfig, stacked, x, side, kv_of, rec,
     place = [mixers[:j].count(mixer) for j, mixer in enumerate(mixers)]
     n_rec = {mixer: mixers.count(mixer) for mixer in mixers if mixer}
     states = {name: rec[name] for mixer in n_rec for name in mixer.names}
+    # a period's "window" layers: their rings do not ride in the carry.
+    # A prompt's come back whole as the scan's ys; a step's kernel reads
+    # the stacked rings where they lie (the scan closes over them) and
+    # its new ROWS come back, for one write behind the scan
+    # (``ring_append_rows``): PR 56's way, the one way both scans have
+    n_win = kinds.count("window")
+    prompt = side.cache_is_empty
+    rings = tuple(rec[name] for name in RING_NAMES) if n_win else ()
+    assert not n_win or prompt or x.shape[1] == 1, (
+        "a \"window\" layer: a prompt into an empty cache, or one new "
+        "position a slot")
 
     def body(carry, inp):
         h, idx, states = carry
         period, kv_p = inp
-        rows, counts, f = [], [], 0
+        rows, counts, kept, f = [], [], [], 0
         for at_j, (layer_params, kind, mixer, j) in enumerate(
                 zip(period, kinds, mixers, place)):
             if experts.get(at_j):
@@ -927,13 +1001,18 @@ def scan_periods_cached(cfg: ModelConfig, stacked, x, side, kv_of, rec,
                     kv_layer = kv_layer + n_lead
                 cache = kv_of(kv_layer, *(a[f] for a in kv_p))
                 f += 1
+            elif kind == "window":
+                cache = True if prompt else rings + (
+                    idx * n_win + kinds[:at_j].count("window"),)
             elif mixer:
                 at = idx * n_rec[mixer] + j
                 cache = _rec_state_at(mixer, states, at, h.shape[1] == 1)
             h, aux, *new = layer_forward(cfg, layer_params, h, side, None,
-                                         kv_cache=cache)
+                                         kv_cache=cache, kind=kind)
             if attends:
                 rows += new
+            elif kind == "window":
+                kept += new
             elif mixer:
                 states = {**states,
                           **_rec_write_back(mixer, states, new[0], at)}
@@ -944,12 +1023,18 @@ def scan_periods_cached(cfg: ModelConfig, stacked, x, side, kv_of, rec,
                  "rows": jnp.zeros((2,), jnp.float32)})
         stack = lambda xs_: jax.tree.map(lambda *a: jnp.stack(a), *xs_) \
             if xs_ else ()
-        return (h, idx + 1, states), (stack(rows), stack(counts))
+        return (h, idx + 1, states), (stack(rows), stack(counts),
+                                      stack(kept))
 
-    (x, _, states), (rows, counts) = jax.lax.scan(
+    (x, _, states), (rows, counts, kept) = jax.lax.scan(
         body, (x, jnp.int32(0), states), xs)
     flat = lambda a: a.reshape((a.shape[0] * a.shape[1],) + a.shape[2:])
     rows, counts = jax.tree.map(flat, rows), jax.tree.map(flat, counts)
+    if n_win:
+        kept = jax.tree.map(flat, kept)
+        states = {**states, **dict(zip(RING_NAMES, (
+            kept if prompt else ring_append_rows(
+                rings, kept, side.position_ids[:, 0]))))}
     if lead_rows:
         rows = jax.tree.map(lambda *a: jnp.concatenate(
             [jnp.stack(a[:-1]), a[-1]]), *lead_rows, rows)
@@ -1025,34 +1110,80 @@ def _full_attend(cfg: ModelConfig, p: Params, u, side: AttnSideInputs,
 
 
 def _window_attend(cfg: ModelConfig, p: Params, u, side: AttnSideInputs,
-                   layer, ring):
-    """A "window" layer: a sequence on itself under the window (``ring``
-    None: nothing kept; True: a prompt, whose ring comes back), or one
-    new position a slot on the slot's ring ``(ring_k, ring_v, at)`` (the
-    window layers' rings stacked, and which of them), whose new rows come
-    back.  -> ``(out, None | the ring | the new rows)``."""
-    q = diff_attention.project_q(cfg, p, u)
-    k, v = diff_attention.project_kv(cfg, p, u)
+                   layer, ring, gate_x=None):
+    """A "window" layer, of a stack of runs or of the period scan: a
+    sequence on itself under the window (``ring`` None: nothing kept;
+    True: a prompt, whose ring comes back), or one new position a slot on
+    the slot's ring ``(ring_k, ring_v, at)`` (the window layers' rings
+    stacked, and which of them), whose new rows come back.  -> ``(out,
+    None | the ring | the new rows)``.
+
+    The ring, its install (``diff_attention.ring_of``), its attention
+    (``attend_ring``) and the rows' one write (``ring_append_rows``) are
+    one mechanism; what differs by stack is read off ``cfg``.  Under
+    differential attention: the pairs' projections, a ring row the keys
+    of a pair side by side, the pairs' difference (``layer``).  Else:
+    grouped heads at the window layers' own head count and rotation
+    (``cfg.window_layer_config``), a ring row one key head's, and the gate
+    a head (``gate_x``).  A key goes to the ring ROTATED at its own
+    position, from the prompt and from a step alike, and a query is
+    rotated at its own: the ring's rows need no positions, the count mask
+    stands as it is."""
+    if cfg.diff_attention:
+        w = cfg
+        q = diff_attention.project_q(cfg, p, u)
+        k, v = diff_attention.project_kv(cfg, p, u)
+        as_rows = lambda k_: diff_attention.pair_rows(cfg, k_)  # noqa: E731
+        finish = lambda a: diff_attention.finish(  # noqa: E731
+            cfg, p, a, layer)
+    else:
+        w = cfg.window_layer_config
+        q, k, v, _gate = _project_heads(w, p, u, side)
+        k, v = jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2)   # head-major
+        as_rows = lambda k_: k_  # noqa: E731
+        finish = lambda a: _project_out(  # noqa: E731
+            w, p, a, None, u if gate_x is None else gate_x)
     kept = None
     if ring is None or ring is True:
-        a = diff_attention.attend_seq(cfg, q, k, v, cfg.sliding_window)
+        a = diff_attention.attend_seq(w, q, k, v, cfg.sliding_window)
         if ring is True:
             n = (jnp.full((u.shape[0],), u.shape[1], jnp.int32)
                  if side.valid is None
                  else jnp.sum(side.valid, axis=1, dtype=jnp.int32))
             ring_k, ring_v = (diff_attention.ring_of(
                 a_, n, cfg.sliding_window) for a_ in (k, v))
-            kept = (diff_attention.pair_rows(cfg, ring_k), ring_v)
+            kept = (as_rows(ring_k), ring_v)
     else:
-        a = diff_attention.attend_ring(cfg, q, *ring, k, v,
+        a = diff_attention.attend_ring(w, q, *ring, k, v,
                                        side.position_ids[:, 0])
-        kept = (diff_attention.pair_rows(cfg, k), v)
-    return diff_attention.finish(cfg, p, a, layer), kept
+        kept = (as_rows(k), v)
+    return finish(a), kept
 
 
 # under these names the serving state tree keeps the "window" layers'
 # rings, stacked over those layers (models/model.py:init_rec_state)
 RING_NAMES = ("win_k", "win_v")
+
+
+@jax.named_scope("swa")
+def ring_append_rows(rings, rows, positions):
+    """Write a step's new rows into the "window" layers' rings, in
+    place: ``rings`` (k and v, each ``[window layers, slots, heads, W,
+    width]``), ``rows`` the same with one position, slot ``s``'s at row
+    ``positions[s] % W`` (a free slot rewrites a row of its own dead
+    ring).  One ``dynamic_update_slice`` a slot over all the layers, as
+    ``cache_append_rows`` writes the pool."""
+    zero = jnp.int32(0)
+
+    def ap(ring, r):
+        at = positions % ring.shape[3]
+        for s_ in range(r.shape[1]):
+            ring = jax.lax.dynamic_update_slice(
+                ring, r[:, s_:s_ + 1].astype(ring.dtype),
+                [zero, jnp.int32(s_), zero, at[s_], zero])
+        return ring
+
+    return tuple(ap(ring, r) for ring, r in zip(rings, rows))
 
 
 def scan_runs_cached(cfg: ModelConfig, stacked, x, side: AttnSideInputs,
@@ -1303,7 +1434,8 @@ def stack_forward_paged(cfg: ModelConfig, stacked: Params, x: jax.Array,
 
 def rope_tables(cfg: ModelConfig, dtype=jnp.float32):
     if (cfg.position_embedding_type != PositionEmbeddingType.ROTARY
-            or cfg.rotary_percent < 1.0):   # rotated from the positions
+            or cfg.rotary_percent < 1.0
+            or cfg.rope_rotate_half):       # rotated from the positions
         return None, None
     return precompute_rope_freqs(
         cfg.head_dim,

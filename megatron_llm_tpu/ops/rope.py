@@ -108,9 +108,7 @@ def precompute_rope_freqs(
     attention temperature folded into the tables.  Both frequency-space
     modes leave positions unscaled.
     """
-    inv_freq = 1.0 / (
-        theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
-    )
+    inv_freq = rotary_inv_freq(head_dim, theta)
     table_scale = 1.0
     if scaling_type in ("llama3", "yarn") and scaling_factor != 1.0 \
             and not original_max_positions:
@@ -211,19 +209,52 @@ def apply_rope_flat(
     return out.astype(x.dtype)
 
 
+def rotary_inv_freq(rot_dim: int, theta: float):
+    """The unscaled frequencies of ``rot_dim`` rotated dimensions."""
+    return 1.0 / (theta ** (
+        jnp.arange(0, rot_dim, 2, dtype=jnp.float32) / rot_dim))
+
+
+def rotation_of(cfg) -> tuple:
+    """``(rot_dim, inv_freq, scale)`` of a rotation from the positions
+    (``apply_rope_partial``) as ``cfg`` (a ``ModelConfig``, or a layer
+    kind's view of one) describes it: the first ``rotary_percent`` of the
+    head at ``rope_theta``; under ``rope_scaling_type`` "yarn" with a
+    factor, YaRN's frequencies over the ROTATED dimensions and its
+    attention factor, which multiplies cos and sin: the rotated part of a
+    score carries its square, the rest of the head does not.  ``inv_freq``
+    None: the bare frequencies, computed where they are used."""
+    rot = int(cfg.head_dim * cfg.rotary_percent)
+    if cfg.rope_scaling_type != "yarn" or cfg.rope_scaling_factor == 1.0:
+        return rot, None, 1.0
+    if not cfg.rope_original_max_positions:
+        raise ValueError("yarn rope scaling needs "
+                         "rope_original_max_positions")
+    inv_freq, scale = yarn_scaled_inv_freq(
+        rotary_inv_freq(rot, cfg.rope_theta), cfg.rope_scaling_factor,
+        cfg.rope_beta_fast, cfg.rope_beta_slow,
+        cfg.rope_original_max_positions, rot, cfg.rope_theta,
+        cfg.rope_attention_factor)
+    return rot, inv_freq, scale
+
+
 def apply_rope_partial(x: jax.Array, position_ids: jax.Array, rot_dim: int,
-                       theta: float) -> jax.Array:
+                       theta: float, inv_freq=None,
+                       scale: float = 1.0) -> jax.Array:
     """Rotate the first ``rot_dim`` of the last axis of ``x`` [batch, seq,
     heads, head_dim] and leave the rest: the rotate-half convention
     (dimension ``i`` pairs with ``i + rot_dim / 2``), angles computed from
     ``position_ids`` [batch, seq] in float32, no table (a partial rotation
     goes with contexts whose table would be hundreds of thousands of
-    rows)."""
+    rows).  ``inv_freq`` [rot_dim / 2]: scaled frequencies in place of
+    ``theta``'s own, and ``scale`` on cos and sin (``rotation_of``)."""
     half = rot_dim // 2
-    inv_freq = 1.0 / (theta ** (
-        jnp.arange(0, rot_dim, 2, dtype=jnp.float32) / rot_dim))
+    if inv_freq is None:
+        inv_freq = rotary_inv_freq(rot_dim, theta)
     ang = position_ids.astype(jnp.float32)[..., None] * inv_freq
     cos, sin = jnp.cos(ang)[:, :, None, :], jnp.sin(ang)[:, :, None, :]
+    if scale != 1.0:
+        cos, sin = scale * cos, scale * sin
     xf = x.astype(jnp.float32)
     x1, x2 = xf[..., :half], xf[..., half:rot_dim]
     return jnp.concatenate(

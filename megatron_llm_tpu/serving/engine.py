@@ -399,6 +399,8 @@ class _Request:
         self.result: Optional[FinishedRequest] = None
         self.submit_time = time.perf_counter()
         self.first_token_time: Optional[float] = None
+        # when its admission first parked on pool blocks (None: never)
+        self.parked_at: Optional[float] = None
         # Absolute wall-clock deadline (perf_counter domain); None = never.
         self.deadline: Optional[float] = (
             None if deadline_s is None
@@ -1277,9 +1279,13 @@ class ServingEngine:
         # layers that walk a cache another layer wrote ("cross"), beside
         # the one that wrote it: a step's walks are counted by kind, its
         # decode spans carry the cached positions it attended
-        self._kv_readers = ({"full": cfg.kv_layers,
-                             "cross": cfg.cross_layers}
-                            if cfg.cross_layers else {})
+        self._kv_readers = (
+            {"full": cfg.kv_layers, "cross": cfg.cross_layers}
+            if cfg.cross_layers else
+            {"full": cfg.kv_layers} if cfg.window_layers else {})
+        # rows a slot's "window" rings hold at most (0: no such layers):
+        # a step's decode spans carry the live ones (``ring_rows``)
+        self._ring_window = cfg.sliding_window if cfg.window_layers else 0
         # (KV heads, heads a copy) of the paged walk over its pool, read
         # off the pool at the first step that counts by it (_walk_arg)
         self._walk_grid = None
@@ -2140,7 +2146,19 @@ class ServingEngine:
             self._release_adapter(req)
             self.slots.release(slot)
             self._held = req
+            if req.parked_at is None:
+                # a free slot (and whatever fixed state goes with it: a
+                # hybrid stack's rings and states wait in place) and too
+                # few free blocks: counted once a request, timed until
+                # its admission
+                req.parked_at = t_in
+                self.metrics.inc("admissions_parked")
             return False
+        parked_arg = {}
+        if req.parked_at is not None:
+            parked_s = t_in - req.parked_at
+            self.metrics.inc("admission_parked_seconds_total", parked_s)
+            parked_arg = {"parked_ms": round(1e3 * parked_s, 3)}
         self.slots.set_reservation(slot, need)
         t = self.metrics.timers("serving-prefill", 2)
         t.start()
@@ -2228,7 +2246,8 @@ class ServingEngine:
                        args={"prompt_len": plen, "padded": padded,
                              "cached_tokens": lease.tokens if lease else 0,
                              "iter": self._iter, **self._experts_arg,
-                             **self._prefill_arg, **flash_arg})
+                             **self._prefill_arg, **flash_arg,
+                             **parked_arg})
         if self._counts_ssm:
             self.metrics.add_ssm_positions("prefill", plen)
         self._admit_count += 1
@@ -3139,13 +3158,15 @@ class ServingEngine:
         if self._latent:
             return {"walk_steps": int(np.count_nonzero(fills)),
                     "walk_prefetched": 0}
+        ring = ({"ring_rows": int(np.minimum(fills, self._ring_window).sum())}
+                if self._ring_window else {})
         if self._walk_grid is None:
             self._walk_grid = pool_walk(
                 jax.tree.leaves(self.slots.k_pool)[0],
                 jax.tree.leaves(self.slots.v_pool)[0],
                 self.slots.tables.shape[1])[:2]
         steps, prefetched = walk_counts(fills, *self._walk_grid)
-        return {"walk_steps": steps, "walk_prefetched": prefetched}
+        return {"walk_steps": steps, "walk_prefetched": prefetched, **ring}
 
     # tpulint: hot-path
     def _process_step_results(self, step: _Inflight) -> float:

@@ -135,6 +135,13 @@ _COUNTERS = (
     # owned blocks); anything nonzero on a pure prefix-hit workload means
     # zero-copy sharing broke (tests/serving/test_prefix_cache.py).
     "cow_copies_total",
+    # whole-prompt admissions that found a free slot and too few free
+    # pool blocks for the request's worst case (serving/engine.py:
+    # _prefill_into_slot parks it at the queue's head): requests that
+    # parked, once each, and the seconds from a request's first parking
+    # to its admission.  Nonzero under a pool set smaller than its
+    # slots' worst case (kv_pool_blocks): the price of that setting.
+    "admissions_parked", "admission_parked_seconds_total",
     # speculative decoding (serving/engine.py): draft tokens proposed by
     # the host n-gram drafter vs draft tokens the batched verify step
     # accepted, plus verify iterations run.  The acceptance ratio is the

@@ -69,9 +69,10 @@ def main(argv=None) -> int:
     ap.add_argument("--load", required=True, help="checkpoint directory")
     ap.add_argument("--model", default="llama2",
                     choices=["llama", "llama2", "codellama", "falcon", "gpt",
-                             "phi4flash"],
-                    help="phi4flash (--size mini-flash-reasoning) is a "
-                         "hybrid stack: start it with "
+                             "laguna", "phi4flash"],
+                    help="phi4flash (--size mini-flash-reasoning) and "
+                         "laguna (--size xs.2-pp8-stage0) are hybrid "
+                         "stacks: start them with "
                          "--prefix_cache_blocks 0 (docs/serving.md)")
     ap.add_argument("--size", default="7b")
     ap.add_argument("--tokenizer_type", default="SentencePieceTokenizer")
@@ -298,7 +299,8 @@ def main(argv=None) -> int:
                "codellama": families.code_llama,
                "falcon": families.falcon,
                "gpt": families.gpt,
-               "phi4flash": families.phi4flash}[args.model]
+               "phi4flash": families.phi4flash,
+               "laguna": families.laguna}[args.model]
     lm = factory(args.size)
     if args.kv_quant:
         import dataclasses

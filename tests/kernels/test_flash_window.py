@@ -171,3 +171,87 @@ def test_the_ring_kernel_is_the_plain_composition(layer):
     again = ring_decode(q, stale, ring_v, kn, vn, pos, jnp.int32(layer),
                         softmax_scale=0.25, interpret=True)
     np.testing.assert_array_equal(again[4], got[4])
+
+
+# --- the same two kernels at the period scan's "window" kind: ordinary
+# grouped heads, 8 query heads a KV head, a ring row ONE key head's (r = 1)
+
+@pytest.mark.parametrize("s,window,block,live", [
+    (512, 128, 128, 7),
+    (300, 100, 128, 5),
+    (640, 256, 128, 12),     # a window of two tiles: three tiles a row block
+])
+def test_a_window_at_eight_query_heads_a_kv_head(s, window, block, live):
+    """``flash_attention(window=)`` at 16 query heads on 2 KV heads, keys
+    and values of one width (no shared value head): the masked plain
+    product, on the band's tiles alone."""
+    q, k, v = _rand(0, (2, s, 16, 16)), _rand(1, (2, s, 2, 16)), _rand(
+        2, (2, s, 2, 16))
+    got = flash_attention(q, k, v, window=window, block_q=block,
+                          block_k=block, interpret=True)
+    want = _masked_reference(q, k, v, window)
+    assert got.shape == (2, s, 16, 16)
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+    assert tile_plan(s, s, block, block, True, window).live == live
+    off = flash_attention(q, k, v, window=window + 1, block_q=block,
+                          block_k=block, interpret=True)
+    assert np.abs(off - want).max() > 1e-3
+
+
+def _ring_reference(q, ring_k, ring_v, kn, vn, pos, scale):
+    """One new position a slot on its ring, written out: slot ``b`` at
+    position ``p`` sees the ring's rows that hold positions ``p - W + 1 ..
+    p - 1`` (row ``c`` holds one where ``c < p`` and ``c != p % W``) and
+    its own new row; query head ``j`` reads KV head ``j // (heads / kv)``."""
+    S, heads, d = q.shape
+    kv, W = ring_k.shape[1], ring_k.shape[2]
+    out = np.zeros((S, heads, d), np.float32)
+    for b in range(S):
+        p = int(pos[b])
+        rows = [c for c in range(W) if c < p and c != p % W]
+        for j in range(heads):
+            g = j // (heads // kv)
+            keys = np.concatenate([ring_k[b, g, rows], kn[b, g]])
+            vals = np.concatenate([ring_v[b, g, rows], vn[b, g]])
+            sc = keys @ q[b, j] * scale
+            w = np.exp(sc - sc.max())
+            out[b, j] = (w / w.sum()) @ vals
+    return out
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+def test_the_ring_kernel_at_one_key_head_a_row(layer):
+    """``ring_decode`` at ``r = 1``: 16 query heads of 16 on 2 KV heads of
+    16, 8 query rows a KV head, on layer ``layer`` of three stacked rings
+    of 8 positions, against a masked plain product written out; and
+    ``attend_ring``'s plain composition agrees.  The rows hold keys
+    already rotated at their own positions, so neither knows of a
+    rotation: the count mask stands as it is."""
+    from megatron_llm_tpu.config import laguna_config
+    from megatron_llm_tpu.kernels.ring_decode import ring_decode
+    from megatron_llm_tpu.models import diff_attention
+
+    L, S, kv, d, W, heads = 3, 6, 2, 16, 8, 16
+    q = _rand(0, (S, heads, d))
+    ring_k, ring_v = (_rand(i, (L, S, kv, W, d)) for i in (1, 2))
+    kn, vn = _rand(3, (S, kv, 1, d)), _rand(4, (S, kv, 1, d))
+    pos = jnp.asarray([0, 3, 7, 8, 13, 30], jnp.int32)
+    got = ring_decode(q, ring_k, ring_v, kn, vn, pos, jnp.int32(layer),
+                      softmax_scale=0.25, interpret=True)
+    want = _ring_reference(*(np.asarray(a) for a in (
+        q, ring_k[layer], ring_v[layer], kn, vn, pos)), 0.25)
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+    cfg = laguna_config(
+        hidden_size=64, num_attention_heads=4, window_attention_heads=heads,
+        num_kv_heads=kv, kv_channels=d, num_experts=8, moe_top_k=2,
+        sliding_window=W, vocab_size=512, make_vocab_size_divisible_by=8,
+        attention_multiplier=0.25).window_layer_config
+    assert cfg.attention_impl != "flash"
+    plain = diff_attention.attend_ring(cfg, q[:, None], ring_k, ring_v,
+                                       jnp.int32(layer), kn, vn, pos)[:, 0]
+    np.testing.assert_allclose(plain, want, atol=2e-5, rtol=2e-5)
+    # the stale row holds anything: it must not reach the output
+    stale = ring_k.at[layer, 4, :, 13 % W].set(1e4)
+    again = ring_decode(q, stale, ring_v, kn, vn, pos, jnp.int32(layer),
+                        softmax_scale=0.25, interpret=True)
+    np.testing.assert_array_equal(again[4], got[4])

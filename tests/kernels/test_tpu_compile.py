@@ -883,6 +883,113 @@ def test_a_dropless_prefill_routes_through_the_grouped_kernel(topo,
             result, path)
 
 
+
+# -- the period scan's "window" kind at Laguna-XS.2's widths ---------------
+
+@pytest.mark.parametrize("s,live", [(2048, 7), (16384, 63)])
+def test_flash_attention_under_a_window_at_eight_query_heads_a_kv_head(
+        topo, s, live):
+    """The Laguna cell's window layers: 64 query heads of 128 on 8 KV
+    heads of 128 (ordinary grouped heads, keys and values of one width),
+    a window of 512 in blocks of 512, at the smallest and the largest of
+    the cell's eight prefill buckets: the band is the diagonal tile and
+    the one before it; forward only."""
+    args = (_sds((1, s, 64, 128)), _sds((1, s, 8, 128)),
+            _sds((1, s, 8, 128)))
+    fn = lambda q, k, v: flash_attention(  # noqa: E731
+        q, k, v, window=512, block_q=512, block_k=512, interpret=False)
+    traced = str(jax.make_jaxpr(fn)(*args))
+    assert f"grid=(1, 64, {live})" in traced
+    text = _compile(fn, args, SingleDeviceSharding(topo.devices[0]))
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
+def test_the_ring_kernel_at_one_key_head_a_row(topo):
+    """The Laguna cell's window layers' decode step: 40 slots, three
+    stacked rings of 512 rows of 8 KV heads of 128 (r = 1), 64 query heads
+    as 8 rows a KV head; the stacked rings are read where they lie."""
+    from megatron_llm_tpu.kernels.ring_decode import ring_decode
+
+    S = 40
+    text = _compile(
+        lambda q, rk, rv, kn, vn, pos, layer: ring_decode(
+            q, rk, rv, kn, vn, pos, layer[0], softmax_scale=128 ** -0.5,
+            interpret=False),
+        (_sds((S, 64, 128)), _sds((3, S, 8, 512, 128)),
+         _sds((3, S, 8, 512, 128)), _sds((S, 8, 1, 128)),
+         _sds((S, 8, 1, 128)), _sds((S,), jnp.int32),
+         _sds((1,), jnp.int32)),
+        SingleDeviceSharding(topo.devices[0]))
+    _no_copy_of(text, "bf16[3,40,8,512,128]")
+    _no_copy_of(text, "bf16[1,40,8,512,128]")
+    assert relayout_bytes(text) == {}
+
+
+def test_grouped_mlp_at_256_experts_of_512_and_eight_choices(topo):
+    """The held experts' kernel at Laguna-XS.2's widths (256 experts of
+    2048 x 512, eight choices a token): a 16 384-token prompt's 131 072
+    pairs and a 40-slot step's 320."""
+    from megatron_llm_tpu.ops.activations import swiglu
+
+    one = SingleDeviceSharding(topo.devices[0])
+    E, h, f, k = 256, 2048, 512, 8
+    i32 = jnp.int32
+    for tokens in (16384, 40):
+        text = _compile(
+            lambda x, order, sizes, *w: grouped_mlp(
+                x, order, sizes, *w, swiglu, choices=k, interpret=False),
+            (_sds((tokens, h), jnp.float32), _sds((tokens * k,), i32),
+             _sds((E,), i32), _sds((E, h, f)), _sds((E, h, f)),
+             _sds((E, f, h))), one)
+        assert not relayout_bytes(text, min_bytes=2 * E * h * f)
+
+
+def test_a_window_and_full_decode_step_at_the_cells_size(topo, monkeypatch):
+    """The engine's decode executable for the Laguna cell: five layers (a
+    leading dense full layer, three window layers, a full expert layer),
+    40 slots, a pool of 2560 blocks over the TWO full layers, three rings
+    a slot.  10.68 GB of arguments of which pool and rings (2.94 GB) are
+    donated and aliased; thirteen kernels in the program text (two paged
+    walks, three ring kernels, four routers and four grouped expert
+    kernels); neither the pool nor the stacked rings nor an expert stack
+    is copied."""
+    from megatron_llm_tpu.config import laguna_config
+    from megatron_llm_tpu.serving import engine as engine_lib
+
+    monkeypatch.setattr(attn_ops, "_backend", lambda: "tpu")
+    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
+    one = SingleDeviceSharding(topo.devices[0])
+    S, blocks, bk, T = 40, 2560, 128, 144
+    cfg = laguna_config(attention_impl="flash")
+    place = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
+    params = jax.eval_shape(
+        lambda key: model_lib.init_params(key, cfg), jax.random.key(0))
+    pool = jax.eval_shape(lambda: model_lib.init_kv_pool(cfg, blocks, bk))
+    rec = jax.eval_shape(lambda: model_lib.init_rec_state(cfg, S))
+    assert rec["win_k"].shape == rec["win_v"].shape == (3, S, 8, 512, 128)
+    assert [a.shape for a in pool] == [(2, blocks, 8, bk, 128)] * 2
+    i32, f32 = jnp.int32, jnp.float32
+    vec = lambda dtype: place(_sds((S,), dtype))  # noqa: E731
+    compiled = engine_lib._decode_donated.lower(
+        cfg, place(params), *place(pool), place(_sds((S, T), i32)),
+        vec(i32), vec(i32), vec(jnp.uint32), vec(i32), vec(bool), vec(f32),
+        vec(i32), vec(f32), rec=place(rec), live=vec(bool)).compile()
+    mem = compiled.memory_analysis()
+    donated = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree.leaves((pool, rec)))
+    assert 10.6e9 < mem.argument_size_in_bytes < 10.8e9
+    assert donated <= mem.alias_size_in_bytes < donated + 2 ** 20
+    assert mem.temp_size_in_bytes < 0.2e9
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 13
+    _no_copy_of(text, "bf16[3,40,8,512,128]")
+    _no_copy_of(text, "bf16[1,40,8,512,128]")
+    _no_copy_of(text, f"bf16[2,{blocks},")
+    _no_copy_of(text, f"bf16[1,{blocks},")
+    _no_copy_of(text, "bf16[256,2048,512]")
+    _no_copy_of(text, "bf16[1,256,2048,512]")
+
 # -- the dropless router's choice -------------------------------------------
 
 @pytest.mark.parametrize("tokens,experts,k,biased", [
