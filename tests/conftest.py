@@ -109,30 +109,17 @@ def pytest_runtest_setup(item):
             )
 
 
-def pytest_configure(config):
-    config.addinivalue_line(
-        "markers", "incremental: xfail-chain steps within a test class"
-    )
-    config.addinivalue_line("markers", "tpu: requires real TPU hardware")
-    config.addinivalue_line(
-        "markers",
-        "chaos: fault-injection tests (resilience/chaos.py) — simulated "
-        "I/O failures, crashes mid-save, poisoned batches; CPU-fast and "
-        "part of the default tier-1 run",
-    )
-    config.addinivalue_line(
-        "markers",
-        "slow: >13s single-test compile cost on the 1-core CI host; "
-        "`-m 'not slow'` is the fast inner-loop tier, the full suite "
-        "(default) is required before any snapshot/commit of substance",
-    )
+# The markers (``incremental``, ``tpu``, ``chaos``, ``slow``) are registered
+# once, in pyproject.toml's ``[tool.pytest.ini_options]``.
 
 
-# The heavyweight end-to-end tests (each dominated by XLA compiles of
-# large sharded programs; this host has ONE cpu core, so compile time is
-# irreducible wall-clock — and the persistent compile cache is disabled,
-# see above).  Centralized here instead of per-file markers so the list
-# mirrors `--durations` output directly.
+# The heavyweight end-to-end tests, each dominated by XLA compiles of
+# large sharded programs (the persistent compile cache is off, see above).
+# Tier-1 is the driver's command: ``-m 'not slow'`` over six xdist workers
+# (``--dist load``) under a 1470 s limit, so a test here costs none of
+# that budget and guards nothing a PR is held to.  Centralized here
+# instead of per-file markers so the list mirrors `--durations` output
+# directly.
 _SLOW_TESTS = {
     "test_int8_training_composes_with_pipeline",
     "test_two_process_dryrun",

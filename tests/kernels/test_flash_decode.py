@@ -1,5 +1,7 @@
 """Pallas decode-attention kernel vs the einsum reference (interpret mode)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -74,6 +76,16 @@ from megatron_llm_tpu.kernels.flash_decode import (  # noqa: E402
 )
 
 
+# Interpret mode traces and lowers a kernel in Python for ~5 s of a call
+# before anything runs, and a bare call does it again every time: the
+# paged walks below go through one jit a pool form, so the calls of one
+# set of shapes (a geometry's fills, a slot's neighbourhoods, a pool's
+# shuffles) are traced, lowered and compiled once a process.
+_paged = jax.jit(functools.partial(flash_decode_paged, interpret=True))
+_paged_int8 = jax.jit(functools.partial(flash_decode_paged_int8,
+                                        interpret=True))
+
+
 def _shuffled_tables(b, T, rng):
     """Per-row block tables with deliberately non-contiguous physical
     ids (1..b*T shuffled; id 0 is the trash block)."""
@@ -118,8 +130,8 @@ def test_paged_equals_dense_fp32_bitwise_across_layouts():
     for _ in range(2):
         tables = _shuffled_tables(b, max_len // bk, rng)
         k_pool, v_pool = _paged_layout([k, v], bk, tables, 1e4)
-        got.append(np.asarray(flash_decode_paged(
-            q, k_pool, v_pool, jnp.asarray(tables), lens, interpret=True)))
+        got.append(np.asarray(_paged(q, k_pool, v_pool, jnp.asarray(tables),
+                                     lens)))
     np.testing.assert_allclose(got[0], np.asarray(want), rtol=1e-6,
                                atol=1e-6)
     np.testing.assert_array_equal(got[0], got[1])
@@ -147,9 +159,8 @@ def test_paged_equals_dense_int8_bitwise_across_layouts():
         tables = _shuffled_tables(b, max_len // bk, rng)
         kq_p, vq_p = _paged_layout([k_q, v_q], bk, tables, 127)
         ks_p, vs_p = _paged_layout([k_s, v_s], bk, tables, 1e4)
-        got.append(np.asarray(flash_decode_paged_int8(
-            q, kq_p, ks_p, vq_p, vs_p, jnp.asarray(tables), lens,
-            interpret=True)))
+        got.append(np.asarray(_paged_int8(
+            q, kq_p, ks_p, vq_p, vs_p, jnp.asarray(tables), lens)))
     np.testing.assert_allclose(got[0], np.asarray(want), rtol=1e-5,
                                atol=1e-5)
     np.testing.assert_array_equal(got[0], got[1])
@@ -244,14 +255,16 @@ def _plain_walk(q, k_dense, v_dense, k_new, v_new, fills, scale):
 _GEOMETRIES = {"falcon71x64mqa": _FALCON, "gqa32x128kv8": _GQA128,
                "gqa16x256kv2": _GQA256, "pairs40x64kv20v10": _PAIRS}
 _BETWEEN = ("empty_between", "ends_empty", "all_empty", "one_iteration")
-_WALKS = (
-    [(g, p, "edges") for g in list(_GEOMETRIES)[:3]
-     for p in ("fp32", "bf16", "int8")]
-    + [("pairs40x64kv20v10", p, "edges") for p in ("fp32", "bf16")]
-    + [("gqa32x128kv8", p, f) for p in ("fp32", "bf16", "int8")
-       for f in _BETWEEN]
-    + [("pairs40x64kv20v10", "fp32", f) for f in _BETWEEN]
-)
+# (a geometry and pool's fills one after another: on one worker they run
+# one executable)
+_WALKS = [(g, p, f)
+          for g, pools, kinds in (
+              ("falcon71x64mqa", ("fp32", "bf16", "int8"), ()),
+              ("gqa32x128kv8", ("fp32", "bf16", "int8"), _BETWEEN),
+              ("gqa16x256kv2", ("fp32", "bf16", "int8"), ()),
+              ("pairs40x64kv20v10", ("fp32",), _BETWEEN),
+              ("pairs40x64kv20v10", ("bf16",), ()))
+          for p in pools for f in ("edges",) + tuple(kinds)]
 
 
 @pytest.mark.parametrize("geometry,pool,fill_kind", _WALKS,
@@ -292,11 +305,10 @@ def test_paged_new_row_matches_gathered_einsum(geometry, pool, fill_kind):
         kq_p, vq_p = _paged_layout([kq, vq], bk, tables, 127)
         ks_p, vs_p = _paged_layout([ks, vs], bk, tables, 1e4)
         kn, vn = quantize_rows(k_new), quantize_rows(v_new)
-        got = flash_decode_paged_int8(
+        got = _paged_int8(
             q, kq_p, ks_p, vq_p, vs_p, jnp.asarray(tables),
             jnp.asarray(fills),
-            new_rows=(dequantize_cache(kn), dequantize_cache(vn)),
-            interpret=True)
+            new_rows=(dequantize_cache(kn), dequantize_cache(vn)))
         k_dense = {"q": jnp.asarray(kq), "scale": jnp.asarray(ks)}
         v_dense = {"q": jnp.asarray(vq), "scale": jnp.asarray(vs)}
     else:
@@ -305,9 +317,8 @@ def test_paged_new_row_matches_gathered_einsum(geometry, pool, fill_kind):
         k_dense, v_dense = jnp.asarray(k, dt), jnp.asarray(v, dt)
         k_p, = _paged_layout([np.asarray(k_dense)], bk, tables, 1e4)
         v_p, = _paged_layout([np.asarray(v_dense)], bk, tables, 1e4)
-        got = flash_decode_paged(
-            q, k_p, v_p, jnp.asarray(tables), jnp.asarray(fills),
-            new_rows=(k_new, v_new), interpret=True)
+        got = _paged(q, k_p, v_p, jnp.asarray(tables), jnp.asarray(fills),
+                     new_rows=(k_new, v_new))
     assert np.isfinite(np.asarray(got, np.float32)).all()
 
     if geometry is _PAIRS:
@@ -353,15 +364,15 @@ def test_paged_slot_never_sees_what_an_earlier_slot_copied(pool):
         clean = [jnp.asarray(rng.integers(-127, 128, shape), jnp.int8),
                  jnp.asarray(rng.uniform(0.01, 0.1, shape[:3]), jnp.float32)]
         clean = clean + [clean[0][::-1], clean[1][::-1]]
-        call = flash_decode_paged_int8
+        call = _paged_int8
     else:
         clean = [jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
                  for _ in range(2)]
-        call = flash_decode_paged
+        call = _paged
     dirty = [a if a.dtype == jnp.int8 else a.at[1:1 + t].set(jnp.nan)
              for a in clean]
-    want = call(q, *clean, tables, fills, interpret=True)
-    got = call(q, *dirty, tables, fills, interpret=True)
+    want = call(q, *clean, tables, fills)
+    got = call(q, *dirty, tables, fills)
     assert np.isfinite(np.asarray(got[1], np.float32)).all()
     np.testing.assert_array_equal(np.asarray(got[1], np.float32),
                                   np.asarray(want[1], np.float32))
@@ -386,11 +397,10 @@ def test_paged_whole_pool_layer_index_equals_layer_view(geometry):
             for _ in range(2)]
     tables = jnp.asarray(_shuffled_tables(b, t, rng))
     for layer in (0, 2):
-        want = flash_decode_paged(q, pools[0][layer], pools[1][layer],
-                                  tables, fills, new_rows=rows,
-                                  interpret=True)
-        got = flash_decode_paged(q, *pools, tables, fills, new_rows=rows,
-                                 layer=jnp.int32(layer), interpret=True)
+        want = _paged(q, pools[0][layer], pools[1][layer], tables, fills,
+                      new_rows=rows)
+        got = _paged(q, *pools, tables, fills, new_rows=rows,
+                     layer=jnp.int32(layer))
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
@@ -414,11 +424,11 @@ def _neighbourhood(geometry, rng, mine, fills, nan_blocks):
                               jnp.int8),
                   jnp.asarray(rng.uniform(0.01, 0.1, (1 + b * t, kv, bk)),
                               jnp.float32)] * 2
-        call = flash_decode_paged_int8
+        call = _paged_int8
     else:
         leaves = [jnp.asarray(rng.normal(size=(1 + b * t, h, bk, w)), dt)
                   for h, w in ((kv, d), (vh, dv))]
-        call = flash_decode_paged
+        call = _paged
     if nan_blocks:
         leaves = [a if a.dtype == jnp.int8 else a.at[1:].set(jnp.nan)
                   for a in leaves]
@@ -426,7 +436,7 @@ def _neighbourhood(geometry, rng, mine, fills, nan_blocks):
     fills = np.asarray(fills, np.int32)
     fills[2] = mine["fill"]
     return call(q, *leaves, jnp.asarray(tables), jnp.asarray(fills),
-                new_rows=rows, interpret=True)[2]
+                new_rows=rows)[2]
 
 
 @pytest.mark.parametrize("pool", ["bf16", "int8", "pairs"])

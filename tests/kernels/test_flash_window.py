@@ -4,6 +4,8 @@ head shared by consecutive key heads (the band's live tiles counted), and
 the paged walk at such a value head of a width of its own; and the
 kernel of a window layer's decode step on the stacked rings."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -13,7 +15,17 @@ from megatron_llm_tpu.kernels import flash_attention as fa
 from megatron_llm_tpu.kernels import flash_decode as fd
 from megatron_llm_tpu.kernels.flash_attention import (flash_attention,
                                                        tile_plan)
+from megatron_llm_tpu.kernels.ring_decode import ring_decode
 from megatron_llm_tpu.ops.attention import dot_product_attention
+
+# An interpreted kernel is traced and lowered in Python for seconds a bare
+# call: the decode kernels below are called through one jit each, so the
+# calls of one set of shapes (the two fills, a ring and its stale twin, the
+# two layers: a traced index) are traced once a process.
+_paged = jax.jit(functools.partial(fd.flash_decode_paged, softmax_scale=0.25,
+                                   interpret=True))
+_ring = jax.jit(functools.partial(ring_decode, softmax_scale=0.25,
+                                  interpret=True))
 
 
 def _rand(k, shape):
@@ -112,9 +124,8 @@ def test_the_paged_walk_at_a_value_head_shared_by_two_key_heads(fills):
         used = -(-int(fills[i] + 1) // bk)
         tables[i, :used] = ids[n:n + used]
         n += used
-    got = fd.flash_decode_paged(q, kp, vp, tables, fills, new_rows=(kn, vn),
-                                layer=jnp.int32(1), softmax_scale=0.25,
-                                interpret=True)
+    got = _paged(q, kp, vp, tables, fills, new_rows=(kn, vn),
+                 layer=jnp.int32(1))
     assert got.shape == (S, 8, dv)
     kd = jnp.moveaxis(kp[1][tables], 2, 1).reshape(S, 4, T * bk, d)
     vd = jnp.moveaxis(vp[1][tables], 2, 1).reshape(S, 2, T * bk, dv)
@@ -151,7 +162,6 @@ def test_the_ring_kernel_is_the_plain_composition(layer):
     ring not yet full, the first wrap, and far past it — the row the
     new position will take is never counted."""
     from megatron_llm_tpu.config import phi4flash_config
-    from megatron_llm_tpu.kernels.ring_decode import ring_decode
     from megatron_llm_tpu.models import diff_attention
 
     L, S, kv, d, W = 3, 6, 4, 16, 8
@@ -159,8 +169,7 @@ def test_the_ring_kernel_is_the_plain_composition(layer):
     ring_k, ring_v = (_rand(i, (L, S, 2, W, 2 * d)) for i in (1, 2))
     kn, vn = _rand(3, (S, kv, 1, d)), _rand(4, (S, 2, 1, 2 * d))
     pos = jnp.asarray([0, 3, 7, 8, 13, 30], jnp.int32)
-    got = ring_decode(q, ring_k, ring_v, kn, vn, pos, jnp.int32(layer),
-                      softmax_scale=0.25, interpret=True)
+    got = _ring(q, ring_k, ring_v, kn, vn, pos, jnp.int32(layer))
     cfg = phi4flash_config(attention_multiplier=0.25)
     assert cfg.attention_impl != "flash"
     want = diff_attention.attend_ring(cfg, q[:, None], ring_k, ring_v,
@@ -168,8 +177,7 @@ def test_the_ring_kernel_is_the_plain_composition(layer):
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
     # the stale row holds anything: it must not reach the output
     stale = ring_k.at[layer, 4, :, 13 % W].set(1e4)
-    again = ring_decode(q, stale, ring_v, kn, vn, pos, jnp.int32(layer),
-                        softmax_scale=0.25, interpret=True)
+    again = _ring(q, stale, ring_v, kn, vn, pos, jnp.int32(layer))
     np.testing.assert_array_equal(again[4], got[4])
 
 
@@ -228,7 +236,6 @@ def test_the_ring_kernel_at_one_key_head_a_row(layer):
     already rotated at their own positions, so neither knows of a
     rotation: the count mask stands as it is."""
     from megatron_llm_tpu.config import laguna_config
-    from megatron_llm_tpu.kernels.ring_decode import ring_decode
     from megatron_llm_tpu.models import diff_attention
 
     L, S, kv, d, W, heads = 3, 6, 2, 16, 8, 16
@@ -236,8 +243,7 @@ def test_the_ring_kernel_at_one_key_head_a_row(layer):
     ring_k, ring_v = (_rand(i, (L, S, kv, W, d)) for i in (1, 2))
     kn, vn = _rand(3, (S, kv, 1, d)), _rand(4, (S, kv, 1, d))
     pos = jnp.asarray([0, 3, 7, 8, 13, 30], jnp.int32)
-    got = ring_decode(q, ring_k, ring_v, kn, vn, pos, jnp.int32(layer),
-                      softmax_scale=0.25, interpret=True)
+    got = _ring(q, ring_k, ring_v, kn, vn, pos, jnp.int32(layer))
     want = _ring_reference(*(np.asarray(a) for a in (
         q, ring_k[layer], ring_v[layer], kn, vn, pos)), 0.25)
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
@@ -252,6 +258,5 @@ def test_the_ring_kernel_at_one_key_head_a_row(layer):
     np.testing.assert_allclose(plain, want, atol=2e-5, rtol=2e-5)
     # the stale row holds anything: it must not reach the output
     stale = ring_k.at[layer, 4, :, 13 % W].set(1e4)
-    again = ring_decode(q, stale, ring_v, kn, vn, pos, jnp.int32(layer),
-                        softmax_scale=0.25, interpret=True)
+    again = _ring(q, stale, ring_v, kn, vn, pos, jnp.int32(layer))
     np.testing.assert_array_equal(again[4], got[4])

@@ -46,18 +46,23 @@ def layer(key, cfg, slots, layers, dead=None):
 
 
 def run(cfg, p, qkvz, ba, live, S, tail, at):
-    # the layer as a traced scalar, as the scan over periods hands it over
-    return jax.jit(lambda *a: gdn_step(*a, eps=cfg.norm_eps))(
+    # the layer as a traced scalar, as the scan over periods hands it
+    # over: the kernel's own jitted call takes it so, and the cases that
+    # differ in the layer alone run one executable
+    return gdn_step(
         qkvz[:, 0], ba[:, 0], p["conv"], p["A_log"], p["dt_bias"],
-        p["norm"]["scale"], live, S, tail, jnp.int32(at))
+        p["norm"]["scale"], live, S, tail, jnp.int32(at), eps=cfg.norm_eps)
+
+
+_plain = jax.jit(gdn.one_position, static_argnums=0)
 
 
 def check(cfg, p, qkvz, ba, live, S, tail, at, tol=2e-5):
     """The kernel's three results against the plain composition's; the
     other layers and the dead slots as they were, bit for bit."""
     o, new, new_tail = run(cfg, p, qkvz, ba, live, S, tail, at)
-    want_o, want = jax.jit(lambda *a: gdn.one_position(cfg, p, *a))(
-        qkvz, ba, gdn.GDNState(S[at], tail[at]), live[:, None])
+    want_o, want = _plain(cfg, p, qkvz, ba, gdn.GDNState(S[at], tail[at]),
+                          live[:, None])
     assert o.shape == want_o[:, 0].shape and o.dtype == jnp.float32
     np.testing.assert_allclose(o, want_o[:, 0], atol=tol, rtol=tol)
     np.testing.assert_allclose(new[at], want.S, atol=tol, rtol=tol)

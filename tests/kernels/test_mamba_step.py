@@ -75,10 +75,12 @@ def layer(key, H, hp, n, groups, slots, layers, flat, dtype=jnp.float32):
 
 
 def run(p, zx, live, ssm, tail, at):
-    # the layer as a traced scalar, as the scan over periods hands it over
-    return jax.jit(lambda *a: mamba_step(*a, eps=EPS))(
+    # the layer as a traced scalar, as the scan over periods hands it
+    # over: the kernel's own jitted call takes it so, and the cases that
+    # differ in the layer alone run one executable
+    return mamba_step(
         zx, p["conv"], p["conv_bias"], p["dt_bias"], p["A_log"], p["D"],
-        p["scale"], live, ssm, tail, jnp.int32(at))
+        p["scale"], live, ssm, tail, jnp.int32(at), eps=EPS)
 
 
 def check(p, zx, live, ssm, tail, at, groups):
@@ -101,10 +103,17 @@ def check(p, zx, live, ssm, tail, at, groups):
         assert float(jnp.abs(new_tail[at, 0] - tail[at, 0]).max()) > 1e-3
 
 
-@pytest.mark.parametrize("flat", [False, True], ids=["rows", "flat"])
-@pytest.mark.parametrize("where", ["first", "middle", "last"])
-@pytest.mark.parametrize("slots", [1, 3, 8])
-@pytest.mark.parametrize("per", [1, 2, 16])
+# (the layer innermost: the three cases of one set of shapes run one after
+# another, on one worker, through one executable)
+STACKED = [(per, slots, where, flat) for per in (1, 2, 16)
+           for slots in (1, 3, 8) for flat in (False, True)
+           for where in ("first", "middle", "last")]
+
+
+@pytest.mark.parametrize(
+    "per,slots,where,flat", STACKED,
+    ids=["%d-%d-%s-%s" % (p, s, w, "flat" if f else "rows")
+         for p, s, w, f in STACKED])
 def test_one_layer_of_the_stacked_states_is_advanced_where_it_lies(
         per, slots, where, flat):
     layers, H = 3, per * G

@@ -9,8 +9,23 @@ chip and compiles for a topology that is described, not attached
 (``jax.experimental.topologies``), so each case below lowers one kernel
 with ``interpret=False`` at a real width and compiles it for ``v5e:2x2``.
 Nothing runs: a pass says "compiles", never "correct" or "fast".
-Whole-step compiles (15-25 s each) live in ``chip_smoke.py``'s own
-planning pass, not here.
+
+Nine whole programs of the engine at published widths are compiled here
+too (beside the Llama-family steps and verify walks at toy widths, 2-10 s
+each), because what they hold shows only in the chip's compiler's output
+at a cell's shapes, and ``chip_smoke.py``'s planning pass compiles a
+Llama-2-7B-width train step and decode step and no other: the decode step
+of each of six served families at its cell's slots, tables and pool (a
+donated stack of states, rings or latent rows aliased and never copied, N
+kernels a layer, the bytes ``memory_analysis`` states), one dropless
+prefill, and Falcon's composed step on one chip and under tp=2 (no weight
+re-laid, the sampler's sorts under their ``conditional``).  They are at
+the cell's or the published depth, which costs nothing: a scan's body is
+compiled once (``_SMALL_VOCAB`` says what does cost, what was cut, and
+which two steps hold the head, the table and the sampler at a published
+vocabulary).  13-17 s each alone on eight cores at 2048 entries and 28 s
+at phi-4-flash's 200 064, 25-45 s beside five other workers; a new family
+adds one, within the budget ROADMAP D17 (b) sets for a PR's tests.
 """
 
 import contextlib
@@ -111,6 +126,32 @@ def _compile(fn, args, sharding):
 
 def _sds(shape, dtype=BF16):
     return jax.ShapeDtypeStruct(shape, dtype)
+
+
+# What a whole-step compile costs here (measured, PR 59: 23-28 s each alone
+# on eight cores at the published vocabularies, 13-17 s at 2048) is the
+# backend's code generation for ONE trip of each scan's body at the cell's
+# slots (a quarter of a second a slot) and the sampler's ordering of the
+# vocabulary (10 s at 100 352 entries, 24 s at 128 256): not the depth (8
+# layers or 4, 40 or 20, 32 or 10 compile in the same seconds: a scan's
+# body is compiled once) and not the experts.  So the depth and the slots
+# stay the cell's, which the bytes asserted below need, and four decode
+# steps (kanana, granite, Qwen3-Next, Laguna) and Falcon-40B's width under
+# tp=2 compile at 2048 entries, with ``argument_size_in_bytes`` AND
+# ``temp_size_in_bytes`` restated from the compile at 2048: each temp
+# bound is the parent's times (reading at 2048 / reading at the published
+# vocabulary), so it is as far above its reading as it was (the readings
+# beside each bound).  At 2048 entries the table is 8 MiB, the least
+# ``relayout_bytes`` reports, and a sort that small may be turned into a
+# select: what reads the head, the table and the sampler AT SIZE is held
+# by two steps that keep their published vocabulary,
+# ``test_a_stack_of_runs_decode_step_at_its_published_depth`` (phi-4-mini-
+# flash, 200 064 entries, the table tied to the head: no re-layout of
+# 8 MiB in the step, the bytes) and
+# ``test_composed_decode_step_touches_only_live_kv[falcon7b_one_chip]``
+# (65 024: the table read where it lies, the sorts under their
+# ``conditional``).
+_SMALL_VOCAB = 2048
 
 
 # -- attention / norm kernels at published widths --------------------------
@@ -232,44 +273,58 @@ def test_mla_decode(topo):
     assert relayout_bytes(text) == {}
 
 
-def test_a_latent_attention_decode_step_copies_no_pool_and_no_expert(
-        topo, monkeypatch):
-    """The engine's decode executable for the kanana-2 stage whole: the
-    dense layer before the scan, five expert layers in its ``while``, 44
-    slots of 16 896 positions: 13.3 GB of arguments of which the 5.1 GB
-    pool of latent rows is donated and aliased.  The scan closes over the
-    stack's experts and the grouped kernel addresses its layer: sliced
-    out for the custom call, a layer's 128 experts were three copies of
-    0.4 GB, 6 GB a step (PR 52).  And ``wq`` lies where it lies: the
-    query is rotated as the matmul leaves it (cut into heads at once the
-    product re-laid 25 MB a layer)."""
-    from megatron_llm_tpu.config import deepseek_v3_config
+def _engine_decode_step(topo, monkeypatch, cfg, slots, table, blocks,
+                        block=128):
+    """The engine's decode step of ``cfg`` as a serving process on the
+    chip traces it (the kernels not interpreted), lowered for one
+    described chip over shape-only arguments: ``slots`` slots, tables of
+    ``table`` columns, a pool of ``blocks`` blocks of ``block`` rows →
+    ``(lowered, pool, rec)``, the shapes of what the step is donated."""
     from megatron_llm_tpu.serving import engine as engine_lib
 
     monkeypatch.setattr(attn_ops, "_backend", lambda: "tpu")
     monkeypatch.setattr(kernels, "default_interpret", lambda: False)
     one = SingleDeviceSharding(topo.devices[0])
-    S, T, bk = 44, 132, 128
-    cfg = deepseek_v3_config("kanana-2-30b-a3b-pp8-stage0",
-                             attention_impl="flash")
     place = lambda tree: jax.tree.map(  # noqa: E731
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
     params = jax.eval_shape(
         lambda key: model_lib.init_params(key, cfg), jax.random.key(0))
-    pool = jax.eval_shape(lambda: model_lib.init_kv_pool(cfg, S * T + 1, bk))
-    rec = jax.eval_shape(lambda: model_lib.init_rec_state(cfg, S))
+    pool = jax.eval_shape(lambda: model_lib.init_kv_pool(cfg, blocks, block))
+    rec = jax.eval_shape(lambda: model_lib.init_rec_state(cfg, slots))
     i32, f32 = jnp.int32, jnp.float32
-    vec = lambda dtype: place(_sds((S,), dtype))  # noqa: E731
-    compiled = engine_lib._decode_donated.lower(
-        cfg, place(params), *place(pool), place(_sds((S, T), i32)),
+    vec = lambda dtype: place(_sds((slots,), dtype))  # noqa: E731
+    lowered = engine_lib._decode_donated.lower(
+        cfg, place(params), *place(pool), place(_sds((slots, table), i32)),
         vec(i32), vec(i32), vec(jnp.uint32), vec(i32), vec(bool), vec(f32),
-        vec(i32), vec(f32), rec=place(rec), live=vec(bool)).compile()
+        vec(i32), vec(f32), rec=place(rec), live=vec(bool))
+    return lowered, pool, rec
+
+
+def test_a_latent_attention_decode_step_copies_no_pool_and_no_expert(
+        topo, monkeypatch):
+    """The engine's decode executable for the kanana-2 stage whole: the
+    dense layer before the scan, five expert layers in its ``while``, 44
+    slots of 16 896 positions, the vocabulary cut to 2048 (``_SMALL_VOCAB``:
+    1.0 GB of table and head less): 11.7 GB of arguments of which the 5.1
+    GB pool of latent rows is donated and aliased.  The scan closes over the
+    stack's experts and the grouped kernel addresses its layer: sliced
+    out for the custom call, a layer's 128 experts were three copies of
+    0.4 GB, 6 GB a step (PR 52).  And ``wq`` lies where it lies: the
+    query is rotated as the matmul leaves it (cut into heads at once the
+    product re-laid 25 MB a layer)."""
+    S, T, bk = 44, 132, 128
+    cfg = deepseek_v3_config("kanana-2-30b-a3b-pp8-stage0",
+                             attention_impl="flash", vocab_size=_SMALL_VOCAB)
+    lowered, pool, _rec = _engine_decode_step(topo, monkeypatch, cfg, S, T,
+                                              S * T + 1)
+    compiled = lowered.compile()
     mem = compiled.memory_analysis()
     held = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(pool))
     assert held == 6 * (S * T + 1) * bk * 576 * 2
     assert held <= mem.alias_size_in_bytes < held + 2 ** 20
-    assert 12.6e9 < mem.argument_size_in_bytes < 12.9e9
-    assert mem.temp_size_in_bytes < 0.2e9
+    assert 11.6e9 < mem.argument_size_in_bytes < 11.8e9
+    # (4.29e6 read; 100.6e6 under 0.2e9 at the published 128 256 entries)
+    assert mem.temp_size_in_bytes < 8.5e6
     text = compiled.as_text()
     # the walk once in the dense layer and once in the scan's body
     assert len(ops_under_scopes(text, ["mla_decode"], {"custom-call"})) == 2
@@ -597,29 +652,13 @@ def test_a_state_space_decode_step_rewrites_its_states_in_place(
     advances its layer where it lies, no other operation of the step
     touches them, and between the layer's two projections the kernel is
     all that runs.  A second copy of the states would not fit the chip."""
-    from megatron_llm_tpu.config import nemotron_h_config
-    from megatron_llm_tpu.serving import engine as engine_lib
-
-    monkeypatch.setattr(attn_ops, "_backend", lambda: "tpu")
-    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
-    one = SingleDeviceSharding(topo.devices[0])
-    S, blocks, bk = 128, 32, 128
+    S, blocks = 128, 32
     cfg = nemotron_h_config("3-super-120b-a12b-ep4-rank0", num_layers=11,
                             attention_impl="flash")
-    place = lambda tree: jax.tree.map(  # noqa: E731
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
-    params = jax.eval_shape(
-        lambda key: model_lib.init_params(key, cfg), jax.random.key(0))
-    pool = jax.eval_shape(
-        lambda: model_lib.init_kv_pool(cfg, S * blocks + 1, bk))
-    rec = jax.eval_shape(lambda: model_lib.init_rec_state(cfg, S))
+    lowered, pool, rec = _engine_decode_step(topo, monkeypatch, cfg, S,
+                                             blocks, S * blocks + 1)
     assert rec["ssm"].shape == (5, S, 128, 64, 128)
-    i32, f32 = jnp.int32, jnp.float32
-    vec = lambda dtype: place(_sds((S,), dtype))  # noqa: E731
-    compiled = engine_lib._decode_donated.lower(
-        cfg, place(params), *place(pool), place(_sds((S, blocks), i32)),
-        vec(i32), vec(i32), vec(jnp.uint32), vec(i32), vec(bool), vec(f32),
-        vec(i32), vec(f32), rec=place(rec), live=vec(bool)).compile()
+    compiled = lowered.compile()
     mem = compiled.memory_analysis()
     donated = sum(a.size * a.dtype.itemsize
                   for a in jax.tree.leaves((pool, rec)))
@@ -668,9 +707,10 @@ def test_mamba_step_at_one_group_of_64_heads(topo):
 
 def test_a_whole_depth_hybrid_step_moves_no_stacked_state(topo, monkeypatch):
     """The engine's decode executable and its state install for
-    granite-4.0-h-micro whole: 40 layers in four scanned periods, 64
-    slots, 12.9 GB of arguments of which the 6.6 GB of pool and slot state
-    are donated and aliased.  Inside the scan's ``while`` every
+    granite-4.0-h-micro at its depth: 40 layers in four scanned periods,
+    64 slots, the vocabulary cut to 2048 (``_SMALL_VOCAB``), 12.5 GB of
+    arguments of which the 6.6 GB of pool and slot state are donated and
+    aliased.  Inside the scan's ``while`` every
     state-space layer advances its own layer of the stacked states where
     they lie (between its two projections the kernel is all that runs),
     and nothing copies or re-lays an array of all the layers' states or
@@ -678,35 +718,23 @@ def test_a_whole_depth_hybrid_step_moves_no_stacked_state(topo, monkeypatch):
     rows padded to a tile of four) XLA:TPU re-laid the whole 160 MB array
     twice between every two layers of a period, 7 GB a step (PR 49)."""
     from megatron_llm_tpu.config import granite_hybrid_config
-    from megatron_llm_tpu.serving import engine as engine_lib
     from megatron_llm_tpu.serving import slots as slots_lib
 
-    monkeypatch.setattr(attn_ops, "_backend", lambda: "tpu")
-    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
-    one = SingleDeviceSharding(topo.devices[0])
-    S, blocks, bk = 64, 24, 128
-    cfg = granite_hybrid_config("4.0-h-micro", attention_impl="flash")
-    place = lambda tree: jax.tree.map(  # noqa: E731
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
-    params = jax.eval_shape(
-        lambda key: model_lib.init_params(key, cfg), jax.random.key(0))
-    pool = jax.eval_shape(
-        lambda: model_lib.init_kv_pool(cfg, S * blocks + 1, bk))
-    rec = jax.eval_shape(lambda: model_lib.init_rec_state(cfg, S))
+    S, blocks = 64, 24
+    cfg = granite_hybrid_config("4.0-h-micro", attention_impl="flash",
+                                vocab_size=_SMALL_VOCAB)
+    lowered, pool, rec = _engine_decode_step(topo, monkeypatch, cfg, S,
+                                             blocks, S * blocks + 1)
     assert rec["ssm"].shape == (36, S, 64, 64, 128)
     assert rec["ssm_conv"].shape == (36, S, 3 * 4352)
-    i32, f32 = jnp.int32, jnp.float32
-    vec = lambda dtype: place(_sds((S,), dtype))  # noqa: E731
-    compiled = engine_lib._decode_donated.lower(
-        cfg, place(params), *place(pool), place(_sds((S, blocks), i32)),
-        vec(i32), vec(i32), vec(jnp.uint32), vec(i32), vec(bool), vec(f32),
-        vec(i32), vec(f32), rec=place(rec), live=vec(bool)).compile()
+    compiled = lowered.compile()
     mem = compiled.memory_analysis()
     donated = sum(a.size * a.dtype.itemsize
                   for a in jax.tree.leaves((pool, rec)))
-    assert 12.8e9 < mem.argument_size_in_bytes < 13.1e9
+    assert 12.4e9 < mem.argument_size_in_bytes < 12.7e9
     assert donated <= mem.alias_size_in_bytes < donated + 2 ** 20
-    assert mem.temp_size_in_bytes < 0.15e9
+    # (5.32e6 read; 83.3e6 under 0.15e9 at the published 100 352 entries)
+    assert mem.temp_size_in_bytes < 9.5e6
     text = compiled.as_text()
     # the kernel once a state-space layer of a period's body
     assert len(ops_under_scopes(text, ["mamba_step"], {"custom-call"})) \
@@ -715,9 +743,12 @@ def test_a_whole_depth_hybrid_step_moves_no_stacked_state(topo, monkeypatch):
     assert not [k for k in relayout_bytes(text) if k.startswith("f32[36,")]
     assert "remat_compressed" not in text
     # and the install writes one slot's rows into the donated stack
+    one = SingleDeviceSharding(topo.devices[0])
+    place = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
     slot = jax.eval_shape(lambda: model_lib.init_rec_state(cfg, 1))
     mem = slots_lib._install_rec_donated.lower(
-        place(rec), place(slot), place(_sds((), i32))).compile(
+        place(rec), place(slot), place(_sds((), jnp.int32))).compile(
         ).memory_analysis()
     states = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(rec))
     assert states <= mem.alias_size_in_bytes < states + 2 ** 20
@@ -727,9 +758,13 @@ def test_a_whole_depth_hybrid_step_moves_no_stacked_state(topo, monkeypatch):
 def test_a_stack_of_runs_decode_step_at_its_published_depth(topo,
                                                             monkeypatch):
     """The engine's decode executable for phi-4-mini-flash-reasoning
-    whole: 32 layers in three runs, 64 slots of 8192 positions, 11.96 GB
-    of arguments of which the 4.25 GB of pool, Mamba-1 states and window
-    rings are donated and aliased.  Three kernels in the program text
+    whole: 32 layers in three runs, 64 slots of 8192 positions, the
+    published 200 064 entries in a table tied to the head (the largest
+    head served, and kept at its size: ``_SMALL_VOCAB``), 11.96 GB of
+    arguments of which the 4.25 GB of pool, Mamba-1 states and window
+    rings are donated and aliased, 0.33 GB of temporaries (the logits and
+    the sampler's buffers are most of them: 18 MB at 2048 entries).  Three
+    kernels in the program text
     (the full layer's walk, written out, the cross layers' in their run's
     ``while``, and the window layers' ring kernel in theirs, which takes
     the stacked rings and the layer's index: no ring is sliced or
@@ -737,31 +772,17 @@ def test_a_stack_of_runs_decode_step_at_its_published_depth(topo,
     into heads behind a barrier), the stacked Mamba-1 states advanced
     where they lie."""
     from megatron_llm_tpu.config import phi4flash_config
-    from megatron_llm_tpu.serving import engine as engine_lib
 
-    monkeypatch.setattr(attn_ops, "_backend", lambda: "tpu")
-    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
-    one = SingleDeviceSharding(topo.devices[0])
     S, blocks, bk = 64, 64, 128
     cfg = phi4flash_config(attention_impl="flash")
-    place = lambda tree: jax.tree.map(  # noqa: E731
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
-    params = jax.eval_shape(
-        lambda key: model_lib.init_params(key, cfg), jax.random.key(0))
-    pool = jax.eval_shape(
-        lambda: model_lib.init_kv_pool(cfg, S * blocks + 1, bk))
-    rec = jax.eval_shape(lambda: model_lib.init_rec_state(cfg, S))
+    lowered, pool, rec = _engine_decode_step(topo, monkeypatch, cfg, S,
+                                             blocks, S * blocks + 1)
     assert rec["ssm1"].shape == (9, S, 16, 5120)
     assert rec["win_k"].shape == (8, S, 10, 512, 128)
     assert rec["win_v"].shape == (8, S, 10, 512, 128)
     assert [a.shape for a in pool] == [(1, S * blocks + 1, 20, bk, 64),
                                        (1, S * blocks + 1, 10, bk, 128)]
-    i32, f32 = jnp.int32, jnp.float32
-    vec = lambda dtype: place(_sds((S,), dtype))  # noqa: E731
-    compiled = engine_lib._decode_donated.lower(
-        cfg, place(params), *place(pool), place(_sds((S, blocks), i32)),
-        vec(i32), vec(i32), vec(jnp.uint32), vec(i32), vec(bool), vec(f32),
-        vec(i32), vec(f32), rec=place(rec), live=vec(bool)).compile()
+    compiled = lowered.compile()
     mem = compiled.memory_analysis()
     donated = sum(a.size * a.dtype.itemsize
                   for a in jax.tree.leaves((pool, rec)))
@@ -784,7 +805,8 @@ def test_a_delta_rule_decode_step_advances_its_states_where_they_lie(
         topo, monkeypatch):
     """The engine's decode executable for Qwen3-Next at the published
     widths and the long-document cell's 44 slots, two periods with 64 held
-    experts: the scan is a ``while`` and a layer's place in the stacked
+    experts and a vocabulary of 2048 (``_SMALL_VOCAB``): the scan is a
+    ``while`` and a layer's place in the stacked
     states a traced scalar (the cell's stage of one period unrolls; it
     read the same under these assertions in a scratch compile and in the
     chip's trace, PERF.md, PR 51).  Each DeltaNet layer's kernel
@@ -796,38 +818,23 @@ def test_a_delta_rule_decode_step_advances_its_states_where_they_lie(
     step, and its update made four passes; PR 49's tails were re-laid
     whole between the layers of a ``while``)."""
     periods = 2
-    from megatron_llm_tpu.config import qwen3_next_config
-    from megatron_llm_tpu.serving import engine as engine_lib
-
-    monkeypatch.setattr(attn_ops, "_backend", lambda: "tpu")
-    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
-    one = SingleDeviceSharding(topo.devices[0])
-    S, blocks, bk = 44, 130, 128
+    S, blocks = 44, 130
     cfg = qwen3_next_config(
         "80b-a3b-ep2-rank0", num_layers=4 * periods, attention_impl="flash",
-        num_experts=64)
-    place = lambda tree: jax.tree.map(  # noqa: E731
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
-    params = jax.eval_shape(
-        lambda key: model_lib.init_params(key, cfg), jax.random.key(0))
-    pool = jax.eval_shape(
-        lambda: model_lib.init_kv_pool(cfg, S * blocks + 1, bk))
-    rec = jax.eval_shape(lambda: model_lib.init_rec_state(cfg, S))
+        num_experts=64, vocab_size=_SMALL_VOCAB)
+    lowered, pool, rec = _engine_decode_step(topo, monkeypatch, cfg, S,
+                                             blocks, S * blocks + 1)
     L = 3 * periods
     assert rec["S"].shape == (L, S, 32, 128, 128)
     assert rec["conv"].shape == (L, S, 3, 8192)
-    i32, f32 = jnp.int32, jnp.float32
-    vec = lambda dtype: place(_sds((S,), dtype))  # noqa: E731
-    compiled = engine_lib._decode_donated.lower(
-        cfg, place(params), *place(pool), place(_sds((S, blocks), i32)),
-        vec(i32), vec(i32), vec(jnp.uint32), vec(i32), vec(bool), vec(f32),
-        vec(i32), vec(f32), rec=place(rec), live=vec(bool)).compile()
+    compiled = lowered.compile()
     mem = compiled.memory_analysis()
     donated = sum(a.size * a.dtype.itemsize
                   for a in jax.tree.leaves((pool, rec)))
     # (the tails' 44 slots are padded to whole tiles of eight)
     assert donated <= mem.alias_size_in_bytes < donated + 2 ** 22
-    assert mem.temp_size_in_bytes < 0.5e9
+    # (7.97e6 read; 24.4e6 under 0.5e9 at the rank's 75 968 entries)
+    assert mem.temp_size_in_bytes < 0.16e9
     text = compiled.as_text()
     # the kernel once a DeltaNet layer of a period's body, and beside it
     # between the projections relabellings and constants alone
@@ -948,39 +955,27 @@ def test_a_window_and_full_decode_step_at_the_cells_size(topo, monkeypatch):
     """The engine's decode executable for the Laguna cell: five layers (a
     leading dense full layer, three window layers, a full expert layer),
     40 slots, a pool of 2560 blocks over the TWO full layers, three rings
-    a slot.  10.68 GB of arguments of which pool and rings (2.94 GB) are
-    donated and aliased; thirteen kernels in the program text (two paged
+    a slot, the vocabulary cut to 2048 (``_SMALL_VOCAB``).  9.88 GB of
+    arguments of which pool and rings (2.94 GB) are donated and aliased; thirteen kernels in the program text (two paged
     walks, three ring kernels, four routers and four grouped expert
     kernels); neither the pool nor the stacked rings nor an expert stack
     is copied."""
     from megatron_llm_tpu.config import laguna_config
-    from megatron_llm_tpu.serving import engine as engine_lib
 
-    monkeypatch.setattr(attn_ops, "_backend", lambda: "tpu")
-    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
-    one = SingleDeviceSharding(topo.devices[0])
     S, blocks, bk, T = 40, 2560, 128, 144
-    cfg = laguna_config(attention_impl="flash")
-    place = lambda tree: jax.tree.map(  # noqa: E731
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
-    params = jax.eval_shape(
-        lambda key: model_lib.init_params(key, cfg), jax.random.key(0))
-    pool = jax.eval_shape(lambda: model_lib.init_kv_pool(cfg, blocks, bk))
-    rec = jax.eval_shape(lambda: model_lib.init_rec_state(cfg, S))
+    cfg = laguna_config(attention_impl="flash", vocab_size=_SMALL_VOCAB)
+    lowered, pool, rec = _engine_decode_step(topo, monkeypatch, cfg, S, T,
+                                             blocks)
     assert rec["win_k"].shape == rec["win_v"].shape == (3, S, 8, 512, 128)
     assert [a.shape for a in pool] == [(2, blocks, 8, bk, 128)] * 2
-    i32, f32 = jnp.int32, jnp.float32
-    vec = lambda dtype: place(_sds((S,), dtype))  # noqa: E731
-    compiled = engine_lib._decode_donated.lower(
-        cfg, place(params), *place(pool), place(_sds((S, T), i32)),
-        vec(i32), vec(i32), vec(jnp.uint32), vec(i32), vec(bool), vec(f32),
-        vec(i32), vec(f32), rec=place(rec), live=vec(bool)).compile()
+    compiled = lowered.compile()
     mem = compiled.memory_analysis()
     donated = sum(a.size * a.dtype.itemsize
                   for a in jax.tree.leaves((pool, rec)))
-    assert 10.6e9 < mem.argument_size_in_bytes < 10.8e9
+    assert 9.8e9 < mem.argument_size_in_bytes < 10.0e9
     assert donated <= mem.alias_size_in_bytes < donated + 2 ** 20
-    assert mem.temp_size_in_bytes < 0.2e9
+    # (27.5e6 read; 28.2e6 under 0.2e9 at the published 100 352 entries)
+    assert mem.temp_size_in_bytes < 0.19e9
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 13
     _no_copy_of(text, "bf16[3,40,8,512,128]")
@@ -1049,30 +1044,24 @@ def test_a_router_chooses_without_a_sort_a_gather_or_a_scatter(
 
     from megatron_llm_tpu.serving import engine as engine_lib
 
-    monkeypatch.setattr(attn_ops, "_backend", lambda: "tpu")
-    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
-    one = SingleDeviceSharding(topo.devices[0])
     cfg = _ROUTED[preset]()
-    place = lambda tree: jax.tree.map(  # noqa: E731
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
-    params = jax.eval_shape(
-        lambda key: model_lib.init_params(key, cfg), jax.random.key(0))
-    i32, f32 = jnp.int32, jnp.float32
     if program == "prefill":
+        monkeypatch.setattr(attn_ops, "_backend", lambda: "tpu")
+        monkeypatch.setattr(kernels, "default_interpret", lambda: False)
+        one = SingleDeviceSharding(topo.devices[0])
+        place = lambda tree: jax.tree.map(  # noqa: E731
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+            tree)
+        params = jax.eval_shape(
+            lambda key: model_lib.init_params(key, cfg), jax.random.key(0))
         lowered = engine_lib._prefill_impl.lower(
-            cfg, place(params), place(_sds((1, 2048), i32)),
-            place(_sds((1,), i32)), max_seq_len=2048 + 256,
+            cfg, place(params), place(_sds((1, 2048), jnp.int32)),
+            place(_sds((1,), jnp.int32)), max_seq_len=2048 + 256,
             want_logprobs=False)
     else:
-        S, blocks, bk = 8, 4, 128
-        pool = jax.eval_shape(
-            lambda: model_lib.init_kv_pool(cfg, S * blocks + 1, bk))
-        rec = jax.eval_shape(lambda: model_lib.init_rec_state(cfg, S))
-        vec = lambda dtype: place(_sds((S,), dtype))  # noqa: E731
-        lowered = engine_lib._decode_donated.lower(
-            cfg, place(params), *place(pool), place(_sds((S, blocks), i32)),
-            vec(i32), vec(i32), vec(jnp.uint32), vec(i32), vec(bool),
-            vec(f32), vec(i32), vec(f32), rec=place(rec), live=vec(bool))
+        S, blocks = 8, 4
+        lowered, _pool, _rec = _engine_decode_step(
+            topo, monkeypatch, cfg, S, blocks, S * blocks + 1)
     # the program as it was written, with every operation's scope path
     options = xla_client._xla.HloPrintOptions()
     options.print_metadata = True
@@ -1274,7 +1263,11 @@ def test_composed_decode_step_touches_only_live_kv(topo, monkeypatch, size,
     slice writes 8 MiB or more: every weight is read once, where it
     lies.  The sampler's ordering of the vocabulary stayed under a
     ``conditional`` (XLA turns small ones into selects that run both
-    sides): a step whose every slot is greedy sorts nothing."""
+    sides): a step whose every slot is greedy sorts nothing.  On one chip
+    at the published 65 024 entries; under tp=2 at ``_SMALL_VOCAB``, so
+    what that case holds is the pool's split and ``wq``: the head's
+    matmul split over the vocabulary, the table's masked lookup and the
+    gathered logits' sort are NOT read at their size under tp here."""
     from megatron_llm_tpu.config import falcon_config
     from megatron_llm_tpu.models import sharding as sharding_lib
     from megatron_llm_tpu.serving import engine as engine_lib
@@ -1285,7 +1278,9 @@ def test_composed_decode_step_touches_only_live_kv(topo, monkeypatch, size,
     # a pool too large for XLA to stage in fast memory, as the 32-layer
     # pool is: else the module copies it there and back
     nb = 1 + 16 * (slots * t + 256)
-    cfg = falcon_config(size, num_layers=layers, attention_impl="flash")
+    # (the published 65 024 entries on one chip; ``_SMALL_VOCAB`` under tp)
+    cfg = falcon_config(size, num_layers=layers, attention_impl="flash",
+                        **({"vocab_size": _SMALL_VOCAB} if tp else {}))
     params = jax.eval_shape(
         lambda k: model_lib.init_params(k, cfg), jax.random.key(0))
     pools = jax.eval_shape(lambda: model_lib.init_kv_pool(cfg, nb, bk))

@@ -142,19 +142,24 @@ def test_a_padded_prefill_then_steps_is_the_whole_sequence(model):
     carried K/V and states, against one forward pass over it all."""
     cfg, params = model
     toks = jax.random.randint(jax.random.key(2), (1, 50), 1, 500)
-    want = model_lib.forward(cfg, params, toks)[0]
+    want = jax.jit(lambda t: model_lib.forward(cfg, params, t))(toks)[0]
     k, v = model_lib.init_kv_cache(cfg, 1, 64)
     prompt, bucket = 37, 48
     padded = jnp.pad(toks[:, :prompt], ((0, 0), (0, bucket - prompt)))
     valid = jnp.arange(bucket)[None] < prompt
-    logits, k, v, rec = model_lib.forward_cached_hybrid(
-        cfg, params, padded, k, v, jnp.int32(0),
-        model_lib.init_rec_state(cfg, 1), valid=valid, empty_cache=True)
+    logits, k, v, rec = jax.jit(
+        lambda t, k, v, valid: model_lib.forward_cached_hybrid(
+            cfg, params, t, k, v, jnp.int32(0),
+            model_lib.init_rec_state(cfg, 1), valid=valid,
+            empty_cache=True))(padded, k, v, valid)
     np.testing.assert_allclose(logits[0, :prompt], want[:prompt], atol=2e-5)
+    # one executable for the thirteen steps (op by op, each step traced
+    # and compiled its scan again)
+    step = jax.jit(lambda t, k, v, n, rec: model_lib.forward_cached_hybrid(
+        cfg, params, t, k, v, n, rec))
     for i in range(prompt, 50):
-        l, k, v, rec = model_lib.forward_cached_hybrid(
-            cfg, params, toks[:, i:i + 1], k, v,
-            jnp.full((1,), i, jnp.int32), rec)
+        l, k, v, rec = step(toks[:, i:i + 1], k, v,
+                            jnp.full((1,), i, jnp.int32), rec)
         np.testing.assert_allclose(l[0, 0], want[i], atol=2e-5)
 
 

@@ -126,13 +126,17 @@ def test_a_step_through_the_carry_is_the_parents_form(monkeypatch):
         jax.random.key(1))
     toks = jax.random.randint(jax.random.key(5), (3, 23), 1, 500)
 
+    # the prompt once: ``_one_position`` is the one-position side's alone
+    # (``gdn_block``: ``s == 1``), so the patch below is not in it
+    k, v = model_lib.init_kv_cache(cfg, 3, 32)
+    _, k0, v0, rec0 = jax.jit(
+        lambda t, k, v: model_lib.forward_cached_hybrid(
+            cfg, params, t, k, v, jnp.int32(0),
+            model_lib.init_rec_state(cfg, 3), empty_cache=True))(
+                toks[:, :20], k, v)
+
     def run():
-        k, v = model_lib.init_kv_cache(cfg, 3, 32)
-        _, k, v, rec = jax.jit(
-            lambda t, k, v: model_lib.forward_cached_hybrid(
-                cfg, params, t, k, v, jnp.int32(0),
-                model_lib.init_rec_state(cfg, 3), empty_cache=True))(
-                    toks[:, :20], k, v)
+        k, v, rec = k0, v0, rec0
         step = jax.jit(lambda t, k, v, n, rec, live:
                        model_lib.forward_cached_hybrid(
                            cfg, params, t, k, v, n, rec, valid=live))
@@ -256,7 +260,8 @@ def test_a_padded_prefill_is_the_unpadded_one(model):
     np.testing.assert_array_equal(padded[3]["load"], exact[3]["load"])
     assert int(exact[3]["load"].sum()) == cfg.num_layers * n * cfg.moe_top_k
     # and the state is not the one after the padded tail
-    through = prefill(toks, None)
+    # (every position marked: the padded call's executable)
+    through = prefill(toks, jnp.ones((1, width), bool))
     assert float(jnp.abs(through[3]["S"] - exact[3]["S"]).max()) > 1e-3
 
 

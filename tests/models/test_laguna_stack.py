@@ -8,6 +8,7 @@ rings; each omission the reference must catch; and the older presets'
 lowered programs, which this PR leaves byte for byte."""
 
 import dataclasses
+import functools
 import hashlib
 
 import jax
@@ -51,6 +52,7 @@ def model():
     params = jax.tree_util.tree_map_with_path(_shake, params)
     tokens = jax.random.randint(jax.random.key(1), (1, PROMPT + STEPS + 1),
                                 1, 500)
+    served_programs(cfg)        # before any test's body, so any patch
     return cfg, params, tokens
 
 
@@ -80,9 +82,11 @@ def program_logits(cfg, params, tokens):
             lambda p, t: model_lib.forward(cfg, p, t))(params, tokens)[0])
 
 
-def served_logits(cfg, params, tokens, steps=STEPS):
-    """A bucket-padded prefill, then ``steps`` decode steps on the dense
-    view of the gather route, every step's logits."""
+def _programs(cfg):
+    """The jitted prefill and decode step ``served_logits`` runs, traced
+    where they are first called: an omission patched in is served through
+    a pair of its own (``programs=_programs``), the faithful model
+    through ``served_programs``."""
     @jax.jit
     def prefill(params, padded, k, v, rec):
         valid = jnp.arange(BUCKET)[None, :] < PROMPT
@@ -95,6 +99,13 @@ def served_logits(cfg, params, tokens, steps=STEPS):
         return model_lib.forward_cached_hybrid(
             cfg, params, token, k, v, t, rec, valid=jnp.ones((1, 1), bool))
 
+    return prefill, step
+
+
+def served_logits(cfg, params, tokens, steps=STEPS, programs=None):
+    """A bucket-padded prefill, then ``steps`` decode steps on the dense
+    view of the gather route, every step's logits."""
+    prefill, step = (programs or served_programs)(cfg)
     with jax.default_matmul_precision("highest"):
         k, v = model_lib.init_kv_cache(cfg, 1, 128)
         rec = model_lib.init_rec_state(cfg, 1)
@@ -107,6 +118,24 @@ def served_logits(cfg, params, tokens, steps=STEPS):
                                      jnp.array([t]), rec)
             out.append(np.asarray(logits[0, 0]))
     return np.stack(out), rec
+
+
+@functools.lru_cache(maxsize=None)
+def served_programs(cfg):
+    """One pair a configuration a process: every test that serves the
+    faithful program (here and in test_window_kind.py) runs the same two
+    executables.  Both are run once here, on zeros, before the pair is
+    handed out, and the module's fixture builds its configuration's pair
+    before any test's body runs: a test that patches the model and forgets
+    ``programs=_programs`` reads the faithful pair and fails, and cannot
+    leave a pair traced under its patch to the tests behind it."""
+    pair = _programs(cfg)
+    params = jax.tree.map(
+        lambda a: jnp.zeros(a.shape, a.dtype),
+        jax.eval_shape(lambda: model_lib.init_params(jax.random.key(0), cfg)))
+    served_logits(cfg, params, jnp.zeros((1, PROMPT + 1), jnp.int32),
+                  steps=1, programs=lambda _cfg: pair)
+    return pair
 
 
 def test_the_preset_is_the_published_stage():
@@ -320,7 +349,8 @@ def test_the_reference_catches(model, monkeypatch, what):
         else:
             want = reference_logits(cfg, params, tokens)[
                 PROMPT - 1:PROMPT + STEPS]
-            got, _rec = served_logits(cfg, params, tokens)
+            got, _rec = served_logits(cfg, params, tokens,
+                                      programs=_programs)
     finally:
         monkeypatch.undo()
         moe._dropless.clear_cache()

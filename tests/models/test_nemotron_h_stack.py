@@ -148,7 +148,8 @@ def test_a_padded_prefill_is_the_unpadded_one(model):
     per_layer = np.asarray(exact[3]["load"]).sum(axis=1)
     assert per_layer.tolist() == [0, n * cfg.moe_top_k, 0,
                                   n * cfg.moe_top_k]
-    through = prefill(toks, None)
+    # (every position marked: the padded call's executable)
+    through = prefill(toks, jnp.ones((1, width), bool))
     assert float(jnp.abs(through[3]["ssm"] - exact[3]["ssm"]).max()) > 1e-4
 
 
@@ -187,13 +188,19 @@ def test_a_stack_may_keep_both_kinds_of_recurrent_state():
     assert sorted(model_lib.rec_states(rec)) == ["S", "conv", "ssm",
                                                  "ssm_conv"]
     toks = jax.random.randint(jax.random.key(3), (2, 20), 1, 500)
-    want = model_lib.forward(cfg, params, toks)
+    # (one executable a program: op by op, each of a scan's hundreds of
+    # operations is compiled and kept by itself)
+    want = jax.jit(lambda t: model_lib.forward(cfg, params, t))(toks)
     k, v = model_lib.init_kv_cache(cfg, 2, 32)
-    logits, k, v, rec = model_lib.forward_cached_hybrid(
-        cfg, params, toks[:, :19], k, v, jnp.int32(0), rec, empty_cache=True)
+    logits, k, v, rec = jax.jit(
+        lambda t, k, v, rec: model_lib.forward_cached_hybrid(
+            cfg, params, t, k, v, jnp.int32(0), rec, empty_cache=True))(
+                toks[:, :19], k, v, rec)
     np.testing.assert_allclose(logits, want[:, :19], atol=2e-5)
-    last, *_ = model_lib.forward_cached_hybrid(
-        cfg, params, toks[:, 19:], k, v, jnp.full((2,), 19, jnp.int32), rec)
+    last, *_ = jax.jit(
+        lambda t, k, v, n, rec: model_lib.forward_cached_hybrid(
+            cfg, params, t, k, v, n, rec))(
+                toks[:, 19:], k, v, jnp.full((2,), 19, jnp.int32), rec)
     np.testing.assert_allclose(last[:, 0], want[:, 19], atol=2e-5)
 
 
@@ -209,11 +216,15 @@ def test_the_bias_a_seed_brings_levels_the_stacks_own_load():
         jax.random.key(0))
     toks = jax.random.randint(jax.random.key(99), (1, 512), 1, 500)
 
-    def busiest_over_mean(p):
+    @jax.jit
+    def load_of(p):
         k, v = model_lib.init_kv_cache(cfg, 1, 512)
-        load = np.asarray(model_lib.forward_cached_hybrid(
+        return model_lib.forward_cached_hybrid(
             cfg, p, toks, k, v, jnp.int32(0),
-            model_lib.init_rec_state(cfg, 1), empty_cache=True)[3]["load"])
+            model_lib.init_rec_state(cfg, 1), empty_cache=True)[3]["load"]
+
+    def busiest_over_mean(p):
+        load = np.asarray(load_of(p))
         return (load[[1, 3]].max(axis=1) / load[[1, 3]].mean(axis=1)).max()
 
     drawn = [dict(layer) for layer in params["layers"]]

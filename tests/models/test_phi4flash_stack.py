@@ -6,6 +6,7 @@ cut to one row and 40 decode steps through the cache, the rings and the
 states; and each omission the reference must catch."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -47,6 +48,7 @@ def model():
     params = jax.tree_util.tree_map_with_path(_shake, params)
     tokens = jax.random.randint(jax.random.key(1), (1, PROMPT + STEPS + 1),
                                 1, 500)
+    served_programs(cfg)        # before any test's body, so any patch
     return cfg, params, tokens
 
 
@@ -84,9 +86,11 @@ def program_logits(cfg, params, tokens):
             lambda p, t: model_lib.forward(cfg, p, t))(params, tokens)[0])
 
 
-def served_logits(cfg, params, tokens, steps=STEPS):
-    """A bucket-padded prefill cut to its last row, then ``steps`` decode
-    steps on the dense view of the gather route, every step's logits."""
+def _programs(cfg):
+    """The jitted prefill and decode step ``served_logits`` runs, traced
+    where they are first called: an omission patched in is served through
+    a pair of its own (``programs=_programs``), the faithful model
+    through ``served_programs``."""
     @jax.jit
     def prefill(params, padded, k, v, rec):
         valid = jnp.arange(BUCKET)[None, :] < PROMPT
@@ -99,6 +103,13 @@ def served_logits(cfg, params, tokens, steps=STEPS):
         return model_lib.forward_cached_hybrid(
             cfg, params, token, k, v, t, rec, valid=jnp.ones((1, 1), bool))
 
+    return prefill, step
+
+
+def served_logits(cfg, params, tokens, steps=STEPS, programs=None):
+    """A bucket-padded prefill cut to its last row, then ``steps`` decode
+    steps on the dense view of the gather route, every step's logits."""
+    prefill, step = (programs or served_programs)(cfg)
     with jax.default_matmul_precision("highest"):
         k, v = model_lib.init_kv_cache(cfg, 1, 128)
         rec = model_lib.init_rec_state(cfg, 1)
@@ -111,6 +122,24 @@ def served_logits(cfg, params, tokens, steps=STEPS):
                                      jnp.array([t]), rec)
             out.append(np.asarray(logits[0, 0]))
     return np.stack(out), rec
+
+
+@functools.lru_cache(maxsize=None)
+def served_programs(cfg):
+    """One pair a configuration a process: every test that serves the
+    faithful program (here and in test_window_kind.py) runs the same two
+    executables.  Both are run once here, on zeros, before the pair is
+    handed out, and the module's fixture builds its configuration's pair
+    before any test's body runs: a test that patches the model and forgets
+    ``programs=_programs`` reads the faithful pair and fails, and cannot
+    leave a pair traced under its patch to the tests behind it."""
+    pair = _programs(cfg)
+    params = jax.tree.map(
+        lambda a: jnp.zeros(a.shape, a.dtype),
+        jax.eval_shape(lambda: model_lib.init_params(jax.random.key(0), cfg)))
+    served_logits(cfg, params, jnp.zeros((1, PROMPT + 1), jnp.int32),
+                  steps=1, programs=lambda _cfg: pair)
+    return pair
 
 
 def test_the_preset_is_the_published_model():
@@ -190,6 +219,8 @@ def test_the_timed_prefill_leaves_what_the_every_row_pass_leaves(model):
         padded = jnp.zeros((1, BUCKET), jnp.int32).at[:, :PROMPT].set(
             tokens[:, :PROMPT])
         valid = jnp.arange(BUCKET)[None, :] < PROMPT
+        # (op by op on both sides: the leaves are compared bit for bit,
+        # which two programs compiled whole do not promise)
         cut = model_lib.forward_cached_hybrid(
             cfg, params, padded, k, v, jnp.int32(0), rec, valid=valid,
             empty_cache=True, logit_rows=jnp.array([PROMPT - 1]))
@@ -203,9 +234,10 @@ def test_the_timed_prefill_leaves_what_the_every_row_pass_leaves(model):
         np.testing.assert_array_equal(a, b)
     # the padded tail advanced nothing: the states are those of the
     # prompt alone
-    alone = model_lib.forward_cached_hybrid(
-        cfg, params, tokens[:, :PROMPT], k, v, jnp.int32(0), rec,
-        empty_cache=True, logit_rows=jnp.array([PROMPT - 1]))
+    alone = jax.jit(lambda prompt: model_lib.forward_cached_hybrid(
+        cfg, params, prompt, k, v, jnp.int32(0), rec,
+        empty_cache=True, logit_rows=jnp.array([PROMPT - 1])))(
+            tokens[:, :PROMPT])
     for name in ("ssm1", "ssm1_conv"):
         np.testing.assert_allclose(cut[3][name], alone[3][name], atol=1e-6)
 
@@ -278,7 +310,7 @@ def test_each_omission_fails_the_comparison(model, monkeypatch, omission,
     else:
         omission(monkeypatch)
     if served:
-        got, _ = served_logits(cfg, params, tokens)
+        got, _ = served_logits(cfg, params, tokens, programs=_programs)
         want = want[PROMPT - 1:PROMPT + STEPS]
     else:
         got = program_logits(cfg, params, tokens)
@@ -332,10 +364,7 @@ def test_the_paged_step_writes_the_pool_once_and_the_rings_in_place(
         rec0 = model_lib.init_rec_state(cfg, 1)
         padded = jnp.zeros((1, BUCKET), jnp.int32).at[:, :PROMPT].set(
             tokens[:, :PROMPT])
-        valid = jnp.arange(BUCKET)[None, :] < PROMPT
-        _l, k, v, rec0 = model_lib.forward_cached_hybrid(
-            cfg, params, padded, k, v, jnp.int32(0), rec0, valid=valid,
-            empty_cache=True, logit_rows=jnp.array([PROMPT - 1]))
+        _l, k, v, rec0 = served_programs(cfg)[0](params, padded, k, v, rec0)
         bk, T = 16, 8
         tables = jnp.arange(1, T + 1, dtype=jnp.int32)[None]
         k_pool, v_pool = model_lib.init_kv_pool(cfg, T + 1, bk)
@@ -350,11 +379,15 @@ def test_the_paged_step_writes_the_pool_once_and_the_rings_in_place(
         walk = fd.flash_decode_paged
         monkeypatch.setattr(fd, "flash_decode_paged", lambda *a, **kw: (
             calls.append(kw["new_rows"][1].shape), walk(*a, **kw))[1])
+        # one executable for the three steps, traced under the patches
+        step = jax.jit(lambda token, k_pool, v_pool, t, rec:
+                       model_lib.forward_paged_hybrid(
+                           cfg, params, token, k_pool, v_pool, tables, t, rec,
+                           jnp.ones((1,), bool)))
         out = []
         for t in range(PROMPT, PROMPT + 3):
-            logits, k_pool, v_pool, rec0 = model_lib.forward_paged_hybrid(
-                cfg, params, tokens[:, t:t + 1], k_pool, v_pool, tables,
-                jnp.array([t]), rec0, jnp.ones((1,), bool))
+            logits, k_pool, v_pool, rec0 = step(
+                tokens[:, t:t + 1], k_pool, v_pool, jnp.array([t]), rec0)
             out.append(np.asarray(logits[0, 0]))
     np.testing.assert_allclose(np.stack(out), got_dense[1:], atol=TOL)
     # one walk by the full layer and one by each of the two cross layers,

@@ -30,6 +30,7 @@ def stack(request):
     params = jax.tree_util.tree_map_with_path(mod._shake, params)
     tokens = jax.random.randint(
         jax.random.key(1), (1, mod.PROMPT + mod.STEPS + 1), 1, 500)
+    mod.served_programs(cfg)    # before any test's body, so any patch
     return mod, cfg, params, tokens
 
 
@@ -83,8 +84,8 @@ def test_with_the_kernels_on_the_steps_are_the_plain_steps(stack):
 def test_the_paged_step_writes_the_rings_in_place(stack, monkeypatch):
     """Three decode steps on the paged route (the walk interpreted): the
     full layers' rows go to the pool, the rings take a row each at
-    ``position % window`` by ONE ``ring_append_rows`` a step; logits and
-    rings are the dense route's."""
+    ``position % window`` by ONE ``ring_append_rows`` in the step's
+    program; logits and rings are the dense route's."""
     from megatron_llm_tpu.ops import attention as attn_ops
 
     mod, cfg, params, tokens = stack
@@ -99,10 +100,8 @@ def test_the_paged_step_writes_the_rings_in_place(stack, monkeypatch):
         k, v = model_lib.init_kv_cache(cfg, 1, 128)
         rec0 = model_lib.init_rec_state(cfg, 1)
         padded = jnp.zeros((1, B), jnp.int32).at[:, :P].set(tokens[:, :P])
-        valid = jnp.arange(B)[None, :] < P
-        _l, k, v, rec0 = model_lib.forward_cached_hybrid(
-            cfg, params, padded, k, v, jnp.int32(0), rec0, valid=valid,
-            empty_cache=True, logit_rows=jnp.array([P - 1]))
+        # (a prefill of its own, traced under the patch above)
+        _l, k, v, rec0 = mod._programs(cfg)[0](params, padded, k, v, rec0)
         assert not writes                 # a prompt installs, it appends not
         bk, T = 16, 8
         tables = jnp.arange(1, T + 1, dtype=jnp.int32)[None]
@@ -113,14 +112,18 @@ def test_the_paged_step_writes_the_rings_in_place(stack, monkeypatch):
         monkeypatch.setattr(
             attn_ops, "paged_decode_kernel_eligible",
             lambda s, d, block, platform: s == 1)
+        # one executable for the three steps
+        step = jax.jit(lambda token, k_pool, v_pool, t, rec:
+                       model_lib.forward_paged_hybrid(
+                           cfg, params, token, k_pool, v_pool, tables, t, rec,
+                           jnp.ones((1,), bool)))
         out = []
         for t in range(P, P + 3):
-            logits, k_pool, v_pool, rec0 = model_lib.forward_paged_hybrid(
-                cfg, params, tokens[:, t:t + 1], k_pool, v_pool, tables,
-                jnp.array([t]), rec0, jnp.ones((1,), bool))
+            logits, k_pool, v_pool, rec0 = step(
+                tokens[:, t:t + 1], k_pool, v_pool, jnp.array([t]), rec0)
             out.append(np.asarray(logits[0, 0]))
     np.testing.assert_allclose(np.stack(out), got_dense[1:], atol=TOL)
-    assert len(writes) == 3
+    assert len(writes) == 1               # in the one trace of the step
     assert k_pool.shape[0] == v_pool.shape[0] == cfg.kv_layers
     for name in transformer.RING_NAMES:
         np.testing.assert_allclose(rec0[name], rec[name], atol=1e-5)

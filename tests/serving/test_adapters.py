@@ -255,10 +255,24 @@ def test_no_recompiles_as_adapters_rotate(tiny):
                         prefix_cache_blocks=0)
     engine = ServingEngine(cfg, params, ecfg, adapters=reg).start()
     try:
-        # warmup: prefill + decode + install, with and without adapter
-        engine.submit(PROMPT, 4, use_eos_stop=False,
-                      adapter_id="t0").result(600)
-        engine.submit(PROMPT, 4, use_eos_stop=False).result(600)
+        # warmup: prefill + decode + install, with and without adapter;
+        # the second joins while the first decodes, as the rotation's
+        # requests join each other (the pipelined step's merge of a
+        # newcomer is an executable too: one after the other, this test
+        # passed only behind a test that had compiled it).  Submitted from
+        # the first's second token (its first decode step's), on the
+        # scheduler's own thread: whatever the machine's load, it is
+        # admitted with a step of the first in flight
+        seen, second = [], []
+
+        def join(tok):
+            seen.append(tok)
+            if len(seen) == 2:
+                second.append(engine.submit(PROMPT, 4, use_eos_stop=False))
+
+        engine.submit(PROMPT, 8, use_eos_stop=False, adapter_id="t0",
+                      on_token=join).result(600)
+        second[0].result(600)
         with no_recompiles():
             handles = [
                 engine.submit(PROMPT, 6, use_eos_stop=False,

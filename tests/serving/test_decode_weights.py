@@ -12,6 +12,7 @@ into transposes no weight.
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -121,6 +122,15 @@ _STACKS = {
 }
 
 
+@functools.lru_cache(maxsize=None)
+def _built(stack):
+    """A stack's configuration, parameters and late slots, its tokens and
+    ``model.forward``'s logits of them: once for both routes."""
+    cfg, params, late = _STACKS[stack]()
+    tokens = jax.random.randint(jax.random.key(6), (3, 5), 1, cfg.vocab_size)
+    return cfg, params, late, tokens, model_lib.forward(cfg, params, tokens)
+
+
 @pytest.mark.parametrize("route", ["gather", "paged"])
 @pytest.mark.parametrize("stack", list(_STACKS))
 def test_decode_step_logits_are_forwards(monkeypatch, stack, route):
@@ -130,13 +140,11 @@ def test_decode_step_logits_are_forwards(monkeypatch, stack, route):
     at every position: over the same quantised parameters under each
     precision policy, and within the int8 pool's rounding of them where
     the pool is quantised."""
-    cfg, params, late = _STACKS[stack]()
+    cfg, params, late, tokens, want = _built(stack)
     if route == "paged":
         monkeypatch.setattr(attn_ops, "_backend", lambda: "tpu")
     pool = model_lib.init_kv_pool(cfg, 2, BLOCK)[0]
     assert model_lib.paged_decode_eligible(cfg, pool) == (route == "paged")
-    tokens = jax.random.randint(jax.random.key(6), (3, 5), 1, cfg.vocab_size)
-    want = model_lib.forward(cfg, params, tokens)
     got = _decode(cfg, params, tokens, late)
     assert got.dtype == want.dtype == jnp.float32
     tol = _POOL_TOL[cfg.kv_cache_quant]

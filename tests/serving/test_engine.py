@@ -17,9 +17,10 @@ import numpy as np
 import pytest
 
 from megatron_llm_tpu.config import tiny_config
-from megatron_llm_tpu.generation import generate_tokens, score_tokens
+from megatron_llm_tpu.generation import score_tokens
 from megatron_llm_tpu.models import model as model_lib
 from megatron_llm_tpu.serving import EngineConfig, QueueFull, ServingEngine
+from tests.serving.one_shot import reference as _reference
 
 
 @pytest.fixture(scope="module")
@@ -34,18 +35,6 @@ def _engine(cfg, params, **overrides):
     kw = dict(max_batch_size=4, max_seq_len=64, max_queue_size=16)
     kw.update(overrides)
     return ServingEngine(cfg, params, EngineConfig(**kw))
-
-
-def _reference(cfg, params, prompt, max_new):
-    """One-shot greedy rollout for a single prompt — the trajectory the
-    server produced before the engine existed."""
-    total = len(prompt) + max_new
-    toks = np.zeros((1, total), np.int32)
-    toks[0, :len(prompt)] = prompt
-    out = generate_tokens(cfg, params, jnp.asarray(toks),
-                          jnp.asarray([len(prompt)], jnp.int32),
-                          eos_id=-1, use_eos_stop=False)
-    return np.asarray(out.tokens)[0].tolist()
 
 
 def test_continuous_batching_matches_one_shot(tiny):
@@ -630,7 +619,9 @@ class TestSpeculative:
                                   length - 6).tolist()
             for c in range(2, cfg.vocab_size - 2):
                 prompt = [a, b, c, x] + filler + [a, b]
-                first, second = _reference(cfg, params, prompt, 2)[length:]
+                # (hundreds of two-token rollouts: as short as they are)
+                first, second = _reference(cfg, params, prompt, 2,
+                                           window=length + 2)[length:]
                 if first == c and second != x:
                     return prompt
         pytest.fail(f"no rejecting prompt of length {length} found")

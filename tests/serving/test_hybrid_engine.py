@@ -53,7 +53,7 @@ def test_a_reused_slot_serves_as_a_fresh_engine_does(model):
     whole at admission, so its last tenant (and the speculative step that
     advanced it after it retired) leaves nothing behind."""
     cfg, params = model
-    prompts = prompts_of([40, 75, 33, 64, 21])
+    prompts = prompts_of([40, 57, 33, 64, 21])
     shared, eng = serve(cfg, params, prompts)
     for p, got in zip(prompts, shared):
         (alone,), _ = serve(cfg, params, [p])

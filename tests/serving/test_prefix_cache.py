@@ -23,7 +23,6 @@ import numpy as np
 import pytest
 
 from megatron_llm_tpu.config import tiny_config
-from megatron_llm_tpu.generation import generate_tokens
 from megatron_llm_tpu.models import model as model_lib
 from megatron_llm_tpu.serving import (
     EngineConfig,
@@ -31,6 +30,7 @@ from megatron_llm_tpu.serving import (
     ServingEngine,
     ServingMetrics,
 )
+from tests.serving.one_shot import reference as _reference
 
 
 @pytest.fixture(scope="module")
@@ -295,16 +295,6 @@ def _engine(cfg, params, **overrides):
               prefill_bucket=4, prefix_cache_blocks=32)
     kw.update(overrides)
     return ServingEngine(cfg, params, EngineConfig(**kw))
-
-
-def _reference(cfg, params, prompt, max_new):
-    total = len(prompt) + max_new
-    toks = np.zeros((1, total), np.int32)
-    toks[0, :len(prompt)] = prompt
-    out = generate_tokens(cfg, params, jnp.asarray(toks),
-                          jnp.asarray([len(prompt)], jnp.int32),
-                          eos_id=-1, use_eos_stop=False)
-    return np.asarray(out.tokens)[0].tolist()
 
 
 def _run_seq(engine, specs):

@@ -28,13 +28,13 @@ import pytest
 
 from megatron_llm_tpu.analysis.sanitizers import no_recompiles
 from megatron_llm_tpu.config import tiny_config
-from megatron_llm_tpu.generation import generate_tokens
 from megatron_llm_tpu.models import model as model_lib
 from megatron_llm_tpu.obs import compile as obs_compile
 from megatron_llm_tpu.resilience.chaos import chaos
 from megatron_llm_tpu.serving import EngineConfig, ServingEngine
 from megatron_llm_tpu.serving.block_pool import BlockPool, HostKVTier
 from megatron_llm_tpu.serving.queue import RequestQueue
+from tests.serving.one_shot import reference as _reference
 
 
 @pytest.fixture(scope="module")
@@ -50,16 +50,6 @@ def _engine(cfg, params, **overrides):
               idle_wait_s=0.005, kv_block_size=8)
     kw.update(overrides)
     return ServingEngine(cfg, params, EngineConfig(**kw))
-
-
-def _reference(cfg, params, prompt, max_new):
-    total = len(prompt) + max_new
-    toks = np.zeros((1, total), np.int32)
-    toks[0, :len(prompt)] = prompt
-    out = generate_tokens(cfg, params, jnp.asarray(toks),
-                          jnp.asarray([len(prompt)], jnp.int32),
-                          eos_id=-1, use_eos_stop=False)
-    return np.asarray(out.tokens)[0].tolist()
 
 
 def _prompt(cfg, n, seed):
