@@ -15,7 +15,7 @@ from __future__ import annotations
 import dataclasses
 import json
 from dataclasses import dataclass, field
-from typing import Any, Optional, Sequence
+from typing import Any, NamedTuple, Optional, Sequence
 
 import jax.numpy as jnp
 
@@ -30,27 +30,42 @@ class PositionEmbeddingType:
     NONE = "none"
 
 
-# the block kinds of ModelConfig.layer_pattern, and of them those that keep
-# keys and values, those that keep a state-space state, and those with a
-# feed-forward part
-BLOCK_KINDS = ("full", "linear", "ssm", "attention", "mamba", "mlp",
-               "window")
-KV_KINDS = ("full", "attention")
-MAMBA_KINDS = ("ssm", "mamba")
-FFN_KINDS = ("full", "linear", "ssm", "mlp", "window")
-# the further block kinds of a stack of runs (ModelConfig.layer_runs),
-# beside "full", each a mixer and the MLP: a Mamba-1 mixer, attention over
-# a window kept as a ring a slot ("window": the period scan's kind too,
-# one meaning for both: ``sliding_window`` keys a query, a ring a slot in
-# ``init_rec_state``'s ``win_k`` / ``win_v``, no pool layer), a gated
-# memory unit, attention with another layer's keys and values
-RUN_KINDS = ("ssm1", "window", "gmu", "cross")
+class BlockKind(NamedTuple):
+    """The static half of what a block kind is (models/transformer.py
+    binds each kind's initialiser and mixer to it, once): what a layer of
+    the kind keeps between positions, and whether it has a feed-forward
+    part behind its mixer, under a norm of its own.  ``keeps``: "kv" a
+    layer of the KV pool; "window" a ring of ``sliding_window`` keys and
+    values a slot and no pool layer; "linear", "mamba", "ssm1" recurrent
+    states under the names models/model.py:REC_STATE_KINDS gives them;
+    "" nothing.  ``reads``: what an earlier layer hands it at the same
+    position: "memory" the last "ssm1" layer's, "kv" the one "full"
+    layer's keys and values.  ``of_runs``: the kind stands in a stack
+    written as runs (``ModelConfig.layer_runs``) alone."""
+
+    keeps: str = ""
+    ffn: bool = True
+    reads: str = ""
+    of_runs: bool = False
 
 
-class AttnMaskType:
-    CAUSAL = "causal"
-    PADDING = "padding"
-    PREFIX = "prefix"
+# What every count of layers, ``init_rec_state`` and the layer scan read.
+# A block of two parts is a mixer and then the feed-forward part (the
+# experts where num_experts > 0), each under a norm of its own; a block of
+# one part (``ffn`` False, or "mlp": no mixer) is ``h + f(norm(h))``.
+KINDS = {
+    "full": BlockKind("kv"),                # softmax attention: latent
+    #   (kv_lora_rank) or differential (diff_attention) where the model's is
+    "linear": BlockKind("linear"),          # Gated DeltaNet
+    "ssm": BlockKind("mamba"),              # a Mamba-2 mixer
+    "window": BlockKind("window"),          # attention over a window
+    "attention": BlockKind("kv", ffn=False),
+    "mamba": BlockKind("mamba", ffn=False),
+    "mlp": BlockKind(),
+    "ssm1": BlockKind("ssm1", of_runs=True),    # a Mamba-1 mixer
+    "gmu": BlockKind(reads="memory", of_runs=True),   # a gated memory unit
+    "cross": BlockKind(reads="kv", of_runs=True),     # a query's attention
+}
 
 
 _DTYPES = {
@@ -165,14 +180,11 @@ class ModelConfig:
     # embeddings, lm_head, norms and the attention einsum stay bf16/fp32.
     # The TPU analogue of the reference's optional TransformerEngine FP8
     # (megatron/model/transformer.py:932-951, off by default there too).
-    # Measured on v5e (2026-07-31): ~parity with bf16 at 7B-width
-    # (23.9k vs 23.6k tok/s) and a net loss at 374M (0.477 vs 0.53 MFU).
-    # Round-5 decomposition (docs/perf_notes.md §2) shows parity is a
-    # measured CEILING of this design, not tuning debt: XLA's int8 MXU
-    # dot reaches 1.35x bf16 (not the 2x nameplate), dynamic
-    # quantization costs ~85% of a dot standalone, and the TE-style
-    # unquantized backward (2/3 of FLOPs) caps the step at <=1.13x.
-    # Prefer the flag only under activation-memory pressure.  Note the
+    # No speed is claimed for it: no cell of the benchmark runs it (not
+    # measured: PERF.md section 7), and by design the backward, two
+    # thirds of a step's FLOPs, is unquantized and the operands are
+    # quantized anew a call.  Prefer the flag only under
+    # activation-memory pressure.  Note the
     # int8 dots escape the "selective" remat policy as int32 saveables —
     # pair with recompute="full" at memory-tight shapes.
     # ops/quant.py:int8_training_matmul.
@@ -222,16 +234,10 @@ class ModelConfig:
     fused_lm_head: bool = False
     # Width of an attention head where it is not hidden / heads.
     kv_channels: Optional[int] = None
-    # Hybrid stacks: the block kinds of one period of layers.  A block of
-    # two parts, a mixer and then a feed-forward part, each under a norm
-    # of its own: "full" (softmax attention), "linear" (Gated DeltaNet,
-    # models/gated_deltanet.py) or "ssm" (a Mamba-2 mixer,
-    # models/mamba2.py).  A block of one part under one norm,
-    # ``h + f(norm(h))``: "attention" (softmax attention alone), "mamba"
-    # (a Mamba-2 mixer alone, models/mamba2.py) or "mlp" (the feed-forward
-    # part alone: the experts where num_experts > 0).  The stack is
-    # scanned by period (models/transformer.py) and num_layers is a
-    # multiple of it.  () = every layer "full": the one-kind stack.
+    # Hybrid stacks: the block kinds (``KINDS``) of one period of layers.
+    # The stack is that period scanned num_layers / len(period) times
+    # (models/transformer.py:scan_stack): ONE run of ``stack_runs``.
+    # () = every layer "full": the one-kind stack.
     layer_pattern: tuple = ()
     # Gated DeltaNet geometry: key heads x key width, value heads x value
     # width (value heads a multiple of key heads), causal depthwise
@@ -326,15 +332,15 @@ class ModelConfig:
     moe_first_dense_layers: int = 0
     moe_dense_ffn_size: int = 0
     moe_n_group: int = 1
-    # A stack of runs: ``((period, times), ...)``, each run a period of
-    # block kinds scanned ``times`` times, one run after the other
-    # (models/transformer.py:scan_runs_cached).  ``layer_pattern`` is then
-    # the whole stack written out, one period of num_layers kinds, which
-    # is what every count of layers reads.  A run hands two things to the
-    # runs behind it: the last "ssm1" layer's memory (its scan's output
-    # before the gate, read by every "gmu" layer at the same position) and
-    # the one "full" layer's keys and values (read by every "cross"
-    # layer, which projects a query alone).
+    # A stack of several runs: ``((period, times), ...)``, each run a
+    # period of block kinds scanned ``times`` times, one run after the
+    # other (models/transformer.py:scan_stack).  ``layer_pattern`` is then
+    # the scanned layers written out, one period, which is what every
+    # count of layers reads.  A run hands two things to the runs behind
+    # it: the last "ssm1" layer's memory (its scan's output before the
+    # gate, read by every "gmu" layer at the same position) and the one
+    # "full" layer's keys and values (read by every "cross" layer, which
+    # projects a query alone).
     layer_runs: tuple = ()
     # Differential attention (arXiv 2410.05258) as the attention part of
     # the "full", "window" and "cross" kinds: adjacent query heads pair,
@@ -346,7 +352,7 @@ class ModelConfig:
     diff_attention: bool = False
     # keys a query of a "window" layer sees, its own among them
     sliding_window: int = 0
-    # What a "window" layer of the period scan has of its own (None: the
+    # What a "window" layer of plain attention has of its own (None: the
     # model's): its query heads (the KV heads are the model's, so the
     # query-to-KV-head ratio differs by kind), and its rotation ``(theta,
     # share of the head rotated, scaling factor)`` in place of rope_theta,
@@ -436,35 +442,31 @@ class ModelConfig:
                 + period * (self.scanned_layers // len(period)))
 
     @property
-    def kv_layers(self) -> int:
-        """Layers that keep keys and values: the KV pool's layer axis."""
-        return sum(kind in KV_KINDS for kind in self.layer_kinds)
+    def stack_runs(self) -> tuple:
+        """The scanned layers as runs ``((period, times), ...)``: a
+        ``layer_pattern`` alone is one run of its periods."""
+        period = self.layer_pattern or ("full",)
+        return self.layer_runs or (
+            (period, self.scanned_layers // len(period)),)
 
-    @property
-    def linear_layers(self) -> int:
-        """Layers that keep a delta-rule state (serving/slots.py)."""
-        return self.layer_kinds.count("linear")
+    def layers_keeping(self, what: str) -> int:
+        """Layers of a kind that keeps ``what`` (``BlockKind.keeps``)."""
+        return sum(KINDS[kind].keeps == what for kind in self.layer_kinds)
 
-    @property
-    def mamba_layers(self) -> int:
-        """Layers that keep a state-space state (serving/slots.py)."""
-        return sum(kind in MAMBA_KINDS for kind in self.layer_kinds)
-
-    @property
-    def mamba1_layers(self) -> int:
-        """Layers that keep a Mamba-1 state (serving/slots.py)."""
-        return self.layer_kinds.count("ssm1")
-
-    @property
-    def window_layers(self) -> int:
-        """Layers that keep a ring of keys and values a slot."""
-        return self.layer_kinds.count("window")
+    # the layers that keep keys and values (the KV pool's layer axis), a
+    # delta-rule state, a state-space state, a Mamba-1 state
+    # (serving/slots.py) and a ring of keys and values a slot
+    kv_layers = property(lambda self: self.layers_keeping("kv"))
+    linear_layers = property(lambda self: self.layers_keeping("linear"))
+    mamba_layers = property(lambda self: self.layers_keeping("mamba"))
+    mamba1_layers = property(lambda self: self.layers_keeping("ssm1"))
+    window_layers = property(lambda self: self.layers_keeping("window"))
 
     @property
     def cross_layers(self) -> int:
         """Layers that attend with the one "full" layer's keys and
         values: readers of the pool that write nothing to it."""
-        return self.layer_kinds.count("cross")
+        return sum(KINDS[kind].reads == "kv" for kind in self.layer_kinds)
 
     @property
     def v_heads(self) -> int:
@@ -483,7 +485,7 @@ class ModelConfig:
         if self.num_experts == 0:
             return ()
         return tuple(i for i, kind in enumerate(self.layer_kinds)
-                     if kind in FFN_KINDS
+                     if KINDS[kind].ffn
                      and i >= self.moe_first_dense_layers)
 
     @property
@@ -525,9 +527,9 @@ class ModelConfig:
             self.hidden_size % self.num_attention_heads == 0)
         assert self.num_attention_heads % self.kv_heads == 0
         if self.layer_pattern:
-            assert set(self.layer_pattern) <= set(
-                BLOCK_KINDS + RUN_KINDS if self.layer_runs
-                else BLOCK_KINDS), (
+            assert all(kind in KINDS and (self.layer_runs
+                                          or not KINDS[kind].of_runs)
+                       for kind in self.layer_pattern), (
                 f"unknown block kind in {self.layer_pattern!r}")
             assert self.scanned_layers % len(self.layer_pattern) == 0, (
                 f"num_layers {self.num_layers} is not whole periods of "
@@ -537,13 +539,17 @@ class ModelConfig:
             assert self.mamba_num_heads % self.mamba_n_groups == 0
         if self.layer_runs:
             self._validate_runs()
-        elif "window" in self.layer_pattern:
-            self._validate_window()
-        else:
+        # (the differential family's "window" layers are _validate_runs')
+        differential = bool(self.layer_runs) and self.diff_attention
+        if "window" in self.layer_pattern:
+            assert self.sliding_window > 0, "\"window\" needs sliding_window"
+            if not differential:
+                self._validate_window()
+        elif not differential:
             assert (self.window_attention_heads is None
                     and self.window_rope is None), (
                 "window_attention_heads and window_rope are a \"window\" "
-                "layer's of the period scan")
+                "layer's of plain attention")
         if self.kv_lora_rank:
             self._validate_latent_attention()
         else:
@@ -600,14 +606,23 @@ class ModelConfig:
         return self
 
     def _validate_runs(self) -> None:
-        """A stack of runs as models/transformer.py carries it."""
+        """A stack of runs as models/transformer.py carries it: the runs
+        written out are the scanned layers; the kinds of runs alone and
+        differential attention come together, with what that family
+        (``phi4flash_config``) does not have refused."""
         flat = tuple(kind for period, times in self.layer_runs
                      for kind in period * times)
-        assert flat == self.layer_pattern and len(flat) == self.num_layers, (
-            "layer_runs written out is layer_pattern, num_layers kinds")
-        kinds = set(flat)
-        assert kinds <= set(RUN_KINDS) | {"full"}, (
-            f"a stack of runs holds {RUN_KINDS} and \"full\": {kinds}")
+        assert (flat == self.layer_pattern
+                and len(flat) == self.scanned_layers), (
+            "layer_runs written out is layer_pattern, the scanned layers")
+        kinds = {kind: KINDS[kind] for kind in flat}
+        if not (self.diff_attention
+                or any(k.of_runs for k in kinds.values())):
+            return
+        assert all(k.of_runs or kind in ("window", "full")
+                   for kind, k in kinds.items()), (
+            "a stack of runs that attends differentially holds \"full\", "
+            f"\"window\" and the kinds of runs alone: {set(kinds)}")
         assert self.diff_attention and self.kv_heads % 2 == 0 and (
             self.num_attention_heads == 2 * self.kv_heads), (
             "a stack of runs attends differentially: query heads pair, "
@@ -619,30 +634,30 @@ class ModelConfig:
                 and not self.qk_norm and not self.attn_output_gate), (
             "a stack of runs: no rotation, experts, parallel block, 8-bit "
             "K/V, context parallelism, q/k norm or output gate")
-        if "window" in kinds:
-            assert self.sliding_window > 0, "\"window\" needs sliding_window"
-        if "ssm1" in kinds:
+        keeps = [k.keeps for k in kinds.values()]
+        reads = [KINDS[kind].reads for kind in flat]
+        if "ssm1" in keeps:
             assert self.mamba1_inner > 0 and self.mamba1_dt_rank > 0
-        if "gmu" in kinds:
-            assert "ssm1" in flat[:flat.index("gmu")], (
+        if "memory" in reads:
+            assert "ssm1" in [KINDS[kind].keeps
+                              for kind in flat[:reads.index("memory")]], (
                 "a gated memory unit reads an earlier \"ssm1\" layer's "
                 "memory")
-        if "cross" in kinds:
+        if "kv" in reads:
             assert flat.count("full") == 1 and (
-                flat.index("full") < flat.index("cross")), (
+                flat.index("full") < reads.index("kv")), (
                 "\"cross\" layers read the keys and values of the one "
                 "\"full\" layer before them")
 
     def _validate_window(self) -> None:
-        """A "window" layer of the period scan as models/transformer.py
+        """A "window" layer of plain attention as models/transformer.py
         carries it: ordinary attention (grouped heads, a rotation, a gate
         a head) on a ring of ``sliding_window`` rows a slot."""
-        assert self.sliding_window > 0, "\"window\" needs sliding_window"
         assert not (self.diff_attention or self.kv_lora_rank
                     or self.parallel_attn or self.attn_output_gate
                     or self.kv_cache_quant != "none"
                     or self.context_parallel_axis is not None), (
-            "a \"window\" layer of the period scan: no differential or "
+            "a \"window\" layer of plain attention: no differential or "
             "latent attention, parallel block, element-wise output gate, "
             "8-bit K/V or context parallelism")
         if self.position_embedding_type == PositionEmbeddingType.ROTARY:
@@ -663,13 +678,15 @@ class ModelConfig:
         """The layer at which a prefill may cut the rows it carries on to
         the one whose logits are asked for: the "full" layer whose keys
         and values every later layer reads, where no later layer keeps
-        anything of the positions in between ("gmu", "cross" alone).
+        anything of the positions in between (each reads what an earlier
+        layer hands it, alone).
         None: no such layer."""
         kinds = self.layer_kinds
-        if "cross" not in kinds:
+        if not self.cross_layers:
             return None
         at = kinds.index("full")
-        return at if set(kinds[at + 1:]) <= {"gmu", "cross"} else None
+        return at if all(KINDS[kind].reads
+                         for kind in kinds[at + 1:]) else None
 
     def _validate_latent_attention(self) -> None:
         """Latent attention as models/mla.py carries it; what it does
@@ -699,7 +716,7 @@ class ModelConfig:
         assert self.qk_rope_head_dim % 2 == 0
         assert self.layer_pattern == ("full",), (
             "latent attention is the attention part of a period of one "
-            "\"full\" block (the period scan carries the float32 "
+            "\"full\" block (the layer scan carries the float32 "
             "stream and the expert counters)")
         assert (self.position_embedding_type == PositionEmbeddingType.ROTARY
                 and not self.qk_norm and not self.attn_output_gate
@@ -1484,11 +1501,12 @@ def laguna_config(size: str = "xs.2-pp8-stage0", **overrides) -> ModelConfig:
     whole vocabulary.  ``xs.2`` names the published 40 layers, which are
     the leading layer, NINE periods and a last run of three window
     layers: 39 scanned layers are not whole periods, so it is refused
-    until the period scan takes a last partial period (a stack of runs,
-    ``layer_runs`` ``(((w, w, w, f), 9), ((w, w, w), 1))``, with experts
-    and a leading layer: ROADMAP R3 a0).  ``layer_pattern`` as an
-    override (a test's or a rehearsal's small stack) with a depth that is
-    not whole periods gives the leading layer and one period."""
+    here: the layer scan carries them as a stack of runs (``layer_runs``
+    ``(((w, w, w, f), 9), ((w, w, w), 1))`` behind the leading layer),
+    serving them is a preset's and a cell's change (ROADMAP R3 a0).
+    ``layer_pattern`` as an override (a test's or a rehearsal's small
+    stack) with a depth that is not whole periods gives the leading layer
+    and one period."""
     pattern = ("window", "window", "window", "full")
     base = dict(
         norm_type="rmsnorm",
@@ -1546,10 +1564,10 @@ def laguna_config(size: str = "xs.2-pp8-stage0", **overrides) -> ModelConfig:
     if (base["num_layers"] - lead) % period:
         if "layer_pattern" not in overrides:
             raise ValueError(
-                f"num_layers {base['num_layers']}: the period scan takes "
+                f"num_layers {base['num_layers']}: this preset takes "
                 f"the leading layer and whole periods of {period}; the "
                 "published 40 layers end in a run of three window layers "
-                "(a last partial period is not carried)")
+                "(a stack of runs: layer_runs, not this preset's yet)")
         base["num_layers"] = lead + period
     return ModelConfig(**base).validate()
 

@@ -6,7 +6,7 @@ The rings of all the window layers lie stacked, ``ring_k`` and ``ring_v``
 key heads and a row of the key ring holds those ``r`` heads' keys side by
 side, position ``t`` of a slot at row ``t % W``.  ``r = 2`` is
 differential attention's pair (a stack of runs); ``r = 1`` ordinary
-grouped heads, a ring row one key head's (the period scan's "window"
+grouped heads, a ring row one key head's (plain attention's "window"
 kind: 8 KV heads of 128 with 8 query rows each at Laguna-XS.2's widths).
 The kernel
 takes the stacked arrays and the layer's index: a layer scan that slices

@@ -37,10 +37,14 @@ The forms, by what the keys and values are:
   on the block pool (the "full" and "cross" layers' decode step).
 
 Every form takes its operands in the weights' precision and returns the
-attention output in it; ``finish`` works in float32.
+attention output in it; ``finish`` works in float32.  ``attend_full`` and
+``attend_handed`` are the "full" and the "cross" layer's attention part
+whole: the projections, the form its cache asks for, ``finish``.
 """
 
 from __future__ import annotations
+
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -259,6 +263,72 @@ def ring_of(rows, length, window: int):
     at = r + window * jnp.maximum((length[:, None] - 1 - r) // window, 0)
     at = jnp.minimum(at, rows.shape[2] - 1)
     return jnp.take_along_axis(rows, at[:, None, :, None], axis=2)
+
+
+class KVHand(NamedTuple):
+    """What the one "full" layer of a stack of runs hands to the "cross"
+    layers behind it: its keys and values, in the form the cross layers
+    attend them.  ``form`` "seq": ``k v`` head-major rows of the whole
+    sequence, a query at every position; "rows": dense ``k v`` [b, ., S,
+    .] and the positions ``last`` [b, r] of the few query rows; "paged":
+    ``paged`` the pool (``transformer.PagedKV``) and ``k v`` the step's own
+    rows, which are not in it yet."""
+
+    form: str
+    k: jax.Array
+    v: jax.Array
+    last: Optional[jax.Array] = None
+    paged: Optional[tuple] = None
+
+
+def attend_handed(cfg: ModelConfig, p: Params, u, layer, hand: KVHand,
+                  q_rows=None):
+    """A layer's differential attention with the keys and values of
+    ``hand``: its own query projection (of the rows ``q_rows`` [b] alone
+    where given), the form's attention, the pairs' difference and the
+    output projection."""
+    if q_rows is not None:
+        u = jnp.take_along_axis(u, q_rows[:, None, None], axis=1)
+    q = project_q(cfg, p, u)
+    if hand.form == "seq":
+        a = attend_seq(cfg, q, hand.k, hand.v)
+    elif hand.form == "rows":
+        a = attend_rows(cfg, q, hand.k, hand.v, hand.last)
+    else:
+        from ..ops.attention import paged_decode_attention
+
+        pg = hand.paged
+        a = paged_decode_attention(
+            q, pg.k_pool, pg.v_pool, pg.tables, pg.fills, hand.k, hand.v,
+            pg.layer, softmax_scale=_scale(cfg))
+    return finish(cfg, p, a, layer)
+
+
+def attend_full(cfg: ModelConfig, p: Params, u, side, layer, cache,
+                cut_rows):
+    """The "full" layer of a stack of runs: attention on its own keys and
+    values, all of them.  ``cache``: None (a whole sequence, nothing
+    kept), the dense ``(k_cache, v_cache, cache_len)`` or a
+    ``transformer.PagedKV``.  ``cut_rows`` [b] (a prompt into an empty cache
+    alone): the keys and values of every row, the query and the output
+    of that row only.  -> ``(out, the new rows or None, the hand)``."""
+    k, v = project_kv(cfg, p, u)
+    if hasattr(cache, "tables"):
+        k, v = k.astype(cache.k_pool.dtype), v.astype(cache.v_pool.dtype)
+        hand = KVHand("paged", k, v, paged=cache)
+    elif cache is None or side.cache_is_empty:
+        hand = (KVHand("seq", k, v) if cut_rows is None
+                else KVHand("rows", k, v, last=cut_rows[:, None]))
+    else:
+        # one new position on the dense view of the gather route
+        from ..ops.kv_quant import cache_update
+
+        k_cache, v_cache, cache_len = cache
+        hand = KVHand("rows", cache_update(k_cache, k, cache_len),
+                      cache_update(v_cache, v, cache_len),
+                      last=side.position_ids)
+    out = attend_handed(cfg, p, u, layer, hand, cut_rows)
+    return out, (None if cache is None else (k, v)), hand
 
 
 @jax.named_scope("gmu")

@@ -125,7 +125,7 @@ def init_state(cfg: ModelConfig, batch: int) -> MambaState:
     (PERF.md, PR 49: both measured)."""
     H, P, _G, N, _di, ch = dims(cfg)
     rows = cfg.mamba_conv_kernel - 1
-    periods = cfg.num_layers // max(len(cfg.layer_pattern), 1)
+    periods = max(times for _period, times in cfg.stack_runs)
     return MambaState(
         jnp.zeros((batch, H, P, N), jnp.float32),
         jnp.zeros((batch, rows * ch) if periods > 1 else (batch, rows, ch),

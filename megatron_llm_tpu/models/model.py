@@ -17,10 +17,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..config import ModelConfig, PositionEmbeddingType
-from . import gated_deltanet, mamba1, mamba2
 from .transformer import (
+    REC_MIXERS,
     RING_NAMES,
-    ring_append_rows,
     STREAM_DTYPE,
     AttnSideInputs,
     Params,
@@ -33,8 +32,7 @@ from .transformer import (
     layer_forward,
     norm_init,
     rope_tables,
-    scan_periods_cached,
-    scan_runs_cached,
+    scan_stack,
     stack_forward,
     stack_forward_cached,
     stack_forward_paged,
@@ -107,35 +105,34 @@ def level_router_bias(cfg: ModelConfig, params: Params, key: jax.Array,
     mixer keep the bias they drew."""
     from .moe import level_bias
 
-    kinds = cfg.layer_pattern
     toks = jax.random.randint(key, (1, tokens), 1, cfg.vocab_size - 1)
     position_ids = jnp.arange(tokens, dtype=jnp.int32)[None]
     cos, sin = rope_tables(cfg)
     side = AttnSideInputs(rope_cos=cos, rope_sin=sin,
                           position_ids=position_ids, deterministic=True)
     x = embed(cfg, params, toks, position_ids).astype(STREAM_DTYPE)
-    stacks = list(params["layers"])
-
-    def layer_of(j, i):
-        return jax.tree.map(lambda a: a[i], stacks[j])
-
+    runs = [list(trees) for trees in (
+        params["layers"] if cfg.layer_runs else [params["layers"]])]
     for p in _lead_layers(params.get("lead_layers")):
         x = layer_forward(cfg.lead_layer_config, p, x, side)[0]
-    for layer in range(cfg.scanned_layers):
-        j, i = layer % len(kinds), layer // len(kinds)
-        mlp = stacks[j].get("mlp", {})
-        if "router_bias" in mlp and (kinds[j] == "mlp"
-                                     or "attn" in stacks[j]):
-            p = layer_of(j, i)
-            h1 = (norm_apply(cfg.norm_type, x, p["input_norm"],
-                             cfg.norm_eps, impl=cfg.norm_impl)
-                  if kinds[j] == "mlp"
-                  else ffn_input(cfg, p, x, side, kinds[j]))
-            bias = level_bias(cfg, p["mlp"], h1[0])
-            stacks[j] = {**stacks[j], "mlp": {
-                **mlp, "router_bias": mlp["router_bias"].at[i].set(bias)}}
-        x = layer_forward(cfg, layer_of(j, i), x, side, kind=kinds[j])[0]
-    return {**params, "layers": stacks}
+    for (period, times), stacks in zip(cfg.stack_runs, runs):
+        for i in range(times):
+            for j, kind in enumerate(period):
+                p = jax.tree.map(lambda a: a[i], stacks[j])
+                mlp = stacks[j].get("mlp", {})
+                if "router_bias" in mlp and (kind == "mlp"
+                                             or "attn" in stacks[j]):
+                    h1 = (norm_apply(cfg.norm_type, x, p["input_norm"],
+                                     cfg.norm_eps, impl=cfg.norm_impl)
+                          if kind == "mlp"
+                          else ffn_input(cfg, p, x, side, kind))
+                    bias = level_bias(cfg, p["mlp"], h1[0])
+                    p = {**p, "mlp": {**p["mlp"], "router_bias": bias}}
+                    stacks[j] = {**stacks[j], "mlp": {
+                        **mlp, "router_bias": mlp["router_bias"].at[i].set(
+                            bias)}}
+                x = layer_forward(cfg, p, x, side, kind=kind)[0]
+    return {**params, "layers": runs if cfg.layer_runs else runs[0]}
 
 
 @jax.named_scope("embed")
@@ -307,8 +304,7 @@ def forward_cached(
     ``last_logit_only=True`` unembeds only the final position (logits come
     back [b, 1, vocab]) — prefill callers that just seed the decode loop
     skip the full [b, s, padded_vocab] projection, which XLA does NOT
-    narrow through a later slice (measured 85 ms of a 220 ms b=8/s=1024
-    prefill on v5e spent in the discarded logits).
+    narrow through a later slice.
 
     The caller owns advancing ``cache_len`` (reference: InferenceParams
     sequence-offset bookkeeping, megatron/text_generation/forward_step.py).
@@ -316,9 +312,8 @@ def forward_cached(
     ``empty_cache=True`` is the caller's STATIC promise that
     ``cache_len == 0`` (the first prefill): attention then runs ordinary
     causal attention over the window — the flash kernel — instead of the
-    O(s·max_len) cached-score einsum, which dominated prefill cost
-    (measured 30.9k tok/s vs ~130k tok/s forward-only capability at
-    b=8, s=1024 on v5e).  The cache K/V writes are identical either way.
+    O(s·max_len) cached-score einsum.  The cache K/V writes are identical
+    either way.
     """
     if rope is None:
         cos, sin = rope_tables(cfg)
@@ -609,8 +604,7 @@ def init_kv_cache(cfg: ModelConfig, batch_size: int, max_len: int,
     Head-major layout: each (layer, batch, head)'s [max_len, d] block is
     contiguous, so the decode GEMVs contract straight over it — the
     seq-major layout forced XLA to materialize a transposed copy of the
-    whole cache every step (measured ~20 ms/step at max_len=1024 vs ~1 ms
-    bandwidth floor).
+    whole cache every step.
 
     With ``cfg.kv_cache_quant == "int8"`` each side is the int8
     {"q", "scale"} form of ops/kv_quant.py — half the decode cache
@@ -662,7 +656,8 @@ def init_rec_state(cfg: ModelConfig, batch_size: int) -> dict:
     [mamba layers, b, taps - 1, channels] (flat, [.., (taps - 1) x
     channels], where the scan has more than one period:
     ``mamba2.init_state``)
-    (``REC_STATE_KINDS`` names them by kind).  And two counters carried
+    (``REC_STATE_KINDS`` names them by what a kind keeps).  And two
+    counters carried
     on the device and read when somebody asks: ``load`` [layers, router
     outputs] int32, how often each expert was chosen by the positions
     these states were advanced over (zero rows for the layers that do
@@ -671,12 +666,10 @@ def init_rec_state(cfg: ModelConfig, batch_size: int) -> dict:
     each count as two words (``add_rows``: a long prompt adds 10^5 to
     it)."""
     rec = {}
-    for kind, n, init in (("linear", cfg.linear_layers, gated_deltanet),
-                          ("mamba", cfg.mamba_layers, mamba2),
-                          ("ssm1", cfg.mamba1_layers, mamba1)):
+    for keeps, mixer in REC_MIXERS.items():
+        n = cfg.layers_keeping(keeps)
         if n:
-            one = init.init_state(cfg, batch_size)
-            for name, a in zip(REC_STATE_KINDS[kind], one):
+            for name, a in zip(mixer.names, mixer.start(cfg, batch_size)):
                 rec[name] = jnp.zeros((n,) + a.shape, a.dtype)
     if cfg.window_layers:
         # a "window" layer's ring: the last ``sliding_window`` keys and
@@ -688,20 +681,19 @@ def init_rec_state(cfg: ModelConfig, batch_size: int) -> dict:
         # contiguous write
         ring = (cfg.window_layers, batch_size, cfg.v_heads,
                 cfg.sliding_window, cfg.v_head_width)
-        rec["win_k"] = jnp.zeros(ring, cfg.dtype)
-        rec["win_v"] = jnp.zeros(ring, cfg.dtype)
+        for name in RING_NAMES:
+            rec[name] = jnp.zeros(ring, cfg.dtype)
     return {**rec,
             "load": jnp.zeros((cfg.num_layers, cfg.router_experts),
                               jnp.int32),
             "rows": jnp.zeros((cfg.num_layers, 2, 2), jnp.int32)}
 
 
-# the names of ``init_rec_state``'s state arrays, by the kind of mixer that
-# keeps them: "linear" a Gated DeltaNet layer's, "mamba" a Mamba-2 mixer's
-# (the block kinds ``config.MAMBA_KINDS``)
-REC_STATE_KINDS = {"linear": gated_deltanet.STATE_NAMES,
-                   "mamba": mamba2.STATE_NAMES,
-                   "ssm1": mamba1.STATE_NAMES,
+# the names of ``init_rec_state``'s state arrays, by what the block kinds
+# that keep them keep (``config.BlockKind.keeps``: a Gated DeltaNet
+# layer's, a Mamba-2 mixer's, a Mamba-1 mixer's, a "window" layer's rings)
+REC_STATE_KINDS = {**{keeps: mixer.names
+                      for keeps, mixer in REC_MIXERS.items()},
                    "window": RING_NAMES}
 
 
@@ -729,7 +721,14 @@ def rows_total(rows):
     return (rows[..., 0] << _ROWS_WORD) + rows[..., 1]
 
 
-def _counted(rec: dict, new: dict, counts: dict) -> dict:
+def _counted(cfg: ModelConfig, rec: dict, new: dict, counts: dict) -> dict:
+    """``new`` with ``rec``'s counters advanced by ``counts``."""
+    if cfg.layer_runs and not cfg.num_experts:
+        # (a stack written as runs that routes nothing hands its counters
+        # through untouched: the phi-4 cell's programs were lowered so; a
+        # pattern's dense stack adds its zeros, as the granite cell's
+        # were: PERF.md section 7)
+        return {**new, "load": rec["load"], "rows": rec["rows"]}
     one = counts["rows"].astype(jnp.int32)
     return {**new, "load": rec["load"] + counts["load"].astype(jnp.int32),
             "rows": add_rows(rec["rows"],
@@ -745,7 +744,15 @@ def forward_cached_hybrid(cfg: ModelConfig, params: Params, tokens,
     max_len, d]`` as there, the recurrent mixers continue ``rec``
     (``init_rec_state``) over the positions ``valid`` [b, s] marks (None:
     all; a prefix of each row) and leave it untouched over the others.
-    → ``(logits, new_k_cache, new_v_cache, new_rec)``."""
+    → ``(logits, new_k_cache, new_v_cache, new_rec)``.
+
+    A prompt into an empty cache whose ``logit_rows`` names one row a
+    sequence is cut to that row at the boundary between two decoders,
+    where the stack has one (``cfg.row_cut_layer``): every row goes
+    through the layers before it and through the "full" layer's key and
+    value projection, that row alone through its attention, every later
+    layer, the final norm and the head; the states, the rings and the
+    cache are what every row left."""
     from ..ops.kv_quant import cache_update
 
     b, s = tokens.shape
@@ -756,60 +763,23 @@ def forward_cached_hybrid(cfg: ModelConfig, params: Params, tokens,
     x = embed(cfg, params, tokens, position_ids)
     side = AttnSideInputs(position_ids=position_ids, deterministic=True,
                           cache_is_empty=empty_cache, valid=valid)
-    if cfg.layer_runs:
-        return _forward_cached_runs(cfg, params, x, side, k_cache, v_cache,
-                                    cache_len, rec, logit_rows)
-    x, (rows_k, rows_v), new, counts = scan_periods_cached(
-        cfg, params["layers"], x, side,
-        lambda _idx, k_l, v_l: (k_l, v_l, cache_len), rec,
-        kv_xs=(k_cache, v_cache), lead=params.get("lead_layers"))
-    k_cache = cache_update(k_cache, rows_k, cache_len)
-    v_cache = cache_update(v_cache, rows_v, cache_len)
-    x = norm_apply(cfg.norm_type, x, params["final_norm"], cfg.norm_eps,
-                   impl=cfg.norm_impl).astype(cfg.dtype)
-    if logit_rows is not None:
-        x = jnp.take_along_axis(
-            x, logit_rows.astype(jnp.int32)[:, None, None], axis=1)
-    return (unembed(cfg, params, x), k_cache, v_cache,
-            _counted(rec, new, counts))
-
-
-def _forward_cached_runs(cfg: ModelConfig, params: Params, x, side, k_cache,
-                         v_cache, cache_len, rec: dict, logit_rows):
-    """``forward_cached_hybrid`` for a stack of runs.  A prompt into an
-    empty cache whose ``logit_rows`` names one row a sequence is cut to
-    that row at the boundary between the two decoders
-    (``cfg.row_cut_layer``): every row goes through the layers before it
-    and through the "full" layer's key and value projection, that row
-    alone through its attention, every later layer, the final norm and
-    the head; the states, the rings and the cache are what every row
-    left.  A step (one new position on the dense view of the gather
-    route) writes its rows into the rings here."""
-    from ..ops.kv_quant import cache_update
-
-    prompt = side.cache_is_empty
     cut = (logit_rows.astype(jnp.int32)
-           if prompt and logit_rows is not None
+           if empty_cache and logit_rows is not None
            and cfg.row_cut_layer is not None else None)
-    x, (rows_k, rows_v), states, rings = scan_runs_cached(
+    x, (rows_k, rows_v), new, counts = scan_stack(
         cfg, params["layers"], x, side,
         lambda _idx, k_l, v_l: (k_l, v_l, cache_len), rec,
-        kv_xs=(k_cache, v_cache), cut_rows=cut)
+        kv_xs=(k_cache, v_cache), lead=params.get("lead_layers"),
+        cut_rows=cut)
     k_cache = cache_update(k_cache, rows_k, cache_len)
     v_cache = cache_update(v_cache, rows_v, cache_len)
-    if rings is not None and not prompt:
-        # a step's rows: written into the rings here, in place
-        rings = ring_append_rows(tuple(rec[n] for n in RING_NAMES), rings,
-                                 side.position_ids[:, 0])
-    if rings is not None:
-        states = {**states, **dict(zip(RING_NAMES, rings))}
     x = norm_apply(cfg.norm_type, x, params["final_norm"], cfg.norm_eps,
                    impl=cfg.norm_impl).astype(cfg.dtype)
     if logit_rows is not None and cut is None:
         x = jnp.take_along_axis(
             x, logit_rows.astype(jnp.int32)[:, None, None], axis=1)
     return (unembed(cfg, params, x), k_cache, v_cache,
-            {**states, "load": rec["load"], "rows": rec["rows"]})
+            _counted(cfg, rec, new, counts))
 
 
 def forward_paged_hybrid(cfg: ModelConfig, params: Params, tokens, k_pool,
@@ -828,32 +798,17 @@ def forward_paged_hybrid(cfg: ModelConfig, params: Params, tokens, k_pool,
     bids = jnp.take_along_axis(tables, (fills // bk)[:, None], axis=1)[:, 0]
     offs = fills % bk
     valid = live[:, None]
-    if cfg.layer_runs and paged_decode_eligible(cfg, k_pool,
-                                                tokens.shape[1]):
+    if paged_decode_eligible(cfg, k_pool, tokens.shape[1]):
         x = embed(cfg, params, tokens, fills[:, None])
         side = AttnSideInputs(position_ids=fills[:, None],
                               deterministic=True, valid=valid)
-        x, (rows_k, rows_v), states, rows = scan_runs_cached(
-            cfg, params["layers"], x, side,
-            lambda idx: PagedKV(k_pool, v_pool, tables, fills, idx), rec)
-        if rows is not None:
-            states = {**states, **dict(zip(RING_NAMES, ring_append_rows(
-                tuple(rec[n] for n in RING_NAMES), rows, fills)))}
-        x = norm_apply(cfg.norm_type, x, params["final_norm"],
-                       cfg.norm_eps, impl=cfg.norm_impl).astype(cfg.dtype)
-        logits = unembed(cfg, params, x)
-        rec = {**states, "load": rec["load"], "rows": rec["rows"]}
-    elif paged_decode_eligible(cfg, k_pool, tokens.shape[1]):
-        x = embed(cfg, params, tokens, fills[:, None])
-        side = AttnSideInputs(position_ids=fills[:, None],
-                              deterministic=True, valid=valid)
-        x, (rows_k, rows_v), new, counts = scan_periods_cached(
+        x, (rows_k, rows_v), new, counts = scan_stack(
             cfg, params["layers"], x, side,
             lambda idx: PagedKV(k_pool, v_pool, tables, fills, idx), rec,
             lead=params.get("lead_layers"))
         x = norm_apply(cfg.norm_type, x, params["final_norm"],
                        cfg.norm_eps, impl=cfg.norm_impl).astype(cfg.dtype)
-        logits, rec = unembed(cfg, params, x), _counted(rec, new, counts)
+        logits, rec = unembed(cfg, params, x), _counted(cfg, rec, new, counts)
     else:
         k_dense = cache_gather_blocks(k_pool, tables)
         v_dense = cache_gather_blocks(v_pool, tables)
@@ -1085,10 +1040,6 @@ def cache_slot_copy(dst_cache, src_cache, dst_slot, dst_pos, src_slot,
             (jnp.int32(0), dst_slot, jnp.int32(0), dst_pos) + zeros)
 
     return jax.tree.map(cp, dst_cache, src_cache)
-
-
-def num_params(params: Params) -> int:
-    return sum(p.size for p in jax.tree.leaves(params))
 
 
 def flops_per_token(cfg: ModelConfig, seq_len: int) -> float:

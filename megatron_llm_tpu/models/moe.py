@@ -223,8 +223,8 @@ def _held_experts(cfg: ModelConfig, interpret: bool, p: Params, x, local,
     pair's place.  The pairs of experts that are not here sort last, past
     the last group: a sort key each and nothing else.  Where ``p`` holds
     ``expert_layer`` its three matrices are a whole stack's, of which the
-    kernel takes that layer (``models/transformer.py:
-    scan_periods_cached`` hands a scanned stack's experts over so)."""
+    kernel takes that layer (``models/transformer.py:scan_stack`` hands
+    the experts of a run of several periods over so)."""
     g, k = local.shape
     n, E = g * k, cfg.num_experts
     cbits = (k - 1).bit_length()

@@ -30,12 +30,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from ..config import (
-    KV_KINDS,
-    MAMBA_KINDS,
-    ModelConfig,
-    PositionEmbeddingType,
-)
+from ..config import KINDS, ModelConfig, PositionEmbeddingType
 from ..ops.activations import get_activation, is_glu
 from ..ops.attention import _mesh_active, attention
 from ..ops.norms import norm_apply, norm_init
@@ -72,66 +67,66 @@ def _normal(key, shape, std, dtype):
     return (std * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
 
 
+def _init_stds(cfg: ModelConfig):
+    """→ (std, the output layers': scaled by 1/sqrt(2*num_layers))."""
+    std = cfg.init_method_std
+    return std, (std / (2.0 * cfg.num_layers) ** 0.5 if cfg.use_scaled_init
+                 else std)
+
+
+def _init_attn(keys, cfg: ModelConfig, kind: str) -> Params:
+    """An attention part's parameters: differential, latent or grouped
+    heads, by what the model's attention is."""
+    h, d, nkv, dtype = cfg.hidden_size, cfg.head_dim, cfg.kv_heads, cfg.dtype
+    std, out_std = _init_stds(cfg)
+    if cfg.diff_attention:
+        return diff_attention.init_diff_attn_params(
+            keys[0], cfg, cross=KINDS[kind].reads == "kv")
+    if cfg.kv_lora_rank and KINDS[kind].keeps == "kv":
+        return mla.init_mla_params(keys[0], cfg, std, out_std)
+    # (a "window" layer of plain attention has a head count of its own)
+    nq = (cfg.window_layer_config if KINDS[kind].keeps == "window"
+          else cfg).num_attention_heads
+    attn: Params = {
+        # with an output gate: per head, the query's columns then the
+        # gate's
+        "wq": _normal(keys[0], (h, nq * d * (2 if cfg.attn_output_gate
+                                             else 1)), std, dtype),
+        "wk": _normal(keys[1], (h, nkv * d), std, dtype),
+        "wv": _normal(keys[2], (h, nkv * d), std, dtype),
+        "wo": _normal(keys[3], (nq * d, h), out_std, dtype),
+    }
+    if cfg.use_bias or cfg.qkv_bias:
+        attn["bq"] = jnp.zeros((nq * d,), dtype)
+        attn["bk"] = jnp.zeros((nkv * d,), dtype)
+        attn["bv"] = jnp.zeros((nkv * d,), dtype)
+    if cfg.use_bias:
+        attn["bo"] = jnp.zeros((h,), dtype)
+    if cfg.qk_norm:
+        attn["q_norm"] = norm_init(cfg.norm_type, d, dtype)
+        attn["k_norm"] = norm_init(cfg.norm_type, d, dtype)
+    if cfg.attn_head_gate:
+        # kept in float32, as the router is: one scalar a head
+        attn["wg"] = std * jax.random.normal(
+            jax.random.fold_in(keys[0], 1), (h, nq), jnp.float32)
+    return attn
+
+
 def init_layer_params(key: jax.Array, cfg: ModelConfig,
                       kind: str = "full") -> Params:
     """Parameters of one transformer layer (unstacked), of one of
-    ``config.BLOCK_KINDS``.  A block of two parts holds a mixer
-    (``"attn"``, ``"gdn"`` for a ``"linear"`` layer, ``"mamba"`` for an
-    ``"ssm"`` layer), ``"mlp"`` and a norm for each; a block of one part
-    holds that part (``"attn"``, ``"mamba"`` or ``"mlp"``) under
-    ``"input_norm"`` alone."""
-    h = cfg.hidden_size
-    d = cfg.head_dim
-    # (a "window" layer of the period scan has a head count of its own)
-    nq = (cfg.window_layer_config if kind == "window"
-          else cfg).num_attention_heads
-    nkv = cfg.kv_heads
-    ffn = cfg.ffn_size
-    dtype = cfg.dtype
-    std = cfg.init_method_std
-    # output-layer init scaled by 1/sqrt(2*num_layers)
-    out_std = std / (2.0 * cfg.num_layers) ** 0.5 if cfg.use_scaled_init else std
-
+    ``config.KINDS``: under ``"input_norm"`` the kind's mixer, under the
+    name ``MIXERS`` gives it (none for an ``"mlp"`` block), and where the
+    kind has a feed-forward part ``"mlp"``, under a norm of its own
+    where both are there."""
+    h, ffn, dtype = cfg.hidden_size, cfg.ffn_size, cfg.dtype
+    std, out_std = _init_stds(cfg)
     keys = jax.random.split(key, 8)
     layer: Params = {"input_norm": norm_init(cfg.norm_type, h, dtype)}
-    if cfg.diff_attention and kind in ("full", "window", "cross"):
-        layer["attn"] = diff_attention.init_diff_attn_params(
-            keys[0], cfg, cross=kind == "cross")
-    elif kind == "ssm1":
-        layer["mamba1"] = mamba1.init_mamba1_params(keys[7], cfg)
-    elif kind == "gmu":
-        layer["gmu"] = diff_attention.init_gmu_params(keys[7], cfg)
-    elif kind in KV_KINDS and cfg.kv_lora_rank:
-        layer["attn"] = mla.init_mla_params(keys[0], cfg, std, out_std)
-    elif kind in KV_KINDS or kind == "window":
-        attn: Params = {
-            # with an output gate: per head, the query's columns then the
-            # gate's
-            "wq": _normal(keys[0], (h, nq * d * (2 if cfg.attn_output_gate
-                                                 else 1)), std, dtype),
-            "wk": _normal(keys[1], (h, nkv * d), std, dtype),
-            "wv": _normal(keys[2], (h, nkv * d), std, dtype),
-            "wo": _normal(keys[3], (nq * d, h), out_std, dtype),
-        }
-        if cfg.use_bias or cfg.qkv_bias:
-            attn["bq"] = jnp.zeros((nq * d,), dtype)
-            attn["bk"] = jnp.zeros((nkv * d,), dtype)
-            attn["bv"] = jnp.zeros((nkv * d,), dtype)
-        if cfg.use_bias:
-            attn["bo"] = jnp.zeros((h,), dtype)
-        if cfg.qk_norm:
-            attn["q_norm"] = norm_init(cfg.norm_type, d, dtype)
-            attn["k_norm"] = norm_init(cfg.norm_type, d, dtype)
-        if cfg.attn_head_gate:
-            # kept in float32, as the router is: one scalar a head
-            attn["wg"] = std * jax.random.normal(
-                jax.random.fold_in(keys[0], 1), (h, nq), jnp.float32)
-        layer["attn"] = attn
-    elif kind == "linear":
-        layer["gdn"] = init_gdn_params(keys[7], cfg)
-    elif kind in MAMBA_KINDS:
-        layer["mamba"] = mamba2.init_mamba_params(keys[7], cfg)
-    if kind in ("attention", "mamba"):
+    mixer = MIXERS[kind]
+    if mixer.name:
+        layer[mixer.name] = mixer.init(keys, cfg, kind)
+    if not KINDS[kind].ffn:
         return layer                     # a mixer alone
 
     if cfg.num_experts > 0:
@@ -152,7 +147,7 @@ def init_layer_params(key: jax.Array, cfg: ModelConfig,
             mlp["b_up"] = jnp.zeros((ffn,), dtype)
             mlp["b_down"] = jnp.zeros((h,), dtype)
     layer["mlp"] = mlp
-    if kind == "mlp":
+    if not mixer.name:
         return layer                     # the feed-forward part alone
     if cfg.parallel_attn:
         if cfg.parallel_layernorm:
@@ -168,28 +163,23 @@ def init_stack_params(key: jax.Array, cfg: ModelConfig,
                       num_layers: Optional[int] = None) -> Params:
     """All layers of the scan, stacked on a leading axis (scan/pipeline
     layout).  A hybrid stack (``cfg.layer_pattern``) is a list with one
-    such tree a position of the period, each stacked over the periods;
-    leading dense layers are not among them (``init_lead_params``)."""
+    such tree a position of the period, each stacked over the periods (a
+    stack written as runs, ``cfg.layer_runs``: a list of such lists, one a
+    run); leading dense layers are not among them
+    (``init_lead_params``)."""
     n = num_layers if num_layers is not None else cfg.scanned_layers
-    if cfg.layer_runs:
-        # a list of runs, each a list with one tree a position of the
-        # run's period, stacked over the run's periods
-        keys, runs, at = jax.random.split(key, cfg.num_layers), [], 0
-        for period, times in cfg.layer_runs:
-            mine = keys[at:at + len(period) * times]
-            runs.append([jax.vmap(lambda k, kind=kind: init_layer_params(
-                k, cfg, kind))(mine[j::len(period)])
-                for j, kind in enumerate(period)])
-            at += len(period) * times
-        return runs
-    if cfg.layer_pattern:
-        kinds = cfg.layer_pattern
-        keys = jax.random.split(key, n)
-        return [jax.vmap(lambda k, kind=kind: init_layer_params(
-            k, cfg, kind))(keys[j::len(kinds)])
-            for j, kind in enumerate(kinds)]
     keys = jax.random.split(key, n)
-    return jax.vmap(lambda k: init_layer_params(k, cfg))(keys)
+    if not cfg.layer_pattern:
+        return jax.vmap(lambda k: init_layer_params(k, cfg))(keys)
+    runs, at = [], 0
+    for period, times in cfg.stack_runs:
+        mine = keys[at:at + len(period) * times]
+        runs.append([jax.vmap(lambda k, kind=kind: init_layer_params(
+            k, cfg, kind))(mine[j::len(period)])
+            for j, kind in enumerate(period)])
+        at += len(period) * times
+    # (a stack written as runs: a list of runs, each such a list)
+    return runs if cfg.layer_runs else runs[0]
 
 
 def init_lead_params(key: jax.Array, cfg: ModelConfig) -> Params:
@@ -455,12 +445,9 @@ def attention_block(cfg: ModelConfig, p: Params, x: jax.Array,
     softmax_scale = (1.0 / (cfg.head_dim ** 0.5)
                      if cfg.attention_multiplier is None
                      else cfg.attention_multiplier)
-    if cfg.apply_query_key_layer_scaling:
-        # reference scales by 1/layer inside softmax and compensates in the
-        # matmul (transformer.py:191-236); net effect is standard scale, so
-        # only the numerically-relevant fp32 softmax is kept.
-        pass
-
+    # (cfg.apply_query_key_layer_scaling: the reference scales by 1/layer
+    # inside softmax and compensates in the matmul, transformer.py:191-236;
+    # the net effect is the standard scale, so only the fp32 softmax is kept)
     drop_rng = None
     if not side.deterministic and cfg.attention_dropout > 0.0:
         drop_rng = jax.random.fold_in(layer_rng, 1)
@@ -491,9 +478,7 @@ def attention_block(cfg: ModelConfig, p: Params, x: jax.Array,
             # prefill fast path: no prior rows to attend, so this is
             # ordinary causal attention over the window — the flash
             # kernel at O(s²) instead of the cached-score einsum at
-            # O(s·max_len) (which at s=1024, max_len=1152 materialized
-            # ~300 MB of scores per layer: measured 30.9k tok/s prefill
-            # vs ~4x that through this path on v5e)
+            # O(s·max_len), whose scores are materialized a layer
             ctx = attention(
                 q, k, v,
                 impl=cfg.attention_impl,
@@ -527,8 +512,7 @@ def attention_block(cfg: ModelConfig, p: Params, x: jax.Array,
     if kv_cache is not None:
         # return only the NEW rows [b, nkv, s, d] — the caller writes them
         # into its persistent cache with a row-sized dynamic_update_slice,
-        # so decode never copies the O(max_len) cache (measured 8-30x of
-        # the whole per-step cost before this change)
+        # so decode never copies the O(max_len) cache
         return out, (new_k, new_v)
     return out
 
@@ -581,26 +565,78 @@ def _mlp_dispatch(cfg: ModelConfig, p: Params, x: jax.Array, lora=None,
     return mlp_block(cfg, p, x, lora=lora), jnp.zeros((), jnp.float32)
 
 
+class Handed(NamedTuple):
+    """What earlier layers hand a block at the same positions, in a stack
+    whose kinds read such (``config.BlockKind.reads``): the last Mamba-1
+    layer's ``memory`` (its scan's output before the gate) and the one
+    "full" layer's keys and values ``kv`` (``diff_attention.KVHand``).  And
+    ``cut_rows`` [b] (a prompt into an empty cache alone): the row of
+    each sequence that is carried on from that "full" layer's attention
+    (``cfg.row_cut_layer``)."""
+
+    memory: Optional[jax.Array] = None
+    kv: Optional[diff_attention.KVHand] = None
+    cut_rows: Optional[jax.Array] = None
+
+
+class _Call(NamedTuple):
+    """What a block hands its mixer beside the norm's output and the
+    cache (``Mixer.apply``)."""
+
+    rng: object
+    lora: object
+    gate_x: jax.Array
+    layer: object
+    handed: Handed
+
+
+def _mixer_part(cfg: ModelConfig, p: Params, x, h1, side: AttnSideInputs,
+                kind: str, rng, cache, layer_idx, lora, handed: Handed):
+    """A block's mixer on ``h1``, the stream ``x`` under the block's
+    first norm → ``(the stream the result is added to, the result (None:
+    the kind has no mixer), the mixer's new cache, what is handed on)``."""
+    mixer = MIXERS[kind]
+    if not mixer.name:
+        return x, None, None, handed
+    # a hybrid stack's residual stream is float32 (``STREAM_DTYPE``);
+    # attention computes in the model's own precision, and a gate a head
+    # reads the stream's norm as it is
+    u = h1
+    if cfg.layer_pattern and mixer.name == "attn":
+        u = h1.astype(cfg.dtype)
+    out, new_cache, handed = mixer.apply(
+        cfg, p[mixer.name], u, side, cache,
+        _Call(rng, lora, h1, layer_idx, handed))
+    if handed.cut_rows is not None and KINDS[kind].keeps == "kv":
+        # the boundary between the decoders: from here on, one row of
+        # each sequence
+        cut = lambda a: jnp.take_along_axis(  # noqa: E731
+            a, handed.cut_rows[:, None, None], axis=1)
+        x = cut(x)
+        if handed.memory is not None:
+            handed = handed._replace(memory=cut(handed.memory))
+    return x, out, new_cache, handed
+
+
 def layer_forward(cfg: ModelConfig, p: Params, x: jax.Array,
                   side: AttnSideInputs, layer_rng=None,
                   kv_cache: Optional[tuple] = None,
-                  layer_idx=None, lora=None, kind: str = "full"):
-    """One pre-LN residual block, sequential or Falcon-parallel.
+                  layer_idx=None, lora=None, kind: str = "full",
+                  handed: Optional[Handed] = None):
+    """One pre-LN residual block of ``kind`` (``config.KINDS``): the
+    kind's mixer (``MIXERS``) and then the feed-forward part, each under
+    a norm of its own, sequential or Falcon-parallel; a kind of one part
+    is that part under one norm, ``x + f(norm(x))``.
 
     Parity: megatron/model/transformer.py:695-817
     (ParallelTransformerLayer.forward).  Returns ``(out, moe_aux)``; with
-    ``kv_cache`` returns ``(out, moe_aux, new_cache)``.
+    ``kv_cache`` (what the kind's mixer keeps: ``Mixer.apply``)
+    ``(out, moe_aux, new_cache)``; with ``handed`` (a layer scan's:
+    :class:`Handed`) what this block hands on comes last.
 
     ``layer_idx`` (global layer number, may be traced) drives the LIMA
     dropout ramp and per-layer drop-path rate; None → flat rates.
-
-    A block of one part (a hybrid stack's ``"attention"``, ``"mamba"``
-    and ``"mlp"`` kinds) is ``_one_part_forward``'s.  ``kind`` "window":
-    the block's attention part is ``_window_attend``'s, and its
-    ``kv_cache`` the ring's forms there (True or the stacked rings).
     """
-    if "mlp" not in p or not any(m in p for m in ("attn", "gdn", "mamba")):
-        return _one_part_forward(cfg, p, x, side, layer_rng, kv_cache)
     if layer_idx is not None and (cfg.lima_dropout
                                   or cfg.drop_path_rate > 0.0):
         hidden_dropout, dp_rate = _layer_rates(cfg, layer_idx)
@@ -621,38 +657,20 @@ def layer_forward(cfg: ModelConfig, p: Params, x: jax.Array,
         return _drop_path(out, dp_rate,
                           jax.random.fold_in(layer_rng, salt + 2),
                           side.deterministic)
+
     # Sequence parallelism: the residual stream enters/leaves each layer
     # seq-sharded; GSPMD turns this into the all-gather-before-qkv /
     # reduce-scatter-after-wo/w_down pattern the reference's
     # ColumnParallel(gather_output=False, sequence_parallel=True) layers
     # hand-code (core/tensor_parallel/layers.py:225-296).
     x = seq_constrain(x, side.seq_shard_axes)
-    residual = x
     h1 = norm_apply(cfg.norm_type, x, p["input_norm"], cfg.norm_eps,
                     impl=cfg.norm_impl)
-    new_cache = None
-    gate_x = h1          # a gate a head reads the stream's norm as it is
-    if cfg.layer_pattern:
-        # a hybrid stack's residual stream is float32 (``stream_dtype``);
-        # attention computes in the model's own precision
-        h1 = h1 if "attn" not in p else h1.astype(cfg.dtype)
-    if "gdn" in p:
-        # a linear layer: its "cache" is the recurrent state, carried or
-        # (None) started at zero; the new one is dropped with no cache
-        attn_out, new_cache = gdn_block(cfg, p["gdn"], h1, kv_cache,
-                                        side.valid)
-    elif "mamba" in p:
-        # an ssm layer: the same, with the state-space state
-        attn_out, new_cache = mamba2.mamba_block(cfg, p["mamba"], h1,
-                                                 kv_cache, side.valid)
-    elif kind == "window":
-        with jax.named_scope("swa"):
-            attn_out, new_cache = _window_attend(
-                cfg, p["attn"], h1, side, layer_idx, kv_cache, gate_x)
-    else:
-        attn_out, new_cache = _attend(cfg, p["attn"], h1, side, layer_rng,
-                                      kv_cache, lora, gate_x)
-
+    ffn = KINDS[kind].ffn
+    aux = None if ffn else _aux_zero(cfg)    # a mixer alone counts nothing
+    x, attn_out, new_cache, handed_on = _mixer_part(
+        cfg, p, x, h1, side, kind, layer_rng, kv_cache, layer_idx, lora,
+        handed or Handed())
     if cfg.parallel_attn:
         if cfg.parallel_layernorm:
             mlp_in = norm_apply(cfg.norm_type, x, p["mlp_norm"],
@@ -660,68 +678,21 @@ def layer_forward(cfg: ModelConfig, p: Params, x: jax.Array,
         else:
             mlp_in = h1
         mlp_out, aux = _mlp_dispatch(cfg, p["mlp"], mlp_in, lora=lora)
-        result = residual + _scaled(
-            cfg, branch_drop(attn_out + mlp_out, 2))
+        x = x + _scaled(cfg, branch_drop(attn_out + mlp_out, 2))
     else:
-        x = residual + _scaled(cfg, branch_drop(attn_out, 2))
-        h2 = norm_apply(cfg.norm_type, x, p["post_attn_norm"],
-                        cfg.norm_eps, impl=cfg.norm_impl)
-        m, aux = _mlp_dispatch(cfg, p["mlp"], h2, lora=lora,
-                               valid=side.valid)
-        result = x + _scaled(cfg, branch_drop(m, 3))
-    result = seq_constrain(result, side.seq_shard_axes)
+        if attn_out is not None:
+            x = x + _scaled(cfg, branch_drop(attn_out, 2))
+            if ffn:
+                h1 = norm_apply(cfg.norm_type, x, p["post_attn_norm"],
+                                cfg.norm_eps, impl=cfg.norm_impl)
+        if ffn:
+            m, aux = _mlp_dispatch(cfg, p["mlp"], h1, lora=lora,
+                                   valid=side.valid)
+            x = x + _scaled(cfg, branch_drop(m, 3))
+    result = (seq_constrain(x, side.seq_shard_axes), aux)
     if kv_cache is not None:
-        return result, aux, new_cache
-    return result, aux
-
-
-def _attend(cfg: ModelConfig, p: Params, h1: jax.Array,
-            side: AttnSideInputs, layer_rng, kv_cache, lora=None,
-            gate_x=None):
-    """A block's attention part, softmax attention over K/V a KV head or
-    latent attention (``cfg.kv_lora_rank``), → ``(out, the new rows or
-    None)``."""
-    if cfg.kv_lora_rank:
-        out = mla.mla_block(cfg, p, h1, side, kv_cache)
-    elif kv_cache is not None:
-        out = attention_block(cfg, p, h1, side, layer_rng, kv_cache,
-                              lora=lora, gate_x=gate_x)
-    else:
-        out = attention_block(cfg, p, h1, side, layer_rng, lora=lora,
-                              gate_x=gate_x)
-    return out if kv_cache is not None else (out, None)
-
-
-def _one_part_forward(cfg: ModelConfig, p: Params, x: jax.Array,
-                      side: AttnSideInputs, layer_rng, kv_cache,
-                      kind: str = "full"):
-    """A block of one part under one norm, ``x + f(norm(x))``: softmax
-    attention, a Mamba-2 mixer or the feed-forward part alone, by what
-    ``p`` holds.  Returns as ``layer_forward`` does; the feed-forward
-    part keeps no cache (``kv_cache`` None, two results)."""
-    h1 = norm_apply(cfg.norm_type, x, p["input_norm"], cfg.norm_eps,
-                    impl=cfg.norm_impl)
-    aux, new_cache = _aux_zero(cfg), None
-    if "mamba" in p:
-        # its "cache" is the state-space state, carried or (None) started
-        # at zero; the new one is dropped with no cache
-        out, new_cache = mamba2.mamba_block(cfg, p["mamba"], h1, kv_cache,
-                                            side.valid)
-    elif kind == "window":
-        with jax.named_scope("swa"):
-            out, new_cache = _window_attend(
-                cfg, p["attn"], h1.astype(cfg.dtype), side, None, kv_cache,
-                h1)
-    elif "attn" in p:
-        # attention computes in the model's own precision
-        out, new_cache = _attend(cfg, p["attn"], h1.astype(cfg.dtype), side,
-                                 layer_rng, kv_cache, gate_x=h1)
-    else:
-        out, aux = _mlp_dispatch(cfg, p["mlp"], h1, valid=side.valid)
-    result = x + _scaled(cfg, out)
-    if kv_cache is not None:
-        return result, aux, new_cache
-    return result, aux
+        result += (new_cache,)
+    return result if handed is None else result + (handed_on,)
 
 
 def ffn_input(cfg: ModelConfig, p: Params, x: jax.Array,
@@ -729,10 +700,12 @@ def ffn_input(cfg: ModelConfig, p: Params, x: jax.Array,
     """What the feed-forward part of a two-part attention block reads:
     the stream with the attention part's result added, under the block's
     second norm (``models/model.py:level_router_bias``)."""
-    x = _one_part_forward(cfg, {k: p[k] for k in ("input_norm", "attn")},
-                          x, side, None, None, kind)[0]
-    return norm_apply(cfg.norm_type, x, p["post_attn_norm"], cfg.norm_eps,
-                      impl=cfg.norm_impl)
+    h1 = norm_apply(cfg.norm_type, x, p["input_norm"], cfg.norm_eps,
+                    impl=cfg.norm_impl)
+    x, out, _new, _on = _mixer_part(cfg, p, x, h1, side, kind, None, None,
+                                    None, None, Handed())
+    return norm_apply(cfg.norm_type, x + _scaled(cfg, out),
+                      p["post_attn_norm"], cfg.norm_eps, impl=cfg.norm_impl)
 
 
 def _scaled(cfg: ModelConfig, out):
@@ -771,13 +744,14 @@ def stack_forward(cfg: ModelConfig, stacked: Params, x: jax.Array,
     ``lead``: the leading dense layers (``init_lead_params``), which run
     before the scan.
     """
-    if cfg.layer_runs:
-        assert lora is None and lead is None
-        return scan_runs_cached(cfg, stacked, x, side)[0], _aux_zero(cfg)
     if cfg.layer_pattern:
+        # (no rematerialisation: such a stack is served, not trained)
         assert lora is None, "a hybrid stack takes no adapters"
-        return _stack_forward_periods(cfg, stacked, x, side, base_rng,
-                                      layer_offset, lead)
+        x, _rows, _states, aux = scan_stack(
+            cfg, stacked, x, side, lead=lead, base_rng=base_rng,
+            layer_offset=layer_offset)
+        return x, (jax.tree.map(lambda a: a.sum(0), aux)
+                   if cfg.num_experts else _aux_zero(cfg))
     arenas, mask = lora if lora is not None else (None, None)
 
     def body(carry, inp):
@@ -826,74 +800,35 @@ def _aux_zero(cfg: ModelConfig):
 STREAM_DTYPE = jnp.float32
 
 
-def _stack_forward_periods(cfg: ModelConfig, stacked, x, side, base_rng,
-                           layer_offset, lead=None):
-    """``stack_forward`` for a hybrid stack: the scan runs over the
-    periods, and its body runs one period's layers in order, each of the
-    kind its position has.  Every recurrent mixer starts from a zero state
-    and drops the one it ends with: a whole sequence, no cache.  (No
-    rematerialisation: such a stack is served, not trained.)"""
-    n_pos = len(cfg.layer_pattern)
-    n_lead = cfg.moe_first_dense_layers
-    x = x.astype(STREAM_DTYPE)
-    for layer_params in _lead_layers(lead):
-        x = layer_forward(cfg.lead_layer_config, layer_params, x, side)[0]
+class _Stacked(NamedTuple):
+    """A recurrent mixer's two stacked state arrays and the number of the
+    layer among them that a call is about."""
 
-    def body(carry, period):
-        h, idx, aux_sum = carry
-        for j, layer_params in enumerate(period):
-            layer = idx * n_pos + j
-            if n_lead:
-                layer = layer + n_lead
-            rng = (None if base_rng is None
-                   else jax.random.fold_in(base_rng, layer))
-            h, aux = layer_forward(cfg, layer_params, h, side, rng,
-                                   layer_idx=layer_offset + layer,
-                                   kind=cfg.layer_pattern[j])[:2]
-            aux_sum = jax.tree.map(jnp.add, aux_sum, aux)
-        return (h, idx + 1, aux_sum), None
-
-    (x, _, aux), _ = jax.lax.scan(body, (x, 0, _aux_zero(cfg)),
-                                  tuple(stacked))
-    return x, aux
+    S: jax.Array
+    conv: jax.Array
+    at: jax.Array
 
 
-class _RecMixer(NamedTuple):
-    """A kind of recurrent mixer as ``scan_periods_cached`` carries its
-    state: the class of a layer's state (``S``, ``conv``, ``at``), the
-    names of the two stacked arrays in ``models/model.py:init_rec_state``'s
-    tree, and the scope of the form that makes a prompt's end state."""
-
-    state: type
-    names: tuple
-    scope: str
-
-
-_GDN = _RecMixer(GDNState, gated_deltanet.STATE_NAMES, "gdn/gdn_scan")
-_MAMBA = _RecMixer(mamba2.MambaState, mamba2.STATE_NAMES,
-                   "mamba/mamba_scan")
-_REC_KINDS = {"linear": _GDN, **{kind: _MAMBA for kind in MAMBA_KINDS}}
-
-
-def _rec_state_at(mixer: _RecMixer, stacked: dict, at, one_position: bool):
+def _rec_state_at(mixer: "Mixer", stacked: dict, at, one_position: bool):
     """Layer ``at`` of ``mixer``'s stacked states ``{name: [layers, b,
     ...]}``; for one position both arrays stay stacked (the state's
     ``at``): the kernel picks the layer's state and tail where they
     lie."""
     S, conv = (stacked[name] for name in mixer.names)
-    if one_position:
+    if one_position or mixer.state is _Stacked:
         return mixer.state(S, conv, at)
     conv, S = (jax.lax.dynamic_index_in_dim(a, at, 0, keepdims=False)
                for a in (conv, S))
     return mixer.state(S, conv)
 
 
-def _rec_write_back(mixer: _RecMixer, stacked: dict, new, at) -> dict:
+def _rec_write_back(mixer: "Mixer", stacked: dict, new, at) -> dict:
     """``new`` as layer ``at`` of ``mixer``'s stacked states, in place: a
     prompt's end state and tail (XLA fuses the update into the write,
     whose operation is this one: so it stands under the scope of the form
-    that made the state).  One position's kernel has written its layer
-    into both stacked arrays already (``new.at``)."""
+    that made the state).  One position's kernel, and a mixer that takes
+    the arrays stacked at any length, has written its layer into both
+    already (``new.at``)."""
     if new.at is not None:
         return dict(zip(mixer.names, new[:2]))
     with jax.named_scope(mixer.scope):
@@ -901,231 +836,24 @@ def _rec_write_back(mixer: _RecMixer, stacked: dict, new, at) -> dict:
             stacked[name], a, at, 0) for name, a in zip(mixer.names, new)}
 
 
-def scan_periods_cached(cfg: ModelConfig, stacked, x, side, kv_of, rec,
-                        kv_xs: tuple = (), lead=None):
-    """The cached forms of a hybrid stack, prefill and decode alike: a
-    scan over the periods whose body gives each layer that attends
-    (``"full"``, ``"attention"``) its ``kv_cache`` (``kv_of(kv_layer,
-    *slices of kv_xs)``, ``kv_layer`` counting those layers alone: the KV
-    cache's own layer axis) and each recurrent mixer its state out of
-    ``rec`` (``models/model.py:init_rec_state``): a ``"linear"`` layer
-    ``{"S": [linear layers, b, ...], "conv": [...]}``, a ``"mamba"`` or
-    ``"ssm"`` layer ``{"ssm": [mamba layers, b, ...], "ssm_conv":
-    [...]}``.  The states of either kind ride in the scan's carry: a
-    prompt's layer reads and rewrites its own slice in place, a decode
-    step's kernel takes them stacked and advances its layer where it lies
-    (``_rec_state_at``, ``_rec_write_back``).  A ``"window"`` layer
-    (``_window_attend``) keeps no cache layer and a ring a slot under
-    ``RING_NAMES``: a prompt's rings come back whole, a step attends the
-    stacked rings where they lie and its new rows are written behind the
-    scan, once (``ring_append_rows``); either way they are among the
-    states returned.
-
-    → ``(hidden, (rows_k, rows_v) stacked over the attending layers, rec's
-    states advanced over the positions ``side.valid`` marks, counts
-    ``{"load": [layers, router outputs], "rows": [layers, 2]}``: the
-    experts those positions chose, and the (token, choice) rows each
-    layer's experts multiplied and skipped; zero for a layer without
-    experts)``.
-
-    The leading dense layers ``lead`` (``init_lead_params``) run before
-    the scan, each attending with the first of the KV cache's layers and
-    counting no expert; their rows and zero counts come first."""
-    kinds = cfg.layer_pattern
-    n_per = cfg.scanned_layers // len(kinds)
-    n_full = sum(kind in KV_KINDS for kind in kinds)
-    n_lead = cfg.moe_first_dense_layers
-    x = x.astype(STREAM_DTYPE)
-    lead_rows = []
-    for i, layer_params in enumerate(_lead_layers(lead)):
-        x, _aux, new = layer_forward(
-            cfg.lead_layer_config, layer_params, x, side, None,
-            kv_cache=kv_of(jnp.int32(i), *(a[i] for a in kv_xs)))
-        lead_rows.append(new)
-    if n_lead:
-        kv_xs = tuple(a[n_lead:] for a in kv_xs)
-
-    def by_period(a, n):
-        return a.reshape((n_per, n) + a.shape[1:])
-
-    # Where the scan has more than one period, the routed experts'
-    # matrices do not ride in its xs: a per-layer slice of them is a copy
-    # of every expert for the kernel's custom call.  The scan closes over
-    # the stack's and the kernel addresses its layer (``expert_layer``).
-    # With one period XLA makes no copy, and the two expert cells'
-    # programs stay what they were (their digests are held to the
-    # parent's): one way for both is a change to those cells, to be
-    # measured on them (PERF.md section 7 w).
-    experts = {}
-    if n_per > 1 and cfg.moe_dropless:
-        stacked = list(stacked)
-        for j, tree in enumerate(stacked):
-            mlp = tree.get("mlp", {})
-            experts[j] = {k: mlp[k] for k in ("w_gate", "w_up", "w_down")
-                          if k in mlp}
-            stacked[j] = {**tree, "mlp": {k: v for k, v in mlp.items()
-                                          if k not in experts[j]}}
-    xs = (tuple(stacked), tuple(by_period(a, n_full) for a in kv_xs))
-    # a layer's recurrent mixer (None: it keeps no such state), its place
-    # among that mixer's layers of a period, and how many those are
-    mixers = [_REC_KINDS.get(kind) for kind in kinds]
-    place = [mixers[:j].count(mixer) for j, mixer in enumerate(mixers)]
-    n_rec = {mixer: mixers.count(mixer) for mixer in mixers if mixer}
-    states = {name: rec[name] for mixer in n_rec for name in mixer.names}
-    # a period's "window" layers: their rings do not ride in the carry.
-    # A prompt's come back whole as the scan's ys; a step's kernel reads
-    # the stacked rings where they lie (the scan closes over them) and
-    # its new ROWS come back, for one write behind the scan
-    # (``ring_append_rows``): PR 56's way, the one way both scans have
-    n_win = kinds.count("window")
-    prompt = side.cache_is_empty
-    rings = tuple(rec[name] for name in RING_NAMES) if n_win else ()
-    assert not n_win or prompt or x.shape[1] == 1, (
-        "a \"window\" layer: a prompt into an empty cache, or one new "
-        "position a slot")
-
-    def body(carry, inp):
-        h, idx, states = carry
-        period, kv_p = inp
-        rows, counts, kept, f = [], [], [], 0
-        for at_j, (layer_params, kind, mixer, j) in enumerate(
-                zip(period, kinds, mixers, place)):
-            if experts.get(at_j):
-                layer_params = {**layer_params, "mlp": {
-                    **layer_params["mlp"], **experts[at_j],
-                    "expert_layer": idx}}
-            attends, cache = kind in KV_KINDS, None
-            if attends:
-                kv_layer = idx * n_full + f
-                if n_lead:
-                    kv_layer = kv_layer + n_lead
-                cache = kv_of(kv_layer, *(a[f] for a in kv_p))
-                f += 1
-            elif kind == "window":
-                cache = True if prompt else rings + (
-                    idx * n_win + kinds[:at_j].count("window"),)
-            elif mixer:
-                at = idx * n_rec[mixer] + j
-                cache = _rec_state_at(mixer, states, at, h.shape[1] == 1)
-            h, aux, *new = layer_forward(cfg, layer_params, h, side, None,
-                                         kv_cache=cache, kind=kind)
-            if attends:
-                rows += new
-            elif kind == "window":
-                kept += new
-            elif mixer:
-                states = {**states,
-                          **_rec_write_back(mixer, states, new[0], at)}
-            counts.append(
-                {name: aux[name] for name in ("load", "rows")}
-                if isinstance(aux, dict) else
-                {"load": jnp.zeros((0,), jnp.float32),
-                 "rows": jnp.zeros((2,), jnp.float32)})
-        stack = lambda xs_: jax.tree.map(lambda *a: jnp.stack(a), *xs_) \
-            if xs_ else ()
-        return (h, idx + 1, states), (stack(rows), stack(counts),
-                                      stack(kept))
-
-    (x, _, states), (rows, counts, kept) = jax.lax.scan(
-        body, (x, jnp.int32(0), states), xs)
-    flat = lambda a: a.reshape((a.shape[0] * a.shape[1],) + a.shape[2:])
-    rows, counts = jax.tree.map(flat, rows), jax.tree.map(flat, counts)
-    if n_win:
-        kept = jax.tree.map(flat, kept)
-        states = {**states, **dict(zip(RING_NAMES, (
-            kept if prompt else ring_append_rows(
-                rings, kept, side.position_ids[:, 0]))))}
-    if lead_rows:
-        rows = jax.tree.map(lambda *a: jnp.concatenate(
-            [jnp.stack(a[:-1]), a[-1]]), *lead_rows, rows)
-        counts = jax.tree.map(lambda a: jnp.concatenate(
-            [jnp.zeros((n_lead,) + a.shape[1:], a.dtype), a]), counts)
-    return x, rows, states, counts
-
-
-class KVHand(NamedTuple):
-    """What the one "full" layer of a stack of runs hands to the "cross"
-    layers behind it: its keys and values, in the form the cross layers
-    attend them.  ``form`` "seq": ``k v`` head-major rows of the whole
-    sequence, a query at every position; "rows": dense ``k v`` [b, ., S,
-    .] and the positions ``last`` [b, r] of the few query rows; "paged":
-    ``paged`` the pool (:class:`PagedKV`) and ``k v`` the step's own
-    rows, which are not in it yet."""
-
-    form: str
-    k: jax.Array
-    v: jax.Array
-    last: Optional[jax.Array] = None
-    paged: Optional[PagedKV] = None
-
-
-def _diff_attend(cfg: ModelConfig, p: Params, u, layer, hand: KVHand,
-                 q_rows=None):
-    """A layer's differential attention with the keys and values of
-    ``hand``: its own query projection (of the rows ``q_rows`` [b] alone
-    where given), the form's attention, the pairs' difference and the
-    output projection."""
-    if q_rows is not None:
-        u = jnp.take_along_axis(u, q_rows[:, None, None], axis=1)
-    q = diff_attention.project_q(cfg, p, u)
-    if hand.form == "seq":
-        a = diff_attention.attend_seq(cfg, q, hand.k, hand.v)
-    elif hand.form == "rows":
-        a = diff_attention.attend_rows(cfg, q, hand.k, hand.v, hand.last)
-    else:
-        from ..ops.attention import paged_decode_attention
-
-        pg = hand.paged
-        a = paged_decode_attention(
-            q, pg.k_pool, pg.v_pool, pg.tables, pg.fills, hand.k, hand.v,
-            pg.layer, softmax_scale=diff_attention._scale(cfg))
-    return diff_attention.finish(cfg, p, a, layer)
-
-
-def _full_attend(cfg: ModelConfig, p: Params, u, side: AttnSideInputs,
-                 layer, cache, cut_rows):
-    """The "full" layer of a stack of runs: attention on its own keys and
-    values, all of them.  ``cache``: None (a whole sequence, nothing
-    kept), the dense ``(k_cache, v_cache, cache_len)`` or a
-    :class:`PagedKV`.  ``cut_rows`` [b] (a prompt into an empty cache
-    alone): the keys and values of every row, the query and the output
-    of that row only.  -> ``(out, the new rows or None, the hand)``."""
-    k, v = diff_attention.project_kv(cfg, p, u)
-    if isinstance(cache, PagedKV):
-        k, v = k.astype(cache.k_pool.dtype), v.astype(cache.v_pool.dtype)
-        hand = KVHand("paged", k, v, paged=cache)
-    elif cache is None or side.cache_is_empty:
-        hand = (KVHand("seq", k, v) if cut_rows is None
-                else KVHand("rows", k, v, last=cut_rows[:, None]))
-    else:
-        # one new position on the dense view of the gather route
-        from ..ops.kv_quant import cache_update
-
-        k_cache, v_cache, cache_len = cache
-        hand = KVHand("rows", cache_update(k_cache, k, cache_len),
-                      cache_update(v_cache, v, cache_len),
-                      last=side.position_ids)
-    out = _diff_attend(cfg, p, u, layer, hand, cut_rows)
-    return out, (None if cache is None else (k, v)), hand
-
-
+@jax.named_scope("swa")
 def _window_attend(cfg: ModelConfig, p: Params, u, side: AttnSideInputs,
-                   layer, ring, gate_x=None):
-    """A "window" layer, of a stack of runs or of the period scan: a
-    sequence on itself under the window (``ring`` None: nothing kept;
-    True: a prompt, whose ring comes back), or one new position a slot on
-    the slot's ring ``(ring_k, ring_v, at)`` (the window layers' rings
-    stacked, and which of them), whose new rows come back.  -> ``(out,
-    None | the ring | the new rows)``.
+                   ring, c: _Call):
+    """A "window" layer's mixer (``Mixer.apply``): a sequence on itself
+    under the window (``ring`` None: nothing kept; True: a prompt, whose
+    ring comes back), or one new position a slot on the slot's ring
+    ``(ring_k, ring_v, at)`` (the window layers' rings stacked, and which
+    of them), whose new rows come back.  -> ``(out, None | the ring | the
+    new rows, what was handed)``.
 
     The ring, its install (``diff_attention.ring_of``), its attention
     (``attend_ring``) and the rows' one write (``ring_append_rows``) are
     one mechanism; what differs by stack is read off ``cfg``.  Under
     differential attention: the pairs' projections, a ring row the keys
-    of a pair side by side, the pairs' difference (``layer``).  Else:
+    of a pair side by side, the pairs' difference (``c.layer``).  Else:
     grouped heads at the window layers' own head count and rotation
     (``cfg.window_layer_config``), a ring row one key head's, and the gate
-    a head (``gate_x``).  A key goes to the ring ROTATED at its own
+    a head (``c.gate_x``).  A key goes to the ring ROTATED at its own
     position, from the prompt and from a step alike, and a query is
     rotated at its own: the ring's rows need no positions, the count mask
     stands as it is."""
@@ -1135,14 +863,14 @@ def _window_attend(cfg: ModelConfig, p: Params, u, side: AttnSideInputs,
         k, v = diff_attention.project_kv(cfg, p, u)
         as_rows = lambda k_: diff_attention.pair_rows(cfg, k_)  # noqa: E731
         finish = lambda a: diff_attention.finish(  # noqa: E731
-            cfg, p, a, layer)
+            cfg, p, a, c.layer)
     else:
         w = cfg.window_layer_config
         q, k, v, _gate = _project_heads(w, p, u, side)
         k, v = jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2)   # head-major
         as_rows = lambda k_: k_  # noqa: E731
         finish = lambda a: _project_out(  # noqa: E731
-            w, p, a, None, u if gate_x is None else gate_x)
+            w, p, a, None, c.gate_x)
     kept = None
     if ring is None or ring is True:
         a = diff_attention.attend_seq(w, q, k, v, cfg.sliding_window)
@@ -1157,7 +885,7 @@ def _window_attend(cfg: ModelConfig, p: Params, u, side: AttnSideInputs,
         a = diff_attention.attend_ring(w, q, *ring, k, v,
                                        side.position_ids[:, 0])
         kept = (as_rows(k), v)
-    return finish(a), kept
+    return finish(a), kept, c.handed
 
 
 # under these names the serving state tree keeps the "window" layers'
@@ -1186,163 +914,315 @@ def ring_append_rows(rings, rows, positions):
     return tuple(ap(ring, r) for ring, r in zip(rings, rows))
 
 
-def scan_runs_cached(cfg: ModelConfig, stacked, x, side: AttnSideInputs,
-                     kv_of=None, rec: Optional[dict] = None,
-                     kv_xs: tuple = (), cut_rows=None):
-    """A stack of runs (``cfg.layer_runs``), every form of it: each run a
-    scan over its periods (a run of one period is written out), one run
-    after the other.  Without ``rec``: a whole sequence, every Mamba-1
-    mixer from a zero state, nothing kept.  With ``rec``
+class Mixer(NamedTuple):
+    """The models' half of a block kind (``config.KINDS`` holds the static
+    half): the ``name`` a layer's tree holds the mixer's parameters under
+    ("": the kind is the feed-forward part alone), ``init(keys, cfg,
+    kind)`` which makes them, and ``apply(cfg, p, u, side, cache, call) →
+    (out, the new cache, what is handed on)`` on the block's normed input
+    ``u``; ``cache`` is what the kind keeps (``scan_stack``), None where
+    nothing is kept.  A recurrent mixer also: the class of a layer's
+    ``state`` (``S``, ``conv``, ``at``; :class:`_Stacked`: ``apply`` takes
+    the stacked arrays and its layer's number at any length and slices and
+    writes its layer itself), the ``names`` of the two stacked arrays in
+    ``models/model.py:init_rec_state``'s tree, ``start(cfg, batch)`` a
+    sequence's, and the ``scope`` of the form that makes a prompt's end
+    state."""
+
+    name: str = ""
+    init: object = None
+    apply: object = None
+    state: Optional[type] = None
+    names: tuple = ()
+    start: object = None
+    scope: str = ""
+
+
+def _mix_attention(cfg, p, u, side, cache, c: _Call):
+    """Softmax attention over K/V a KV head, latent attention
+    (``cfg.kv_lora_rank``) or, differential, the "full" layer of its
+    family, whose keys and values are handed on."""
+    if cfg.diff_attention:
+        with jax.named_scope("attention"):
+            out, new, kv = diff_attention.attend_full(
+                cfg, p, u, side, c.layer, cache, c.handed.cut_rows)
+        return out, new, c.handed._replace(kv=kv)
+    if cfg.kv_lora_rank:
+        out = mla.mla_block(cfg, p, u, side, cache)
+    else:
+        out = attention_block(cfg, p, u, side, c.rng, cache, lora=c.lora,
+                              gate_x=c.gate_x)
+    return (out if cache is not None else (out, None)) + (c.handed,)
+
+
+def _mix_cross(cfg, p, u, side, cache, c: _Call):
+    with jax.named_scope("xattn"):
+        return diff_attention.attend_handed(
+            cfg, p, u, c.layer, c.handed.kv), None, c.handed
+
+
+def _mix_mamba1(cfg, p, u, side, cache, c: _Call):
+    """``cache``: the Mamba-1 layers' stacked states and this layer's
+    number; its slice goes through the mixer and is written back here,
+    under the scope of the form that made it."""
+    state = None if cache is None else mamba1.Mamba1State(*(
+        jax.lax.dynamic_index_in_dim(a, cache.at, 0, keepdims=False)
+        for a in cache[:2]))
+    out, new, memory = mamba1.mamba1_block(cfg, p, u, state, side.valid)
+    if cache is not None:
+        with jax.named_scope("mamba1/" + (
+                "mamba1_scan" if side.cache_is_empty else "mamba1_step")):
+            new = _Stacked(*(jax.lax.dynamic_update_index_in_dim(
+                a, n, cache.at, 0) for a, n in zip(cache[:2], new)),
+                cache.at)
+    return out, new, c.handed._replace(memory=memory)
+
+
+_ATTENTION = Mixer("attn", _init_attn, _mix_attention)
+_MAMBA2 = Mixer(
+    "mamba", lambda keys, cfg, kind: mamba2.init_mamba_params(keys[7], cfg),
+    lambda cfg, p, u, side, cache, c: mamba2.mamba_block(
+        cfg, p, u, cache, side.valid) + (c.handed,),
+    mamba2.MambaState, mamba2.STATE_NAMES, mamba2.init_state,
+    "mamba/mamba_scan")
+MIXERS = {
+    "full": _ATTENTION,
+    "attention": _ATTENTION,
+    "window": Mixer("attn", _init_attn, _window_attend),
+    "cross": Mixer("attn", _init_attn, _mix_cross),
+    "linear": Mixer(
+        "gdn", lambda keys, cfg, kind: init_gdn_params(keys[7], cfg),
+        lambda cfg, p, u, side, cache, c: gdn_block(
+            cfg, p, u, cache, side.valid) + (c.handed,),
+        GDNState, gated_deltanet.STATE_NAMES, gated_deltanet.init_state,
+        "gdn/gdn_scan"),
+    "ssm": _MAMBA2,
+    "mamba": _MAMBA2,
+    "ssm1": Mixer(
+        "mamba1",
+        lambda keys, cfg, kind: mamba1.init_mamba1_params(keys[7], cfg),
+        _mix_mamba1, _Stacked, mamba1.STATE_NAMES, mamba1.init_state),
+    "gmu": Mixer(
+        "gmu",
+        lambda keys, cfg, kind: diff_attention.init_gmu_params(keys[7], cfg),
+        lambda cfg, p, u, side, cache, c: (
+            diff_attention.gmu_block(p, u, c.handed.memory), None, c.handed)),
+    "mlp": Mixer(),
+}
+assert set(MIXERS) == set(KINDS)
+# the recurrent mixers, by what their kinds keep (``BlockKind.keeps``)
+REC_MIXERS = {KINDS[kind].keeps: mixer for kind, mixer in MIXERS.items()
+              if mixer.names}
+
+
+def scan_stack(cfg: ModelConfig, stacked, x, side: AttnSideInputs,
+               kv_of=None, rec: Optional[dict] = None, kv_xs: tuple = (),
+               lead=None, cut_rows=None, base_rng=None, layer_offset=0):
+    """Every form of a hybrid stack: the leading dense layers ``lead``
+    (``init_lead_params``), then the runs of ``cfg.stack_runs`` one after
+    the other, each a scan over its periods whose body runs one period's
+    layers in order through ``layer_forward``, each of the kind its
+    position has.  (``stacked``: ``init_stack_params``' tree; a
+    ``layer_pattern``'s list of trees is the one run.)
+
+    Without ``rec``: a whole sequence, every recurrent mixer from a zero
+    state, nothing kept (``base_rng``, ``layer_offset``: the dropout of
+    ``stack_forward``).  With ``rec``
     (``models/model.py:init_rec_state``): a prompt into an empty cache
-    (``side.cache_is_empty``: the Mamba-1 states end at each row's last
-    valid position, every "window" layer's ring is what the prompt leaves
-    in it) or one new position a slot (the states advance where
-    ``side.valid``, the rings are read where they lie and their new rows
-    come back).  The "full" layer gets its cache from ``kv_of(kv layer,
-    *slices of kv_xs)`` as ``scan_periods_cached`` gives it.
+    (``side.cache_is_empty``) or one new position a slot.  What a layer
+    keeps it is given by its kind (``config.BlockKind.keeps``):
 
-    Handed from run to run: the memory of the last "ssm1" layer before a
-    "gmu" layer, and the "full" layer's keys and values (:class:`KVHand`)
-    for the "cross" layers.  ``cut_rows`` [b]: from the "full" layer's
-    attention on, only that row of each sequence is carried
-    (``cfg.row_cut_layer``): its output is [b, 1, h].
+    - "kv": ``kv_of(kv_layer, *slices of kv_xs)``, ``kv_layer`` counting
+      those layers alone, the leading ones first: the KV cache's own
+      layer axis; its new rows come back.
+    - a recurrent state: the states ride in the scan's carry; a prompt's
+      layer reads and rewrites its own slice in place, a step's kernel
+      takes them stacked and advances its layer where it lies
+      (``_rec_state_at``, ``_rec_write_back``); they advance over the
+      positions ``side.valid`` marks.
+    - "window": a ring a slot under ``RING_NAMES``, not in the carry: a
+      prompt's rings come back whole, a step attends the stacked rings
+      where they lie (the scan closes over them) and its new rows are
+      written behind the scan, once (``ring_append_rows``); either way
+      they are among the states returned.
 
-    -> ``(hidden, (rows_k, rows_v) of the "full" layers stacked or None,
-    the Mamba-1 states {name: array} or None, the "window" layers' (k,
-    v) or None: after a prompt their rings ``[window layers, b, ., W,
-    .]``, after a step their new ROWS ``[window layers, b, ., 1, .]``
-    for the caller's one write)``."""
-    x = x.astype(STREAM_DTYPE)
-    step = rec is not None and not side.cache_is_empty
-    assert not step or x.shape[1] == 1
+    Handed from layer to layer and from run to run (:class:`Handed`): the
+    memory of the last "ssm1" layer before a "gmu" layer, and the "full"
+    layer's keys and values for the "cross" layers, which therefore
+    stands in a run that is written out (one period, in a stack of
+    several runs): a scan hands on no keys and no stream cut to one row.
+    ``cut_rows`` [b]: from that layer's attention on only that row of
+    each sequence is carried (``cfg.row_cut_layer``): [b, 1, h] comes
+    back.
+
+    → ``(hidden, (rows_k, rows_v) stacked over the layers that keep
+    "kv" or None, the states {name: array} advanced and the rings
+    among them, counts: every layer's ``_mlp_dispatch``
+    aux stacked [layers, ...], of which ``load`` and ``rows`` are what
+    the engine counts: the experts the valid positions chose and the
+    (token, choice) rows each layer's experts multiplied and skipped;
+    zero rows for a layer without experts, the leading ones among
+    them)``."""
+    runs = cfg.stack_runs
+    trees = stacked if cfg.layer_runs else [stacked]
+    several = len(runs) > 1
+    n_lead = cfg.moe_first_dense_layers
+    kinds = cfg.layer_kinds[n_lead:]            # the scanned layers'
+    prompt = side.cache_is_empty
+    assert rec is None or prompt or x.shape[1] == 1, (
+        "a prompt into an empty cache, or one new position a slot")
     assert cut_rows is None or cfg.row_cut_layer is not None
-    states = None if rec is None else {
-        name: rec[name] for name in mamba1.STATE_NAMES if name in rec}
-    kinds = cfg.layer_kinds
-    memory_in, hand_in = None, None    # what the runs before hand on
-    all_rows, rings = [], []
-    layer0 = 0
-    for (period, times), trees in zip(cfg.layer_runs, stacked):
+    x = x.astype(STREAM_DTYPE)
+    lead_rows = []
+    for i, layer_params in enumerate(_lead_layers(lead)):
+        x, _aux, *new = layer_forward(
+            cfg.lead_layer_config, layer_params, x, side,
+            kv_cache=None if kv_of is None else kv_of(
+                jnp.int32(i), *(a[i] for a in kv_xs)))
+        lead_rows += new
+    if n_lead:
+        kv_xs = tuple(a[n_lead:] for a in kv_xs)
+    states = {} if rec is None else {
+        name: rec[name] for keeps, mixer in REC_MIXERS.items()
+        if cfg.layers_keeping(keeps) for name in mixer.names}
+    rings = (tuple(rec[name] for name in RING_NAMES)
+             if rec is not None and cfg.window_layers else ())
+
+    def nth(first, idx, per, j):
+        """The number, among its like, of the ``j``-th of the ``per``
+        such layers of period ``idx`` of a run before which ``first``
+        stand (one run needs no first: spelled as the cells' programs
+        were lowered)."""
+        at = idx * per
+        if several:
+            at = first + at
+        return at + j
+
+    flat = lambda a: a.reshape(  # noqa: E731
+        (a.shape[0] * a.shape[1],) + a.shape[2:])
+    stack = lambda xs_: jax.tree.map(  # noqa: E731
+        lambda *a: jnp.stack(a), *xs_) if xs_ else ()
+    out = ([], [], [])                  # rows, counts, kept: a part a run
+    memory_in, kv_in = None, None       # what the runs before hand on
+    layer0, first = 0, {}       # the layers, and those that keep a thing,
+    #                             before the run
+    for (period, times), run_trees in zip(runs, trees):
         n = len(period)
+        keeps = [KINDS[kind].keeps for kind in period]
+        per = {k: keeps.count(k) for k in keeps}
         later = kinds[layer0 + n * times:]
+        reads = [KINDS[kind].reads for kind in later]
         # (a run's memory is carried on where a later run gates with it
         # before making its own)
-        hands_memory = "ssm1" in period and "gmu" in later and (
-            "ssm1" not in later[:later.index("gmu")])
-        base = {kind: kinds[:layer0].count(kind)
-                for kind in ("ssm1", "window", "full")}
-        per = {kind: period.count(kind) for kind in base}
+        hands_memory = "ssm1" in per and "memory" in reads and (
+            "ssm1" not in [KINDS[kind].keeps
+                           for kind in later[:reads.index("memory")]])
+        # Where a run scans more than one period, the routed experts'
+        # matrices do not ride in its xs: a per-layer slice of them is a
+        # copy of every expert for the kernel's custom call.  The scan
+        # closes over the stack's and the kernel addresses its layer
+        # (``expert_layer``).  With one period XLA makes no copy, and the
+        # two expert cells' programs stay what they were (their digests
+        # are held to the parent's): one way for both is a change to
+        # those cells, to be measured on them (PERF.md section 7 w).
+        experts = {}
+        if times > 1 and cfg.moe_dropless:
+            run_trees = list(run_trees)
+            for j, tree in enumerate(run_trees):
+                mlp = tree.get("mlp", {})
+                experts[j] = {k: mlp[k] for k in ("w_gate", "w_up", "w_down")
+                              if k in mlp}
+                run_trees[j] = {**tree, "mlp": {
+                    k: v for k, v in mlp.items() if k not in experts[j]}}
 
         # (called in this turn of the loop, written out or under the scan:
         # it reads the turn's own variables)
         def body(carry, inp):
             h, idx, states, memory = carry
             period_params, kv_p = inp
-            memory = memory_in if memory is None else memory
-            hand = hand_in
-            rows, kept = [], []
-            seen = {kind: 0 for kind in per}
-            for j, (kind, p) in enumerate(zip(period, period_params)):
-                layer = layer0 + idx * n + j
-                at = base.get(kind, 0) + idx * per.get(kind, 0) \
-                    + seen.get(kind, 0)
-                if kind in seen:
-                    seen[kind] += 1
-                u = norm_apply(cfg.norm_type, h, p["input_norm"],
-                               cfg.norm_eps, impl=cfg.norm_impl)
-                if kind == "ssm1":
-                    state = None if states is None else mamba1.Mamba1State(
-                        *(jax.lax.dynamic_index_in_dim(
-                            states[name], at, 0, keepdims=False)
-                          for name in mamba1.STATE_NAMES))
-                    out, new, memory = mamba1.mamba1_block(
-                        cfg, p["mamba1"], u, state, side.valid)
-                    if states is not None:
-                        with jax.named_scope(
-                                "mamba1/" + ("mamba1_step" if step
-                                             else "mamba1_scan")):
-                            states = {name: jax.lax.
-                                      dynamic_update_index_in_dim(
-                                          states[name], a, at, 0)
-                                      for name, a in zip(
-                                          mamba1.STATE_NAMES, new)}
-                elif kind == "gmu":
-                    out = diff_attention.gmu_block(p["gmu"], u, memory)
-                elif kind == "window":
-                    with jax.named_scope("swa"):
-                        ring = (None if rec is None else True if not step
-                                else tuple(rec[name] for name in RING_NAMES)
-                                + (at,))
-                        out, keep = _window_attend(
-                            cfg, p["attn"], u.astype(cfg.dtype), side,
-                            layer, ring)
-                    if keep is not None:
-                        kept.append(keep)
-                elif kind == "full":
-                    cache = None if kv_of is None else kv_of(
-                        at, *(a[seen[kind] - 1] for a in kv_p))
-                    with jax.named_scope("attention"):
-                        out, new, hand = _full_attend(
-                            cfg, p["attn"], u.astype(cfg.dtype), side, layer,
-                            cache, cut_rows)
-                    if new is not None:
-                        rows.append(new)
-                    if cut_rows is not None:
-                        # the boundary between the decoders: from here
-                        # on, one row of each sequence
-                        cut = lambda a: jnp.take_along_axis(  # noqa: E731
-                            a, cut_rows[:, None, None], axis=1)
-                        h = cut(h)
-                        memory = None if memory is None else cut(memory)
-                else:                                   # "cross"
-                    with jax.named_scope("xattn"):
-                        out = _diff_attend(cfg, p["attn"],
-                                           u.astype(cfg.dtype), layer, hand)
-                h = h + out
-                u = norm_apply(cfg.norm_type, h, p["post_attn_norm"],
-                               cfg.norm_eps, impl=cfg.norm_impl)
-                h = h + _mlp_dispatch(cfg, p["mlp"], u)[0]
-            stack = lambda xs_: jax.tree.map(  # noqa: E731
-                lambda *a: jnp.stack(a), *xs_) if xs_ else ()
-            carry = (h, idx + 1, states, memory if hands_memory else None)
-            return carry, (stack(rows), stack(kept), hand)
+            handed = Handed(memory_in if memory is None else memory, kv_in,
+                            cut_rows)
+            rows, counts, kept = [], [], []
+            seen = dict.fromkeys(per, 0)
+            for j, (kind, keep, p) in enumerate(
+                    zip(period, keeps, period_params)):
+                if experts.get(j):
+                    p = {**p, "mlp": {**p["mlp"], **experts[j],
+                                      "expert_layer": idx}}
+                layer = nth(layer0, idx, n, j)
+                if n_lead:
+                    layer = layer + n_lead
+                cache, mixer = None, REC_MIXERS.get(keep)
+                if keep:
+                    at = nth(first.get(keep, 0), idx, per[keep], seen[keep])
+                if keep == "kv" and kv_of is not None:
+                    cache = kv_of(at + n_lead if n_lead else at,
+                                  *(a[seen[keep]] for a in kv_p))
+                elif keep == "window" and rec is not None:
+                    cache = True if prompt else rings + (at,)
+                elif mixer and rec is not None:
+                    cache = _rec_state_at(mixer, states, at, h.shape[1] == 1)
+                if keep:
+                    seen[keep] += 1
+                h, aux, *new, handed = layer_forward(
+                    cfg, p, h, side,
+                    None if base_rng is None
+                    else jax.random.fold_in(base_rng, layer),
+                    kv_cache=cache,
+                    layer_idx=layer_offset + layer if layer_offset else layer,
+                    kind=kind, handed=handed)
+                if cache is not None and mixer:
+                    states = {**states,
+                              **_rec_write_back(mixer, states, new[0], at)}
+                elif cache is not None:
+                    (rows if keep == "kv" else kept).extend(new)
+                counts.append(aux if isinstance(aux, dict) else {
+                    "load": jnp.zeros((0,), jnp.float32),
+                    "rows": jnp.zeros((2,), jnp.float32)})
+            carry = (h, idx + 1, states,
+                     handed.memory if hands_memory else None)
+            return carry, (stack(rows), stack(counts), stack(kept)), handed.kv
 
-        def by_period(a, kind):
-            a = a[base[kind]:base[kind] + times * per[kind]]
-            return a.reshape((times, per[kind]) + a.shape[1:])
-
-        xs = (tuple(trees), tuple(by_period(a, "full") for a in kv_xs))
+        lo, n_kv = first.get("kv", 0), per.get("kv", 0)
+        xs = (tuple(run_trees), tuple(
+            (a[lo:lo + times * n_kv] if several else a)
+            .reshape((times, n_kv) + a.shape[1:]) for a in kv_xs))
         carry = (x, jnp.int32(0), states, None)
-        if times == 1:
+        if several and times == 1:
             # written out: the period may hand on what a scan could not
             # (the keys and values, a stream cut to one row)
-            carry, (rows, kept, hand_in) = body(
-                carry, jax.tree.map(lambda a: a[0], xs))
-            rows, kept = jax.tree.map(lambda a: a[None], (rows, kept))
+            carry, ys, kv_in = body(carry, jax.tree.map(lambda a: a[0], xs))
+            ys = jax.tree.map(lambda a: a[None], ys)
         else:
-            assert "full" not in period or "cross" not in kinds, (
+            assert "kv" not in per or not cfg.cross_layers, (
                 "the layer whose keys and values are handed on stands in "
-                "a run of one period")
+                "a run of one period, of several runs")
             if hands_memory:
                 carry = carry[:3] + (jnp.zeros(
                     x.shape[:2] + (cfg.mamba1_inner,), jnp.float32),)
-
-            def scanned(c, i):
-                c, (rows, kept, _hand) = body(c, i)
-                return c, (rows, kept)
-
-            carry, (rows, kept) = jax.lax.scan(scanned, carry, xs)
+            carry, ys = jax.lax.scan(lambda c, i: body(c, i)[:2], carry, xs)
         x, _, states, memory_in = carry
-        flat = lambda a: a.reshape(  # noqa: E731
-            (a.shape[0] * a.shape[1],) + a.shape[2:])
-        if per["full"] and kv_of is not None:
-            all_rows.append(jax.tree.map(flat, rows))
-        if per["window"] and rec is not None:
-            rings.append(jax.tree.map(flat, kept))
+        for parts, ys_ in zip(out, ys):
+            if jax.tree.leaves(ys_):
+                parts.append(jax.tree.map(flat, ys_))
         layer0 += n * times
-    cat = lambda parts: jax.tree.map(  # noqa: E731
-        lambda *a: jnp.concatenate(a), *parts) if parts else None
-    return x, cat(all_rows), states, cat(rings)
+        for k in per:
+            first[k] = first.get(k, 0) + times * per[k]
+    rows, counts, kept = (
+        jax.tree.map(lambda *a: jnp.concatenate(a), *parts) if parts else None
+        for parts in out)
+    if kept is not None:
+        # a prompt's rings whole; a step's rows, written behind the scan
+        states = {**states, **dict(zip(RING_NAMES, (
+            kept if prompt else ring_append_rows(
+                rings, kept, side.position_ids[:, 0]))))}
+    if lead_rows:
+        rows = jax.tree.map(lambda *a: jnp.concatenate(
+            [jnp.stack(a[:-1]), a[-1]]), *lead_rows, rows)
+    if n_lead:
+        counts = jax.tree.map(lambda a: jnp.concatenate(
+            [jnp.zeros((n_lead,) + a.shape[1:], a.dtype), a]), counts)
+    return x, rows, states, counts
 
 
 def _scan_layers_cached(cfg: ModelConfig, stacked: Params, x: jax.Array,
@@ -1382,10 +1262,8 @@ def stack_forward_cached(cfg: ModelConfig, stacked: Params, x: jax.Array,
     parameter layout, so one compiled layer body serves every depth.  The
     caches enter the scan as read-only *xs* (per-layer slices); each layer
     returns only its new token rows ([L, b, nkv, s, d] stacked ys) and one
-    batched dynamic_update_slice after the scan writes them back — earlier
-    designs that threaded updated caches through the scan ys re-stacked
-    (copied) the entire cache every decode step, which dominated decode
-    latency (3x measured at max_len=256, worse as the window grows).
+    batched dynamic_update_slice after the scan writes them back (caches
+    threaded through the scan's ys are re-stacked, copied whole, a step).
     Returns ``(hidden, new_k_cache, new_v_cache)``; the caller advances
     ``cache_len``.  Parity: the reference's InferenceParams threading
     through ParallelTransformer (transformer.py:423-496,1158-1246).

@@ -57,8 +57,8 @@ DEVICE_SCOPES = (
     # projections and the absorption, and the absorbed form's kernel (the
     # expanded form runs "flash_fwd")
     "mla_proj", "mla_decode",
-    # a stack of runs (models/transformer.py:scan_runs_cached): the
-    # Mamba-1 mixer and its parts (models/mamba1.py), the window layers
+    # the kinds of a stack of runs alone and "window"
+    # (models/transformer.py:MIXERS): the Mamba-1 mixer and its parts (models/mamba1.py), the window layers
     # ("swa": their flash_fwd under the window, the ring's attention, the
     # kernel "ring_decode", and its row writes), the cross layers
     # ("xattn": their walks of the one cached layer run "flash_decode")

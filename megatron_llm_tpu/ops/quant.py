@@ -171,10 +171,9 @@ def mm(x: jax.Array, w) -> jax.Array:
 # int8* operands — the same tensors the forward consumed, matching
 # TransformerEngine's fp8 wgrad/dgrad semantics (see _int8_mm_bwd) — and
 # the fp32 master-weight update (training/optimizer.py) is untouched.
-# Measured ceiling (v5e, docs/perf_notes.md §2): the fwd is 1.46x a bf16
-# dot (XLA's int8 dot reaches 1.35x, dynamic quantization eats the rest)
-# but the unquantized bwd holds the full step at ~1.04x; int8
-# dgrad/wgrad + static scaling are the path to a real win.
+# No speed is claimed for it (no cell of the benchmark runs it: PERF.md
+# section 7): the operands are quantized anew a call and the backward is
+# unquantized; int8 dgrad/wgrad + static scaling are the path to a win.
 # ---------------------------------------------------------------------------
 
 

@@ -126,7 +126,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..analysis import sanitizers
-from ..config import ModelConfig
+from ..config import KINDS, ModelConfig
 from ..generation.sampling import NEG_INF
 from ..kernels.flash_attention import tile_plan
 from ..kernels.flash_decode import pool_walk, walk_counts
@@ -1270,17 +1270,18 @@ class ServingEngine:
         # a hybrid stack's kinds of slot state, on its prefill and decode
         # spans ("linear", "mamba", "linear+mamba"; no such field for a
         # one-kind stack), and whether a state-space layer is among them
-        kinds = [kind for kind, n in (("linear", cfg.linear_layers),
-                                      ("mamba", cfg.mamba_layers),
-                                      ("ssm1", cfg.mamba1_layers),
-                                      ("window", cfg.window_layers)) if n]
+        kinds = [keeps for keeps in model_lib.REC_STATE_KINDS
+                 if cfg.layers_keeping(keeps)]
         self._state_arg = {"state_kinds": "+".join(kinds)} if kinds else {}
         self._counts_ssm = cfg.mamba_layers + cfg.mamba1_layers > 0
-        # layers that walk a cache another layer wrote ("cross"), beside
-        # the one that wrote it: a step's walks are counted by kind, its
-        # decode spans carry the cached positions it attended
+        # layers that walk a cache another layer wrote (the kinds that
+        # read "kv": ``config.KINDS``), beside the one that wrote it: a
+        # step's walks are counted by kind, its decode spans carry the
+        # cached positions it attended
         self._kv_readers = (
-            {"full": cfg.kv_layers, "cross": cfg.cross_layers}
+            {"full": cfg.kv_layers, **{
+                kind: cfg.layer_kinds.count(kind)
+                for kind, k in KINDS.items() if k.reads == "kv"}}
             if cfg.cross_layers else
             {"full": cfg.kv_layers} if cfg.window_layers else {})
         # rows a slot's "window" rings hold at most (0: no such layers):

@@ -4,7 +4,6 @@ block whose mixer is Mamba-2, the chunked form at the published chunk of
 every other preset's lowered program as it was), and the preset's sizes."""
 
 import dataclasses
-import hashlib
 
 import jax
 import jax.numpy as jnp
@@ -12,13 +11,10 @@ import numpy as np
 import pytest
 
 from megatron_llm_tpu import config as config_lib
-from megatron_llm_tpu.config import (falcon_config, granite_hybrid_config,
-                                     nemotron_h_config, qwen3_next_config,
-                                     tiny_config)
+from megatron_llm_tpu.config import granite_hybrid_config, tiny_config
 from megatron_llm_tpu.models import mamba2
 from megatron_llm_tpu.models import model as model_lib
 from megatron_llm_tpu.models import transformer
-from megatron_llm_tpu.serving import engine as engine_lib
 
 TINY = dict(num_layers=4, layer_pattern=("ssm", "full"), hidden_size=64,
             num_attention_heads=4, num_kv_heads=2, kv_channels=16,
@@ -80,8 +76,8 @@ def test_a_two_part_block_holds_a_mixer_and_the_mlp(model):
     assert sorted(ssm["mlp"]) == ["w_down", "w_gate", "w_up"]
     assert ssm["mamba"]["w_in"].shape == (2, 64, 64 + 96 + 8)
     assert "lm_head" not in params
-    assert set(config_lib.MAMBA_KINDS) | set(config_lib.KV_KINDS) \
-        | set(config_lib.FFN_KINDS) == set(config_lib.BLOCK_KINDS)
+    assert (config_lib.KINDS["ssm"], config_lib.KINDS["full"]) == (
+        config_lib.BlockKind("mamba"), config_lib.BlockKind("kv"))
     rec = model_lib.init_rec_state(cfg, 3)
     # two periods: the tail is kept flat, whole tiles of (slots, lanes);
     # one period keeps its three rows apart
@@ -92,7 +88,8 @@ def test_a_two_part_block_holds_a_mixer_and_the_mlp(model):
     # one layer, by hand: x + r mixer(norm x), then x + r mlp(norm x)
     p = jax.tree.map(lambda a: a[0], ssm)
     x = jax.random.normal(jax.random.key(1), (2, 24, 64))
-    got, _ = transformer.layer_forward(cfg, p, x, transformer.AttnSideInputs())
+    got, _ = transformer.layer_forward(cfg, p, x, transformer.AttnSideInputs(),
+                                       kind="ssm")
     rms = lambda v, w: w * v * jax.lax.rsqrt(  # noqa: E731
         jnp.mean(v * v, -1, keepdims=True) + cfg.norm_eps)
     r = cfg.residual_multiplier
@@ -165,100 +162,14 @@ def test_a_padded_prefill_then_steps_is_the_whole_sequence(model):
 
 # --- what the new fields leave as it was --------------------------------
 
-OTHERS = {
-    "falcon": lambda: falcon_config(
-        "7b", hidden_size=64, num_layers=2, num_attention_heads=4,
-        ffn_hidden_size=128, vocab_size=512, make_vocab_size_divisible_by=8,
-        params_dtype="float32"),
-    "qwen3_next": lambda: qwen3_next_config(
-        "80b-a3b-ep2-rank0", num_layers=4, hidden_size=64,
-        num_attention_heads=4, num_kv_heads=2, kv_channels=16,
-        linear_num_key_heads=2, linear_num_value_heads=4,
-        linear_key_head_dim=8, linear_value_head_dim=8, ffn_hidden_size=32,
-        num_experts=4, moe_router_experts=8, moe_top_k=2,
-        moe_shared_expert_size=32, vocab_size=512,
-        make_vocab_size_divisible_by=8, moe_group_size=64,
-        params_dtype="float32"),
-    # (the published heads and groups: the state step's tiling is theirs)
-    "nemotron_h": lambda: nemotron_h_config(
-        "3-super-120b-a12b-ep4-rank0", num_layers=4,
-        layer_pattern=("attention", "mlp", "mamba", "mlp"), hidden_size=64,
-        num_attention_heads=4, num_kv_heads=2, kv_channels=16,
-        ffn_hidden_size=32, moe_shared_expert_size=48, moe_latent_size=32,
-        num_experts=4, moe_router_experts=16, moe_top_k=6, vocab_size=512,
-        mamba_num_heads=128, mamba_head_dim=8, mamba_n_groups=8,
-        mamba_state_size=16, mamba_chunk_size=8,
-        max_position_embeddings=512, make_vocab_size_divisible_by=8,
-        moe_group_size=64, params_dtype="float32"),
-}
-
-# sha256 of the lowered text, taken with this function on the parent
-# commit (b731d33, PR 47): the four multipliers at their defaults, the
-# generalised tiling of the state step's kernel at eight groups of 16
-# heads, and a one-period stack's convolution tail leave every line of
-# them as it was.  A PR that changes what a preset lowers to replaces its
-# digest on purpose: ("nemotron_h", "decode") is PR 50's, whose one
-# kernel takes a state-space layer's whole step between its two
-# projections (the convolution and its tail, the step size, the skip, the
-# gate, the norm) where the parent's program held a dozen small
-# operations around the kernel; both ("qwen3_next", ...) are PR 51's: the
-# delta rule's states ride in the period scan's carry as the state-space
-# states do (a prompt's layer reads and rewrites its slice in place where
-# the states went through the scan as xs and ys) and a decode step's
-# DeltaNet layer is one kernel on the stacked states between its two
-# projections (kernels/gdn_step.py) where the parent's program held the
-# plain composition.  All four of ("qwen3_next", ...) and ("nemotron_h",
-# ...) are PR 53's: a dropless layer's router takes its k experts, their
-# scores and the load from one kernel's k rounds of max-and-mask
-# (kernels/moe_router.py) where the parent's programs held a ``top_k``,
-# on the sigmoid path a ``take_along_axis``, and a scatter-add of rows x
-# k single elements; the same experts in the same order
-# (tests/models/test_moe.py).  The two of "falcon" are PR 47's.
-LOWERED = {
-    ("falcon", "decode"): "ba47a517f99fe833",
-    ("falcon", "prefill"): "8cebe19aaf9ad16b",
-    ("qwen3_next", "decode"): "b182f5e7cd4193b0",
-    ("qwen3_next", "prefill"): "ca62eb8f3f806c11",
-    ("nemotron_h", "decode"): "e40cbf71ee017751",
-    ("nemotron_h", "prefill"): "a0390066aa4053b5",
-}
-
-
-def lowered(cfg, program, slots=2, blocks=4, bk=16) -> str:
-    i32, f32 = jnp.int32, jnp.float32
-    params = jax.eval_shape(lambda k: model_lib.init_params(k, cfg),
-                            jax.random.key(0))
-    if program == "prefill":
-        return engine_lib._prefill_impl.lower(
-            cfg, params, jax.ShapeDtypeStruct((1, 32), i32),
-            jax.ShapeDtypeStruct((1,), i32), max_seq_len=64,
-            want_logprobs=False).as_text()
-    pool = jax.eval_shape(
-        lambda: model_lib.init_kv_pool(cfg, slots * blocks + 1, bk))
-    vec = lambda d: jax.ShapeDtypeStruct((slots,), d)  # noqa: E731
-    state = {}
-    if cfg.layer_pattern:
-        state = dict(rec=jax.eval_shape(
-            lambda: model_lib.init_rec_state(cfg, slots)), live=vec(bool))
-    return engine_lib._decode_plain.lower(
-        cfg, params, *pool, jax.ShapeDtypeStruct((slots, blocks), i32),
-        vec(i32), vec(i32), vec(jnp.uint32), vec(i32), vec(bool), vec(f32),
-        vec(i32), vec(f32), **state).as_text()
-
-
-@pytest.mark.parametrize("preset,program", sorted(LOWERED))
-def test_the_other_presets_lower_to_what_they_did(preset, program):
-    text = lowered(OTHERS[preset](), program)
-    digest = hashlib.sha256(text.encode()).hexdigest()[:16]
-    assert digest == LOWERED[preset, program], (preset, program, digest)
-
-
 def test_a_multiplier_at_its_default_is_not_in_the_program():
     """The same tiny stack with the four at their defaults and with each
     written out at the value that changes nothing lowers to one text; away
     from it the text differs."""
     base = tiny(embedding_multiplier=1.0, residual_multiplier=1.0,
                 attention_multiplier=None, logits_scaling=1.0)
+    from tests.models.test_lowered_programs import lowered
+
     text = lowered(base, "decode")
     assert lowered(dataclasses.replace(
         base, attention_multiplier=16 ** -0.5), "decode") == text

@@ -9,7 +9,6 @@ lowered programs, which this PR leaves byte for byte."""
 
 import dataclasses
 import functools
-import hashlib
 
 import jax
 import jax.numpy as jnp
@@ -388,42 +387,12 @@ def test_a_full_layer_rotates_half_the_head_by_yarn():
 
 
 # --- what the new fields leave as it was ----------------------------------
-# sha256 of the lowered text of PR 56's stack of runs and PR 52's latent
-# stack at their tests' tiny sizes, taken with test_granite_hybrid_stack.py's
-# ``lowered`` on the parent commit (5dab4d3, PR 57) before this PR's first
-# edit: this PR opens the files they live in (the falcon, qwen3_next,
-# nemotron_h, falcon-40b and granite digests stand in the two older files)
-
-LOWERED = {
-    ("phi4flash", "decode"): "f2c80b6a703c909b",
-    ("phi4flash", "prefill"): "efefa58cbb0956d8",
-    ("kanana", "decode"): "c3a130d9a47ee2af",
-    ("kanana", "prefill"): "96123cb1191b441e",
-}
-
-
-def _older(preset):
-    if preset == "phi4flash":
-        from tests.models import test_phi4flash_stack as other
-    else:
-        from tests.models import test_mla_stack as other
-    return other.tiny()
-
-
-@pytest.mark.parametrize("preset,program", sorted(LOWERED))
-def test_the_stack_of_runs_and_the_latent_stack_lower_to_what_they_did(
-        preset, program):
-    from tests.models.test_granite_hybrid_stack import lowered
-
-    text = lowered(_older(preset), program)
-    digest = hashlib.sha256(text.encode()).hexdigest()[:16]
-    assert digest == LOWERED[preset, program], (preset, program, digest)
-
 
 def test_a_window_field_at_its_default_is_not_in_the_program():
     """A stack without a "window" layer lowers to one text whether the
     new fields are written out at their defaults or left."""
-    from tests.models.test_granite_hybrid_stack import lowered, tiny as older
+    from tests.models.test_granite_hybrid_stack import tiny as older
+    from tests.models.test_lowered_programs import lowered
 
     base = older()
     assert lowered(dataclasses.replace(

@@ -255,10 +255,10 @@ def _bf16_state(monkeypatch):
 
 
 def _bf16_ring(monkeypatch):
-    ring_of, append = diff_attention.ring_of, model_lib.ring_append_rows
+    ring_of, append = diff_attention.ring_of, transformer.ring_append_rows
     monkeypatch.setattr(diff_attention, "ring_of",
                         lambda *a: _rounded(ring_of(*a)))
-    monkeypatch.setattr(model_lib, "ring_append_rows", lambda rings, rows,
+    monkeypatch.setattr(transformer, "ring_append_rows", lambda rings, rows,
                         pos: append(rings, jax.tree.map(_rounded, rows), pos))
 
 
@@ -274,7 +274,7 @@ def _memory_after_the_gate(monkeypatch):
 def _cross_reads_its_own_input(monkeypatch):
     """A cross layer whose keys and values are projected from ITS input
     (with the full layer's weights) and not handed from the full layer."""
-    attend, full = transformer._diff_attend, transformer._full_attend
+    attend, full = diff_attention.attend_handed, diff_attention.attend_full
     held = {}
 
     def full_attend(cfg, p, u, *a):
@@ -287,8 +287,8 @@ def _cross_reads_its_own_input(monkeypatch):
             hand = hand._replace(k=k, v=v)
         return attend(cfg, p, u, layer, hand, q_rows)
 
-    monkeypatch.setattr(transformer, "_full_attend", full_attend)
-    monkeypatch.setattr(transformer, "_diff_attend", diff_attend)
+    monkeypatch.setattr(diff_attention, "attend_full", full_attend)
+    monkeypatch.setattr(diff_attention, "attend_handed", diff_attend)
 
 
 @pytest.mark.parametrize("omission,served", [
